@@ -1,0 +1,408 @@
+"""chip_smoke.py — the quickest proof that the system starts on the chip.
+
+Drives the exact-search main path once through the entry points a user
+calls, at the full width of bench config 4 (``input3.in``: 200 000 x
+10 000 x 64, k in [1, 32], exact mode, ``--pallas``), and checks every
+answer byte for byte against the captured output of the reference's own
+binary (``oracle_capture/oracle_4.out``):
+
+  generate  python -m dmlp_tpu.io.datagen            sha256 pinned
+  batch     python -m dmlp_tpu --pallas ...          cmp oracle
+  serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
+                                                     stats, SIGTERM drain
+  mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
+
+A chip serves one process at a time, so this parent never initialises a
+JAX backend: every phase that needs the chip is one child, run to its end
+before the next starts, and everything checked about the device comes
+from what the child itself wrote (the device stamp in the ``--metrics``
+summary, the daemon's ready file and ``stats`` reply). Any miss — a child
+on another platform, a Pallas kernel in interpret mode, a degrade-ladder
+rung below the first, a retry, a mesh that left the corpus on one
+device — is a non-zero exit naming the check, and no result line.
+
+The configs run in the order given (default ``1,4``): config 1 is the
+same path at a size that takes seconds, so a machine with no chip fails
+there instead of after minutes at full width; a config is started only
+if every one before it passed. ``--configs 1`` under JAX_PLATFORMS=cpu
+is the dry run before a chip call: all checksums must match and the
+exit is non-zero naming ``platform is cpu``.
+
+Timings printed here are smoke timings of single cold runs, not
+measurements. The last stdout line of a passing run is one JSON object:
+``{"ok": true, "device": {"platform", "kind", "count"}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import subprocess
+import sys
+import time
+from typing import Any, Dict, List, Optional, Tuple
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(REPO, "outputs", "chip_smoke")        # large inputs
+LOGS = os.path.join(REPO, "chiprun_out", "chip_smoke")    # child records
+
+SERVE_REQUESTS = 4         # query requests sent to the daemon ...
+SERVE_REQUEST_QUERIES = 256  # ... each this many queries, own k each
+CHILD_TIMEOUT_S = 900.0
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# -- children ------------------------------------------------------------------
+
+def child_env() -> Dict[str, str]:
+    """The caller's environment (platform and compile-cache placement
+    included) plus the checkout on the path — and the tune cache pointed
+    at a file that does not exist, so kernel variants are the committed
+    heuristic and nothing outside the checkout is read."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["DMLP_TPU_TUNE_CACHE"] = os.path.join(WORK, "no_tune_cache.json")
+    return env
+
+
+def run_child(argv: List[str], stdin_path: Optional[str], out_path: str,
+              err_path: str) -> Tuple[int, float]:
+    """One child to completion; (exit code, wall seconds)."""
+    t0 = time.monotonic()
+    with open(out_path, "wb") as out, open(err_path, "wb") as err, \
+            open(stdin_path or os.devnull, "rb") as stdin:
+        proc = subprocess.Popen([sys.executable] + argv, stdin=stdin,
+                                stdout=out, stderr=err, env=child_env(),
+                                cwd=REPO)
+        try:
+            rc = proc.wait(timeout=CHILD_TIMEOUT_S)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            rc = proc.wait()
+    return rc, time.monotonic() - t0
+
+
+def tail(path: str, lines: int = 15) -> str:
+    try:
+        with open(path, errors="replace") as f:
+            return "".join(f.readlines()[-lines:])
+    except OSError:
+        return ""
+
+
+# -- checks --------------------------------------------------------------------
+
+def check_stamp(stamp: Any, mesh: Optional[List[int]], ladder: bool,
+                num_data: int) -> List[str]:
+    """Every miss in one child's device stamp, named."""
+    if not isinstance(stamp, dict):
+        return ["child wrote no device stamp"]
+    bad = []
+    if stamp.get("platform") != "tpu":
+        bad.append(f"platform is {stamp.get('platform')}")
+    if not stamp.get("peak_flops_known"):
+        bad.append(f"device_kind {stamp.get('device_kind')!r} is not in "
+                   "the peaks table")
+    want_devices = mesh[0] * mesh[1] if mesh else 1
+    if stamp.get("device_count", 0) < want_devices:
+        bad.append(f"device_count is {stamp.get('device_count')}, the "
+                   f"phase needs {want_devices}")
+    if stamp.get("mesh") != mesh:
+        bad.append(f"mesh is {stamp.get('mesh')}, asked for {mesh}")
+    if stamp.get("select") != "extract":
+        bad.append(f"select is {stamp.get('select')}")
+    if stamp.get("extract_impl") != "fused":
+        bad.append(f"extract_impl is {stamp.get('extract_impl')}")
+    if stamp.get("pallas_interpret") is not False:
+        bad.append("pallas_interpret is "
+                   f"{stamp.get('pallas_interpret')}")
+    variant = stamp.get("kernel_variant") or {}
+    if variant.get("from_tune_cache") is not False:
+        bad.append(f"kernel variant {variant} did not come from the "
+                   "committed heuristic")
+    if ladder and stamp.get("degrade_rung") != "lowp":
+        bad.append(f"degrade rung is {stamp.get('degrade_rung')}")
+    if stamp.get("degradations"):
+        bad.append(f"degradations recorded: {stamp['degradations']}")
+    if stamp.get("retries"):
+        bad.append(f"retries recorded: {stamp['retries']}")
+    if mesh and mesh[0] * mesh[1] > 1:
+        rows = stamp.get("corpus_rows_per_device") or {}
+        share = set(rows.values())
+        if len(rows) != mesh[0] * mesh[1] or len(share) != 1:
+            bad.append(f"corpus rows per device are {rows}, not one "
+                       f"equal share on each of {mesh[0] * mesh[1]}")
+        else:
+            s = share.pop()
+            if not (s * mesh[0] >= num_data and s < num_data):
+                bad.append(f"each device holds {s} rows: not a 1/"
+                           f"{mesh[0]} share of {num_data}")
+    return bad
+
+
+def stamp_line(stamp: Dict[str, Any], cache: Dict[str, Any]) -> str:
+    v = stamp.get("kernel_variant") or {}
+    hit = ("off" if not cache.get("dir") else
+           f"{cache.get('hits')} hit(s) / {cache.get('misses')} written "
+           f"of {cache.get('requests')} in {cache.get('dir')}")
+    rows = stamp.get("corpus_rows_per_device")
+    return ((f"    corpus rows per device: {rows}\n" if rows else "")
+            + f"    device: {stamp.get('platform')} "
+            f"{stamp.get('device_kind')!r} x{stamp.get('device_count')} "
+            f"mesh={stamp.get('mesh')} select={stamp.get('select')} "
+            f"impl={stamp.get('extract_impl')} "
+            f"interpret={stamp.get('pallas_interpret')} "
+            f"rung={stamp.get('degrade_rung')} "
+            f"variant=tq{v.get('tile_q')}/ne{v.get('ne')}/kc{v.get('kc')} "
+            f"repairs={stamp.get('repairs')}\n"
+            f"    compile (smoke timing): backend "
+            f"{cache.get('backend_compile_ms')} ms; cache {hit}")
+
+
+# -- phases --------------------------------------------------------------------
+
+class Config:
+    """One bench config: generator arguments (dmlp_tpu.bench.configs)
+    and the pinned input hash + captured reference output."""
+
+    def __init__(self, config_id: int):
+        from dmlp_tpu.bench.configs import BENCH_CONFIGS
+        with open(os.path.join(REPO, "oracle_capture",
+                               "ORACLE_GOLDEN.json")) as f:
+            golden = json.load(f)["configs"][str(config_id)]
+        self.id = config_id
+        self.cfg = BENCH_CONFIGS[config_id]
+        self.input_sha256 = golden["input_sha256"]
+        self.input_path = os.path.join(WORK, self.cfg.input_name)
+        with open(os.path.join(REPO, "oracle_capture",
+                               golden["out_file"])) as f:
+            self.oracle_lines = f.read().splitlines(keepends=True)
+
+    def log(self, name: str) -> str:
+        return os.path.join(LOGS, f"config{self.id}_{name}")
+
+
+def phase_generate(c: Config) -> List[str]:
+    g = c.cfg
+    rc, wall = run_child(
+        ["-m", "dmlp_tpu.io.datagen", "--num_data", str(g.num_data),
+         "--num_queries", str(g.num_queries), "--num_attrs",
+         str(g.num_attrs), "--min", str(g.min_attr), "--max",
+         str(g.max_attr), "--minK", str(g.min_k), "--maxK", str(g.max_k),
+         "--num_labels", str(g.num_labels), "--seed", str(g.seed),
+         "--output", c.input_path],
+        None, c.log("generate.out"), c.log("generate.err"))
+    if rc != 0:
+        return [f"datagen exited {rc}: {tail(c.log('generate.err'), 3)}"]
+    h = hashlib.sha256()
+    with open(c.input_path, "rb") as f:
+        for block in iter(lambda: f.read(1 << 22), b""):
+            h.update(block)
+    say(f"  generate: {c.cfg.input_name} {g.num_data} x {g.num_queries} "
+        f"x {g.num_attrs}, k in [{g.min_k}, {g.max_k}]; wall {wall:.1f} s")
+    if h.hexdigest() != c.input_sha256:
+        return [f"input sha256 is {h.hexdigest()}, ORACLE_GOLDEN.json "
+                f"pins {c.input_sha256}"]
+    return []
+
+
+def phase_solve(c: Config, name: str, mode_args: List[str],
+                mesh: Optional[List[int]], ladder: bool
+                ) -> Tuple[List[str], Optional[Dict[str, Any]]]:
+    """A batch solve through ``python -m dmlp_tpu``; (misses, stamp)."""
+    metrics = c.log(f"{name}.metrics.jsonl")
+    if os.path.exists(metrics):
+        os.remove(metrics)
+    rc, wall = run_child(
+        ["-m", "dmlp_tpu", "--pallas", "--warmup", "--phase-times",
+         "--metrics", metrics] + mode_args,
+        c.input_path, c.log(f"{name}.out"), c.log(f"{name}.err"))
+    if rc != 0:
+        return [f"engine exited {rc}:\n{tail(c.log(name + '.err'))}"], None
+    err_lines = [ln.strip() for ln in tail(c.log(f"{name}.err"), 12)
+                 .splitlines() if ln.startswith(("Time taken", "phase "))]
+    with open(metrics) as f:
+        summary = [json.loads(ln) for ln in f if '"summary"' in ln][-1]
+    stamp = summary.get("device")
+    say(f"  {name}: wall {wall:.1f} s (smoke timing); child: "
+        + "; ".join(err_lines) + f"; parser {summary.get('parser')}")
+    if isinstance(stamp, dict):
+        say(stamp_line(stamp, summary.get("compile_cache") or {}))
+    bad = []
+    with open(c.log(f"{name}.out")) as f:
+        if f.read().splitlines(keepends=True) != c.oracle_lines:
+            bad.append("stdout differs from the captured reference "
+                       "output")
+    bad += check_stamp(stamp, mesh, ladder, c.cfg.num_data)
+    return bad, stamp if isinstance(stamp, dict) else None
+
+
+def read_queries(c: Config, count: int) -> Tuple[List[int], List[list]]:
+    """The first ``count`` queries of the input's query section, with
+    their own k — straight from the text the batch child parsed."""
+    ks, rows = [], []
+    with open(c.input_path) as f:
+        f.readline()
+        for _ in range(c.cfg.num_data):
+            f.readline()
+        for _ in range(count):
+            parts = f.readline().split()
+            ks.append(int(parts[1]))
+            rows.append([float(v) for v in parts[2:]])
+    return ks, rows
+
+
+def phase_serve(c: Config) -> List[str]:
+    """Daemon up, a few query requests in input order, one stats, SIGTERM
+    drain; answers compared with the matching oracle lines."""
+    from dmlp_tpu.serve import client as sc  # imports jax, touches no device
+    nreq = min(SERVE_REQUESTS,
+               max(c.cfg.num_queries // SERVE_REQUEST_QUERIES, 1))
+    per = min(SERVE_REQUEST_QUERIES, c.cfg.num_queries)
+    ready_path, errlog = c.log("serve.ready.json"), c.log("serve.err")
+    if os.path.exists(ready_path):
+        os.remove(ready_path)
+    t0 = time.monotonic()
+    with open(errlog, "wb") as err:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "dmlp_tpu.serve", "--corpus",
+             c.input_path, "--pallas", "--ready-file", ready_path,
+             "--warm-buckets", f"{per}x{c.cfg.max_k}"],
+            stdout=subprocess.DEVNULL, stderr=err, env=child_env(),
+            cwd=REPO)
+    bad: List[str] = []
+    try:
+        ready = sc.await_ready(proc, ready_path,
+                               timeout_s=CHILD_TIMEOUT_S, errlog=errlog)
+        t_ready = time.monotonic()
+        ks, rows = read_queries(c, nreq * per)
+        cli = sc.ServeClient(ready["port"], timeout_s=CHILD_TIMEOUT_S)
+        try:
+            resps = [cli.query(rows[i * per:(i + 1) * per],
+                               ks=ks[i * per:(i + 1) * per],
+                               req_id=f"smoke{i}") for i in range(nreq)]
+            stats = cli.stats()["stats"]
+        finally:
+            cli.close()
+        t_served = time.monotonic()
+        for r in resps:
+            if not r.get("ok"):
+                bad.append(f"request {r.get('id')} failed: "
+                           f"{r.get('error')}")
+        if not bad and sc.contract_text(
+                [r["checksums"] for r in resps]) \
+                != "".join(c.oracle_lines[:nreq * per]):
+            bad.append("served checksums differ from the captured "
+                       "reference output")
+        sc.sigterm_drain(proc, timeout_s=120, errlog=errlog)
+        if "drained clean" not in tail(errlog, 5):
+            bad.append("daemon exited 0 without 'drained clean'")
+        say(f"  serve: {nreq} requests x {per} queries; wall to ready "
+            f"{t_ready - t0:.1f} s (warm-up "
+            f"{ready.get('cold_start_compile_ms')} ms), requests + stats "
+            f"{t_served - t_ready:.2f} s, drain "
+            f"{time.monotonic() - t_served:.1f} s (smoke timings); "
+            f"parser {ready.get('parser')}")
+        say(stamp_line(stats.get("device") or {},
+                       stats.get("compile_cache") or {}))
+        for where, doc in (("ready file", ready), ("stats reply", stats)):
+            bad += [f"{where}: {m}" for m in check_stamp(
+                doc.get("device"), None, True, c.cfg.num_data)]
+        # every bucket the daemon built, warmed or served
+        bad += [f"bucket {k} took path {p}" for k, p in sorted(
+            stats["engine"]["paths"].items()) if p != "extract"]
+    except (RuntimeError, OSError, KeyError, ValueError) as e:
+        bad.append(f"{type(e).__name__}: {e}\n{tail(errlog)}")
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    return bad
+
+
+def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
+    """All phases of one config; (misses naming phase and check, the
+    batch child's stamp)."""
+    c = Config(config_id)
+    say(f"config {config_id}:")
+    misses = [f"config {config_id} generate: {m}"
+              for m in phase_generate(c)]
+    if misses:
+        return misses, None
+    bad, stamp = phase_solve(c, "batch", [], None, ladder=True)
+    misses += [f"config {config_id} batch: {m}" for m in bad]
+    if stamp is None:
+        return misses, None
+    misses += [f"config {config_id} serve: {m}" for m in phase_serve(c)]
+    from dmlp_tpu.config import EngineConfig
+    chips = stamp.get("device_count", 0)
+    shard = -(-c.cfg.num_data // 4)
+    if chips < 4:
+        say(f"  sharded, ring at --mesh 4,1: not run: {chips} chip(s)")
+    elif shard <= EngineConfig.AUTO_SELECT_THRESHOLD:
+        say(f"  sharded, ring at --mesh 4,1: not run: a {shard}-row "
+            "shard is below the size at which the engine selects the "
+            "kernel")
+    else:
+        for mode in ("sharded", "ring"):
+            bad, _ = phase_solve(c, mode, ["--mode", mode, "--mesh", "4,1"],
+                                 [4, 1], ladder=False)
+            misses += [f"config {config_id} {mode} 4,1: {m}" for m in bad]
+    return misses, stamp
+
+
+def parent_backend_state() -> Tuple[bool, str]:
+    """(clean, what to say): this process must not have initialised a
+    JAX backend — it would hold the chip its children need."""
+    if "jax" not in sys.modules:
+        return True, "jax never imported"
+    from jax._src import xla_bridge  # read-only; jax has no public query
+    if xla_bridge.backends_are_initialized():
+        return False, "a JAX backend was initialised"
+    return True, "jax imported (by dmlp_tpu.serve.client), no backend " \
+        "initialised"
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--configs", default="1,4",
+                    help="bench configs to run, in order (default 1,4: "
+                         "the same path small, then at full width)")
+    args = ap.parse_args(argv)
+    if not os.path.isdir(os.path.join(REPO, "dmlp_tpu")):
+        print(f"chip_smoke: no dmlp_tpu package beside {__file__}",
+              file=sys.stderr)
+        return 2
+    sys.path.insert(0, REPO)
+    os.makedirs(WORK, exist_ok=True)
+    os.makedirs(LOGS, exist_ok=True)
+    t0 = time.monotonic()
+    misses: List[str] = []
+    stamp = None
+    for config_id in (int(v) for v in args.configs.split(",")):
+        misses, stamp = run_config(config_id)
+        if misses:
+            break   # no wider config on a machine that failed this one
+    clean, state = parent_backend_state()
+    say(f"parent: {state}; total wall {time.monotonic() - t0:.1f} s")
+    if not clean:
+        misses.append(f"parent: {state}")
+    if misses:
+        say("chip_smoke: FAILED")
+        for m in misses:
+            say(f"  FAIL {m}")
+        return 1
+    say("chip_smoke: every phase passed")
+    print(json.dumps({"ok": True, "device": {
+        "platform": stamp["platform"], "kind": stamp["device_kind"],
+        "count": stamp["device_count"]}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

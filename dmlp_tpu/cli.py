@@ -24,7 +24,6 @@ Usage::
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import sys
 from typing import IO, Optional, Sequence
 
@@ -49,15 +48,11 @@ def parse_mesh_arg(parser, value):
     return (r, c)
 
 
-def make_engine(config: EngineConfig, stderr=None):
-    """Engine registry (lazy imports keep CLI start light).
-
-    An explicit mesh_shape that needs more devices than this host has
-    falls back to the auto-factorized mesh with a stderr warning — bench
-    configs carry mesh hints sized for their target topology (the
-    run_bench.sh task-count analog), and a portable harness must still run
-    (degraded, visibly) on smaller hosts.
-    """
+def make_engine(config: EngineConfig):
+    """Engine registry (lazy imports keep CLI start light). An explicit
+    mesh_shape that needs more devices than this host has is an error
+    (parallel.mesh.make_mesh raises): a run on another mesh than the one
+    asked for is not the run that was asked for."""
     if config.mode == "single":
         from dmlp_tpu.engine.single import SingleChipEngine
         return SingleChipEngine(config)
@@ -68,15 +63,6 @@ def make_engine(config: EngineConfig, stderr=None):
             from dmlp_tpu.engine.auto import AutoShardedEngine as cls
         else:
             from dmlp_tpu.engine.ring import RingEngine as cls
-        if config.mesh_shape is not None:
-            import jax
-            r, c = config.mesh_shape
-            if r * c > len(jax.devices()):
-                import sys as _sys
-                (stderr or _sys.stderr).write(
-                    f"warning: mesh {r},{c} needs {r * c} devices, have "
-                    f"{len(jax.devices())}; using auto mesh\n")
-                config = dataclasses.replace(config, mesh_shape=None)
         return cls(config)
     raise ValueError(f"unknown mode {config.mode!r}")
 
@@ -86,13 +72,17 @@ def _emit_metrics(path: str, args, inp, timer: EngineTimer, phase_ms: dict,
                   extract_impl: Optional[str] = None,
                   mem_model: Optional[dict] = None,
                   prune: Optional[dict] = None,
-                  precision: Optional[dict] = None) -> None:
+                  precision: Optional[dict] = None,
+                  engine=None) -> None:
     """Append per-phase records + one run summary to the metrics JSONL.
 
     The summary is the contract record: it always carries a ``counters``
     block — either cost-analysis flops/bytes or the explicit
-    ``counters_unavailable`` marker — never silence."""
-    from dmlp_tpu.obs.run import SCHEMA_VERSION
+    ``counters_unavailable`` marker — never silence. A device solve also
+    says where it ran (``device``, obs.run.device_stamp) and what the
+    persistent compile cache did (``compile_cache``)."""
+    from dmlp_tpu.obs.run import SCHEMA_VERSION, device_stamp
+    from dmlp_tpu.utils import compile_cache
     from dmlp_tpu.utils.metrics_log import MetricsLogger
 
     with MetricsLogger(path=path) as mlog:
@@ -106,9 +96,13 @@ def _emit_metrics(path: str, args, inp, timer: EngineTimer, phase_ms: dict,
             "num_data": inp.params.num_data,
             "num_queries": inp.params.num_queries,
             "num_attrs": inp.params.num_attrs,
+            "parser": inp.parser,
             "counters": counters if counters is not None
             else {"counters_unavailable": True},
         }
+        if engine is not None:
+            summary["device"] = device_stamp(engine)
+            summary["compile_cache"] = compile_cache.stats()
         if comms is not None:
             summary["comms"] = comms
         if extract_impl is not None:
@@ -240,10 +234,10 @@ def main(argv: Optional[Sequence[str]] = None,
                              "timed region excludes XLA compilation (the "
                              "reference engine pays no JIT)")
     parser.add_argument("--compile-cache", metavar="DIR", default=None,
-                        help="persistent XLA compilation cache dir (best "
-                             "effort; later runs reuse on-disk "
-                             "executables); $DMLP_TPU_COMPILE_CACHE is "
-                             "the ambient form (flag wins)")
+                        help="persistent XLA compilation cache dir; "
+                             "default <checkout>/.jax_cache, and "
+                             "$JAX_COMPILATION_CACHE_DIR, when set, "
+                             "wins over both (utils.compile_cache)")
     parser.add_argument("--sanitize", action="store_true",
                         help="wrap the solve in "
                              "jax.transfer_guard('disallow') + "
@@ -325,8 +319,6 @@ def main(argv: Optional[Sequence[str]] = None,
 
 def _run_cli(parser, args, stdin, stdout, stderr, tracer, probe) -> int:
     mesh_shape = parse_mesh_arg(parser, args.mesh)
-    from dmlp_tpu.utils.compile_cache import enable_from_flag
-    enable_from_flag(args.compile_cache)  # before any compile
     if args.engine == "auto":
         # --engine auto == the jax engine with the compiler-sharded
         # mode; keep the summary-record fields consistent.
@@ -351,7 +343,9 @@ def _run_cli(parser, args, stdin, stdout, stderr, tracer, probe) -> int:
         with obs_span("cli.solve", engine="golden"):
             results = knn_golden(inp)
     else:
-        engine = make_engine(config, stderr=stderr)
+        from dmlp_tpu.utils.compile_cache import enable_compile_cache
+        enable_compile_cache(args.compile_cache)  # before any compile
+        engine = make_engine(config)
         solve = engine.run_device_full if args.device_full else engine.run
         if args.warmup:
             with timer.phase("warmup_compile"), \
@@ -435,7 +429,8 @@ def _run_cli(parser, args, stdin, stdout, stderr, tracer, probe) -> int:
                           if engine is not None else None,
                           precision=getattr(engine, "last_precision",
                                             None)
-                          if engine is not None else None)
+                          if engine is not None else None,
+                          engine=engine)
         if args.counters:
             _emit_counters_stderr(counters, timer.elapsed_ms, stderr)
         if args.hlo_report and probe is not None:

@@ -39,10 +39,9 @@ class EngineConfig:
         "bfloat16"). The reference computes in float64 (engine.cpp:12);
         TPU MXU is f32/bf16, so strict-parity runs add host rescoring
         (``exact``). "auto" resolves to bfloat16 on TPU backends in exact
-        mode — staging in bf16 halves the host->device bytes that bound
-        the end-to-end solve on a transfer-limited link (measured 2.3x at
-        200k x 10k, BENCH_BF16_r04.json) while the f64 rescore + the
-        tie-overflow repair keep results identical — and to float32
+        mode — staging in bf16 halves the host->device bytes while the
+        f64 rescore + the tie-overflow repair keep results identical
+        (speed on the chip: not measured) — and to float32
         everywhere else (CPU bf16 is emulated and slower; fast mode's
         output IS the device ordering, so it never changes dtype
         implicitly).
@@ -131,11 +130,8 @@ class EngineConfig:
         if not self.exact:
             return "float32"
         import jax
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:
-            return "float32"
-        return "bfloat16" if platform == "tpu" else "float32"
+        return ("bfloat16" if jax.devices()[0].platform == "tpu"
+                else "float32")
 
     def resolve_precision(self) -> str:
         """Concrete first-pass precision ("f32" | "bf16") for this run,

@@ -295,7 +295,7 @@ class AutoShardedEngine(ShardedEngine):
         # real one from the compiled program (module docstring)
         self._last_dispatch = None
         self._pending_iters = []
-        self.last_extract_impl = None
+        self.last_extract_impl = self.last_variant = None
         self.last_prune = None
 
     def comms_from_hlo(self):

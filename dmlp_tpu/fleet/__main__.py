@@ -259,7 +259,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if supervisor is not None:
         child_rcs = supervisor.wait_children()
     if args.record:
-        from dmlp_tpu.obs.run import RunRecord, current_device
+        from dmlp_tpu.fleet.loadgen import served_device
+        from dmlp_tpu.obs.run import RunRecord
         stats = router.stats()
         metrics = {
             "healthy_replicas": stats["healthy_replicas"],
@@ -282,7 +283,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                           "supervised": supervised,
                           "mode": "closed_loop"},
                   metrics=metrics,
-                  device=current_device()).append_jsonl(args.record)
+                  device=served_device(stats)).append_jsonl(args.record)
     racecheck.write_report_if_requested()
     bad = [c for c in child_rcs if c["rc"] != 0]
     if supervisor is not None:

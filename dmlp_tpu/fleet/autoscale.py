@@ -80,8 +80,9 @@ class ReplicaSpec:
         # template: relaunches, scale-ups, and capacity re-splits all
         # reuse the first generation's executables (their bucket shapes
         # are identical by construction), so the fleet's cold-start
-        # compile time pays once. $DMLP_TPU_COMPILE_CACHE is the
-        # ambient form (inherited env) when no explicit dir is given.
+        # compile time pays once. With no explicit dir the replicas use
+        # utils.compile_cache's default; $JAX_COMPILATION_CACHE_DIR in
+        # the inherited environment wins over both.
         self.compile_cache = (os.path.abspath(compile_cache)
                               if compile_cache else None)
 

@@ -36,7 +36,10 @@ class FleetProc:
 def _repo_env(extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    # The platform is the caller's: children inherit JAX_PLATFORMS (the
+    # test suite pins cpu in the environment it hands down), so on a
+    # chip host the replicas reach the chip.
+    env = dict(os.environ,
                PYTHONPATH=repo + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     env.update(extra or {})
@@ -64,9 +67,6 @@ def spawn_replica(corpus_path: str, out_dir: str, name: str,
     if record:
         cmd += ["--record", record]
     if compile_cache:
-        # First-class form; $DMLP_TPU_COMPILE_CACHE also rides the
-        # inherited environment (_repo_env copies os.environ), so a
-        # harness can warm a whole replica tree either way.
         cmd += ["--compile-cache", compile_cache]
     with open(errlog, "w") as ef:
         proc = subprocess.Popen(cmd, stderr=ef,

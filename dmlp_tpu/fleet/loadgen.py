@@ -21,8 +21,19 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from dmlp_tpu.obs.run import RunRecord, current_device
+from dmlp_tpu.obs.run import RunRecord, stamp_device_kind
 from dmlp_tpu.serve import client as sc
+
+
+def served_device(stats: Dict[str, Any]) -> Optional[str]:
+    """The device kind behind a front-end's ``stats`` reply: a daemon's
+    own device stamp, or — through a router — the first replica's as
+    last probed. The load generator holds no device and never asks jax
+    for one; it records what the serving processes stamped."""
+    stamps = [stats.get("device")] + [
+        r.get("device") for r in stats.get("replicas", [])
+        if isinstance(r, dict)]
+    return next((k for k in map(stamp_device_kind, stamps) if k), None)
 
 
 def offered_qps(requests: List[Dict[str, Any]],
@@ -114,7 +125,6 @@ def run_levels(port: int, header: Dict[str, Any],
     production). Each record's config pins the level tag the ledger
     keys the series by (``fleet/x2/p99_ms``), the offered qps, and the
     fleet topology."""
-    device = current_device()
     out: List[RunRecord] = []
     for speed in sorted(speeds):
         metrics = run_level(port, header, requests, speed, reps=reps)
@@ -127,7 +137,16 @@ def run_levels(port: int, header: Dict[str, Any],
                     "replicas": replicas, "trace": trace,
                     "mode": "open_loop",
                     "requests_per_rep": len(requests), "reps": reps},
-            metrics=metrics, device=device))
+            metrics=metrics))
+    # After the load: a router only knows its replicas' stamps once its
+    # prober has reached them.
+    cli = sc.ServeClient(port)
+    try:
+        device = served_device(cli.stats().get("stats", {}))
+    finally:
+        cli.close()
+    for rec in out:
+        rec.device = device
     return out
 
 
@@ -139,6 +158,7 @@ def _fleet_slo_stats(port: int) -> Dict[str, Any]:
     st = sc.ServeClient(port).stats().get("stats", {})
     out: Dict[str, Any] = {
         "replicas": st.get("healthy_replicas"),
+        "device": served_device(st),
         "objectives": {},
     }
     for name, o in (st.get("slo") or {}).get("objectives", {}).items():
@@ -237,12 +257,14 @@ def ramp_record(arm: str, objective: str,
     rejected = sum(int(s["metrics"].get("rejected", 0)) for s in steps)
     metrics["errors"] = errors
     metrics["rejected"] = rejected
+    device = next((s["slo"]["device"] for s in reversed(steps)
+                   if (s.get("slo") or {}).get("device")), None)
     return RunRecord(
         kind="slo", tool=tool,
         config={"arm": arm, "objective": objective, "mode": "ramp",
                 "levels": [s["level"] for s in steps],
                 "replicas": replicas, "trace": trace},
-        metrics=metrics, device=current_device())
+        metrics=metrics, device=device)
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

@@ -113,17 +113,10 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             raise ValueError(f"unknown merge strategy {merge!r}")
         cfg = config or EngineConfig(mode="sharded")
         if mesh is None:
-            shape = mesh_shape or cfg.mesh_shape
-            if shape is not None:
-                # An explicit shape needs only shape-many devices — a
-                # replica pinned to 2 of a host's 8 virtual devices is
-                # the normal fleet deployment, not an error.
-                import jax as _jax
-                r0, c0 = shape
-                mesh = make_mesh(shape,
-                                 devices=_jax.devices()[:r0 * c0])
-            else:
-                mesh = make_mesh(None)
+            # An explicit shape takes the first shape-many devices — a
+            # replica pinned to 2 of a host's 8 virtual devices is the
+            # normal fleet deployment, not an error.
+            mesh = make_mesh(mesh_shape or cfg.mesh_shape)
         super().__init__(cfg, mesh)
         self._merge_strategy = merge
         r, c = self.mesh.devices.shape
@@ -166,8 +159,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         self._chunk_rows = chunk_rows       # per-shard rows per chunk
         self.capacity_rows = r * shard_rows
         if self._extract_ok:
-            from dmlp_tpu.ops.pallas_distance import native_pallas_backend
-            self._interpret = not native_pallas_backend()
+            from dmlp_tpu.ops.pallas_distance import pallas_interpret
+            self._interpret = pallas_interpret()
         else:
             self._interpret = True
 
@@ -220,6 +213,12 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         reg.gauge("serve.corpus_rows").set(n)
         reg.gauge("serve.capacity_rows").set(self.capacity_rows)
         reg.gauge("serve.mesh_shards").set(r)
+
+    def corpus_rows_per_device(self) -> Dict[str, int]:
+        """Resident corpus rows each device holds (the device stamp)."""
+        from dmlp_tpu.obs.run import rows_per_device
+        return rows_per_device(self._chunks if self._chunks is not None
+                               else [self._mono[0]])
 
     # -- resident staging -----------------------------------------------------
 
@@ -581,7 +580,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         self.last_phase_ms = {}
         self.last_comms = []
         self._pending_iters = []
-        self.last_extract_impl = None
+        self.last_extract_impl = self.last_variant = None
         self.last_prune = None
         self.last_prune_fraction = None
         self._pending_gate = None

@@ -98,6 +98,9 @@ class Replica:
         #: engine capacity — the consistency/re-shard inputs
         self.last_corpus: Optional[Dict[str, int]] = None
         self.capacity_rows: Optional[int] = None
+        #: the replica's own device stamp (obs.run.device_stamp) as last
+        #: probed — the router holds no device and records this instead
+        self.last_device: Optional[Dict[str, Any]] = None
 
     # -- guarded state ---------------------------------------------------------
 
@@ -112,7 +115,8 @@ class Replica:
                     "last_error": self._last_error,
                     "corpus": dict(self.last_corpus)
                     if self.last_corpus else None,
-                    "capacity_rows": self.capacity_rows}
+                    "capacity_rows": self.capacity_rows,
+                    "device": self.last_device}
 
     def mark(self, healthy: Optional[bool] = None,
              draining: Optional[bool] = None,
@@ -137,7 +141,8 @@ class Replica:
 
     def probe_ok(self, draining: bool = False,
                  corpus: Optional[Dict[str, int]] = None,
-                 capacity_rows: Optional[int] = None) -> None:
+                 capacity_rows: Optional[int] = None,
+                 device: Optional[Dict[str, Any]] = None) -> None:
         """One healthy probe. A marked-down replica needs
         ``revive_probes`` CONSECUTIVE healthy probes before it routes
         again — a flapping replica must not rejoin on its first good
@@ -151,6 +156,8 @@ class Replica:
                 self.last_corpus = corpus
             if capacity_rows is not None:
                 self.capacity_rows = int(capacity_rows)
+            if device is not None:
+                self.last_device = device
             if self._quarantined:
                 return
             if not self._healthy:
@@ -518,11 +525,13 @@ class FleetRouter:
             draining = bool(st.get("admission", {}).get("draining"))
             corpus = st.get("corpus")
             cap = st.get("engine", {}).get("capacity_rows")
+            dev = st.get("device")
             rep.probe_ok(draining=draining,
                          corpus=corpus if isinstance(corpus, dict)
                          else None,
                          capacity_rows=cap if isinstance(cap, int)
-                         else None)
+                         else None,
+                         device=dev if isinstance(dev, dict) else None)
         except (OSError, ValueError) as e:
             rep.probe_fail(f"probe: {e}")
 

@@ -101,7 +101,7 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
         else:
             ok = np.ones(q1 - q0, bool)
 
-        # Batched finalize over the whole query block (VERDICT r3 item 6:
+        # Batched finalize over the whole query block (round-3 review item 6:
         # the per-query Python finalize loop dominated oracle time at
         # benchmark scale — 182 s on harness config 4). finalize_host is
         # the engines' own vectorized implementation of the identical

@@ -84,6 +84,10 @@ class KNNInput:
     data_attrs: np.ndarray    # (num_data, num_attrs)  float64
     ks: np.ndarray            # (num_queries,)  int32
     query_attrs: np.ndarray   # (num_queries, num_attrs)  float64
+    # Which parser produced it ("python" | "native"): large inputs fall
+    # back from the C++ tokenizer to the Python one silently when the
+    # on-demand g++ build fails, so the run records say which ran.
+    parser: str = "python"
 
     @property
     def data_ids(self) -> np.ndarray:

@@ -119,7 +119,8 @@ def parse_input_text_native(text) -> KNNInput:
                              query_attrs.reshape(-1), errbuf, len(errbuf))
     if rc != 0:
         raise _located_error(errbuf.value.decode("ascii"), rc)
-    return KNNInput(Params(nd, nq, na), labels, data_attrs, ks, query_attrs)
+    return KNNInput(Params(nd, nq, na), labels, data_attrs, ks, query_attrs,
+                    parser="native")
 
 
 def _located_error(msg: str, rc: int) -> ParseError:

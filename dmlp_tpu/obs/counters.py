@@ -245,8 +245,9 @@ def roofline(flops: float, bytes_accessed: float, elapsed_s: float,
 
     Peak comes from the training side's per-chip table
     (train.metrics.peak_flops_per_chip), so 'utilization_vs_peak' is
-    directly comparable to the train loop's MFU. Conservative fallback
-    peak on unknown hardware, same as there."""
+    directly comparable to the train loop's MFU. On a device kind the
+    table does not know, the achieved rates stand alone: no peak, no
+    utilisation."""
     out = {"flops": flops, "bytes_accessed": bytes_accessed,
            "elapsed_s": elapsed_s}
     if elapsed_s > 0:
@@ -254,14 +255,15 @@ def roofline(flops: float, bytes_accessed: float, elapsed_s: float,
         out["achieved_bytes_per_s"] = bytes_accessed / elapsed_s
     if bytes_accessed > 0:
         out["arithmetic_intensity"] = flops / bytes_accessed
+    from dmlp_tpu.train.metrics import (UnknownDeviceKind,
+                                        peak_flops_per_chip)
     try:
-        from dmlp_tpu.train.metrics import peak_flops_per_chip
         peak = peak_flops_per_chip()
-        out["peak_flops_per_chip"] = peak
-        if elapsed_s > 0 and peak > 0:
-            out["utilization_vs_peak"] = flops / (elapsed_s * n_chips * peak)
-    except Exception:
-        pass  # no backend / no devices: the static counters still stand
+    except UnknownDeviceKind:
+        return out
+    out["peak_flops_per_chip"] = peak
+    if elapsed_s > 0:
+        out["utilization_vs_peak"] = flops / (elapsed_s * n_chips * peak)
     return out
 
 

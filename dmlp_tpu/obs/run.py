@@ -38,22 +38,79 @@ SCHEMA_VERSION = 2
 
 def round_from_name(path: str) -> Optional[int]:
     """The measurement round encoded in an artifact filename (the
-    ``_rNN`` convention: BENCH_r05.json -> 5), or None."""
+    ``_rNN`` convention: BENCH_r08.jsonl -> 8), or None."""
     m = re.search(r"_r(\d+)", os.path.basename(path))
     return int(m.group(1)) if m else None
 
 
-def current_device() -> Optional[str]:
-    """Best-effort device kind for the envelope ``device`` field:
-    the first device's ``device_kind`` (falls back to platform name).
-    Touches ``jax.devices()`` — callers that must not initialize a
-    backend should pass ``device`` explicitly instead."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        return str(getattr(dev, "device_kind", None) or dev.platform)
-    except Exception:
-        return None
+def current_device() -> str:
+    """Device kind for the envelope ``device`` field, as jax reports it.
+    Touches ``jax.devices()``, so it belongs to the process that solves:
+    a chip serves one process at a time, and a parent that asked would
+    hold the chip its children need. Parents record what the child
+    wrote (:func:`device_stamp`, :func:`stamp_device_kind`)."""
+    import jax
+    return str(jax.devices()[0].device_kind)
+
+
+def device_stamp(engine=None) -> Dict[str, Any]:
+    """Where this process ran and which path its last solve took — the
+    one block the CLI ``--metrics`` summary, the daemon's ready file and
+    its ``stats`` reply all carry, so that a parent that never touches
+    jax (chip_smoke.py, the fleet launchers) can check it. Only the
+    solving process may call this (see :func:`current_device`)."""
+    import jax
+
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
+    from dmlp_tpu.resilience import stats as rs_stats
+    from dmlp_tpu.train.metrics import peak_flops_for_kind
+    devs = jax.devices()
+    res = rs_stats.snapshot()
+    kind = str(devs[0].device_kind)
+    stamp: Dict[str, Any] = {
+        "platform": devs[0].platform,
+        "device_kind": kind,
+        "device_count": len(devs),
+        # a utilisation can be computed for this kind (the peaks table
+        # knows it); on any other kind only rates are reported
+        "peak_flops_known": peak_flops_for_kind(kind) is not None,
+        "pallas_interpret": pallas_interpret(),
+        "degradations": res["degradations"],
+        "retries": res["retries"],
+    }
+    if engine is not None:
+        mesh = getattr(engine, "mesh", None)
+        stamp.update(
+            mesh=list(mesh.devices.shape) if mesh is not None else None,
+            select=getattr(engine, "_last_select", None),
+            extract_impl=getattr(engine, "last_extract_impl", None),
+            # None where the engine has no ladder (the mesh engines)
+            degrade_rung=getattr(engine, "last_degrade_rung", None),
+            kernel_variant=getattr(engine, "last_variant", None),
+            repairs=getattr(engine, "last_repairs", None))
+        rows = getattr(engine, "corpus_rows_per_device", None)
+        if rows is not None:
+            stamp["corpus_rows_per_device"] = rows()
+    return stamp
+
+
+def rows_per_device(arrays, into: Optional[Dict[str, int]] = None
+                    ) -> Dict[str, int]:
+    """Rows of ``arrays`` (data-sharded device arrays) each device
+    holds, summed by device id — what shows that a mesh solve spread
+    the corpus instead of staging it all on the first device."""
+    rows = {} if into is None else into
+    for arr in arrays:
+        for shard in arr.addressable_shards:
+            key = str(shard.device.id)
+            rows[key] = rows.get(key, 0) + int(shard.data.shape[0])
+    return rows
+
+
+def stamp_device_kind(stamp: Optional[Dict[str, Any]]) -> Optional[str]:
+    """The envelope ``device`` value out of a child's device stamp
+    (None when the child wrote none)."""
+    return stamp.get("device_kind") if isinstance(stamp, dict) else None
 
 
 def _host_context() -> Dict[str, Any]:
@@ -62,8 +119,8 @@ def _host_context() -> Dict[str, Any]:
         import jax
         ctx["jax"] = jax.__version__
         # Touching jax.devices() would initialize a backend as a side
-        # effect (and can dial a remote TPU); record only what is free.
-    except Exception:
+        # effect (and claim the chip); record only what is free.
+    except ImportError:
         pass
     return ctx
 

@@ -105,6 +105,20 @@ def variant_for(impl: str, kc: int, b: int, qb: int | None = None,
     return _rv(kc, b, qb, a, precision)
 
 
+def variant_stamp(impl: str, kc: int, b: int, qb: int, a: int,
+                  precision: str = "f32") -> dict:
+    """:func:`variant_for` plus where the variant came from — the
+    device stamp's ``kernel_variant`` (obs.run.device_stamp): the tiles
+    this dispatch runs with and whether a tune-cache FILE (state outside
+    the checkout) supplied them rather than the committed heuristic."""
+    from dmlp_tpu.tune import lookup_variant
+    v = variant_for(impl, kc, b, qb, a, precision)
+    cached = lookup_variant(
+        kc, b, a=a, precision=precision,
+        kernel=FUSED_KERNEL if impl == "fused" else "extract_topk")
+    return {**v, "kc": kc, "from_tune_cache": cached == v}
+
+
 def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
                carry_d: jax.Array | None = None,
                carry_i: jax.Array | None = None, *, n_real,

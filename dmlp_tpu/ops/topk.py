@@ -200,13 +200,13 @@ def make_block_step(select: str, k: int, use_pallas: bool = False,
         top-k by distance.
         """
         from dmlp_tpu.ops.pallas_distance import (fused_dist_segmin,
-                                                  native_pallas_backend,
+                                                  pallas_interpret,
                                                   supports)
         if use_pallas and supports(query_attrs.shape[0], battrs.shape[0],
                                    battrs.shape[1]):
             tile, segmin = fused_dist_segmin(
                 query_attrs, battrs, bids,
-                interpret=not native_pallas_backend())
+                interpret=pallas_interpret())
         else:
             tile = masked_pairwise_sq_l2(query_attrs, battrs, bids,
                                          accum_dtype)

@@ -286,8 +286,7 @@ def place_global_data(engine, parsed: dict):
     dsh2 = NamedSharding(mesh, P(DATA_AXIS, None))
     dsh1 = NamedSharding(mesh, P(DATA_AXIS))
     # Stage attrs in the engine's resolved dtype: each process converts
-    # its own shard on host, so bf16 halves the per-host feed bytes (the
-    # DCN-side analog of the single-chip staging win, BENCH_BF16_r04).
+    # its own shard on host, so bf16 halves the per-host feed bytes.
     np_dtype = engine._np_dtype()
     ga = build_global(dsh2, (npad, na),
                       parsed["p_attrs"].astype(np_dtype, copy=False),

@@ -39,14 +39,17 @@ def make_mesh(shape: Optional[Tuple[int, int]] = None,
     """Build the 2D ("data", "query") mesh.
 
     ``shape=None`` auto-factorizes over all available devices (or the
-    ``devices`` given). Explicit shapes must multiply to the device count
-    used.
+    ``devices`` given). An explicit R x C shape takes the first R*C of
+    them (``--mesh 2,1`` on a four-chip host); one that needs more
+    devices than there are is an error.
     """
     devices = list(devices if devices is not None else jax.devices())
     if shape is None:
         shape = balanced_dims(len(devices))
     r, c = shape
-    if r * c != len(devices):
-        raise ValueError(f"mesh shape {shape} != device count {len(devices)}")
+    if r * c > len(devices):
+        raise ValueError(f"mesh shape {shape} needs {r * c} devices, "
+                         f"have {len(devices)}")
     import numpy as np
-    return Mesh(np.asarray(devices).reshape(r, c), (DATA_AXIS, QUERY_AXIS))
+    return Mesh(np.asarray(devices[:r * c]).reshape(r, c),
+                (DATA_AXIS, QUERY_AXIS))

@@ -10,10 +10,11 @@ wrapped site shares:
 - **classification** (:func:`classify`): three-way. ``transient``
   (injected transients, connection/timeout errors, jax runtime errors
   carrying the UNAVAILABLE / DEADLINE_EXCEEDED / ABORTED markers) is
-  retried here; ``oom`` (simulated or real RESOURCE_EXHAUSTED) is NOT —
-  retrying the same allocation is futile, the degradation ladder
+  retried here; ``oom`` (simulated or real HBM RESOURCE_EXHAUSTED) is
+  NOT — retrying the same allocation is futile, the degradation ladder
   (resilience.degrade) owns that recovery; everything else is ``fatal``
-  and propagates immediately.
+  and propagates immediately — including a kernel that fails to
+  compile, whose VMEM overflow also reads RESOURCE_EXHAUSTED.
 - **deterministic jitter**: the backoff delay's jitter fraction is a
   hash of (policy seed, site, attempt) — full de-thundering across
   sites, bit-reproducible across runs (a chaos run's timing profile is
@@ -46,6 +47,14 @@ TRANSIENT_MARKERS = ("DEADLINE_EXCEEDED", "UNAVAILABLE", "ABORTED",
 #: substrings classified as out-of-memory (ladder recovery, not retry)
 OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Out of memory", "out of memory")
 
+#: substrings of a kernel COMPILE failure. A kernel that does not
+#: compile is a bug, not a capacity event: stepping down the ladder
+#: would end on the host oracle with exit 0 and no device work. The
+#: kernel running out of VMEM is a property of its tiling, decided at
+#: compile time, and says RESOURCE_EXHAUSTED too — so these are tested
+#: before OOM_MARKERS.
+KERNEL_COMPILE_MARKERS = ("Mosaic failed to compile", "vmem", "VMEM")
+
 
 def resilience_enabled() -> bool:
     """The layer-wide kill switch ($DMLP_TPU_RESILIENCE=0 disables) —
@@ -61,6 +70,9 @@ def classify(exc: BaseException) -> str:
                         TimeoutError, InterruptedError, OperationTimeout)):
         return "transient"
     msg = str(exc)
+    if type(exc).__name__ == "MosaicError" \
+            or any(m in msg for m in KERNEL_COMPILE_MARKERS):
+        return "fatal"
     if any(m in msg for m in OOM_MARKERS):
         return "oom"
     if any(m in msg for m in TRANSIENT_MARKERS):

@@ -101,10 +101,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "GSPMD partitioner — engine.auto's merge "
                         "point as the micro-batch epilogue)")
     p.add_argument("--compile-cache", metavar="DIR", default=None,
-                   help="persistent XLA compilation cache dir (best "
-                        "effort; restarts then reuse executables); "
-                        "$DMLP_TPU_COMPILE_CACHE is the ambient form "
-                        "(flag wins)")
+                   help="persistent XLA compilation cache dir; "
+                        "default <checkout>/.jax_cache, and "
+                        "$JAX_COMPILATION_CACHE_DIR, when set, "
+                        "wins over both (utils.compile_cache)")
     p.add_argument("--telemetry", metavar="FILE", default=None)
     p.add_argument("--telemetry-port", type=int, default=None,
                    metavar="PORT")
@@ -148,11 +148,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     from dmlp_tpu.io.grammar import parse_input
     from dmlp_tpu.resilience import inject as rs_inject
     from dmlp_tpu.serve.daemon import ServeDaemon
-    from dmlp_tpu.utils.compile_cache import enable_from_flag
+    from dmlp_tpu.utils.compile_cache import enable_compile_cache
 
-    # --compile-cache wins; $DMLP_TPU_COMPILE_CACHE is the ambient form
-    # (fleet harnesses warm a whole replica tree through the env).
-    enable_from_flag(args.compile_cache)
+    enable_compile_cache(args.compile_cache)
     budget = None
     if args.hbm_budget != "auto":
         budget = int(args.hbm_budget)

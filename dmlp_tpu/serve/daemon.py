@@ -243,9 +243,17 @@ class ServeDaemon:
         telemetry.registry().gauge("serve.ready").set(1)
 
     def write_ready_file(self, path: str) -> None:
+        from dmlp_tpu.obs.run import device_stamp
+        from dmlp_tpu.utils import compile_cache
         stats = self.engine.bucket_stats()
         doc = {
             "port": self.port, "pid": os.getpid(),
+            # where this daemon runs and which path its warm-up solves
+            # took — what a launcher that holds no device records
+            "device": device_stamp(self.engine),
+            "compile_cache": compile_cache.stats(),
+            "parser": self.corpus.parser,
+            "paths": stats["paths"],
             "cold_start_compile_ms": self.engine.cold_start_compile_ms,
             "compile_count": self.engine.compile_count,
             "buckets": stats["buckets"],
@@ -300,8 +308,12 @@ class ServeDaemon:
         elapsed = (time.monotonic() - self._t_ready) \
             if self._t_ready else 0.0
         done = reg.counter("serve.requests_completed").total()
+        from dmlp_tpu.obs.run import device_stamp
+        from dmlp_tpu.utils import compile_cache
         out = {
             "protocol": protocol.PROTOCOL_VERSION,
+            "device": device_stamp(eng),
+            "compile_cache": compile_cache.stats(),
             "engine": eng.bucket_stats(),
             # The fleet prober's consistency probe: rows + rolling
             # checksum + ingest epoch, comparable across replicas

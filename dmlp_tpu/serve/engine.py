@@ -347,8 +347,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             _, self._ex_nchunks, self._ex_chunk_rows = plan_chunks(
                 self.capacity_rows, eg, cfg.data_block)
             self._ex_rows = self._ex_nchunks * self._ex_chunk_rows
-            from dmlp_tpu.ops.pallas_distance import native_pallas_backend
-            self._interpret = not native_pallas_backend()
+            from dmlp_tpu.ops.pallas_distance import pallas_interpret
+            self._interpret = pallas_interpret()
         else:
             self._ex_nchunks = self._ex_chunk_rows = self._ex_rows = 0
             self._interpret = True
@@ -795,6 +795,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         throttle = ChunkThrottle()
         self._last_select = "extract"
         self.last_extract_impl = impl
+        self.last_variant = pallas_fused.variant_stamp(
+            impl, entry.kcap, cr, entry.qpad, na, prec)
         with obs_span("serve.solve_extract", qpad=entry.qpad,
                       kcap=entry.kcap, impl=impl,
                       carry=self.gate_carry, scheduled=len(order),
@@ -883,6 +885,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         q_dev = stage_put(q, self._staging)
         self._last_select = "extract"
         self.last_extract_impl = impl
+        self.last_variant = pallas_fused.variant_stamp(
+            impl, kc, cr, entry.qpad, na, prec)
         od = oi = None
         throttle = ChunkThrottle()
         with obs_span("serve.solve_multipass", qpad=entry.qpad,
@@ -964,7 +968,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     def _solve(self, inp: KNNInput) -> Tuple[TopK, int]:
         self.last_phase_ms = {}
         self._pending_iters = []
-        self.last_extract_impl = None
+        self.last_extract_impl = self.last_variant = None
         self.last_prune = None
         if inp.params.num_data != self.n_real:
             raise ValueError(
@@ -1111,9 +1115,3 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             if isinstance(self.last_precision, dict) else None,
         }
 
-
-# Hoisted to utils.compile_cache (the batch CLI, train loop, and fleet
-# spawn paths need the same opt-in); re-exported here so serve embedders
-# and `serve/__main__.py` keep importing it from this module unchanged.
-from dmlp_tpu.utils.compile_cache import (  # noqa: E402,F401
-    enable_persistent_compile_cache)

@@ -38,7 +38,7 @@ def prefetch_to_device(it: Iterator[Tuple[np.ndarray, np.ndarray]],
     before the current step's results are consumed overlaps host->device
     DMA with device compute — without this the train loop eats a full
     transfer latency per step (the round-1 loop's synchronous per-step
-    device_put, flagged in VERDICT.md "What's weak" #3).
+    device_put, flagged in the round-4 review).
     """
     import collections
 

@@ -596,15 +596,14 @@ def main(argv=None) -> int:
                         "--offload means 'all' (the bench_4 host-offload "
                         "analog)")
     p.add_argument("--compile-cache", metavar="DIR", default=None,
-                   help="persistent XLA compilation cache dir (best "
-                        "effort; re-runs at the same shapes skip the "
-                        "step-function compiles); "
-                        "$DMLP_TPU_COMPILE_CACHE is the ambient form "
-                        "(flag wins)")
+                   help="persistent XLA compilation cache dir; "
+                        "default <checkout>/.jax_cache, and "
+                        "$JAX_COMPILATION_CACHE_DIR, when set, "
+                        "wins over both (utils.compile_cache)")
     args = p.parse_args(argv)
 
-    from dmlp_tpu.utils.compile_cache import enable_from_flag
-    enable_from_flag(args.compile_cache)
+    from dmlp_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache(args.compile_cache)
     mesh_shape = None
     if args.mesh:
         mesh_shape = tuple(int(d) for d in args.mesh.split(","))

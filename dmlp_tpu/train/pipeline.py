@@ -262,7 +262,7 @@ def flat_forward(flat, x):
 # 1/V stage slice, so the pipeline fill/drain shrinks: forward span
 # (M - 1 + V*S) * F/V = ((M-1)/V + S) * F vs GPipe's (M - 1 + S) * F —
 # the bubble term drops by V, which is the whole point at small M
-# (VERDICT r4 item 6). Backward is still jax.grad through the scan (the
+# (round-4 review item 6). Backward is still jax.grad through the scan (the
 # reverse schedule inherits the same 1/V tick cost).
 #
 # Why not plain (non-interleaved) 1F1B: in a single-jit SPMD program the

@@ -108,8 +108,7 @@ def supports_injit_offload() -> bool:
 
     TPU runtimes do; XLA:CPU lacks the annotate_device_placement custom
     call ("No registered implementation ... for Host"), so the eager
-    fallback (make_eager_offload_step) is used there. Probe-compiled once,
-    like ops.pallas_distance.native_pallas_backend.
+    fallback (make_eager_offload_step) is used there. Probe-compiled once.
     """
     try:
         dev = jax.devices()[0]

@@ -76,7 +76,7 @@ def sweep_point(n_chips: int, dims: Sequence[int], batch_per_chip: int,
         "global_batch": batch,
         "samples_per_sec_per_chip": round(tm["samples_per_sec_per_chip"], 1),
         "step_time_ms": round(tm["step_time_ms"], 2),
-        "mfu": round(tm["mfu"], 4),
+        **({"mfu": round(tm["mfu"], 4)} if "mfu" in tm else {}),
         "dims": list(dims),
         "offload": offload,
         "dtype": dtype or "float32",

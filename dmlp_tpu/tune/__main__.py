@@ -75,16 +75,14 @@ def main(argv=None) -> int:
     ap.add_argument("--validate", metavar="PATH", default=None,
                     help="schema-check an existing cache file and exit")
     ap.add_argument("--compile-cache", metavar="DIR", default=None,
-                    help="persistent XLA compilation cache "
-                         "(utils.compile_cache; $DMLP_TPU_COMPILE_CACHE "
-                         "is the ambient form) — a sweep compiles every "
-                         "variant once, so a re-sweep against the same "
-                         "dir skips straight to the run-time "
-                         "measurements")
+                    help="persistent XLA compilation cache dir; "
+                         "default <checkout>/.jax_cache, and "
+                         "$JAX_COMPILATION_CACHE_DIR, when set, "
+                         "wins over both (utils.compile_cache)")
     args = ap.parse_args(argv)
 
-    from dmlp_tpu.utils.compile_cache import enable_from_flag
-    enable_from_flag(args.compile_cache)
+    from dmlp_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache(args.compile_cache)
 
     from dmlp_tpu.tune.cache import (VariantCache, cache_path,
                                      clear_lookup_memo)

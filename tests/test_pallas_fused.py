@@ -286,13 +286,13 @@ def test_fused_rung_degrades_to_two_pass_on_oom(monkeypatch, tmp_path):
 # -- analytic cost model -----------------------------------------------------
 
 def test_fused_cost_model_shows_hbm_traffic_elimination():
-    """The acceptance number: on the ROOFLINE_r05 shape the fused
+    """The acceptance number: on the parity dispatch shape the fused
     dispatch's HBM bytes drop by exactly the (nq, nd) f32 distance
     write+read the two-pass pipeline pays — ~2x hot-path traffic."""
     from dmlp_tpu.obs.kernel_cost import (fused_topk_cost,
                                           two_pass_equivalent_cost)
 
-    qb, b, a, kc = 10240, 204800, 64, 40   # ROOFLINE_r05 dispatch shape
+    qb, b, a, kc = 10240, 204800, 64, 40   # parity dispatch shape
     fused = fused_topk_cost(qb, b, a, kc)
     two = two_pass_equivalent_cost(qb, b, a, kc)
     dist_rt = 2.0 * 4.0 * qb * b           # f32 write + re-read

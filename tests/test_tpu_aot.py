@@ -1,0 +1,80 @@
+"""The kernels must COMPILE for the chip, checked without one.
+
+libtpu is installed in the CPU container, and
+``jax.experimental.topologies`` builds a compile-only "TPU v5 lite"
+topology from it, so Mosaic and XLA:TPU can be asked to compile every
+kernel variant the engine can dispatch at the bench config 4 shape
+(input3: 200 000 x 10 000 x 64, k in [1, 32]) — the dispatch the engine
+plans on a TPU: bf16 staging, kcap 144, (tile_q 64, ne 4), qpad 10112,
+chunks of 51 200 rows. This catches what interpret mode cannot: PR 18's
+bf16 first pass had only ever run interpreted and did not compile
+("Bad lhs type": bf16 operands under an fp32 contract precision).
+Compiling says nothing about running; chip_smoke.py does that.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.ops.pallas_extract import _extract_topk_jit
+
+QPAD, CHUNK, NA, KCAP = 10112, 51200, 64, 144
+VARIANT = dict(tile_q=64, tile_n=12800, ne=4, unroll=1)   # kcap > 64
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four compile-only devices of a v5e 2x2 host."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # no libtpu / no such topology in this build
+        pytest.skip(f"libtpu cannot build the v5e:2x2 topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.mark.parametrize("carry", [False, True], ids=["fresh", "carry"])
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("mxu_gate", [True, False],
+                         ids=["fused", "two_pass"])
+def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    lists = ((spec((QPAD, KCAP), jnp.float32),
+              spec((QPAD, KCAP), jnp.int32)) if carry else (None, None))
+    _extract_topk_jit.lower(
+        spec((QPAD, NA), jnp.bfloat16), spec((CHUNK, NA), jnp.bfloat16),
+        *lists, n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
+        kc=KCAP, interpret=False, block_skip=True, mxu_gate=mxu_gate,
+        floor=None, precision=precision, **VARIANT).compile()
+
+
+@pytest.mark.slow   # ~25 s, nearly all of it XLA:TPU compiling the merge sort
+def test_sharded_engine_program_compiles_for_v5e_2x2(v5e):
+    """The all-gather-merge mesh program, kernel inside shard_map, on
+    the four-chip host's 2x2 mesh."""
+    from dmlp_tpu.engine.sharded import ShardedEngine
+    from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS
+    mesh = Mesh(np.asarray(v5e).reshape(2, 2), (DATA_AXIS, QUERY_AXIS))
+    eng = ShardedEngine(EngineConfig(mode="sharded", use_pallas=True,
+                                     dtype="bfloat16"), mesh=mesh)
+    rows, qpad = 2 * 102400, 2 * 5056       # 2 data shards, 2 query shards
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    compiled = eng._fn(KCAP, 102400, "extract", "fused", "f32").lower(
+        spec((rows, NA), jnp.bfloat16, DATA_AXIS, None),
+        spec((rows,), jnp.int32, DATA_AXIS),
+        spec((rows,), jnp.int32, DATA_AXIS),
+        spec((qpad, NA), jnp.bfloat16, QUERY_AXIS, None)).compile()
+    assert "all-gather" in compiled.as_text()

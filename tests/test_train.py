@@ -162,7 +162,29 @@ def test_weak_scaling_sweep_runs():
         assert p["global_batch"] == 32 * p["n_chips"]
 
 
+def test_unknown_device_kind_has_no_peak_and_no_mfu():
+    """A device kind the peaks table does not know raises — no fallback
+    peak — and the step's rates are reported without a utilisation."""
+    from dmlp_tpu.obs.counters import roofline
+    from dmlp_tpu.train import metrics
+    assert not hasattr(metrics, "FALLBACK_PEAK_FLOPS")
+    with pytest.raises(metrics.UnknownDeviceKind, match="'cpu'"):
+        metrics.peak_flops_per_chip()       # the suite runs on cpu
+    params = init_mlp(jax.random.PRNGKey(0), (10, 20, 5))
+    m = throughput_metrics(params, batch_size=100, step_time_s=0.5,
+                           n_chips=1)
+    assert m["samples_per_sec"] == 200.0 and "mfu" not in m
+    rl = roofline(1e9, 1e6, 0.5)
+    assert rl["achieved_flops_per_s"] == 2e9
+    assert "utilization_vs_peak" not in rl
+    assert "peak_flops_per_chip" not in rl
+
+
 def test_train_bench_smoke(monkeypatch):
+    # train_bench is a measurement path: it needs the device's peak, and
+    # the cpu the suite runs on has none in the table.
+    from dmlp_tpu.train import metrics
+    monkeypatch.setitem(metrics.PEAK_FLOPS_BY_KIND, "cpu", 1e12)
     monkeypatch.setenv("TRAIN_DIMS", "8,16,4")
     monkeypatch.setenv("TRAIN_BATCH", "32")
     monkeypatch.setenv("TRAIN_STEPS", "3")
@@ -252,7 +274,7 @@ def test_train_loop_parallelism_families(tmp_path):
 
 
 def test_train_loop_moe_a2a_dispatch():
-    """VERDICT r4 item 1: the capacity + all-to-all MoE dispatch is
+    """round-4 review item 1: the capacity + all-to-all MoE dispatch is
     reachable from the production loop (moe_dispatch="a2a"), trains with
     finite loss, and at cf >= EP (zero drops) its first-step loss equals
     the dense dispatch's on the identical state/batch."""

@@ -162,7 +162,7 @@ def test_pp3_step_matches_flat_reference(dp, tp, pp):
 @pytest.mark.parametrize("dp,pp,vv,n_micro", [(2, 4, 2, 4), (1, 4, 3, 2),
                                               (1, 2, 2, 2)])
 def test_interleaved_step_matches_flat_reference(dp, pp, vv, n_micro):
-    """VERDICT r4 item 6: the interleaved (1F1B-interleaved / virtual
+    """round-4 review item 6: the interleaved (1F1B-interleaved / virtual
     stages) schedule must produce the unpipelined flat stack's loss and
     updated params exactly — same criterion as the GPipe equivalence."""
     from dmlp_tpu.train.pipeline import (build_ppi_state, make_pp_mesh,

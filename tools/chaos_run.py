@@ -150,7 +150,10 @@ def check_faulted_run(kind: str, golden: bytes, out_b: bytes,
              "was invisible")
     return {"kind": kind, "fired": len(fired),
             "retries": res["retries"],
-            "degradations": res["degradations"]}
+            "degradations": res["degradations"],
+            # the engine subprocess's own device stamp: this parent
+            # never asks jax where its children ran
+            "device": (summary.get("device") or {}).get("device_kind")}
 
 
 def measure_overhead(input_path: str, pairs: int, timeout_s: float):
@@ -322,8 +325,7 @@ def main(argv=None) -> int:
           f"on {overhead['engine_ms_resilience_on']} ms)")
 
     if args.record:
-        from dmlp_tpu.obs.run import (RunRecord, current_device,
-                                      round_from_name)
+        from dmlp_tpu.obs.run import RunRecord, round_from_name
         RunRecord(
             kind="chaos", tool="tools.chaos_run",
             config={"config": CONFIG_ID, "seed_base": args.seed_base,
@@ -334,7 +336,8 @@ def main(argv=None) -> int:
                      **({"train": train_summary} if train_summary
                         else {}),
                      **overhead},
-            device=current_device(),
+            device=next((r["device"] for r in results
+                         if r.get("device")), None),
             round=round_from_name(args.record)).append_jsonl(args.record)
     print("chaos_run: all chaos invariants hold")
     return 0

@@ -70,7 +70,8 @@ import numpy as np                                         # noqa: E402
 
 from dmlp_tpu.fleet import harness as fh                   # noqa: E402
 from dmlp_tpu.io.grammar import KNNInput, Params, parse_input_text  # noqa: E402
-from dmlp_tpu.obs.run import RunRecord, current_device     # noqa: E402
+from dmlp_tpu.fleet.loadgen import served_device          # noqa: E402
+from dmlp_tpu.obs.run import RunRecord                     # noqa: E402
 from dmlp_tpu.serve import client as sc                    # noqa: E402
 
 BATCH_CAP = 16
@@ -210,7 +211,6 @@ def main(argv=None) -> int:
     for stale in os.listdir(out):
         if stale.endswith("_ready.json") or stale == "router_ready.json":
             os.remove(os.path.join(out, stale))
-    device = current_device()
 
     corpus_txt = sc.corpus_text(HEADER)
     corpus_path = os.path.join(out, "corpus.in")
@@ -232,6 +232,9 @@ def main(argv=None) -> int:
         st = router_stats(ready["port"])
         if st["healthy_replicas"] != 2:
             fail(f"fleet not healthy at ready: {st['replicas']}")
+        # The replicas' own stamp: this parent holds no device (asking
+        # jax here would claim the chip the replicas need).
+        device = served_device(st)
         say(f"supervised fleet ready: router :{ready['port']}, "
             f"mesh 2x1 replicas "
             f"{[m['replica'] for m in managed]}")
@@ -491,6 +494,10 @@ def main(argv=None) -> int:
         "no flight dumps")
 
     # ---- campaign 4: warm compile-cache relaunch ----------------------------
+    # This campaign places the cache itself (cold arm = empty dir), so
+    # an ambient placement, which would win over --compile-cache
+    # (utils.compile_cache), is taken out of the replicas' environment.
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     import shutil
     ccdir = os.path.join(out, "compile_cache")
     shutil.rmtree(ccdir, ignore_errors=True)   # cold arm = empty cache

@@ -241,6 +241,9 @@ def main(argv=None) -> int:
            merged_path, "--record", record, "--json"]
     if args.round is not None:
         cmd += ["--round", str(args.round)]
+    kind = (reps[0].ready.get("device") or {}).get("device_kind")
+    if kind:
+        cmd += ["--device", kind]
     cp = subprocess.run(cmd, capture_output=True, text=True,
                         env=fh._repo_env())
     if cp.returncode != 0:

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Same-platform single-vs-mesh engine A/B (VERDICT r4 weak item 2 / next 7).
+"""Same-platform single-vs-mesh engine A/B (round-4 review, weak item 2).
 
 HARNESS_r04 showed config 3 (sharded, 8 virtual CPU devices) at 2.5x
 config 2 (single engine) on the identical 100k x 5k x 64 input — but those
@@ -8,7 +8,7 @@ config 3 = virtual CPU emulation), so the ratio conflated mesh-driver
 overhead with the platform gap. This tool runs both engines (plus ring)
 on the SAME platform and input, interleaved with rotating starts
 (verify-skill methodology), and records per-engine median/min plus the
-single-relative overhead — the decomposition VERDICT asked for.
+single-relative overhead — the decomposition the review asked for.
 
 Usage (CPU virtual mesh, config-3's venue):
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

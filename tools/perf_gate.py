@@ -7,8 +7,8 @@ latest round against the previous one:
 
 - a series gates only when the comparison is QUALIFIED: both rounds on
   the same device, both carrying >= MIN_TRIALS per-trial samples (the
-  noise band needs raw trials — a single-shot number on the tunneled
-  link measures weather, not the engine);
+  noise band needs raw trials — a single-shot number says nothing
+  about spread);
 - a qualified regression beyond the noise band
   (``compare_points(...)["regressed"]``) FAILS the gate, naming the
   series, rounds, medians, and band;

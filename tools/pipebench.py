@@ -2,7 +2,7 @@
 """PIPEBENCH: GPipe vs interleaved (1F1B-interleaved) schedule A/B.
 
 Runs both dp_pp schedules at fixed shape across small microbatch counts
-(the regime VERDICT r4 item 6 targets: the GPipe bubble term
+(the regime round-4 review item 6 targets: the GPipe bubble term
 (S-1)/(M+S-1) is largest there), interleaved A/B with rotating starts
 (the verify-skill methodology), and records per-config median/min step
 times plus the analytic bubble fractions — one schema-1 RunRecord

@@ -1,4 +1,4 @@
-"""Offload step-time decomposition (VERDICT r3 item 7).
+"""Offload step-time decomposition (round-3 review item 7).
 
 The r3 numbers: resident 68.5% MFU vs offload 54.5% (at 4x the batch).
 This tool explains the gap with three fenced measurements at the SAME

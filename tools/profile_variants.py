@@ -100,9 +100,9 @@ def main() -> int:
         out[f"{tag}/merge_sortseg"] = amortized(merge_sortseg, carry, cand)
 
     # End-to-end seg solve, one big chunk vs 4 chunks, via streaming_topk.
-    from dmlp_tpu.ops.pallas_distance import native_pallas_backend
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
     from dmlp_tpu.ops.topk import streaming_topk
-    native = native_pallas_backend()
+    native = not pallas_interpret()
     rng = np.random.default_rng(0)
     n = 204800
     q = jnp.asarray(rng.uniform(0, 100, (nq, a)), jnp.float32)

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Repair-rate sweep: measure the kcap margin's generality (VERDICT r4 #9).
+"""Repair-rate sweep: measure the kcap margin's generality (round-4 review #9).
 
 The bf16 staging margin 96 + k/2 (engine.single.resolve_kcap) was
 calibrated at one shape (200k x 10k x 64); the eps-aware hazard test +

@@ -1,4 +1,4 @@
-"""Scale + capacity proof for the extraction solve (VERDICT r3 item 8).
+"""Scale + capacity proof for the extraction solve (round-3 review item 8).
 
 Runs the fenced extract solve (sort epilogue included, bench.py scope) at
 dataset rungs up to >= 4M x 10k x 64 — a shape whose dense (Q, N) f32
@@ -27,10 +27,10 @@ def main() -> int:
     import jax
 
     from dmlp_tpu.engine.single import _extract_finalize
-    from dmlp_tpu.ops.pallas_distance import native_pallas_backend
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
     from dmlp_tpu.ops.pallas_extract import extract_topk, supports
 
-    if not native_pallas_backend():
+    if pallas_interpret():
         print("needs the native TPU backend", file=sys.stderr)
         return 1
 

@@ -124,7 +124,7 @@ def main(argv=None) -> int:
            "--record", record, "--faults", faults_path,
            "--tick-ms", "2"]
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    env = dict(os.environ,      # platform inherited from the caller
                PYTHONPATH=repo + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     with open(errlog, "w") as ef:

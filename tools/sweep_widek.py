@@ -1,4 +1,4 @@
-"""Wide-k extraction-kernel tuning sweep (VERDICT r3 item 4).
+"""Wide-k extraction-kernel tuning sweep (round-3 review item 4).
 
 SCALE_r03 showed the extraction solve degrading 1.64x from kcap 40 to 136
 (98 -> 161 ms at 204800 x 10240 x 64) with the k=40-tuned defaults
@@ -28,10 +28,10 @@ from bench import (_env_int, make_workload, stage_extract_inputs,  # noqa: E402
 
 def main() -> int:
     from dmlp_tpu.engine.single import _extract_finalize
-    from dmlp_tpu.ops.pallas_distance import native_pallas_backend
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
     from dmlp_tpu.ops.pallas_extract import BLOCK_ROWS, extract_topk
 
-    if not native_pallas_backend():
+    if pallas_interpret():
         print("needs the native TPU backend", file=sys.stderr)
         return 1
 

@@ -105,8 +105,11 @@ def attribute(merged: dict) -> dict:
 
 
 def emit_records(levels: dict, record_path: str, trace_path: str,
-                 round_: int = None) -> int:
-    from dmlp_tpu.obs.run import RunRecord, current_device
+                 round_: int = None, device: str = None) -> int:
+    """``device`` is the kind the TRACED fleet ran on (its replicas'
+    device stamp, handed in by whoever ran the fleet) — an offline pass
+    over a trace file has no device of its own to report."""
+    from dmlp_tpu.obs.run import RunRecord
     n = 0
     for lvl, att in levels.items():
         metrics = {"attributed_requests": att["n"]}
@@ -121,7 +124,7 @@ def emit_records(levels: dict, record_path: str, trace_path: str,
                           "trace": os.path.basename(trace_path),
                           "quantiles": [qt for qt, _ in QUANTILES]},
                   metrics=metrics, round=round_,
-                  device=current_device()).append_jsonl(record_path)
+                  device=device).append_jsonl(record_path)
         n += 1
     return n
 
@@ -137,6 +140,10 @@ def main(argv=None) -> int:
                          "(narration to stderr)")
     ap.add_argument("--round", type=int, default=None,
                     help="measurement round stamped into the records")
+    ap.add_argument("--device", default=None,
+                    help="device kind the traced fleet ran on (its "
+                         "replicas' device stamp), stamped into the "
+                         "records")
     args = ap.parse_args(argv)
 
     def say(msg):
@@ -158,7 +165,7 @@ def main(argv=None) -> int:
             f"residual {p99['residual_ms']}ms)")
     if args.record:
         n = emit_records(levels, args.record, args.merged,
-                         round_=args.round)
+                         round_=args.round, device=args.device)
         say(f"tail_attrib: appended {n} tailattrib record(s) -> "
             f"{args.record}")
     if args.json:

@@ -6,7 +6,7 @@ proofs, every failure exits nonzero with the reason named:
 
 1. **Contract byte-identity** — bench config 1 runs through the real
    CLI in interleaved ``--telemetry`` OFF/ON pairs (order alternating
-   per pair, the BENCH_MODES_r04 weather methodology); every run's
+   per pair); every run's
    stdout must be byte-identical to the plain run's. Telemetry is
    stderr/filesystem-only by construction; this proves it.
 2. **OpenMetrics validity** — the final ON-arm snapshot file passes the
@@ -267,17 +267,19 @@ def main(argv=None) -> int:
         else None
     if overhead is not None:
         say(f"telemetry overhead {overhead:+.1f}% (median {med_off} -> "
-            f"{med_on} ms; raw samples ride in the record — on this "
-            "shared container the point estimate is weather)")
+            f"{med_on} ms; raw samples ride in the record — on a "
+            "shared machine the point estimate is noise)")
 
     # 2. OpenMetrics validity of the ON-arm snapshot
     check_openmetrics(tel_path)
 
-    # 3. analytic peak-HBM model vs measured watermark
-    rec = reconcile_in_process(cfg, input_path)
-
-    # 4. flight recorder on a retries-exhausted fault
+    # 3. flight recorder on a retries-exhausted fault
     check_flight_recorder(cfg, input_path, args.out)
+
+    # 4. analytic peak-HBM model vs measured watermark. In-process, so
+    # it comes after every engine subprocess: once this process has
+    # touched jax it holds the chip a child would need.
+    rec = reconcile_in_process(cfg, input_path)
 
     # 5. RunRecord + ledger round-trip
     if args.record:

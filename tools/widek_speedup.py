@@ -1,14 +1,14 @@
 #!/usr/bin/env python
-"""Wide-k multi-pass extract vs streaming fallback A/B (VERDICT r4 #2).
+"""Wide-k multi-pass extract vs streaming fallback A/B (round-4 review #2).
 
 The r4 engine dropped ALL-wide-k inputs (every query's k beyond the
 kernel's 512-slot window) to the streaming selects; r5 runs the kernel in
-floor-raised multi-passes. This measures the payoff at the VERDICT's
+floor-raised multi-passes. This measures the payoff at the review's
 shape (200k x 1k x 64, k=4096): the multipass engine vs an engine forced
 onto the streaming select, interleaved reps, identical input, both
 checksum-validated against each other.
 
-Run in the DEFAULT env (real chip). CPU works too (interpret kernel) but
+Run on the chip. CPU works too (interpret kernel) but
 the numbers then measure the interpreter, not the kernel.
 
 Usage: python tools/widek_speedup.py [--out WIDEK_MP_r05.json]
