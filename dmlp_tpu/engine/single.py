@@ -1314,7 +1314,8 @@ class SingleChipEngine:
         # only (an O(N*A) host pass the kcap >= n case never uses).
         dn_max = None
 
-        fetch_ms = final_ms = 0.0
+        fetch_ms = hazard_ms = final_ms = 0.0
+        targs = self._rid_args()
         for top, qpad, idx, select in segments:
             sub = inp if idx is None else subset_queries(inp, idx)
             nq = sub.params.num_queries
@@ -1327,6 +1328,7 @@ class SingleChipEngine:
                 cols_dev = _boundary_cols(top.dists, jax.device_put(ks_pad))
 
             t0 = _time.perf_counter()
+            self._before_fetch(t0)
             # NOTE: the "fetch" phase time includes the wait for all
             # enqueued device work (staging + solve), not just the readback
             # bytes — and past _CHUNK_WINDOW chunks the enqueue phase
@@ -1334,41 +1336,56 @@ class SingleChipEngine:
             # as "readback costs X ms".
             fetch = ([] if self.config.exact else [top.dists]) + [top.ids] \
                 + ([cols_dev] if cols_dev is not None else [])
-            with obs_span("single.fetch", select=select, kcap=kcap):
+            with obs_span("single.fetch", select=select, kcap=kcap,
+                          **targs):
                 fetched = list(resilient_get(fetch))
-            dists = None if self.config.exact \
-                else np.asarray(fetched.pop(0), np.float64)[:nq]
-            ids = fetched.pop(0)[:nq]
-            flags = None
-            if cols_dev is not None:
-                kth, last = np.asarray(fetched.pop(0), np.float64)[:, :nq]
-                if dn_max is None:
-                    dn_max = float(np.einsum(
-                        "na,na->n", inp.data_attrs, inp.data_attrs).max()) \
-                        if n else 0.0
-                qn = np.einsum("qa,qa->q", sub.query_attrs, sub.query_attrs)
-                eps = staging_eps(last, qn, dn_max, self._staging,
-                                  inp.params.num_attrs)
-                if prec == "bf16" and select == "extract":
-                    # The low-precision first pass perturbs device
-                    # distances by up to lowp_eps ON TOP of the staging
-                    # rounding; the hazard test must clear both.
-                    # Streaming-fallback segments never cast, so their
-                    # eps stays the staging bound alone.
-                    eps = eps + lowp_eps("bf16", qn, dn_max)
-                flags = boundary_hazard(kth, last, eps)
-            # Multi-pass extraction's own loss detectors (stall/shortfall,
-            # _solve_extract_multipass) join the standard boundary test.
-            mp = getattr(self, "_mp_hazard", None)
-            if mp is not None and idx is None:
-                flags = mp if flags is None else (flags | mp)
-            labels = np.where(ids >= 0,
-                              inp.labels[np.clip(ids, 0, max(n - 1, 0))], -1) \
-                if n else np.full_like(ids, -1)
-            fetch_ms += (_time.perf_counter() - t0) * 1e3
+            t1 = _time.perf_counter()
+            fetch_ms += (t1 - t0) * 1e3
+            # Everything the host does between the readback and the
+            # finalize: the staging-eps hazard test (whose dn_max is a
+            # pass over the WHOLE host corpus) and the label gather.
+            with obs_span("single.hazard", rows=n, **targs) as hz:
+                dists = None if self.config.exact \
+                    else np.asarray(fetched.pop(0), np.float64)[:nq]
+                ids = fetched.pop(0)[:nq]
+                flags = None
+                if cols_dev is not None:
+                    kth, last = np.asarray(fetched.pop(0),
+                                           np.float64)[:, :nq]
+                    if dn_max is None:
+                        with obs_span("single.dn_max", rows=n, **targs):
+                            dn_max = float(np.einsum(
+                                "na,na->n", inp.data_attrs,
+                                inp.data_attrs).max()) if n else 0.0
+                    qn = np.einsum("qa,qa->q", sub.query_attrs,
+                                   sub.query_attrs)
+                    eps = staging_eps(last, qn, dn_max, self._staging,
+                                      inp.params.num_attrs)
+                    if prec == "bf16" and select == "extract":
+                        # The low-precision first pass perturbs device
+                        # distances by up to lowp_eps ON TOP of the
+                        # staging rounding; the hazard test must clear
+                        # both. Streaming-fallback segments never cast,
+                        # so their eps stays the staging bound alone.
+                        eps = eps + lowp_eps("bf16", qn, dn_max)
+                    flags = boundary_hazard(kth, last, eps)
+                # Multi-pass extraction's own loss detectors (stall/
+                # shortfall, _solve_extract_multipass) join the standard
+                # boundary test.
+                mp = getattr(self, "_mp_hazard", None)
+                if mp is not None and idx is None:
+                    flags = mp if flags is None else (flags | mp)
+                labels = np.where(
+                    ids >= 0,
+                    inp.labels[np.clip(ids, 0, max(n - 1, 0))], -1) \
+                    if n else np.full_like(ids, -1)
+                hz.set(flagged=0 if flags is None
+                       else int(np.count_nonzero(flags)))
 
             t0 = _time.perf_counter()
-            with obs_span("single.finalize", exact=self.config.exact) as sp:
+            hazard_ms += (t0 - t1) * 1e3
+            with obs_span("single.finalize", exact=self.config.exact,
+                          **targs) as sp:
                 results = finalize_host(dists, labels, ids, sub.ks,
                                         sub.query_attrs, sub.data_attrs,
                                         exact=self.config.exact,
@@ -1376,7 +1393,10 @@ class SingleChipEngine:
                 if flags is not None:
                     suspects = np.nonzero(flags)[0]
                     if suspects.size:
-                        repair_boundary_overflow(results, suspects, sub)
+                        with obs_span("single.repair",
+                                      queries=int(suspects.size), **targs):
+                            repair_boundary_overflow(results, suspects,
+                                                     sub)
                         self.last_repairs += int(suspects.size)
                         sp.set(repairs=int(suspects.size))
             if idx is None:
@@ -1386,9 +1406,21 @@ class SingleChipEngine:
                     merged[int(orig)] = results[local_i]
             final_ms += (_time.perf_counter() - t0) * 1e3
         self.last_phase_ms["fetch"] = fetch_ms
+        self.last_phase_ms["hazard"] = hazard_ms
         self.last_phase_ms["finalize"] = final_ms
         self._flush_measured_iters()
         return merged
+
+    def _rid_args(self) -> dict:
+        """Args every span of one solve carries: none for a batch solve;
+        the serving core (serve.engine.ResidentServingCore) tags the
+        micro-batch it is solving."""
+        return {}
+
+    def _before_fetch(self, t_pc: float) -> None:
+        """Seam: everything of the solve is enqueued and the readback
+        starts at ``t_pc`` (perf_counter). The serving engine closes its
+        ``serve.solve_epilogue`` span here; a batch solve has none."""
 
     def run_device_full(self, inp: KNNInput) -> List[QueryResult]:
         """All-device pipeline (vote + report order on TPU); f32 ordering.

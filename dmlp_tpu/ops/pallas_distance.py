@@ -124,6 +124,7 @@ def fused_dist_segmin(q_attrs: jax.Array, d_attrs: jax.Array,
     grid = (qb // tq, b // tn)
     dist, segmin_t = pl.pallas_call(
         functools.partial(_kernel, precision=precision),
+        name="dmlp_dist_segmin",     # the event's name in a device trace
         grid=grid,
         in_specs=[
             pl.BlockSpec((tq, a), lambda i, j: (i, 0)),

@@ -499,8 +499,15 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     kern = functools.partial(_kernel, kc=kc, fresh=fresh, ne=ne,
                              unroll=unroll, block_skip=block_skip,
                              mxu_gate=mxu_gate, precision=precision)
+    # The name is what a profiler capture and the compiled HLO show for
+    # this custom call (``%dmlp_topk_fused.1 = ... custom-call(...)``):
+    # it states the form, so a trace tells the MXU-gated kernel from the
+    # ungated one and a chunk's first fold (no carry) from the carried.
+    name = ("dmlp_topk_fused" if mxu_gate else "dmlp_topk_extract") \
+        + ("_fresh" if fresh else "")
     out_d, out_i, out_iters = pl.pallas_call(
         kern,
+        name=name,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 2), lambda i, j: (0, 0),

@@ -67,6 +67,8 @@ class Request:
     results: Optional[List] = None                # QueryResults (local ids)
     error: Optional[str] = None
     latency_ms: Optional[float] = None
+    batch: Optional[int] = None                   # serial of the micro-
+    #                                               batch it rode
     corpus_rows: Optional[int] = None             # ingest outcome
     payload: Optional[Dict[str, Any]] = None      # corpus outcome
 
@@ -100,6 +102,9 @@ class MicroBatcher:
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self.batches = 0
+        # Serial of the micro-batch in hand: every span of one batch
+        # carries it as ``batch``. Consumer-thread-private.
+        self._serial = 0
         # perf_counter at which the consumer woke for the current
         # collect cycle — the queue-wait / coalesce-wait boundary for
         # phase spans. Consumer-thread-private.
@@ -253,7 +258,7 @@ class MicroBatcher:
         """One request-phase span through the complete_at seam (tracer
         AND the PR 9 telemetry observer, so ``serve.phase.*.ms``
         histograms stay live); rid-tagged when the request carried
-        one. Callers gate on sinks_active()."""
+        one. A no-op when no sink is installed."""
         if rid:
             args["rid"] = rid
         obs_trace.complete_at(name, t0, max(t0, t1), **args)
@@ -318,13 +323,19 @@ class MicroBatcher:
 
     def _execute_batch(self, batch: List[Request]) -> None:
         reg = telemetry.registry()
+        # check: allow-concurrency=R702 — _serial is read and written
+        # only here, on the batcher thread.
+        self._serial += 1
+        serial = self._serial
         total = sum(r.nq for r in batch)
-        q = np.concatenate([r.query_attrs for r in batch])
-        ks = np.concatenate([r.ks for r in batch])
-        qpad, _ = self.engine.bucket_shape(
-            total, int(ks.max()) if total else 1)
-        tracing = obs_trace.sinks_active()
-        rids = ",".join(r.rid for r in batch if r.rid) if tracing else ""
+        with obs_span("serve.batch_assemble", batch=serial,
+                      requests=len(batch), queries=total):
+            q = np.concatenate([r.query_attrs for r in batch])
+            ks = np.concatenate([r.ks for r in batch])
+            qpad, _ = self.engine.bucket_shape(
+                total, int(ks.max()) if total else 1)
+        rids = ",".join(r.rid for r in batch if r.rid) \
+            if obs_trace.sinks_active() else ""
         t0 = time.perf_counter()
         try:
             # The chaos harness's straggler-solve site: a delay fault
@@ -336,15 +347,17 @@ class MicroBatcher:
             rs_inject.fire("serve.solve", requests=len(batch),
                            queries=total)
             with obs_span("serve.micro_batch", requests=len(batch),
-                          queries=total, qpad=qpad,
+                          queries=total, qpad=qpad, batch=serial,
                           **({"rids": rids} if rids else {})):
+                # Single consumer thread: the engine reads these inside
+                # solve_batch to tag its internal spans.
+                self.engine.trace_batch = serial
                 if rids:
-                    # Single consumer thread: the engine reads this
-                    # inside solve_batch to rid-tag its internal spans.
                     self.engine.trace_rids = rids
                 try:
                     results = self.engine.solve_batch(q, ks)
                 finally:
+                    self.engine.trace_batch = None
                     if rids:
                         self.engine.trace_rids = None
         except Exception as e:  # check: no-retry — batch fails visibly,
@@ -354,16 +367,44 @@ class MicroBatcher:
                 r.complete(error=msg)
             return
         t1 = time.perf_counter()
-        ms = (t1 - t0) * 1e3
+        with obs_span("serve.batch_deliver", batch=serial,
+                      requests=len(batch), queries=total):
+            self._deliver(batch, results, serial, t0, t1, total, qpad)
+
+    def _deliver(self, batch: List[Request], results: List, serial: int,
+                 t0: float, t1: float, total: int, qpad: int) -> None:
+        """A solved micro-batch back to its requests: the batch's
+        counters and always-on timings, then per request the slice of
+        results, the completion and its phase decomposition — each
+        phase one clock pair that feeds a registry histogram (every
+        daemon; ``stats`` reports them as ``phases_ms``) and, while a
+        sink is installed, the ``serve.phase.*`` span."""
+        reg = telemetry.registry()
         with self._cond:
             # handler threads read `batches` through daemon.stats()
             # while this consumer increments it — guard the write so
             # the field has one discipline (reads are single int loads)
             self.batches += 1
         reg.counter("serve.batches").inc()
-        reg.histogram("serve.batch_latency_ms", unit="ms").observe(ms)
         reg.histogram("serve.batch_queries").observe(total)
-        reg.gauge("serve.batch_fill").set(round(total / max(qpad, 1), 6))
+        # The engine's own parts of the batch (engine.last_phase_ms: the
+        # dispatch loop, the readback, the hazard pass, the float64
+        # finalize), whichever this engine reports.
+        parts = getattr(self.engine, "last_phase_ms", None) or {}
+        for part, hist in (
+                ("dispatch",
+                 reg.histogram("serve.batch_ms.dispatch", unit="ms")),
+                ("fetch", reg.histogram("serve.batch_ms.fetch", unit="ms")),
+                ("hazard",
+                 reg.histogram("serve.batch_ms.hazard", unit="ms")),
+                ("finalize",
+                 reg.histogram("serve.batch_ms.finalize", unit="ms"))):
+            if part in parts:
+                hist.observe(parts[part])
+        h_queue = reg.histogram("serve.phase_ms.queue", unit="ms")
+        h_coalesce = reg.histogram("serve.phase_ms.coalesce", unit="ms")
+        h_solve = reg.histogram("serve.phase_ms.solve", unit="ms")
+        h_finalize = reg.histogram("serve.phase_ms.finalize", unit="ms")
         off = 0
         for r in batch:
             sub = results[off:off + r.nq]
@@ -372,28 +413,34 @@ class MicroBatcher:
             local = [dataclasses.replace(qr, query_id=qr.query_id - off)
                      for qr in sub]
             off += r.nq
+            r.batch = serial
             r.complete(results=local)
             reg.counter("serve.requests_completed").inc()
             reg.counter("serve.queries_completed").inc(r.nq)
             reg.histogram("serve.request_latency_ms", unit="ms").observe(
                 (time.monotonic() - r.t_enqueue) * 1e3,
                 exemplar=r.rid or None)
-            if tracing:
-                # Per-request phase decomposition. queue ends when the
-                # consumer woke (clamped: a request that arrived during
-                # the coalesce tick has zero queue wait); coalesce runs
-                # to solve start; the full batch solve interval is
-                # attributed to EVERY coalesced request (documented
-                # overlap — the phases of one rid tile its wall time,
-                # they do not sum across rids).
-                # check: allow-concurrency=R702 — batcher-thread-only
-                # read (see _execute_ingest).
-                q1 = min(max(self._wake_pc, r.t_enqueue_pc), t0)
-                self._phase("serve.phase.queue", r.t_enqueue_pc, q1,
-                            r.rid)
-                self._phase("serve.phase.coalesce", q1, t0, r.rid,
-                            requests=len(batch))
-                self._phase("serve.phase.solve", t0, t1, r.rid,
-                            queries=total, qpad=qpad)
-                self._phase("serve.phase.finalize", t1,
-                            time.perf_counter(), r.rid)
+            # Per-request phase decomposition. queue ends when the
+            # consumer woke (clamped: a request that arrived during
+            # the coalesce tick has zero queue wait); coalesce runs
+            # to solve start; the full batch solve interval is
+            # attributed to EVERY coalesced request (documented
+            # overlap — the phases of one rid tile its wall time,
+            # they do not sum across rids); finalize is this
+            # request's delivery, from the solve's end to here.
+            # check: allow-concurrency=R702 — batcher-thread-only
+            # read (see _execute_ingest).
+            q1 = min(max(self._wake_pc, r.t_enqueue_pc), t0)
+            t2 = time.perf_counter()
+            h_queue.observe((q1 - r.t_enqueue_pc) * 1e3)
+            h_coalesce.observe((t0 - q1) * 1e3)
+            h_solve.observe((t1 - t0) * 1e3)
+            h_finalize.observe((t2 - t1) * 1e3)
+            self._phase("serve.phase.queue", r.t_enqueue_pc, q1,
+                        r.rid, batch=serial)
+            self._phase("serve.phase.coalesce", q1, t0, r.rid,
+                        requests=len(batch), batch=serial)
+            self._phase("serve.phase.solve", t0, t1, r.rid,
+                        queries=total, qpad=qpad, batch=serial)
+            self._phase("serve.phase.finalize", t1, t2, r.rid,
+                        batch=serial)

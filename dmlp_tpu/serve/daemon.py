@@ -37,6 +37,30 @@ from dmlp_tpu.serve.batching import MicroBatcher, Request
 from dmlp_tpu.serve.engine import ResidentEngine
 
 
+#: The always-on phase timings the ``stats`` op reports as
+#: ``phases_ms``: where a request's time went ("request": fed once per
+#: query request, by the handler thread and the batcher) and where a
+#: micro-batch's went ("batch": fed once per micro-batch from the
+#: engine's ``last_phase_ms``). Registered, with these literal names,
+#: at the sites that time them (serve/daemon.py, serve/batching.py).
+#: NB ``request.finalize`` is the per-request DELIVERY after the solve
+#: (the ``serve.phase.finalize`` span); ``batch.finalize`` is the
+#: engine's float64 finalize.
+PHASE_HISTOGRAMS = {
+    "request": (("parse", "serve.phase_ms.parse"),
+                ("queue", "serve.phase_ms.queue"),
+                ("coalesce", "serve.phase_ms.coalesce"),
+                ("solve", "serve.phase_ms.solve"),
+                ("finalize", "serve.phase_ms.finalize"),
+                ("respond", "serve.phase_ms.respond"),
+                ("write", "serve.phase_ms.write")),
+    "batch": (("dispatch", "serve.batch_ms.dispatch"),
+              ("fetch", "serve.batch_ms.fetch"),
+              ("hazard", "serve.batch_ms.hazard"),
+              ("finalize", "serve.batch_ms.finalize")),
+}
+
+
 def default_warm_buckets(corpus: KNNInput) -> List[Tuple[int, int]]:
     """Warm-up shapes: the corpus file's own query section is the
     operator's declaration of expected traffic — bucket every (count,
@@ -62,6 +86,7 @@ class _Handler(socketserver.StreamRequestHandler):
             raw = self.rfile.readline(protocol.MAX_LINE_BYTES + 1)
             if not raw:
                 break
+            t_read = time.perf_counter()
             if len(raw) > protocol.MAX_LINE_BYTES:
                 self.wfile.write(protocol.encode(
                     {"ok": False,
@@ -81,8 +106,10 @@ class _Handler(socketserver.StreamRequestHandler):
             # the process exits (handler threads are daemonized).
             daemon._track_inflight(+1)
             try:
+                req = None
                 try:
-                    resp = daemon.handle_line(line)
+                    resp, req = daemon.serve_line(line, t_read,
+                                                  nbytes=len(raw))
                 except protocol.ProtocolError as e:
                     resp = {"ok": False, "error": str(e)}
                 except Exception as e:  # check: no-retry — the
@@ -91,19 +118,30 @@ class _Handler(socketserver.StreamRequestHandler):
                     # batcher
                     resp = {"ok": False,
                             "error": f"{type(e).__name__}: {e}"}
-                w0 = (time.perf_counter()
-                      if obs_trace.sinks_active() else 0.0)
+                w0 = time.perf_counter()
                 self.wfile.write(protocol.encode(resp))
                 self.wfile.flush()
-                if w0:
-                    rid = resp.get("rid", "")
-                    obs_trace.complete_at(
-                        "serve.phase.write", w0, time.perf_counter(),
-                        **({"rid": rid} if rid else {}))
+                w1 = time.perf_counter()
+                if req is not None and req.kind == "query":
+                    telemetry.registry().histogram(
+                        "serve.phase_ms.write", unit="ms").observe(
+                            (w1 - w0) * 1e3)
+                rid = resp.get("rid", "")
+                obs_trace.complete_at(
+                    "serve.phase.write", w0, w1,
+                    **({"rid": rid} if rid else {}),
+                    **_batch_arg(req))
             finally:
                 daemon._track_inflight(-1)
             if resp.get("draining"):
                 break
+
+
+def _batch_arg(req: Optional[Request]) -> Dict[str, int]:
+    """The ``batch`` span arg of a request that rode a micro-batch."""
+    if req is None or req.batch is None:
+        return {}
+    return {"batch": req.batch}
 
 
 class _Server(socketserver.ThreadingTCPServer):
@@ -240,7 +278,6 @@ class ServeDaemon:
             daemon=True)
         self._server_thread.start()
         self._t_ready = time.monotonic()
-        telemetry.registry().gauge("serve.ready").set(1)
 
     def write_ready_file(self, path: str) -> None:
         from dmlp_tpu.obs.run import device_stamp
@@ -287,20 +324,54 @@ class ServeDaemon:
                 self._inflight_cond.wait(timeout=left)
 
     def handle_line(self, line: str) -> Dict[str, Any]:
+        return self.serve_line(line)[0]
+
+    def serve_line(self, line: str, t_read: Optional[float] = None,
+                   nbytes: Optional[int] = None
+                   ) -> Tuple[Dict[str, Any], Optional[Request]]:
+        """One request line to its response, and the Request it made
+        (None for the control ops). ``t_read`` is the perf_counter at
+        which the line had been read: the start of the request's parse
+        phase (now, when the caller read no socket). A query request's
+        ``parse`` and ``respond`` phases are timed here, each one clock
+        pair feeding its always-on histogram and — with a sink
+        installed — its ``serve.phase.*`` span."""
+        if t_read is None:
+            t_read = time.perf_counter()
         obj = protocol.parse_request(line, self.corpus.params.num_attrs)
         if isinstance(obj, dict):                 # control ops
             if obj.get("op") == "stats":
-                return {"ok": True, "stats": self.stats()}
+                return {"ok": True, "stats": self.stats()}, None
             self._drain_event.set()               # "drain"
-            return {"ok": True, "draining": True}
+            return {"ok": True, "draining": True}, None
         req: Request = obj
+        if req.kind != "query":
+            self.batcher.submit(req)
+            req.done.wait()
+            if req.kind == "ingest":
+                return protocol.ingest_response(req), req
+            return protocol.corpus_response(req), req
+        reg = telemetry.registry()
+        rid = {"rid": req.rid} if req.rid else {}
+        t_parsed = time.perf_counter()
+        reg.histogram("serve.phase_ms.parse", unit="ms").observe(
+            (t_parsed - t_read) * 1e3)
         self.batcher.submit(req)
         req.done.wait()
-        if req.kind == "ingest":
-            return protocol.ingest_response(req)
-        if req.kind == "corpus":
-            return protocol.corpus_response(req)
-        return protocol.query_response(req)
+        # recorded now, not when it ended: only now is the micro-batch
+        # the request rode known
+        obs_trace.complete_at(
+            "serve.phase.parse", t_read, t_parsed, queries=req.nq,
+            bytes=len(line) if nbytes is None else nbytes, **rid,
+            **_batch_arg(req))
+        r0 = time.perf_counter()
+        resp = protocol.query_response(req)
+        r1 = time.perf_counter()
+        reg.histogram("serve.phase_ms.respond", unit="ms").observe(
+            (r1 - r0) * 1e3)
+        obs_trace.complete_at("serve.phase.respond", r0, r1,
+                              queries=req.nq, **rid, **_batch_arg(req))
+        return resp, req
 
     def stats(self) -> Dict[str, Any]:
         reg = telemetry.registry()
@@ -336,6 +407,15 @@ class ServeDaemon:
                 "p99": round(h.quantile(0.99), 3),
                 "count": h.count,
             }
+        phases = {
+            group: {key: {"count": h.count,
+                          "p50": round(h.quantile(0.5), 3),
+                          "p95": round(h.quantile(0.95), 3)}
+                    for key, h in ((k, reg.get(n)) for k, n in names)
+                    if h is not None and h.count}
+            for group, names in PHASE_HISTOGRAMS.items()}
+        if any(phases.values()):
+            out["phases_ms"] = phases
         if self.slo is not None:
             try:
                 out["slo"] = self.slo.snapshot()
@@ -440,7 +520,6 @@ class ServeDaemon:
         flush records + final telemetry snapshot, close. No flight
         dump — this is not a crash."""
         self.admission.draining = True
-        telemetry.registry().gauge("serve.ready").set(0)
         self._server.shutdown()
         self.batcher.stop(drain=True)
         # The batcher completed every queued request; now wait for the

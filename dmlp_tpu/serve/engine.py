@@ -64,6 +64,7 @@ from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, ChunkThrottle,
 from dmlp_tpu.io.grammar import KNNInput, Params
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import telemetry
+from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.ops.topk import TopK
 from dmlp_tpu.resilience import degrade as rs_degrade
@@ -150,11 +151,20 @@ class ResidentServingCore:
     #: class default) whenever untraced.
     trace_rids: Optional[str] = None
 
+    #: serial of the micro-batch currently in solve_batch — set/cleared
+    #: by the batcher like ``trace_rids``; every span of one batch
+    #: carries it as ``batch``. None outside the batcher (warm-up).
+    trace_batch: Optional[int] = None
+
     def _rid_args(self) -> Dict[str, Any]:
-        """Span-args rider carrying the current batch's rids — empty
-        (and allocation-only) when untraced."""
-        t = self.trace_rids
-        return {"rids": t} if t else {}
+        """Span-args rider carrying the current micro-batch's serial and
+        its rids — empty (and allocation-only) outside the batcher."""
+        out: Dict[str, Any] = {}
+        if self.trace_batch is not None:
+            out["batch"] = self.trace_batch
+        if self.trace_rids:
+            out["rids"] = self.trace_rids
+        return out
 
     def _bucket_entry(self, nq: int, kmax: int):
         """The bucket for (nq, kmax), building (and counting) it on
@@ -172,9 +182,6 @@ class ResidentServingCore:
             ms = (time.perf_counter() - t0) * 1e3
             self.bucket_compile_ms[entry.key] = round(ms, 3)
             self.compile_count += 1
-            reg = telemetry.registry()
-            reg.counter("serve.bucket_compiles").inc(label=entry.key)
-            reg.histogram("serve.bucket_compile_ms", unit="ms").observe(ms)
         return entry
 
     def warmup(self, buckets) -> Dict[str, float]:
@@ -207,10 +214,8 @@ class ResidentServingCore:
                 (time.perf_counter() - tb) * 1e3, 3)
         self.cold_start_compile_ms = round(
             (time.perf_counter() - t0) * 1e3, 3)
-        reg = telemetry.registry()
-        reg.gauge("serve.cold_start_compile_ms").set(
+        telemetry.registry().gauge("serve.cold_start_compile_ms").set(
             self.cold_start_compile_ms)
-        reg.gauge("serve.warm_buckets").set(len(self._buckets))
         return per
 
     # -- corpus signature (fleet consistency checking reads this) -----------
@@ -356,21 +361,23 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         host_rows = max(self.capacity_rows, self._ex_rows)
 
         # -- host originals (float64 finalize rescore reads these) -----------
-        self._host_attrs = np.zeros((host_rows, na), np.float64)
-        self._host_attrs[:n] = corpus.data_attrs
-        self._host_labels = np.full(host_rows, -1, np.int32)
-        self._host_labels[:n] = corpus.labels
+        with obs_span("serve.init.host_copy", rows=host_rows, na=na):
+            self._host_attrs = np.zeros((host_rows, na), np.float64)
+            self._host_attrs[:n] = corpus.data_attrs
+            self._host_labels = np.full(host_rows, -1, np.int32)
+            self._host_labels[:n] = corpus.labels
         self.n_real = n
-        self._sig_init()
+        with obs_span("serve.init.row_hashes", rows=n):
+            self._sig_init()
 
         # -- the resident staged corpus (the streaming paths' view) ----------
-        sdt = np_staging_dtype(self._staging)
-        attrs = np.zeros((self.capacity_rows, na), sdt)
-        attrs[:n] = corpus.data_attrs
-        ids = np.full(self.capacity_rows, -1, np.int32)
-        ids[:n] = np.arange(n, dtype=np.int32)
         with obs_span("serve.stage_resident", rows=self.capacity_rows,
                       na=na):
+            sdt = np_staging_dtype(self._staging)
+            attrs = np.zeros((self.capacity_rows, na), sdt)
+            attrs[:n] = corpus.data_attrs
+            ids = np.full(self.capacity_rows, -1, np.int32)
+            ids[:n] = np.arange(n, dtype=np.int32)
             self._d_attrs = stage_put(attrs, self._staging)
             self._d_labels = jax.device_put(
                 self._host_labels[:self.capacity_rows])
@@ -378,7 +385,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
         # -- bucket registry + compile bookkeeping ---------------------------
         self._buckets: Dict[Tuple[int, int], _Bucket] = {}
-        self._ingest_shapes: set = set()
         self.compile_count = 0
         self.cold_start_compile_ms: Optional[float] = None
         self.bucket_compile_ms: Dict[str, float] = {}
@@ -387,6 +393,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         # floor chain scales by — both invalidated/updated on ingest.
         self._mp_full = None
         self._dn_max_cache: Optional[float] = None
+        # perf_counter at which the extract path's dispatch loop ended
+        # (the start of the serve.solve_epilogue span); None otherwise.
+        self._epilogue_pc: Optional[float] = None
         # Cross-request gate state: per-chunk winner histogram + last
         # batch's gated-tile stats (pending device scalar, tile count).
         self._block_hits = np.zeros(max(self._ex_nchunks, 1), np.int64)
@@ -636,10 +645,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             # state so the pad region rewrites what is already there.
             mpad = min(shape_bucket(m), self.capacity_rows - at)
             mpad = max(mpad, m)
-            if (mpad, "u") not in self._ingest_shapes:
-                self._ingest_shapes.add((mpad, "u"))
-                telemetry.registry().counter(
-                    "serve.ingest_compiles").inc(label=str(mpad))
             sdt = np_staging_dtype(self._staging)
             blk = np.ascontiguousarray(
                 self._host_attrs[at:at + mpad], sdt)
@@ -700,8 +705,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             t0 = time.perf_counter()
             self._compile_stream(entry)
             self.compile_count += 1
-            telemetry.registry().counter("serve.bucket_compiles").inc(
-                label=entry.key + "_stream_fallback")
             self.bucket_compile_ms[entry.key + "_stream_fallback"] = \
                 round((time.perf_counter() - t0) * 1e3, 3)
         nq = inp.params.num_queries
@@ -769,57 +772,78 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                                 ) -> Optional[Tuple[TopK, int]]:
         from dmlp_tpu.ops import pallas_fused
         from dmlp_tpu.ops.summaries import note_scan
-        kern, impl = pallas_fused.resolve_topk_kernel(
-            entry.qpad, self._ex_chunk_rows, self.num_attrs, entry.kcap,
-            rung=self._degrade_rung)
-        if kern is None:
-            return None
         nq = inp.params.num_queries
         na = self.num_attrs
-        prec = active_precision(self)  # plan-clamped; outside the jits
-        q = np.zeros((entry.qpad, na), np.float32)
-        q[:nq] = inp.query_attrs
-        q_dev = stage_put(q, self._staging)
         cr = self._ex_chunk_rows
-        order = self._chunk_order()
-        survivors, prune_stats = self._prune_survivors(inp, entry, q_dev)
-        if survivors is not None:
-            # Survivor ∩ hot-first order: the winner-histogram sort
-            # stays the fold order, pruned chunks simply drop out.
-            order = [c for c in order if survivors[c]]
+        with obs_span("serve.solve_stage", qpad=entry.qpad,
+                      **self._rid_args()):
+            kern, impl = pallas_fused.resolve_topk_kernel(
+                entry.qpad, cr, na, entry.kcap, rung=self._degrade_rung)
+            if kern is None:
+                return None
+            prec = active_precision(self)  # plan-clamped; outside the jits
+            q = np.zeros((entry.qpad, na), np.float32)
+            q[:nq] = inp.query_attrs
+            q_dev = stage_put(q, self._staging)
+            order = self._chunk_order()
+            survivors, prune_stats = self._prune_survivors(inp, entry,
+                                                           q_dev)
+            if survivors is not None:
+                # Survivor ∩ hot-first order: the winner-histogram sort
+                # stays the fold order, pruned chunks simply drop out.
+                order = [c for c in order if survivors[c]]
+            self._last_select = "extract"
+            self.last_extract_impl = impl
+            self.last_variant = pallas_fused.variant_stamp(
+                impl, entry.kcap, cr, entry.qpad, na, prec)
         od = oi = None
         gz = None
         ntiles = 0
         scanned = 0
+        dispatches = 0
         item = self._staging_itemsize()
         throttle = ChunkThrottle()
-        self._last_select = "extract"
-        self.last_extract_impl = impl
-        self.last_variant = pallas_fused.variant_stamp(
-            impl, entry.kcap, cr, entry.qpad, na, prec)
+        clock = time.perf_counter
+        # Where the loop's wall time goes: inside kern(...) (the host
+        # dispatching), inside the throttle (blocked on the device), and
+        # the rest (the gate counter's eager ops, bookkeeping).
+        kern_s = wait_s = 0.0
         with obs_span("serve.solve_extract", qpad=entry.qpad,
                       kcap=entry.kcap, impl=impl,
                       carry=self.gate_carry, scheduled=len(order),
-                      **self._rid_args()):
+                      **self._rid_args()) as sp:
+            t_loop = clock()
             for c in order:
                 lo = c * cr
                 nr = min(self.n_real - lo, cr)
                 if nr <= 0:
                     continue
+                t0 = clock()
                 od, oi, iters = kern(q_dev, self._chunks[c], od, oi,
                                      n_real=nr, id_base=lo, kc=entry.kcap,
                                      interpret=self._interpret,
                                      precision=prec)
+                t1 = clock()
                 scanned += nr * na * item
                 z = jnp.sum(iters == 0)
                 gz = z if gz is None else gz + z
                 ntiles += int(np.prod(iters.shape))
+                dispatches += 1
+                t2 = clock()
                 throttle.tick(od)
-                telemetry.sample_memory_now()
+                kern_s += t1 - t0
+                wait_s += clock() - t2
+            self.last_phase_ms["dispatch"] = (clock() - t_loop) * 1e3
+            sp.set(dispatches=dispatches,
+                   kernel_dispatch_ms=round(kern_s * 1e3, 3),
+                   throttle_wait_ms=round(wait_s * 1e3, 3))
         if od is None:
             # Every scheduled chunk was empty (cannot happen with a
             # sound mask, the belt above): fall back to a dense fold.
             return None
+        # Closed by _before_fetch, where SingleChipEngine._run starts
+        # the readback: the epilogue's enqueues run on into _run.
+        self._epilogue_pc = clock()
         self._pending_gate = (gz, ntiles)
         note_scan(self, scanned_bytes=scanned,
                   dense_bytes=self.n_real * na * item,
@@ -830,6 +854,12 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.last_prune_fraction = self.last_prune["pruned_fraction"]
         top = _extract_finalize(od, oi, self._d_labels, k=entry.kcap)
         return top, entry.qpad
+
+    def _before_fetch(self, t_pc: float) -> None:
+        e0, self._epilogue_pc = self._epilogue_pc, None
+        if e0 is not None:
+            obs_trace.complete_at("serve.solve_epilogue", e0, t_pc,
+                                  **self._rid_args())
 
     # -- wide-k multipass serving (ROADMAP item (d)) --------------------------
 
@@ -948,7 +978,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         needed = np.minimum(inp.ks.astype(np.int64), n)
         shortfall = np.asarray(valid_h)[:nq] < needed
         self._mp_hazard = stalled[:nq] | shortfall
-        telemetry.registry().counter("serve.multipass_batches").inc()
         return top, entry.qpad
 
     def _chunk_order(self) -> List[int]:
@@ -968,6 +997,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     def _solve(self, inp: KNNInput) -> Tuple[TopK, int]:
         self.last_phase_ms = {}
         self._pending_iters = []
+        self._epilogue_pc = None
         self.last_extract_impl = self.last_variant = None
         self.last_prune = None
         if inp.params.num_data != self.n_real:
@@ -1024,27 +1054,32 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         return results
 
     def _after_batch(self, results: List[QueryResult]) -> None:
-        if self._pending_gate is not None:
-            gz, ntiles = self._pending_gate
-            self._pending_gate = None
-            try:
-                gated = int(jax.device_get(gz))  # check: allow-host-sync
-                frac = gated / max(ntiles, 1)
-                self.last_gated_fraction = frac
-                reg = telemetry.registry()
-                reg.gauge("serve.gate.gated_fraction").set(round(frac, 6))
-                reg.counter("serve.gate.tiles_total").inc(ntiles)
-                reg.counter("serve.gate.tiles_gated").inc(gated)
-            except Exception:  # check: no-retry — stats never fail a batch
-                pass
-        if self.gate_carry and self._ex_nchunks and results:
-            ids = np.concatenate(
-                [np.asarray(r.neighbor_ids, np.int64) for r in results])
-            ids = ids[ids >= 0]
-            if ids.size:
-                hits = np.bincount(ids // self._ex_chunk_rows,
-                                   minlength=self._ex_nchunks)
-                self._block_hits[:len(hits)] += hits
+        with obs_span("serve.after_batch", **self._rid_args()) as sp:
+            if self._pending_gate is not None:
+                gz, ntiles = self._pending_gate
+                self._pending_gate = None
+                try:
+                    gated = int(
+                        jax.device_get(gz))  # check: allow-host-sync
+                    frac = gated / max(ntiles, 1)
+                    self.last_gated_fraction = frac
+                    reg = telemetry.registry()
+                    reg.gauge("serve.gate.gated_fraction").set(
+                        round(frac, 6))
+                    reg.counter("serve.gate.tiles_total").inc(ntiles)
+                    reg.counter("serve.gate.tiles_gated").inc(gated)
+                    sp.set(gated=gated, tiles=ntiles)
+                except Exception:  # check: no-retry — stats never fail
+                    pass           # a batch
+            if self.gate_carry and self._ex_nchunks and results:
+                ids = np.concatenate(
+                    [np.asarray(r.neighbor_ids, np.int64)
+                     for r in results])
+                ids = ids[ids >= 0]
+                if ids.size:
+                    hits = np.bincount(ids // self._ex_chunk_rows,
+                                       minlength=self._ex_nchunks)
+                    self._block_hits[:len(hits)] += hits
 
     # -- memory-model hooks (ResidentServingCore contract) --------------------
 

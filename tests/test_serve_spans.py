@@ -1,0 +1,263 @@
+"""PR 25's spans and always-on phase timings, by structure and not by
+duration: one micro-batch through a daemon with a Tracer installed
+yields every span of the batch cycle once, tiling it and sharing one
+``batch``; with no sink installed the daemon still answers "queue or
+solve?" from ``stats``; the kernels carry their names."""
+
+from __future__ import annotations
+
+import json
+import re
+import socket
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.engine.single import SingleChipEngine
+from dmlp_tpu.io.grammar import KNNInput, Params
+from dmlp_tpu.obs import telemetry
+from dmlp_tpu.obs import trace as obs_trace
+from dmlp_tpu.serve.daemon import PHASE_HISTOGRAMS, ServeDaemon
+
+NA = 8
+
+#: the batcher thread's spans of one batch cycle, in order
+CYCLE = ["serve.batch_assemble", "serve.micro_batch", "serve.batch_deliver"]
+#: the children that tile serve.micro_batch, in order
+IN_BATCH = ["serve.solve_stage", "serve.solve_extract",
+            "serve.solve_epilogue", "single.fetch", "single.hazard",
+            "single.finalize", "serve.after_batch"]
+NESTED = {"single.dn_max": "single.hazard",
+          "single.repair": "single.finalize"}
+REQUEST = ["parse", "queue", "coalesce", "solve", "finalize", "respond",
+           "write"]
+
+
+def tied_corpus(copies=40, points=60, seed=5) -> KNNInput:
+    """Every point ``copies`` times: a query AT a point finds more rows
+    at distance 0 than the candidate window holds, so the boundary test
+    flags it and the host repair runs."""
+    rng = np.random.default_rng(seed)
+    pts = rng.integers(-8, 9, (points, NA)).astype(np.float64)
+    rows = np.repeat(pts, copies, axis=0)
+    n = len(rows)
+    return KNNInput(Params(n, 0, NA),
+                    rng.integers(0, 4, n).astype(np.int32), rows,
+                    np.zeros(0, np.int32), np.zeros((0, NA)))
+
+
+def ask(port, obj):
+    with socket.create_connection(("127.0.0.1", port), timeout=60) as s:
+        f = s.makefile("rwb")
+        f.write((json.dumps(obj) + "\n").encode())
+        f.flush()
+        return json.loads(f.readline())
+
+
+def query(corpus, rid="", nq=3):
+    return {"op": "query", "id": "q", "k": 4,
+            "queries": corpus.data_attrs[:nq * 40:40].tolist(),
+            **({"rid": rid} if rid else {})}
+
+
+@pytest.fixture(scope="module")
+def traced():
+    """One request = one micro-batch through a daemon on the extract
+    path, traced; the events after warm-up."""
+    corpus = tied_corpus()
+    tracer = obs_trace.install(obs_trace.Tracer())
+    daemon = None
+    try:
+        daemon = ServeDaemon(
+            corpus, EngineConfig(use_pallas=True, select="extract"),
+            warm_buckets=[(3, 4)])
+        daemon.start()
+        mark = len(tracer.events())
+        resp = ask(daemon.port, query(corpus, rid="r-1"))
+        assert resp["ok"], resp
+        stats = ask(daemon.port, {"op": "stats"})["stats"]
+    finally:
+        if daemon is not None:
+            daemon.close()
+        obs_trace.uninstall()
+    events = [e for e in tracer.events() if e.get("ph") == "X"]
+    return {"all": events,
+            "served": [e for e in tracer.events()[mark:]
+                       if e.get("ph") == "X"],
+            "stats": stats}
+
+
+def named(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+@pytest.mark.parametrize(
+    "name", CYCLE + IN_BATCH + sorted(NESTED)
+    + [f"serve.phase.{p}" for p in REQUEST])
+def test_one_micro_batch_yields_the_span_once(traced, name):
+    spans = named(traced["served"], name)
+    if name == "serve.phase.write":      # the stats reply is written too
+        spans = [e for e in spans if e.get("args", {}).get("rid")]
+    assert len(spans) == 1, [e["name"] for e in traced["served"]]
+    assert spans[0]["args"]["batch"] == 1
+
+
+def test_batcher_spans_tile_the_cycle_in_order(traced):
+    cycle = [named(traced["served"], n)[0] for n in CYCLE]
+    assert len({e["tid"] for e in cycle}) == 1          # one thread
+    for a, b in zip(cycle, cycle[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    batch = cycle[1]
+    kids = [named(traced["served"], n)[0] for n in IN_BATCH]
+    assert {e["tid"] for e in kids} == {batch["tid"]}
+    for k in kids:
+        assert inside(k, batch), k["name"]
+    for a, b in zip(kids, kids[1:]):                    # disjoint, in order
+        assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
+    for child, parent in NESTED.items():
+        assert inside(named(traced["served"], child)[0],
+                      named(traced["served"], parent)[0])
+
+
+def test_span_args_say_what_the_work_was(traced):
+    ev = {n: named(traced["served"], n)[0]["args"]
+          for n in CYCLE + IN_BATCH + sorted(NESTED)
+          + ["serve.phase.parse", "serve.phase.respond"]}
+    for n in ("serve.batch_assemble", "serve.batch_deliver"):
+        assert (ev[n]["requests"], ev[n]["queries"]) == (1, 3)
+    assert ev["serve.micro_batch"]["queries"] == 3      # as before PR 25
+    assert ev["serve.solve_stage"]["qpad"] == \
+        ev["serve.micro_batch"]["qpad"]
+    loop = ev["serve.solve_extract"]
+    assert loop["dispatches"] >= 1
+    assert loop["kernel_dispatch_ms"] >= 0 and loop["throttle_wait_ms"] >= 0
+    assert ev["single.hazard"]["rows"] == ev["single.dn_max"]["rows"] == 2400
+    # every query sits on 40 copies of its point: all three are flagged
+    assert ev["single.hazard"]["flagged"] == 3
+    assert ev["single.finalize"]["repairs"] == 3
+    assert ev["single.repair"]["queries"] == 3
+    assert ev["serve.after_batch"]["tiles"] >= 1
+    assert ev["serve.phase.parse"]["queries"] == 3
+    assert ev["serve.phase.parse"]["bytes"] > 0
+    assert ev["serve.phase.parse"]["rid"] == "r-1"
+    assert ev["serve.phase.respond"]["rid"] == "r-1"
+
+
+@pytest.mark.parametrize("name", ["serve.init.host_copy",
+                                  "serve.init.row_hashes",
+                                  "serve.stage_resident",
+                                  "serve.stage_chunks",
+                                  "serve.warmup_bucket"])
+def test_set_up_is_spanned_once_per_daemon(traced, name):
+    spans = named(traced["all"], name)
+    assert len(spans) == 1
+    assert not named(traced["served"], name)    # and before any request
+
+
+def test_warm_up_solves_carry_no_batch(traced):
+    warm = [e for e in named(traced["all"], "single.hazard")
+            if e not in traced["served"]]
+    assert warm and all("batch" not in e["args"] for e in warm)
+
+
+# -- without a tracer -----------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def untraced():
+    """Three requests, one after the other (three micro-batches),
+    through a daemon with neither a Tracer nor a telemetry session."""
+    assert obs_trace.active() is None and not obs_trace.sinks_active()
+    corpus = tied_corpus()
+    daemon = ServeDaemon(
+        corpus, EngineConfig(use_pallas=True, select="extract"),
+        warm_buckets=[(3, 4)])
+    try:
+        daemon.start()
+        null_while_serving = obs_trace.span("serve.micro_batch")
+        for _ in range(3):
+            assert ask(daemon.port, query(corpus))["ok"]
+        stats = ask(daemon.port, {"op": "stats"})["stats"]
+    finally:
+        daemon.close()
+    return {"stats": stats, "span": null_while_serving}
+
+
+def test_no_sink_means_no_span(untraced):
+    assert untraced["span"] is obs_trace.NULL_SPAN
+    assert not telemetry.enabled()
+
+
+@pytest.mark.parametrize("group,key", [(g, k) for g, names in
+                                       PHASE_HISTOGRAMS.items()
+                                       for k, _ in names])
+def test_stats_reports_every_phase_without_a_tracer(untraced, group, key):
+    stats = untraced["stats"]
+    assert stats["requests_completed"] == 3 and stats["batches"] == 3
+    got = stats["phases_ms"][group][key]
+    assert got["count"] == 3        # the requests, or the batches, served
+    assert 0 <= got["p50"] <= got["p95"]
+    assert stats["request_latency_ms"]["count"] == 3
+
+
+def test_phase_timings_exclude_warm_up(traced):
+    """Warm-up solves run through solve_batch too; only the batcher
+    feeds the histograms, so the counts are those of served batches."""
+    parts = traced["stats"]["phases_ms"]["batch"]
+    assert {k: v["count"] for k, v in parts.items()} == {
+        "dispatch": 1, "fetch": 1, "hazard": 1, "finalize": 1}
+
+
+def test_batch_engine_reports_the_hazard_pass():
+    """engine.last_phase_ms: fetch no longer hides the hazard pass."""
+    corpus = tied_corpus()
+    q = corpus.data_attrs[:120:40]
+    inp = KNNInput(Params(corpus.params.num_data, len(q), NA),
+                   corpus.labels, corpus.data_attrs,
+                   np.full(len(q), 4, np.int32), q)
+    eng = SingleChipEngine(EngineConfig())
+    eng.run(inp)
+    assert {"fetch", "hazard", "finalize"} <= set(eng.last_phase_ms)
+    assert all(v >= 0 for v in eng.last_phase_ms.values())
+
+
+# -- kernel names ---------------------------------------------------------------
+
+def _pallas_names(jaxpr) -> list:
+    """The ``name=dmlp_...`` params the printed jaxpr carries (the
+    pallas_call's; the jits around it have other names)."""
+    return re.findall(r"\bname=(dmlp_\w+)", str(jaxpr))
+
+
+@pytest.mark.parametrize("mxu_gate,carry,want", [
+    (True, False, "dmlp_topk_fused_fresh"),
+    (True, True, "dmlp_topk_fused"),
+    (False, False, "dmlp_topk_extract_fresh"),
+    (False, True, "dmlp_topk_extract"),
+])
+def test_extract_kernel_is_named_by_its_form(mxu_gate, carry, want):
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    q, d = jnp.zeros((8, 16)), jnp.zeros((1024, 16))
+    lists = (jnp.zeros((8, 16)), jnp.zeros((8, 16), jnp.int32)) \
+        if carry else (None, None)
+    jaxpr = jax.make_jaxpr(
+        lambda q, d, cd, ci: extract_topk(
+            q, d, cd, ci, n_real=1000, kc=16, interpret=True,
+            mxu_gate=mxu_gate))(q, d, *lists)
+    assert _pallas_names(jaxpr) == [want]
+
+
+def test_distance_kernel_is_named():
+    from dmlp_tpu.ops.pallas_distance import fused_dist_segmin
+    q, d = jnp.zeros((8, 16)), jnp.zeros((1024, 16))
+    jaxpr = jax.make_jaxpr(
+        lambda q, d, i: fused_dist_segmin(q, d, i, interpret=True))(
+            q, d, jnp.arange(1024, dtype=jnp.int32))
+    assert _pallas_names(jaxpr) == ["dmlp_dist_segmin"]

@@ -50,11 +50,19 @@ def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
 
     lists = ((spec((QPAD, KCAP), jnp.float32),
               spec((QPAD, KCAP), jnp.int32)) if carry else (None, None))
-    _extract_topk_jit.lower(
+    compiled = _extract_topk_jit.lower(
         spec((QPAD, NA), jnp.bfloat16), spec((CHUNK, NA), jnp.bfloat16),
         *lists, n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
         kc=KCAP, interpret=False, block_skip=True, mxu_gate=mxu_gate,
         floor=None, precision=precision, **VARIANT).compile()
+    # The custom call's HLO instruction — what a device trace shows as
+    # the event's name — states the kernel's form.
+    name = ("dmlp_topk_fused" if mxu_gate else "dmlp_topk_extract") \
+        + ("" if carry else "_fresh")
+    call = next(line for line in compiled.as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert call.lstrip().removeprefix("ROOT ").startswith(f"%{name}."), \
+        call[:120]
 
 
 @pytest.mark.slow   # ~25 s, nearly all of it XLA:TPU compiling the merge sort
