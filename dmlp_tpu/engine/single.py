@@ -1310,8 +1310,9 @@ class SingleChipEngine:
         self.last_comms = []   # one chip: no collectives (obs.comms)
         merged: List[QueryResult] = [None] * inp.params.num_queries
         # Max squared data-row norm (f64): scales the staging-dtype
-        # perturbation bound of the hazard test — computed on first need
-        # only (an O(N*A) host pass the kcap >= n case never uses).
+        # perturbation bound of the hazard test. Asked of _corpus_dn_max
+        # on first need only (the kcap >= n case never needs it) and
+        # kept for the run's later segments.
         dn_max = None
 
         fetch_ms = hazard_ms = final_ms = 0.0
@@ -1342,8 +1343,10 @@ class SingleChipEngine:
             t1 = _time.perf_counter()
             fetch_ms += (t1 - t0) * 1e3
             # Everything the host does between the readback and the
-            # finalize: the staging-eps hazard test (whose dn_max is a
-            # pass over the WHOLE host corpus) and the label gather.
+            # finalize: the staging-eps hazard test and the label gather.
+            # dn_max_cached says whether the test's corpus-wide scalar was
+            # at hand (a resident engine's, or an earlier segment's) or
+            # cost a pass over the WHOLE host corpus inside this span.
             with obs_span("single.hazard", rows=n, **targs) as hz:
                 dists = None if self.config.exact \
                     else np.asarray(fetched.pop(0), np.float64)[:nq]
@@ -1352,11 +1355,10 @@ class SingleChipEngine:
                 if cols_dev is not None:
                     kth, last = np.asarray(fetched.pop(0),
                                            np.float64)[:, :nq]
-                    if dn_max is None:
-                        with obs_span("single.dn_max", rows=n, **targs):
-                            dn_max = float(np.einsum(
-                                "na,na->n", inp.data_attrs,
-                                inp.data_attrs).max()) if n else 0.0
+                    cached = dn_max is not None
+                    if not cached:
+                        dn_max, cached = self._corpus_dn_max(inp)
+                    hz.set(dn_max_cached=cached)
                     qn = np.einsum("qa,qa->q", sub.query_attrs,
                                    sub.query_attrs)
                     eps = staging_eps(last, qn, dn_max, self._staging,
@@ -1416,6 +1418,19 @@ class SingleChipEngine:
         the serving core (serve.engine.ResidentServingCore) tags the
         micro-batch it is solving."""
         return {}
+
+    def _corpus_dn_max(self, inp: KNNInput) -> Tuple[float, bool]:
+        """Seam: the largest squared data-row norm of ``inp``'s corpus in
+        float64, and whether it was at hand (True) or took a pass now
+        (False). A batch solve has one input and no state: it makes the
+        O(N*A) host pass, once a run. The serving core
+        (serve.engine.ResidentServingCore) owns its corpus and answers
+        from the value it keeps with it."""
+        n = inp.params.num_data
+        with obs_span("single.dn_max", rows=n, **self._rid_args()):
+            return (float(np.einsum("na,na->n", inp.data_attrs,
+                                    inp.data_attrs).max()) if n else 0.0,
+                    False)
 
     def _before_fetch(self, t_pc: float) -> None:
         """Seam: everything of the solve is enqueued and the readback

@@ -274,10 +274,21 @@ class ResidentServingCore:
     def _dn_max(self) -> float:
         if self._dn_max_cache is None:
             a = self._host_attrs[:self.n_real]
-            self._dn_max_cache = float(
-                np.einsum("na,na->n", a, a).max()) if self.n_real \
-                else 0.0
+            # The one pass over the whole float64 host corpus: spanned
+            # where it runs (ResidentEngine: set-up, so no batch).
+            with obs_span("single.dn_max", rows=self.n_real,
+                          **self._rid_args()):
+                self._dn_max_cache = float(
+                    np.einsum("na,na->n", a, a).max()) if self.n_real \
+                    else 0.0
         return self._dn_max_cache
+
+    def _corpus_dn_max(self, inp: KNNInput) -> Tuple[float, bool]:
+        """SingleChipEngine._run's seam: ``inp`` is a micro-batch over
+        the resident corpus (_solve refuses any other), whose value is
+        resident too — equal to the pass over the live rows after loads
+        and appends, never smaller after an overwrite (ingest)."""
+        return self._dn_max(), True
 
     def _note_ingested_norms(self, attrs: np.ndarray) -> None:
         """Append-only ingest keeps the cache incremental: the max
@@ -369,6 +380,11 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.n_real = n
         with obs_span("serve.init.row_hashes", rows=n):
             self._sig_init()
+        # The corpus max-sq-norm the hazard test and the multipass floor
+        # chain scale by is resident like the rows: its whole-corpus pass
+        # is paid here, once a daemon, and ingest keeps it right.
+        self._dn_max_cache: Optional[float] = None
+        self._dn_max()
 
         # -- the resident staged corpus (the streaming paths' view) ----------
         with obs_span("serve.stage_resident", rows=self.capacity_rows,
@@ -389,10 +405,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.cold_start_compile_ms: Optional[float] = None
         self.bucket_compile_ms: Dict[str, float] = {}
         # Wide-k multipass residency: the concatenated resident chunks
-        # (passes 2+ re-sweep it whole) and the corpus max-sq-norm the
-        # floor chain scales by — both invalidated/updated on ingest.
+        # (passes 2+ re-sweep it whole), invalidated on ingest.
         self._mp_full = None
-        self._dn_max_cache: Optional[float] = None
         # perf_counter at which the extract path's dispatch loop ended
         # (the start of the serve.solve_epilogue span); None otherwise.
         self._epilogue_pc: Optional[float] = None

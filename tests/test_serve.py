@@ -136,6 +136,72 @@ def test_ingest_parity_and_no_solve_recompilation():
     assert eng.n_real == 500 + 1 + 7 + 64
 
 
+def _append(corpus, rng):
+    return (None, rng.integers(0, 4, 40).astype(np.int32),
+            rng.uniform(-10, 10, (40, corpus.params.num_attrs)))
+
+
+def _overwrite(scale):
+    def rows(corpus, rng):
+        """Writes over the rows that hold the largest norm (and their
+        neighbours) with rows ``scale`` times their size."""
+        a = corpus.data_attrs
+        top = int(np.einsum("na,na->n", a, a).argmax())
+        at = min(max(top - 3, 0), len(a) - 8)
+        return (at, rng.integers(0, 4, 8).astype(np.int32),
+                a[at:at + 8] * scale)
+    return rows
+
+
+@pytest.mark.parametrize("path", ["stream", "extract"])
+@pytest.mark.parametrize("change,relation", [
+    (None, "=="), (_append, "=="),
+    (_overwrite(0.25), ">"), (_overwrite(3.0), "==")],
+    ids=["load_only", "append", "overwrite_smaller", "overwrite_larger"])
+def test_hazard_norm_is_the_resident_one_and_never_too_small(
+        monkeypatch, path, change, relation):
+    """The hazard test's corpus-wide scalar comes from the resident
+    engine, not from a pass a batch: after a load or an append it is
+    the float64 number a pass over the live rows gives; after an
+    overwrite it may be larger (eps widens, answers do not move) and
+    is never smaller. Served answers equal the solo solve's."""
+    from dmlp_tpu.engine import single
+    corpus = make_corpus(n=700, na=5, seed=12)
+    config = extract_config() if path == "extract" else EngineConfig()
+    eng = ResidentEngine(corpus, config, capacity=1024)
+    rng = np.random.default_rng(44)
+    labels, attrs = corpus.labels, corpus.data_attrs.copy()
+    if change is not None:
+        at, newl, newa = change(corpus, rng)
+        eng.ingest(newl, newa, start=at)
+        if at is None:
+            labels = np.concatenate([labels, newl])
+            attrs = np.vstack([attrs, newa])
+        else:
+            labels = labels.copy()
+            labels[at:at + len(newl)] = newl
+            attrs[at:at + len(newa)] = newa
+    live = KNNInput(Params(len(labels), 0, 5), labels, attrs,
+                    np.zeros(0, np.int32), np.zeros((0, 5)))
+    used = []
+    real_eps = single.staging_eps
+
+    def spy(last, qn, dn_max, *rest):
+        used.append(dn_max)
+        return real_eps(last, qn, dn_max, *rest)
+    q = attrs[np.linspace(0, len(attrs) - 1, 9).astype(int)]
+    ks = rng.integers(1, 9, 9).astype(np.int32)
+    with monkeypatch.context() as m:        # the served solve only
+        m.setattr(single, "staging_eps", spy)
+        got = format_results(eng.solve_batch(q, ks))
+    true_max = float(np.einsum("na,na->n", attrs, attrs).max())
+    assert len(used) == 1
+    assert used[0] >= true_max
+    assert (used[0] == true_max) if relation == "==" \
+        else (used[0] > true_max)
+    assert got == solo_and_golden(live, q, ks, config)
+
+
 def test_ingest_capacity_error():
     eng = ResidentEngine(make_corpus(n=500), EngineConfig(),
                          capacity=512)

@@ -30,8 +30,9 @@ CYCLE = ["serve.batch_assemble", "serve.micro_batch", "serve.batch_deliver"]
 IN_BATCH = ["serve.solve_stage", "serve.solve_extract",
             "serve.solve_epilogue", "single.fetch", "single.hazard",
             "single.finalize", "serve.after_batch"]
-NESTED = {"single.dn_max": "single.hazard",
-          "single.repair": "single.finalize"}
+NESTED = {"single.repair": "single.finalize"}
+#: the whole-corpus norm pass: set-up's since PR 26, no batch's child
+DN_MAX = "single.dn_max"
 REQUEST = ["parse", "queue", "coalesce", "solve", "finalize", "respond",
            "write"]
 
@@ -139,7 +140,9 @@ def test_span_args_say_what_the_work_was(traced):
     loop = ev["serve.solve_extract"]
     assert loop["dispatches"] >= 1
     assert loop["kernel_dispatch_ms"] >= 0 and loop["throttle_wait_ms"] >= 0
-    assert ev["single.hazard"]["rows"] == ev["single.dn_max"]["rows"] == 2400
+    assert ev["single.hazard"]["rows"] == 2400
+    # the hazard test read the engine's resident max row norm
+    assert ev["single.hazard"]["dn_max_cached"] is True
     # every query sits on 40 copies of its point: all three are flagged
     assert ev["single.hazard"]["flagged"] == 3
     assert ev["single.finalize"]["repairs"] == 3
@@ -153,6 +156,7 @@ def test_span_args_say_what_the_work_was(traced):
 
 @pytest.mark.parametrize("name", ["serve.init.host_copy",
                                   "serve.init.row_hashes",
+                                  DN_MAX,
                                   "serve.stage_resident",
                                   "serve.stage_chunks",
                                   "serve.warmup_bucket"])
@@ -166,6 +170,19 @@ def test_warm_up_solves_carry_no_batch(traced):
     warm = [e for e in named(traced["all"], "single.hazard")
             if e not in traced["served"]]
     assert warm and all("batch" not in e["args"] for e in warm)
+
+
+def test_the_norm_pass_is_set_up_and_no_hazard_span_holds_it(traced):
+    """The resident engine's one pass over the whole corpus runs while
+    the engine is built: before warm-up's solves, outside every
+    ``single.hazard`` (warm-up's too), tagged with no batch."""
+    (pas,) = named(traced["all"], DN_MAX)
+    assert pas["args"] == {"rows": 2400}
+    hazards = named(traced["all"], "single.hazard")
+    assert len(hazards) >= 2                    # warm-up's and the served
+    assert not any(inside(pas, h) for h in hazards)
+    assert all(pas["ts"] + pas["dur"] <= h["ts"] for h in hazards)
+    assert all(h["args"]["dn_max_cached"] is True for h in hazards)
 
 
 # -- without a tracer -----------------------------------------------------------
@@ -226,6 +243,35 @@ def test_batch_engine_reports_the_hazard_pass():
     eng.run(inp)
     assert {"fetch", "hazard", "finalize"} <= set(eng.last_phase_ms)
     assert all(v >= 0 for v in eng.last_phase_ms.values())
+
+
+def test_batch_engine_makes_the_norm_pass_once_a_run_inside_hazard():
+    """A batch solve owns no corpus: each run computes the value on
+    first need, inside that segment's ``single.hazard``
+    (``dn_max_cached`` false); the run's later segment reuses it. A k
+    beyond the kernel's window routes the run into two segments."""
+    corpus = tied_corpus()
+    q = corpus.data_attrs[:6 * 40:40]
+    ks = np.array([4, 700, 2, 8, 640, 1], np.int32)
+    inp = KNNInput(Params(corpus.params.num_data, len(q), NA),
+                   corpus.labels, corpus.data_attrs, ks, q)
+    eng = SingleChipEngine(EngineConfig(use_pallas=True, select="extract"))
+    tracer = obs_trace.install(obs_trace.Tracer())
+    try:
+        eng.run(inp)
+        first = len(tracer.events())
+        eng.run(inp)
+    finally:
+        obs_trace.uninstall()
+    for events in (tracer.events()[:first], tracer.events()[first:]):
+        spans = [e for e in events if e.get("ph") == "X"]
+        (pas,) = named(spans, DN_MAX)
+        assert pas["args"] == {"rows": 2400}
+        first_seg, second_seg = named(spans, "single.hazard")
+        assert eng.last_hetk == (4, 2)
+        assert first_seg["args"]["dn_max_cached"] is False
+        assert second_seg["args"]["dn_max_cached"] is True
+        assert inside(pas, first_seg)
 
 
 # -- kernel names ---------------------------------------------------------------
