@@ -11,6 +11,8 @@ binary (``oracle_capture/oracle_4.out``):
   serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
                                                      stats, SIGTERM drain
   mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
+  mesh serve  python -m dmlp_tpu.serve --mesh 4x1    the same requests, the
+                                                     corpus sharded
 
 A chip serves one process at a time, so this parent never initialises a
 JAX backend: every phase that needs the chip is one child, run to its end
@@ -257,14 +259,18 @@ def read_queries(c: Config, count: int) -> Tuple[List[int], List[list]]:
     return ks, rows
 
 
-def phase_serve(c: Config) -> List[str]:
-    """Daemon up, a few query requests in input order, one stats, SIGTERM
-    drain; answers compared with the matching oracle lines."""
+def phase_serve(c: Config, mesh: Optional[List[int]] = None) -> List[str]:
+    """Daemon up (mesh-resident over ``mesh`` when given), a few query
+    requests in input order, one stats, SIGTERM drain; answers compared
+    with the matching oracle lines."""
     from dmlp_tpu.serve import client as sc  # imports jax, touches no device
     nreq = min(SERVE_REQUESTS,
                max(c.cfg.num_queries // SERVE_REQUEST_QUERIES, 1))
     per = min(SERVE_REQUEST_QUERIES, c.cfg.num_queries)
-    ready_path, errlog = c.log("serve.ready.json"), c.log("serve.err")
+    name = "serve.mesh" if mesh else "serve"
+    mesh_args = ["--mesh", f"{mesh[0]}x{mesh[1]}", "--mesh-merge",
+                 "allgather"] if mesh else []
+    ready_path, errlog = c.log(f"{name}.ready.json"), c.log(f"{name}.err")
     if os.path.exists(ready_path):
         os.remove(ready_path)
     t0 = time.monotonic()
@@ -272,7 +278,7 @@ def phase_serve(c: Config) -> List[str]:
         proc = subprocess.Popen(
             [sys.executable, "-m", "dmlp_tpu.serve", "--corpus",
              c.input_path, "--pallas", "--ready-file", ready_path,
-             "--warm-buckets", f"{per}x{c.cfg.max_k}"],
+             "--warm-buckets", f"{per}x{c.cfg.max_k}"] + mesh_args,
             stdout=subprocess.DEVNULL, stderr=err, env=child_env(),
             cwd=REPO)
     bad: List[str] = []
@@ -302,7 +308,7 @@ def phase_serve(c: Config) -> List[str]:
         sc.sigterm_drain(proc, timeout_s=120, errlog=errlog)
         if "drained clean" not in tail(errlog, 5):
             bad.append("daemon exited 0 without 'drained clean'")
-        say(f"  serve: {nreq} requests x {per} queries; wall to ready "
+        say(f"  {name}: {nreq} requests x {per} queries; wall to ready "
             f"{t_ready - t0:.1f} s (warm-up "
             f"{ready.get('cold_start_compile_ms')} ms), requests + stats "
             f"{t_served - t_ready:.2f} s, drain "
@@ -311,8 +317,9 @@ def phase_serve(c: Config) -> List[str]:
         say(stamp_line(stats.get("device") or {},
                        stats.get("compile_cache") or {}))
         for where, doc in (("ready file", ready), ("stats reply", stats)):
+            # the mesh engines solve without the degrade ladder
             bad += [f"{where}: {m}" for m in check_stamp(
-                doc.get("device"), None, True, c.cfg.num_data)]
+                doc.get("device"), mesh, mesh is None, c.cfg.num_data)]
         # every bucket the daemon built, warmed or served
         bad += [f"bucket {k} took path {p}" for k, p in sorted(
             stats["engine"]["paths"].items()) if p != "extract"]
@@ -343,9 +350,10 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
     chips = stamp.get("device_count", 0)
     shard = -(-c.cfg.num_data // 4)
     if chips < 4:
-        say(f"  sharded, ring at --mesh 4,1: not run: {chips} chip(s)")
+        say(f"  sharded, ring, serve at --mesh 4,1: not run: {chips} "
+            "chip(s)")
     elif shard <= EngineConfig.AUTO_SELECT_THRESHOLD:
-        say(f"  sharded, ring at --mesh 4,1: not run: a {shard}-row "
+        say(f"  sharded, ring, serve at --mesh 4,1: not run: a {shard}-row "
             "shard is below the size at which the engine selects the "
             "kernel")
     else:
@@ -353,6 +361,8 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
             bad, _ = phase_solve(c, mode, ["--mode", mode, "--mesh", "4,1"],
                                  [4, 1], ladder=False)
             misses += [f"config {config_id} {mode} 4,1: {m}" for m in bad]
+        misses += [f"config {config_id} serve --mesh 4x1: {m}"
+                   for m in phase_serve(c, [4, 1])]
     return misses, stamp
 
 

@@ -444,7 +444,9 @@ class ShardedEngine:
                 rsh = NamedSharding(self.mesh, P())
                 qsh = NamedSharding(self.mesh, P(QUERY_AXIS, None))
 
-                def merged(cd, ci, lab_g):
+                # Named like the shard_map branch's program: a device
+                # trace finds either as jit_dmlp_mesh_merge.
+                def dmlp_mesh_merge(cd, ci, lab_g):
                     qpad = cd.shape[1]
                     md = jnp.moveaxis(cd, 0, 1).reshape(qpad, -1)
                     mi = jnp.moveaxis(ci, 0, 1).reshape(qpad, -1)
@@ -455,7 +457,7 @@ class ShardedEngine:
                     return select_topk(md, ml, mi, k)
 
                 self._fns[key] = jax.jit(
-                    merged, in_shardings=(csh3, csh3, rsh),
+                    dmlp_mesh_merge, in_shardings=(csh3, csh3, rsh),
                     out_shardings=TopK(qsh, qsh, qsh))
                 return self._fns[key]
 
@@ -466,12 +468,21 @@ class ShardedEngine:
                     return allgather_merge_topk(top, k, DATA_AXIS)
                 return ring_allreduce_topk(top, k, DATA_AXIS)
 
-            self._fns[key] = jax.jit(shard_map(
+            sharded = shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(DATA_AXIS, QUERY_AXIS, None),
                           P(DATA_AXIS, QUERY_AXIS, None), P()),
                 out_specs=P(QUERY_AXIS, None),
-                check_vma=False))
+                check_vma=False)
+
+            # The jit's name is the device trace's handle on the merge:
+            # the module runs as jit_dmlp_mesh_merge on every chip, and
+            # its all-gather (or ring permutes) and re-select fusions
+            # are the XLA Ops inside that module's intervals.
+            def dmlp_mesh_merge(cd, ci, lab_g):
+                return sharded(cd, ci, lab_g)
+
+            self._fns[key] = jax.jit(dmlp_mesh_merge)
         return self._fns[key]
 
     # -- heterogeneous-k outlier programs (mesh form of single's router) ----

@@ -24,10 +24,11 @@ ways a persistent multi-chip server needs:
   golden oracle.
 - **Resident per-(shard, chunk) summaries.** The pruned two-stage
   solve's block summaries (PR 13) are built once over the shard-local
-  chunk ranges and kept resident; each micro-batch scores them
-  (host-side — the summaries are O(blocks * a)) into per-chunk live
-  masks, so chunks every shard pruned are never dispatched at all.
-  Ingest rebuilds exactly the touched blocks' summaries.
+  chunk ranges and kept resident, replicated over the mesh; each
+  micro-batch scores them on the devices (the single-chip resident
+  engine's scorer) into per-chunk live masks, so chunks every shard
+  pruned are never dispatched at all. Ingest rebuilds exactly the
+  touched blocks' summaries.
 - **Shard-routed ingest.** Appended rows land at their global row
   positions — i.e. in the owning shard's span of the touched chunk
   buffers — via a full restage of exactly those fixed-shape chunk
@@ -45,6 +46,7 @@ inside it). Both layouts share the one global-row-id contract.
 from __future__ import annotations
 
 import math
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -165,12 +167,16 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             self._interpret = True
 
         # -- host originals (the float64 finalize rescore reads these) -------
-        self._host_attrs = np.zeros((self.capacity_rows, na), np.float64)
-        self._host_attrs[:n] = corpus.data_attrs
-        self._host_labels = np.full(self.capacity_rows, -1, np.int32)
-        self._host_labels[:n] = corpus.labels
+        with obs_span("fleet.init.host_copy", rows=self.capacity_rows,
+                      na=na):
+            self._host_attrs = np.zeros((self.capacity_rows, na),
+                                        np.float64)
+            self._host_attrs[:n] = corpus.data_attrs
+            self._host_labels = np.full(self.capacity_rows, -1, np.int32)
+            self._host_labels[:n] = corpus.labels
         self.n_real = n
-        self._sig_init()
+        with obs_span("fleet.init.row_hashes", rows=n):
+            self._sig_init()
         # Corpus max squared norm for the boundary-repair eps — cached
         # (an O(n*a) host pass per micro-batch would sit in every
         # request's tail latency at corpus scale), updated
@@ -192,9 +198,11 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             self._stage_chunks()
         else:
             self._ensure_monolithic()
+        self._check_placement()
 
         # -- resident per-(shard, chunk) summaries ---------------------------
         self._summ = None
+        self._summ_dev = None
         self.summary_rebuilds = 0
         self.last_prune_fraction = None
         if self._chunks is not None:
@@ -219,6 +227,25 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         from dmlp_tpu.obs.run import rows_per_device
         return rows_per_device(self._chunks if self._chunks is not None
                                else [self._mono[0]])
+
+    def _check_placement(self) -> None:
+        """Refuse a placement that leaves a mesh device without its
+        share: every device of the mesh holds the same number of
+        resident rows (its shard's chunk buffers, or its slice of the
+        monolithic layout) and no device outside the mesh holds any. A
+        mesh daemon whose corpus sits on one device would still answer
+        exactly, so nothing downstream would notice."""
+        r, _ = self.mesh.devices.shape
+        want = (self._nchunks * self._chunk_rows
+                if self._chunks is not None else self._shard_rows)
+        rows = self.corpus_rows_per_device()
+        mesh_ids = {str(d.id) for d in self.mesh.devices.flat}
+        if set(rows) != mesh_ids or any(v != want for v in rows.values()):
+            raise RuntimeError(
+                f"mesh {list(self.mesh.devices.shape)} placement refused: "
+                f"each of devices {sorted(mesh_ids, key=int)} must hold "
+                f"{want} resident rows (capacity {self.capacity_rows} "
+                f"over {r} data shards), rows per device are {rows}")
 
     # -- resident staging -----------------------------------------------------
 
@@ -292,6 +319,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         with obs_span("fleet.summary_build", blocks=r * self._nchunks):
             self._summ = osum.build_summaries(self._host_attrs,
                                               self._block_ranges())
+            self._stage_summaries()
         telemetry.registry().gauge("prune.summary_blocks").set(
             r * self._nchunks)
 
@@ -309,30 +337,40 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             b = rr * self._nchunks + t
             osum.update_block(self._summ, b, self._host_attrs[lo:hi],
                               lo_hi=(lo, hi))
+        self._stage_summaries()
         self.summary_rebuilds += len(blocks)
         telemetry.registry().counter("prune.summary_rebuilds").inc(
             len(blocks))
 
-    def _prune_live(self, inp: KNNInput):
-        """Per-micro-batch stage 1: score the RESIDENT summaries (host
-        f64 — they are tiny) into an (R, T) live mask + stats, or
-        (None, None) for a dense fold. Sound per ops.summaries: a
-        pruned block provably contributes nothing below the staging-eps
-        margin, and the exact stage is unchanged."""
+    def _put_resident(self, value):
+        return jax.device_put(value, self._rsh)
+
+    def _prune_live(self, inp: KNNInput, entry: _MeshBucket, q_dev):
+        """Per-micro-batch stage 1: score the RESIDENT summaries on the
+        devices (the single-chip resident engine's scorer over the
+        mesh-replicated copies; on the host in float64 the pass cost
+        seconds a batch at corpus scale) into an (R, T) live mask +
+        stats, or (None, None) for a dense fold. Sound per
+        ops.summaries: a pruned block provably contributes nothing
+        below the staging-eps margin, and the exact stage is
+        unchanged."""
         from dmlp_tpu.ops import summaries as osum
-        if (self._summ is None or not self.config.exact
+        if (self._summ_dev is None or not self.config.exact
                 or not osum.prune_enabled()
                 or inp.params.num_queries == 0):
             return None, None
         r, _ = self.mesh.devices.shape
-        with obs_span("fleet.prune_score", blocks=r * self._nchunks,
-                      **self._rid_args()):
-            keep, stats = osum.prune_mask(inp.query_attrs, inp.ks,
-                                          self._summ,
-                                          staging=self._staging,
-                                          precision=self._active_prec())
-        self.last_prune_fraction = stats["pruned_fraction"]
-        return keep.reshape(r, self._nchunks), stats
+        keep = self._score_summaries(inp, entry.qpad, q_dev,
+                                     "fleet.prune_score",
+                                     r * self._nchunks)
+        if not keep.any():
+            return None, None   # belt: score_blocks keeps >= 1 block
+        total = int(np.count_nonzero(self._summ.counts > 0))
+        pruned = total - int(np.count_nonzero(keep))
+        self.last_prune_fraction = round(pruned / total, 6) if total \
+            else 0.0
+        return keep.reshape(r, self._nchunks), {
+            "blocks_total": total, "blocks_pruned": pruned}
 
     # -- shape buckets --------------------------------------------------------
 
@@ -417,13 +455,15 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         from dmlp_tpu.ops.summaries import note_scan
         r, c = self.mesh.devices.shape
         k, cr = entry.kcap, self._chunk_rows
-        impl = self._extract_impl("extract", entry.qloc, cr,
-                                  self.num_attrs, k)
-        prec = self._active_prec()  # resolved outside the jits (R2)
-        q_dev = self._stage_queries(inp, entry.qpad)
-        keep_m, prune_stats = self._prune_live(inp)
-        cd, ci = self._chunk_init_fn(r, entry.qpad, k)()
-        step = self._chunk_fold_fn(k, self._interpret, impl, prec)
+        with obs_span("fleet.stage_queries", qpad=entry.qpad,
+                      **self._rid_args()):
+            impl = self._extract_impl("extract", entry.qloc, cr,
+                                      self.num_attrs, k)
+            prec = self._active_prec()  # resolved outside the jits (R2)
+            q_dev = self._stage_queries(inp, entry.qpad)
+            cd, ci = self._chunk_init_fn(r, entry.qpad, k)()
+            step = self._chunk_fold_fn(k, self._interpret, impl, prec)
+        keep_m, prune_stats = self._prune_live(inp, entry, q_dev)
         item = np.dtype(self._np_dtype()).itemsize
         # Pre-walk the fold schedule so the one-time dispatch record
         # can claim the count that will ACTUALLY dispatch — claiming
@@ -434,18 +474,21 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # tighten before the cold chunks' tiles reach the MXU gate.
         schedule = []
         scanned = 0
-        for t in self._chunk_order():
-            live_col = None if keep_m is None else keep_m[:, t]
-            spans = [self._block_span(rr, t) for rr in range(r)]
-            real = [hi > lo for lo, hi in spans]
-            if not any(real):
-                continue            # capacity tail: no resident rows yet
-            if live_col is not None and not (live_col & real).any():
-                continue            # every shard pruned this chunk
-            for rr, (lo, hi) in enumerate(spans):
-                if hi > lo and (live_col is None or live_col[rr]):
-                    scanned += (hi - lo) * self.num_attrs * item
-            schedule.append((t, live_col))
+        with obs_span("fleet.fold_schedule", chunks=self._nchunks,
+                      carry=self.gate_carry, **self._rid_args()) as sp:
+            for t in self._chunk_order():
+                live_col = None if keep_m is None else keep_m[:, t]
+                spans = [self._block_span(rr, t) for rr in range(r)]
+                real = [hi > lo for lo, hi in spans]
+                if not any(real):
+                    continue        # capacity tail: no resident rows yet
+                if live_col is not None and not (live_col & real).any():
+                    continue        # every shard pruned this chunk
+                for rr, (lo, hi) in enumerate(spans):
+                    if hi > lo and (live_col is None or live_col[rr]):
+                        scanned += (hi - lo) * self.num_attrs * item
+                schedule.append((t, live_col))
+            sp.set(scheduled=len(schedule))
         dispatched = 0
         throttle = ChunkThrottle()
         mi = MeasuredIters(self, "fleet.chunk_fold",
@@ -454,10 +497,17 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         self._last_select = "extract"
         gz = None
         ntiles = 0
+        clock = time.perf_counter
+        # Where the loop's wall time goes: inside step(...) (the host
+        # dispatching the shard_map fold), inside the throttle (blocked
+        # on the devices), and the rest (the live-mask put, the gate
+        # counter's eager ops, the memory sample, bookkeeping).
+        step_s = wait_s = 0.0
         with obs_span("fleet.solve_resident", qpad=entry.qpad, kcap=k,
                       chunks=self._nchunks, scheduled=len(schedule),
                       impl=impl, mesh=[r, c],
-                      carry=self.gate_carry, **self._rid_args()):
+                      carry=self.gate_carry, **self._rid_args()) as sp:
+            t_loop = clock()
             for t, live_col in schedule:
                 lv = self._ones_live if live_col is None \
                     else jax.device_put(np.asarray(live_col, np.int32),
@@ -467,8 +517,10 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                         step, (cd, ci, self._chunks[t], q_dev,
                                self._sc_dev[t], lv),
                         count=len(schedule), site="fleet.chunk_fold")
+                t0 = clock()
                 cd, ci, its = step(cd, ci, self._chunks[t], q_dev,
                                    self._sc_dev[t], lv)
+                t1 = clock()
                 mi.add(its)
                 # Gate effectiveness: a 0-iteration tile was gated (or
                 # skip-gated) outright — summed on device, read back
@@ -478,29 +530,52 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                 gz = z if gz is None else gz + z
                 ntiles += math.prod(its.shape)
                 dispatched += 1
+                t2 = clock()
                 throttle.tick(cd)
+                step_s += t1 - t0
+                wait_s += clock() - t2
                 telemetry.sample_memory_now()
-        mi.done()
-        self._pending_gate = (gz, ntiles) if gz is not None else None
-        blocks_total = sum(1 for rr in range(r)
-                           for t in range(self._nchunks)
-                           if self._block_span(rr, t)[1]
-                           > self._block_span(rr, t)[0])
-        note_scan(self, scanned_bytes=scanned,
-                  dense_bytes=self.n_real * self.num_attrs * item,
-                  blocks_total=(prune_stats or {}).get("blocks_total",
-                                                       blocks_total),
-                  blocks_pruned=(prune_stats or {}).get("blocks_pruned",
-                                                        0))
-        self.last_comms = engine_comms(self._merge_strategy, (r, c),
-                                       entry.qpad // c, k)
-        merge_fn = self._chunk_merge_fn(k)
-        obs_counters.record_dispatch(merge_fn, (cd, ci, self._lab_dev),
-                                     site="fleet.chunk_merge")
+            loop_ms = (clock() - t_loop) * 1e3
+            sp.set(dispatches=dispatched,
+                   kernel_dispatch_ms=round(step_s * 1e3, 3),
+                   throttle_wait_ms=round(wait_s * 1e3, 3))
+            mi.done()
+            self._pending_gate = (gz, ntiles) if gz is not None else None
+            blocks_total = sum(1 for rr in range(r)
+                               for t in range(self._nchunks)
+                               if self._block_span(rr, t)[1]
+                               > self._block_span(rr, t)[0])
+            note_scan(self, scanned_bytes=scanned,
+                      dense_bytes=self.n_real * self.num_attrs * item,
+                      blocks_total=(prune_stats or {}).get(
+                          "blocks_total", blocks_total),
+                      blocks_pruned=(prune_stats or {}).get(
+                          "blocks_pruned", 0))
+            self.last_comms = engine_comms(self._merge_strategy, (r, c),
+                                           entry.qpad // c, k)
+            merge_fn = self._chunk_merge_fn(k)
+            obs_counters.record_dispatch(merge_fn,
+                                         (cd, ci, self._lab_dev),
+                                         site="fleet.chunk_merge")
+        # The queued folds finish here, so that fleet.merge times the
+        # merge program alone (its dispatch, the collective, the
+        # re-select) and not the tail of the fold.
+        # (blocked on whether or not a tracer is installed: the phase
+        # timings behind `stats` are the same numbers the spans carry.)
+        t_drain = clock()
+        with obs_span("fleet.merge_drain", dispatches=dispatched,
+                      **self._rid_args()):
+            jax.block_until_ready((cd, ci))  # check: allow-host-sync
+        merge_bytes = sum(t.bytes_total for t in self.last_comms)
+        telemetry.registry().counter("fleet.merge_bytes").inc(merge_bytes)
+        t_merge = clock()
         with obs_span("fleet.merge", mesh=[r, c], kc=k,
-                      **self._rid_args()) as sp:
+                      strategy=self._merge_strategy, bytes=merge_bytes,
+                      **self._rid_args()):
             top = merge_fn(cd, ci, self._lab_dev)
-            sp.fence(top.dists)
+            jax.block_until_ready(top.dists)  # check: allow-host-sync
+        self.last_phase_ms["dispatch"] = loop_ms + (t_merge - t_drain) * 1e3
+        self.last_phase_ms["merge"] = (clock() - t_merge) * 1e3
         return top
 
     def _chunk_order(self) -> List[int]:
@@ -510,42 +585,31 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         aggregate winner count (hottest first) when gate carry-over is
         on, natural otherwise. Stable sort: cold chunks keep their
         natural relative order."""
-        with obs_span("fleet.fold_schedule", chunks=self._nchunks,
-                      carry=self.gate_carry, **self._rid_args()):
-            if not self.gate_carry:
-                return list(range(self._nchunks))
-            heat = self._block_hits.sum(axis=0)
-            return list(np.argsort(-heat[:self._nchunks], kind="stable"))
+        if not self.gate_carry:
+            return list(range(self._nchunks))
+        heat = self._block_hits.sum(axis=0)
+        return list(np.argsort(-heat[:self._nchunks], kind="stable"))
 
     def _after_batch(self, results: List[QueryResult]) -> None:
         """Cross-request gate bookkeeping (the single-chip resident
         engine's discipline, per-shard): flush the pending gated-tile
         readback, then credit each winner row's owning (shard, chunk)
         block in the carried histogram."""
-        if self._pending_gate is not None:
-            gz, ntiles = self._pending_gate
-            self._pending_gate = None
-            try:
-                gated = int(jax.device_get(gz))  # check: allow-host-sync
-                frac = gated / max(ntiles, 1)
-                self.last_gated_fraction = frac
-                reg = telemetry.registry()
-                reg.gauge("serve.gate.gated_fraction").set(round(frac, 6))
-                reg.counter("serve.gate.tiles_total").inc(ntiles)
-                reg.counter("serve.gate.tiles_gated").inc(gated)
-            except Exception:  # check: no-retry — stats never fail a batch
-                pass
-        if self.gate_carry and self._nchunks and results:
-            ids = np.concatenate(
-                [np.asarray(r.neighbor_ids, np.int64) for r in results])
-            ids = ids[ids >= 0]
-            if ids.size:
-                r, _ = self.mesh.devices.shape
-                rr = ids // self._shard_rows
-                t = (ids - rr * self._shard_rows) // self._chunk_rows
-                hits = np.bincount(rr * self._nchunks + t,
-                                   minlength=r * self._nchunks)
-                self._block_hits += hits.reshape(r, self._nchunks)
+        with obs_span("fleet.after_batch", **self._rid_args()) as sp:
+            flush_measured_iters(self)
+            self._flush_pending_gate(sp)
+            if self.gate_carry and self._nchunks and results:
+                ids = np.concatenate(
+                    [np.asarray(r.neighbor_ids, np.int64)
+                     for r in results])
+                ids = ids[ids >= 0]
+                if ids.size:
+                    r, _ = self.mesh.devices.shape
+                    rr = ids // self._shard_rows
+                    t = (ids - rr * self._shard_rows) // self._chunk_rows
+                    hits = np.bincount(rr * self._nchunks + t,
+                                       minlength=r * self._nchunks)
+                    self._block_hits += hits.reshape(r, self._nchunks)
 
     def _solve_resident_stream(self, inp: KNNInput, entry: _MeshBucket):
         """Streaming fallback on the resident MONOLITHIC arrays: the
@@ -553,11 +617,15 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         from dmlp_tpu.ops.summaries import note_scan
         self._ensure_monolithic()
         d_attrs, d_labels, d_ids = self._mono
-        q_dev = self._stage_queries(inp, entry.qpad)
+        with obs_span("fleet.stage_queries", qpad=entry.qpad,
+                      **self._rid_args()):
+            q_dev = self._stage_queries(inp, entry.qpad)
+        t0 = time.perf_counter()
         with obs_span("fleet.solve_stream", qpad=entry.qpad,
                       kcap=entry.kcap, **self._rid_args()):
             top = self.solve_global(d_attrs, d_labels, d_ids, q_dev,
                                     kmax=entry.kb)
+        self.last_phase_ms["dispatch"] = (time.perf_counter() - t0) * 1e3
         dense = self.n_real * self.num_attrs \
             * np.dtype(self._np_dtype()).itemsize
         note_scan(self, scanned_bytes=dense, dense_bytes=dense,
@@ -593,25 +661,30 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             top = self._solve_resident_chunks(inp, entry)
         else:
             top = self._solve_resident_stream(inp, entry)
-        telemetry.sample_memory_now()
         self.last_repairs = 0
+        clock = time.perf_counter
+        t0 = clock()
         with obs_span("fleet.fetch", **self._rid_args()):
+            telemetry.sample_memory_now()
             od, ol, oi = resilient_get((top.dists, top.labels, top.ids),
                                        site="sharded.fetch")
             dists = np.asarray(od, np.float64)[:nq]
             labels = ol[:nq]
             ids = oi[:nq]
-        with obs_span("fleet.finalize", exact=self.config.exact,
-                      **self._rid_args()):
-            results = finalize_host(dists, labels, ids, inp.ks,
-                                    inp.query_attrs, inp.data_attrs,
-                                    exact=self.config.exact)
+        t1 = clock()
+        # What the host does between the readback and the finalize: the
+        # eps-widened boundary test. dn_max_cached says whether the
+        # corpus-wide scalar was at hand or cost a pass over the WHOLE
+        # float64 host corpus inside this span (a daemon's first batch).
+        suspects = np.zeros(0, np.int64)
+        with obs_span("fleet.hazard", rows=n, **self._rid_args()) as hz:
             if self._last_select in ("sort", "topk", "seg", "extract") \
                     and dists.shape[1] < n:
                 # Same per-shard-truncation hazard test as the batch
                 # mesh engines (engine.sharded._run): the merged kcap-th
                 # bounds every shard's horizon, so the eps-widened
                 # boundary test covers per-shard truncation too.
+                hz.set(dn_max_cached=self._dn_max_cache is not None)
                 dn_max = self._dn_max()
                 qn = np.einsum("qa,qa->q", inp.query_attrs,
                                inp.query_attrs)
@@ -625,10 +698,23 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                     eps = eps + lowp_eps("bf16", qn, dn_max)
                 suspects = np.nonzero(
                     boundary_overflow(dists, inp.ks, eps))[0]
-                if suspects.size:
+                hz.set(flagged=int(suspects.size))
+        t2 = clock()
+        with obs_span("fleet.finalize", exact=self.config.exact,
+                      **self._rid_args()) as sp:
+            results = finalize_host(dists, labels, ids, inp.ks,
+                                    inp.query_attrs, inp.data_attrs,
+                                    exact=self.config.exact)
+            if suspects.size:
+                with obs_span("fleet.repair", queries=int(suspects.size),
+                              **self._rid_args()):
                     repair_boundary_overflow(results, suspects, inp)
-                    self.last_repairs += int(suspects.size)
-        flush_measured_iters(self)
+                self.last_repairs += int(suspects.size)
+            sp.set(repairs=int(suspects.size))
+        t3 = clock()
+        self.last_phase_ms["fetch"] = (t1 - t0) * 1e3
+        self.last_phase_ms["hazard"] = (t2 - t1) * 1e3
+        self.last_phase_ms["finalize"] = (t3 - t2) * 1e3
         self._after_batch(results)
         return results
 
@@ -705,6 +791,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         return new_n
 
     # -- memory-model hooks (ResidentServingCore contract) --------------------
+
+    mem_per_device = True
 
     def mem_model(self, nq: int = 0, kmax: int = 0) -> Dict[str, object]:
         """Per-device fleet model at this engine's own bucket_plan;

@@ -98,17 +98,26 @@ def live_array_bytes() -> Optional[int]:
         return None
 
 
-def measured_watermark() -> Dict[str, Any]:
+def measured_watermark(per_device: bool = False) -> Dict[str, Any]:
     """One-shot watermark: allocator peak when available, live-array
     bytes otherwise, explicit marker when neither basis reports. For a
     watermark tracked ACROSS a run, use the telemetry sampler's
-    ``measured_peak()`` (it maxes over ticks)."""
+    ``measured_peak()`` (it maxes over ticks).
+
+    The allocator basis is the SUM over the host's devices — the
+    process's footprint, what a single-device engine's model and budget
+    are compared with. ``per_device`` gives the FULLEST device's peak
+    instead: what a per-device model (``fleet_engine_model``) and a
+    per-device budget (one chip's ``bytes_limit``) must be held to, or
+    a mesh whose every chip is a quarter full reads as one chip
+    overfull."""
     stats = device_memory_stats()
     if stats is not None:
         peaks = [st.get("peak_bytes_in_use", st.get("bytes_in_use", 0))
                  for st in stats if st]
         if peaks:
-            return {"bytes": int(sum(peaks)), "basis": "memory_stats"}
+            return {"bytes": int(max(peaks) if per_device else sum(peaks)),
+                    "basis": "memory_stats"}
     live = live_array_bytes()
     if live:
         return {"bytes": live, "basis": "live_arrays"}

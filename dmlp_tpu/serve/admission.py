@@ -117,9 +117,16 @@ class AdmissionController:
         set); None when no budget basis exists."""
         if self.budget_bytes is None:
             return None
-        sess = telemetry.session()
-        measured = (sess.sampler.measured_peak() if sess
-                    else memwatch.measured_watermark())
+        if getattr(self.engine, "mem_per_device", False):
+            # A mesh engine's model and the budget are ONE device's:
+            # so is the watermark (the fullest device's peak, not the
+            # host's sum, which on four quarter-full chips already
+            # passes one chip's limit and sheds every request).
+            measured = memwatch.measured_watermark(per_device=True)
+        else:
+            sess = telemetry.session()
+            measured = (sess.sampler.measured_peak() if sess
+                        else memwatch.measured_watermark())
         used = int(measured.get("bytes", 0) or 0)
         used = max(used, self._resident_model_bytes())
         return self.budget_bytes - used

@@ -388,12 +388,14 @@ class MicroBatcher:
         reg.counter("serve.batches").inc()
         reg.histogram("serve.batch_queries").observe(total)
         # The engine's own parts of the batch (engine.last_phase_ms: the
-        # dispatch loop, the readback, the hazard pass, the float64
-        # finalize), whichever this engine reports.
+        # dispatch loop, a mesh engine's cross-shard merge, the
+        # readback, the hazard pass, the float64 finalize), whichever
+        # this engine reports.
         parts = getattr(self.engine, "last_phase_ms", None) or {}
         for part, hist in (
                 ("dispatch",
                  reg.histogram("serve.batch_ms.dispatch", unit="ms")),
+                ("merge", reg.histogram("serve.batch_ms.merge", unit="ms")),
                 ("fetch", reg.histogram("serve.batch_ms.fetch", unit="ms")),
                 ("hazard",
                  reg.histogram("serve.batch_ms.hazard", unit="ms")),

@@ -55,6 +55,7 @@ PHASE_HISTOGRAMS = {
                 ("respond", "serve.phase_ms.respond"),
                 ("write", "serve.phase_ms.write")),
     "batch": (("dispatch", "serve.batch_ms.dispatch"),
+              ("merge", "serve.batch_ms.merge"),
               ("fetch", "serve.batch_ms.fetch"),
               ("hazard", "serve.batch_ms.hazard"),
               ("finalize", "serve.batch_ms.finalize")),

@@ -297,7 +297,93 @@ class ResidentServingCore:
             nn = np.einsum("ma,ma->m", attrs, attrs).max()
             self._dn_max_cache = max(self._dn_max_cache, float(nn))
 
+    # -- gate effectiveness (the fused kernel's gated-tile count) ------------
+
+    def _flush_pending_gate(self, sp) -> None:
+        """Read back the batch's pending gated-tile scalar (a host sync,
+        after the result fetch) into the gate gauges and the span."""
+        if self._pending_gate is None:
+            return
+        gz, ntiles = self._pending_gate
+        self._pending_gate = None
+        try:
+            gated = int(jax.device_get(gz))  # check: allow-host-sync
+            frac = gated / max(ntiles, 1)
+            self.last_gated_fraction = frac
+            reg = telemetry.registry()
+            reg.gauge("serve.gate.gated_fraction").set(round(frac, 6))
+            reg.counter("serve.gate.tiles_total").inc(ntiles)
+            reg.counter("serve.gate.tiles_gated").inc(gated)
+            sp.set(gated=gated, tiles=ntiles)
+        except Exception:  # check: no-retry — stats never fail a batch
+            pass
+
+    # -- resident block summaries on the device (pruned solve, stage 1) -----
+
+    def _put_resident(self, value):
+        """Place one small resident array where this engine's solves
+        read it (a mesh engine replicates it over its mesh)."""
+        return jax.device_put(value)
+
+    def _stage_summaries(self) -> None:
+        """Conservative f32 copies of ``self._summ`` on the device
+        (tiny: O(blocks * a)), with the eps constants the scorer
+        widens its thresholds by."""
+        from dmlp_tpu.engine.finalize import (EPS_CANCEL_COEF, LOWP_COEF,
+                                              EPS_REL_BF16, EPS_REL_F32)
+        from dmlp_tpu.ops import summaries as osum
+        dev = {k: self._put_resident(v)
+               for k, v in osum.stage_summaries(self._summ).items()}
+        rel = EPS_REL_BF16 if self._staging == "bfloat16" else EPS_REL_F32
+        # score_blocks widens thresholds by eps_rel*sqrt(thr*scale) +
+        # eps_cancel*scale with scale = qn + dn_max; lowp_eps is
+        # LOWP_COEF*scale, so folding the plan's coefficient into the
+        # staged eps_cancel scalar composes the bf16 first-pass bound
+        # additively — exactly prune_mask's precision widening. Plan-
+        # level (not per-rung): on the f32 rungs the extra slack only
+        # keeps a few more blocks, never drops one.
+        dev["eps_rel"] = self._put_resident(np.float32(rel))
+        dev["eps_cancel"] = self._put_resident(
+            np.float32(EPS_CANCEL_COEF * (self.num_attrs + 2)
+                       + LOWP_COEF[self._precision_plan]))
+        self._summ_dev = dev
+
+    def _score_summaries(self, inp: KNNInput, qpad: int, q_dev,
+                         span: str, blocks: int) -> np.ndarray:
+        """Score the RESIDENT summaries on device for one padded
+        micro-batch (ops.summaries.score_blocks — compiled once per
+        bucket shape) and read back the tiny (blocks,) survivor mask."""
+        from dmlp_tpu.obs import counters as obs_counters
+        from dmlp_tpu.ops import summaries as osum
+        with obs_span(span, blocks=blocks, qpad=qpad,
+                      **self._rid_args()):
+            nq = inp.params.num_queries
+            ks = np.ones(qpad, np.int32)
+            ks[:nq] = inp.ks
+            qvalid = np.zeros(qpad, bool)
+            qvalid[:nq] = True
+            sd = self._summ_dev
+            args = (q_dev, self._put_resident(qvalid),
+                    self._put_resident(ks),
+                    sd["counts"], sd["nmin"], sd["nmax"], sd["lo"],
+                    sd["hi"], sd["dn_max"], sd["eps_rel"],
+                    sd["eps_cancel"])
+            obs_counters.record_dispatch(osum.score_blocks, args,
+                                         site=span)
+            mask = osum.score_blocks(*args)
+            # Deliberate tiny fence: the (blocks,) mask decides WHICH
+            # resident chunks the folds dispatch over, so the host must
+            # read it before enqueueing them — O(blocks) bytes, priced
+            # by the analytic score model, nothing like a result fetch.
+            return np.asarray(
+                jax.device_get(mask))  # check: allow-host-sync
+
     # -- memory-model hooks (admission + memwatch read these) ---------------
+
+    #: True when :meth:`mem_model` prices ONE device of several (a mesh
+    #: engine): admission then holds it to the fullest device's
+    #: watermark, not to the sum over the host's devices.
+    mem_per_device = False
 
     def mem_model(self, nq: int = 0, kmax: int = 0):
         raise NotImplementedError
@@ -567,25 +653,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         telemetry.registry().gauge("prune.summary_blocks").set(
             self._ex_nchunks)
 
-    def _stage_summaries(self) -> None:
-        from dmlp_tpu.engine.finalize import (EPS_CANCEL_COEF, LOWP_COEF,
-                                              EPS_REL_BF16, EPS_REL_F32)
-        from dmlp_tpu.ops import summaries as osum
-        dev = osum.stage_summaries(self._summ)
-        rel = EPS_REL_BF16 if self._staging == "bfloat16" else EPS_REL_F32
-        # score_blocks widens thresholds by eps_rel*sqrt(thr*scale) +
-        # eps_cancel*scale with scale = qn + dn_max; lowp_eps is
-        # LOWP_COEF*scale, so folding the plan's coefficient into the
-        # staged eps_cancel scalar composes the bf16 first-pass bound
-        # additively — exactly prune_mask's precision widening. Plan-
-        # level (not per-rung): on the f32 rungs the extra slack only
-        # keeps a few more blocks, never drops one.
-        dev["eps_rel"] = jax.device_put(np.float32(rel))
-        dev["eps_cancel"] = jax.device_put(
-            np.float32(EPS_CANCEL_COEF * (self.num_attrs + 2)
-                       + LOWP_COEF[self._precision_plan]))
-        self._summ_dev = dev
-
     def _rebuild_summary_blocks(self, blocks) -> None:
         """Ingest invalidation: rebuild EXACTLY the touched blocks'
         summaries from their current host rows, then restage the
@@ -749,32 +816,14 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         shape) and read back the tiny (blocks,) survivor mask. Active
         on the ladder's top ``prune`` rung in exact mode only; returns
         (mask, stats) or (None, None) for a dense fold."""
-        from dmlp_tpu.obs import counters as obs_counters
         from dmlp_tpu.ops import summaries as osum
         if (self._summ_dev is None
                 or self._degrade_rung not in ("lowp", "prune")
                 or not self.config.exact or not osum.prune_enabled()):
             return None, None
-        nq = inp.params.num_queries
-        ks = np.ones(entry.qpad, np.int32)
-        ks[:nq] = inp.ks
-        qvalid = np.zeros(entry.qpad, bool)
-        qvalid[:nq] = True
-        sd = self._summ_dev
-        args = (q_dev, jax.device_put(qvalid), jax.device_put(ks),
-                sd["counts"], sd["nmin"], sd["nmax"], sd["lo"],
-                sd["hi"], sd["dn_max"], sd["eps_rel"], sd["eps_cancel"])
-        with obs_span("serve.prune_score", blocks=self._ex_nchunks,
-                      qpad=entry.qpad, **self._rid_args()):
-            obs_counters.record_dispatch(osum.score_blocks, args,
-                                         site="serve.prune_score")
-            mask = osum.score_blocks(*args)
-            # Deliberate tiny fence: the (blocks,) mask decides WHICH
-            # resident chunks the folds dispatch over, so the host must
-            # read it before enqueueing them — O(blocks) bytes, priced
-            # by the analytic score model, nothing like a result fetch.
-            keep = np.asarray(
-                jax.device_get(mask))  # check: allow-host-sync
+        keep = self._score_summaries(inp, entry.qpad, q_dev,
+                                     "serve.prune_score",
+                                     self._ex_nchunks)
         total = int(np.count_nonzero(
             self._summ.counts[:self._ex_nchunks] > 0))
         pruned = total - int(np.count_nonzero(keep))
@@ -1069,22 +1118,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     def _after_batch(self, results: List[QueryResult]) -> None:
         with obs_span("serve.after_batch", **self._rid_args()) as sp:
-            if self._pending_gate is not None:
-                gz, ntiles = self._pending_gate
-                self._pending_gate = None
-                try:
-                    gated = int(
-                        jax.device_get(gz))  # check: allow-host-sync
-                    frac = gated / max(ntiles, 1)
-                    self.last_gated_fraction = frac
-                    reg = telemetry.registry()
-                    reg.gauge("serve.gate.gated_fraction").set(
-                        round(frac, 6))
-                    reg.counter("serve.gate.tiles_total").inc(ntiles)
-                    reg.counter("serve.gate.tiles_gated").inc(gated)
-                    sp.set(gated=gated, tiles=ntiles)
-                except Exception:  # check: no-retry — stats never fail
-                    pass           # a batch
+            self._flush_pending_gate(sp)
             if self.gate_carry and self._ex_nchunks and results:
                 ids = np.concatenate(
                     [np.asarray(r.neighbor_ids, np.int64)

@@ -217,6 +217,11 @@ def test_no_sink_means_no_span(untraced):
                                        for k, _ in names])
 def test_stats_reports_every_phase_without_a_tracer(untraced, group, key):
     stats = untraced["stats"]
+    if (group, key) == ("batch", "merge"):
+        # a mesh daemon's part (tests/test_mesh_serve.py): one chip
+        # merges nothing and reports none
+        assert key not in stats["phases_ms"][group]
+        return
     assert stats["requests_completed"] == 3 and stats["batches"] == 3
     got = stats["phases_ms"][group][key]
     assert got["count"] == 3        # the requests, or the batches, served
