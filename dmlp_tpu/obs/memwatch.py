@@ -296,8 +296,7 @@ def serve_engine_model(capacity_rows: int, na: int,
                        staging: str = "float32", qpad: int = 0,
                        kcap: int = 0, extract_chunks: int = 0,
                        chunk_rows: int = 0,
-                       summary_blocks: int = 0,
-                       multipass_rows: int = 0) -> Dict[str, Any]:
+                       summary_blocks: int = 0) -> Dict[str, Any]:
     """Peak resident device bytes for the serving layer's
     :class:`~dmlp_tpu.serve.engine.ResidentEngine`: the capacity-padded
     resident corpus (+ labels/ids mask arrays), the extract path's
@@ -318,12 +317,6 @@ def serve_engine_model(capacity_rows: int, na: int,
         # solve (ops.summaries.stage_summaries): two (B, A) f32 boxes,
         # two (B,) f32 norm bands, one (B,) i32 count vector.
         terms["resident_summaries"] = summary_blocks * (8 * na + 12)
-    if multipass_rows:
-        # The wide-k multipass path keeps a SECOND full copy of the
-        # resident chunks concatenated on device (passes 2+ re-sweep
-        # it whole); un-modeled it would let admission over-admit by a
-        # corpus once the first wide-k bucket warms.
-        terms["multipass_resident"] = multipass_rows * na * item
     if qpad:
         terms["query_blocks"] = qpad * na * item
         terms["topk_carries"] = 2 * qpad * kcap * _TOPK_ITEMSIZE

@@ -94,14 +94,13 @@ class AdmissionController:
     def _resident_model_bytes(self) -> int:
         """The corpus-only model total, cached — it only moves when
         the engine's resident_state_key changes (lazy stagings: extract
-        chunks, the wide-k multipass concat, the mesh monolithic
-        layout), so rebuilding the model per request is pure hot-path
+        chunks, the mesh monolithic layout), so rebuilding the model per request is pure hot-path
         waste. The memo is read both under the batcher's queue lock
         (decide_queued) and from handler threads (snapshot), hence its
         own guard."""
         # The engine names its own invalidation state (chunk staging,
-        # the wide-k multipass concat, the mesh monolithic layout —
-        # each a resident allocation the floor must follow).
+        # the mesh monolithic layout — each a resident allocation the
+        # floor must follow).
         state = self.engine.resident_state_key()
         with self._lock:
             cached = self._model_cache

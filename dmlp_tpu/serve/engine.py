@@ -19,8 +19,22 @@ inherited unchanged:
 - **Per-bucket compile-once solves.** Requests bucket to power-of-two
   (qpad, k) shape buckets. The streaming path is lowered and compiled
   AHEAD of time per bucket (``jit(...).lower(...).compile()``); the
-  extract path's kernels compile on the bucket's first dispatch
-  (:meth:`warmup` front-loads both before the first request).
+  extract path's one program a bucket compiles on the bucket's first
+  dispatch (:meth:`warmup` front-loads both before the first request).
+- **One program a micro-batch folds the resident chunks.** The extract
+  path's resident copy is ONE device array (nchunks, chunk_rows, A),
+  filled a chunk at a time by a donated update (ingest restages a
+  chunk the same way). ``_fold_stack`` folds every scheduled chunk of
+  it in one dispatch: the kernel once a chunk, the first with no
+  carry, the rest carried, the gated tiles summed beside them. The
+  fold order, its length and the row count are device data, so a new
+  schedule, a pruned chunk or an ingest reuses the executable. Python
+  dispatches it once and first blocks in the readback
+  (``single.fetch``); there is no per-chunk loop, no eager gate
+  counter and no ``ChunkThrottle`` (which bounds STAGED chunks in
+  flight; nothing here is staged). Indexing the stack costs a copy of
+  the chunk on the device, made by the pass that computes its row
+  norms (``%multiply_reduce_fusion``'s second output).
   :attr:`compile_count` counts bucket builds — a replay whose buckets
   were all warmed must leave it unchanged, the serving layer's
   no-per-request-recompilation proof.
@@ -39,9 +53,11 @@ inherited unchanged:
   exceeds the extraction kernel's single-pass window routes through
   the batch engine's multi-pass extraction driver AGAINST THE RESIDENT
   CHUNKS (:meth:`ResidentEngine._solve_resident_multipass`): no
-  staging per request, floor-chained resident re-sweeps over a cached
-  concatenation, and the driver's stall/shortfall hazards feed run()'s
-  exact repair — byte-identical to the solo multipass solve.
+  staging per request, pass 1 by the program above, floor-chained
+  re-sweeps of the same stack as one array (``_sweep_stack``: no
+  further copy of the corpus), and the driver's stall/shortfall
+  hazards feed run()'s exact repair — byte-identical to the solo
+  multipass solve.
 """
 
 from __future__ import annotations
@@ -55,8 +71,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dmlp_tpu.config import EngineConfig
-from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, ChunkThrottle,
-                                    SingleChipEngine, _extract_finalize,
+from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, SingleChipEngine,
+                                    _extract_finalize,
                                     _topk_blocks, active_precision,
                                     fit_blocks, np_staging_dtype,
                                     plan_chunks, resilient_get, resolve_kcap,
@@ -103,6 +119,74 @@ def _update_rows_2d(buf, blk, start):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _update_rows_1d(buf, blk, start):
     return jax.lax.dynamic_update_slice(buf, blk, (start,))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _update_chunk(stack, blk, c):
+    return jax.lax.dynamic_update_index_in_dim(stack, blk, c, 0)
+
+
+#: what picks the compiled kernel: every one is part of the two
+#: programs' jit cache key, resolved by their caller OUTSIDE the jit
+_KERNEL_STATICS = ("kc", "interpret", "tile_q", "tile_n", "ne", "unroll",
+                   "mxu_gate", "precision")
+
+
+def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
+                    precision: str, interpret: bool) -> Dict[str, Any]:
+    """The static arguments of ``extract_topk`` for the kernel ``impl``
+    ("fused" | "extract", from resolve_topk_kernel) at dispatch shape
+    (qb, b, a): exactly what ``fused_topk`` / ``extract_topk`` would
+    resolve for themselves, made concrete here so that it keys the
+    enclosing program's jit cache."""
+    from dmlp_tpu.ops import pallas_fused
+    from dmlp_tpu.ops.pallas_extract import _TN
+    v = pallas_fused.variant_for(impl, kc, b, qb, a, precision)
+    return dict(kc=kc, interpret=interpret, tile_q=v["tile_q"],
+                tile_n=v.get("tile_n", _TN), ne=v["ne"],
+                unroll=v["unroll"], mxu_gate=impl == "fused",
+                precision=precision)
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _fold_stack(q, stack, order, nfold, n_real, **kern):
+    """Fold ``nfold`` chunks of the resident ``stack`` (nchunks,
+    chunk_rows, A), in the order ``order[:nfold]`` gives, into one
+    running top-k: the kernel once a chunk, the first with no carry
+    (the ``_fresh`` form), the rest carried. ``order`` (padded to a
+    fixed length), ``nfold`` and ``n_real`` are device data, so a new
+    schedule, a pruned chunk or an ingest runs the same executable.
+    Returns (dists, ids, gated): ``gated`` counts the (query tile,
+    data block) pairs either gate elided (0 recorded iterations)."""
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    cr = stack.shape[1]
+
+    def fold(c, od, oi):
+        lo = c * cr
+        return extract_topk(q, stack[c], od, oi,
+                            n_real=jnp.minimum(n_real - lo, cr),
+                            id_base=lo, **kern)
+
+    od, oi, its = fold(order[0], None, None)
+
+    def body(i, carry):
+        od, oi, gated = carry
+        od, oi, its = fold(order[i], od, oi)
+        return od, oi, gated + jnp.sum(its == 0)
+
+    return jax.lax.fori_loop(1, nfold, body,
+                             (od, oi, jnp.sum(its == 0)))
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _sweep_stack(q, stack, n_real, floor, **kern):
+    """One kernel call over the whole ``stack`` as one (nchunks *
+    chunk_rows, A) array (a multipass re-sweep above ``floor``). The
+    reshape is free inside the program; an eager one would copy the
+    corpus."""
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    return extract_topk(q, stack.reshape(-1, stack.shape[-1]),
+                        n_real=n_real, id_base=0, floor=floor, **kern)
 
 
 class _Bucket:
@@ -454,7 +538,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         else:
             self._ex_nchunks = self._ex_chunk_rows = self._ex_rows = 0
             self._interpret = True
-        self._chunks: Optional[List] = None
+        # The extract path's resident copy: ONE device array (nchunks,
+        # chunk_rows, A), so a whole fold is one program (_fold_stack).
+        self._chunks = None
         host_rows = max(self.capacity_rows, self._ex_rows)
 
         # -- host originals (float64 finalize rescore reads these) -----------
@@ -490,10 +576,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.compile_count = 0
         self.cold_start_compile_ms: Optional[float] = None
         self.bucket_compile_ms: Dict[str, float] = {}
-        # Wide-k multipass residency: the concatenated resident chunks
-        # (passes 2+ re-sweep it whole), invalidated on ingest.
-        self._mp_full = None
-        # perf_counter at which the extract path's dispatch loop ended
+        # perf_counter at which the extract path's fold was dispatched
         # (the start of the serve.solve_epilogue span); None otherwise.
         self._epilogue_pc: Optional[float] = None
         # Cross-request gate state: per-chunk winner histogram + last
@@ -616,19 +699,15 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     def _ensure_chunks(self) -> None:
         if self._chunks is not None or not self._extract_ok:
             return
-        sdt = np_staging_dtype(self._staging)
         cr = self._ex_chunk_rows
-        chunks = []
         with obs_span("serve.stage_chunks", chunks=self._ex_nchunks,
                       chunk_rows=cr):
+            # Allocated on the device, then filled a chunk at a time by
+            # a donated update: the host never holds a second corpus.
+            self._chunks = jnp.zeros((self._ex_nchunks, cr, self.num_attrs),
+                                     np_staging_dtype(self._staging))
             for c in range(self._ex_nchunks):
-                a = np.zeros((cr, self.num_attrs), sdt)
-                lo = c * cr
-                hi = min(lo + cr, self.n_real)
-                if hi > lo:
-                    a[:hi - lo] = self._host_attrs[lo:hi]
-                chunks.append(stage_put(a, self._staging))
-        self._chunks = chunks
+                self._restage_chunk(c)
         self._build_summaries()
 
     # -- resident block summaries (pruned two-stage solve, stage 0) -----------
@@ -680,7 +759,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         a = np.zeros((cr, self.num_attrs), sdt)
         if hi > lo:
             a[:hi - lo] = self._host_attrs[lo:hi]
-        self._chunks[c] = stage_put(a, self._staging)
+        self._chunks = _update_chunk(
+            self._chunks, stage_put(a, self._staging),
+            jax.device_put(np.int32(c)))
 
     # -- incremental ingestion ------------------------------------------------
 
@@ -748,10 +829,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                 # rebuild with the rows — a stale summary could keep a
                 # block pruned whose NEW rows belong in a top-k.
                 self._rebuild_summary_blocks(touched)
-                # Wide-k residency: the cached chunk concatenation is
-                # stale the moment a chunk restages (same shapes, so
-                # the rebuild never recompiles).
-                self._mp_full = None
             # Overwrites can only RAISE the cached max-sq-norm (the
             # old row's norm may linger) — conservative: a too-large
             # dn_max only widens the boundary-repair eps, never
@@ -831,6 +908,28 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             return None, None   # belt: score_blocks keeps >= 1 block
         return keep, {"blocks_total": total, "blocks_pruned": pruned}
 
+    def _fold_resident(self, q_dev, order, impl: str, kc: int,
+                       prec: str):
+        """Dispatch ONE program that folds the resident chunks
+        ``order`` names, in that order (_fold_stack). Returns the
+        running lists, the gated-tile count (all three still on the
+        device) and the number of (query tile, data block) pairs the
+        fold visited."""
+        from dmlp_tpu.ops.pallas_distance import _tile
+        cr = self._ex_chunk_rows
+        qpad = q_dev.shape[0]
+        kern = _kernel_statics(impl, kc, cr, qpad, self.num_attrs, prec,
+                               self._interpret)
+        padded = np.zeros(self._ex_nchunks, np.int32)
+        padded[:len(order)] = order
+        od, oi, gated = _fold_stack(
+            q_dev, self._chunks,
+            *jax.device_put((padded, np.int32(len(order)),
+                             np.int32(self.n_real))), **kern)
+        tiles = (qpad // _tile(qpad, kern["tile_q"], 8)) \
+            * (cr // _tile(cr, kern["tile_n"], 128 * kern["ne"]))
+        return od, oi, gated, len(order) * tiles
+
     def _solve_resident_extract(self, inp: KNNInput, entry: _Bucket
                                 ) -> Optional[Tuple[TopK, int]]:
         from dmlp_tpu.ops import pallas_fused
@@ -851,64 +950,42 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             order = self._chunk_order()
             survivors, prune_stats = self._prune_survivors(inp, entry,
                                                            q_dev)
-            if survivors is not None:
-                # Survivor ∩ hot-first order: the winner-histogram sort
-                # stays the fold order, pruned chunks simply drop out.
-                order = [c for c in order if survivors[c]]
+            # Survivor ∩ hot-first order: the winner-histogram sort
+            # stays the fold order, pruned chunks simply drop out, and
+            # so do chunks past the last real row.
+            order = [c for c in order if c * cr < self.n_real
+                     and (survivors is None or survivors[c])]
+            if not order:
+                # Cannot happen with a sound mask (score_blocks keeps
+                # >= 1 block): fall back to a dense fold.
+                return None
             self._last_select = "extract"
             self.last_extract_impl = impl
             self.last_variant = pallas_fused.variant_stamp(
                 impl, entry.kcap, cr, entry.qpad, na, prec)
-        od = oi = None
-        gz = None
-        ntiles = 0
-        scanned = 0
-        dispatches = 0
-        item = self._staging_itemsize()
-        throttle = ChunkThrottle()
         clock = time.perf_counter
-        # Where the loop's wall time goes: inside kern(...) (the host
-        # dispatching), inside the throttle (blocked on the device), and
-        # the rest (the gate counter's eager ops, bookkeeping).
-        kern_s = wait_s = 0.0
         with obs_span("serve.solve_extract", qpad=entry.qpad,
                       kcap=entry.kcap, impl=impl,
                       carry=self.gate_carry, scheduled=len(order),
                       **self._rid_args()) as sp:
-            t_loop = clock()
-            for c in order:
-                lo = c * cr
-                nr = min(self.n_real - lo, cr)
-                if nr <= 0:
-                    continue
-                t0 = clock()
-                od, oi, iters = kern(q_dev, self._chunks[c], od, oi,
-                                     n_real=nr, id_base=lo, kc=entry.kcap,
-                                     interpret=self._interpret,
-                                     precision=prec)
-                t1 = clock()
-                scanned += nr * na * item
-                z = jnp.sum(iters == 0)
-                gz = z if gz is None else gz + z
-                ntiles += int(np.prod(iters.shape))
-                dispatches += 1
-                t2 = clock()
-                throttle.tick(od)
-                kern_s += t1 - t0
-                wait_s += clock() - t2
-            self.last_phase_ms["dispatch"] = (clock() - t_loop) * 1e3
-            sp.set(dispatches=dispatches,
-                   kernel_dispatch_ms=round(kern_s * 1e3, 3),
-                   throttle_wait_ms=round(wait_s * 1e3, 3))
-        if od is None:
-            # Every scheduled chunk was empty (cannot happen with a
-            # sound mask, the belt above): fall back to a dense fold.
-            return None
+            t0 = clock()
+            od, oi, gated, ntiles = self._fold_resident(
+                q_dev, order, impl, entry.kcap, prec)
+            ms = (clock() - t0) * 1e3
+            self.last_phase_ms["dispatch"] = ms
+            # One program folds every scheduled chunk: the host only
+            # enqueues it (nothing here waits for the device; the fold's
+            # device time shows where the host first blocks, in
+            # single.fetch).
+            sp.set(dispatches=1, chunks=len(order),
+                   kernel_dispatch_ms=round(ms, 3), throttle_wait_ms=0.0)
         # Closed by _before_fetch, where SingleChipEngine._run starts
         # the readback: the epilogue's enqueues run on into _run.
         self._epilogue_pc = clock()
-        self._pending_gate = (gz, ntiles)
-        note_scan(self, scanned_bytes=scanned,
+        self._pending_gate = (gated, ntiles)
+        item = self._staging_itemsize()
+        scanned = sum(min(self.n_real - c * cr, cr) for c in order)
+        note_scan(self, scanned_bytes=scanned * na * item,
                   dense_bytes=self.n_real * na * item,
                   blocks_total=(prune_stats or {}).get(
                       "blocks_total", -(-self.n_real // cr)),
@@ -926,23 +1003,14 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     # -- wide-k multipass serving (ROADMAP item (d)) --------------------------
 
-    def _resident_full(self):
-        """The resident chunks as ONE device array for the multipass
-        resident sweeps — concatenated lazily, cached across requests,
-        invalidated on ingest. Same shapes every rebuild, so the concat
-        compiles once (covered by the wide bucket's warm-up)."""
-        if self._mp_full is None:
-            self._mp_full = self._chunks[0] if self._ex_nchunks == 1 \
-                else jnp.concatenate(self._chunks, axis=0)
-        return self._mp_full
-
     def _solve_resident_multipass(self, inp: KNNInput, entry: _Bucket
                                   ) -> Optional[Tuple[TopK, int]]:
         """k past the kernel's single-pass window, served on the
         existing multi-pass extraction driver (engine.single
         ._solve_extract_multipass) against the RESIDENT chunks: pass 1
-        folds the resident chunk buffers (no staging), passes 2+
-        re-sweep the cached resident concatenation with the on-device
+        folds them in natural order with the single-pass path's one
+        program (``_fold_stack``; no staging), passes 2+ re-sweep the
+        same stack as one array (``_sweep_stack``) with the on-device
         floor chain (``_mp_floor``), and ``_mp_merge`` dedups and
         composite-sorts to the bucket width. The driver's two loss
         modes (tie-plateau stall / eps-window shortfall) set
@@ -962,7 +1030,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         if kern is None:
             return None
         full_rows = self._ex_nchunks * self._ex_chunk_rows
-        kern_full, _impl_full = pallas_fused.resolve_topk_kernel(
+        kern_full, impl_full = pallas_fused.resolve_topk_kernel(
             entry.qpad, full_rows, self.num_attrs, kc,
             rung=self._degrade_rung)
         if kern_full is None:
@@ -980,43 +1048,28 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
             impl, kc, cr, entry.qpad, na, prec)
-        od = oi = None
-        throttle = ChunkThrottle()
+        sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad, na,
+                                prec, self._interpret)
         with obs_span("serve.solve_multipass", qpad=entry.qpad,
                       kcap=kcap, passes=npasses, impl=impl,
                       **self._rid_args()):
-            for c in range(self._ex_nchunks):
-                lo = c * cr
-                nr = min(n - lo, cr)
-                if nr <= 0:
-                    continue
-                od, oi, _its = kern(q_dev, self._chunks[c], od, oi,
-                                    n_real=nr, id_base=lo, kc=kc,
-                                    interpret=self._interpret,
-                                    precision=prec)
-                throttle.tick(od)
-                telemetry.sample_memory_now()
-            if od is None:
-                return None
+            od, oi, _gated, _tiles = self._fold_resident(
+                q_dev, range(-(-n // cr)), impl, kc, prec)
             ods, ois = [od], [oi]
             qn_host = np.zeros(entry.qpad, np.float64)
             qn_host[:nq] = np.einsum("qa,qa->q", inp.query_attrs,
                                      inp.query_attrs)
             qn_dev = jax.device_put(np.asarray(qn_host, np.float32))
-            dn_dev = jax.device_put(np.float32(self._dn_max()))
-            d_full = self._resident_full()
+            dn_dev, n_dev = jax.device_put((np.float32(self._dn_max()),
+                                            np.int32(n)))
             fds = []
             for _p in range(1, npasses):
                 floor_dev, fd = _mp_floor(ods[-1], qn_dev, dn_dev,
                                           staging=self._staging, na=na,
                                           precision=prec)
                 fds.append(fd)
-                od, oi, _its = kern_full(q_dev, d_full, n_real=n,
-                                         id_base=0, kc=kc,
-                                         interpret=self._interpret,
-                                         floor=floor_dev,
-                                         precision=prec)
-                throttle.tick(od)
+                od, oi, _its = _sweep_stack(q_dev, self._chunks, n_dev,
+                                            floor_dev, **sweep)
                 ods.append(od)
                 ois.append(oi)
             fds.append(_mp_floor(ods[-1], qn_dev, dn_dev,
@@ -1142,21 +1195,20 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         return memwatch.serve_engine_model(
             self.capacity_rows, self.num_attrs, staging=self._staging,
             qpad=qpad, kcap=kcap,
-            extract_chunks=(self._ex_nchunks if self._chunks else 0),
+            extract_chunks=(self._ex_nchunks
+                            if self._chunks is not None else 0),
             chunk_rows=self._ex_chunk_rows,
             summary_blocks=(self._ex_nchunks
-                            if self._summ_dev is not None else 0),
-            multipass_rows=(self._ex_nchunks * self._ex_chunk_rows
-                            if self._mp_full is not None else 0))
+                            if self._summ_dev is not None else 0))
 
     def batch_model_bytes(self, nq: int, kmax: int) -> int:
         terms = self.mem_model(nq, kmax)["terms"]
         return int(terms["query_blocks"] + terms["topk_carries"])
 
     def resident_state_key(self):
-        # The floor moves when the extract chunks stage and when the
-        # wide-k multipass concat materializes (a SECOND corpus copy).
-        return (self._chunks is not None, self._mp_full is not None)
+        # The floor moves when the extract chunks stage (wide-k sweeps
+        # read the same stack: no further copy).
+        return (self._chunks is not None,)
 
     # -- introspection --------------------------------------------------------
 
@@ -1188,7 +1240,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             "capacity_rows": self.capacity_rows,
             "gate_carry": self.gate_carry,
             "last_gated_fraction": self.last_gated_fraction,
-            "extract_chunks": self._ex_nchunks if self._chunks else 0,
+            "extract_chunks": self._ex_nchunks
+            if self._chunks is not None else 0,
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,
             "last_prune_fraction": self.last_prune_fraction,

@@ -212,12 +212,18 @@ def test_resident_wide_k_routes_through_multipass_and_stays_golden():
     assert eng.last_mp_passes > 1         # the multipass driver ran
     assert solo_eng.last_mp_passes > 1    # ...and is the solo path too
     assert eng.compile_count == cc        # no per-request compiles
-    # The resident multipass concat is a SECOND corpus copy on device:
-    # admission's resident floor must price it once warmed (and the
-    # memwatch serve model must carry the term).
+    # Passes 2+ sweep the resident stack itself (a reshape inside the
+    # program): no further corpus copy on the device, so the memwatch
+    # serve model carries no term for one and admission's floor is the
+    # one it priced when the chunks staged.
+    import jax
     from dmlp_tpu.obs import memwatch
     from dmlp_tpu.serve.admission import AdmissionController
-    assert eng._mp_full is not None
+    full = (eng._ex_nchunks * eng._ex_chunk_rows, 4)
+    assert eng._chunks.shape == (eng._ex_nchunks, eng._ex_chunk_rows, 4)
+    assert [a for a in jax.live_arrays()
+            if a.shape == full and a is not eng._d_attrs] == []
+    assert eng.resident_state_key() == (True,)
     adm = AdmissionController(eng)
     total = adm._resident_model_bytes()
     model = memwatch.model_for_engine(
@@ -225,9 +231,9 @@ def test_resident_wide_k_routes_through_multipass_and_stays_golden():
                       eng._host_labels[:eng.n_real],
                       eng._host_attrs[:eng.n_real], ks,
                       np.asarray(q, np.float64)))
-    mp_term = model["terms"].get("multipass_resident", 0)
-    assert mp_term >= eng._ex_nchunks * eng._ex_chunk_rows * 4 * 2
-    assert total >= mp_term
+    assert "multipass_resident" not in model["terms"]
+    assert total >= model["terms"]["extract_chunks"] \
+        == eng._chunks.nbytes
 
 
 def test_resident_wide_k_survives_ingest_invalidation():

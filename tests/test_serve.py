@@ -320,6 +320,149 @@ def test_extract_ingest_into_new_chunk_stays_golden():
     assert got == solo_and_golden(grown, q, ks, extract_config())
 
 
+# -- the one-program resident fold ---------------------------------------------
+
+#: what changes between micro-batches, in the order the journey makes
+#: them: (step, hand-set winner histogram, hand-set survivor mask,
+#: ingest (start row, rows), the fold order the engine must then
+#: schedule)
+FOLD_STEPS = [
+    ("natural", [0, 0, 0], None, None, [0, 1]),
+    ("hot_first", [1, 5, 3], None, None, [1, 0]),
+    ("survivors", [1, 5, 3], [False, True, True], None, [1]),
+    ("restaged_chunk", [0, 0, 0], None, (100, 64), [0, 1]),
+    ("appended_rows", [0, 0, 0], None, (None, 8000), [0, 1, 2]),
+    ("hot_first_partial_last", [1, 5, 3], None, None, [1, 2, 0]),
+]
+FOLD_BUCKETS = {"q128": 5, "q1024": 1000}
+
+
+def _reference_fold(eng, q_dev, order, kc, prec):
+    """Today's fold as PR 27 ran it: the resolved kernel once a chunk
+    from Python, first call with no carry, the gate counted eagerly."""
+    from dmlp_tpu.ops import pallas_fused
+    cr = eng._ex_chunk_rows
+    kern, _ = pallas_fused.resolve_topk_kernel(
+        q_dev.shape[0], cr, eng.num_attrs, kc, rung=eng._degrade_rung)
+    od = oi = None
+    gated = tiles = 0
+    for c in order:
+        od, oi, its = kern(q_dev, eng._chunks[c], od, oi,
+                           n_real=min(eng.n_real - c * cr, cr),
+                           id_base=c * cr, kc=kc,
+                           interpret=eng._interpret, precision=prec)
+        gated += int(np.count_nonzero(np.asarray(its) == 0))
+        tiles += its.size
+    return np.array(od), np.array(oi), gated, tiles
+
+
+@pytest.fixture(scope="module")
+def fold_journey():
+    """One engine, two warm buckets, FOLD_STEPS in order; a micro-batch
+    a bucket a step. Records what the engine's one program was given
+    and gave, what the chunk-by-chunk reference gives for the same
+    order on the same chunks, and the compile counters."""
+    from dmlp_tpu.serve import engine as se
+    calls = []
+
+    class Recording(ResidentEngine):
+        def _fold_resident(self, q_dev, order, impl, kc, prec):
+            out = super()._fold_resident(q_dev, order, impl, kc, prec)
+            calls.append((q_dev, list(order), kc, prec, out))
+            return out
+
+    corpus = make_corpus(n=20000, na=4, seed=91)   # 12800 + 7200 + 0 rows
+    eng = Recording(corpus, extract_config())
+    assert eng._ex_nchunks == 3 and eng._ex_chunk_rows == 12800
+    eng.warmup([(nq, 6) for nq in FOLD_BUCKETS.values()])
+    calls.clear()
+    warm = (eng.compile_count, se._fold_stack._cache_size())
+    rng = np.random.default_rng(92)
+    seen = {}
+    for step, hits, survivors, ingest, _want in FOLD_STEPS:
+        if ingest is not None:
+            start, m = ingest
+            eng.ingest(rng.integers(0, 4, m).astype(np.int32),
+                       rng.uniform(-10, 10, (m, 4)), start=start)
+        # (an instance attribute over the method; popped to restore it)
+        eng.__dict__.pop("_prune_survivors", None)
+        if survivors is not None:
+            eng._prune_survivors = (
+                lambda inp, entry, q_dev, keep=np.asarray(survivors): (
+                    keep, {"blocks_total": 3,
+                           "blocks_pruned": int((~keep).sum())}))
+        for bucket, nq in FOLD_BUCKETS.items():
+            eng._block_hits[:] = hits
+            eng.solve_batch(rng.uniform(-10, 10, (nq, 4)),
+                            rng.integers(1, 7, nq).astype(np.int32))
+            q_dev, order, kc, prec, (od, oi, gated, tiles) = calls.pop()
+            assert not calls
+            seen[step, bucket] = {
+                "order": order, "n_real": eng.n_real,
+                # copies: a view would keep the device array alive
+                "got": (np.array(od), np.array(oi), int(gated), tiles),
+                "want": _reference_fold(eng, q_dev, order, kc, prec),
+                "counters": (eng.compile_count,
+                             se._fold_stack._cache_size())}
+    return {"warm": warm, "steps": seen}
+
+
+@pytest.mark.parametrize("bucket", sorted(FOLD_BUCKETS))
+@pytest.mark.parametrize("step,want_order",
+                         [(s[0], s[4]) for s in FOLD_STEPS])
+def test_one_program_fold_equals_the_chunk_loop_bit_for_bit(
+        fold_journey, step, want_order, bucket):
+    rec = fold_journey["steps"][step, bucket]
+    assert rec["order"] == want_order
+    (od, oi, gated, tiles), (rod, roi, rgated, rtiles) = \
+        rec["got"], rec["want"]
+    assert od.dtype == rod.dtype == np.float32 and od.shape == rod.shape
+    # bit for bit: the running lists themselves, not just the answers
+    assert od.tobytes() == rod.tobytes()
+    assert oi.tobytes() == roi.tobytes()
+    # the gate gauges: as many tiles gated, of as many visited
+    assert (gated, tiles) == (rgated, rtiles)
+
+
+@pytest.mark.parametrize("step", [s[0] for s in FOLD_STEPS])
+def test_fold_schedule_and_ingest_never_recompile(fold_journey, step):
+    """A new order, fewer survivors, a restaged chunk, more rows: the
+    same executable (bucket builds and the program's jit cache)."""
+    for bucket in FOLD_BUCKETS:
+        assert fold_journey["steps"][step, bucket]["counters"] \
+            == fold_journey["warm"]
+
+
+def test_appended_rows_reached_the_fold(fold_journey):
+    steps = fold_journey["steps"]
+    assert steps["restaged_chunk", "q128"]["n_real"] == 20000
+    assert steps["appended_rows", "q128"]["n_real"] == 28000
+
+
+def test_wide_k_sweeps_the_resident_stack_and_stays_golden():
+    """k past the kernel's window over SEVERAL resident chunks: pass 1
+    is the one-program fold, the later passes sweep the same stack as
+    one array, and nothing else of the corpus's size is on the device."""
+    import jax
+    corpus = make_corpus(n=20000, na=4, seed=93)
+    size = 3 * 12800 * 4 * 4                 # one copy of the stack
+    before = {id(a) for a in jax.live_arrays() if a.nbytes >= size}
+    eng = ResidentEngine(corpus, extract_config())
+    eng.warmup([(2, 600)])
+    cc = eng.compile_count
+    assert eng.bucket_stats()["paths"]["q128k1024"] == "multipass"
+    rng = np.random.default_rng(94)
+    q = rng.uniform(-10, 10, (2, 4))
+    ks = np.asarray([520, 600], np.int32)
+    got = format_results(eng.solve_batch(q, ks))
+    assert got == solo_and_golden(corpus, q, ks, extract_config())
+    assert eng.last_mp_passes > 1 and eng.compile_count == cc
+    assert not hasattr(eng, "_mp_full")
+    assert eng._chunks.nbytes == size
+    mine = {id(a) for a in jax.live_arrays() if a.nbytes >= size} - before
+    assert mine <= {id(eng._chunks), id(eng._d_attrs)}
+
+
 # -- admission control --------------------------------------------------------
 
 def test_admission_memory_budget_sheds_before_solve():

@@ -6,6 +6,7 @@ solve?" from ``stats``; the kernels carry their names."""
 
 from __future__ import annotations
 
+import itertools
 import json
 import re
 import socket
@@ -137,9 +138,11 @@ def test_span_args_say_what_the_work_was(traced):
     assert ev["serve.micro_batch"]["queries"] == 3      # as before PR 25
     assert ev["serve.solve_stage"]["qpad"] == \
         ev["serve.micro_batch"]["qpad"]
+    # one program folds every scheduled chunk (one, at 2400 rows)
     loop = ev["serve.solve_extract"]
-    assert loop["dispatches"] >= 1
-    assert loop["kernel_dispatch_ms"] >= 0 and loop["throttle_wait_ms"] >= 0
+    assert loop["dispatches"] == 1
+    assert loop["chunks"] == loop["scheduled"] == 1
+    assert loop["kernel_dispatch_ms"] >= 0 and loop["throttle_wait_ms"] == 0
     assert ev["single.hazard"]["rows"] == 2400
     # the hazard test read the engine's resident max row norm
     assert ev["single.hazard"]["dn_max_cached"] is True
@@ -183,6 +186,86 @@ def test_the_norm_pass_is_set_up_and_no_hazard_span_holds_it(traced):
     assert not any(inside(pas, h) for h in hazards)
     assert all(pas["ts"] + pas["dur"] <= h["ts"] for h in hazards)
     assert all(h["args"]["dn_max_cached"] is True for h in hazards)
+
+
+# -- the programs one micro-batch runs ------------------------------------------
+
+def _programs(events, lo, hi):
+    """Names of the compiled programs the host dispatched in [lo, hi):
+    the profiler's ``PjitFunction(name)`` host events (nested repeats
+    of one call folded) — and how many executables ran there."""
+    inside_ = [(s, n) for s, n in events if lo <= s < hi]
+    names = [n[len("PjitFunction("):-1] for _, n in inside_
+             if n.startswith("PjitFunction(")]
+    runs = sum(n == "PjRtCpuExecutable::Execute" for _, n in inside_)
+    return [n for n, _ in itertools.groupby(names)], runs
+
+
+@pytest.fixture(scope="module")
+def profiled_batch(tmp_path_factory):
+    """One warm micro-batch over three resident chunks, under the
+    Tracer's own profiler capture (``annotate`` mirrors the spans into
+    it): the host thread's events and the span args."""
+    import glob
+    from dmlp_tpu.serve.engine import ResidentEngine
+    rng = np.random.default_rng(8)
+    n = 30000                               # 3 chunks of 12800 rows
+    corpus = KNNInput(Params(n, 0, 4),
+                      rng.integers(0, 4, n).astype(np.int32),
+                      rng.uniform(-10, 10, (n, 4)),
+                      np.zeros(0, np.int32), np.zeros((0, 4)))
+    eng = ResidentEngine(corpus, EngineConfig(
+        use_pallas=True, select="extract", data_block=12800))
+    q, ks = rng.uniform(-10, 10, (5, 4)), np.full(5, 4, np.int32)
+    eng.warmup([(5, 4)])
+    eng.solve_batch(q, ks)
+    out = str(tmp_path_factory.mktemp("profile"))
+    tracer = obs_trace.install(obs_trace.Tracer(annotate=True,
+                                                profile_dir=out))
+    try:
+        assert tracer._profiling, "jax.profiler would not start"
+        eng.solve_batch(q, ks)
+    finally:
+        obs_trace.uninstall()
+        tracer.write(out + "/spans.json")
+    (pb,) = glob.glob(out + "/plugins/profile/*/*.xplane.pb")
+    spans = {e["name"]: e.get("args", {}) for e in tracer.events()
+             if e.get("ph") == "X"}
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            events = [(e.start_ns, e.name) for e in line.events]
+            if any(n == "serve.solve_extract" for _, n in events):
+                at = {n: s for s, n in events}
+                ends = {e.name: e.start_ns + e.duration_ns
+                        for e in line.events}
+                return {"events": events, "at": at, "ends": ends,
+                        "spans": spans}
+    raise AssertionError("the capture holds no serve.solve_extract")
+
+
+def test_the_fold_is_one_program_a_batch(profiled_batch):
+    at, ends = profiled_batch["at"], profiled_batch["ends"]
+    names, runs = _programs(profiled_batch["events"],
+                            at["serve.solve_extract"],
+                            ends["serve.solve_extract"])
+    assert (names, runs) == (["_fold_stack"], 1)
+    loop = profiled_batch["spans"]["serve.solve_extract"]
+    assert (loop["dispatches"], loop["chunks"], loop["scheduled"]) \
+        == (1, 3, 3)
+
+
+def test_no_eager_gate_counter_between_stage_and_fetch(profiled_batch):
+    """From the start of serve.solve_stage to the readback (where
+    serve.solve_epilogue ends) the host dispatches the prune scorer,
+    the fold, and the epilogue's two programs: no per-chunk
+    ``jit_equal`` / ``jit__reduce_sum`` / ``jit_add``."""
+    at = profiled_batch["at"]
+    names, runs = _programs(profiled_batch["events"],
+                            at["serve.solve_stage"], at["single.fetch"])
+    assert not {"equal", "_reduce_sum", "add"} & set(names)
+    assert names == ["_score", "_fold_stack", "_extract_finalize",
+                     "_boundary_cols"]
+    assert runs == len(names)
 
 
 # -- without a tracer -----------------------------------------------------------

@@ -65,6 +65,37 @@ def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
         call[:120]
 
 
+def test_resident_fold_program_compiles_for_v5e(v5e):
+    """The serving engine's one-program fold at ``bigann.bulk``'s shape
+    (q1024, 82 resident chunks of 51 200 x 128 float32, kcap 32): a
+    while loop whose trip count is data, the ``_fresh`` kernel before
+    it and the carried kernel in its body, so a device trace shows one
+    kernel event a chunk (benchmark/readers/kernel_ms.py counts them)."""
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    compiled = _fold_stack.lower(
+        spec((1024, 128), jnp.float32), spec((82, 51200, 128), jnp.float32),
+        spec((82,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        **_kernel_statics("fused", 32, 51200, 1024, 128, "f32", False)
+    ).compile()
+    hlo = compiled.as_text()
+    calls = [line.lstrip().removeprefix("ROOT ").split(" ", 1)[0]
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(c.rsplit(".", 1)[0] for c in calls) == [
+        "%dmlp_topk_fused", "%dmlp_topk_fused_fresh"], calls
+    assert " while(" in hlo
+    # the stack is an argument, not a constant, and nothing else of its
+    # size is allocated: the program holds no second corpus
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 82 * 51200 * 128 * 4
+    assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
+
+
 @pytest.mark.slow   # ~25 s, nearly all of it XLA:TPU compiling the merge sort
 def test_sharded_engine_program_compiles_for_v5e_2x2(v5e):
     """The all-gather-merge mesh program, kernel inside shard_map, on
