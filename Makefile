@@ -5,11 +5,11 @@
 CXX ?= g++
 CXXFLAGS ?= -O3 -Wall -shared -fPIC
 
-.PHONY: all native test tier1 bench obs-smoke obs-dist-smoke tune-smoke \
-	perf-gate check lint chaos-smoke telemetry-smoke serve-smoke \
+.PHONY: all native test tier1 obs-smoke obs-dist-smoke tune-smoke \
+	check lint chaos-smoke telemetry-smoke serve-smoke \
 	race-smoke prune-smoke precision-smoke fleet-smoke \
 	fleet-chaos-smoke fleet-trace-smoke slo-smoke auto-smoke \
-	hlo-smoke serve-bench fleet-bench clean
+	hlo-smoke fleet-bench clean
 
 all: native
 
@@ -18,7 +18,7 @@ native: native/_fastparse.so
 native/_fastparse.so: native/fastparse.cpp
 	$(CXX) $(CXXFLAGS) -o $@ $<
 
-test: obs-smoke obs-dist-smoke tune-smoke perf-gate check lint \
+test: obs-smoke obs-dist-smoke tune-smoke check lint \
 	chaos-smoke telemetry-smoke serve-smoke race-smoke prune-smoke \
 	precision-smoke fleet-smoke fleet-chaos-smoke fleet-trace-smoke \
 	slo-smoke auto-smoke hlo-smoke
@@ -60,15 +60,15 @@ check:
 # Generic hygiene (the conservative ruff subset, pyproject [tool.ruff]):
 # ruff when the environment has it, plus the checker's built-in R0
 # family either way — this container ships no ruff, so R0 IS the gate
-# here, over the package, tools, tests, and bench.py.
+# here, over the package, tools and tests.
 lint:
 	@if command -v ruff >/dev/null 2>&1; then \
-	  ruff check dmlp_tpu tools tests bench.py; \
+	  ruff check dmlp_tpu tools tests; \
 	else \
 	  echo "ruff not installed; R0 family covers the same rule set"; \
 	fi
 	JAX_PLATFORMS=cpu python -m dmlp_tpu.check --families R0 \
-	  --no-baseline dmlp_tpu tools tests bench.py
+	  --no-baseline dmlp_tpu tools tests
 
 # Tier-1 no-regression guard (ROADMAP "Tier-1 verify"): on this
 # container's jax (0.4.37, CPU backend) the suite must hold >= 277
@@ -80,10 +80,6 @@ lint:
 tier1:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
 	  --continue-on-collection-errors
-
-# One-line JSON benchmark on the current backend (TPU under the default env).
-bench:
-	python bench.py
 
 # Observability smoke: run bench config 1 through the real CLI with
 # --trace/--metrics on CPU, then validate the artifacts' structural
@@ -123,21 +119,6 @@ tune-smoke:
 	JAX_PLATFORMS=cpu python -m dmlp_tpu.tune \
 	  --validate outputs/tune_smoke_cache.json
 
-# Perf ledger + regression sentinel: build the ledger over every root
-# artifact (schema RunRecords + grandfathered legacy shapes; >= 90%
-# parsed or the smoke fails, the rest explicit unparseable entries),
-# write the trajectory report, then gate tracked series — a round that
-# regresses a gated series beyond its noise band on comparable devices
-# fails the build (honest insufficient_trials / device_mismatch
-# markers never do).
-perf-gate:
-	mkdir -p outputs
-	JAX_PLATFORMS=cpu python -m dmlp_tpu.report \
-	  --out outputs/LEDGER.json --md outputs/PERF_REPORT.md \
-	  --min-coverage 0.9
-	JAX_PLATFORMS=cpu python tools/perf_gate.py \
-	  --ledger outputs/LEDGER.json
-
 # Chaos smoke (README "Resilience & chaos testing"): bench config 1 and
 # a short --nan-guard train run replayed under three seeded fault
 # schedules (straggler delays, transient exceptions + corrupt parse,
@@ -147,7 +128,7 @@ perf-gate:
 # resilience counters and resilience.* trace events, one schedule must
 # replay with a bit-identical injection log, and the zero-fault overhead
 # of the wrappers is measured with an interleaved on/off A/B into a
-# ledger-ingestible RunRecord.
+# RunRecord.
 chaos-smoke:
 	mkdir -p outputs/chaos
 	rm -f outputs/chaos/CHAOS_SMOKE.jsonl
@@ -162,8 +143,8 @@ chaos-smoke:
 # peak-HBM model reconciled against the measured watermark within the
 # documented basis bounds (or the explicit marker), a FLIGHT_*.json
 # post-mortem left by a retries-exhausted fault, and the overhead +
-# watermark numbers round-tripped through the perf ledger as a
-# telemetry/ series with raw per-arm samples.
+# watermark numbers written as a kind="telemetry" RunRecord with raw
+# per-arm samples.
 telemetry-smoke:
 	mkdir -p outputs/telemetry
 	rm -f outputs/telemetry/TELEMETRY_SMOKE.jsonl
@@ -180,7 +161,7 @@ telemetry-smoke:
 # injected memory squeeze shed by admission control (visible rejection,
 # no ladder degradation), wire ingestion verified against the grown
 # corpus, and a SIGTERM drain that exits 0 with no flight dump — with
-# the serve RunRecord round-tripped through the perf ledger.
+# the serve RunRecord read back (RunRecord.load).
 serve-smoke:
 	mkdir -p outputs/serve
 	rm -f outputs/serve/SERVE_SMOKE.jsonl
@@ -237,8 +218,8 @@ precision-smoke:
 # (inputs/serve_trace2.jsonl) replayed closed-loop THROUGH the router
 # is byte-identical to the golden oracle with traffic actually fanned;
 # compile counters stay flat on both replicas; paced OPEN-LOOP replay
-# at two offered-load multipliers lands p50/p95/p99 in gated
-# fleet/<level>/ ledger series; a wide-k request (k past the kernel's
+# at two offered-load multipliers lands p50/p95/p99 in one
+# kind="fleet" RunRecord a level; a wide-k request (k past the kernel's
 # single-pass window) serves through the multipass driver against the
 # resident chunks, golden and compile-flat; one ingest through the
 # router fans out to every replica and the grown-corpus replay stays
@@ -271,9 +252,8 @@ fleet-smoke:
 # one replica's ingest: the router reports the divergence, the health
 # prober's corpus-checksum comparison detects it, and the targeted
 # delta re-ingest repairs it — counters non-vacuous, repaired fleet
-# golden, every process exits 0, no flight dumps. The chaos RunRecords
-# round-trip the perf ledger as gated fleet/chaos_*/ series
-# (FLEET_CHAOS_r15.jsonl is the committed round).
+# golden, every process exits 0, no flight dumps. Each campaign
+# writes one kind="fleet" RunRecord, read back at the end.
 fleet-chaos-smoke:
 	mkdir -p outputs/fleet_chaos
 	rm -f outputs/fleet_chaos/FLEET_CHAOS_SMOKE.jsonl
@@ -291,9 +271,8 @@ fleet-chaos-smoke:
 # queue->coalesce->solve->finalize->write, phase sums reconciling with
 # client latency within tolerance. (4) check_trace --fleet passes the
 # merged trace and REJECTS a tampered one (fabricated retry hop).
-# (5) tail_attrib names each level's dominant phase and its
-# fleet/<level>/phase/*_p99_ms RunRecords ledger-ingest and perf-gate
-# (TAILATTRIB_r16.jsonl is the committed round).
+# (5) tail_attrib names each level's dominant phase and writes its
+# per-level phase p99s as kind="fleet" RunRecords.
 fleet-trace-smoke:
 	mkdir -p outputs/fleet_trace
 	rm -f outputs/fleet_trace/TAILATTRIB.jsonl
@@ -311,9 +290,8 @@ fleet-trace-smoke:
 # slo.alert stream validated by check_trace --fleet after the causal
 # merge) while the predictive arm follows the canary burn rate and
 # scales ahead of the hot level with zero customer-objective burn;
-# both arms byte-identical to the golden oracle, both ramp RunRecords
-# ledger-ingested as gated slo/<arm>/ series (SLO_r17.jsonl is the
-# committed round).
+# both arms byte-identical to the golden oracle, one kind="slo" ramp
+# RunRecord an arm.
 slo-smoke:
 	mkdir -p outputs/slo
 	rm -f outputs/slo/SLO_SMOKE.jsonl
@@ -322,16 +300,10 @@ slo-smoke:
 	  --record outputs/slo/SLO_SMOKE.jsonl
 
 # Compiler-sharded engine smoke (README "Compiler-driven sharding &
-# persistent compile cache"): (1) the `--engine auto` CLI alias
-# end-to-end on bench input 1 — contract stdout byte-identical to the
-# default single-chip run (and hence to the f64 golden oracle the
-# bench step diffs below); (2) bench --auto-ab on config 1:
-# interleaved auto/sharded/ring arms with byte-identity asserted
-# before any timing enters the record and the warmup-compile split
-# broken out per arm; (3) the kind="auto" RunRecord round-trips the
-# perf ledger as a gated auto/config1/ series. The warm-relaunch
-# cold-start check (persistent compile cache) lives in
-# fleet-chaos-smoke campaign 4.
+# persistent compile cache"): the `--engine auto` CLI alias end-to-end
+# on bench input 1 — contract stdout byte-identical to the default
+# single-chip run. The warm-relaunch cold-start check (persistent
+# compile cache) lives in fleet-chaos-smoke campaign 4.
 auto-smoke:
 	mkdir -p outputs/auto
 	JAX_PLATFORMS=cpu python -c "from dmlp_tpu.bench.configs import BENCH_CONFIGS; \
@@ -344,19 +316,6 @@ auto-smoke:
 	  > outputs/auto/auto.out 2> outputs/auto/auto.err
 	grep -q "Time taken:" outputs/auto/auto.err
 	cmp outputs/auto/single.out outputs/auto/auto.out
-	rm -f outputs/auto/AUTO_SMOKE.jsonl
-	JAX_PLATFORMS=cpu python -m dmlp_tpu.bench 1 --auto-ab --reps 2 \
-	  --metrics outputs/auto/AUTO_SMOKE.jsonl \
-	  | tee outputs/auto/bench.out
-	grep -q "byte-identical" outputs/auto/bench.out
-	JAX_PLATFORMS=cpu python -c "import sys; \
-	from dmlp_tpu.obs.ledger import ingest_file; \
-	e = ingest_file('outputs/auto/AUTO_SMOKE.jsonl'); \
-	assert e['status'] == 'parsed', e; \
-	s = {p['series'] for p in e['points']}; \
-	assert any(x.startswith('auto/config1/') for x in s), sorted(s); \
-	sys.path.insert(0, 'tools'); import perf_gate as pg; \
-	assert pg.gated('auto/config1/engine_ms_auto')"
 
 # Compiled-program introspection smoke (README "Compiler
 # introspection"): bench input 1 through the real CLI per engine mode
@@ -368,17 +327,16 @@ auto-smoke:
 # engine's report names at least one partitioner-chosen collective
 # with nonzero per-mesh-axis bytes and exactly-reconciling gspmd_*
 # records; the memory leg carries hlo_peak_bytes or the explicit
-# hlo_memory_unavailable marker; and each kind="hlo" RunRecord
-# round-trips the ledger as a gated hlo/<mode>/ series
-# (HLO_r20.jsonl is the committed round).
+# hlo_memory_unavailable marker; and each mode writes one kind="hlo"
+# RunRecord.
 hlo-smoke:
 	mkdir -p outputs/hlo
 	JAX_PLATFORMS=cpu python tools/hlo_smoke.py --out outputs/hlo
 
-# Fleet SLO bench (not in `make test`; emits the FLEET_rNN ledger
-# rounds): 2 replicas (one mesh-resident) + router, the paced trace
-# replayed OPEN-LOOP at a sweep of offered-load multipliers, 3 reps
-# per level — the p99-under-offered-load curve, gated by perf_gate.
+# Fleet load sweep (not in `make test`; CPU rehearsal of the fleet
+# path, not a performance record: PERF.md): 2 replicas (one
+# mesh-resident) + router, the paced trace replayed OPEN-LOOP at a
+# sweep of offered-load multipliers, 3 reps per level.
 # On a TPU host drop JAX_PLATFORMS and add
 # --replica-flags "--pallas --select extract".
 fleet-bench:
@@ -386,16 +344,6 @@ fleet-bench:
 	JAX_PLATFORMS=cpu python tools/fleet_bench.py \
 	  --metrics outputs/fleet_bench/FLEET_BENCH.jsonl \
 	  --out outputs/fleet_bench --replicas 2 --mesh-replica --reps 3
-
-# Serving throughput bench (not in `make test`; emits the SERVE_rNN
-# ledger rounds): replay inputs/serve_trace1.jsonl against the daemon
-# in interleaved gate-carry on/off arms. On a TPU host drop
-# JAX_PLATFORMS and keep the pallas flags.
-serve-bench:
-	mkdir -p outputs
-	python -m dmlp_tpu.bench serve --reps 2 \
-	  --metrics outputs/SERVE_BENCH.jsonl \
-	  --serve-flags "--pallas --select extract --data-block 12800"
 
 clean:
 	rm -f native/_fastparse.so

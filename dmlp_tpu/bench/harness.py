@@ -1,4 +1,4 @@
-"""Benchmark orchestration: generate -> oracle (cached) -> engine -> compare.
+"""Differential check: generate -> oracle (cached) -> engine -> compare.
 
 The TPU-native run_bench.sh. Per config (configs.py):
 
@@ -161,7 +161,6 @@ def run_engine(cfg: BenchConfig, input_path: str, outputs_dir: str,
     CLI — the per-config observability capture.
     """
     import subprocess
-    import sys
 
     argv = [sys.executable, "-m", "dmlp_tpu", "--mode", mode or cfg.mode]
     argv += _engine_flags(cfg, mode or cfg.mode)
@@ -206,7 +205,6 @@ def run_engine_multiproc(cfg: BenchConfig, input_path: str, outputs_dir: str,
     import concurrent.futures as cf
     import socket
     import subprocess
-    import sys
 
     def launch_once():
         # NOTE: probe-then-rebind has an inherent TOCTOU window (another
@@ -277,11 +275,6 @@ def run_config(config_id: int, base_dir: str = ".",
                counters: bool = False,
                record_path: Optional[str] = None,
                profile_dir: Optional[str] = None,
-               obs_overhead: bool = False,
-               fused_ab: bool = False,
-               prune_ab: bool = False,
-               precision_ab: bool = False,
-               auto_ab: bool = False,
                telemetry_dir: Optional[str] = None) -> dict:
     """Full benchmark flow for one config; returns a result summary dict.
 
@@ -308,29 +301,7 @@ def run_config(config_id: int, base_dir: str = ".",
     virtual-CPU platform (``cfg.virtual_devices``) or an environment
     pinned to CPU records the explicit ``profile_unavailable`` marker
     instead of a capture — never a silently absent artifact.
-
-    ``obs_overhead`` SELF-MEASURES the observability layer's cost: the
-    engine runs in interleaved pairs — tracing+counters OFF then ON
-    (order alternating per pair) — and the result records
-    ``obs_overhead_pct``
-    (median-on vs median-off engine time) plus both raw sample lists,
-    so the obs layer's own overhead becomes a tracked ledger series
-    instead of a "<2%, trust us" claim. Single-process configs only;
-    failures record the explicit ``obs_overhead_unavailable`` marker.
-
-    ``fused_ab`` A/B-measures the fused distance→top-k megakernel
-    (ops.pallas_fused) against the two-pass pipeline it replaces: the
-    engine runs in interleaved ``DMLP_TPU_FUSED=1`` / ``=0`` pairs
-    (same alternating order), BOTH arms' stdout
-    must be byte-identical (the fused kernel's contract), and the
-    result records ``engine_ms_fused`` / ``engine_ms_two_pass``
-    medians with raw per-arm sample lists — the fused win (or loss)
-    becomes a gated ledger series (`tools/perf_gate.py`), not a prose
-    claim. Single-process configs only; failures and byte mismatches
-    record the explicit ``fused_ab_unavailable`` / identity fields.
     """
-    import sys
-
     out = out or sys.stdout
     cfg = BENCH_CONFIGS[config_id]
     if cfg.timeout_s is not None:
@@ -345,8 +316,7 @@ def run_config(config_id: int, base_dir: str = ".",
     # claim the chip the engine subprocesses need). The RunRecord takes
     # its device from the engine's own device stamp where the run wrote
     # a metrics file (--trace), and stays unset otherwise rather than
-    # guessed — the ledger's device_mismatch guard treats that as
-    # unspecified.
+    # guessed.
     cpu_pinned = bool(cfg.virtual_devices) or (
         (env if env is not None else os.environ)
         .get("JAX_PLATFORMS", "") == "cpu")
@@ -480,7 +450,6 @@ def run_config(config_id: int, base_dir: str = ".",
            "oracle_ms": oracle_ms, "engine_ms": _extract_ms(ee),
            "percent_vs_oracle": percent}
     if len(rep_ms) > 1:
-        import statistics
         res["engine_ms"] = round(statistics.median(rep_ms))
         res["engine_ms_reps"] = rep_ms
         if oracle_ms:
@@ -503,656 +472,10 @@ def run_config(config_id: int, base_dir: str = ".",
         # backend rejected the capture): an explicit marker, not a
         # RunRecord pointing at an empty directory.
         profile = ("unavailable", "engine wrote no capture")
-    if obs_overhead:
-        res.update(_measure_obs_overhead(
-            cfg, input_path, outputs_dir, out, mode=mode, fast=fast,
-            timeout_s=timeout_s, env=env, pairs=n_reps))
-    if fused_ab:
-        res.update(_measure_fused_ab(
-            cfg, input_path, outputs_dir, out, mode=mode, fast=fast,
-            timeout_s=timeout_s, env=env, pairs=n_reps,
-            oracle_want=want if check_reps else None))
-    if prune_ab:
-        prune_res = _measure_prune_ab(
-            cfg, input_path, outputs_dir, out, mode=mode, fast=fast,
-            timeout_s=timeout_s, env=env, pairs=n_reps,
-            oracle_want=want if check_reps else None)
-        res.update(prune_res)
-        if record_path:
-            # A dedicated kind="prune" RunRecord so the A/B lands in
-            # the ledger's ``prune/configN/...`` family (gated by
-            # tools/perf_gate.py) alongside the plain bench record.
-            import dataclasses as _dc
-
-            from dmlp_tpu.obs.run import RunRecord, round_from_name
-            RunRecord(kind="prune", tool="dmlp_tpu.bench",
-                      config=_dc.asdict(cfg), metrics=dict(prune_res),
-                      device="cpu" if cpu_pinned else None,
-                      round=round_from_name(record_path)
-                      ).append_jsonl(record_path)
-    if precision_ab:
-        prec_res = _measure_precision_ab(
-            cfg, input_path, outputs_dir, out, mode=mode, fast=fast,
-            timeout_s=timeout_s, env=env, pairs=n_reps,
-            oracle_want=want if check_reps else None)
-        res.update(prec_res)
-        if record_path:
-            # A dedicated kind="precision" RunRecord so the A/B lands
-            # in the ledger's ``precision/configN/...`` family (gated
-            # by tools/perf_gate.py) alongside the plain bench record.
-            import dataclasses as _dc
-
-            from dmlp_tpu.obs.run import RunRecord, round_from_name
-            RunRecord(kind="precision", tool="dmlp_tpu.bench",
-                      config=_dc.asdict(cfg), metrics=dict(prec_res),
-                      device="cpu" if cpu_pinned else None,
-                      round=round_from_name(record_path)
-                      ).append_jsonl(record_path)
-    if auto_ab:
-        auto_res = _measure_auto_ab(
-            cfg, input_path, outputs_dir, out, fast=fast,
-            timeout_s=timeout_s, env=env, pairs=n_reps,
-            oracle_want=want if check_reps else None)
-        res.update(auto_res)
-        if record_path:
-            # A dedicated kind="auto" RunRecord so the compiler-vs-
-            # hand-rolled A/B lands in the ledger's ``auto/configN/...``
-            # family (gated by tools/perf_gate.py) alongside the plain
-            # bench record.
-            import dataclasses as _dc
-
-            from dmlp_tpu.obs.run import RunRecord, round_from_name
-            RunRecord(kind="auto", tool="dmlp_tpu.bench",
-                      config=_dc.asdict(cfg), metrics=dict(auto_res),
-                      device="cpu" if cpu_pinned else None,
-                      round=round_from_name(record_path)
-                      ).append_jsonl(record_path)
     if record_path:
         _append_run_record(record_path, cfg, res, trace_dir,
                            profile=profile, cpu_pinned=cpu_pinned,
                            telemetry_dir=telemetry_dir)
-    return res
-
-
-def _measure_obs_overhead(cfg: BenchConfig, input_path: str,
-                          outputs_dir: str, out: TextIO,
-                          mode: Optional[str], fast: bool,
-                          timeout_s: float, env: Optional[dict],
-                          pairs: int) -> dict:
-    """Interleaved obs-on/obs-off engine timings -> the
-    ``obs_overhead_pct`` fields (see run_config docstring). "On" means
-    the full opt-in capture stack: span tracing + metrics JSONL +
-    cost-analysis counters, exactly what ``--trace/--metrics/
-    --counters`` enable. Never raises: any failed run yields the
-    explicit ``obs_overhead_unavailable`` marker instead."""
-    import statistics
-
-    if cfg.procs > 1:
-        return {"obs_overhead_unavailable": "multi-process config "
-                "(observability capture is single-process only)"}
-    on_flags = ["--trace",
-                os.path.join(outputs_dir,
-                             f"obs_overhead_trace_c{cfg.config_id}.json"),
-                "--metrics",
-                os.path.join(outputs_dir,
-                             f"obs_overhead_metrics_c{cfg.config_id}.jsonl"),
-                "--counters"]
-    times: dict = {"off": [], "on": []}
-    try:
-        for rep in range(max(pairs, 1)):
-            order = ("off", "on") if rep % 2 == 0 else ("on", "off")
-            for arm in order:
-                _, err_path = run_engine(
-                    cfg, input_path, outputs_dir, mode=mode, fast=fast,
-                    timeout_s=timeout_s, env=env,
-                    obs_flags=on_flags if arm == "on" else None)
-                with open(err_path) as f:
-                    ms = _extract_ms(f.read())
-                if ms is None:
-                    return {"obs_overhead_unavailable":
-                            f"no timing line in the {arm}-arm run"}
-                times[arm].append(ms)
-    except (EngineTimeout, RuntimeError) as e:
-        return {"obs_overhead_unavailable":
-                f"engine run failed during the A/B: {e}"}
-    med_off = statistics.median(times["off"])
-    med_on = statistics.median(times["on"])
-    if med_off <= 0:
-        return {"obs_overhead_unavailable":
-                "off-arm median rounded to 0 ms (a percentage would "
-                "be meaningless)", "engine_ms_obs_off": times["off"],
-                "engine_ms_obs_on": times["on"]}
-    pct = (med_on - med_off) / med_off * 100.0
-    out.write(f"Config {cfg.config_id}: obs overhead "
-              f"{pct:+.1f}% (median {med_off} -> {med_on} ms over "
-              f"{len(times['off'])} interleaved pair(s))\n")
-    return {"obs_overhead_pct": round(pct, 2),
-            "engine_ms_obs_off": times["off"],
-            "engine_ms_obs_on": times["on"]}
-
-
-def _measure_fused_ab(cfg: BenchConfig, input_path: str,
-                      outputs_dir: str, out: TextIO,
-                      mode: Optional[str], fast: bool,
-                      timeout_s: float, env: Optional[dict],
-                      pairs: int, oracle_want: Optional[str]) -> dict:
-    """Interleaved fused-megakernel vs two-pass engine timings (see
-    run_config docstring): ``DMLP_TPU_FUSED=1`` against ``=0``, order
-    alternating per pair so both arms share machine conditions. Three results
-    ride in the record:
-
-    - ``engine_ms_fused`` / ``engine_ms_two_pass`` medians plus the raw
-      ``*_reps`` lists (the ledger's per-trial evidence — the fused win
-      becomes a gated series, `tools/perf_gate.py`);
-    - ``fused_ab_pct``: median fused vs two-pass (negative = fused
-      faster);
-    - ``fused_ab_identical``: every fused-arm stdout byte-equal to every
-      two-pass-arm stdout (and to the oracle when the run is in exact
-      mode) — the megakernel's bit-identity contract, CHECKED per run,
-      not assumed. A mismatch marks the A/B unavailable (a wrong-output
-      arm's timing must not become a ledger point).
-
-    The A/B is never VACUOUS: both arms run with ``--metrics``
-    (symmetric, so the tiny cost-probe overhead cancels in the
-    comparison) and the fused arm's summary must report
-    ``extract_impl == "fused"`` — a config whose dispatch shape the
-    fused kernel does not support (or that never takes an extract-kernel
-    path at all) records the explicit ``fused_ab_vacuous`` marker
-    instead of an identical-code pair masquerading as a gated series.
-
-    Never raises: failures record ``fused_ab_unavailable``."""
-    import json
-    import statistics
-
-    if cfg.procs > 1:
-        return {"fused_ab_unavailable": "multi-process config (the A/B "
-                "drives the single-process engine CLI)"}
-    base_env = dict(env if env is not None else os.environ)
-    times: dict = {"fused": [], "two_pass": []}
-    outputs: dict = {"fused": set(), "two_pass": set()}
-    impls: dict = {"fused": set(), "two_pass": set()}
-    arm_env = {"fused": "1", "two_pass": "0"}
-    metrics_paths = {
-        arm: os.path.join(outputs_dir,
-                          f"fused_ab_metrics_{arm}_c{cfg.config_id}.jsonl")
-        for arm in arm_env}
-    for mpath in metrics_paths.values():
-        if os.path.exists(mpath):   # metrics JSONL appends; start clean
-            os.remove(mpath)
-    try:
-        for rep in range(max(pairs, 1)):
-            order = ("two_pass", "fused") if rep % 2 == 0 \
-                else ("fused", "two_pass")
-            for arm in order:
-                e = dict(base_env)
-                e["DMLP_TPU_FUSED"] = arm_env[arm]
-                out_path, err_path = run_engine(
-                    cfg, input_path, outputs_dir, mode=mode, fast=fast,
-                    timeout_s=timeout_s, env=e,
-                    obs_flags=["--metrics", metrics_paths[arm]])
-                with open(out_path) as f:
-                    outputs[arm].add(f.read())
-                with open(err_path) as f:
-                    ms = _extract_ms(f.read())
-                if ms is None:
-                    return {"fused_ab_unavailable":
-                            f"no timing line in the {arm}-arm run"}
-                times[arm].append(ms)
-    except (EngineTimeout, RuntimeError) as e:
-        return {"fused_ab_unavailable":
-                f"engine run failed during the A/B: {e}"}
-    metrics_err = None
-    for arm, mpath in metrics_paths.items():
-        try:
-            with open(mpath) as f:
-                for line in f:
-                    rec = json.loads(line)
-                    if rec.get("event") == "summary":
-                        impls[arm].add(rec.get("extract_impl"))
-        except (OSError, ValueError) as e:
-            metrics_err = f"{arm}-arm metrics channel unreadable: {e}"
-    identical = (len(outputs["fused"]) == 1
-                 and outputs["fused"] == outputs["two_pass"]
-                 and (oracle_want is None
-                      or outputs["fused"] == {oracle_want}))
-    if not identical:
-        return {"fused_ab_unavailable":
-                "fused/two-pass stdout MISMATCH — bit-identity contract "
-                "violated; timings withheld", "fused_ab_identical": False}
-    if metrics_err is not None or not impls["fused"]:
-        # The vacuity check below needs a parsed summary per arm; an
-        # unreadable/empty metrics channel is an INFRASTRUCTURE failure,
-        # not evidence about which kernel the config dispatches — report
-        # it as unavailable, never as vacuous (timings withheld: an A/B
-        # whose arms we cannot attribute must not become a gated series).
-        return {"fused_ab_identical": True,
-                "fused_ab_unavailable": metrics_err
-                or "no engine summary parsed from the A/B metrics "
-                   "channel — cannot attribute the arms to kernels"}
-    if impls["fused"] != {"fused"}:
-        # Identical arms AND the fused arm never dispatched the fused
-        # kernel: the pair measured the same code twice. An honest
-        # marker, not a ledger series (timings withheld).
-        return {"fused_ab_vacuous": True,
-                "fused_ab_identical": True,
-                "fused_ab_unavailable":
-                    "the DMLP_TPU_FUSED=1 arm dispatched "
-                    f"{sorted(str(i) for i in impls['fused'])} (not the "
-                    "fused kernel) — this config's solve never takes "
-                    "the fused path; an identical-code A/B must not "
-                    "become a gated series"}
-    med_f = statistics.median(times["fused"])
-    med_t = statistics.median(times["two_pass"])
-    res = {"fused_ab_identical": True,
-           "fused_ab_impls": {a_: sorted(str(i) for i in v)
-                              for a_, v in impls.items()},
-           "engine_ms_fused": round(med_f),
-           "engine_ms_fused_reps": times["fused"],
-           "engine_ms_two_pass": round(med_t),
-           "engine_ms_two_pass_reps": times["two_pass"]}
-    if med_t > 0:
-        pct = (med_f - med_t) / med_t * 100.0
-        res["fused_ab_pct"] = round(pct, 2)
-        out.write(f"Config {cfg.config_id}: fused A/B {pct:+.1f}% "
-                  f"(median {med_t} -> {med_f} ms over "
-                  f"{len(times['fused'])} interleaved pair(s), "
-                  "byte-identical)\n")
-    return res
-
-
-def _measure_prune_ab(cfg: BenchConfig, input_path: str,
-                      outputs_dir: str, out: TextIO,
-                      mode: Optional[str], fast: bool,
-                      timeout_s: float, env: Optional[dict],
-                      pairs: int, oracle_want: Optional[str]) -> dict:
-    """Interleaved pruned vs dense engine timings: ``DMLP_TPU_PRUNE=1``
-    against ``=0``, order alternating per pair (the repo's interleaved
-    A/B methodology). The record carries:
-
-    - ``engine_ms_pruned`` / ``engine_ms_dense`` medians plus raw
-      ``*_reps`` lists (ledger per-trial evidence -> a gated
-      ``prune/configN/...`` series);
-    - ``scanned_bytes_pruned`` / ``scanned_bytes_dense`` /
-      ``scanned_bytes_ratio`` from the engines' scan accounting
-      (ops.summaries.note_scan via the CLI metrics summary) — the
-      bytes claim as a checked number, both ways;
-    - ``prune_ab_identical``: every pruned-arm stdout byte-equal to
-      every dense-arm stdout (and the oracle in exact mode) — the
-      pruned solve's byte-identity contract, CHECKED per run;
-    - ``prune_ab_vacuous`` when the pruned arm pruned zero blocks
-      (e.g. a uniform corpus, where no block is provably out of every
-      top-k): the timings/bytes still record — a ratio of 1.0 on a
-      shape pruning cannot help is an honest measurement, unlike the
-      fused A/B's identical-code case — but the flag says so.
-
-    Never raises: failures record ``prune_ab_unavailable``."""
-    import json
-    import statistics
-
-    if cfg.procs > 1:
-        return {"prune_ab_unavailable": "multi-process config (the A/B "
-                "drives the single-process engine CLI)"}
-    base_env = dict(env if env is not None else os.environ)
-    arm_env = {"pruned": "1", "dense": "0"}
-    times: dict = {a: [] for a in arm_env}
-    outputs: dict = {a: set() for a in arm_env}
-    metrics_paths = {
-        arm: os.path.join(outputs_dir,
-                          f"prune_ab_metrics_{arm}_c{cfg.config_id}.jsonl")
-        for arm in arm_env}
-    for mpath in metrics_paths.values():
-        if os.path.exists(mpath):   # metrics JSONL appends; start clean
-            os.remove(mpath)
-    try:
-        for rep in range(max(pairs, 1)):
-            order = ("dense", "pruned") if rep % 2 == 0 \
-                else ("pruned", "dense")
-            for arm in order:
-                e = dict(base_env)
-                e["DMLP_TPU_PRUNE"] = arm_env[arm]
-                out_path, err_path = run_engine(
-                    cfg, input_path, outputs_dir, mode=mode, fast=fast,
-                    timeout_s=timeout_s, env=e,
-                    obs_flags=["--metrics", metrics_paths[arm]])
-                with open(out_path) as f:
-                    outputs[arm].add(f.read())
-                with open(err_path) as f:
-                    ms = _extract_ms(f.read())
-                if ms is None:
-                    return {"prune_ab_unavailable":
-                            f"no timing line in the {arm}-arm run"}
-                times[arm].append(ms)
-    except (EngineTimeout, RuntimeError) as e:
-        return {"prune_ab_unavailable":
-                f"engine run failed during the A/B: {e}"}
-    identical = (len(outputs["pruned"]) == 1
-                 and outputs["pruned"] == outputs["dense"]
-                 and (oracle_want is None
-                      or outputs["pruned"] == {oracle_want}))
-    if not identical:
-        return {"prune_ab_unavailable":
-                "pruned/dense stdout MISMATCH — byte-identity contract "
-                "violated; timings withheld", "prune_ab_identical": False}
-    prune_blocks: dict = {}
-    for arm, mpath in metrics_paths.items():
-        try:
-            with open(mpath) as f:
-                for line in f:
-                    rec = json.loads(line)
-                    if rec.get("event") == "summary" \
-                            and isinstance(rec.get("prune"), dict):
-                        prune_blocks[arm] = rec["prune"]
-        except (OSError, ValueError) as e:
-            return {"prune_ab_identical": True,
-                    "prune_ab_unavailable":
-                        f"{arm}-arm metrics channel unreadable: {e}"}
-    if set(prune_blocks) != set(arm_env):
-        return {"prune_ab_identical": True,
-                "prune_ab_unavailable":
-                    "no scan-accounting block in the A/B metrics "
-                    "channel — cannot attribute scanned bytes to arms"}
-    med_p = statistics.median(times["pruned"])
-    med_d = statistics.median(times["dense"])
-    sb_p = int(prune_blocks["pruned"].get("scanned_bytes", 0))
-    sb_d = int(prune_blocks["dense"].get("scanned_bytes", 0))
-    res = {"prune_ab_identical": True,
-           "engine_ms_pruned": round(med_p),
-           "engine_ms_pruned_reps": times["pruned"],
-           "engine_ms_dense": round(med_d),
-           "engine_ms_dense_reps": times["dense"],
-           "scanned_bytes_pruned": sb_p,
-           "scanned_bytes_dense": sb_d,
-           "prune_blocks_total": prune_blocks["pruned"].get(
-               "blocks_total"),
-           "prune_blocks_pruned": prune_blocks["pruned"].get(
-               "blocks_pruned", 0)}
-    if sb_d:
-        res["scanned_bytes_ratio"] = round(sb_p / sb_d, 4)
-    if not res["prune_blocks_pruned"]:
-        res["prune_ab_vacuous"] = True
-    if med_d > 0:
-        pct = (med_p - med_d) / med_d * 100.0
-        res["prune_ab_pct"] = round(pct, 2)
-        out.write(f"Config {cfg.config_id}: prune A/B {pct:+.1f}% "
-                  f"(median {med_d} -> {med_p} ms, scanned bytes "
-                  f"{sb_d} -> {sb_p}, "
-                  f"{res['prune_blocks_pruned']}/"
-                  f"{res['prune_blocks_total']} blocks pruned, "
-                  "byte-identical)\n")
-    return res
-
-
-def _measure_precision_ab(cfg: BenchConfig, input_path: str,
-                          outputs_dir: str, out: TextIO,
-                          mode: Optional[str], fast: bool,
-                          timeout_s: float, env: Optional[dict],
-                          pairs: int, oracle_want: Optional[str]) -> dict:
-    """Interleaved bf16-first-pass vs f32 engine timings:
-    ``DMLP_TPU_PRECISION=bf16`` against ``=f32``, order alternating per
-    pair (the repo's interleaved A/B methodology). The record carries:
-
-    - ``engine_ms_bf16`` / ``engine_ms_f32`` medians plus raw
-      ``*_reps`` lists (ledger per-trial evidence -> a gated
-      ``precision/configN/...`` series);
-    - ``precision_ab_identical``: every bf16-arm stdout byte-equal to
-      every f32-arm stdout (and the oracle in exact mode) — the
-      low-precision pass's byte-identity contract (lowp_eps-inflated
-      windows + unchanged f64 rescore), CHECKED per run, not assumed.
-      A mismatch withholds the timings: a wrong-output arm must never
-      become a ledger point;
-    - ``precision_kcap_f32`` / ``precision_kcap_bf16`` /
-      ``precision_kcap_inflation`` from the engines' per-arm
-      ``precision`` summary blocks (engine.last_precision) — the
-      window-inflation cost of the bound, as a checked number.
-
-    The A/B is never VACUOUS: the bf16 arm's summary must report
-    ``active == "bf16"`` — a fast-mode run (precision resolves f32
-    when there is no rescore backstop) or an engine without the lowp
-    rung records the explicit ``precision_ab_unavailable`` marker
-    instead of an identical-code pair masquerading as a gated series.
-
-    Never raises: failures record ``precision_ab_unavailable``."""
-    import json
-    import statistics
-
-    if cfg.procs > 1:
-        return {"precision_ab_unavailable": "multi-process config (the "
-                "A/B drives the single-process engine CLI)"}
-    base_env = dict(env if env is not None else os.environ)
-    arm_env = {"bf16": "bf16", "f32": "f32"}
-    times: dict = {a: [] for a in arm_env}
-    outputs: dict = {a: set() for a in arm_env}
-    metrics_paths = {
-        arm: os.path.join(
-            outputs_dir,
-            f"precision_ab_metrics_{arm}_c{cfg.config_id}.jsonl")
-        for arm in arm_env}
-    for mpath in metrics_paths.values():
-        if os.path.exists(mpath):   # metrics JSONL appends; start clean
-            os.remove(mpath)
-    try:
-        for rep in range(max(pairs, 1)):
-            order = ("f32", "bf16") if rep % 2 == 0 \
-                else ("bf16", "f32")
-            for arm in order:
-                e = dict(base_env)
-                e["DMLP_TPU_PRECISION"] = arm_env[arm]
-                out_path, err_path = run_engine(
-                    cfg, input_path, outputs_dir, mode=mode, fast=fast,
-                    timeout_s=timeout_s, env=e,
-                    obs_flags=["--metrics", metrics_paths[arm]])
-                with open(out_path) as f:
-                    outputs[arm].add(f.read())
-                with open(err_path) as f:
-                    ms = _extract_ms(f.read())
-                if ms is None:
-                    return {"precision_ab_unavailable":
-                            f"no timing line in the {arm}-arm run"}
-                times[arm].append(ms)
-    except (EngineTimeout, RuntimeError) as e:
-        return {"precision_ab_unavailable":
-                f"engine run failed during the A/B: {e}"}
-    identical = (len(outputs["bf16"]) == 1
-                 and outputs["bf16"] == outputs["f32"]
-                 and (oracle_want is None
-                      or outputs["bf16"] == {oracle_want}))
-    if not identical:
-        return {"precision_ab_unavailable":
-                "bf16/f32 stdout MISMATCH — byte-identity contract "
-                "violated; timings withheld",
-                "precision_ab_identical": False}
-    prec_blocks: dict = {}
-    for arm, mpath in metrics_paths.items():
-        try:
-            with open(mpath) as f:
-                for line in f:
-                    rec = json.loads(line)
-                    if rec.get("event") == "summary" \
-                            and isinstance(rec.get("precision"), dict):
-                        prec_blocks[arm] = rec["precision"]
-        except (OSError, ValueError) as e:
-            return {"precision_ab_identical": True,
-                    "precision_ab_unavailable":
-                        f"{arm}-arm metrics channel unreadable: {e}"}
-    if set(prec_blocks) != set(arm_env):
-        return {"precision_ab_identical": True,
-                "precision_ab_unavailable":
-                    "no precision block in the A/B metrics channel — "
-                    "cannot attribute the arms to first-pass dtypes"}
-    if prec_blocks["bf16"].get("active") != "bf16":
-        # Identical arms AND the bf16 arm never cast: the pair measured
-        # the same code twice. An honest marker, not a ledger series.
-        return {"precision_ab_vacuous": True,
-                "precision_ab_identical": True,
-                "precision_ab_unavailable":
-                    "the DMLP_TPU_PRECISION=bf16 arm ran with active "
-                    f"precision {prec_blocks['bf16'].get('active')!r} "
-                    "(fast mode, or an engine without the lowp rung) — "
-                    "an identical-code A/B must not become a gated "
-                    "series"}
-    med_b = statistics.median(times["bf16"])
-    med_f = statistics.median(times["f32"])
-    res = {"precision_ab_identical": True,
-           "engine_ms_bf16": round(med_b),
-           "engine_ms_bf16_reps": times["bf16"],
-           "engine_ms_f32": round(med_f),
-           "engine_ms_f32_reps": times["f32"]}
-    for arm in arm_env:
-        kcap = prec_blocks[arm].get("kcap")
-        if kcap is not None:
-            res[f"precision_kcap_{arm}"] = kcap
-    infl = prec_blocks["bf16"].get("kcap_inflation")
-    if infl is not None:
-        res["precision_kcap_inflation"] = infl
-    if med_f > 0:
-        pct = (med_b - med_f) / med_f * 100.0
-        res["precision_ab_pct"] = round(pct, 2)
-        out.write(f"Config {cfg.config_id}: precision A/B {pct:+.1f}% "
-                  f"(median {med_f} -> {med_b} ms over "
-                  f"{len(times['bf16'])} interleaved pair(s), kcap "
-                  f"{res.get('precision_kcap_f32', '?')} -> "
-                  f"{res.get('precision_kcap_bf16', '?')}, "
-                  "byte-identical)\n")
-    return res
-
-
-def _measure_auto_ab(cfg: BenchConfig, input_path: str,
-                     outputs_dir: str, out: TextIO,
-                     fast: bool, timeout_s: float, env: Optional[dict],
-                     pairs: int, oracle_want: Optional[str]) -> dict:
-    """Interleaved compiler-sharded vs hand-rolled engine timings: the
-    GSPMD engine (``--mode auto``) against BOTH hand-written merges
-    (``--mode sharded`` all-gather, ``--mode ring``), arm order
-    alternating per rep (the repo's interleaved A/B methodology). The
-    record carries:
-
-    - ``engine_ms_auto`` / ``engine_ms_sharded`` / ``engine_ms_ring``
-      medians plus raw ``*_reps`` lists (ledger per-trial evidence ->
-      a gated ``auto/configN/...`` series) and the headline
-      ``auto_ab_pct_vs_sharded`` / ``auto_ab_pct_vs_ring`` deltas;
-    - ``compile_ms_*``: each arm's ``warmup_compile`` phase (the
-      ``--warmup`` solve that pays XLA compilation, reported via
-      ``--phase-times``) — the compile-time side of the A/B, split out
-      so a GSPMD partitioner that searches longer for its schedule is
-      charged visibly rather than hidden in an untimed warmup;
-    - ``auto_ab_identical``: every arm's stdout byte-equal to every
-      other arm's (and the oracle in exact mode) — the auto engine's
-      core contract, CHECKED per run, not assumed. A mismatch
-      withholds the timings: a wrong-output arm must never become a
-      ledger point;
-    - ``auto_ab_degenerate_mesh``: honest marker when the config pins
-      no multi-device mesh — on a 1-device CPU container all three
-      arms compile 1x1-mesh programs with no cross-shard merge at
-      all, so the timings compare jit overheads, not collective
-      schedules (the TPU round owns the qualified claim);
-    - ``auto_hlo_bytes_*`` / ``auto_hlo_collectives_auto``: each arm's
-      compiled-program collective bytes (CLI ``--hlo-report``, obs.hlo)
-      — the A/B compares communication volume, not just wall time, and
-      the auto arm's entry names which collectives GSPMD actually chose
-      (``auto_hlo_unavailable`` marker when introspection failed).
-
-    Never raises: failures record ``auto_ab_unavailable``."""
-    import re as _re
-    import statistics
-
-    if cfg.procs > 1:
-        return {"auto_ab_unavailable": "multi-process config (the A/B "
-                "drives the single-process engine CLI)"}
-    arms = ("auto", "sharded", "ring")
-    times: dict = {a: [] for a in arms}
-    compile_ms: dict = {a: [] for a in arms}
-    outputs: dict = {a: set() for a in arms}
-    hlo_paths = {a: os.path.join(
-        outputs_dir, f"hlo_auto_ab_{a}_config{cfg.config_id}.jsonl")
-        for a in arms}
-    try:
-        for rep in range(max(pairs, 1)):
-            order = arms if rep % 2 == 0 else tuple(reversed(arms))
-            for arm in order:
-                out_path, err_path = run_engine(
-                    cfg, input_path, outputs_dir, mode=arm, fast=fast,
-                    timeout_s=timeout_s, env=env,
-                    obs_flags=["--phase-times",
-                               "--hlo-report", hlo_paths[arm]])
-                with open(out_path) as f:
-                    outputs[arm].add(f.read())
-                with open(err_path) as f:
-                    err_text = f.read()
-                ms = _extract_ms(err_text)
-                if ms is None:
-                    return {"auto_ab_unavailable":
-                            f"no timing line in the {arm}-arm run"}
-                times[arm].append(ms)
-                m = _re.search(r"phase warmup_compile:\s*([0-9.]+) ms",
-                               err_text)
-                if m:
-                    compile_ms[arm].append(round(float(m.group(1)), 1))
-    except (EngineTimeout, RuntimeError) as e:
-        return {"auto_ab_unavailable":
-                f"engine run failed during the A/B: {e}"}
-    identical = (all(len(outputs[a]) == 1 for a in arms)
-                 and outputs["auto"] == outputs["sharded"]
-                 == outputs["ring"]
-                 and (oracle_want is None
-                      or outputs["auto"] == {oracle_want}))
-    if not identical:
-        return {"auto_ab_unavailable":
-                "auto/sharded/ring stdout MISMATCH — byte-identity "
-                "contract violated; timings withheld",
-                "auto_ab_identical": False}
-    med = {a: statistics.median(times[a]) for a in arms}
-    res: dict = {"auto_ab_identical": True}
-    for a in arms:
-        res[f"engine_ms_{a}"] = round(med[a])
-        res[f"engine_ms_{a}_reps"] = times[a]
-        if compile_ms[a]:
-            res[f"compile_ms_{a}"] = round(
-                statistics.median(compile_ms[a]))
-            res[f"compile_ms_{a}_reps"] = compile_ms[a]
-    for rival in ("sharded", "ring"):
-        if med[rival] > 0:
-            res[f"auto_ab_pct_vs_{rival}"] = round(
-                (med["auto"] - med[rival]) / med[rival] * 100.0, 2)
-    # Communication-volume side of the A/B: each arm's compiled-program
-    # collective bytes, and the auto arm's partitioner-chosen schedule
-    # (introspection runs outside the CLI's timed region, so the
-    # timings above are unaffected).
-    import json as _json
-    for a in arms:
-        try:
-            with open(hlo_paths[a]) as f:
-                hdoc = _json.loads(f.read().splitlines()[-1])
-            res[f"auto_hlo_bytes_{a}"] = \
-                hdoc["metrics"]["collective_bytes_total"]
-            if a == "auto":
-                res["auto_hlo_collectives_auto"] = sorted(
-                    (hdoc["comms"].get("collective_totals") or {}))
-        except Exception as e:
-            res.setdefault("auto_hlo_unavailable", {})[a] = \
-                f"{type(e).__name__}: {e}"
-    if not cfg.virtual_devices or cfg.virtual_devices <= 1:
-        res["auto_ab_degenerate_mesh"] = True
-    else:
-        # The mesh is N virtual devices on ONE CPU: every delta here
-        # (notably GSPMD's partitioning/compile cost) measures the
-        # emulated platform, not a TPU slice's ICI schedule.
-        res["auto_ab_virtual_mesh_devices"] = cfg.virtual_devices
-    def _pct(rival: str) -> str:
-        v = res.get(f"auto_ab_pct_vs_{rival}")
-        return f"{v:+.1f}%" if v is not None else "n/a"
-
-    out.write(f"Config {cfg.config_id}: auto A/B {_pct('sharded')} vs "
-              f"sharded, {_pct('ring')} vs ring (medians sharded "
-              f"{res['engine_ms_sharded']} / ring "
-              f"{res['engine_ms_ring']} -> auto "
-              f"{res['engine_ms_auto']} ms over {len(times['auto'])} "
-              f"interleaved rep(s), compile "
-              f"{res.get('compile_ms_sharded', '?')} / "
-              f"{res.get('compile_ms_ring', '?')} -> "
-              f"{res.get('compile_ms_auto', '?')} ms, byte-identical"
-              + (", DEGENERATE 1x1 mesh"
-                 if res.get("auto_ab_degenerate_mesh") else "")
-              + ")\n")
     return res
 
 
@@ -1196,7 +519,7 @@ def _append_run_record(record_path: str, cfg: BenchConfig, res: dict,
         else:
             metrics["profile_unavailable"] = profile[1]
     from dmlp_tpu.obs.run import round_from_name
-    # Schema-2 envelope fields the ledger keys on. Device: what the
+    # Schema-2 envelope fields. Device: what the
     # engine subprocess itself stamped into its metrics summary, else
     # "cpu" when the harness pinned it there (the cpu_pinned verdict
     # from run_config), else unset — never jax.devices() in this parent.
@@ -1224,197 +547,20 @@ def _child_device(metrics_path: Optional[str]) -> Optional[str]:
     return stamp_device_kind(stamp)
 
 
-def run_serve(base_dir: str = ".", trace_path: Optional[str] = None,
-              reps: int = 2, record_path: Optional[str] = None,
-              timeout_s: float = 600.0, connections: int = 4,
-              max_batch_queries: int = 64,
-              extra_flags: Optional[list] = None,
-              out: TextIO = sys.stdout) -> dict:
-    """Serve mode: replay a recorded mixed-(nq, k) query trace against
-    the real daemon (``python -m dmlp_tpu.serve`` subprocess) in
-    interleaved gate-carry ON/OFF arms, and emit ONE schema-2 RunRecord
-    (kind "serve" -> ledger ``serve/...`` series) with sustained
-    request/query throughput, client-side latency quantiles, raw
-    per-arm sample lists, and the warm-up A/B's gated-block fractions.
-
-    Hard assertions, not best-effort: every response must match the
-    float64 golden oracle byte-for-byte, both arms must match each
-    other, the daemon's compile counter must not move between ready
-    and drain (no per-request recompilation), and SIGTERM must drain
-    to exit 0 with no flight-recorder dump."""
-    import subprocess
-
-    from dmlp_tpu.io.grammar import parse_input_text
-    from dmlp_tpu.obs.run import (RunRecord, round_from_name,
-                                  stamp_device_kind)
-    from dmlp_tpu.serve import client as serve_client
-
-    trace_path = trace_path or os.path.join(base_dir, "inputs",
-                                            "serve_trace1.jsonl")
-    header, reqs = serve_client.load_trace(trace_path)
-    outputs = os.path.join(base_dir, "outputs", "serve_bench")
-    os.makedirs(outputs, exist_ok=True)
-    corpus_txt = serve_client.corpus_text(header)
-    corpus_path = os.path.abspath(os.path.join(outputs, "corpus.in"))
-    with open(corpus_path, "w") as f:
-        f.write(corpus_txt)
-    corpus = parse_input_text(corpus_txt)
-    golden = serve_client.golden_reference(corpus, header, reqs)
-    golden_text = serve_client.contract_text(golden)
-    # Warm every shape bucket the replay can hit BEFORE ready — only
-    # then is the compile-counter assertion below meaningful.
-    warm = serve_client.warm_buckets_for_trace(reqs, max_batch_queries)
-    warm_spec = ",".join(f"{nq}x{k}" for nq, k in warm)
-
-    arm_results: dict = {"on": [], "off": []}
-    cold_ms: list = []
-    res: dict = {"trace": trace_path, "requests": len(reqs),
-                 "queries": int(sum(int(r["nq"]) for r in reqs)),
-                 "checksums_match": True}
-    for rep in range(max(reps, 1)):
-        # Interleave arm order per rep (neither arm systematically
-        # runs first).
-        arms = ("on", "off") if rep % 2 == 0 else ("off", "on")
-        for arm in arms:
-            tag = f"rep{rep}_{arm}"
-            ready = os.path.join(outputs, f"ready_{tag}.json")
-            errlog = os.path.join(outputs, f"daemon_{tag}.err")
-            if os.path.exists(ready):
-                os.remove(ready)
-            # --telemetry arms the session (and hence the flight
-            # recorder, whose dump dir is the snapshot file's dir) so
-            # the no-flight-dump drain assertion below has teeth.
-            cmd = [sys.executable, "-m", "dmlp_tpu.serve",
-                   "--corpus", corpus_path, "--port", "0",
-                   "--ready-file", ready, "--gate-carry", arm,
-                   "--warm-buckets", warm_spec,
-                   "--max-batch-queries", str(max_batch_queries),
-                   "--telemetry",
-                   os.path.join(outputs, f"telemetry_{tag}.prom"),
-                   "--tick-ms", "2"] + list(extra_flags or [])
-            # A crash in a PREVIOUS invocation may have left flight
-            # dumps here; clear them or the no-dump assertion below
-            # would fail every later orderly run forever.
-            serve_client.clear_flight_dumps(outputs)
-            with open(errlog, "w") as ef:
-                proc = subprocess.Popen(cmd, stderr=ef,
-                                        stdout=subprocess.DEVNULL)
-            try:
-                ready_doc = serve_client.await_ready(
-                    proc, ready, timeout_s=timeout_s, errlog=errlog)
-                port = ready_doc["port"]
-                res["device"] = stamp_device_kind(ready_doc.get("device"))
-                t0 = time.perf_counter()
-                responses = serve_client.replay(
-                    port, header, reqs, connections=connections)
-                wall_s = time.perf_counter() - t0
-                bad = [r for r in responses if not r.get("ok")]
-                if bad:
-                    raise RuntimeError(
-                        f"serve replay ({tag}): {len(bad)} failed "
-                        f"responses, first: {bad[0]}")
-                text = serve_client.contract_text(
-                    [r["checksums"] for r in responses])
-                if text != golden_text:
-                    res["checksums_match"] = False
-                    raise RuntimeError(
-                        f"serve replay ({tag}): responses differ from "
-                        "the golden oracle")
-                cli = serve_client.ServeClient(port)
-                stats = cli.stats()["stats"]
-                cli.close()
-                if stats["engine"]["compile_count"] != \
-                        ready_doc["compile_count"]:
-                    raise RuntimeError(
-                        f"serve replay ({tag}): compile count moved "
-                        f"{ready_doc['compile_count']} -> "
-                        f"{stats['engine']['compile_count']} — a "
-                        "request recompiled")
-                serve_client.sigterm_drain(proc, errlog=errlog)
-                flights = serve_client.flight_dumps(outputs)
-                if flights:
-                    raise RuntimeError(
-                        f"orderly drain left flight dumps: {flights}")
-                arm_results[arm].append({
-                    "wall_s": wall_s,
-                    "requests_per_sec": len(reqs) / wall_s,
-                    "queries_per_sec": res["queries"] / wall_s,
-                    "latency_ms": sorted(r["client_ms"]
-                                         for r in responses),
-                    "gated_fraction":
-                        stats["engine"]["last_gated_fraction"],
-                })
-                cold_ms.append(ready_doc["cold_start_compile_ms"])
-            finally:
-                if proc.poll() is None:
-                    proc.kill()
-                    proc.wait(timeout=30)
-
-    def _q(sorted_ms: list, q: float) -> float:
-        return sorted_ms[min(int(q * (len(sorted_ms) - 1) + 0.5),
-                             len(sorted_ms) - 1)]
-
-    metrics: dict = {
-        "requests": len(reqs), "trace_queries": res["queries"],
-        "cold_start_compile_ms": statistics.median(cold_ms),
-        "cold_start_compile_ms_reps": cold_ms,
-        "connections": connections,
-    }
-    for arm, runs in arm_results.items():
-        rps = [round(r["requests_per_sec"], 3) for r in runs]
-        lat = sorted(ms for r in runs for ms in r["latency_ms"])
-        metrics[f"requests_per_sec_carry_{arm}"] = statistics.median(rps)
-        metrics[f"requests_per_sec_carry_{arm}_reps"] = rps
-        metrics[f"queries_per_sec_carry_{arm}"] = statistics.median(
-            [round(r["queries_per_sec"], 3) for r in runs])
-        metrics[f"request_latency_p50_ms_carry_{arm}"] = round(
-            _q(lat, 0.50), 3)
-        metrics[f"request_latency_p95_ms_carry_{arm}"] = round(
-            _q(lat, 0.95), 3)
-        metrics[f"carry_{arm}_latency_ms"] = [round(v, 3) for v in lat]
-        gf = [r["gated_fraction"] for r in runs
-              if r["gated_fraction"] is not None]
-        if gf:
-            metrics[f"gated_fraction_carry_{arm}"] = round(
-                statistics.median(gf), 6)
-            metrics[f"gated_fraction_carry_{arm}_reps"] = gf
-    res.update(metrics)
-    out.write(
-        f"Serve bench: {len(reqs)} requests x {reps} rep(s)/arm, "
-        f"carry-on {metrics['requests_per_sec_carry_on']} req/s vs "
-        f"carry-off {metrics['requests_per_sec_carry_off']} req/s, "
-        f"p50 {metrics['request_latency_p50_ms_carry_on']} ms, "
-        "all arms byte-identical to the golden oracle\n")
-    if record_path:
-        RunRecord(
-            kind="serve", tool="dmlp_tpu.bench",
-            config={"trace": os.path.basename(trace_path),
-                    "corpus": header["corpus"],
-                    "connections": connections, "reps": reps,
-                    "flags": list(extra_flags or [])},
-            metrics=metrics, round=round_from_name(record_path),
-            artifacts={"trace": trace_path},
-            device=res["device"]).append_jsonl(record_path)
-    res["ok"] = True
-    return res
-
-
 def reference_binary_fields(cap_path: str, config_id: int,
                             engine_ms: float) -> dict:
     """Annotation fields comparing an engine time against the captured
-    reference-binary run for ``config_id`` — shared by this harness and
-    bench.py so the capture-schema handling cannot drift. Best-effort by
+    reference-binary run for ``config_id``. Best-effort by
     contract: returns {} (never raises, never partial fields) when the
     capture is absent, unreadable, or malformed — the annotation must not
     be able to discard a completed benchmark result."""
-    import json as _json
     try:
         with open(cap_path) as f:
-            ref = _json.load(f)["configs"][str(config_id)]
+            ref = json.load(f)["configs"][str(config_id)]
         ref_ms = float(ref["time_taken_ms"])  # validate; store raw below
         ref_np = int(ref["np"])
     except (OSError, KeyError, TypeError, ValueError,
-            _json.JSONDecodeError):
+            json.JSONDecodeError):
         return {}
     # `not (ref_ms > 0)` also rejects NaN (NaN <= 0 is False) — a NaN
     # multiple would serialize as invalid strict JSON downstream.
@@ -1427,12 +573,9 @@ def reference_binary_fields(cap_path: str, config_id: int,
 
 def main(argv=None) -> int:
     import argparse
-    import sys
 
     p = argparse.ArgumentParser(prog="dmlp_tpu.bench", description=__doc__)
-    p.add_argument("config", help="1|2|3|4|5|all|serve ('serve' "
-                                  "replays --serve-trace against the "
-                                  "resident daemon)")
+    p.add_argument("config", help="1|2|3|4|5|all")
     p.add_argument("--mode", default=None,
                    choices=[None, "single", "sharded", "ring", "auto"])
     p.add_argument("--fast", action="store_true",
@@ -1468,64 +611,7 @@ def main(argv=None) -> int:
                         "DIR/profile_configN (real-TPU runs; CPU configs "
                         "record the profile_unavailable marker), linked "
                         "from the config's RunRecord artifacts")
-    p.add_argument("--obs-overhead", action="store_true",
-                   help="self-measure the observability layer: run "
-                        "interleaved engine pairs with tracing+counters "
-                        "off vs on and record obs_overhead_pct in the "
-                        "config's RunRecord (single-process configs)")
-    p.add_argument("--fused-ab", action="store_true",
-                   help="A/B the fused distance→top-k megakernel: run "
-                        "interleaved DMLP_TPU_FUSED=1/0 engine pairs, "
-                        "verify the arms byte-identical, and record "
-                        "engine_ms_fused / engine_ms_two_pass (+ raw "
-                        "rep lists) in the config's RunRecord "
-                        "(single-process configs)")
-    p.add_argument("--prune-ab", action="store_true",
-                   help="A/B the pruned two-stage solve: run "
-                        "interleaved DMLP_TPU_PRUNE=1/0 engine pairs, "
-                        "verify the arms byte-identical, and record "
-                        "engine_ms_pruned / engine_ms_dense plus "
-                        "scanned-bytes both ways (+ raw rep lists) as "
-                        "a kind=\"prune\" RunRecord per config "
-                        "(single-process configs)")
-    p.add_argument("--precision-ab", action="store_true",
-                   help="A/B the low-precision first pass: run "
-                        "interleaved DMLP_TPU_PRECISION=bf16/f32 "
-                        "engine pairs, verify the arms byte-identical "
-                        "(and vs the oracle in exact mode), and record "
-                        "engine_ms_bf16 / engine_ms_f32 plus the "
-                        "kcap window inflation (+ raw rep lists) as a "
-                        "kind=\"precision\" RunRecord per config "
-                        "(single-process configs)")
-    p.add_argument("--auto-ab", action="store_true",
-                   help="A/B the compiler-sharded engine: run "
-                        "interleaved --mode auto / sharded / ring "
-                        "engine arms, verify all three byte-identical "
-                        "(and vs the oracle in exact mode), and record "
-                        "engine_ms_auto / engine_ms_sharded / "
-                        "engine_ms_ring plus each arm's "
-                        "warmup-compile split (+ raw rep lists) as a "
-                        "kind=\"auto\" RunRecord per config "
-                        "(single-process configs)")
-    p.add_argument("--serve-trace", metavar="FILE", default=None,
-                   help="recorded query trace for the serve mode "
-                        "(default inputs/serve_trace1.jsonl)")
-    p.add_argument("--serve-connections", type=int, default=4,
-                   help="concurrent replay connections (micro-batching "
-                        "coalesces across them)")
-    p.add_argument("--serve-flags", default="",
-                   help="extra daemon flags, space-separated (e.g. "
-                        "'--pallas --data-block 12800')")
     args = p.parse_args(argv)
-
-    if args.config == "serve":
-        res = run_serve(base_dir=args.base_dir,
-                        trace_path=args.serve_trace,
-                        reps=args.reps, record_path=args.metrics,
-                        timeout_s=args.timeout,
-                        connections=args.serve_connections,
-                        extra_flags=args.serve_flags.split() or None)
-        return 0 if res.get("ok") else 1
 
     ids = list(BENCH_CONFIGS) if args.config == "all" else [int(args.config)]
     ok = True
@@ -1536,11 +622,6 @@ def main(argv=None) -> int:
                          trace_dir=args.trace_dir, counters=args.counters,
                          record_path=args.metrics,
                          profile_dir=args.profile_dir,
-                         obs_overhead=args.obs_overhead,
-                         fused_ab=args.fused_ab,
-                         prune_ab=args.prune_ab,
-                         precision_ab=args.precision_ab,
-                         auto_ab=args.auto_ab,
                          telemetry_dir=args.telemetry_dir)
         # `timed_out` is a marker, not a verdict (markers never gate):
         # the config's RunRecord documents the hang; a wrong checksum
@@ -1550,5 +631,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    import sys
     sys.exit(main())

@@ -107,8 +107,7 @@ def _emit_metrics(path: str, args, inp, timer: EngineTimer, phase_ms: dict,
             summary["comms"] = comms
         if extract_impl is not None:
             # Which top-k kernel the solve actually dispatched ("fused"
-            # | "extract") — the bench harness's fused A/B reads this to
-            # refuse recording a vacuous (never-dispatched-fused) pair.
+            # | "extract").
             summary["extract_impl"] = extract_impl
         if mem_model is not None:
             # The analytic peak-HBM model + measured watermark
@@ -118,15 +117,14 @@ def _emit_metrics(path: str, args, inp, timer: EngineTimer, phase_ms: dict,
             summary["mem"] = mem_model
         if prune is not None:
             # Scanned-bytes + prune accounting of the pruned two-stage
-            # solve (ops.summaries.note_scan) — the bench --prune-ab
-            # harness and `make prune-smoke` read these per arm.
+            # solve (ops.summaries.note_scan) — `make prune-smoke`
+            # reads these per arm.
             summary["prune"] = prune
         if precision is not None:
             # First-pass precision record (engine.last_precision:
             # active/configured precision, kcap, window inflation) —
-            # the bench --precision-ab harness reads this per arm to
-            # refuse recording a vacuous (never-cast-bf16) pair, and
-            # `make precision-smoke` asserts the inflation is visible.
+            # `make precision-smoke` refuses a vacuous (never-cast-bf16)
+            # arm by it and asserts the inflation is visible.
             summary["precision"] = precision
         # Recovery is never silent: when the resilience layer did
         # anything (or a fault schedule was installed, even if nothing

@@ -9,10 +9,9 @@ merge point is a ``jax.lax.with_sharding_constraint`` resharding
 (data-partitioned per-shard candidate lists -> query-partitioned merged
 lists): XLA's GSPMD partitioner picks the collective schedule the
 hand-written engines spell out by hand (PAPERS.md arXiv 2204.06514 is
-the method paper). The bench harness A/Bs the two per config
-(``--auto-ab`` -> the gated ``auto/`` ledger family): where GSPMD
-matches the hand-rolled layouts the record justifies deleting code,
-where it loses it justifies keeping shard_map.
+the method paper). No benchmark cell runs either yet (ROADMAP D5):
+where GSPMD matches the hand-rolled layouts on the chip that justifies
+deleting code, where it loses it justifies keeping shard_map.
 
 Correctness is inherited, not re-proven: the program returns merged
 (dist, label, id) candidate lists in the engines' selection order, and
@@ -53,7 +52,7 @@ collective schedule (obs.hlo) and populates ``last_comms`` with
 ``gspmd_*`` traffic records naming which collectives the partitioner
 actually chose, on which mesh axis, and how many bytes they move. The
 derivation lowers outside the timed region and only when introspection
-is requested (CLI ``--hlo-report``, bench ``--auto-ab``), so the solve
+is requested (CLI ``--hlo-report``), so the solve
 path itself stays claim-free.
 """
 

@@ -29,7 +29,7 @@ the ROADMAP calls for:
 - :mod:`dmlp_tpu.fleet.loadgen` — the open-loop SLO harness: paced
   replay fires requests ON SCHEDULE regardless of completions (queue
   delay lands in the measured latency), swept over offered-load
-  multipliers into ledger-gated ``fleet/<level>/...`` RunRecords — the
+  multipliers into one kind="fleet" RunRecord a level — the
   p99-under-offered-load curve, not just closed-loop throughput.
 - :mod:`dmlp_tpu.fleet.autoscale` — the self-healing half's lifecycle
   owner: a router-side supervisor that spawns/retires replica daemons

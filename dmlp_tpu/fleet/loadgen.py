@@ -1,18 +1,18 @@
-"""Open-loop SLO harness: p99 under OFFERED load, as a gated series.
+"""Open-loop SLO harness: p99 under OFFERED load.
 
 Closed-loop replay (serve.client.replay) measures throughput at the
 pace the daemon sets — useful, but it cannot say "at 2× today's load,
 p99 is still X ms", because a closed-loop client slows down exactly
 when the server does. This module drives
 :func:`dmlp_tpu.serve.client.replay_open_loop` over a sweep of speed
-multipliers of a paced trace and emits one ledger-gated RunRecord per
-level (kind "fleet" -> ``fleet/<level>/<metric>`` series, gated by
-``tools/perf_gate.py``): requests fire on the trace's schedule whether
+multipliers of a paced trace and emits one RunRecord per level (kind
+"fleet", the level tag in ``config``): requests fire on the trace's schedule whether
 or not earlier ones completed, so daemon-side queueing shows up in the
 latency quantiles instead of silently stretching the experiment.
 
-``reps >= 3`` gives each level's quantiles a real noise band in the
-ledger (obs.ledger qualifies A/B comparisons on raw trial lists).
+The smokes and ``tools/fleet_bench.py`` drive it on CPU as a rehearsal
+of the fleet path; the performance record is ``python3 -m
+benchmark.run``'s (PERF.md; ROADMAP D1 on what is left of this module).
 """
 
 from __future__ import annotations
@@ -56,8 +56,7 @@ def run_level(port: int, header: Dict[str, Any],
     per-rep p50/p95/p99/max client latency (measured from the
     SCHEDULED fire time — queue delay included), dispatch lag, error
     and rejection counts, achieved vs offered qps. Scalar metrics are
-    the median across reps; ``*_reps`` carry the raw per-rep lists so
-    the ledger can qualify noise bands."""
+    the median across reps; ``*_reps`` carry the raw per-rep lists."""
     per_rep: Dict[str, List[float]] = {
         "p50_ms": [], "p95_ms": [], "p99_ms": [], "max_ms": [],
         "lag_p95_ms": [], "achieved_qps": []}
@@ -122,9 +121,8 @@ def run_levels(port: int, header: Dict[str, Any],
                ) -> List[RunRecord]:
     """The p99-vs-offered-load curve: one RunRecord per speed level,
     slowest level first (a warm daemon sees rising load, like
-    production). Each record's config pins the level tag the ledger
-    keys the series by (``fleet/x2/p99_ms``), the offered qps, and the
-    fleet topology."""
+    production). Each record's config pins the level tag (``x2``),
+    the offered qps, and the fleet topology."""
     out: List[RunRecord] = []
     for speed in sorted(speeds):
         metrics = run_level(port, header, requests, speed, reps=reps)
@@ -210,11 +208,11 @@ def ramp_record(arm: str, objective: str,
                 steps: List[Dict[str, Any]], *,
                 replicas: int = 1, trace: str = "",
                 tool: str = "dmlp_tpu.fleet.loadgen") -> RunRecord:
-    """One kind="slo" RunRecord summarizing a ramp arm (ledger series
-    ``slo/<arm>/<metric>``, gated by ``tools/perf_gate.py``). The A/B
-    contract the smoke asserts lives in these metrics: the predictive
-    arm's ``breach_cycles`` stays 0 (and ``max_burn_fast`` <= 1)
-    at ramp levels where the reactive arm's breach fires."""
+    """One kind="slo" RunRecord summarizing a ramp arm (the arm tag in
+    ``config``). The A/B contract the smoke asserts lives in these
+    metrics: the predictive arm's ``breach_cycles`` stays 0 (and
+    ``max_burn_fast`` <= 1) at ramp levels where the reactive arm's
+    breach fires."""
     peak = steps[-1]["metrics"] if steps else {}
     max_burn_fast = 0.0
     max_burn_slow = 0.0
@@ -227,8 +225,8 @@ def ramp_record(arm: str, objective: str,
             replicas_final = int(slo["replicas"])
         # Burn maxima are scoped to the DECLARED objective: a canary
         # objective the predictive policy follows is EXPECTED to burn
-        # (that is the lead it buys) and must not pollute the gated
-        # customer-objective series.
+        # (that is the lead it buys) and must not pollute the
+        # customer objective's numbers.
         target = (slo.get("objectives") or {}).get(objective, {})
         max_burn_fast = max(max_burn_fast,
                             float(target.get("burn_fast", 0.0)))
@@ -270,7 +268,7 @@ def ramp_record(arm: str, objective: str,
 def main(argv: Optional[Sequence[str]] = None) -> int:
     """``python -m dmlp_tpu.fleet.loadgen`` — drive one arm of the
     ramp against a running front-end and append its kind="slo"
-    RunRecord (``slo/<arm>/...`` ledger series)."""
+    RunRecord."""
     import argparse
     import json
     import sys

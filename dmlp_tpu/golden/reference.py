@@ -11,7 +11,7 @@ binaries' observed contract, not the author engine.cpp's:
   LABEL-FREE. The author's engine.cpp breaks selection ties by larger label
   (engine.cpp:251-254), but the actual oracle binaries bench_1/2/3 match
   the label-free order exactly on 300/300 tie-adversarial fuzz cases
-  (TIE_SEMANTICS_r05.json), while the label-aware order mismatched 18% of
+  (tools/fuzz_vs_binaries.py), while the label-aware order mismatched 18% of
   cases in the discovery census; bench_4 disagrees with its own siblings
   on ties — id-ASC report order — so the majority semantics is the
   contract;
@@ -47,10 +47,10 @@ def _select_order(dists: np.ndarray, labels: np.ndarray, ids: np.ndarray) -> np.
     Labels play no role in selection — measured, not assumed: build round
     5 ran the actual oracle binaries (isolated-singleton Open MPI) on
     tie-adversarial inputs and bench_1/2/3 match this label-free order
-    exactly (0/300 mismatches; TIE_SEMANTICS_r05.json), while the
+    exactly (0/300 mismatches; tools/fuzz_vs_binaries.py), while the
     author's engine.cpp label-aware comparator (engine.cpp:251-254)
     mismatched 18% in the discovery census. (bench_4 orders report ties
-    id-ASC — inconsistent with its own siblings; see the artifact.)
+    id-ASC — inconsistent with its own siblings: 79 of the 300 cases.)
     ``labels`` stays in the signature for call-site symmetry."""
     del labels
     return np.lexsort((-ids, dists))

@@ -26,8 +26,8 @@ timing/metrics schemas:
   merge, grad ``psum``, the MoE all-to-all, and the pipeline's
   activation ``ppermute``).
 - :mod:`dmlp_tpu.obs.run` — the versioned :class:`RunRecord` artifact
-  writer all emitters share (replacing the divergent ``BENCH_*.json``
-  shapes going forward; the legacy ``tools/*`` emitters are migrated).
+  writer all emitters share, and the device stamp a solving process
+  hands its parent.
 - :mod:`dmlp_tpu.obs.telemetry` — the LIVE half: a process-wide
   thread-safe metrics registry (counters / gauges / log-bucket
   streaming histograms with bounded-error p50/p95/p99), a background
@@ -42,13 +42,16 @@ timing/metrics schemas:
   (``memory_stats()`` / live-array bytes, with the explicit
   ``mem_stats_unavailable`` marker), and their reconciliation under
   documented per-basis tolerance bounds.
-- :mod:`dmlp_tpu.obs.ledger` — the perf ledger: ingests every run
-  artifact (schema RunRecords AND the grandfathered legacy shapes)
-  into per-series round-keyed trajectories with noise-aware A/B deltas
-  (MAD bands over per-trial samples; explicit ``insufficient_trials``
-  / ``device_mismatch`` markers). Rendered by ``python -m
-  dmlp_tpu.report``; gated by ``tools/perf_gate.py`` (``make
-  perf-gate``).
+- :mod:`dmlp_tpu.obs.hlo` — compiled-program introspection: the
+  collectives, fusions and memory of an engine's compiled HLO text
+  (``--hlo-report``), reconciled against the comms and memory models.
+- :mod:`dmlp_tpu.obs.slo` — the streaming SLO engine: objectives over
+  registry histograms, burn rates, alert state and the breach flight
+  dump the fleet's autoscaler reads.
+
+None of this is the performance record: speed is measured on the chip
+by ``python3 -m benchmark.run`` and recorded by the driver in
+``PERF_LEDGER.jsonl`` (PERF.md).
 
 Every module here is import-light: none of them import jax at module
 level, so the CLI's fast startup path is unaffected when observability is

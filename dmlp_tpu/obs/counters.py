@@ -40,7 +40,7 @@ __all__ = ["CostProbe", "normalize_cost", "lowered_cost", "roofline",
 # cost_analysis() shapes normalize_cost could not use, deduplicated and
 # bounded — attached to the counters_unavailable marker so the next JAX
 # API drift (a renamed key, a new container type) is diagnosable from a
-# ledger entry instead of a repro session.
+# run record instead of a repro session.
 _UNRECOGNIZED_MAX = 4
 _unrecognized_shapes: list = []
 

@@ -143,6 +143,9 @@ _NUM_PARTITIONS_RE = re.compile(r"num_partitions=(\d+)")
 _TRIP_COUNT_RE = re.compile(r"known_trip_count[\"':\s{]+n[\"':\s]+(\d+)")
 _WHILE_RE = re.compile(r"\swhile\(")
 _BODY_RE = re.compile(r"body=%?([\w.\-]+)")
+# instruction definition: `[ROOT] %name = <shape> opcode(`
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?(%?[\w.\-]+)\s+=\s+")
+_OPERAND_NAME_RE = re.compile(r"%[\w.\-]+")
 # computation definition: `%name (args...) -> type {` (args may nest
 # parens and carry /*index=N*/ comments — only the leading name matters)
 _COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(")
@@ -165,6 +168,42 @@ def _shape_bytes(segment: str) -> Tuple[int, List[str]]:
         total += n * item
         dtypes.append(dt)
     return total, dtypes
+
+
+def _paren_close(text: str, start: int) -> int:
+    """Index of the ``)`` closing the ``(`` at ``text[start]`` (the last
+    index when the text ends unbalanced)."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(text) - 1
+
+
+def _result_segment(rest: str) -> str:
+    """The result-shape text at the head of ``rest`` (an instruction
+    line after its ``name = ``): a parenthesised tuple, or one token."""
+    if rest.startswith("("):
+        return rest[:_paren_close(rest, 0) + 1]
+    return rest.split(" ", 1)[0]
+
+
+def _instruction_shapes(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> its result-shape text. The compiled text of
+    jax >= 0.9 names a collective's operands without their shapes
+    (``collective-permute(%gte.3)``); the shape is on the operand's own
+    defining line."""
+    shapes: Dict[str, str] = {}
+    for raw in hlo_text.splitlines():
+        m = _INSTR_RE.match(raw)
+        if m:
+            shapes[m.group(1).lstrip("%")] = _result_segment(
+                raw[m.end():])
+    return shapes
 
 
 def _parse_groups(line: str,
@@ -244,6 +283,7 @@ def parse_collectives(hlo_text: str) -> List[Dict[str, Any]]:
     if m:
         num_partitions = int(m.group(1))
 
+    shapes: Optional[Dict[str, str]] = None   # built on first need
     ops: List[Dict[str, Any]] = []
     # body computation -> (trip_count or None), caller computation
     loops: Dict[str, Tuple[Optional[int], str]] = {}
@@ -274,18 +314,21 @@ def parse_collectives(hlo_text: str) -> List[Dict[str, Any]]:
         eq = raw.find("=")
         result_seg = raw[eq + 1: om.start()] if eq >= 0 else ""
         start = raw.find("(", om.end() - 1)
-        depth, end = 0, len(raw)
-        for i in range(start, len(raw)):
-            if raw[i] == "(":
-                depth += 1
-            elif raw[i] == ")":
-                depth -= 1
-                if depth == 0:
-                    end = i
-                    break
-        operand_seg = raw[start:end + 1]
+        operand_seg = raw[start:_paren_close(raw, start) + 1]
         result_bytes, result_dtypes = _shape_bytes(result_seg)
         operand_bytes, operand_dtypes = _shape_bytes(operand_seg)
+        shape_unresolved = False
+        if not operand_bytes:
+            # operands named without shapes: read each one's defining
+            # line (a name the text never defines stays unpriced)
+            if shapes is None:
+                shapes = _instruction_shapes(hlo_text)
+            names = [n.lstrip("%")
+                     for n in _OPERAND_NAME_RE.findall(operand_seg)]
+            shape_unresolved = not names or any(
+                n not in shapes for n in names)
+            operand_bytes, operand_dtypes = _shape_bytes(
+                " ".join(shapes.get(n, "") for n in names))
         n_pairs = ring = n_rings = 0
         if kind == "collective-permute":
             n_pairs, ring, n_rings = _parse_pairs(raw)
@@ -297,6 +340,7 @@ def parse_collectives(hlo_text: str) -> List[Dict[str, Any]]:
                 # degenerate text without operand shapes: derive the
                 # shard payload from the gathered result
                 operand_bytes = result_bytes // group_size
+                shape_unresolved = False
         ops.append({
             "kind": kind, "computation": comp,
             "dtypes": operand_dtypes or result_dtypes,
@@ -304,6 +348,9 @@ def parse_collectives(hlo_text: str) -> List[Dict[str, Any]]:
             "result_bytes": result_bytes,
             "group_size": group_size, "n_groups": n_groups,
             **({"n_pairs": n_pairs} if n_pairs else {}),
+            # bytes_moved is then a lower bound, and says so
+            **({"operand_shape_unresolved": True}
+               if shape_unresolved else {}),
         })
 
     # transitive loop multiplier per computation (nested whiles multiply)
@@ -339,6 +386,9 @@ def collective_totals(
         agg["ops"] += 1
         agg["count"] += op["count"] * dispatch_count
         agg["bytes_moved"] += op["bytes_moved"] * dispatch_count
+        if op.get("operand_shape_unresolved"):
+            agg["ops_shape_unresolved"] = \
+                agg.get("ops_shape_unresolved", 0) + 1
     return out
 
 
@@ -542,10 +592,14 @@ def reconcile_comms(reports: List[Tuple[HloReport, int, str]],
     ``model_only`` means the model prices a collective the compiled
     program never dispatches."""
     hlo_bytes: Dict[str, int] = {}
+    unresolved: Dict[str, int] = {}
     for rep, count, _site in reports:
         for kind, agg in rep.totals.items():
             hlo_bytes[kind] = hlo_bytes.get(kind, 0) \
                 + agg["bytes_moved"] * count
+            if agg.get("ops_shape_unresolved"):
+                unresolved[kind] = unresolved.get(kind, 0) \
+                    + agg["ops_shape_unresolved"]
     model_bytes, model_names = _traffic_kind_bytes(traffics)
     kinds: Dict[str, Any] = {}
     for kind in sorted(set(hlo_bytes) | set(model_bytes)):
@@ -553,7 +607,12 @@ def reconcile_comms(reports: List[Tuple[HloReport, int, str]],
         ent: Dict[str, Any] = {"hlo_bytes": h, "model_bytes": mdl}
         if model_names.get(kind):
             ent["models"] = sorted(set(model_names[kind]))
-        if h and mdl:
+        if unresolved.get(kind):
+            # the compiled text names operands this parser could not
+            # price: the HLO side is a lower bound, so no verdict
+            ent.update(hlo_shape_unavailable=unresolved[kind],
+                       within_tolerance=False)
+        elif h and mdl:
             ratio = h / mdl
             lo, hi = COMMS_RATIO_BOUNDS
             ent.update(ratio=round(ratio, 3),
@@ -720,9 +779,8 @@ def build_report_doc(reports: List[Tuple[HloReport, int, str]],
 
 
 def flat_metrics(doc: Dict[str, Any]) -> Dict[str, Any]:
-    """The ledger-facing scalars of a report doc — what the ``hlo/``
-    series family gates round-over-round (collective bytes per kind, op
-    counts, static peak memory)."""
+    """The scalars of a report doc the ``kind="hlo"`` RunRecord carries
+    (collective bytes per kind, op counts, static peak memory)."""
     out: Dict[str, Any] = {
         "collective_bytes_total": doc.get("collective_bytes_total", 0),
         "executables_introspected": len(doc.get("executables", ())),

@@ -1,25 +1,24 @@
 """Versioned run-artifact records — the one schema every emitter shares.
 
-Five rounds of benchmarking left ~15 ``tools/*.py`` scripts each inventing
-its own ``BENCH_*.json`` shape; nothing downstream can consume them
-uniformly. :class:`RunRecord` is the replacement going forward: a small
-versioned envelope (schema, tool, kind, host context) around free-form
-``config``/``metrics`` payloads plus the structured observability blocks
-(``counters`` from obs.counters, ``comms`` from obs.comms, ``artifacts``
-paths to trace files). Existing artifacts are grandfathered; new emitters
-write RunRecords (the bench harness and the engine CLI already do).
+:class:`RunRecord` is a small versioned envelope (schema, tool, kind,
+host context) around free-form ``config``/``metrics`` payloads plus the
+structured observability blocks (``counters`` from obs.counters,
+``comms`` from obs.comms, ``artifacts`` paths to trace files). The engine
+CLI (``--record``, ``--hlo-report``), the serve daemon, the telemetry
+session, the train loop, the differential harness (``python -m
+dmlp_tpu.bench --metrics``) and the smokes under ``tools/`` write it.
+A record says what a run did and counted; it is not a performance
+record (those are the driver's ``PERF_LEDGER.jsonl``, from ``python3 -m
+benchmark.run`` on the chip: PERF.md).
 
 Records serialize as strict JSON. ``write`` emits one record per file;
 ``append_jsonl`` appends one record per line for multi-run logs — both
 atomic enough for the single-writer tooling here.
 
-Schema 2 promotes the two fields the perf ledger (obs.ledger) keys
-series on from free-form payload convention to the envelope: ``round``
-(the measurement round, the ``_rNN`` suffix convention of the root
-artifacts) and ``device`` (the device kind the run measured on — the
-ledger refuses to compare rounds across devices, so emitters that know
-their device must say so). Both are optional: schema-1 records load
-unchanged and the ledger falls back to filename/round heuristics.
+Schema 2 added two optional envelope fields: ``round`` (taken from an
+``_rNN`` suffix of the output file's name, when it has one) and
+``device`` (the device kind the run measured on, from the solving
+process's own stamp). Schema-1 records load unchanged.
 """
 
 from __future__ import annotations
@@ -30,7 +29,7 @@ import os
 import platform
 import re
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 #: bump on any backward-incompatible field change; consumers key on this
 SCHEMA_VERSION = 2
@@ -179,6 +178,13 @@ class RunRecord:
     def load(path: str) -> "RunRecord":
         with open(path) as f:
             return RunRecord.from_dict(json.loads(f.readline()))
+
+    @staticmethod
+    def load_all(path: str) -> List["RunRecord"]:
+        """Every record of an ``append_jsonl`` log, in file order."""
+        with open(path) as f:
+            return [RunRecord.from_dict(json.loads(ln)) for ln in f
+                    if ln.strip()]
 
     @staticmethod
     def from_dict(d: Dict[str, Any]) -> "RunRecord":

@@ -1,8 +1,8 @@
 """Live in-process telemetry: metrics registry, sampler, OpenMetrics
 export, and the crash flight recorder.
 
-Every observability layer so far (spans, counters, comms models, the
-perf ledger) is post-hoc — artifacts written after a batch run exits.
+Every other observability layer (spans, counters, comms models, run
+records) is post-hoc — artifacts written after a batch run exits.
 This module is the LIVE half, the substrate the future serving daemon's
 p50/p95/p99 / QPS / memory-headroom contract lands on:
 
@@ -1137,7 +1137,7 @@ class TelemetrySession:
 
     def snapshot_record(self, extra_config: Optional[dict] = None):
         """The telemetry snapshot as a schema RunRecord (kind
-        "telemetry") — the ledger-ingestible serialization. Scalar
+        "telemetry"). Scalar
         gauges/counters become metrics; histograms contribute their
         p50/p95/p99/count."""
         from dmlp_tpu.obs.run import RunRecord, current_device

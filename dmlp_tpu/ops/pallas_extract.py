@@ -2,8 +2,8 @@
 
 The round-2 solve wrote every (Q, B) distance tile to HBM (8.4 GB at the
 benchmark shape) and selected from it with segment-min + gather + lax.top_k
-— measured on v5e the selection pipeline costs ~15x the distance matmul
-(tools/profile_amortized.py). This kernel is the fix:
+— measured on v5e in build round 3 the selection pipeline cost ~15x the
+distance matmul. This kernel is the fix:
 selection happens in VMEM while the distance block is still resident, so
 the tile never exists in HBM at all.
 
@@ -80,7 +80,7 @@ QUERY_TILE = _TQ
 
 def tuned_variant(kc: int) -> dict:
     """Per-width kernel tuning, measured on v5e at 204800 x 10240 x 64
-    (SWEEP_WIDEK_r04.jsonl, fenced solve incl. the sort epilogue):
+    (a pre-round sweep, its record gone; not re-measured by any cell):
 
     - narrow lists (kc <= 64): the r3 default (tq=128, ne=2) — 101.7 ms
       at kc=64; ne=4 ties (101.3), tq/ne changes within noise.
@@ -416,8 +416,8 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     just ran) changes which compiled kernel the next call uses instead
     of silently reusing a trace baked with the old variant.
     ``block_skip`` toggles the threshold-gated block prefilter
-    (output-identical either way; off only for A/B measurement,
-    tools/roofline_extract.py). ``mxu_gate`` enables the fused
+    (output-identical either way; off only for A/B measurement).
+    ``mxu_gate`` enables the fused
     megakernel's norm-bound MXU tile gating (output-identical;
     ops.pallas_fused.fused_topk is the public face, which also resolves
     variants from the fused tune-cache namespace). ``precision``

@@ -527,7 +527,7 @@ def note_scan(engine, *, scanned_bytes: int, dense_bytes: int,
               blocks_total: int, blocks_pruned: int) -> None:
     """Fold one solve's scanned-bytes accounting into
     ``engine.last_prune`` and the live telemetry registry — the
-    ledgered counters the A/B harness and the OpenMetrics scrape read
+    counters the ``--metrics`` summary and the OpenMetrics scrape carry
     (``scan.bytes_streamed`` / ``prune.blocks_pruned`` /
     ``prune.gated_fraction``). Dense solves record too (blocks_pruned
     0), so the pruned-vs-dense byte ratio is computable from either

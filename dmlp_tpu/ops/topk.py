@@ -3,7 +3,7 @@
 The correctness contract is order-sensitive (checksums, survey §4). The
 MEASURED oracle-binary comparator (r5: the binaries ran in-container and
 were fuzzed on tie-adversarial inputs, golden.reference docstring /
-TIE_SEMANTICS_r05.json) breaks both selection and report ties to the
+tools/fuzz_vs_binaries.py) breaks both selection and report ties to the
 **larger id**, label-free. ``jax.lax.top_k`` breaks ties by lowest index,
 so it cannot express this; instead selection is a multi-operand
 ``jax.lax.sort`` over the composite key
@@ -50,7 +50,7 @@ def select_topk(dists: jax.Array, labels: jax.Array, ids: jax.Array,
                 k: int) -> TopK:
     """Select the k best (dist asc, id desc) along the last axis — the
     MEASURED oracle-binary comparator (label-free; golden.reference
-    docstring / TIE_SEMANTICS_r05.json), identical to the report order.
+    docstring / tools/fuzz_vs_binaries.py), identical to the report order.
 
     ``labels``/``ids`` broadcast against ``dists`` (e.g. (N,) vs (Q, N)).
     If k exceeds the axis size, results are padded with (+inf, -1, -1).

@@ -49,7 +49,7 @@ crashing, and every rung preserves the contract checksums exactly:
                     byte-identity is by construction.
 
 Each step records a ``resilience.degrade`` trace event and a stats
-degradation entry, so the ledger and chaos harness can see recovery
+degradation entry, so run records and the chaos harness can see recovery
 happen (and measure what it cost).
 """
 

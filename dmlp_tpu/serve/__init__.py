@@ -23,7 +23,7 @@ ONCE and then serves query streams at throughput:
   first responder).
 - :mod:`dmlp_tpu.serve.daemon` / :mod:`~dmlp_tpu.serve.protocol` — the
   line-JSON TCP daemon with live telemetry (``--telemetry-port`` is
-  the scrape surface), periodic ledger-ingestible serve RunRecords,
+  the scrape surface), periodic serve RunRecords,
   and a graceful SIGTERM drain (in-flight micro-batches finish, the
   final snapshot flushes, no flight-recorder dump on an orderly exit).
 - :mod:`dmlp_tpu.serve.client` — the replay client + recorded-trace

@@ -7,9 +7,8 @@ data section, warms the shape buckets derived from its query section
 (:mod:`dmlp_tpu.serve.protocol`) on a localhost TCP port. Telemetry is
 the PR 9 substrate unchanged: ``--telemetry-port`` is the live
 OpenMetrics scrape surface, per-request latency lands in the
-registry's log-bucket histograms, and ``--record`` appends
-ledger-ingestible serve RunRecords (kind "serve" -> ``serve/...``
-series, gated by ``make perf-gate``).
+registry's log-bucket histograms, and ``--record`` appends serve
+RunRecords (kind "serve").
 
 Shutdown contract (the graceful-drain satellite): SIGTERM (or an
 in-band ``drain`` op) stops admission ("draining" rejections), lets
@@ -192,8 +191,8 @@ class ServeDaemon:
         # The registry is process-global but stats()/snapshot_record()
         # divide by THIS daemon's uptime: zero the serve.* counters so
         # a second daemon lifetime in one process (tests, in-process
-        # embedding) doesn't inherit the first one's counts and feed
-        # inflated requests_per_sec into the ledger.
+        # embedding) doesn't inherit the first one's counts and report
+        # an inflated requests_per_sec.
         telemetry.registry().reset(prefix="serve")
         if mesh_shape is not None:
             # Mesh-resident replica: the corpus held sharded-resident
@@ -424,12 +423,10 @@ class ServeDaemon:
                 pass
         return out
 
-    # -- ledger records --------------------------------------------------------
+    # -- run records -----------------------------------------------------------
 
     def snapshot_record(self):
-        """The serving state as a ledger-ingestible RunRecord (kind
-        "serve" -> ``serve/<metric>`` series; requests_per_sec is
-        higher-better, latency quantiles lower-better)."""
+        """The serving state as a RunRecord (kind "serve")."""
         from dmlp_tpu.obs.run import RunRecord, current_device
         reg = telemetry.registry()
         eng = self.engine

@@ -4,7 +4,8 @@ BASELINE.json's metric set ("samples/sec/chip", north star >= 40% MFU) needs
 a number measured on the real chip, not just the accounting in
 train.metrics. ``train_bench()`` runs the dp x tp sharded train step
 (train.step) at a matmul-heavy shape and reports measured throughput as one
-JSON-able dict (bench.py prints it when BENCH_MODE=train).
+JSON-able dict (``python -m dmlp_tpu.train.bench`` prints it). No cell of
+``BENCHMARK.json`` runs it yet (ROADMAP R11).
 
 Batches come from a small device-resident pool, cycled across steps: the
 benchmark measures the training step (fwd/bwd/update on the MXU + XLA

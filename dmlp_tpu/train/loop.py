@@ -561,9 +561,7 @@ def main(argv=None) -> int:
     p.add_argument("--metrics-file", default=None)
     p.add_argument("--record", metavar="FILE", default=None,
                    help="write one versioned RunRecord (obs.run) "
-                        "summarizing the run to FILE — the "
-                        "ledger-ingestible train artifact "
-                        "(python -m dmlp_tpu.report)")
+                        "summarizing the run to FILE")
     p.add_argument("--trace", metavar="FILE", default=None,
                    help="write a Perfetto/Chrome-trace JSON of the run's "
                         "step/checkpoint spans to FILE (obs.trace)")

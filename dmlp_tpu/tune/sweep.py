@@ -4,15 +4,14 @@ For each requested (kernel, shape, kc) the sweep enumerates every
 variant the kernel can actually tile — ``tile_q`` x ``tile_n`` x ``ne``
 x ``unroll``, gated by ``ops.pallas_extract.variant_supports`` so the
 sweep can never persist a variant the hot path would reject — times each
-with the dependent-readback fence the bench tools share
-(bench.time_fenced_solve_ms), and records
+with a dependent-readback fence (:func:`_fenced_ms`), and records
 the winner in the variant cache (:mod:`dmlp_tpu.tune.cache`) under that
 kernel's namespace. The fused megakernel (ops.pallas_fused) shares the
 tile space but sweeps separately: its MXU gate turns warm no-improve
 blocks into one VPU bound pass, which shifts the block-size trade-off
 the winner encodes.
 
-Two honesty rules carried over from the bench methodology:
+Two honesty rules:
 
 - compile + the eager perturbation chain are warmed OUT of the timed
   region (the r2 mismeasurement: the chain's tiny kernels compile on
@@ -91,8 +90,7 @@ def smoke_space(qb: int, b: int, a: int, kc: int) -> List[Dict]:
 
 
 def _fenced_ms(fn, q, d, reps: int) -> float:
-    """bench.time_fenced_solve_ms methodology, local so the package does
-    not depend on the repo-root driver script: compile + fence, warm the
+    """Compile + fence, warm the
     perturbation chain, then time ``reps`` chained dispatches bounded by
     a dependent scalar readback."""
     r = fn(q, d)
@@ -147,12 +145,10 @@ def sweep_extract(n: int, nq: int, a: int, kcs: Sequence[int],
 
     - the CHUNKED shape (plan_chunks on the extract granule) — what
       engine.single._solve_extract dispatches per staged chunk;
-    - the WHOLE padded dataset — what the multipass resident passes,
-      bench's device-solve path, and tools/roofline_extract.py
-      dispatch. Without this point the documented on-hardware recipe
-      (tune, then roofline) would resolve the roofline's b=npad
-      dispatch in a bucket the sweep never keyed and silently fall
-      back to the heuristic.
+    - the WHOLE padded dataset — what the multipass resident passes
+      dispatch. Without this point a b=npad dispatch would resolve in
+      a bucket the sweep never keyed and silently fall back to the
+      heuristic.
 
     Queries pad to whole query tiles. ``kernel`` ("extract" | "fused")
     selects which kernel the variants drive; winners persist under that

@@ -231,40 +231,45 @@ def test_fleet_mesh_engine_accepts_auto_merge():
         MeshResidentEngine(corpus, EngineConfig(), merge="bogus")
 
 
-# -- the gated auto/ ledger family --------------------------------------------
+# -- the differential harness under --mode auto --------------------------------
 
-def test_auto_runrecord_lands_in_gated_auto_family(tmp_path):
-    from dmlp_tpu.obs.ledger import ingest_file
+def test_harness_auto_mode_record_loads_as_runrecord(tmp_path, monkeypatch):
+    """``python -m dmlp_tpu.bench N --mode auto --metrics FILE``: the
+    compiler-sharded engine against the cached golden oracle through
+    the real CLI, and the config's RunRecord reads back."""
+    import io
+
+    from dmlp_tpu.bench import configs as bench_configs
+    from dmlp_tpu.bench.harness import run_config
     from dmlp_tpu.obs.run import RunRecord
+    monkeypatch.setitem(
+        bench_configs.BENCH_CONFIGS, 1,
+        bench_configs.BenchConfig(1, 200, 20, 4, 0.0, 10.0, 1, 8, 4, 7,
+                                  "tiny.in"))
     rec = tmp_path / "AUTO_r99.jsonl"
-    RunRecord(kind="auto", tool="dmlp_tpu.bench",
-              config={"config_id": 2},
-              metrics={"engine_ms_auto": 100.0,
-                       "engine_ms_auto_reps": [99.0, 101.0],
-                       "compile_ms_auto": 400.0},
-              round=99).append_jsonl(str(rec))
-    entry = ingest_file(str(rec))
-    assert entry["status"] == "parsed"
-    series = {p["series"] for p in entry["points"]}
-    assert "auto/config2/engine_ms_auto" in series
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools", "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-    assert pg.gated("auto/config2/engine_ms_auto")
+    buf = io.StringIO()
+    res = run_config(1, base_dir=str(tmp_path), mode="auto", out=buf,
+                     record_path=str(rec))
+    assert res["checksums_match"], buf.getvalue()
+    back = RunRecord.load(str(rec))
+    assert (back.kind, back.tool) == ("bench", "dmlp_tpu.bench")
+    assert back.round == 99        # from the file's _r99 suffix
+    assert back.metrics["checksums_match"] is True
+    assert back.metrics["engine_ms"] is not None
 
 
-# -- persistent compile cache: relaunch is cheaper, compile count flat --------
+# -- persistent compile cache: a relaunch is served from it, compile count flat
 
-def test_warm_compile_cache_relaunch_cheaper_and_count_flat(
+def test_warm_compile_cache_relaunch_hits_and_count_flat(
         tmp_path, monkeypatch):
     """Two serve daemons, same corpus + warm buckets, same
-    ``--compile-cache`` dir: the second (warm) cold start must be
-    strictly cheaper with an unchanged bucket compile count — the
-    executables are reused, not rebuilt. Subprocesses, not threads:
-    jax's in-process jit cache would mask the persistent layer."""
+    ``--compile-cache`` dir: the first writes every program it compiles
+    to the cache (misses, no hit), the second is served every one of
+    them from it (hits, no miss) with an unchanged bucket compile count
+    — the executables are reused, not rebuilt. Counts, which repeat
+    exactly; the two cold-start wall times (~400 ms each on CPU) are
+    not compared. Subprocesses, not threads: jax's in-process jit cache
+    would mask the persistent layer."""
     from dmlp_tpu.fleet import harness as fh
     from dmlp_tpu.serve import client as sc
     # The test places the cache itself; an ambient placement would win
@@ -278,14 +283,14 @@ def test_warm_compile_cache_relaunch_cheaper_and_count_flat(
     corpus_path.write_text(sc.corpus_text(header))
     ccdir = tmp_path / "compile_cache"
     out = str(tmp_path)
-    colds, counts = [], []
+    caches, counts = [], []
     for gen in ("cold", "warm"):
         fp = fh.spawn_replica(str(corpus_path), out, f"cc_{gen}",
                               "8x8", batch_cap=8,
                               compile_cache=str(ccdir))
         try:
             fh.await_replica(fp)
-            colds.append(fp.ready["cold_start_compile_ms"])
+            caches.append(fp.ready["compile_cache"])
             counts.append(fp.ready["compile_count"])
             cli = sc.ServeClient(fp.ready["port"])
             cli.drain()
@@ -296,8 +301,12 @@ def test_warm_compile_cache_relaunch_cheaper_and_count_flat(
     assert os.path.isdir(str(ccdir)) and os.listdir(str(ccdir)), \
         "the persistent cache directory stayed empty"
     assert counts[1] == counts[0]
-    assert colds[1] < colds[0], \
-        f"warm relaunch not cheaper: {colds[0]} -> {colds[1]} ms"
+    cold, warm = caches
+    assert cold["dir"] == warm["dir"] == str(ccdir)
+    assert cold["hits"] == 0 and cold["misses"] > 0, cold
+    assert warm["misses"] == 0 and warm["hits"] == cold["misses"], \
+        f"warm relaunch was not served from the cache: {cold} -> {warm}"
+    assert warm["requests"] == cold["requests"]
 
 
 def test_compile_cache_env_beats_flag_and_default_is_checkout(

@@ -277,43 +277,3 @@ def test_per_config_timeout_override(tiny_cfg, tmp_path, monkeypatch):
     res = run_config(1, base_dir=str(tmp_path), out=buf, timeout_s=600.0,
                      env=_scrubbed_env())
     assert res.get("timed_out") is True        # 600s harness limit unused
-
-
-def test_run_config_fused_ab_records_and_checks_identity(monkeypatch,
-                                                         tmp_path):
-    """ISSUE 8: ``fused_ab=True`` runs interleaved DMLP_TPU_FUSED=1/0
-    engine pairs, verifies the arms byte-identical (and equal to the
-    oracle in exact mode), CONFIRMS the fused arm actually dispatched
-    the fused kernel (extract_impl via the metrics channel), and
-    records both medians with raw per-rep lists — the ledger's
-    per-trial evidence for the fused series."""
-    from dmlp_tpu.bench import configs as bench_configs
-    cfg = BenchConfig(1, 900, 12, 4, -20.0, 20.0, 1, 28, 5, 7, "tiny.in",
-                      use_pallas=True, select="extract")
-    monkeypatch.setitem(bench_configs.BENCH_CONFIGS, 1, cfg)
-    buf = io.StringIO()
-    res = run_config(1, base_dir=str(tmp_path), out=buf,
-                     env=_scrubbed_env(), fused_ab=True)
-    assert res["checksums_match"], buf.getvalue()
-    assert res.get("fused_ab_identical") is True, res
-    assert res["fused_ab_impls"]["fused"] == ["fused"]
-    assert res["fused_ab_impls"]["two_pass"] == ["extract"]
-    assert isinstance(res["engine_ms_fused"], int)
-    assert isinstance(res["engine_ms_two_pass"], int)
-    assert len(res["engine_ms_fused_reps"]) == 1      # pairs = reps = 1
-    assert len(res["engine_ms_two_pass_reps"]) == 1
-    assert "fused A/B" in buf.getvalue()
-
-
-def test_run_config_fused_ab_vacuous_marker(tiny_cfg, tmp_path):
-    """A config that never takes the fused path (tiny_cfg: no pallas —
-    both arms run identical code) must record the explicit
-    ``fused_ab_vacuous`` marker and WITHHOLD the timing series: an
-    identical-code pair must not become a gated ledger series."""
-    buf = io.StringIO()
-    res = run_config(1, base_dir=str(tmp_path), out=buf,
-                     env=_scrubbed_env(), fused_ab=True)
-    assert res["checksums_match"], buf.getvalue()
-    assert res.get("fused_ab_vacuous") is True, res
-    assert "fused_ab_unavailable" in res
-    assert "engine_ms_fused" not in res

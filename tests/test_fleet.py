@@ -521,7 +521,7 @@ def test_open_loop_replay_fires_on_schedule_and_measures_queue_delay():
         d.close()
 
 
-def test_loadgen_levels_emit_gated_fleet_series(tmp_path):
+def test_loadgen_levels_emit_one_fleet_record_a_level(tmp_path):
     corpus = make_corpus()
     d = _start_daemon(corpus, warm_buckets=[(2, 8), (1, 8)])
     try:
@@ -541,19 +541,16 @@ def test_loadgen_levels_emit_gated_fleet_series(tmp_path):
             assert rec.metrics["p99_ms"] > 0
             assert len(rec.metrics["p99_ms_reps"]) == 2
             rec.append_jsonl(str(path))
-        from dmlp_tpu.obs.ledger import ingest_file
-        entry = ingest_file(str(path))
-        assert entry["status"] == "parsed"
-        series = {p["series"] for p in entry["points"]}
-        assert "fleet/x2/p99_ms" in series
-        assert "fleet/x4/p99_ms" in series
-        p99 = next(p for p in entry["points"]
-                   if p["series"] == "fleet/x2/p99_ms")
-        assert p99["better"] == "lower"
-        assert p99["round"] == 99
-        qps = next(p for p in entry["points"]
-                   if p["series"] == "fleet/x2/offered_qps")
-        assert qps["better"] == "higher"
+        from dmlp_tpu.obs.run import RunRecord
+        back = RunRecord.load_all(str(path))
+        assert [(r.kind, r.tool, r.config["level"]) for r in back] == [
+            ("fleet", "dmlp_tpu.fleet.loadgen", "x2"),
+            ("fleet", "dmlp_tpu.fleet.loadgen", "x4")]
+        for r in back:
+            assert r.config["mode"] == "open_loop"
+            assert r.metrics["p99_ms"] > 0
+            assert r.metrics["offered_qps"] > 0
+            assert r.device     # the serving process's own stamp
     finally:
         d.close()
 

@@ -418,16 +418,19 @@ def test_tail_attrib_names_the_dominant_phase(tmp_path):
     assert p99["residual_ms"] == pytest.approx(5.0)
 
 
-def test_tailattrib_records_land_as_gated_phase_series(tmp_path):
-    from dmlp_tpu.obs.ledger import ingest_file
+def test_tailattrib_records_carry_level_and_phase_quantiles(tmp_path):
     from dmlp_tpu.obs.run import RunRecord
-    rec = RunRecord(kind="tailattrib", tool="tools.tail_attrib",
-                    config={"level": "x8", "dominant_p99": "queue"},
-                    metrics={"queue_p99_ms": 12.5, "solve_p99_ms": 8.0})
+    from tools.merge_traces import merge_fleet
+    from tools.tail_attrib import attribute, emit_records
+    _write_fleet_dir(tmp_path)
+    levels = attribute(merge_fleet(str(tmp_path)))
     path = tmp_path / "TAILATTRIB.jsonl"
-    rec.append_jsonl(str(path))
-    entry = ingest_file(str(path))
-    assert entry["status"] == "parsed"
-    series = {p["series"] for p in entry["points"]}
-    assert "fleet/x8/phase/queue_p99_ms" in series
-    assert "fleet/x8/phase/solve_p99_ms" in series
+    assert emit_records(levels, str(path), "merged.json", round_=16,
+                        device="cpu") == 1
+    back = RunRecord.load(str(path))
+    assert (back.kind, back.tool) == ("tailattrib", "tools.tail_attrib")
+    assert back.config["level"] == "x4"
+    assert back.config["dominant_p99"] == "solve"
+    assert back.metrics["solve_p99_ms"] == pytest.approx(10.0)
+    assert "queue_p99_ms" in back.metrics
+    assert (back.round, back.device) == (16, "cpu")

@@ -190,6 +190,27 @@ class TestParsing:
         assert op["count"] == 1            # honest lower bound
         assert op["trip_count_unknown"] is True
 
+    def test_operands_named_without_shapes_resolve_from_their_definition(
+            self):
+        # jax 0.9's compiled text: `collective-permute(%gte.1)` — the
+        # shape sits on %gte.1's own line
+        text = WHILE_TRIP.replace(
+            "collective-permute(f32[8,8] %gte.1)",
+            "collective-permute(%gte.1)")
+        op = obs_hlo.parse_collectives(text)[0]
+        assert op["operand_bytes"] == 256
+        assert op["bytes_moved"] == 256 * 4 * 3 == 3072
+        assert "operand_shape_unresolved" not in op
+
+    def test_operand_the_text_never_defines_is_marked_not_priced(self):
+        text = CP.replace("collective-permute(f32[4,8] %p)",
+                          "collective-permute(%elsewhere.7)")
+        op = obs_hlo.parse_collectives(text)[0]
+        assert op["operand_bytes"] == 0
+        assert op["operand_shape_unresolved"] is True
+        totals = obs_hlo.collective_totals([op])
+        assert totals["collective-permute"]["ops_shape_unresolved"] == 1
+
     def test_async_start_counted_done_skipped(self):
         ops = obs_hlo.parse_collectives(ASYNC_PAIR)
         assert len(ops) == 1               # -done is bookkeeping
@@ -224,6 +245,18 @@ def _fixture_report(text, label="fix"):
 
 
 class TestReconcile:
+    def test_comms_unpriced_hlo_side_says_so_and_gives_no_verdict(self):
+        text = CP.replace("collective-permute(f32[4,8] %p)",
+                          "collective-permute(%elsewhere.7)")
+        model = CollectiveTraffic("ring_allreduce_topk", "data", 4,
+                                  128, 128)
+        rec = obs_hlo.reconcile_comms(
+            [(_fixture_report(text), 1, "solve")], [model])
+        ent = rec["kinds"]["collective-permute"]
+        assert ent["hlo_shape_unavailable"] == 1
+        assert ent["within_tolerance"] is False
+        assert "ratio" not in ent and "model_only" not in ent
+
     def test_comms_exact_match_within_tolerance(self):
         rep = _fixture_report(AG_EXPLICIT)
         # model twin: per-device (g-1) x 128 = 384 B over 2 groups of 4
@@ -411,7 +444,7 @@ class TestLiveEngines:
 
 
 # ---------------------------------------------------------------------------
-# CLI --hlo-report round-trip through the ledger
+# CLI --hlo-report writes a RunRecord that loads back
 # ---------------------------------------------------------------------------
 
 def _run_cli(args, text):
@@ -422,7 +455,7 @@ def _run_cli(args, text):
 
 
 @pytest.mark.parametrize("mode", ["sharded", "auto"])
-def test_cli_hlo_report_roundtrip(tmp_path, mode):
+def test_cli_hlo_report_loads_as_runrecord(tmp_path, mode):
     text = generate_input_text(90, 11, 4, -3, 3, 1, 7, 3, seed=44)
     base, _ = _run_cli(["--mode", mode], text)
     path = tmp_path / "HLO.jsonl"
@@ -438,14 +471,14 @@ def test_cli_hlo_report_roundtrip(tmp_path, mode):
         ag = rec["comms_model"]["kinds"]["all-gather"]
         assert ag["within_tolerance"] is True
 
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(str(path))
-    assert entry["status"] == "parsed"
-    series = {p["series"] for p in entry["points"]}
-    assert f"hlo/{mode}/collective_bytes_total" in series
-    from tools.perf_gate import GATED_PREFIXES
-    assert any(s.startswith("hlo/") for s in series)
-    assert "hlo/" in GATED_PREFIXES
+    from dmlp_tpu.obs.run import RunRecord
+    back = RunRecord.load(str(path))
+    assert (back.kind, back.tool) == ("hlo", "dmlp_tpu.cli")
+    assert back.config["mode"] == mode
+    assert back.metrics["collective_bytes_total"] \
+        == doc["metrics"]["collective_bytes_total"]
+    assert back.round is None      # "HLO.jsonl" names no round
+    assert back.device             # the solving process's own stamp
 
 
 # ---------------------------------------------------------------------------

@@ -347,6 +347,38 @@ def test_runrecord_jsonl_append(tmp_path):
     assert [json.loads(ln)["kind"] for ln in lines] == ["a", "b"]
 
 
+def test_runrecord_schema2_fields_roundtrip():
+    rec = RunRecord(kind="bench", tool="x", round=6, device="TPU v5 lite")
+    back = RunRecord.from_dict(json.loads(rec.to_json()))
+    assert back.schema == SCHEMA_VERSION
+    assert back.round == 6 and back.device == "TPU v5 lite"
+    # a schema-1 record (no round/device) still loads
+    old = RunRecord.from_dict({"kind": "bench", "tool": "x", "schema": 1})
+    assert old.round is None and old.device is None
+
+
+def test_runrecord_write_and_append_load_back_with_envelope(tmp_path):
+    from dmlp_tpu.obs.run import round_from_name
+    single = str(tmp_path / "BENCH_r06.json")
+    RunRecord(kind="bench", tool="t", config={"config_id": 1},
+              metrics={"engine_ms": 100,
+                       "engine_ms_reps": [99, 100, 101]},
+              device="cpu", round=round_from_name(single)).write(single)
+    back = RunRecord.load(single)
+    assert (back.kind, back.tool, back.round, back.device) \
+        == ("bench", "t", 6, "cpu")
+    assert back.metrics["engine_ms_reps"] == [99, 100, 101]
+    multi = str(tmp_path / "runs.jsonl")
+    assert round_from_name(multi) is None
+    RunRecord(kind="train", tool="t2", metrics={"step_time_ms": 5.0},
+              round=6).append_jsonl(multi)
+    RunRecord(kind="train", tool="t2", metrics={"step_time_ms": 4.0},
+              round=6).append_jsonl(multi)
+    assert RunRecord.load(multi).metrics == {"step_time_ms": 5.0}
+    assert [r.metrics["step_time_ms"]
+            for r in RunRecord.load_all(multi)] == [5.0, 4.0]
+
+
 def test_runrecord_schema_guard_and_serialization_error():
     with pytest.raises(ValueError, match="newer"):
         RunRecord.from_dict({"kind": "x", "tool": "t",

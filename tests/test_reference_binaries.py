@@ -3,7 +3,7 @@
 These run the stripped engines from the reference checkout via Open MPI's
 isolated-singleton mode (one rank, no orted — discovered in build round
 5) and diff them against the golden model, pinning the measured tie
-semantics (TIE_SEMANTICS_r05.json) inside the committed suite. Skipped
+semantics (tools/fuzz_vs_binaries.py) inside the committed suite. Skipped
 automatically where the reference checkout or a compatible libmpi is
 absent, so the suite stays portable.
 """
@@ -58,7 +58,7 @@ def test_golden_matches_binaries_on_adversarial_ties(seed):
     """Tie-heavy adversarial instances: golden must be checksum-identical
     to bench_1/2/3 (the measured label-free tie semantics; bench_4
     disagrees with its own siblings on ties and is excluded here —
-    tools/fuzz_vs_binaries.py / TIE_SEMANTICS_r05.json)."""
+    tools/fuzz_vs_binaries.py)."""
     rng = np.random.default_rng(seed)
     n = int(rng.integers(10, 120))
     nq = int(rng.integers(1, 8))

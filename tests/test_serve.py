@@ -741,8 +741,8 @@ def test_daemon_rejections_surface_as_protocol_errors():
         d.close()
 
 
-def test_daemon_serve_record_round_trips_ledger(tmp_path):
-    rec = tmp_path / "SERVE_TEST_r99.jsonl"
+def test_daemon_serve_record_loads_as_runrecord(tmp_path):
+    rec = tmp_path / "SERVE_TEST.jsonl"
     corpus = make_corpus(n=300)
     d = ServeDaemon(corpus, EngineConfig(), port=0,
                     record_path=str(rec), warm_buckets=[(1, 1)])
@@ -753,13 +753,12 @@ def test_daemon_serve_record_round_trips_ledger(tmp_path):
         cli.close()
     finally:
         d.drain()
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(str(rec))
-    assert entry["status"] == "parsed"
-    series = {p["series"] for p in entry["points"]}
-    assert "serve/cold_start_compile_ms" in series
-    assert "serve/requests_per_sec" in series
-    assert any(p["round"] == 99 for p in entry["points"])
+    from dmlp_tpu.obs.run import RunRecord
+    back = RunRecord.load(str(rec))
+    assert back.kind == "serve"
+    assert "cold_start_compile_ms" in back.metrics
+    assert "requests_per_sec" in back.metrics
+    assert (back.tool, back.device) == ("dmlp_tpu.serve", "cpu")
 
 
 # -- concurrent serving: parallel query + ingest + drain ----------------------

@@ -1,7 +1,7 @@
 """Streaming SLO engine tests: windowed quantiles vs numpy, the
 Sampler drift fix, burn-rate hysteresis / flap suppression, Theil–Sen
 trends, the predictive autoscale policy, the slo.alert trace contract,
-and the slo/ ledger + gate plumbing."""
+and the ramp record."""
 
 import json
 import math
@@ -14,8 +14,6 @@ from dmlp_tpu.fleet.autoscale import (predictive_target_replicas,
                                       target_replicas)
 from dmlp_tpu.obs import slo as obs_slo
 from dmlp_tpu.obs import telemetry
-from dmlp_tpu.obs.ledger import (_better_direction,
-                                 _runrecord_series_name)
 from dmlp_tpu.obs.telemetry import Histogram, Registry
 
 REL = telemetry.HIST_QUANTILE_REL_ERROR
@@ -662,7 +660,7 @@ def test_check_fleet_alert_streams_are_per_objective(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# slo/ ledger family + gate + ramp record
+# the ramp record
 # ---------------------------------------------------------------------------
 
 
@@ -697,35 +695,6 @@ def test_ramp_record_summarizes_arm():
     steps[-1]["slo"]["objectives"]["lat:p99"].update(
         state="firing", cycles=0, burn_fast=6.0)
     rec2 = ramp_record("reactive", "lat:p99", steps)
+    assert (rec2.kind, rec2.config["arm"]) == ("slo", "reactive")
     assert rec2.metrics["breach_cycles"] >= 1
     assert rec2.metrics["worst_state_level"] == 2
-
-
-def test_slo_records_key_per_arm_series_and_gate():
-    from dmlp_tpu.fleet.loadgen import ramp_record
-    from tools.perf_gate import gated
-    rec = ramp_record("predictive", "lat:p99", _ramp_steps())
-    name = _runrecord_series_name(rec, "breach_cycles")
-    assert name == "slo/predictive/breach_cycles"
-    assert gated(name, _better_direction(name))
-    assert _better_direction(name) == "lower"
-    assert _better_direction(
-        _runrecord_series_name(rec, "max_burn_fast")) == "lower"
-    assert _better_direction(
-        _runrecord_series_name(rec, "peak_p99_ms")) == "lower"
-    rec2 = ramp_record("reactive", "lat:p99", _ramp_steps())
-    assert _runrecord_series_name(
-        rec2, "breach_cycles") == "slo/reactive/breach_cycles"
-
-
-def test_slo_ledger_ingests_ramp_records(tmp_path):
-    from dmlp_tpu.fleet.loadgen import ramp_record
-    from dmlp_tpu.obs.ledger import build_ledger
-    rec = ramp_record("predictive", "lat:p99", _ramp_steps())
-    rec.round = 17
-    rec.append_jsonl(str(tmp_path / "SLO_r17.jsonl"))
-    ledger = build_ledger(str(tmp_path))
-    assert "slo/predictive/breach_cycles" in ledger["series"]
-    pt = ledger["series"]["slo/predictive/breach_cycles"][0]
-    assert pt["value"] == 0
-    assert pt["round"] == 17

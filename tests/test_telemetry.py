@@ -597,8 +597,8 @@ class TestSession:
         assert not rs_stats.any_activity()
         assert rs_stats.snapshot()["retries"] == 0
 
-    def test_snapshot_record_is_ledger_ingestible(self, tmp_path):
-        from dmlp_tpu.obs.ledger import ingest_file
+    def test_snapshot_record_loads_as_runrecord(self, tmp_path):
+        from dmlp_tpu.obs.run import RunRecord
         s = telemetry.start(handle_signals=False)
         try:
             telemetry.REGISTRY.counter("unit.solves").inc(4)
@@ -609,11 +609,10 @@ class TestSession:
         assert rec.kind == "telemetry"
         path = str(tmp_path / "TEL_r99.jsonl")
         rec.append_jsonl(path)
-        entry = ingest_file(path)
-        assert entry["status"] == "parsed"
-        series = {p["series"] for p in entry["points"]}
-        assert "telemetry/unit_solves_total" in series
-        assert "telemetry/unit_ms_p50" in series
+        back = RunRecord.load(path)
+        assert back.kind == "telemetry"
+        assert back.metrics["unit_solves_total"] == 4
+        assert "unit_ms_p50" in back.metrics
 
 
 # ---------------------------------------------------------------------------

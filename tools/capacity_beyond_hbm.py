@@ -33,9 +33,8 @@ Correctness: exact mode (f64 rescore + eps-hazard repair) end-to-end;
 VALIDATE_QUERIES queries are solved by the vectorized f64 oracle and
 diffed checksum-for-checksum, per arm.
 
-Writes a schema RunRecord (obs.run) to CAPACITY_BEYOND_HBM_r13.json —
-ledger-ingestible (python -m dmlp_tpu.report); the r04 ad-hoc shape is
-grandfathered. Env: CAP_NUM_DATA, CAP_NUM_QUERIES, CAP_VALIDATE
+Writes a schema RunRecord (obs.run) to $BENCH_OUT (default
+outputs/CAPACITY_BEYOND_HBM.json). Env: CAP_NUM_DATA, CAP_NUM_QUERIES, CAP_VALIDATE
 (default 8), BENCH_OUT.
 """
 
@@ -105,8 +104,9 @@ def main(argv=None) -> int:
         nq = int(os.environ.get("CAP_NUM_QUERIES", 2048))
         nv = int(os.environ.get("CAP_VALIDATE", 8))
         na, k = 64, 32
-        out_path = os.environ.get("BENCH_OUT",
-                                  "CAPACITY_BEYOND_HBM_r13.json")
+        out_path = os.environ.get(
+            "BENCH_OUT", os.path.join("outputs",
+                                      "CAPACITY_BEYOND_HBM.json"))
         # f32 directly (rng.random supports dtype; rng.uniform does not
         # and would materialize a 2x-size f64 intermediate): this IS the
         # staged form; f64 originals at this scale would double host

@@ -32,8 +32,7 @@ harness enforces that end to end:
    (step-identical recovery).
 5. **Zero-fault overhead** — interleaved A/B pairs with the resilience
    layer disabled ($DMLP_TPU_RESILIENCE=0) vs enabled (no faults),
-   recorded as ``resilience_overhead_pct`` in a ledger-ingestible
-   RunRecord (the PR 5 ``--obs-overhead`` pattern).
+   recorded as ``resilience_overhead_pct`` in a RunRecord.
 
 Usage::
 
@@ -158,7 +157,7 @@ def check_faulted_run(kind: str, golden: bytes, out_b: bytes,
 
 def measure_overhead(input_path: str, pairs: int, timeout_s: float):
     """Interleaved resilience off/on engine pairs, no faults — the
-    zero-fault cost of the wrappers (PR 5 --obs-overhead pattern)."""
+    zero-fault cost of the wrappers."""
     times = {"off": [], "on": []}
     for rep in range(max(pairs, 1)):
         order = ("off", "on") if rep % 2 == 0 else ("on", "off")
@@ -244,8 +243,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="outputs/chaos",
                     help="schedule/log/trace artifact directory")
     ap.add_argument("--record", default=None, metavar="FILE",
-                    help="append the chaos RunRecord (JSONL) to FILE — "
-                         "the ledger-ingestible artifact")
+                    help="append the chaos RunRecord (JSONL) to FILE")
     ap.add_argument("--seed-base", type=int, default=1000)
     ap.add_argument("--overhead-pairs", type=int, default=None)
     ap.add_argument("--no-train", action="store_true")

@@ -1,23 +1,25 @@
 #!/usr/bin/env python
-"""Fleet SLO bench: the p99-under-offered-load curve as ledger rounds.
+"""Fleet load sweep: the p99-under-offered-load curve, one record a level.
 
 Spawns N daemon replicas + the fleet router over a paced trace, then
 replays the trace OPEN-LOOP (serve.client.replay_open_loop — requests
 fire on the t_ms schedule regardless of completions, so daemon-side
 queueing lands in the latency quantiles) at a sweep of offered-load
 multipliers, ``--reps`` times per level. One RunRecord per level lands
-in ``--metrics`` (kind "fleet" -> ``fleet/<level>/<metric>`` series,
-gated by ``make perf-gate``); the router's closed-loop snapshot record
+in ``--metrics`` (kind "fleet", the level tag in ``config``); the
+router's closed-loop snapshot record
 rides along under level "router".
 
-Not part of ``make test`` (``make fleet-smoke`` is the CI gate); this
-is the FLEET_rNN emitter. On a TPU host drop JAX_PLATFORMS and pass
+Not part of ``make test`` (``make fleet-smoke`` is the CI gate), and
+not the performance record (``python3 -m benchmark.run``, PERF.md;
+ROADMAP D1). On a TPU host drop JAX_PLATFORMS and pass
 ``--replica-flags "--pallas --select extract"``.
 
 Usage::
 
     JAX_PLATFORMS=cpu python tools/fleet_bench.py \
-        --metrics FLEET_r14.jsonl [--replicas 2] [--reps 3] \
+        --metrics outputs/fleet_bench/FLEET_BENCH.jsonl \
+        [--replicas 2] [--reps 3] \
         [--speeds 1,2,4,8] [--trace inputs/serve_trace2.jsonl] \
         [--mesh-replica] [--replica-flags "..."]
 """

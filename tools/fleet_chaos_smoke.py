@@ -36,15 +36,14 @@ process alive at drain time exiting 0, no flight dumps:
 4. **Warm compile-cache relaunch**: a replica is cold-started against
    an empty persistent compile cache (``--compile-cache``), drained,
    and relaunched against the now-populated cache. The relaunch must
-   recover strictly faster (``fleet/chaos_warm_cache/
-   cold_start_compile_ms`` is the warm number, gated lower-is-better),
-   with a flat bucket ``compile_count`` and byte-identical golden
-   replies from the warmed executables — the fleet cold-start story
-   measured, not assumed.
+   be served every program from the cache (hits = the cold start's
+   misses, no miss; the ``chaos_warm_cache`` record's
+   ``cold_start_compile_ms`` is the warm number), with a flat bucket
+   ``compile_count`` and byte-identical golden replies from the
+   warmed executables.
 
-Each campaign lands a ``fleet/chaos_*/...`` RunRecord; the file is
-ingested by the perf ledger and the series are perf-gate-covered
-(``FLEET_CHAOS_r15.jsonl`` is the committed round).
+Each campaign lands one kind="fleet" RunRecord (level ``chaos_*``);
+the file is read back at the end (``RunRecord.load_all``).
 
 Usage::
 
@@ -501,7 +500,7 @@ def main(argv=None) -> int:
     import shutil
     ccdir = os.path.join(out, "compile_cache")
     shutil.rmtree(ccdir, ignore_errors=True)   # cold arm = empty cache
-    colds, counts = [], []
+    colds, counts, caches = [], [], []
     for gen in ("cold", "warm"):
         fp = fh.spawn_replica(corpus_path, out, f"replica_cc_{gen}",
                               warm, batch_cap=BATCH_CAP,
@@ -510,6 +509,7 @@ def main(argv=None) -> int:
             fh.await_replica(fp)
             colds.append(fp.ready["cold_start_compile_ms"])
             counts.append(fp.ready["compile_count"])
+            caches.append(fp.ready["compile_cache"])
             res5 = sc.replay(fp.ready["port"], HEADER, REQS[:4])
             if any(not r.get("ok") for r in res5) or \
                     sc.contract_text([r["checksums"] for r in res5]) \
@@ -528,48 +528,40 @@ def main(argv=None) -> int:
         fail(f"warm relaunch changed bucket compile_count: "
              f"{counts[0]} -> {counts[1]} (the cache must not alter "
              "which programs are built, only how fast)")
-    if not (colds[1] < colds[0]):
-        fail(f"warm relaunch did not recover faster: cold "
-             f"{colds[0]} ms -> warm {colds[1]} ms (persistent "
-             f"compile cache at {ccdir} had no effect)")
-    say(f"warm-cache relaunch OK: cold start {colds[0]:.0f} ms -> "
-        f"warm {colds[1]:.0f} ms "
-        f"({100.0 * (1 - colds[1] / colds[0]):.0f}% faster recovery, "
-        f"compile_count flat at {counts[0]}, warm replies golden)")
+    # Counts, which repeat exactly; the two cold-start wall times are
+    # recorded, not compared (two ~0.5 s CPU timings under a loaded
+    # host swap places).
+    cold, warm_c = caches
+    if cold["hits"] or not cold["misses"] or warm_c["misses"] \
+            or warm_c["hits"] != cold["misses"]:
+        fail(f"warm relaunch was not served from the persistent "
+             f"compile cache at {ccdir}: cold {cold} -> warm {warm_c}")
+    say(f"warm-cache relaunch OK: {warm_c['hits']} programs served "
+        f"from the cache, 0 compiled (cold start compiled "
+        f"{cold['misses']}; {colds[0]:.0f} ms -> {colds[1]:.0f} ms), "
+        f"compile_count flat at {counts[0]}, warm replies golden")
     RunRecord(
         kind="fleet", tool="tools.fleet_chaos_smoke",
         config={"level": "chaos_warm_cache", "replicas": 1,
                 "mode": "persistent_compile_cache_relaunch"},
         metrics={"cold_start_compile_ms": colds[1],
                  "cold_start_compile_ms_cold": colds[0],
-                 "warm_recovery_speedup":
-                     round(colds[0] / max(colds[1], 1e-9), 3),
+                 "compile_cache_hits": warm_c["hits"],
+                 "compile_cache_misses": warm_c["misses"],
                  "compile_count": counts[1]},
         device=device).append_jsonl(record)
 
-    # ---- ledger round-trip + gate coverage ----------------------------------
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(record)
-    if entry["status"] != "parsed":
-        fail(f"chaos RunRecords did not parse in the ledger: "
-             f"{entry.get('error')}")
-    series = {p["series"] for p in entry["points"]}
-    for want_s in ("fleet/chaos_kill/p99_ms", "fleet/chaos_split/p99_ms",
-                   "fleet/chaos_divergence/repair_ms",
-                   "fleet/chaos_warm_cache/cold_start_compile_ms"):
-        if want_s not in series:
-            fail(f"ledger series missing {want_s} "
-                 f"(got {sorted(series)[:8]}...)")
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(os.path.dirname(
-            os.path.abspath(__file__)), "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-    if not pg.gated("fleet/chaos_split/p99_ms"):
-        fail("fleet/chaos_* series are not perf-gate covered")
-    say(f"ledger round-trip OK: {len(entry['points'])} chaos points, "
-        "p99 series gated")
+    # ---- the campaigns' records read back -----------------------------------
+    back = {r.config.get("level"): r
+            for r in RunRecord.load_all(record) if r.kind == "fleet"}
+    for lvl, metric in (("chaos_kill", "p99_ms"),
+                        ("chaos_split", "p99_ms"),
+                        ("chaos_divergence", "repair_ms"),
+                        ("chaos_warm_cache", "cold_start_compile_ms")):
+        if lvl not in back or metric not in back[lvl].metrics:
+            fail(f"no {lvl} RunRecord with {metric} "
+                 f"(got {sorted(map(str, back))})")
+    say(f"run records OK: {sorted(map(str, back))}")
     say("PASS")
     return 0
 

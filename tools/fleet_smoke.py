@@ -19,8 +19,8 @@ the reason named:
    path included).
 4. **Paced open-loop SLO curve** — the trace's t_ms schedule replayed
    open-loop at two offered-load multipliers; the per-level
-   p50/p95/p99 land in fleet RunRecords that round-trip the perf
-   ledger as gated ``fleet/<level>/...`` series.
+   p50/p95/p99 land in one kind="fleet" RunRecord a level, read back
+   at the end (``RunRecord.load_all``).
 5. **Wide-k multipass serving** — a separate extract-path daemon
    serves k past the kernel's single-pass window through the
    multipass driver against its RESIDENT chunks: response golden,
@@ -167,7 +167,7 @@ def main(argv=None) -> int:
                      f"{eng['compile_count']}")
         say("compile-once OK on both replicas")
 
-        # 4. paced open-loop SLO levels -> gated fleet/ ledger series
+        # 4. paced open-loop SLO levels -> one fleet RunRecord a level
         recs = loadgen.run_levels(router.ready["port"], header, reqs,
                                   speeds=[2.0, 8.0], reps=2,
                                   replicas=2, trace="serve_trace2")
@@ -311,27 +311,19 @@ def main(argv=None) -> int:
     say("fleet drain OK: router + both replicas exited 0, no flight "
         "dumps")
 
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(record)
-    if entry["status"] != "parsed":
-        fail(f"fleet RunRecords did not parse in the ledger: "
-             f"{entry.get('error')}")
-    series = {p["series"] for p in entry["points"]}
-    for want_s in ("fleet/x2/p99_ms", "fleet/x8/p99_ms",
-                   "fleet/x2/offered_qps"):
-        if want_s not in series:
-            fail(f"ledger series missing {want_s} "
-                 f"(got {sorted(series)[:8]}...)")
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(os.path.dirname(
-            os.path.abspath(__file__)), "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-    if not pg.gated("fleet/x2/p99_ms"):
-        fail("fleet/ series are not in the perf gate's prefixes")
-    say(f"ledger round-trip OK: {len(entry['points'])} fleet/ points, "
-        "p99-vs-offered-load gated")
+    from dmlp_tpu.obs.run import RunRecord
+    back = RunRecord.load_all(record)
+    levels = {r.config.get("level"): r for r in back
+              if r.kind == "fleet"}
+    for lvl in ("x2", "x8"):
+        if lvl not in levels:
+            fail(f"no fleet RunRecord for level {lvl} "
+                 f"(got {sorted(map(str, levels))})")
+        for m in ("p99_ms", "offered_qps"):
+            if m not in levels[lvl].metrics:
+                fail(f"level {lvl} record lacks {m}")
+    say(f"run records OK: {len(back)} fleet records, levels "
+        f"{sorted(map(str, levels))}")
     say("PASS")
     return 0
 

@@ -26,11 +26,10 @@ nonzero with the reason named:
    canonical phase order, reconcile fraction >= 0.9) on the merged
    trace, and REJECTS a tampered copy carrying a fabricated attempt-2
    retry hop on a non-retried request.
-5. **Tail attribution -> gated ledger** — ``tools/tail_attrib.py``
-   decomposes the per-level p50/p95/p99 into per-phase contributions,
-   names the dominant phase per level, and its ``tailattrib``
-   RunRecords round-trip the perf ledger as gated
-   ``fleet/<level>/phase/<name>`` series.
+5. **Tail attribution** — ``tools/tail_attrib.py`` decomposes the
+   per-level p50/p95/p99 into per-phase contributions, names the
+   dominant phase per level, and writes one ``tailattrib`` RunRecord
+   a level, read back here.
 
 Usage::
 
@@ -236,7 +235,7 @@ def main(argv=None) -> int:
         f"{verdict['reconcile'].get('fraction')}), tampered trace "
         "rejected")
 
-    # 5. tail attribution -> gated fleet/<level>/phase/ ledger series
+    # 5. tail attribution -> one tailattrib RunRecord a level
     cmd = [sys.executable, os.path.join(tools, "tail_attrib.py"),
            merged_path, "--record", record, "--json"]
     if args.round is not None:
@@ -255,29 +254,17 @@ def main(argv=None) -> int:
         if a["dominant_p99"] not in PHASES:
             fail(f"{lvl}: dominant phase {a['dominant_p99']!r} is not "
                  "a known phase")
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(record)
-    if entry["status"] != "parsed":
-        fail(f"tailattrib records did not parse in the ledger: "
-             f"{entry.get('error')}")
-    series = {p["series"] for p in entry["points"]}
-    for want_s in ("fleet/x8/phase/queue_p99_ms",
-                   "fleet/x8/phase/solve_p99_ms",
-                   "fleet/x2/phase/coalesce_p99_ms"):
-        if want_s not in series:
-            fail(f"ledger series missing {want_s} "
-                 f"(got {sorted(series)[:8]}...)")
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(tools, "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-    if not pg.gated("fleet/x8/phase/queue_p99_ms"):
-        fail("fleet/<level>/phase/ series are not in the perf gate's "
-             "prefixes")
+    from dmlp_tpu.obs.run import RunRecord
+    back = {r.config.get("level"): r
+            for r in RunRecord.load_all(record) if r.kind == "tailattrib"}
+    for lvl, metric in (("x8", "queue_p99_ms"), ("x8", "solve_p99_ms"),
+                        ("x2", "coalesce_p99_ms")):
+        if lvl not in back or metric not in back[lvl].metrics:
+            fail(f"no tailattrib RunRecord for {lvl} with {metric} "
+                 f"(got {sorted(map(str, back))})")
     doms = {lvl: a["dominant_p99"] for lvl, a in sorted(att.items())}
     say(f"tail attribution OK: dominant phases {doms}, "
-        f"{len(entry['points'])} gated ledger points -> {record}")
+        f"{len(back)} tailattrib records -> {record}")
 
     flights = sc.flight_dumps(udir) + sc.flight_dumps(tdir)
     if flights:

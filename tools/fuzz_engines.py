@@ -6,7 +6,7 @@ and diffs run() results against the strict float64 golden model —
 checksum-level equality, so any algorithmic, padding, routing, staging,
 or repair bug is a hard failure, not a tolerance judgement.
 
-Axes (superset of FUZZ_r04's):
+Axes:
 - data styles: duplicate-heavy integer grids, continuous uniform,
   CLUSTERED near-duplicates (the style that found the r4 f32
   cancellation hazard), huge magnitudes, mixed clusters+uniform, extreme

@@ -17,7 +17,7 @@ implementations coincide.
 
 Usage:
   python tools/fuzz_vs_binaries.py [--seeds 3000:3100]
-      [--ref /root/reference] [--out TIE_SEMANTICS_r05.json]
+      [--ref /root/reference] [--out outputs/TIE_SEMANTICS.json]
 """
 from __future__ import annotations
 

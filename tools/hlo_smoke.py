@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Compiled-program introspection smoke (`make hlo-smoke`): CI teeth
-for the HLO-derived collective/memory ledger (obs.hlo) on CPU.
+for the HLO-derived collective/memory report (obs.hlo) on CPU.
 
 Four invariants, each a hard failure:
 
@@ -19,9 +19,9 @@ Four invariants, each a hard failure:
    nonzero bytes and per-mesh-axis attribution, and its ``gspmd_*``
    traffic records must reconcile exactly (the honest-but-empty comms
    block is gone).
-4. **Ledger round-trip** — each ``--hlo-report`` RunRecord must ingest
-   as a parsed ``hlo/<mode>/`` series family carrying
-   ``collective_bytes_total`` > 0 for the distributed modes, and the
+4. **Run record** — each ``--hlo-report`` RunRecord must load as kind
+   ``hlo`` with its mode in ``config`` and ``collective_bytes_total``
+   > 0 for the distributed modes, and the
    memory leg must carry either ``hlo_peak_bytes`` (this CPU backend
    populates memory_analysis) or the explicit
    ``hlo_memory_unavailable`` marker — never silence.
@@ -139,19 +139,18 @@ def main(argv=None) -> int:
     print(f"hlo_smoke: auto: partitioner chose "
           f"{', '.join(k for k, _ in named)}; bytes by axis {by_axis}")
 
-    # 4) ledger round-trip per mode
-    from dmlp_tpu.obs.ledger import ingest_file
+    # 4) each mode's run record loads
+    from dmlp_tpu.obs.run import RunRecord
     for mode in MODES:
-        entry = ingest_file(os.path.join(args.out, f"HLO_{mode}.jsonl"))
-        if entry.get("status") != "parsed":
-            fail(f"{mode}: ledger ingest: {entry}")
-        series = {p["series"] for p in entry["points"]}
-        want = f"hlo/{mode}/collective_bytes_total"
-        if want not in series:
-            fail(f"{mode}: series {want} missing: {sorted(series)}")
-    print("hlo_smoke: ledger round-trip ok "
-          "(hlo/<mode>/collective_bytes_total for "
-          + ", ".join(MODES) + ")")
+        rec = RunRecord.load(os.path.join(args.out, f"HLO_{mode}.jsonl"))
+        if rec.kind != "hlo" or rec.config.get("mode") != mode:
+            fail(f"{mode}: record is kind {rec.kind!r}, "
+                 f"mode {rec.config.get('mode')!r}")
+        if not rec.metrics.get("collective_bytes_total", 0) > 0:
+            fail(f"{mode}: collective_bytes_total missing or 0: "
+                 f"{sorted(rec.metrics)}")
+    print("hlo_smoke: run records ok (kind hlo, collective_bytes_total "
+          "> 0 for " + ", ".join(MODES) + ")")
     print("hlo_smoke: PASS")
     return 0
 

@@ -25,8 +25,7 @@ Four invariants, each a hard failure:
    doubles as its regression test.
 
 With ``--record FILE`` the bf16/f32 A/B also lands as a
-kind="precision" RunRecord (ledger series ``precision/configbanded/
-...``), the committed ``PRECISION_rNN.jsonl``'s banded row.
+kind="precision" RunRecord.
 
 Usage: JAX_PLATFORMS=cpu python tools/precision_smoke.py \
        --out outputs/precision [--record .../PRECISION_SMOKE.jsonl]
@@ -195,7 +194,7 @@ def main(argv=None) -> int:
     print(f"precision_smoke: seeded oom recovered via {degs} with "
           "byte-identical output")
 
-    # -- optional ledger record ----------------------------------------------
+    # -- optional run record -------------------------------------------------
     if args.record:
         from dmlp_tpu.obs.run import RunRecord, round_from_name
         RunRecord(

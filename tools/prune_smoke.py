@@ -15,8 +15,8 @@ Four invariants, each a hard failure:
    feature.
 3. **Observability** — the ``--telemetry`` OpenMetrics snapshot of the
    pruned run must carry the ``scan_bytes_streamed`` counter (and the
-   ``prune_*`` family), so the scanned-bytes ledger series is scraped,
-   not inferred.
+   ``prune_*`` family), so the scanned-bytes count is scraped, not
+   inferred.
 4. **Ladder recovery** — under a seeded ``oom`` schedule at the
    staging site the solve must step the resilience ladder past the
    pruned rung (``prune -> fused``, after the top ``lowp`` rung steps
@@ -24,8 +24,7 @@ Four invariants, each a hard failure:
    byte-identical contract stdout.
 
 With ``--record FILE`` the banded A/B also lands as a kind="prune"
-RunRecord (ledger series ``prune/configbanded/...``), the committed
-``PRUNE_rNN.jsonl``'s banded row.
+RunRecord.
 
 Usage: JAX_PLATFORMS=cpu python tools/prune_smoke.py --out outputs/prune
        [--record outputs/prune/PRUNE_SMOKE.jsonl] [--reps 2]
@@ -201,7 +200,7 @@ def main(argv=None) -> int:
     print(f"prune_smoke: seeded oom recovered via {degs} with "
           "byte-identical output")
 
-    # -- optional ledger record ----------------------------------------------
+    # -- optional run record -------------------------------------------------
     if args.record:
         from dmlp_tpu.obs.run import RunRecord, round_from_name
         RunRecord(

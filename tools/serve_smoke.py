@@ -23,10 +23,10 @@ proofs, every failure exits nonzero with the reason named:
 6. **Incremental ingestion** — rows appended over the wire; the next
    replay matches the golden oracle over the GROWN corpus with zero
    new solve compiles.
-7. **Graceful drain + ledger round-trip** — SIGTERM finishes in-flight
-   work, flushes the final snapshot + serve RunRecord, exits 0, leaves
-   NO flight dump; the record parses in obs.ledger as ``serve/...``
-   series (the ``make perf-gate`` surface).
+7. **Graceful drain** — SIGTERM finishes in-flight work, flushes the
+   final snapshot + serve RunRecord, exits 0, leaves NO flight dump;
+   the record loads (``RunRecord.load``) as kind ``serve`` with the
+   daemon's request, latency and cold-start metrics.
 
 Usage::
 
@@ -257,19 +257,16 @@ def main(argv=None) -> int:
         fail(f"final snapshot invalid: {errs[:3]}")
     say("drain OK: exit 0, final snapshot valid, no flight dump")
 
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(record)
-    if entry["status"] != "parsed":
-        fail(f"serve RunRecord did not parse in the ledger: "
-             f"{entry.get('error')}")
-    series = {p["series"] for p in entry["points"]}
-    for want in ("serve/requests_per_sec", "serve/request_latency_p50_ms",
-                 "serve/cold_start_compile_ms"):
-        if want not in series:
-            fail(f"ledger series missing {want} "
-                 f"(got {sorted(series)})")
-    say(f"ledger round-trip OK: {len(entry['points'])} serve/ series "
-        "points")
+    from dmlp_tpu.obs.run import RunRecord
+    rec = RunRecord.load(record)
+    if rec.kind != "serve":
+        fail(f"serve RunRecord has kind {rec.kind!r}")
+    for want in ("requests_per_sec", "request_latency_p50_ms",
+                 "cold_start_compile_ms"):
+        if want not in rec.metrics:
+            fail(f"serve RunRecord lacks {want} "
+                 f"(got {sorted(rec.metrics)})")
+    say(f"serve RunRecord OK: {len(rec.metrics)} metrics")
     say("PASS")
     return 0
 

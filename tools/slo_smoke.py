@@ -35,8 +35,8 @@ and a tighter internal CANARY (``p95 < T_low``):
 
 Both arms first replay a closed-loop slice byte-identical to the
 float64 golden oracle (observability must not perturb the contract
-channel). Each arm lands one kind="slo" RunRecord; the ledger must
-round-trip them as gated ``slo/<arm>/...`` series.
+channel). Each arm lands one kind="slo" RunRecord, read back at the
+end (``RunRecord.load_all``).
 
 Usage::
 
@@ -57,18 +57,15 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from dmlp_tpu.fleet import harness as fh                  # noqa: E402
 from dmlp_tpu.fleet import loadgen                        # noqa: E402
 from dmlp_tpu.io.grammar import parse_input_text          # noqa: E402
 from dmlp_tpu.obs import slo as slomod                    # noqa: E402
 from dmlp_tpu.obs import telemetry                        # noqa: E402
-from dmlp_tpu.obs.ledger import ingest_file               # noqa: E402
+from dmlp_tpu.obs.run import RunRecord                    # noqa: E402
 from dmlp_tpu.obs.telemetry import validate_openmetrics   # noqa: E402
 from dmlp_tpu.serve import client as sc                   # noqa: E402
-
-import perf_gate                                          # noqa: E402
 
 # -- part-2 capacity model ----------------------------------------------------
 # The injected straggler-solve delay makes each replica's micro-batch
@@ -511,21 +508,17 @@ def main(argv=None) -> int:
     say(f"trace OK: merged reactive-arm trace passes check_trace "
         f"--fleet with slo_alerts={verdict.get('slo_alerts')}")
 
-    # -- ledger round-trip ----------------------------------------------------
-    entry = ingest_file(record)
-    if entry.get("status") != "parsed":
-        fail(f"ledger could not parse {record}: {entry}")
-    series = {p["series"] for p in entry.get("points", [])}
-    for want in ("slo/reactive/breach_cycles",
-                 "slo/predictive/breach_cycles",
-                 "slo/predictive/peak_p99_ms"):
-        if want not in series:
-            fail(f"series {want} missing from the ledger ingest: "
-                 f"{sorted(series)}")
-        if not perf_gate.gated(want):
-            fail(f"series {want} is not perf-gated")
-    say(f"ledger OK: {len(series)} slo/ series ingested and gated "
-        f"from {os.path.basename(record)} (round {args.round})")
+    # -- the arms' records read back ------------------------------------------
+    back = {r.config.get("arm"): r
+            for r in RunRecord.load_all(record) if r.kind == "slo"}
+    for arm, metric in (("reactive", "breach_cycles"),
+                        ("predictive", "breach_cycles"),
+                        ("predictive", "peak_p99_ms")):
+        if arm not in back or metric not in back[arm].metrics:
+            fail(f"no slo RunRecord for arm {arm} with {metric} "
+                 f"(got {sorted(map(str, back))})")
+    say(f"run records OK: arms {sorted(map(str, back))} from "
+        f"{os.path.basename(record)} (round {args.round})")
     say("PASS")
     return 0
 

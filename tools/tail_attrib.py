@@ -4,11 +4,9 @@
 Decomposes the client-observed latency quantiles (p50/p95/p99) at each
 offered-load level into per-phase contributions, names the dominant
 phase per level (queue-bound vs solve-bound vs coalesce-bound ...),
-and emits one ledger-ingestible ``kind="tailattrib"`` RunRecord per
-level, so ``fleet/<level>/phase/<name>_p99_ms`` becomes a
-round-over-round series ``tools/perf_gate.py`` gates like every other
-``fleet/`` series (a queue-phase p99 creeping up round-over-round is
-the predictive-autoscaling signal BEFORE the end-to-end SLO slips).
+and emits one ``kind="tailattrib"`` RunRecord per level (a queue-phase
+p99 creeping up is the predictive-autoscaling signal BEFORE the
+end-to-end SLO slips).
 
 Method: the quantiles of a sum are not the sum of quantiles, so naive
 "p99 of each phase" double-counts. Instead, for each quantile q the
@@ -134,7 +132,7 @@ def main(argv=None) -> int:
     ap.add_argument("merged", help="merge_traces --fleet output JSON")
     ap.add_argument("--record", metavar="FILE", default=None,
                     help="append one kind='tailattrib' RunRecord per "
-                         "level here (ledger-ingestible)")
+                         "level here")
     ap.add_argument("--json", action="store_true",
                     help="print the attribution document on stdout "
                          "(narration to stderr)")

@@ -24,11 +24,9 @@ proofs, every failure exits nonzero with the reason named:
 4. **Flight recorder** — a fault schedule drives retries to exhaustion
    inside the CLI; the run must fail AND leave a ``FLIGHT_*.json``
    post-mortem containing recent span events.
-5. **Ledger ingestion** — the overhead + reconcile numbers serialize
-   as ONE RunRecord (kind "telemetry", raw per-arm sample lists) that
-   round-trips through obs.ledger as a parsed ``telemetry/...`` series
-   — the `make perf-gate` surface for telemetry-overhead and peak-HBM
-   regressions.
+5. **Run record** — the overhead + reconcile numbers serialize as ONE
+   RunRecord (kind "telemetry", raw per-arm sample lists) that loads
+   back with both arms' samples and the model's bytes.
 
 Usage::
 
@@ -197,10 +195,7 @@ def emit_record(record_path: str, cfg, times, rec, overhead_pct):
         metrics["peak_hbm_model_vs_measured_pct"] = rec["delta_pct"]
         config_basis = rec["basis"]
     else:
-        # Numeric marker so the ledger creates a visible
-        # `telemetry/.../mem_stats_unavailable` series (string metrics
-        # never become series points — a marker must not vanish from
-        # the report); the human reason rides as a separate string.
+        # Numeric marker; the human reason rides as a separate string.
         metrics["mem_stats_unavailable"] = 1
         metrics["mem_stats_unavailable_reason"] = \
             rec["mem_stats_unavailable"]
@@ -214,24 +209,19 @@ def emit_record(record_path: str, cfg, times, rec, overhead_pct):
     return record_path
 
 
-def check_ledger_roundtrip(record_path: str) -> None:
-    from dmlp_tpu.obs.ledger import ingest_file
-    entry = ingest_file(record_path)
-    if entry["status"] != "parsed":
-        fail(f"telemetry RunRecord did not parse in the ledger: "
-             f"{entry.get('error')}")
-    series = {p["series"] for p in entry["points"]}
-    want_sub = ("engine_ms_telemetry_on", "peak_hbm_model_bytes")
-    for w in want_sub:
-        if not any(w in s for s in series):
-            fail(f"ledger series missing {w} (got {sorted(series)})")
-    trialed = [p for p in entry["points"]
-               if p.get("trials") and "telemetry_on" in p["series"]]
-    if not trialed:
-        fail("the on-arm engine_ms series carries no raw trial "
-             "samples — the gate needs them")
-    say(f"ledger ingestion OK: family={entry['family']}, "
-        f"{len(entry['points'])} series points, raw trials attached")
+def check_record_loads(record_path: str) -> None:
+    from dmlp_tpu.obs.run import RunRecord
+    rec = RunRecord.load(record_path)
+    if rec.kind != "telemetry":
+        fail(f"telemetry RunRecord has kind {rec.kind!r}")
+    for w in ("engine_ms_telemetry_on", "peak_hbm_model_bytes"):
+        if w not in rec.metrics:
+            fail(f"telemetry RunRecord lacks {w} "
+                 f"(got {sorted(rec.metrics)})")
+    if not rec.metrics.get("engine_ms_telemetry_on_reps"):
+        fail("the on-arm engine_ms carries no raw trial samples")
+    say(f"run record OK: kind={rec.kind}, {len(rec.metrics)} metrics, "
+        "raw trials attached")
 
 
 def main(argv=None) -> int:
@@ -284,7 +274,7 @@ def main(argv=None) -> int:
     # 5. RunRecord + ledger round-trip
     if args.record:
         path = emit_record(args.record, cfg, times, rec, overhead)
-        check_ledger_roundtrip(path)
+        check_record_loads(path)
 
     say("OK")
     return 0
