@@ -8,31 +8,41 @@ counterpart of the single-chip engine. It differs from a per-request
 ways a persistent multi-chip server needs:
 
 - **Per-shard resident chunk buffers.** The corpus is staged ONCE at
-  construction into the chunk layout the mesh chunk-fold programs
-  consume: chunk ``t`` is one ``(R * chunk_rows, A)`` device array
-  sharded ``P("data", None)`` holding every shard's ``t``-th piece,
-  padded to a power-of-two capacity. Global row ids stay the affine
-  ``rr * shard_rows + toff + j`` the fold programs derive on device
-  from the ``[n, toff, shard_rows]`` scalar — ``n`` is DATA, so the
-  corpus can grow without recompiling any solve program.
-- **The merge collective as the micro-batch epilogue.** Every
-  coalesced micro-batch runs the per-chunk fold over the resident
-  buffers and then the engines' existing allgather/ring candidate
-  merge (``_chunk_merge_fn``) — followed by the unchanged host
-  float64 finalize + boundary-hazard repair, so every served response
-  is byte-identical to the solo solve over the same corpus AND the
-  golden oracle.
+  construction into ONE device array ``(T, R * chunk_rows, A)`` sharded
+  ``P(None, "data", None)``: a shard holds ``(T, chunk_rows, A)``, its
+  piece of every chunk, padded to a power-of-two capacity; filled a
+  chunk at a time by a donated update (ingest restages a chunk the same
+  way). Global row ids stay the affine ``rr * shard_rows + t *
+  chunk_rows + j`` the fold derives on device (``_chunk_span``'s rule)
+  — the row count is DATA, so the corpus can grow without recompiling
+  any solve program.
+- **One program a micro-batch folds a shard's resident chunks.**
+  ``_resident_fold_fn`` wraps the single-chip resident engine's fold
+  body (``serve.engine.fold_chunks``: the ``_fresh`` kernel call, then
+  a device loop over the carried ones, the gated tiles summed beside
+  them) in ``shard_map``. The fold order, its length, the row count and
+  the per-(shard, chunk) live mask are device data, so a new hot-first
+  order, a pruned piece, a restaged chunk or appended rows reuse the
+  executable. Python dispatches it once and first blocks in
+  ``fleet.merge_drain``; there is no per-chunk loop, no eager gate
+  counter and no ``ChunkThrottle`` (nothing here is staged).
+- **The merge collective as the micro-batch epilogue.** After the fold,
+  the engines' existing allgather/ring candidate merge
+  (``_chunk_merge_fn``, its own program) — followed by the unchanged
+  host float64 finalize + boundary-hazard repair, so every served
+  response is byte-identical to the solo solve over the same corpus AND
+  the golden oracle.
 - **Resident per-(shard, chunk) summaries.** The pruned two-stage
   solve's block summaries (PR 13) are built once over the shard-local
   chunk ranges and kept resident, replicated over the mesh; each
   micro-batch scores them on the devices (the single-chip resident
-  engine's scorer) into per-chunk live masks, so chunks every shard
-  pruned are never dispatched at all. Ingest rebuilds exactly the
+  engine's scorer) into the (R, T) live mask, and chunks every shard
+  pruned are left out of the fold order. Ingest rebuilds exactly the
   touched blocks' summaries.
 - **Shard-routed ingest.** Appended rows land at their global row
   positions — i.e. in the owning shard's span of the touched chunk
-  buffers — via a full restage of exactly those fixed-shape chunk
-  arrays (data inputs, never shapes: zero solve recompilation,
+  buffers — via a full restage of exactly those chunks of the stack
+  (data inputs, never shapes: zero solve recompilation,
   asserted by the compile counter like the single-chip resident
   engine).
 
@@ -45,9 +55,8 @@ inside it). Both layouts share the one global-row-id contract.
 
 from __future__ import annotations
 
-import math
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,11 +67,11 @@ from dmlp_tpu.config import EngineConfig
 from dmlp_tpu.engine.finalize import (boundary_overflow, finalize_host,
                                       lowp_eps, repair_boundary_overflow,
                                       staging_eps)
-from dmlp_tpu.engine.sharded import ShardedEngine, _np_staging_dtype
-from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, ChunkThrottle,
-                                    MeasuredIters, flush_measured_iters,
-                                    plan_chunks, resilient_get, resolve_kcap,
-                                    round_up)
+from dmlp_tpu.engine.sharded import (ShardedEngine, _chunk_span,
+                                     _np_staging_dtype)
+from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, MeasuredIters,
+                                    flush_measured_iters, plan_chunks,
+                                    resilient_get, resolve_kcap, round_up)
 from dmlp_tpu.io.grammar import KNNInput, Params
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import counters as obs_counters
@@ -70,9 +79,12 @@ from dmlp_tpu.obs import memwatch, telemetry
 from dmlp_tpu.obs.comms import engine_comms
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS, make_mesh
-from dmlp_tpu.serve.engine import (CapacityError, ResidentServingCore,
+from dmlp_tpu.serve.engine import (_KERNEL_STATICS, CapacityError,
+                                   ResidentServingCore, _kernel_statics,
+                                   _update_chunk, fold_chunks, fold_tiles,
                                    k_bucket, query_bucket)
 from dmlp_tpu.tune.cache import shape_bucket
+from dmlp_tpu.utils.compat import shard_map
 
 
 class _MeshBucket:
@@ -140,8 +152,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # Cross-request fused-gate warm-up, mesh edition (ROADMAP
         # follow-on (e)): the single-chip hot-block histogram doesn't
         # port 1:1 — here heat is tracked PER (shard, chunk), and the
-        # fold schedule (one dispatch covers every shard's piece of
-        # chunk t) orders chunks by their across-shard aggregate heat.
+        # fold schedule (every shard folds its piece of chunk t at the
+        # same step) orders chunks by their across-shard aggregate heat.
         # The carried state is a winner histogram, never a threshold:
         # within a request thresholds only tighten, so the fold is
         # sound in any order and carry on/off stay byte-identical
@@ -185,14 +197,14 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
 
         # -- resident device state -------------------------------------------
         self._csh = NamedSharding(self.mesh, P(DATA_AXIS, None))
+        self._ssh = NamedSharding(self.mesh, P(None, DATA_AXIS, None))
         self._lsh = NamedSharding(self.mesh, P(DATA_AXIS))
         self._qsh = NamedSharding(self.mesh, P(QUERY_AXIS, None))
         self._rsh = NamedSharding(self.mesh, P())
         self._lab_dev = jax.device_put(
             np.ascontiguousarray(self._host_labels), self._rsh)
-        self._ones_live = jax.device_put(np.ones(r, np.int32), self._lsh)
-        self._chunks: Optional[List] = None
-        self._sc_dev: Optional[List] = None
+        self._chunks: Optional[jax.Array] = None   # the (T, R*cr, A) stack
+        self._live_dense = None        # (R, T) all-ones live mask
         self._mono = None              # (attrs, labels, ids) when staged
         if self._extract_ok:
             self._stage_chunks()
@@ -225,8 +237,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
     def corpus_rows_per_device(self) -> Dict[str, int]:
         """Resident corpus rows each device holds (the device stamp)."""
         from dmlp_tpu.obs.run import rows_per_device
-        return rows_per_device(self._chunks if self._chunks is not None
-                               else [self._mono[0]])
+        return rows_per_device([self._chunks if self._chunks is not None
+                                else self._mono[0]])
 
     def _check_placement(self) -> None:
         """Refuse a placement that leaves a mesh device without its
@@ -272,21 +284,27 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         return a
 
     def _stage_chunks(self) -> None:
+        r, _ = self.mesh.devices.shape
         with obs_span("fleet.stage_resident", chunks=self._nchunks,
                       mesh=list(self.mesh.devices.shape)):
-            self._chunks = [jax.device_put(self._chunk_host(t), self._csh)
-                            for t in range(self._nchunks)]
-        self._refresh_scalars()
+            # Allocated on the devices, then filled a chunk at a time by
+            # a donated update (ResidentEngine._ensure_chunks' way): a
+            # stack of separately staged chunks would hold the corpus
+            # twice while it is built.
+            self._chunks = jnp.zeros(
+                (self._nchunks, r * self._chunk_rows, self.num_attrs),
+                _np_staging_dtype(self._staging), device=self._ssh)
+            for t in range(self._nchunks):
+                self._restage_chunk(t)
+        self._live_dense = jax.device_put(
+            np.ones((r, self._nchunks), np.int32), self._csh)
 
-    def _refresh_scalars(self) -> None:
-        """The per-chunk ``[n, toff, shard_rows]`` fold scalars; rebuilt
-        on ingest (``n`` is the only moving part — a data input, so the
-        fold programs never recompile)."""
-        self._sc_dev = [
-            jax.device_put(np.asarray(
-                [self.n_real, t * self._chunk_rows, self._shard_rows],
-                np.int32), self._rsh)
-            for t in range(self._nchunks)]
+    def _restage_chunk(self, t: int) -> None:
+        """Write chunk ``t``'s current host rows into the stack, in
+        place: same shapes before and after, so no solve recompiles."""
+        self._chunks = _update_chunk(
+            self._chunks, jax.device_put(self._chunk_host(t), self._csh),
+            jax.device_put(np.int32(t), self._rsh))
 
     def _ensure_monolithic(self) -> None:
         """The streaming paths' resident layout: full capacity-padded
@@ -447,108 +465,134 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         np_dtype = self._np_dtype()
         return jax.device_put(q.astype(np_dtype, copy=False), self._qsh)
 
+    def _block_rows(self) -> np.ndarray:
+        """(R, T) resident rows of every (shard, chunk) block."""
+        def rows(rr, t):
+            lo, hi = self._block_span(rr, t)
+            return hi - lo
+
+        r, _ = self.mesh.devices.shape
+        return np.asarray([[rows(rr, t) for t in range(self._nchunks)]
+                           for rr in range(r)], np.int64)
+
+    def _resident_fold_fn(self, kern: Dict[str, Any]):
+        """The one program a bucket: every (row, col) cell folds its
+        shard's pieces of the scheduled resident chunks into its running
+        (qloc, K) lists — ``serve.engine.fold_chunks``, the single-chip
+        resident engine's body, under ``shard_map``. What the mesh adds
+        is how a chunk index becomes (id_base, n_real): ``_chunk_span``'s
+        rule and this shard's bit of the (R, T) live mask (a pruned
+        piece folds with n_real = 0: every id masks to the sentinel, a
+        provable no-op). ``kern`` (``_kernel_statics`` at the cell's
+        dispatch shape, resolved by the caller OUTSIDE the jit) is the
+        cache key; order, its length, the row count and the mask are
+        device data."""
+        key = ("residentfold",) + tuple(kern[s] for s in _KERNEL_STATICS)
+        if key not in self._fns:
+            cr, sr = self._chunk_rows, self._shard_rows
+
+            def local(q_attrs, stack, order, nfold, n, live):
+                def span(t):
+                    id_base, n_real = _chunk_span((n, t * cr, sr), cr)
+                    return id_base, jnp.where(live[0, t] > 0, n_real, 0)
+
+                od, oi, gated, iters = fold_chunks(
+                    q_attrs, stack, order, nfold, span, **kern)
+                # Per cell: gated tiles and summed kernel iterations,
+                # (R, C) after shard_map, read back once a batch.
+                return (od[None], oi[None], gated[None, None],
+                        iters[None, None])
+
+            sharded = shard_map(
+                local, mesh=self.mesh,
+                in_specs=(P(QUERY_AXIS, None), P(None, DATA_AXIS, None),
+                          P(), P(), P(), P(DATA_AXIS, None)),
+                out_specs=(P(DATA_AXIS, QUERY_AXIS, None),
+                           P(DATA_AXIS, QUERY_AXIS, None),
+                           P(DATA_AXIS, QUERY_AXIS),
+                           P(DATA_AXIS, QUERY_AXIS)),
+                check_vma=False)
+
+            # Named for the device trace, like the merge.
+            def dmlp_mesh_fold(q_attrs, stack, order, nfold, n, live):
+                return sharded(q_attrs, stack, order, nfold, n, live)
+
+            self._fns[key] = jax.jit(dmlp_mesh_fold)
+        return self._fns[key]
+
     def _solve_resident_chunks(self, inp: KNNInput, entry: _MeshBucket):
-        """The mesh-resident hot path: fold the resident chunk buffers
-        (pruned chunks dropped per the live masks) and merge across the
-        data axis — the batch engines' chunked driver minus every
-        staging transfer."""
+        """The mesh-resident hot path: ONE program folds every scheduled
+        resident chunk on every shard (pruned pieces masked per the live
+        mask, chunks no shard needs left out of the order), then the
+        merge across the data axis. Python dispatches the fold once and
+        first blocks in ``fleet.merge_drain``."""
         from dmlp_tpu.ops.summaries import note_scan
         r, c = self.mesh.devices.shape
-        k, cr = entry.kcap, self._chunk_rows
+        k, cr, na = entry.kcap, self._chunk_rows, self.num_attrs
         with obs_span("fleet.stage_queries", qpad=entry.qpad,
                       **self._rid_args()):
-            impl = self._extract_impl("extract", entry.qloc, cr,
-                                      self.num_attrs, k)
+            impl = self._extract_impl("extract", entry.qloc, cr, na, k)
             prec = self._active_prec()  # resolved outside the jits (R2)
+            kern = _kernel_statics(impl, k, cr, entry.qloc, na, prec,
+                                   self._interpret)
             q_dev = self._stage_queries(inp, entry.qpad)
-            cd, ci = self._chunk_init_fn(r, entry.qpad, k)()
-            step = self._chunk_fold_fn(k, self._interpret, impl, prec)
+            fold = self._resident_fold_fn(kern)
         keep_m, prune_stats = self._prune_live(inp, entry, q_dev)
-        item = np.dtype(self._np_dtype()).itemsize
-        # Pre-walk the fold schedule so the one-time dispatch record
-        # can claim the count that will ACTUALLY dispatch — claiming
-        # nchunks would overstate the modeled fold work exactly when
-        # pruning (or a part-empty capacity tail) is doing its job.
-        # The walk follows _chunk_order(): hottest chunks first when
-        # gate carry-over is on, so every query's k-th-best thresholds
-        # tighten before the cold chunks' tiles reach the MXU gate.
-        schedule = []
-        scanned = 0
+        # The fold order follows _chunk_order(): hottest chunks first
+        # when gate carry-over is on, so every query's k-th-best
+        # thresholds tighten before the cold chunks' tiles reach the MXU
+        # gate. Left out of it: the capacity tail (no resident rows on
+        # any shard yet) and chunks every shard pruned.
         with obs_span("fleet.fold_schedule", chunks=self._nchunks,
                       carry=self.gate_carry, **self._rid_args()) as sp:
-            for t in self._chunk_order():
-                live_col = None if keep_m is None else keep_m[:, t]
-                spans = [self._block_span(rr, t) for rr in range(r)]
-                real = [hi > lo for lo, hi in spans]
-                if not any(real):
-                    continue        # capacity tail: no resident rows yet
-                if live_col is not None and not (live_col & real).any():
-                    continue        # every shard pruned this chunk
-                for rr, (lo, hi) in enumerate(spans):
-                    if hi > lo and (live_col is None or live_col[rr]):
-                        scanned += (hi - lo) * self.num_attrs * item
-                schedule.append((t, live_col))
-            sp.set(scheduled=len(schedule))
-        dispatched = 0
-        throttle = ChunkThrottle()
-        mi = MeasuredIters(self, "fleet.chunk_fold",
-                           (entry.qloc, cr, self.num_attrs, k),
-                           kernel=impl)
+            rows = self._block_rows()
+            live = rows > 0
+            if keep_m is not None:
+                live &= keep_m
+            order = [t for t in self._chunk_order() if live[:, t].any()]
+            sp.set(scheduled=len(order))
+        item = np.dtype(self._np_dtype()).itemsize
         self._last_select = "extract"
-        gz = None
-        ntiles = 0
         clock = time.perf_counter
-        # Where the loop's wall time goes: inside step(...) (the host
-        # dispatching the shard_map fold), inside the throttle (blocked
-        # on the devices), and the rest (the live-mask put, the gate
-        # counter's eager ops, the memory sample, bookkeeping).
-        step_s = wait_s = 0.0
         with obs_span("fleet.solve_resident", qpad=entry.qpad, kcap=k,
-                      chunks=self._nchunks, scheduled=len(schedule),
-                      impl=impl, mesh=[r, c],
+                      scheduled=len(order), impl=impl, mesh=[r, c],
                       carry=self.gate_carry, **self._rid_args()) as sp:
-            t_loop = clock()
-            for t, live_col in schedule:
-                lv = self._ones_live if live_col is None \
-                    else jax.device_put(np.asarray(live_col, np.int32),
-                                        self._lsh)
-                if dispatched == 0:
-                    obs_counters.record_dispatch(
-                        step, (cd, ci, self._chunks[t], q_dev,
-                               self._sc_dev[t], lv),
-                        count=len(schedule), site="fleet.chunk_fold")
-                t0 = clock()
-                cd, ci, its = step(cd, ci, self._chunks[t], q_dev,
-                                   self._sc_dev[t], lv)
-                t1 = clock()
-                mi.add(its)
-                # Gate effectiveness: a 0-iteration tile was gated (or
-                # skip-gated) outright — summed on device, read back
-                # once per micro-batch in _after_batch. The tile COUNT
-                # is static shape metadata, no transfer.
-                z = jnp.sum(its == 0)
-                gz = z if gz is None else gz + z
-                ntiles += math.prod(its.shape)
-                dispatched += 1
-                t2 = clock()
-                throttle.tick(cd)
-                step_s += t1 - t0
-                wait_s += clock() - t2
-                telemetry.sample_memory_now()
-            loop_ms = (clock() - t_loop) * 1e3
-            sp.set(dispatches=dispatched,
-                   kernel_dispatch_ms=round(step_s * 1e3, 3),
-                   throttle_wait_ms=round(wait_s * 1e3, 3))
+            t0 = clock()
+            padded = np.zeros(self._nchunks, np.int32)
+            padded[:len(order)] = order
+            # (the resident all-ones mask unless a block with rows in
+            # it was pruned: the scorer usually prunes none)
+            mask = self._live_dense if live.sum() == np.count_nonzero(rows) \
+                else jax.device_put(live.astype(np.int32), self._csh)
+            args = (q_dev, self._chunks,
+                    *jax.device_put((padded, np.int32(len(order)),
+                                     np.int32(self.n_real)), self._rsh),
+                    mask)
+            obs_counters.record_dispatch(fold, args, site="fleet.chunk_fold")
+            cd, ci, gated, iters = fold(*args)
+            fold_ms = (clock() - t0) * 1e3
+            # One program folds every scheduled chunk: the host only
+            # enqueues it (serve.solve_extract's convention); the fold's
+            # device time shows in fleet.merge_drain.
+            sp.set(dispatches=1, chunks=len(order),
+                   kernel_dispatch_ms=round(fold_ms, 3),
+                   throttle_wait_ms=0.0)
+            mi = MeasuredIters(self, "fleet.chunk_fold",
+                               (entry.qloc, cr, na, k), kernel=impl)
+            mi.add(iters)
             mi.done()
-            self._pending_gate = (gz, ntiles) if gz is not None else None
-            blocks_total = sum(1 for rr in range(r)
-                               for t in range(self._nchunks)
-                               if self._block_span(rr, t)[1]
-                               > self._block_span(rr, t)[0])
-            note_scan(self, scanned_bytes=scanned,
-                      dense_bytes=self.n_real * self.num_attrs * item,
+            # Gate effectiveness: a 0-iteration tile was gated (or
+            # skip-gated) outright — counted a cell inside the program,
+            # read back once per micro-batch in _after_batch. The tile
+            # COUNT is static shape arithmetic, no transfer.
+            self._pending_gate = (
+                gated,
+                len(order) * r * c * fold_tiles(kern, entry.qloc, cr))
+            note_scan(self,
+                      scanned_bytes=int(rows[live].sum()) * na * item,
+                      dense_bytes=self.n_real * na * item,
                       blocks_total=(prune_stats or {}).get(
-                          "blocks_total", blocks_total),
+                          "blocks_total", int(np.count_nonzero(rows))),
                       blocks_pruned=(prune_stats or {}).get(
                           "blocks_pruned", 0))
             self.last_comms = engine_comms(self._merge_strategy, (r, c),
@@ -557,13 +601,13 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             obs_counters.record_dispatch(merge_fn,
                                          (cd, ci, self._lab_dev),
                                          site="fleet.chunk_merge")
-        # The queued folds finish here, so that fleet.merge times the
-        # merge program alone (its dispatch, the collective, the
-        # re-select) and not the tail of the fold.
+        # The fold finishes here, so that fleet.merge times the merge
+        # program alone (its dispatch, the collective, the re-select)
+        # and not the fold.
         # (blocked on whether or not a tracer is installed: the phase
         # timings behind `stats` are the same numbers the spans carry.)
         t_drain = clock()
-        with obs_span("fleet.merge_drain", dispatches=dispatched,
+        with obs_span("fleet.merge_drain", dispatches=1,
                       **self._rid_args()):
             jax.block_until_ready((cd, ci))  # check: allow-host-sync
         merge_bytes = sum(t.bytes_total for t in self.last_comms)
@@ -574,14 +618,14 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                       **self._rid_args()):
             top = merge_fn(cd, ci, self._lab_dev)
             jax.block_until_ready(top.dists)  # check: allow-host-sync
-        self.last_phase_ms["dispatch"] = loop_ms + (t_merge - t_drain) * 1e3
+        self.last_phase_ms["dispatch"] = fold_ms + (t_merge - t_drain) * 1e3
         self.last_phase_ms["merge"] = (clock() - t_merge) * 1e3
         return top
 
     def _chunk_order(self) -> List[int]:
-        """Fold order over the resident chunks: every dispatch of chunk
-        ``t`` covers ALL shards' ``t``-th pieces, so the schedule is one
-        permutation of ``t`` — ordered by the chunks' across-shard
+        """Fold order over the resident chunks: step ``i`` of the
+        program folds ALL shards' ``order[i]``-th pieces, so the schedule
+        is one permutation of ``t`` — ordered by the chunks' across-shard
         aggregate winner count (hottest first) when gate carry-over is
         on, natural otherwise. Stable sort: cold chunks keep their
         natural relative order."""
@@ -776,9 +820,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             touched = sorted(set(touched))
             if self._chunks is not None:
                 for t in sorted({t for _rr, t in touched}):
-                    self._chunks[t] = jax.device_put(
-                        self._chunk_host(t), self._csh)
-                self._refresh_scalars()
+                    self._restage_chunk(t)
                 self._rebuild_summary_blocks(touched)
             self._lab_dev = jax.device_put(
                 np.ascontiguousarray(self._host_labels), self._rsh)
@@ -852,7 +894,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             "capacity_rows": self.capacity_rows,
             "gate_carry": self.gate_carry,
             "last_gated_fraction": self.last_gated_fraction,
-            "extract_chunks": self._nchunks if self._chunks else 0,
+            "extract_chunks": (self._nchunks if self._chunks is not None
+                               else 0),
             "summary_blocks": (r * self._nchunks if self._summ else 0),
             "summary_rebuilds": self.summary_rebuilds,
             "last_prune_fraction": self.last_prune_fraction,

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import platform
 import re
@@ -95,14 +96,15 @@ def device_stamp(engine=None) -> Dict[str, Any]:
 
 def rows_per_device(arrays, into: Optional[Dict[str, int]] = None
                     ) -> Dict[str, int]:
-    """Rows of ``arrays`` (data-sharded device arrays) each device
-    holds, summed by device id — what shows that a mesh solve spread
-    the corpus instead of staging it all on the first device."""
+    """Rows of ``arrays`` (data-sharded device arrays, attributes on
+    the last axis: (rows, A), or a stack of chunks (T, rows, A)) each
+    device holds, summed by device id — what shows that a mesh solve
+    spread the corpus instead of staging it all on the first device."""
     rows = {} if into is None else into
     for arr in arrays:
         for shard in arr.addressable_shards:
             key = str(shard.device.id)
-            rows[key] = rows.get(key, 0) + int(shard.data.shape[0])
+            rows[key] = rows.get(key, 0) + math.prod(shard.data.shape[:-1])
     return rows
 
 

@@ -148,34 +148,60 @@ def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
                 precision=precision)
 
 
-@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
-def _fold_stack(q, stack, order, nfold, n_real, **kern):
-    """Fold ``nfold`` chunks of the resident ``stack`` (nchunks,
-    chunk_rows, A), in the order ``order[:nfold]`` gives, into one
-    running top-k: the kernel once a chunk, the first with no carry
-    (the ``_fresh`` form), the rest carried. ``order`` (padded to a
-    fixed length), ``nfold`` and ``n_real`` are device data, so a new
-    schedule, a pruned chunk or an ingest runs the same executable.
-    Returns (dists, ids, gated): ``gated`` counts the (query tile,
-    data block) pairs either gate elided (0 recorded iterations)."""
+def fold_chunks(q, stack, order, nfold, span, **kern):
+    """The resident fold's ONE body, traced by both resident engines
+    (``_fold_stack`` here as a plain jit; the mesh engine per shard,
+    under ``shard_map``): fold ``nfold`` chunks of the resident
+    ``stack`` (nchunks, chunk_rows, A), in the order ``order[:nfold]``
+    gives, into one running top-k: the kernel once a chunk, the first
+    with no carry (the ``_fresh`` form), the rest carried in a device
+    loop. ``span(c)`` gives chunk ``c``'s (id_base, n_real) as traced
+    values: the one thing the two engines derive differently.
+    Returns (dists, ids, gated, iters): ``gated`` counts the (query
+    tile, data block) pairs either gate elided (0 recorded
+    iterations), ``iters`` sums the recorded iterations."""
     from dmlp_tpu.ops.pallas_extract import extract_topk
-    cr = stack.shape[1]
 
     def fold(c, od, oi):
-        lo = c * cr
-        return extract_topk(q, stack[c], od, oi,
-                            n_real=jnp.minimum(n_real - lo, cr),
-                            id_base=lo, **kern)
+        id_base, n_real = span(c)
+        return extract_topk(q, stack[c], od, oi, n_real=n_real,
+                            id_base=id_base, **kern)
 
     od, oi, its = fold(order[0], None, None)
 
     def body(i, carry):
-        od, oi, gated = carry
+        od, oi, gated, iters = carry
         od, oi, its = fold(order[i], od, oi)
-        return od, oi, gated + jnp.sum(its == 0)
+        return od, oi, gated + jnp.sum(its == 0), iters + jnp.sum(its)
 
-    return jax.lax.fori_loop(1, nfold, body,
-                             (od, oi, jnp.sum(its == 0)))
+    return jax.lax.fori_loop(
+        1, nfold, body, (od, oi, jnp.sum(its == 0), jnp.sum(its)))
+
+
+def fold_tiles(kern: Dict[str, Any], qb: int, cr: int) -> int:
+    """(query tile, data block) pairs one kernel call of ``fold_chunks``
+    visits at dispatch shape (qb, cr): static shape arithmetic."""
+    from dmlp_tpu.ops.pallas_distance import _tile
+    return (qb // _tile(qb, kern["tile_q"], 8)) \
+        * (cr // _tile(cr, kern["tile_n"], 128 * kern["ne"]))
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _fold_stack(q, stack, order, nfold, n_real, **kern):
+    """``fold_chunks`` over one chip's stack: chunk ``c`` holds rows
+    ``c * chunk_rows`` on, real up to ``n_real``. ``order`` (padded to
+    a fixed length), ``nfold`` and ``n_real`` are device data, so a new
+    schedule, a pruned chunk or an ingest runs the same executable.
+    Returns (dists, ids, gated)."""
+    cr = stack.shape[1]
+
+    def span(c):
+        lo = c * cr
+        return lo, jnp.minimum(n_real - lo, cr)
+
+    od, oi, gated, _iters = fold_chunks(q, stack, order, nfold, span,
+                                        **kern)
+    return od, oi, gated
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
@@ -384,14 +410,16 @@ class ResidentServingCore:
     # -- gate effectiveness (the fused kernel's gated-tile count) ------------
 
     def _flush_pending_gate(self, sp) -> None:
-        """Read back the batch's pending gated-tile scalar (a host sync,
+        """Read back the batch's pending gated-tile count (a scalar, or
+        a mesh engine's one count a cell, summed here: a host sync,
         after the result fetch) into the gate gauges and the span."""
         if self._pending_gate is None:
             return
         gz, ntiles = self._pending_gate
         self._pending_gate = None
         try:
-            gated = int(jax.device_get(gz))  # check: allow-host-sync
+            got = jax.device_get(gz)  # check: allow-host-sync
+            gated = int(np.sum(got))
             frac = gated / max(ntiles, 1)
             self.last_gated_fraction = frac
             reg = telemetry.registry()
@@ -915,7 +943,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         running lists, the gated-tile count (all three still on the
         device) and the number of (query tile, data block) pairs the
         fold visited."""
-        from dmlp_tpu.ops.pallas_distance import _tile
         cr = self._ex_chunk_rows
         qpad = q_dev.shape[0]
         kern = _kernel_statics(impl, kc, cr, qpad, self.num_attrs, prec,
@@ -926,9 +953,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             q_dev, self._chunks,
             *jax.device_put((padded, np.int32(len(order)),
                              np.int32(self.n_real))), **kern)
-        tiles = (qpad // _tile(qpad, kern["tile_q"], 8)) \
-            * (cr // _tile(cr, kern["tile_n"], 128 * kern["ne"]))
-        return od, oi, gated, len(order) * tiles
+        return od, oi, gated, len(order) * fold_tiles(kern, qpad, cr)
 
     def _solve_resident_extract(self, inp: KNNInput, entry: _Bucket
                                 ) -> Optional[Tuple[TopK, int]]:

@@ -14,6 +14,7 @@ import socket
 import jax
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from benchmark import reference
@@ -237,13 +238,13 @@ def test_winners_that_all_live_on_the_last_shard_are_found():
 # -- (e) the placement refusal -------------------------------------------------
 
 class _OneDevice(MeshResidentEngine):
-    """Stages every chunk buffer whole on the mesh's first device."""
+    """Stages the whole chunk stack on the mesh's first device."""
 
     def _stage_chunks(self) -> None:
         first = SingleDeviceSharding(self.mesh.devices.flat[0])
-        self._chunks = [jax.device_put(self._chunk_host(t), first)
-                        for t in range(self._nchunks)]
-        self._refresh_scalars()
+        self._chunks = jax.device_put(
+            np.stack([self._chunk_host(t) for t in range(self._nchunks)]),
+            first)
 
 
 def test_a_corpus_that_sits_on_one_device_is_refused():
@@ -276,6 +277,10 @@ def test_an_even_placement_is_accepted_on_either_layout(select):
     rows = eng.corpus_rows_per_device()
     assert len(rows) == 4 and len(set(rows.values())) == 1
     assert sum(rows.values()) == eng.capacity_rows >= 2048
+    if select == "extract":     # one array: every chunk of every shard
+        assert eng._chunks.shape == (eng._nchunks, 4 * eng._chunk_rows, NA)
+        assert eng._chunks.sharding.spec == P(None, "data", None)
+        assert eng.bucket_stats()["extract_chunks"] == eng._nchunks
 
 
 # -- spans, phase timings, the merge counter -----------------------------------
@@ -334,8 +339,13 @@ def test_the_mesh_spans_follow_each_other_without_overlap(traced):
 
 def test_the_mesh_spans_carry_what_the_metrics_read(traced):
     fold = named(traced["served"], "fleet.solve_resident")[0]["args"]
-    assert fold["dispatches"] == fold["scheduled"] >= 2
-    assert fold["kernel_dispatch_ms"] >= 0 and fold["throttle_wait_ms"] >= 0
+    # one program, every scheduled chunk folded inside it, nothing staged
+    assert fold["dispatches"] == 1
+    assert fold["chunks"] == fold["scheduled"] >= 2
+    assert fold["kernel_dispatch_ms"] >= 0 and fold["throttle_wait_ms"] == 0
+    assert traced["stats"]["engine"]["extract_chunks"] == fold["chunks"]
+    after = named(traced["served"], "fleet.after_batch")[0]["args"]
+    assert 0 <= after["gated"] <= after["tiles"] and after["tiles"] > 0
     hz = named(traced["served"], "fleet.hazard")[0]["args"]
     assert hz["rows"] == 100000 and hz["flagged"] == 0
     assert hz["dn_max_cached"] is True      # warm-up's batch made the pass
@@ -377,6 +387,221 @@ def test_the_merge_program_is_named_for_the_device_trace():
         text = eng._chunk_merge_fn(entry.kcap).lower(
             cd, ci, eng._lab_dev).as_text()
         assert "jit_dmlp_mesh_merge" in text, merge
+
+
+# -- the one-program mesh fold -------------------------------------------------
+
+#: three chunks a shard; the corpus stops inside the last shard's second
+#: chunk, so that shard's third piece is the capacity tail (no real rows)
+FOLD_CAP = 4 * 3 * 12800
+FOLD_SR = FOLD_CAP // 4
+FOLD_N = 3 * FOLD_SR + 12800 + 7200
+FOLD_NA = 4
+ALL = np.ones((4, 3), bool)
+
+
+def _without(*cells):
+    keep = ALL.copy()
+    for rr, t in cells:
+        keep[rr, t] = False
+    return keep
+
+
+#: what changes between micro-batches, in the order the journey makes
+#: them: (step, hand-set winner histogram a chunk, hand-set (R, T) live
+#: mask, ingest (start row, rows), the fold order the engine must then
+#: schedule)
+MESH_FOLD_STEPS = [
+    ("natural", [0, 0, 0], None, None, [0, 1, 2]),
+    ("hot_first", [1, 5, 3], None, None, [1, 2, 0]),
+    ("piece_pruned", [1, 5, 3], _without((1, 1)), None, [1, 2, 0]),
+    ("chunk_pruned", [1, 5, 3],
+     _without((0, 2), (1, 2), (2, 2), (3, 2)), None, [1, 0]),
+    ("capacity_tail_first", [0, 1, 9], None, None, [2, 1, 0]),
+    ("restaged_chunk", [0, 0, 0], None, (100, 64), [0, 1, 2]),
+    ("appended_rows", [0, 0, 0], None, (None, 9000), [0, 1, 2]),
+]
+MESH_FOLD_BUCKETS = {"q128": 5, "q256": 200}
+
+
+def _reference_mesh_fold(eng, q_dev, order, live, n, kc, prec):
+    """The fold a chunk at a time from Python, a shard at a time: the
+    resolved kernel on this shard's piece of chunk ``t``, first call with
+    no carry, with ``_chunk_span``'s (id_base, n_real) worked out by hand
+    (real rows capped at the corpus end AND at the shard's boundary) and
+    the gate counted eagerly."""
+    from dmlp_tpu.ops import pallas_fused
+    r = eng.mesh.devices.shape[0]
+    cr, sr = eng._chunk_rows, eng._shard_rows
+    q = jax.device_put(np.asarray(q_dev))
+    stack = np.asarray(eng._chunks)
+    kern, _ = pallas_fused.resolve_topk_kernel(
+        q.shape[0], cr, eng.num_attrs, kc)
+    ods, ois, gated, tiles = [], [], [], 0
+    for rr in range(r):
+        od = oi = None
+        g = 0
+        for t in order:
+            id_base = rr * sr + t * cr
+            n_real = int(np.clip(min(n - id_base, sr - t * cr), 0, cr))
+            if not live[rr, t]:
+                n_real = 0
+            od, oi, its = kern(
+                q, jax.device_put(stack[t, rr * cr:(rr + 1) * cr]), od, oi,
+                n_real=n_real, id_base=id_base, kc=kc,
+                interpret=eng._interpret, precision=prec)
+            g += int(np.count_nonzero(np.asarray(its) == 0))
+            tiles += its.size
+        ods.append(np.array(od))
+        ois.append(np.array(oi))
+        gated.append(g)
+    return np.stack(ods), np.stack(ois), gated, tiles
+
+
+@pytest.fixture(scope="module")
+def mesh_fold_journey():
+    """One 4x1 engine, two warm buckets, MESH_FOLD_STEPS in order; a
+    micro-batch a bucket a step. Records what the one program was given
+    and gave, what the chunk-by-chunk reference gives for the same order
+    and mask on the same stack, and the compile counters."""
+    calls = []
+
+    class Recording(MeshResidentEngine):
+        def _resident_fold_fn(self, kern):
+            fn = super()._resident_fold_fn(kern)
+
+            def spy(*args):
+                out = fn(*args)
+                calls.append({"fn": fn, "kern": kern, "args": args,
+                              "out": out})
+                return out
+            return spy
+
+        def _after_batch(self, results):
+            calls[-1]["tiles"] = self._pending_gate[1]
+            super()._after_batch(results)
+
+    rng = np.random.default_rng(95)
+    corpus = make_corpus(rng.uniform(-10, 10, (FOLD_N, FOLD_NA)),
+                         rng.integers(0, 4, FOLD_N))
+    eng = Recording(corpus, mesh_config(), mesh_shape=MESH,
+                    capacity=FOLD_CAP)
+    assert (eng._shard_rows, eng._nchunks, eng._chunk_rows) \
+        == (FOLD_SR, 3, 12800)
+    assert eng._block_rows()[3].tolist() == [12800, 7200, 0]
+    eng.warmup([(nq, 6) for nq in MESH_FOLD_BUCKETS.values()])
+    fns = {c["fn"] for c in calls}
+    calls.clear()
+
+    def counters():
+        return (eng.compile_count, len(eng._fns),
+                tuple(sorted(fn._cache_size() for fn in fns)))
+    warm = counters()
+    seen = {}
+    for step, hits, keep, ingest, _want in MESH_FOLD_STEPS:
+        if ingest is not None:
+            start, m = ingest
+            eng.ingest(rng.integers(0, 4, m).astype(np.int32),
+                       rng.uniform(-10, 10, (m, FOLD_NA)), start=start)
+        # (an instance attribute over the method; popped to restore it)
+        eng.__dict__.pop("_prune_live", None)
+        if keep is not None:
+            eng._prune_live = (
+                lambda inp, entry, q_dev, keep=keep: (
+                    keep.copy(), {"blocks_total": 11,
+                                  "blocks_pruned": int((~keep).sum())}))
+        for bucket, nq in MESH_FOLD_BUCKETS.items():
+            eng._block_hits[:] = hits
+            eng.solve_batch(rng.uniform(-10, 10, (nq, FOLD_NA)),
+                            rng.integers(1, 7, nq).astype(np.int32))
+            call = calls.pop()
+            assert not calls and call["fn"] in fns
+            q_dev, _stack, order, nfold, n, live = call["args"]
+            order = [int(t) for t in np.asarray(order)[:int(nfold)]]
+            live = np.asarray(live) > 0
+            od, oi, gated, _iters = call["out"]
+            seen[step, bucket] = {
+                "order": order, "n_real": int(n), "live": live,
+                # copies: a view would keep the device array alive
+                "got": (np.array(od), np.array(oi),
+                        np.asarray(gated)[:, 0].tolist(), call["tiles"]),
+                "want": _reference_mesh_fold(
+                    eng, q_dev, order, live, int(n), call["kern"]["kc"],
+                    call["kern"]["precision"]),
+                "counters": counters()}
+    return {"warm": warm, "steps": seen}
+
+
+@pytest.mark.parametrize("bucket", sorted(MESH_FOLD_BUCKETS))
+@pytest.mark.parametrize("step,want_order",
+                         [(s[0], s[4]) for s in MESH_FOLD_STEPS])
+def test_one_program_mesh_fold_equals_the_chunk_loop_bit_for_bit(
+        mesh_fold_journey, step, want_order, bucket):
+    rec = mesh_fold_journey["steps"][step, bucket]
+    assert rec["order"] == want_order
+    (od, oi, gated, tiles), (rod, roi, rgated, rtiles) = \
+        rec["got"], rec["want"]
+    assert od.dtype == rod.dtype == np.float32
+    assert od.shape == rod.shape and od.shape[0] == 4
+    # bit for bit, every shard's running lists, not just the answers
+    assert od.tobytes() == rod.tobytes()
+    assert oi.tobytes() == roi.tobytes()
+    # the gate gauges: as many tiles gated on each shard, of as many
+    # visited in all
+    assert (gated, tiles) == (rgated, rtiles)
+
+
+@pytest.mark.parametrize("step", [s[0] for s in MESH_FOLD_STEPS])
+def test_mesh_fold_schedule_mask_and_ingest_never_recompile(
+        mesh_fold_journey, step):
+    """A new order, a pruned piece, a pruned chunk, a restaged chunk,
+    more rows: the same executables (bucket builds, the engine's program
+    table and each fold program's jit cache)."""
+    for bucket in MESH_FOLD_BUCKETS:
+        assert mesh_fold_journey["steps"][step, bucket]["counters"] \
+            == mesh_fold_journey["warm"]
+
+
+def test_the_journeys_masks_and_rows_reached_the_mesh_fold(
+        mesh_fold_journey):
+    steps = mesh_fold_journey["steps"]
+    # nothing pruned: the resident all-ones mask (the empty tail needs
+    # no bit, it has no rows); one piece pruned: that bit alone, beside
+    # the tail's
+    assert steps["natural", "q128"]["live"].all()
+    assert steps["piece_pruned", "q128"]["live"].tolist() == [
+        [True, True, True], [True, False, True], [True, True, True],
+        [True, True, False]]
+    assert not steps["chunk_pruned", "q256"]["live"][:, 2].any()
+    assert steps["restaged_chunk", "q128"]["n_real"] == FOLD_N
+    assert steps["appended_rows", "q128"]["n_real"] == FOLD_N + 9000
+    # the last shard's tail piece holds no row of any list until rows
+    # are appended into it
+    tail = 3 * FOLD_SR + 2 * 12800
+    assert steps["capacity_tail_first", "q256"]["got"][1].max() < tail
+    assert steps["appended_rows", "q256"]["got"][1].max() >= tail
+
+
+def test_the_stack_is_the_only_corpus_sized_array_on_the_mesh():
+    """An extract-path mesh daemon holds the corpus once: no list of
+    chunks beside the stack, no monolithic copy, nothing of that size
+    left over from staging or from a solve."""
+    corpus, q = uniform_corpus(), queries(nq=8, seed=23)
+    size = 2 * 4 * 12800 * NA * 4            # one copy of the stack
+    before = {id(a) for a in jax.live_arrays() if a.nbytes >= size}
+    eng = MeshResidentEngine(corpus, mesh_config(), mesh_shape=MESH,
+                             capacity=CAP)
+    eng.warmup([(8, K)])
+    eng.solve_batch(q, np.full(len(q), K, np.int32))
+    rng = np.random.default_rng(24)
+    eng.ingest(rng.integers(0, 10, 50), rng.random((50, NA)) * 255.0)
+    eng.solve_batch(q, np.full(len(q), K, np.int32))
+    assert eng._chunks.nbytes == size and eng._mono is None
+    assert not hasattr(eng, "_sc_dev") and not hasattr(eng, "_ones_live")
+    mine = {id(a) for a in jax.live_arrays() if a.nbytes >= size} - before
+    assert mine == {id(eng._chunks)}
+    assert eng.corpus_rows_per_device() == {
+        str(d.id): 2 * 12800 for d in eng.mesh.devices.flat}
 
 
 # -- admission on a mesh: one device's budget, one device's watermark ----------

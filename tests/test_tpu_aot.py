@@ -96,6 +96,56 @@ def test_resident_fold_program_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
 
 
+def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e):
+    """The mesh daemon's one-program fold at ``bigann-mesh4.bulk``'s
+    shape (q1024, a 4x1 mesh, 164 resident chunks of 4 x 51 200 x 128
+    float32, kcap 32): the one-chip program's body under ``shard_map``.
+    A shard's 4.3 GB of the stack is an argument and nothing near a
+    chunk's size is allocated beside it; no collective runs in the fold
+    (the merge is its own program); and the donated chunk update that
+    builds and restages the stack aliases the whole of it, shard by
+    shard."""
+    from dmlp_tpu.fleet.mesh_engine import MeshResidentEngine
+    from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS
+    from dmlp_tpu.serve.engine import _kernel_statics, _update_chunk
+    mesh = Mesh(np.asarray(v5e).reshape(4, 1), (DATA_AXIS, QUERY_AXIS))
+    t, cr, na = 164, 51200, 128
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    # (the constructor stages a corpus, which described devices cannot
+    # hold: the program needs the mesh and the chunk plan only)
+    eng = object.__new__(MeshResidentEngine)
+    eng.mesh, eng._fns = mesh, {}
+    eng._chunk_rows, eng._shard_rows = cr, 2 ** 23
+    stack = spec((t, 4 * cr, na), jnp.float32, None, DATA_AXIS, None)
+    compiled = eng._resident_fold_fn(
+        _kernel_statics("fused", 32, cr, 1024, na, "f32", False)).lower(
+        spec((1024, na), jnp.float32, QUERY_AXIS, None), stack,
+        spec((t,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        spec((4, t), jnp.int32, DATA_AXIS, None)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_dmlp_mesh_fold")
+    calls = [line.lstrip().removeprefix("ROOT ").split(" ", 1)[0]
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(c.rsplit(".", 1)[0] for c in calls) == [
+        "%dmlp_topk_fused", "%dmlp_topk_fused_fresh"], calls
+    assert " while(" in hlo
+    assert "all-reduce" not in hlo and "all-gather" not in hlo
+    mem = compiled.memory_analysis()          # of one device
+    assert mem.argument_size_in_bytes >= t * cr * na * 4
+    assert mem.temp_size_in_bytes < 4 * cr * na * 4
+    update = _update_chunk.lower(
+        stack, spec((4 * cr, na), jnp.float32, DATA_AXIS, None),
+        spec((), jnp.int32)).compile()
+    assert update.memory_analysis().alias_size_in_bytes == t * cr * na * 4
+    assert update.output_shardings.spec == P(None, DATA_AXIS, None)
+    assert "all-" not in update.as_text()
+
+
 @pytest.mark.slow   # ~25 s, nearly all of it XLA:TPU compiling the merge sort
 def test_sharded_engine_program_compiles_for_v5e_2x2(v5e):
     """The all-gather-merge mesh program, kernel inside shard_map, on

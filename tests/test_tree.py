@@ -224,6 +224,49 @@ def test_package_does_not_import(outside):
     assert not bad, f"dmlp_tpu imports {outside}: {bad}"
 
 
+# -- (d2) one resident fold body -----------------------------------------------
+
+def _imported_names(tree):
+    return {a.name for node in ast.walk(tree)
+            if isinstance(node, ast.ImportFrom) for a in node.names}
+
+
+def _called_names(tree):
+    out = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call):
+            f = node.func
+            out.add(f.attr if isinstance(f, ast.Attribute)
+                    else getattr(f, "id", ""))
+    return out
+
+
+@pytest.mark.parametrize("name", ["fori_loop", "while_loop", "scan",
+                                  "extract_topk", "fused_topk"])
+def test_the_mesh_engine_holds_no_chunk_loop_of_its_own(name):
+    """The resident fold is ``serve.engine.fold_chunks``, traced by both
+    resident engines; the mesh engine wraps it in ``shard_map`` and
+    neither loops over chunks on the device nor calls a kernel itself."""
+    tree = ast.parse(_read("dmlp_tpu/fleet/mesh_engine.py"))
+    assert name not in _called_names(tree)
+    assert name not in _imported_names(tree)
+
+
+def test_the_mesh_engine_imports_the_shared_fold_and_no_throttle():
+    tree = ast.parse(_read("dmlp_tpu/fleet/mesh_engine.py"))
+    assert "fold_chunks" in _imported_names(tree)
+    assert "fold_chunks" in _called_names(tree)
+    assert "ChunkThrottle" not in _imported_names(tree) | {
+        n.id for n in ast.walk(tree) if isinstance(n, ast.Name)}
+    # and the one-chip program is a jit of the same body
+    serve = ast.parse(_read("dmlp_tpu/serve/engine.py"))
+    fold_stack = next(n for n in ast.walk(serve)
+                      if isinstance(n, ast.FunctionDef)
+                      and n.name == "_fold_stack")
+    assert "fold_chunks" in _called_names(fold_stack)
+    assert "fori_loop" not in _called_names(fold_stack)
+
+
 # -- (e) README: what it tells a reader to run or open exists ------------------
 
 def _make_targets():
