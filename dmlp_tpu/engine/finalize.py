@@ -52,23 +52,52 @@ def _vote_batch(labels: np.ndarray, valid: np.ndarray) -> np.ndarray:
     return np.where(best > 0, predicted, -1)
 
 
+#: What one rescore block's (block, K, A) float64 gather may weigh: 512
+#: queries of 32 candidates at 128 attributes, the block every cell ran
+#: before a wide row came.
+RESCORE_BLOCK_BYTES = 512 * 32 * 128 * 8
+
+
+def rescore_block(k: int, num_attrs: int) -> int:
+    """Queries a rescore block: what RESCORE_BLOCK_BYTES holds of
+    (k, num_attrs) float64 candidates, 1 to 512."""
+    return max(1, min(512, RESCORE_BLOCK_BYTES // max(1, k * num_attrs * 8)))
+
+
 def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
-                data_attrs: np.ndarray, block: int = 512) -> np.ndarray:
+                data_attrs: np.ndarray, block: int | None = None
+                ) -> np.ndarray:
     """Exact float64 distances for candidate ids (difference form, like
     computeDistance at engine.cpp:12-18). ids < 0 map to +inf.
 
-    ``block`` bounds the (block, K, A) gather temp. 512 measured 2.4x
-    faster than 1024 at the wide-k shape (10240 x 4608 x 64: 36 s vs
-    87 s — the 2.4 GB temps of block=1024 fall out of cache); 64-512
-    are within noise of each other there and at narrow k the temps are
-    tiny either way."""
+    ``block`` queries are rescored at a time, in ONE (block, K, A)
+    buffer that holds the gathered rows and then their difference; left
+    out, it is rescore_block's. Measured on the serving host of a
+    TPU v5 lite machine (PR 31; 1024 queries x 40 candidates x 960
+    attributes, 314.6 MB gathered; the machine maps no huge pages):
+    blocks of 512 queries, the gather and the difference each a fresh
+    157 MB temporary, over glibc's 32 MB ceiling for the heap, so mapped,
+    faulted in and unmapped every block: 770.8 ms a batch, 37 x the
+    20.6 ms of a 128-attribute batch for 9.4 x the bytes. Blocks of
+    16.6 MB, two temporaries a block: 82 ms in one process and 135 in
+    the next, as the thread's heap kept or trimmed the 33 MB the two
+    freed (its trim threshold is twice the 16.6), a batch every 580 or
+    630 ms. One buffer a call has nothing to trim: 66.9 ms (65.95-66.97
+    over six processes), a batch every 555. The 512 came from a
+    pre-round sweep at 10240 x 4608 x 64 where 64-512 read alike."""
     q, k = cand_ids.shape
+    if block is None:
+        block = rescore_block(k, data_attrs.shape[1])
     out = np.empty((q, k), np.float64)
     safe = np.clip(cand_ids, 0, data_attrs.shape[0] - 1)
+    buf = np.empty((min(block, q), k, data_attrs.shape[1]), data_attrs.dtype)
+    inplace = buf.dtype == np.float64
     for q0 in range(0, q, block):
         q1 = min(q0 + block, q)
-        gathered = data_attrs[safe[q0:q1]]                       # (b, K, A)
-        diff = gathered - query_attrs[q0:q1, None, :]
+        rows = buf[:q1 - q0]                                     # (b, K, A)
+        np.take(data_attrs, safe[q0:q1], axis=0, out=rows, mode="clip")
+        diff = np.subtract(rows, query_attrs[q0:q1, None, :],
+                           out=rows if inplace else None)
         out[q0:q1] = np.einsum("qka,qka->qk", diff, diff)
     out[cand_ids < 0] = np.inf
     return out

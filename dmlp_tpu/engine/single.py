@@ -147,7 +147,15 @@ def plan_chunks(n: int, granule: int, target: int | None) -> Tuple[int, int, int
     work stays negligible, small enough that the first fold starts while
     later chunks are still in flight) of whole ``granule`` blocks covering
     ``n``. Large granules can make the final chunk all padding; drivers
-    skip staging it."""
+    skip staging it.
+
+    A chunk is 51 200 ROWS whatever they weigh: 26 MB at 128 float32
+    attributes, 210 MB at 960 staged on 1 024 lanes. Measured on the
+    resident fold at that width (PR 31, TPU v5 lite, 20 chunks a fold at
+    q1024): the pass that computes a chunk's row norms and indexes it
+    out of the stack takes 12.2 ms a fold beside 88.5 ms of kernel
+    (3.0 beside 61.9 ms at 128 attributes over 82 chunks); the rows a
+    chunk holds were not re-tuned at this width."""
     npad = round_up(max(n, 1), granule)
     t = round_up(target or 51200, granule)
     nchunks = max(1, -(-npad // t))
@@ -172,7 +180,8 @@ def fit_blocks(n: int, target_block: int, granule: int = 8) -> int:
 
 def resolve_kcap(cfg: EngineConfig, kmax: int, select: str, cap: int,
                  staging: str = "float32",
-                 precision: str | None = None) -> int:
+                 precision: str | None = None,
+                 na: int | None = None) -> int:
     """Device candidate-list width: kmax + margin, rounded to 8, clamped to
     [kmax, cap]. The fast selection paths get >= 8 slack beyond kmax even
     with margin 0: the tie-overflow detector compares the k-th and last
@@ -196,12 +205,32 @@ def resolve_kcap(cfg: EngineConfig, kmax: int, select: str, cap: int,
     it once keeps the window static across ladder steps). "bf16" reuses
     the bf16-staging depth (96 + k/2): the cast perturbs every distance
     by at most finalize.lowp_eps, the same coef * (qn + dn_max) shape
-    as the staging cancellation term that margin was calibrated for."""
+    as the staging cancellation term that margin was calibrated for.
+
+    ``na`` (the row width; the resident serving engines give it, where a
+    repair stalls the one batcher thread) deepens the float32 window
+    with the bound it must clear: staging_eps' cancellation term is
+    3 * 2^-22 * (na + 2) * (qn + dn_max), so (na + 2) // 40 slots, which
+    leaves every row of up to 677 attributes at the 16-slot margin.
+    Measured at 960 attributes (PR 31, TPU v5 lite, 10^6 uniform [0, 1)
+    rows, k = 10 in a 32-slot window, 57 344 queries): the batch's
+    tightest query cleared its bound 1.36-1.48 x in the median batch and
+    1.08 x in the worst, none flagged; the k-th to last gap as a sum of
+    order-statistic spacings, fitted to those two quantiles, puts a flag
+    at 5e-6 a query with 32 slots (one run in twelve of the cell would
+    hold one, a host pass over 8 GB of float64), 4e-9 with 40 and, at
+    2048 attributes, 2e-8 with the 72 this rule gives. Fast mode takes
+    the term too (its hazard test and repair are the same): with its 8
+    slots of slack the cell's control repaired about a query a batch,
+    ~4.5 s each."""
     if precision is None:
         precision = cfg.resolve_precision()
     extra = cfg.margin if cfg.exact else 0
     if select in ("sort", "topk", "seg", "extract"):
         extra = max(extra, 8)
+    if na is not None:
+        # In fast mode too: the hazard test and its repair run there.
+        extra = max(extra, (na + 2) // 40)
     if precision == "bf16" and cfg.exact:
         extra = max(extra, 96 + kmax // 2)
     if staging == "bfloat16" and cfg.exact:
@@ -523,6 +552,10 @@ def _device_epilogue(top: TopK, ks, *, num_labels):
 
 class SingleChipEngine:
     """The one-chip engine (CPU backend in CI, TPU in production)."""
+
+    #: the row width resolve_kcap plans the candidate window with; only
+    #: the resident serving engine gives one (its ``na`` argument)
+    _kcap_attrs: int | None = None
 
     def __init__(self, config: EngineConfig = EngineConfig()):
         self.config = config
@@ -1304,7 +1337,8 @@ class SingleChipEngine:
             "kcap": kcap0,
             "kcap_inflation": kcap0 - resolve_kcap(
                 self.config, kmax0, self._last_select, kcap0,
-                staging=self._staging, precision="f32"),
+                staging=self._staging, precision="f32",
+                na=self._kcap_attrs),
         }
         self.last_repairs = 0  # tie-overflow repair rate, for bench records
         self.last_comms = []   # one chip: no collectives (obs.comms)
@@ -1371,6 +1405,12 @@ class SingleChipEngine:
                         # so their eps stays the staging bound alone.
                         eps = eps + lowp_eps("bf16", qn, dn_max)
                     flags = boundary_hazard(kth, last, eps)
+                    # How many times its bound the window clears, for
+                    # the batch's tightest query: 1 or less is a flag.
+                    full = np.isfinite(last) & (eps > 0)
+                    if full.any():
+                        hz.set(clear_min=round(float(
+                            ((last - kth)[full] / eps[full]).min()), 4))
                 # Multi-pass extraction's own loss detectors (stall/
                 # shortfall, _solve_extract_multipass) join the standard
                 # boundary test.
@@ -1386,8 +1426,11 @@ class SingleChipEngine:
 
             t0 = _time.perf_counter()
             hazard_ms += (t0 - t1) * 1e3
+            # gather_bytes: the float64 rows the rescore gathers, one a
+            # candidate (Q x kcap x A x 8 B; none in fast mode).
             with obs_span("single.finalize", exact=self.config.exact,
-                          **targs) as sp:
+                          gather_bytes=ids.size * inp.params.num_attrs * 8
+                          if self.config.exact else 0, **targs) as sp:
                 results = finalize_host(dists, labels, ids, sub.ks,
                                         sub.query_attrs, sub.data_attrs,
                                         exact=self.config.exact,

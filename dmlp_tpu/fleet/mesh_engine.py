@@ -412,14 +412,17 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                             self.query_granule)
         return (c * qloc, k_bucket(kmax))
 
+    def _kcap_for(self, kb: int) -> int:
+        return resolve_kcap(self.config, kb, "extract",
+                            self.capacity_rows, staging=self._staging,
+                            precision=self._precision_plan,
+                            na=self.num_attrs)
+
     def bucket_plan(self, nq: int, kmax: int) -> Tuple[int, int, int]:
         """(qpad, k-bucket, kcap) — the ONE candidate-width derivation
         admission pricing and the memwatch model share with the solve."""
         qpad, kb = self.bucket_shape(nq, kmax)
-        kcap = resolve_kcap(self.config, kb, "extract",
-                            self.capacity_rows, staging=self._staging,
-                            precision=self._precision_plan)
-        return qpad, kb, kcap
+        return qpad, kb, self._kcap_for(kb)
 
     def _active_prec(self) -> str:
         """Per-batch active first-pass precision: the config resolve
@@ -432,9 +435,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
     def _build_bucket(self, qpad: int, kb: int) -> _MeshBucket:
         _r, c = self.mesh.devices.shape
         qloc = qpad // c
-        kcap = resolve_kcap(self.config, kb, "extract",
-                            self.capacity_rows, staging=self._staging,
-                            precision=self._precision_plan)
+        kcap = self._kcap_for(kb)
         path = "stream"
         if self._extract_ok and kcap <= 512:
             from dmlp_tpu.ops import pallas_fused

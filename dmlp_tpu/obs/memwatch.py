@@ -296,7 +296,8 @@ def serve_engine_model(capacity_rows: int, na: int,
                        staging: str = "float32", qpad: int = 0,
                        kcap: int = 0, extract_chunks: int = 0,
                        chunk_rows: int = 0,
-                       summary_blocks: int = 0) -> Dict[str, Any]:
+                       summary_blocks: int = 0,
+                       chunk_attrs: int = 0) -> Dict[str, Any]:
     """Peak resident device bytes for the serving layer's
     :class:`~dmlp_tpu.serve.engine.ResidentEngine`: the capacity-padded
     resident corpus (+ labels/ids mask arrays), the extract path's
@@ -304,21 +305,24 @@ def serve_engine_model(capacity_rows: int, na: int,
     (qpad, kcap) is given — that batch's transient terms (padded query
     block + double-buffered candidate lists). The admission controller
     reads the corpus terms as the floor and prices each bucket's
-    marginal bytes on top."""
+    marginal bytes on top. ``chunk_attrs`` is the width a row of the
+    chunk stack (and of a staged query) holds on the device when the
+    engine pads it to whole lanes (0: ``na``)."""
     item = _staging_itemsize(staging)
+    ca = chunk_attrs or na
     terms: Dict[str, int] = {
         "resident_corpus": capacity_rows * na * item,
         "labels_ids": capacity_rows * 8,
     }
     if extract_chunks:
-        terms["extract_chunks"] = extract_chunks * chunk_rows * na * item
+        terms["extract_chunks"] = extract_chunks * chunk_rows * ca * item
     if summary_blocks:
         # Device-resident block summaries of the pruned two-stage
         # solve (ops.summaries.stage_summaries): two (B, A) f32 boxes,
         # two (B,) f32 norm bands, one (B,) i32 count vector.
         terms["resident_summaries"] = summary_blocks * (8 * na + 12)
     if qpad:
-        terms["query_blocks"] = qpad * na * item
+        terms["query_blocks"] = qpad * ca * item
         terms["topk_carries"] = 2 * qpad * kcap * _TOPK_ITEMSIZE
     return _finish(terms, kind="serve", capacity_rows=capacity_rows,
                    staging=staging)

@@ -36,8 +36,13 @@ engine.cpp:233-257, with a threshold-gated extraction):
 Variant selection (tile_q / tile_n / ne / unroll) resolves through the
 measured autotuner cache (dmlp_tpu.tune) when an entry exists for this
 (device kind, shape bucket, kc, dtype); otherwise the deterministic
-kc-tuned heuristic below — an absent cache (CPU, CI) is bit-identical
-to the pre-tuner behavior.
+heuristic below: tile_q and ne from the list width kc, and the data
+block tile_n from the ROW width (the double-buffered (tile_n, a) block
+is what fills VMEM: 12 800 rows up to 512 attributes, 6 400 at 960,
+2 560 at 2048). An absent cache (CPU, CI) at a <= 512 is bit-identical
+to the pre-tuner behavior. An attribute-axis grid that keeps 12 800
+rows and accumulates the cross term over attribute blocks was measured
+against this on v5e and lost by 25% (tuned_variant's docstring).
 
 Ties are kept by lowest global position (strict `m < T` extraction +
 lowest-lane argmin), i.e. the same semantics as the "topk"/"seg" selects;
@@ -68,7 +73,8 @@ from dmlp_tpu.ops.pallas_distance import _tile
 # one or four. (128, 12800, 2) measured 68 ms vs 148 ms for the previous
 # (512, 8192, 4) default.
 _TQ = 128    # query rows per tile
-_TN = 12800  # data rows per block
+_TN = 12800  # data rows per block, at most (rows past 512 attributes
+#              take a shorter one: _heuristic_variant)
 _E = 2       # extraction candidates per loop iteration (half-block minima)
 
 # Public padding contract for callers (engine.single, bench): pad data to
@@ -79,8 +85,9 @@ QUERY_TILE = _TQ
 
 
 def tuned_variant(kc: int) -> dict:
-    """Per-width kernel tuning, measured on v5e at 204800 x 10240 x 64
-    (a pre-round sweep, its record gone; not re-measured by any cell):
+    """Per-list-width kernel tuning (tile_q, ne), measured on v5e at
+    204800 x 10240 x 64 (a pre-round sweep, its record gone; not
+    re-measured by any cell):
 
     - narrow lists (kc <= 64): the r3 default (tq=128, ne=2) — 101.7 ms
       at kc=64; ne=4 ties (101.3), tq/ne changes within noise.
@@ -90,22 +97,84 @@ def tuned_variant(kc: int) -> dict:
       tiles cut the max-over-rows wasted iterations and ne=4 inserts
       4 candidates per threshold scan. ne=8 / tq=32 / unroll=2 all
       measured worse (refinement rows in the same artifact).
+
+    Measured at 960 attributes (PR 31, TPU v5 lite, one fold of 20
+    chunks of 51 200 x 960 float32 at q1024, kc 32, the kernel's device
+    time a fold; ``chiprun_out/pr31/contest.json`` of that PR's builder,
+    summarised in PERF.md section 6): the row width picks tile_n
+    (_heuristic_variant), and (tq 128, tn 6 400) takes 88.50 ms whether
+    the stack is 960 wide or zero-padded to 1 024 (the padded stack
+    saves 16.8 ms a fold of chunk copies beside the kernel:
+    lane_padded). tq 256 at the same tn: 87.62 (-1.0%, twice the
+    compile time: not taken). An attribute-axis grid at tn 12 800 that
+    accumulates the cross term into the scratch: 110.49 with 512-wide
+    attribute blocks, 130.35 with 256, 97.40 at tq 256: every form
+    slower than the shorter data block, so the kernel has no such axis.
     """
     if kc <= 64:
         return {"tile_q": _TQ, "ne": _E, "unroll": 1}
     return {"tile_q": 64, "ne": 4, "unroll": 1}
 
 
-def _heuristic_variant(kc: int, b: int) -> dict:
+def _whole_lanes(a: int) -> int:
+    return -(-a // 128) * 128
+
+
+def lane_padded(a: int) -> int:
+    """The attribute width a RESIDENT stack holds on the device for
+    rows of ``a`` attributes: whole 128-lane vectors once a row is
+    wider than one (960 -> 1024; a <= 128 stays as it is). Zeros are
+    exact for a squared L2 and for a norm, and cost nothing the chip
+    would not spend anyway: a (tile_n, 960) block takes 1024 lanes in
+    VMEM and the MXU contracts 128 at a time. What they buy is the
+    layout: XLA keeps an array whose minor axis is not whole lanes
+    rows-minor, and the fold then copies every chunk twice (the slice,
+    then a relayout for the kernel) where the padded stack is copied
+    once, by the pass that computes the row norms."""
+    return a if a <= 128 else _whole_lanes(a)
+
+
+#: what the kernel may hold in VMEM by its own reckoning (vmem_bytes);
+#: the compiler is given 96 MiB, the rest is for the block's temporaries
+_VMEM_BOUND = 64 * 2**20
+
+
+def vmem_bytes(tq: int, tn: int, a: int, kc: int) -> int:
+    """VMEM the kernel's blocks take at tiles (tq, tn): the (tq, tn)
+    distance scratch, the double-buffered q and d blocks and the
+    running lists. A block holds whole 128-lane vectors, so a row of
+    ``a`` attributes weighs ``a`` rounded up to lanes (960 -> 1024)."""
+    return (tq * tn + 2 * (tq + tn) * _whole_lanes(a) + 4 * tq * kc) * 4
+
+
+def _heuristic_variant(kc: int, b: int, qb: int | None = None,
+                       a: int | None = None) -> dict:
     """The deterministic fallback: the kc-tuned variant, unless ITS
     ne-alignment can't tile this b (wide-k wants ne=4 → b % 512; a
     caller with pre-shaped shards, e.g. the multi-host feed, may only
     satisfy the ne=2 alignment) — then the default variant keeps kernel
     coverage at r3 tuning rather than silently dropping to the
-    streaming select."""
+    streaming select.
+
+    The data block follows the row width: ``tile_n`` is the largest
+    tile of ``b`` (a 128 * ne multiple that divides it) no longer than
+    _TN whose double-buffered (tile_n, a) block still fits
+    :func:`vmem_bytes`' bound beside the scratch. Up to a = 512 at
+    (tq 128, kc 32) that is _TN itself and the variant carries no
+    ``tile_n``, as before the width entered; a = 960 tiles 51 200 rows
+    by 6 400, a = 2048 by 2 560. Without the dispatch shape (qb, a)
+    the width is unknown and the block stays _TN."""
     v = tuned_variant(kc)
     if b % (128 * v["ne"]) != 0 and b % (128 * _E) == 0:
         v = {"tile_q": _TQ, "ne": _E, "unroll": 1}
+    gran = 128 * v["ne"]
+    if qb is not None and a is not None and b % gran == 0:
+        tq = _tile(qb, v["tile_q"], 8)
+        tn = widest = _tile(b, _TN, gran)
+        while tn > gran and vmem_bytes(tq, tn, a, kc) > _VMEM_BOUND:
+            tn = _tile(b, tn - gran, gran)    # the next tile of b down
+        if tn < widest:
+            v["tile_n"] = tn
     return v
 
 
@@ -134,7 +203,7 @@ def _resolve_variant(kc: int, b: int, qb: int | None = None,
         if qb is None or a is None \
                 or variant_supports(qb, b, a, kc, cached):
             return cached
-    return _heuristic_variant(kc, b)
+    return _heuristic_variant(kc, b, qb, a)
 
 
 def resolve_variant(kc: int, b: int, qb: int | None = None,
@@ -161,8 +230,7 @@ def variant_supports(qb: int, b: int, a: int, kc: int, v: dict) -> bool:
     tq = _tile(qb, v["tile_q"], 8)
     if kc > tn or kc > 512:
         return False
-    vmem = (tq * tn + 2 * (tq + tn) * a + 4 * tq * kc) * 4
-    return vmem <= 64 * 2**20
+    return vmem_bytes(tq, tn, a, kc) <= _VMEM_BOUND
 
 
 def supports(qb: int, b: int, a: int, kc: int) -> bool:
@@ -474,9 +542,8 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     # Validate the ACTUAL tiling (supports() only covers the defaults):
     # the fresh-seed slice and quarter layout need kc <= tn, and the
     # distance scratch + double-buffered blocks must fit VMEM.
-    vmem = (tq * tn + 2 * (tq + tn) * a + 4 * tq * kc) * 4
     if not (qb % 8 == 0 and b % (128 * ne) == 0 and kc <= tn
-            and kc <= 512 and vmem <= 64 * 2**20):
+            and kc <= 512 and vmem_bytes(tq, tn, a, kc) <= _VMEM_BOUND):
         # ValueError, not assert: a caller that skipped supports() must
         # fail loudly under ``python -O`` too, not compute garbage.
         raise ValueError(

@@ -74,7 +74,7 @@ def _resolve_variant(kc: int, b: int, qb: int | None = None,
         if qb is None or a is None \
                 or variant_supports(qb, b, a, kc, cached):
             return cached
-    return _heuristic_variant(kc, b)
+    return _heuristic_variant(kc, b, qb, a)
 
 
 def resolve_variant(kc: int, b: int, qb: int | None = None,

@@ -276,6 +276,17 @@ class ResidentServingCore:
             out["rids"] = self.trace_rids
         return out
 
+    def _variant_args(self) -> Dict[str, int]:
+        """What ``serve.solve_extract`` and ``serve.warmup_bucket`` say
+        of the kernel variant the last solve resolved: its tiles and,
+        where the engine pads it, the width a staged row holds."""
+        from dmlp_tpu.ops.pallas_extract import _TN
+        v = getattr(self, "last_variant", None)
+        if not v:
+            return {}
+        return {"tile_n": _TN, **{k: v[k] for k in (
+            "tile_q", "tile_n", "ne", "a_pad") if k in v}}
+
     def _bucket_entry(self, nq: int, kmax: int):
         """The bucket for (nq, kmax), building (and counting) it on
         first use — warm-up pre-drives this so steady-state serving
@@ -318,8 +329,10 @@ class ResidentServingCore:
             idx = np.arange(nq) % self.n_real
             q = self._host_attrs[:self.n_real][idx]
             ks = np.full(nq, k, np.int32)
-            with obs_span("serve.warmup_bucket", qpad=key[0], kb=key[1]):
+            with obs_span("serve.warmup_bucket", qpad=key[0],
+                          kb=key[1]) as sp:
                 self.solve_batch(q, ks)
+                sp.set(**self._variant_args())
             per[f"q{key[0]}k{key[1]}"] = round(
                 (time.perf_counter() - tb) * 1e3, 3)
         self.cold_start_compile_ms = round(
@@ -446,6 +459,13 @@ class ResidentServingCore:
         from dmlp_tpu.ops import summaries as osum
         dev = {k: self._put_resident(v)
                for k, v in osum.stage_summaries(self._summ).items()}
+        # The scorer reads the batch's query rows as the fold does, at
+        # the stack's width: a zero-wide box in every padded column.
+        pad = getattr(self, "_ex_attrs", self.num_attrs) - self.num_attrs
+        if pad:
+            for k in ("lo", "hi"):
+                dev[k] = self._put_resident(
+                    np.pad(np.asarray(dev[k]), ((0, 0), (0, pad))))
         rel = EPS_REL_BF16 if self._staging == "bfloat16" else EPS_REL_F32
         # score_blocks widens thresholds by eps_rel*sqrt(thr*scale) +
         # eps_cancel*scale with scale = qn + dn_max; lowp_eps is
@@ -533,7 +553,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         cap = capacity or shape_bucket(n)
         if cap < n:
             raise ValueError(f"capacity {cap} < corpus rows {n}")
-        self.num_attrs = na
+        self.num_attrs = self._kcap_attrs = na
         self.gate_carry = bool(gate_carry)
         # First-pass precision PLAN, frozen at construction like every
         # other resident shape decision: bucket kcaps, the staged
@@ -567,7 +587,11 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             self._ex_nchunks = self._ex_chunk_rows = self._ex_rows = 0
             self._interpret = True
         # The extract path's resident copy: ONE device array (nchunks,
-        # chunk_rows, A), so a whole fold is one program (_fold_stack).
+        # chunk_rows, A on whole lanes), so a whole fold is one program
+        # (_fold_stack). The zero columns are staging's: the wire, the
+        # host rows and num_attrs never see them.
+        from dmlp_tpu.ops.pallas_extract import lane_padded
+        self._ex_attrs = lane_padded(na)
         self._chunks = None
         host_rows = max(self.capacity_rows, self._ex_rows)
 
@@ -651,7 +675,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     def _kcap_for(self, kb: int) -> int:
         return resolve_kcap(self.config, kb, self._stream_select,
                             self.capacity_rows, staging=self._staging,
-                            precision=self._precision_plan)
+                            precision=self._precision_plan,
+                            na=self._kcap_attrs)
 
     def bucket_plan(self, nq: int, kmax: int) -> Tuple[int, int, int]:
         """(qpad, k-bucket, kcap) for a request/batch shape — the ONE
@@ -671,7 +696,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         if self._extract_ok and kcap <= 512:
             from dmlp_tpu.ops import pallas_fused
             kern, _ = pallas_fused.resolve_topk_kernel(
-                qpad, self._ex_chunk_rows, self.num_attrs, kcap)
+                qpad, self._ex_chunk_rows, self._ex_attrs, kcap)
             if kern is not None:
                 path = "extract"
                 self._ensure_chunks()
@@ -682,7 +707,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             # extraction driver against the RESIDENT chunks.
             from dmlp_tpu.ops import pallas_fused
             kern, _ = pallas_fused.resolve_topk_kernel(
-                qpad, self._ex_chunk_rows, self.num_attrs, self._MP_KC)
+                qpad, self._ex_chunk_rows, self._ex_attrs, self._MP_KC)
             if kern is not None:
                 path = "multipass"
                 self._ensure_chunks()
@@ -732,7 +757,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                       chunk_rows=cr):
             # Allocated on the device, then filled a chunk at a time by
             # a donated update: the host never holds a second corpus.
-            self._chunks = jnp.zeros((self._ex_nchunks, cr, self.num_attrs),
+            self._chunks = jnp.zeros((self._ex_nchunks, cr, self._ex_attrs),
                                      np_staging_dtype(self._staging))
             for c in range(self._ex_nchunks):
                 self._restage_chunk(c)
@@ -784,9 +809,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         cr = self._ex_chunk_rows
         lo = c * cr
         hi = min(lo + cr, self.n_real)
-        a = np.zeros((cr, self.num_attrs), sdt)
+        a = np.zeros((cr, self._ex_attrs), sdt)
         if hi > lo:
-            a[:hi - lo] = self._host_attrs[lo:hi]
+            a[:hi - lo, :self.num_attrs] = self._host_attrs[lo:hi]
         self._chunks = _update_chunk(
             self._chunks, stage_put(a, self._staging),
             jax.device_put(np.int32(c)))
@@ -936,6 +961,23 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             return None, None   # belt: score_blocks keeps >= 1 block
         return keep, {"blocks_total": total, "blocks_pruned": pruned}
 
+    def _stage_batch_queries(self, inp: KNNInput, qpad: int):
+        """A micro-batch's query rows on the device, padded to the
+        bucket's rows and to the resident stack's width."""
+        q = np.zeros((qpad, self._ex_attrs), np.float32)
+        q[:inp.params.num_queries, :self.num_attrs] = inp.query_attrs
+        return stage_put(q, self._staging)
+
+    def _variant_stamp(self, impl: str, kc: int, qpad: int,
+                       prec: str) -> Dict[str, Any]:
+        """The kernel variant a fold of the resident stack runs with
+        (pallas_fused.variant_stamp at the stack's dispatch shape),
+        with the width the stack holds a row at."""
+        from dmlp_tpu.ops import pallas_fused
+        return {**pallas_fused.variant_stamp(
+            impl, kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec),
+            "a_pad": self._ex_attrs}
+
     def _fold_resident(self, q_dev, order, impl: str, kc: int,
                        prec: str):
         """Dispatch ONE program that folds the resident chunks
@@ -945,7 +987,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         fold visited."""
         cr = self._ex_chunk_rows
         qpad = q_dev.shape[0]
-        kern = _kernel_statics(impl, kc, cr, qpad, self.num_attrs, prec,
+        kern = _kernel_statics(impl, kc, cr, qpad, self._ex_attrs, prec,
                                self._interpret)
         padded = np.zeros(self._ex_nchunks, np.int32)
         padded[:len(order)] = order
@@ -959,19 +1001,17 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                                 ) -> Optional[Tuple[TopK, int]]:
         from dmlp_tpu.ops import pallas_fused
         from dmlp_tpu.ops.summaries import note_scan
-        nq = inp.params.num_queries
         na = self.num_attrs
         cr = self._ex_chunk_rows
         with obs_span("serve.solve_stage", qpad=entry.qpad,
                       **self._rid_args()):
             kern, impl = pallas_fused.resolve_topk_kernel(
-                entry.qpad, cr, na, entry.kcap, rung=self._degrade_rung)
+                entry.qpad, cr, self._ex_attrs, entry.kcap,
+                rung=self._degrade_rung)
             if kern is None:
                 return None
             prec = active_precision(self)  # plan-clamped; outside the jits
-            q = np.zeros((entry.qpad, na), np.float32)
-            q[:nq] = inp.query_attrs
-            q_dev = stage_put(q, self._staging)
+            q_dev = self._stage_batch_queries(inp, entry.qpad)
             order = self._chunk_order()
             survivors, prune_stats = self._prune_survivors(inp, entry,
                                                            q_dev)
@@ -986,13 +1026,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                 return None
             self._last_select = "extract"
             self.last_extract_impl = impl
-            self.last_variant = pallas_fused.variant_stamp(
-                impl, entry.kcap, cr, entry.qpad, na, prec)
+            self.last_variant = self._variant_stamp(
+                impl, entry.kcap, entry.qpad, prec)
         clock = time.perf_counter
         with obs_span("serve.solve_extract", qpad=entry.qpad,
                       kcap=entry.kcap, impl=impl,
                       carry=self.gate_carry, scheduled=len(order),
-                      **self._rid_args()) as sp:
+                      **self._variant_args(), **self._rid_args()) as sp:
             t0 = clock()
             od, oi, gated, ntiles = self._fold_resident(
                 q_dev, order, impl, entry.kcap, prec)
@@ -1050,13 +1090,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         if self._chunks is None or -(-kcap // kc) > self._MP_MAX_PASSES:
             return None
         kern, impl = pallas_fused.resolve_topk_kernel(
-            entry.qpad, self._ex_chunk_rows, self.num_attrs, kc,
+            entry.qpad, self._ex_chunk_rows, self._ex_attrs, kc,
             rung=self._degrade_rung)
         if kern is None:
             return None
         full_rows = self._ex_nchunks * self._ex_chunk_rows
         kern_full, impl_full = pallas_fused.resolve_topk_kernel(
-            entry.qpad, full_rows, self.num_attrs, kc,
+            entry.qpad, full_rows, self._ex_attrs, kc,
             rung=self._degrade_rung)
         if kern_full is None:
             return None
@@ -1066,15 +1106,12 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         n = self.n_real
         cr = self._ex_chunk_rows
         prec = active_precision(self)  # plan-clamped; outside the jits
-        q = np.zeros((entry.qpad, na), np.float32)
-        q[:nq] = inp.query_attrs
-        q_dev = stage_put(q, self._staging)
+        q_dev = self._stage_batch_queries(inp, entry.qpad)
         self._last_select = "extract"
         self.last_extract_impl = impl
-        self.last_variant = pallas_fused.variant_stamp(
-            impl, kc, cr, entry.qpad, na, prec)
-        sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad, na,
-                                prec, self._interpret)
+        self.last_variant = self._variant_stamp(impl, kc, entry.qpad, prec)
+        sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
+                                self._ex_attrs, prec, self._interpret)
         with obs_span("serve.solve_multipass", qpad=entry.qpad,
                       kcap=kcap, passes=npasses, impl=impl,
                       **self._rid_args()):
@@ -1222,7 +1259,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             qpad=qpad, kcap=kcap,
             extract_chunks=(self._ex_nchunks
                             if self._chunks is not None else 0),
-            chunk_rows=self._ex_chunk_rows,
+            chunk_rows=self._ex_chunk_rows, chunk_attrs=self._ex_attrs,
             summary_blocks=(self._ex_nchunks
                             if self._summ_dev is not None else 0))
 
@@ -1265,7 +1302,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             "capacity_rows": self.capacity_rows,
             "gate_carry": self.gate_carry,
             "last_gated_fraction": self.last_gated_fraction,
-            "extract_chunks": self._ex_nchunks
+            # the resident chunks that hold rows: what a dense fold
+            # dispatches (capacity past the last row is staged too)
+            "extract_chunks": -(-self.n_real // self._ex_chunk_rows)
             if self._chunks is not None else 0,
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,

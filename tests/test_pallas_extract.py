@@ -133,15 +133,23 @@ def test_engine_extract_duplicate_ties_fast_mode():
     assert_same_results(got, knn_golden(inp), check_dists=False)
 
 
-def test_engine_extract_unsupported_shape_falls_back():
-    # An attr width the kernel can't tile (the VMEM bound in supports():
-    # double-buffered q/d blocks at na=2000 blow the 64 MB budget), so
-    # _solve_extract — and the multi-pass driver, which shares the gate —
-    # must decline and the chunk-fold driver takes over; still golden.
-    # (k beyond the 512 cap no longer falls back: that case now runs the
-    # multi-pass extraction, test_engine_single.TestMultipassExtract.)
+def test_engine_extract_unsupported_shape_falls_back(monkeypatch):
+    # A shape the kernel can't tile (the VMEM bound in supports()): since
+    # PR 31 the data block follows the row width, so na=2000 itself tiles
+    # (by 256 rows here) and runs the kernel; under a bound no tile of it
+    # fits, _solve_extract — and the multi-pass driver, which shares the
+    # gate — must decline and the chunk-fold driver takes over; still
+    # golden. (k beyond the 512 cap no longer falls back: that case now
+    # runs the multi-pass extraction,
+    # test_engine_single.TestMultipassExtract.)
+    from dmlp_tpu.ops import pallas_extract
     text = generate_input_text(900, 6, 2000, 0, 1, 8, 16, 3, seed=5)
     inp = parse_input_text(text)
+    eng = _engine()
+    got = eng.run(inp)
+    assert eng._last_select == "extract"
+    assert_same_results(got, knn_golden(inp))
+    monkeypatch.setattr(pallas_extract, "_VMEM_BOUND", 2**20)
     eng = _engine()
     got = eng.run(inp)
     assert eng._last_select != "extract"

@@ -96,6 +96,63 @@ def test_resident_fold_program_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
 
 
+def test_wide_row_fold_program_compiles_for_v5e(v5e):
+    """The same program at ``gist.bulk``'s shape: q1024, 21 resident
+    chunks of 51 200 rows of 960 attributes, staged on whole lanes
+    (1024), kcap 40 (the window the width deepens:
+    ``resolve_kcap``). The data block follows the width (6 400 rows: the
+    parent's 12 800 priced 106 MB of VMEM and the bucket fell to the
+    streaming select), Mosaic takes it, and beside the 4.4 GB stack the
+    program allocates one chunk's copy, not the two (a slice, then a
+    relayout) it needs when the stack is left 960 wide."""
+    from dmlp_tpu.ops.pallas_extract import lane_padded
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    from dmlp_tpu.engine.single import resolve_kcap
+    a = lane_padded(960)
+    kc = resolve_kcap(EngineConfig(), 16, "extract", 1 << 20, na=960)
+    assert kc == 40
+    kern = _kernel_statics("fused", kc, 51200, 1024, a, "f32", False)
+    assert (a, kern["tile_q"], kern["tile_n"], kern["ne"]) \
+        == (1024, 128, 6400, 2)
+    compiled = _fold_stack.lower(
+        spec((1024, a), jnp.float32), spec((21, 51200, a), jnp.float32),
+        spec((21,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        **kern).compile()
+    hlo = compiled.as_text()
+    calls = [line.lstrip().removeprefix("ROOT ").split(" ", 1)[0]
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(c.rsplit(".", 1)[0] for c in calls) == [
+        "%dmlp_topk_fused", "%dmlp_topk_fused_fresh"], calls
+    assert " while(" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 21 * 51200 * a * 4
+    assert mem.temp_size_in_bytes < 1.1 * 51200 * a * 4
+
+
+def test_extract_kernel_compiles_for_v5e_at_2048_attributes(v5e):
+    """The width rule's far end (ROADMAP R7): a 2048-attribute row tiles
+    51 200 rows by 2 560, and Mosaic compiles the carried kernel."""
+    from dmlp_tpu.serve.engine import _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    kern = _kernel_statics("fused", 32, 51200, 1024, 2048, "f32", False)
+    assert kern["tile_n"] == 2560
+    _extract_topk_jit.lower(
+        spec((1024, 2048), jnp.float32), spec((51200, 2048), jnp.float32),
+        spec((1024, 32), jnp.float32), spec((1024, 32), jnp.int32),
+        n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
+        block_skip=True, floor=None, **kern).compile()
+
+
 def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e):
     """The mesh daemon's one-program fold at ``bigann-mesh4.bulk``'s
     shape (q1024, a 4x1 mesh, 164 resident chunks of 4 x 51 200 x 128
