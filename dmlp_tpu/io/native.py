@@ -17,7 +17,7 @@ import ctypes
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -40,13 +40,45 @@ def _build() -> bool:
     if (os.path.exists(_LIB)
             and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
         return True
+    # Built beside the target and renamed over it: another process that
+    # loads or builds at the same moment never sees half a library.
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", _LIB, _SRC],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _LIB)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
+    """Declare every entry's signature (ctypes assumes int otherwise)."""
+    lib.dmlp_parse_header.restype = ctypes.c_int
+    lib.dmlp_parse_header.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t,
+        ctypes.POINTER(ctypes.c_long)]
+    lib.dmlp_parse_body.restype = ctypes.c_int
+    lib.dmlp_parse_body.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.c_long, ctypes.c_long,
+        ctypes.c_long,
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
+        ctypes.c_char_p, ctypes.c_size_t]
+    lib.dmlp_float_converter.restype = ctypes.c_char_p
+    lib.dmlp_float_converter.argtypes = []
+    lib.dmlp_parse_json_matrix.restype = ctypes.c_int
+    lib.dmlp_parse_json_matrix.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.c_size_t,
+        np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
+        ctypes.c_size_t, ctypes.POINTER(ctypes.c_long)]
+    return lib
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -65,30 +97,46 @@ def _load() -> Optional[ctypes.CDLL]:
         if not _build():
             return None
         try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
-            # Corrupt/wrong-arch/half-written .so: degrade to the Python
-            # parser rather than poisoning every large parse_input call.
+            _lib = _bind(ctypes.CDLL(_LIB))
+        except (OSError, AttributeError):
+            # Corrupt/wrong-arch/half-written .so, or one that lacks an
+            # entry: degrade to the Python parser rather than poisoning
+            # every large parse_input call.
             return None
-        lib.dmlp_parse_header.restype = ctypes.c_int
-        lib.dmlp_parse_header.argtypes = [
-            ctypes.c_char_p, ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_long)]
-        lib.dmlp_parse_body.restype = ctypes.c_int
-        lib.dmlp_parse_body.argtypes = [
-            ctypes.c_char_p, ctypes.c_size_t, ctypes.c_long, ctypes.c_long,
-            ctypes.c_long,
-            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-            np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
-            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-            np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
-            ctypes.c_char_p, ctypes.c_size_t]
-        _lib = lib
         return _lib
 
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def float_converter() -> Optional[str]:
+    """What the loaded library converts tokens past its 15-digit fast
+    path with: "from_chars" or "strtod" (both correctly rounded); None
+    with no library."""
+    lib = _load()
+    return None if lib is None else lib.dmlp_float_converter().decode()
+
+
+def parse_json_matrix(raw: bytes, start: int
+                      ) -> Optional[Tuple[np.ndarray, int]]:
+    """The JSON array of equal-length number arrays at ``raw[start:]``
+    as a float64 ``(rows, cols)`` array, bit-identical to ``json.loads``
+    + ``np.asarray``, and the offset just past its closing bracket. None
+    when no library is loaded or the scanner does not take the array
+    whole (fastparse.cpp dmlp_parse_json_matrix says what it takes). The
+    scan runs with the interpreter lock released."""
+    lib = _load()
+    if lib is None:
+        return None
+    # a number and its separator are two bytes at least; pages the scan
+    # never writes are never mapped
+    buf = np.empty((len(raw) - start) // 2 + 1, np.float64)
+    out3 = (ctypes.c_long * 3)()
+    if lib.dmlp_parse_json_matrix(raw, start, len(raw), buf, len(buf), out3):
+        return None
+    rows, cols, end = out3
+    return buf[:rows * cols].reshape(rows, cols), end
 
 
 def parse_input_text_native(text) -> KNNInput:

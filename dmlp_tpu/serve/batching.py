@@ -56,6 +56,8 @@ class Request:
     #                                               corpus read offset
     count: Optional[int] = None                   # corpus read length
     debug: bool = False                           # echo neighbors/dists
+    parsed_native: bool = False                   # query_attrs came from
+    #                                               the native scanner
     t_enqueue: float = dataclasses.field(default_factory=time.monotonic)
     # Same instant in the tracer's clock domain: request-phase spans
     # (queue/coalesce/...) are cross-thread intervals stitched from

@@ -24,9 +24,10 @@ import os
 import socketserver
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.io import native
 from dmlp_tpu.io.grammar import KNNInput
 from dmlp_tpu.obs import telemetry
 from dmlp_tpu.obs import trace as obs_trace
@@ -92,14 +93,8 @@ class _Handler(socketserver.StreamRequestHandler):
                     {"ok": False,
                      "error": "request line exceeds the size cap"}))
                 break
-            try:
-                line = raw.decode("utf-8", errors="strict").strip()
-            except UnicodeDecodeError:
-                self.wfile.write(protocol.encode(
-                    {"ok": False, "error": "request is not UTF-8"}))
-                continue
-            if not line:
-                continue
+            # The line goes on as the bytes it came as: the bulk of a
+            # query line never becomes a str (protocol.parse_request).
             # In-flight accounting brackets the RESPONSE WRITE, not
             # just the solve: drain() waits for it, so a drained
             # request's response actually reaches the client before
@@ -108,8 +103,7 @@ class _Handler(socketserver.StreamRequestHandler):
             try:
                 req = None
                 try:
-                    resp, req = daemon.serve_line(line, t_read,
-                                                  nbytes=len(raw))
+                    resp, req = daemon.serve_line(raw, t_read)
                 except protocol.ProtocolError as e:
                     resp = {"ok": False, "error": str(e)}
                 except Exception as e:  # check: no-retry — the
@@ -118,6 +112,8 @@ class _Handler(socketserver.StreamRequestHandler):
                     # batcher
                     resp = {"ok": False,
                             "error": f"{type(e).__name__}: {e}"}
+                if resp is None:    # a blank line: nothing is due
+                    continue
                 w0 = time.perf_counter()
                 self.wfile.write(protocol.encode(resp))
                 self.wfile.flush()
@@ -194,6 +190,13 @@ class ServeDaemon:
         # embedding) doesn't inherit the first one's counts and report
         # an inflated requests_per_sec.
         telemetry.registry().reset(prefix="serve")
+        # The request scanner's library (g++ when it is stale, then
+        # dlopen) comes up beside the staging below, not under the
+        # window's first request and not after set-up; start() joins.
+        self._native_loader = threading.Thread(
+            target=native.native_available, name="serve-native-load",
+            daemon=True)
+        self._native_loader.start()
         if mesh_shape is not None:
             # Mesh-resident replica: the corpus held sharded-resident
             # across the mesh (dmlp_tpu.fleet) — same batcher/admission
@@ -272,6 +275,7 @@ class ServeDaemon:
     def start(self) -> None:
         """Warm the buckets, then open for traffic."""
         self.warmup_ms = self.engine.warmup(self._warm)
+        self._native_loader.join()
         self.batcher.start()
         self._server_thread = threading.Thread(
             target=self._server.serve_forever, name="serve-accept",
@@ -323,14 +327,17 @@ class ServeDaemon:
                     return      # give up, don't wedge the drain
                 self._inflight_cond.wait(timeout=left)
 
-    def handle_line(self, line: str) -> Dict[str, Any]:
+    def handle_line(self, line: Union[str, bytes]) -> Dict[str, Any]:
         return self.serve_line(line)[0]
 
-    def serve_line(self, line: str, t_read: Optional[float] = None,
-                   nbytes: Optional[int] = None
-                   ) -> Tuple[Dict[str, Any], Optional[Request]]:
+    def serve_line(self, line: Union[str, bytes],
+                   t_read: Optional[float] = None
+                   ) -> Tuple[Optional[Dict[str, Any]], Optional[Request]]:
         """One request line to its response, and the Request it made
-        (None for the control ops). ``t_read`` is the perf_counter at
+        (None for the control ops; both None for a blank line). A line
+        given as the ``bytes`` it arrived as has its query matrix
+        decoded natively where the library is loaded
+        (``protocol.parse_request``). ``t_read`` is the perf_counter at
         which the line had been read: the start of the request's parse
         phase (now, when the caller read no socket). A query request's
         ``parse`` and ``respond`` phases are timed here, each one clock
@@ -339,6 +346,8 @@ class ServeDaemon:
         if t_read is None:
             t_read = time.perf_counter()
         obj = protocol.parse_request(line, self.corpus.params.num_attrs)
+        if obj is None:
+            return None, None
         if isinstance(obj, dict):                 # control ops
             if obj.get("op") == "stats":
                 return {"ok": True, "stats": self.stats()}, None
@@ -356,14 +365,16 @@ class ServeDaemon:
         t_parsed = time.perf_counter()
         reg.histogram("serve.phase_ms.parse", unit="ms").observe(
             (t_parsed - t_read) * 1e3)
+        reg.counter("serve.parse_requests").inc(
+            label="native" if req.parsed_native else "fallback")
         self.batcher.submit(req)
         req.done.wait()
         # recorded now, not when it ended: only now is the micro-batch
         # the request rode known
         obs_trace.complete_at(
             "serve.phase.parse", t_read, t_parsed, queries=req.nq,
-            bytes=len(line) if nbytes is None else nbytes, **rid,
-            **_batch_arg(req))
+            native_queries=req.nq if req.parsed_native else 0,
+            bytes=len(line), **rid, **_batch_arg(req))
         r0 = time.perf_counter()
         resp = protocol.query_response(req)
         r1 = time.perf_counter()
@@ -379,6 +390,7 @@ class ServeDaemon:
         elapsed = (time.monotonic() - self._t_ready) \
             if self._t_ready else 0.0
         done = reg.counter("serve.requests_completed").total()
+        parsed = reg.counter("serve.parse_requests")
         from dmlp_tpu.obs.run import device_stamp
         from dmlp_tpu.utils import compile_cache
         out = {
@@ -395,6 +407,13 @@ class ServeDaemon:
             "queries_completed":
                 reg.counter("serve.queries_completed").total(),
             "batches": self.batcher.batches,
+            # which decode this daemon's query lines took (fallback:
+            # json.loads of the whole line) and the converter the
+            # scanner's library was built with (None: no library)
+            "parse": {
+                "native_requests": int(parsed.value("native")),
+                "fallback_requests": int(parsed.value("fallback")),
+                "converter": native.float_converter()},
             "uptime_s": round(elapsed, 3),
             "requests_per_sec": round(done / elapsed, 3) if elapsed
             else None,

@@ -48,10 +48,12 @@ installed — the contract channel never sees the difference.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict
+import re
+from typing import Any, Dict, Optional, Tuple, Union
 
 import numpy as np
 
+from dmlp_tpu.io import native
 from dmlp_tpu.serve.batching import Request
 
 #: protocol schema version, echoed in hello/stats
@@ -79,16 +81,73 @@ def _is_int(v) -> bool:
     return isinstance(v, int) and not isinstance(v, bool)
 
 
-def parse_request(line: str, num_attrs: int) -> Request:
+_QUERIES_KEY = re.compile(rb'"queries"[ \t\n\r]*:')
+
+
+def _scan_queries(line: bytes
+                  ) -> Optional[Tuple[Dict[str, Any], np.ndarray]]:
+    """A query line's object without its ``"queries"`` member, and
+    that member as the float64 matrix ``json.loads`` + ``np.asarray``
+    would make of it, decoded by the native scanner with the
+    interpreter lock released (:func:`native.parse_json_matrix`; a
+    1024 x 960 request is 19 MB of digits, and ~1 M Python floats the
+    other way). None unless all of it is proven: the first
+    ``"queries":`` of the line is followed by an array the scanner
+    takes whole; the bytes before it end where a member of the line's
+    top-level object begins, and the bytes after it continue that
+    object to its end (``json`` parses each, closed by a dummy
+    member); no other spelling of the key stands in either (JSON keeps
+    a repeated key's last value); the op is "query". Every other line
+    — and every line when no library is loaded — is the caller's to
+    parse whole, so what is accepted, what is refused and with which
+    words never depends on the scanner."""
+    m = _QUERIES_KEY.search(line)
+    if m is None:
+        return None
+    got = native.parse_json_matrix(line, m.end())
+    if got is None:
+        return None
+    q, end = got
+    try:
+        obj = json.loads(line[:m.start()].decode("utf-8") + '"":null}')
+        rest = json.loads('{"":null' + line[end:].decode("utf-8"))
+    except (ValueError, RecursionError):
+        return None
+    if "queries" in obj or "queries" in rest:
+        return None
+    obj.update(rest)
+    if obj.get("op", "query") != "query":
+        return None
+    return obj, q
+
+
+def parse_request(line: Union[str, bytes], num_attrs: int
+                  ) -> Union[Request, Dict[str, Any], None]:
     """One wire line -> a validated :class:`Request` (op "query" |
-    "ingest") or a control dict for "stats"/"drain". Raises
-    :class:`ProtocolError` with a client-presentable message."""
+    "ingest" | "corpus") or a control dict for "stats"/"drain". Raises
+    :class:`ProtocolError` with a client-presentable message. A line
+    still in its wire ``bytes`` has its query matrix decoded natively
+    where :func:`_scan_queries` can; where not it is decoded and
+    stripped here, and None says it was blank (no response is due)."""
     if len(line) > MAX_LINE_BYTES:
         raise ProtocolError("request line exceeds the size cap")
-    try:
-        obj = json.loads(line)
-    except json.JSONDecodeError as e:
-        raise ProtocolError(f"bad JSON: {e}") from None
+    q = None
+    if isinstance(line, bytes):
+        scanned = _scan_queries(line)
+        if scanned is not None:
+            obj, q = scanned
+        else:
+            try:
+                line = line.decode("utf-8", errors="strict").strip()
+            except UnicodeDecodeError:
+                raise ProtocolError("request is not UTF-8") from None
+            if not line:
+                return None
+    if q is None:
+        try:
+            obj = json.loads(line)
+        except json.JSONDecodeError as e:
+            raise ProtocolError(f"bad JSON: {e}") from None
     if not isinstance(obj, dict):
         raise ProtocolError("request must be a JSON object")
     op = obj.get("op", "query")
@@ -97,15 +156,17 @@ def parse_request(line: str, num_attrs: int) -> Request:
     req_id = str(obj.get("id", ""))
     rid = str(obj.get("rid", "") or "")
     if op == "query":
-        queries = obj.get("queries")
-        if not isinstance(queries, list) or not queries:
-            raise ProtocolError("query op needs a non-empty 'queries' "
-                                "list of attribute rows")
-        try:
-            q = np.asarray(queries, np.float64)
-        except (TypeError, ValueError):
-            raise ProtocolError("'queries' rows must be numeric and "
-                                "rectangular") from None
+        parsed_native = q is not None
+        if not parsed_native:
+            queries = obj.get("queries")
+            if not isinstance(queries, list) or not queries:
+                raise ProtocolError("query op needs a non-empty 'queries' "
+                                    "list of attribute rows")
+            try:
+                q = np.asarray(queries, np.float64)
+            except (TypeError, ValueError):
+                raise ProtocolError("'queries' rows must be numeric and "
+                                    "rectangular") from None
         if q.ndim != 2 or q.shape[1] != num_attrs:
             raise ProtocolError(
                 f"'queries' must be (nq, {num_attrs}), got {q.shape}")
@@ -123,7 +184,8 @@ def parse_request(line: str, num_attrs: int) -> Request:
             ks_arr = np.asarray(ks, np.int32)
         return Request(kind="query", req_id=req_id, rid=rid,
                        query_attrs=q, ks=ks_arr,
-                       debug=bool(obj.get("debug")))
+                       debug=bool(obj.get("debug")),
+                       parsed_native=parsed_native)
     if op == "ingest":
         rows = obj.get("rows")
         labels = obj.get("labels")
