@@ -480,6 +480,40 @@ def _extract_finalize(od, oi, glabels, *, k):
     return select_topk(od, labels, oi, k)
 
 
+def resolve_sweep_kernel(qpad: int, full_rows: int, na: int, kc: int, *,
+                         chunk_rows: int, rung: str, precision: str):
+    """(kernel, impl) for a multipass plan's passes 2+, which sweep the
+    whole staged array (``full_rows`` = its chunks' rows together) in
+    ONE kernel call, ASSERTED to tile it. Both multipass drivers (the
+    batch engine's and the resident serving engine's) call this before
+    any pass is dispatched. Pass 1 was checked at ``chunk_rows``; that
+    the 128 * ne divisibility and the tile caps carry from a chunk to
+    its multiples is true of today's variants and of nothing else: the
+    variant resolves per row count (a tune-cache entry may pin one at
+    one bucket only), so the carry is checked here, on the variant the
+    sweep will run with, and a tuning change that breaks it fails
+    loudly instead of mis-tiling every pass after the first. The fused
+    / two-pass choice resolves independently per row count too, so
+    pass 1 and the sweeps may legally run different kernels: each is
+    bit-identical, so their union is."""
+    from dmlp_tpu.ops import pallas_fused
+    from dmlp_tpu.ops.pallas_extract import variant_supports
+    kern, impl = pallas_fused.resolve_topk_kernel(
+        qpad, full_rows, na, kc, rung=rung)
+    v = None if kern is None else pallas_fused.variant_for(
+        impl, kc, full_rows, qpad, na, precision)
+    # variant_supports: whole 128 * ne sub-blocks, a tile no narrower
+    # than kc, VMEM room: everything the sweep's one call needs
+    if v is None or not variant_supports(qpad, full_rows, na, kc, v):
+        raise AssertionError(
+            f"multi-pass extract: full-array sweep shape (qb={qpad}, "
+            f"rows={full_rows}, a={na}, kc={kc}, variant={v}) is "
+            f"untileable even though the per-chunk shape "
+            f"(rows={chunk_rows}) tiles — supports() invariants diverged "
+            "between the chunked pass 1 and the resident passes 2+")
+    return kern, impl
+
+
 @functools.partial(jax.jit, static_argnames=("staging", "na", "precision"))
 def _mp_floor(od, qn, dn_max, *, staging: str, na: int,
               precision: str = "f32"):
@@ -960,31 +994,16 @@ class SingleChipEngine:
             qpad, chunk_rows, na, kc, rung=self._degrade_rung)
         if kern is None:
             return None
-        # ADVICE r5 (single.py:614): passes 2+ dispatch the kernel over
-        # the FULL concatenated d_full array, not chunk_rows — today the
-        # 128*ne divisibility and tile caps happen to carry from
-        # chunk_rows to its multiples, but supports() resolves its
-        # variant per row count and nothing guaranteed the carry-over.
-        # Assert the invariant the whole-array sweep actually needs, so
-        # future variant tuning fails loudly here instead of silently
-        # mis-tiling every pass after the first. The fused/two-pass
-        # selection resolves INDEPENDENTLY per row count (the fused
-        # tune-cache namespace may pin a variant at one bucket only), so
-        # pass 1 and the resident passes may legally run different
-        # kernels — each is bit-identical, so the union is too.
+        # Passes 2+ sweep the FULL concatenated d_full array in one
+        # call: the variant resolved for that row count must tile it
+        # (resolve_sweep_kernel asserts it, before anything is staged).
         n_staged = min(nchunks, -(-n // chunk_rows))
         full_rows = n_staged * chunk_rows
-        kern_full, impl_full = pallas_fused.resolve_topk_kernel(
-            qpad, full_rows, na, kc, rung=self._degrade_rung)
-        if kern_full is None:
-            raise AssertionError(
-                f"multi-pass extract: full-array sweep shape (qb={qpad}, "
-                f"rows={full_rows}, a={na}, kc={kc}) is untileable even "
-                f"though the per-chunk shape (rows={chunk_rows}) tiles — "
-                "supports() invariants diverged between the chunked "
-                "pass 1 and the resident passes 2+")
-        interpret = pallas_interpret()
         prec = active_precision(self)
+        kern_full, impl_full = resolve_sweep_kernel(
+            qpad, full_rows, na, kc, chunk_rows=chunk_rows,
+            rung=self._degrade_rung, precision=prec)
+        interpret = pallas_interpret()
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(

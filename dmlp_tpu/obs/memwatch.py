@@ -297,7 +297,8 @@ def serve_engine_model(capacity_rows: int, na: int,
                        kcap: int = 0, extract_chunks: int = 0,
                        chunk_rows: int = 0,
                        summary_blocks: int = 0,
-                       chunk_attrs: int = 0) -> Dict[str, Any]:
+                       chunk_attrs: int = 0,
+                       mp_slots: int = 0) -> Dict[str, Any]:
     """Peak resident device bytes for the serving layer's
     :class:`~dmlp_tpu.serve.engine.ResidentEngine`: the capacity-padded
     resident corpus (+ labels/ids mask arrays), the extract path's
@@ -307,7 +308,15 @@ def serve_engine_model(capacity_rows: int, na: int,
     reads the corpus terms as the floor and prices each bucket's
     marginal bytes on top. ``chunk_attrs`` is the width a row of the
     chunk stack (and of a staged query) holds on the device when the
-    engine pads it to whole lanes (0: ``na``)."""
+    engine pads it to whole lanes (0: ``na``). ``mp_slots`` is what a
+    wide-k bucket's passes extract together (passes x 512 slots a
+    query; 0 for a bucket one pass fills): the multipass driver holds
+    every pass's (qpad, 512) list pair until the merge, their
+    concatenation, and the merge's id-sorted copy and sort workspace
+    (compiled for a v5e at 1024 x 1536: 26.8 MB of temporaries for
+    12.6 MB of lists): four times the lists, beside the merged
+    (qpad, kcap) result that ``topk_carries`` prices. The sweeps read
+    the resident stack itself: no term of the corpus's size."""
     item = _staging_itemsize(staging)
     ca = chunk_attrs or na
     terms: Dict[str, int] = {
@@ -324,6 +333,9 @@ def serve_engine_model(capacity_rows: int, na: int,
     if qpad:
         terms["query_blocks"] = qpad * ca * item
         terms["topk_carries"] = 2 * qpad * kcap * _TOPK_ITEMSIZE
+        if mp_slots:
+            # a list slot is a float32 distance and an int32 id
+            terms["multipass_lists"] = 4 * qpad * mp_slots * 8
     return _finish(terms, kind="serve", capacity_rows=capacity_rows,
                    staging=staging)
 

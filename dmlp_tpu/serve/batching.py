@@ -20,7 +20,7 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -71,6 +71,8 @@ class Request:
     latency_ms: Optional[float] = None
     batch: Optional[int] = None                   # serial of the micro-
     #                                               batch it rode
+    respond_pc: Optional[Tuple[float, float]] = None  # perf_counter pair
+    #                                               around query_response
     corpus_rows: Optional[int] = None             # ingest outcome
     payload: Optional[Dict[str, Any]] = None      # corpus outcome
 

@@ -115,16 +115,18 @@ class _Handler(socketserver.StreamRequestHandler):
                 if resp is None:    # a blank line: nothing is due
                     continue
                 w0 = time.perf_counter()
-                self.wfile.write(protocol.encode(resp))
+                data = protocol.encode(resp)
+                self.wfile.write(data)
                 self.wfile.flush()
                 w1 = time.perf_counter()
                 if req is not None and req.kind == "query":
                     telemetry.registry().histogram(
                         "serve.phase_ms.write", unit="ms").observe(
                             (w1 - w0) * 1e3)
+                    daemon.record_respond(req, len(data))
                 rid = resp.get("rid", "")
                 obs_trace.complete_at(
-                    "serve.phase.write", w0, w1,
+                    "serve.phase.write", w0, w1, bytes=len(data),
                     **({"rid": rid} if rid else {}),
                     **_batch_arg(req))
             finally:
@@ -328,7 +330,28 @@ class ServeDaemon:
                 self._inflight_cond.wait(timeout=left)
 
     def handle_line(self, line: Union[str, bytes]) -> Dict[str, Any]:
-        return self.serve_line(line)[0]
+        resp, req = self.serve_line(line)
+        if req is not None and req.kind == "query":
+            self.record_respond(req)
+        return resp
+
+    @staticmethod
+    def record_respond(req: Request, nbytes: Optional[int] = None) -> None:
+        """The ``serve.phase.respond`` span of a query request, from the
+        clock pair serve_line kept: recorded by whoever holds the
+        response next, once it knows what the span should say of the
+        work: ``k`` (the request's largest: ``query_response`` folds k
+        ids into every checksum and, on a ``debug`` request, makes k
+        ids and k distances a query Python numbers) and, where the
+        response went onto a socket, the ``bytes`` it encoded to."""
+        if not obs_trace.sinks_active():
+            return
+        r0, r1 = req.respond_pc
+        obs_trace.complete_at(
+            "serve.phase.respond", r0, r1, queries=req.nq,
+            k=int(req.ks.max()) if req.nq else 0,
+            **({} if nbytes is None else {"bytes": nbytes}),
+            **({"rid": req.rid} if req.rid else {}), **_batch_arg(req))
 
     def serve_line(self, line: Union[str, bytes],
                    t_read: Optional[float] = None
@@ -342,7 +365,8 @@ class ServeDaemon:
         phase (now, when the caller read no socket). A query request's
         ``parse`` and ``respond`` phases are timed here, each one clock
         pair feeding its always-on histogram and — with a sink
-        installed — its ``serve.phase.*`` span."""
+        installed — its ``serve.phase.*`` span (``respond``'s is
+        recorded by the caller: :meth:`record_respond`)."""
         if t_read is None:
             t_read = time.perf_counter()
         obj = protocol.parse_request(line, self.corpus.params.num_attrs)
@@ -380,8 +404,7 @@ class ServeDaemon:
         r1 = time.perf_counter()
         reg.histogram("serve.phase_ms.respond", unit="ms").observe(
             (r1 - r0) * 1e3)
-        obs_trace.complete_at("serve.phase.respond", r0, r1,
-                              queries=req.nq, **rid, **_batch_arg(req))
+        req.respond_pc = (r0, r1)       # the span: record_respond
         return resp, req
 
     def stats(self) -> Dict[str, Any]:

@@ -635,6 +635,10 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         # batch's gated-tile stats (pending device scalar, tile count).
         self._block_hits = np.zeros(max(self._ex_nchunks, 1), np.int64)
         self._pending_gate: Optional[Tuple] = None
+        # kernel calls the last solve's programs made: the chunks an
+        # extract bucket folded; a multipass bucket's folds of pass 1
+        # and one whole-stack sweep a further pass
+        self.last_kernel_calls = 0
         self.last_gated_fraction: Optional[float] = None
         # Pruned two-stage solve state (ops.summaries): host f64 block
         # summaries at extract-chunk granularity + their device-resident
@@ -686,6 +690,14 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         qpad, kb = self.bucket_shape(nq, kmax)
         return qpad, kb, self._kcap_for(kb)
 
+    def _mp_passes(self, kcap: int) -> int:
+        """Kernel passes a bucket of ``kcap`` slots takes on the wide-k
+        multipass driver; 0 where one pass fills it or the driver does
+        not apply (no extract path, more than _MP_MAX_PASSES)."""
+        passes = -(-kcap // self._MP_KC)
+        return passes if self._extract_ok \
+            and 1 < passes <= self._MP_MAX_PASSES else 0
+
     def _build_bucket(self, qpad: int, kb: int) -> _Bucket:
         cfg = self.config
         kcap = self._kcap_for(kb)
@@ -700,8 +712,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             if kern is not None:
                 path = "extract"
                 self._ensure_chunks()
-        elif self._extract_ok and kcap > self._MP_KC \
-                and -(-kcap // self._MP_KC) <= self._MP_MAX_PASSES:
+        elif self._mp_passes(kcap):
             # Wide-k serving (ROADMAP item (d)): kcap past the kernel's
             # single-pass window routes through the multi-pass
             # extraction driver against the RESIDENT chunks.
@@ -1048,6 +1059,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         # the readback: the epilogue's enqueues run on into _run.
         self._epilogue_pc = clock()
         self._pending_gate = (gated, ntiles)
+        self.last_kernel_calls = len(order)
         item = self._staging_itemsize()
         scanned = sum(min(self.n_real - c * cr, cr) for c in order)
         note_scan(self, scanned_bytes=scanned * na * item,
@@ -1082,7 +1094,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         ``_mp_hazard`` exactly like the batch engine, and run()'s
         boundary repair makes them exact — byte-identical to the solo
         multipass solve and the golden oracle."""
-        from dmlp_tpu.engine.single import _mp_floor, _mp_merge
+        from dmlp_tpu.engine.single import (_mp_floor, _mp_merge,
+                                            resolve_sweep_kernel)
         from dmlp_tpu.ops import pallas_fused
         from dmlp_tpu.ops.summaries import note_scan
         kc = self._MP_KC
@@ -1094,29 +1107,39 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             rung=self._degrade_rung)
         if kern is None:
             return None
-        full_rows = self._ex_nchunks * self._ex_chunk_rows
-        kern_full, impl_full = pallas_fused.resolve_topk_kernel(
-            entry.qpad, full_rows, self._ex_attrs, kc,
-            rung=self._degrade_rung)
-        if kern_full is None:
-            return None
+        prec = active_precision(self)  # plan-clamped; outside the jits
+        cr = self._ex_chunk_rows
+        # Passes 2+ sweep the whole stack in ONE kernel call: the
+        # variant resolved for that row count must tile it, or the
+        # solve stops here, before anything is dispatched.
+        full_rows = self._ex_nchunks * cr
+        _kern_full, impl_full = resolve_sweep_kernel(
+            entry.qpad, full_rows, self._ex_attrs, kc, chunk_rows=cr,
+            rung=self._degrade_rung, precision=prec)
         npasses = -(-kcap // kc)
         nq = inp.params.num_queries
         na = self.num_attrs
         n = self.n_real
-        cr = self._ex_chunk_rows
-        prec = active_precision(self)  # plan-clamped; outside the jits
+        nchunks = -(-n // cr)
         q_dev = self._stage_batch_queries(inp, entry.qpad)
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = self._variant_stamp(impl, kc, entry.qpad, prec)
         sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
                                 self._ex_attrs, prec, self._interpret)
+        floor_args = dict(staging=self._staging, na=na, precision=prec)
+        targs = self._rid_args()
         with obs_span("serve.solve_multipass", qpad=entry.qpad,
-                      kcap=kcap, passes=npasses, impl=impl,
-                      **self._rid_args()):
-            od, oi, _gated, _tiles = self._fold_resident(
-                q_dev, range(-(-n // cr)), impl, kc, prec)
+                      kcap=kcap, passes=npasses, impl=impl, queries=nq,
+                      chunks=nchunks, **targs) as sp:
+            # Every pass is enqueued without a readback (the floors
+            # chain on the device): serve.mp_pass and serve.mp_merge
+            # time the enqueue alone, and the device's time shows where
+            # the host first blocks, in serve.mp_fetch.
+            with obs_span("serve.mp_pass", kc=kc, rows=n, **{"pass": 1},
+                          **targs):
+                od, oi, _gated, _tiles = self._fold_resident(
+                    q_dev, range(nchunks), impl, kc, prec)
             ods, ois = [od], [oi]
             qn_host = np.zeros(entry.qpad, np.float64)
             qn_host[:nq] = np.einsum("qa,qa->q", inp.query_attrs,
@@ -1125,37 +1148,53 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             dn_dev, n_dev = jax.device_put((np.float32(self._dn_max()),
                                             np.int32(n)))
             fds = []
-            for _p in range(1, npasses):
-                floor_dev, fd = _mp_floor(ods[-1], qn_dev, dn_dev,
-                                          staging=self._staging, na=na,
-                                          precision=prec)
-                fds.append(fd)
-                od, oi, _its = _sweep_stack(q_dev, self._chunks, n_dev,
-                                            floor_dev, **sweep)
+            for p in range(2, npasses + 1):
+                with obs_span("serve.mp_pass", kc=kc, rows=n,
+                              **{"pass": p}, **targs):
+                    floor_dev, fd = _mp_floor(ods[-1], qn_dev, dn_dev,
+                                              **floor_args)
+                    fds.append(fd)
+                    od, oi, _its = _sweep_stack(q_dev, self._chunks,
+                                                n_dev, floor_dev, **sweep)
                 ods.append(od)
                 ois.append(oi)
-            fds.append(_mp_floor(ods[-1], qn_dev, dn_dev,
-                                 staging=self._staging, na=na,
-                                 precision=prec)[1])
-            top, valid = _mp_merge(jnp.concatenate(ods, axis=1),
-                                   jnp.concatenate(ois, axis=1),
-                                   self._d_labels, kcap=kcap)
-        self.last_mp_passes = len(ods)
+            with obs_span("serve.mp_merge", kcap=kcap,
+                          slots=npasses * kc, **targs):
+                fds.append(_mp_floor(ods[-1], qn_dev, dn_dev,
+                                     **floor_args)[1])
+                top, valid = _mp_merge(jnp.concatenate(ods, axis=1),
+                                       jnp.concatenate(ois, axis=1),
+                                       self._d_labels, kcap=kcap)
+            # One fence: fd chain (stall check) + final valid counts
+            # (shortfall check) — run()'s repair makes both exact.
+            with obs_span("serve.mp_fetch", **targs):
+                fetched = resilient_get([valid] + fds)
+            valid_h, fd_h = fetched[0], fetched[1:]
+            stalled = np.zeros(entry.qpad, bool)
+            for prev, cur in zip(fd_h, fd_h[1:]):
+                stalled |= np.isfinite(cur) & (cur <= prev)
+            stalled = stalled[:nq]
+            needed = np.minimum(inp.ks.astype(np.int64), n)
+            shortfall = np.asarray(valid_h)[:nq] < needed
+            self._mp_hazard = stalled | shortfall
+            counts = {"stalled": int(np.count_nonzero(stalled)),
+                      "shortfall": int(np.count_nonzero(shortfall))}
+            sp.set(flagged=int(np.count_nonzero(self._mp_hazard)),
+                   **counts)
+        self.last_mp_passes = npasses
+        self.last_kernel_calls = nchunks + npasses - 1
+        reg = telemetry.registry()
+        reg.counter("serve.multipass_batches").inc()
+        reg.counter("serve.multipass_passes").inc(npasses)
+        for label, count in counts.items():
+            if count:
+                reg.counter("serve.multipass_flagged").inc(count,
+                                                           label=label)
         # The multipass plan re-sweeps the whole resident corpus: a
         # dense scan by design, staged bytes counted once.
         dense = n * na * self._staging_itemsize()
         note_scan(self, scanned_bytes=dense, dense_bytes=dense,
                   blocks_total=self._ex_nchunks, blocks_pruned=0)
-        # One fence: fd chain (stall check) + final valid counts
-        # (shortfall check) — run()'s repair makes both exact.
-        fetched = resilient_get([valid] + fds)
-        valid_h, fd_h = fetched[0], fetched[1:]
-        stalled = np.zeros(entry.qpad, bool)
-        for prev, cur in zip(fd_h, fd_h[1:]):
-            stalled |= np.isfinite(cur) & (cur <= prev)
-        needed = np.minimum(inp.ks.astype(np.int64), n)
-        shortfall = np.asarray(valid_h)[:nq] < needed
-        self._mp_hazard = stalled[:nq] | shortfall
         return top, entry.qpad
 
     def _chunk_order(self) -> List[int]:
@@ -1178,6 +1217,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self._epilogue_pc = None
         self.last_extract_impl = self.last_variant = None
         self.last_prune = None
+        self.last_kernel_calls = 0
         if inp.params.num_data != self.n_real:
             raise ValueError(
                 f"resident solve got a foreign corpus "
@@ -1261,11 +1301,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                             if self._chunks is not None else 0),
             chunk_rows=self._ex_chunk_rows, chunk_attrs=self._ex_attrs,
             summary_blocks=(self._ex_nchunks
-                            if self._summ_dev is not None else 0))
+                            if self._summ_dev is not None else 0),
+            mp_slots=self._mp_passes(kcap) * self._MP_KC)
 
     def batch_model_bytes(self, nq: int, kmax: int) -> int:
         terms = self.mem_model(nq, kmax)["terms"]
-        return int(terms["query_blocks"] + terms["topk_carries"])
+        return int(terms["query_blocks"] + terms["topk_carries"]
+                   + terms.get("multipass_lists", 0))
 
     def resident_state_key(self):
         # The floor moves when the extract chunks stage (wide-k sweeps
@@ -1273,6 +1315,20 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         return (self._chunks is not None,)
 
     # -- introspection --------------------------------------------------------
+
+    @staticmethod
+    def _multipass_stats() -> Dict[str, int]:
+        """The always-on counts of the wide-k driver: micro-batches it
+        solved, kernel passes over the corpus they took, and queries it
+        flagged for the host repair, by cause."""
+        reg = telemetry.registry()
+        flagged = reg.counter("serve.multipass_flagged")
+        return {"batches": int(reg.counter(
+                    "serve.multipass_batches").total()),
+                "passes": int(reg.counter(
+                    "serve.multipass_passes").total()),
+                "flagged_stalled": int(flagged.value("stalled")),
+                "flagged_shortfall": int(flagged.value("shortfall"))}
 
     def bucket_stats(self) -> Dict[str, object]:
         # Snapshot the bucket table FIRST: handler threads call this
@@ -1306,6 +1362,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             # dispatches (capacity past the last row is staged too)
             "extract_chunks": -(-self.n_real // self._ex_chunk_rows)
             if self._chunks is not None else 0,
+            # kernel calls the LAST solve made: the chunks it folded
+            # (fewer than extract_chunks when some were pruned) and, on
+            # a multipass bucket, one whole-stack sweep a further pass
+            # (chunks + passes - 1)
+            "last_kernel_calls": self.last_kernel_calls,
+            "last_mp_passes": self.last_mp_passes,
+            "multipass": self._multipass_stats(),
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,
             "last_prune_fraction": self.last_prune_fraction,

@@ -135,6 +135,53 @@ def test_wide_row_fold_program_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < 1.1 * 51200 * a * 4
 
 
+def test_wide_k_programs_compile_for_v5e(v5e):
+    """The multipass driver's two kernel programs at
+    ``bigann-gt1000.bulk``'s shape (q1024, 82 resident chunks of
+    51 200 x 128 float32, k = 1000: bucket 1024, 1152 slots, 3 passes
+    at ``kc`` 512, tiles tile_q 64 / ne 4): pass 1 is the one-program
+    fold with 512-wide lists, every further pass ONE kernel call over
+    the stack as a (4 198 400, 128) array above a per-query floor. The
+    reshape is free inside the program: the sweep allocates no
+    temporary, so no second corpus."""
+    from dmlp_tpu.engine.single import resolve_kcap, resolve_sweep_kernel
+    from dmlp_tpu.serve.engine import (_fold_stack, _kernel_statics,
+                                       _sweep_stack, k_bucket)
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    kcap = resolve_kcap(EngineConfig(dtype="float32"), k_bucket(1000),
+                        "extract", 1 << 22, staging="float32", na=128)
+    assert (k_bucket(1000), kcap, -(-kcap // 512)) == (1024, 1152, 3)
+    rows = 82 * 51200
+    _kern, impl = resolve_sweep_kernel(1024, rows, 128, 512,
+                                       chunk_rows=51200, rung="fused",
+                                       precision="f32")
+    fold = _kernel_statics("fused", 512, 51200, 1024, 128, "f32", False)
+    sweep = _kernel_statics(impl, 512, rows, 1024, 128, "f32", False)
+    assert impl == "fused" and fold == sweep
+    assert (fold["tile_q"], fold["tile_n"], fold["ne"]) == (64, 12800, 4)
+    stack = spec((82, 51200, 128), jnp.float32)
+    folded = _fold_stack.lower(
+        spec((1024, 128), jnp.float32), stack, spec((82,), jnp.int32),
+        spec((), jnp.int32), spec((), jnp.int32), **fold).compile()
+    assert " while(" in folded.as_text()
+    swept = _sweep_stack.lower(
+        spec((1024, 128), jnp.float32), stack, spec((), jnp.int32),
+        spec((1024, 1), jnp.float32), **sweep).compile()
+    calls = [line.lstrip().removeprefix("ROOT ").split(" ", 1)[0]
+             for line in swept.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [c.rsplit(".", 1)[0] for c in calls] \
+        == ["%dmlp_topk_fused_fresh"], calls
+    for compiled in (folded, swept):
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes >= rows * 128 * 4
+        assert mem.temp_size_in_bytes < 2 * 51200 * 128 * 4
+
+
 def test_extract_kernel_compiles_for_v5e_at_2048_attributes(v5e):
     """The width rule's far end (ROADMAP R7): a 2048-attribute row tiles
     51 200 rows by 2 560, and Mosaic compiles the carried kernel."""
