@@ -20,9 +20,10 @@ Two output paths:
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import os
-from typing import List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -582,6 +583,27 @@ def _device_epilogue(top: TopK, ks, *, num_labels):
     valid = in_k & (top.ids >= 0)
     predicted = majority_vote(top.labels, valid, num_labels)
     return predicted, rids, rd
+
+
+@dataclasses.dataclass(eq=False)     # a record is itself, not its fields
+class PendingRun:
+    """One :meth:`SingleChipEngine.run` cut at its fence: what
+    ``_run_begin`` enqueued, for ``_run_finish`` to read back, test and
+    finalize. A batch solve makes one and finishes it at once; the
+    serving engine (serve.engine.ResidentEngine) keeps two alive, so
+    whatever the second half reads of "the solve in hand" lives here and
+    not on the engine."""
+
+    inp: KNNInput
+    # (TopK, qpad, query_idx | None, select, boundary columns | None), all
+    # still on the device
+    segments: List[Tuple] = dataclasses.field(default_factory=list)
+    prec: str = "f32"             # the first pass's precision
+    precision: Optional[Dict[str, Any]] = None   # -> last_precision
+    # the multipass driver's per-query loss flags (stall / shortfall)
+    mp_hazard: Optional[np.ndarray] = None
+    phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
+    repairs: int = 0              # queries the boundary repair recomputed
 
 
 class SingleChipEngine:
@@ -1334,11 +1356,28 @@ class SingleChipEngine:
             return rs_degrade.run_ladder(self, inp, self._run)
 
     def _run(self, inp: KNNInput) -> List[QueryResult]:
-        import time as _time
+        pend = PendingRun(inp)
+        self._run_begin(pend)
+        return self._run_finish(pend)
 
+    def _enqueue(self, pend: PendingRun) -> List[Tuple]:
+        """Seam: enqueue the solve of ``pend.inp`` and return its
+        segments. A batch solve keeps its own state on the engine (one
+        run is alive at a time) and hands the record what the second
+        half reads of it."""
+        segments = self._solve_segments(pend.inp)
+        pend.mp_hazard = self._mp_hazard
+        pend.phase_ms = self.last_phase_ms
+        return segments
+
+    def _run_begin(self, pend: PendingRun) -> None:
+        """The first half of a run: everything that only ENQUEUES (the
+        solve and the boundary columns the hazard test reads). Nothing
+        here waits for the device."""
+        inp = pend.inp
         n = inp.params.num_data
         memwatch.note_engine_model(self, inp)
-        segments = self._solve_segments(inp)
+        segments = self._enqueue(pend)
         # Watermark tick at peak residency: the solve is enqueued, the
         # staged chunks/carries are live, nothing is fetched yet (no-op
         # without a telemetry session).
@@ -1347,19 +1386,36 @@ class SingleChipEngine:
         # at, and how many window slots the bound inflation bought the
         # rescore (kcap minus what an f32-precision plan would have
         # sized — 0 whenever precision resolves to "f32").
-        prec = active_precision(self)
+        pend.prec = active_precision(self)
         kcap0 = int(segments[0][0].dists.shape[1])
         kmax0 = int(inp.ks.max()) if inp.params.num_queries else 0
-        self.last_precision = {
-            "active": prec,
+        pend.precision = {
+            "active": pend.prec,
             "configured": self.config.resolve_precision(),
             "kcap": kcap0,
             "kcap_inflation": kcap0 - resolve_kcap(
-                self.config, kmax0, self._last_select, kcap0,
+                self.config, kmax0, segments[0][3], kcap0,
                 staging=self._staging, precision="f32",
                 na=self._kcap_attrs),
         }
-        self.last_repairs = 0  # tie-overflow repair rate, for bench records
+        for top, qpad, idx, select in segments:
+            cols_dev = None
+            if select in ("sort", "topk", "seg", "extract") \
+                    and top.dists.shape[1] < n:
+                ks = inp.ks if idx is None else inp.ks[idx]
+                ks_pad = np.ones(qpad, np.int32)
+                ks_pad[:len(ks)] = ks
+                cols_dev = _boundary_cols(top.dists, jax.device_put(ks_pad))
+            pend.segments.append((top, qpad, idx, select, cols_dev))
+
+    def _run_finish(self, pend: PendingRun) -> List[QueryResult]:
+        """The second half: the fence (the result fetch), the hazard
+        test, the float64 finalize and the boundary repair."""
+        import time as _time
+
+        inp = pend.inp
+        n = inp.params.num_data
+        prec = pend.prec
         self.last_comms = []   # one chip: no collectives (obs.comms)
         merged: List[QueryResult] = [None] * inp.params.num_queries
         # Max squared data-row norm (f64): scales the staging-dtype
@@ -1370,19 +1426,13 @@ class SingleChipEngine:
 
         fetch_ms = hazard_ms = final_ms = 0.0
         targs = self._rid_args()
-        for top, qpad, idx, select in segments:
+        for top, qpad, idx, select, cols_dev in pend.segments:
             sub = inp if idx is None else subset_queries(inp, idx)
             nq = sub.params.num_queries
             kcap = top.dists.shape[1]
 
-            cols_dev = None
-            if select in ("sort", "topk", "seg", "extract") and kcap < n:
-                ks_pad = np.ones(qpad, np.int32)
-                ks_pad[:nq] = sub.ks
-                cols_dev = _boundary_cols(top.dists, jax.device_put(ks_pad))
-
             t0 = _time.perf_counter()
-            self._before_fetch(t0)
+            self._before_fetch(pend, t0)
             # NOTE: the "fetch" phase time includes the wait for all
             # enqueued device work (staging + solve), not just the readback
             # bytes — and past _CHUNK_WINDOW chunks the enqueue phase
@@ -1433,7 +1483,7 @@ class SingleChipEngine:
                 # Multi-pass extraction's own loss detectors (stall/
                 # shortfall, _solve_extract_multipass) join the standard
                 # boundary test.
-                mp = getattr(self, "_mp_hazard", None)
+                mp = pend.mp_hazard
                 if mp is not None and idx is None:
                     flags = mp if flags is None else (flags | mp)
                 labels = np.where(
@@ -1461,7 +1511,7 @@ class SingleChipEngine:
                                       queries=int(suspects.size), **targs):
                             repair_boundary_overflow(results, suspects,
                                                      sub)
-                        self.last_repairs += int(suspects.size)
+                        pend.repairs += int(suspects.size)
                         sp.set(repairs=int(suspects.size))
             if idx is None:
                 merged = results
@@ -1469,9 +1519,13 @@ class SingleChipEngine:
                 for local_i, orig in enumerate(idx):
                     merged[int(orig)] = results[local_i]
             final_ms += (_time.perf_counter() - t0) * 1e3
-        self.last_phase_ms["fetch"] = fetch_ms
-        self.last_phase_ms["hazard"] = hazard_ms
-        self.last_phase_ms["finalize"] = final_ms
+        pend.phase_ms.update(fetch=fetch_ms, hazard=hazard_ms,
+                             finalize=final_ms)
+        # What the engine reports of "the last run" is the last one
+        # FINISHED (a serving engine has begun another by now).
+        self.last_phase_ms = pend.phase_ms
+        self.last_precision = pend.precision
+        self.last_repairs = pend.repairs  # tie-overflow repair rate
         self._flush_measured_iters()
         return merged
 
@@ -1494,10 +1548,12 @@ class SingleChipEngine:
                                     inp.data_attrs).max()) if n else 0.0,
                     False)
 
-    def _before_fetch(self, t_pc: float) -> None:
-        """Seam: everything of the solve is enqueued and the readback
-        starts at ``t_pc`` (perf_counter). The serving engine closes its
-        ``serve.solve_epilogue`` span here; a batch solve has none."""
+    def _before_fetch(self, pend: PendingRun, t_pc: float) -> None:
+        """Seam: everything of ``pend``'s solve is enqueued and its
+        readback starts at ``t_pc`` (perf_counter). The serving engine
+        closes its ``serve.solve_epilogue`` span here and, on a
+        multipass bucket, makes the driver's own fence; a batch solve
+        has neither."""
 
     def run_device_full(self, inp: KNNInput) -> List[QueryResult]:
         """All-device pipeline (vote + report order on TPU); f32 ordering.

@@ -642,7 +642,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         block in the carried histogram."""
         with obs_span("fleet.after_batch", **self._rid_args()) as sp:
             flush_measured_iters(self)
-            self._flush_pending_gate(sp)
+            gate, self._pending_gate = self._pending_gate, None
+            self._flush_gate(sp, gate)
             if self.gate_carry and self._nchunks and results:
                 ids = np.concatenate(
                     [np.asarray(r.neighbor_ids, np.int64)
@@ -895,6 +896,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             "capacity_rows": self.capacity_rows,
             "gate_carry": self.gate_carry,
             "last_gated_fraction": self.last_gated_fraction,
+            "overlap": self._overlap_stats(),
             "extract_chunks": (self._nchunks if self._chunks is not None
                                else 0),
             "summary_blocks": (r * self._nchunks if self._summ else 0),

@@ -102,11 +102,39 @@ def _host_fallback(inp) -> List:
         return knn_golden_fast(inp)
 
 
-def run_ladder(engine, inp, solve: Callable):
+def steps_down(e: BaseException) -> bool:
+    """Whether the ladder answers ``e`` with a step down (an OOM-class
+    failure, with the ladder enabled) or lets it propagate."""
+    return resilience_enabled() and classify(e) == "oom"
+
+
+def note_step(rung: str, nxt: str, e: BaseException) -> None:
+    """Record one step down: the stats entry and the trace instant
+    (which also lands in the flight recorder when a telemetry session
+    is active: obs.trace's instant observer)."""
+    stats.record_degradation(rung, nxt)
+    from dmlp_tpu.obs import trace as obs_trace
+    obs_trace.instant("resilience.degrade", frm=rung, to=nxt,
+                      error=str(e)[:200])
+
+
+@contextlib.contextmanager
+def top_rung(engine):
+    """One HALF of a solve on the ladder's top rung: a caller that cuts
+    its solve in two (serve.engine.ResidentEngine) runs each half under
+    this and, where ``steps_down`` says so, re-runs the solve whole with
+    ``run_ladder(..., first=1)``."""
+    engine.last_degrade_rung = RUNGS[0]
+    with _rung_context(engine, RUNGS[0]):
+        yield
+
+
+def run_ladder(engine, inp, solve: Callable, first: int = 0):
     """Run ``solve(inp)`` (normally ``engine._run``), stepping down the
     ladder on each OOM-class failure; the last rung needs no device
     memory at all. Non-OOM errors propagate unchanged — the ladder
-    trades capacity, it does not paper over bugs.
+    trades capacity, it does not paper over bugs. ``first`` is the rung
+    to enter at (a caller whose top-rung attempt already failed).
 
     ``DMLP_TPU_RESILIENCE=0`` disables the LADDER (no step-downs), not
     the top rung's feature set: the solve still runs at RUNGS[0], so
@@ -116,11 +144,10 @@ def run_ladder(engine, inp, solve: Callable):
     A/B's resilience-off arm must differ from the on arm by the
     wrappers only."""
     if not resilience_enabled():
-        engine.last_degrade_rung = RUNGS[0]
-        with _rung_context(engine, RUNGS[0]):
+        with top_rung(engine):
             return solve(inp)
-    engine.last_degrade_rung = RUNGS[0]
-    for i, rung in enumerate(RUNGS):
+    for i in range(first, len(RUNGS)):
+        rung = RUNGS[i]
         try:
             engine.last_degrade_rung = rung
             if rung == "host":
@@ -130,11 +157,5 @@ def run_ladder(engine, inp, solve: Callable):
         except Exception as e:
             if classify(e) != "oom" or i + 1 >= len(RUNGS):
                 raise
-            nxt = RUNGS[i + 1]
-            stats.record_degradation(rung, nxt)
-            from dmlp_tpu.obs import trace as obs_trace
-            # The instant also lands in the flight recorder when a
-            # telemetry session is active (obs.trace instant observer).
-            obs_trace.instant("resilience.degrade", frm=rung, to=nxt,
-                              error=str(e)[:200])
+            note_step(rung, RUNGS[i + 1], e)
     raise AssertionError("unreachable: the host rung returns or raises")

@@ -82,14 +82,18 @@ class AdmissionController:
     # -- the memory model ------------------------------------------------------
 
     def batch_bytes(self, nq: int, kmax: int) -> int:
-        """Marginal resident bytes a micro-batch of this shape bucket
-        adds on top of the resident corpus — every resident engine
+        """Marginal resident bytes micro-batches of this shape bucket
+        add on top of the resident corpus — every resident engine
         prices its own per-bucket terms at its own ``bucket_plan``
         (ResidentServingCore.batch_model_bytes: the one kcap
         derivation, so pricing cannot drift from what the solve
         allocates; term names differ between the single-chip and mesh
-        models, which is why the engine owns the sum)."""
-        return int(self.engine.batch_model_bytes(nq, kmax))
+        models, which is why the engine owns the sum), once for each
+        batch the engine keeps on the device at a time
+        (``batches_resident``: the one it reads back and the one begun
+        behind it)."""
+        return int(self.engine.batches_resident
+                   * self.engine.batch_model_bytes(nq, kmax))
 
     def _resident_model_bytes(self) -> int:
         """The corpus-only model total, cached — it only moves when

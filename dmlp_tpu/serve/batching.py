@@ -8,6 +8,17 @@ buckets, and per-request results slice back out bit-identically (the
 solo solve over the same corpus produces the same bytes; both equal
 the golden oracle by the finalize/repair contract).
 
+A micro-batch is solved in two halves (``engine.begin_batch``: what
+only enqueues device work; ``engine.finish_batch``: the fence and the
+host's float64 finalize), and the batcher runs the first half of batch
+N + 1 BEFORE the second half of batch N whenever a batch's worth of
+queries is already waiting: the device folds N + 1 while the host
+finalizes N, and the host finalizes while the device folds. At most
+two batches are alive; a light load (less than a batch queued while
+one is in flight) runs them one after the other, as a serial batcher
+would, so small requests keep collecting company until their fold can
+start.
+
 Single consumer thread: the engine (and its ingest path) is driven by
 exactly one thread, so resident-buffer updates never race a solve.
 Requests complete through a per-request event; connection handlers
@@ -39,9 +50,10 @@ TICK_S = 0.002
 class Request:
     """One admitted unit of work. ``kind`` is "query" | "ingest" |
     "corpus"; non-query requests execute standalone between
-    micro-batches (the one batcher thread serializes them against
-    solves — a ``corpus`` read can therefore never observe a torn
-    ingest)."""
+    micro-batches, with none in flight (the one batcher thread
+    serializes them against solves — a ``corpus`` read can therefore
+    never observe a torn ingest, and both halves of a micro-batch see
+    the corpus its requests were admitted against)."""
 
     kind: str
     req_id: str = ""
@@ -88,8 +100,27 @@ class Request:
         self.done.set()
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A micro-batch between its two halves: begun (its device work is
+    enqueued), not finished."""
+
+    requests: List[Request]
+    total: int           # queries
+    qpad: int
+    wake_pc: float       # perf_counter: the consumer took it off the queue
+    t0: float            # perf_counter: its first half started
+    #: the engine's record of it (begin_batch's return): ``batch`` is the
+    #: serial every span of the batch carries, ``rids`` its trace rids
+    pending: Any
+
+
 class MicroBatcher:
-    """The admission queue + the one batch-execution thread."""
+    """The admission queue + the one batch-execution thread, which
+    keeps up to two micro-batches in the engine: it begins the next one
+    (when the queue already holds a batch's worth of queries) before it
+    finishes and delivers the one in flight. ``ingest`` and ``corpus``
+    requests run with nothing in flight."""
 
     def __init__(self, engine: ResidentEngine,
                  admission: AdmissionController,
@@ -106,13 +137,9 @@ class MicroBatcher:
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self.batches = 0
-        # Serial of the micro-batch in hand: every span of one batch
-        # carries it as ``batch``. Consumer-thread-private.
+        # Serial of the last micro-batch begun: every span of one batch
+        # carries its own as ``batch``. Consumer-thread-private.
         self._serial = 0
-        # perf_counter at which the consumer woke for the current
-        # collect cycle — the queue-wait / coalesce-wait boundary for
-        # phase spans. Consumer-thread-private.
-        self._wake_pc = 0.0
 
     # -- producer side ---------------------------------------------------------
 
@@ -188,7 +215,9 @@ class MicroBatcher:
     def stop(self, drain: bool = True, timeout: float = 60.0) -> None:
         """Stop the batcher thread. ``drain=True`` finishes everything
         already queued first (the SIGTERM path); ``drain=False`` fails
-        queued requests with a shutdown error."""
+        queued requests with a shutdown error. A micro-batch in flight
+        is finished and delivered either way: its device work is
+        already enqueued."""
         with self._cond:
             self._stop = True
             if not drain:
@@ -207,17 +236,28 @@ class MicroBatcher:
         if t is not None:
             t.join(timeout=timeout)
 
-    def _collect(self) -> List[Request]:
-        """Block for work, then drain the queue up to the batch cap —
-        the 'coalesce whatever is queued each tick' core. A lone
-        request waits one tick for company before solving solo."""
+    def _collect(self, block: bool) -> Tuple[List[Request], float]:
+        """Drain the queue up to the batch cap — the 'coalesce whatever
+        is queued each tick' core — and say when (perf_counter: the
+        queue-wait / coalesce-wait boundary of the phase spans).
+        ``block``: wait for work, and let a lone request wait one tick
+        for company before solving solo. With a batch in flight the
+        caller does not block, and takes query requests only if what is
+        queued fills a batch: requests that could still take company
+        (the test the tick makes) wait for it through the finish of the
+        batch in flight, as they would have waited through its whole
+        solve; taken now they would be committed to a batch a device
+        pass before their fold can start. An empty return sends the
+        caller to finish that batch."""
         with self._cond:
-            while not self._queue and not self._stop:
+            while block and not self._queue and not self._stop:
                 self._cond.wait(timeout=0.1)
-            if not self._queue:
-                return []
-            self._wake_pc = time.perf_counter()
-            if not self._stop and self.tick_s > 0 \
+            if not self._queue or (
+                    not block and self._queue[0].kind == "query"
+                    and self._queued_queries < self.max_batch_queries):
+                return [], 0.0
+            wake_pc = time.perf_counter()
+            if block and not self._stop and self.tick_s > 0 \
                     and self._queued_queries < self.max_batch_queries:
                 self._cond.wait(timeout=self.tick_s)
             batch: List[Request] = []
@@ -228,7 +268,7 @@ class MicroBatcher:
                     if batch:
                         break          # solve what we have first
                     self._queue.popleft()
-                    return [head]      # ingest/corpus execute standalone
+                    return [head], wake_pc   # ingest/corpus: standalone
                 if batch and total + head.nq > self.max_batch_queries:
                     break
                 self._queue.popleft()
@@ -240,22 +280,34 @@ class MicroBatcher:
                 #                         nothing queued remains
             telemetry.registry().gauge("serve.queue_depth").set(
                 self._queued_queries)
-            return batch
+            return batch, wake_pc
 
     def _run_loop(self) -> None:
+        flight: Optional[_Flight] = None
         while True:
-            batch = self._collect()
+            batch, wake_pc = self._collect(block=flight is None)
+            if batch and batch[0].kind == "query":
+                # Batch N + 1's fold goes onto the device's queue BEFORE
+                # batch N is read back and finalized.
+                begun = self._begin(batch, wake_pc)
+                if flight is not None:
+                    self._finish(flight)
+                flight = begun
+                continue
+            if flight is not None:
+                # Nothing to begin behind it (the queue is empty or
+                # holds less than a batch, or an ingest / corpus request
+                # heads it and must see no batch in flight).
+                self._finish(flight)
+                flight = None
             if not batch:
                 with self._cond:
                     if self._stop and not self._queue:
                         return
-                continue
-            if batch[0].kind == "ingest":
+            elif batch[0].kind == "ingest":
                 self._execute_ingest(batch[0])
-            elif batch[0].kind == "corpus":
-                self._execute_corpus(batch[0])
             else:
-                self._execute_batch(batch)
+                self._execute_corpus(batch[0])
 
     def _phase(self, name: str, t0: float, t1: float, rid: str,
                **args) -> None:
@@ -270,14 +322,11 @@ class MicroBatcher:
     def _execute_ingest(self, req: Request) -> None:
         e0 = 0.0
         if obs_trace.sinks_active():
+            # Queued until it runs: the batch that was in flight when
+            # it reached the head of the queue is finished first.
             e0 = time.perf_counter()
-            # check: allow-concurrency=R702 — _wake_pc is written in
-            # _collect and read here, both only on the batcher thread
-            # (_run is the sole caller of either); the write holds
-            # _cond only because _collect already does.
-            self._phase("serve.phase.queue", req.t_enqueue_pc,
-                        max(req.t_enqueue_pc, self._wake_pc), req.rid,
-                        kind="ingest")
+            self._phase("serve.phase.queue", req.t_enqueue_pc, e0,
+                        req.rid, kind="ingest")
         try:
             # The fleet chaos harness's dropped-ingest site: a
             # transient fault here fails THIS replica's ingest before
@@ -301,11 +350,8 @@ class MicroBatcher:
         e0 = 0.0
         if obs_trace.sinks_active():
             e0 = time.perf_counter()
-            # check: allow-concurrency=R702 — batcher-thread-only read
-            # (see _execute_ingest).
-            self._phase("serve.phase.queue", req.t_enqueue_pc,
-                        max(req.t_enqueue_pc, self._wake_pc), req.rid,
-                        kind="corpus")
+            self._phase("serve.phase.queue", req.t_enqueue_pc, e0,
+                        req.rid, kind="corpus")
         try:
             state = self.engine.corpus_state()
             labels, attrs = self.engine.corpus_slice(req.start or 0,
@@ -325,10 +371,19 @@ class MicroBatcher:
             self._phase("serve.phase.corpus", e0, time.perf_counter(),
                         req.rid, ok=req.error is None)
 
-    def _execute_batch(self, batch: List[Request]) -> None:
-        reg = telemetry.registry()
-        # check: allow-concurrency=R702 — _serial is read and written
-        # only here, on the batcher thread.
+    @staticmethod
+    def _fail(batch: List[Request], e: Exception) -> None:
+        """A batch fails visibly, alone; the daemon survives."""
+        telemetry.registry().counter("serve.batch_errors").inc()
+        msg = f"{type(e).__name__}: {e}"
+        for r in batch:
+            r.complete(error=msg)
+
+    def _begin(self, batch: List[Request],
+               wake_pc: float) -> Optional[_Flight]:
+        """Assemble a micro-batch and run its first half: its device
+        work is enqueued when this returns (None: it failed, and its
+        requests are answered with the error)."""
         self._serial += 1
         serial = self._serial
         total = sum(r.nq for r in batch)
@@ -350,33 +405,37 @@ class MicroBatcher:
             # fails the whole batch visibly (serve.batch_errors).
             rs_inject.fire("serve.solve", requests=len(batch),
                            queries=total)
-            with obs_span("serve.micro_batch", requests=len(batch),
-                          queries=total, qpad=qpad, batch=serial,
-                          **({"rids": rids} if rids else {})):
-                # Single consumer thread: the engine reads these inside
-                # solve_batch to tag its internal spans.
-                self.engine.trace_batch = serial
-                if rids:
-                    self.engine.trace_rids = rids
-                try:
-                    results = self.engine.solve_batch(q, ks)
-                finally:
-                    self.engine.trace_batch = None
-                    if rids:
-                        self.engine.trace_rids = None
-        except Exception as e:  # check: no-retry — batch fails visibly,
-            reg.counter("serve.batch_errors").inc()  # daemon survives
-            msg = f"{type(e).__name__}: {e}"
-            for r in batch:
-                r.complete(error=msg)
-            return
-        t1 = time.perf_counter()
-        with obs_span("serve.batch_deliver", batch=serial,
-                      requests=len(batch), queries=total):
-            self._deliver(batch, results, serial, t0, t1, total, qpad)
+            pending = self.engine.begin_batch(q, ks, batch=serial,
+                                              rids=rids or None)
+        except Exception as e:  # check: no-retry — batch fails visibly
+            self._fail(batch, e)
+            return None
+        return _Flight(batch, total, qpad, wake_pc, t0, pending)
 
-    def _deliver(self, batch: List[Request], results: List, serial: int,
-                 t0: float, t1: float, total: int, qpad: int) -> None:
+    def _finish(self, f: _Flight) -> None:
+        """The second half of a micro-batch, and its delivery.
+        ``serve.micro_batch`` runs from the start of its first half to
+        the end of its second: two of them overlap in time when the
+        batch was begun behind another (``overlapped``)."""
+        results, error = None, None
+        try:
+            results = self.engine.finish_batch(f.pending)
+        except Exception as e:  # check: no-retry — batch fails visibly
+            error = e
+        t1 = time.perf_counter()
+        obs_trace.complete_at(
+            "serve.micro_batch", f.t0, t1, requests=len(f.requests),
+            queries=f.total, qpad=f.qpad, batch=f.pending.batch,
+            overlapped=int(f.pending.overlapped),
+            **({"rids": f.pending.rids} if f.pending.rids else {}))
+        if error is not None:
+            self._fail(f.requests, error)
+            return
+        with obs_span("serve.batch_deliver", batch=f.pending.batch,
+                      requests=len(f.requests), queries=f.total):
+            self._deliver(f, results, t1)
+
+    def _deliver(self, f: _Flight, results: List, t1: float) -> None:
         """A solved micro-batch back to its requests: the batch's
         counters and always-on timings, then per request the slice of
         results, the completion and its phase decomposition — each
@@ -384,17 +443,22 @@ class MicroBatcher:
         daemon; ``stats`` reports them as ``phases_ms``) and, while a
         sink is installed, the ``serve.phase.*`` span."""
         reg = telemetry.registry()
+        batch, serial, t0 = f.requests, f.pending.batch, f.t0
         with self._cond:
             # handler threads read `batches` through daemon.stats()
             # while this consumer increments it — guard the write so
             # the field has one discipline (reads are single int loads)
             self.batches += 1
         reg.counter("serve.batches").inc()
-        reg.histogram("serve.batch_queries").observe(total)
-        # The engine's own parts of the batch (engine.last_phase_ms: the
-        # dispatch loop, a mesh engine's cross-shard merge, the
-        # readback, the hazard pass, the float64 finalize), whichever
-        # this engine reports.
+        # The pipeline's own count: batches begun while another was in
+        # flight (stats.engine.overlap).
+        reg.counter("serve.batches_overlapped").inc(
+            int(f.pending.overlapped))
+        reg.histogram("serve.batch_queries").observe(f.total)
+        # The engine's own parts of the batch it finished last
+        # (engine.last_phase_ms: the dispatch, a mesh engine's
+        # cross-shard merge, the readback, the hazard pass, the float64
+        # finalize), whichever this engine reports.
         parts = getattr(self.engine, "last_phase_ms", None) or {}
         for part, hist in (
                 ("dispatch",
@@ -427,16 +491,16 @@ class MicroBatcher:
                 (time.monotonic() - r.t_enqueue) * 1e3,
                 exemplar=r.rid or None)
             # Per-request phase decomposition. queue ends when the
-            # consumer woke (clamped: a request that arrived during
-            # the coalesce tick has zero queue wait); coalesce runs
-            # to solve start; the full batch solve interval is
-            # attributed to EVERY coalesced request (documented
-            # overlap — the phases of one rid tile its wall time,
-            # they do not sum across rids); finalize is this
+            # consumer took the batch off the queue (clamped: a request
+            # that arrived during the coalesce tick has zero queue
+            # wait); coalesce runs to solve start; the full batch solve
+            # interval — from the start of its first half to the end of
+            # its second, the other batch's second half in between
+            # included — is attributed to EVERY coalesced request
+            # (documented overlap — the phases of one rid tile its wall
+            # time, they do not sum across rids); finalize is this
             # request's delivery, from the solve's end to here.
-            # check: allow-concurrency=R702 — batcher-thread-only
-            # read (see _execute_ingest).
-            q1 = min(max(self._wake_pc, r.t_enqueue_pc), t0)
+            q1 = min(max(f.wake_pc, r.t_enqueue_pc), t0)
             t2 = time.perf_counter()
             h_queue.observe((q1 - r.t_enqueue_pc) * 1e3)
             h_coalesce.observe((t0 - q1) * 1e3)
@@ -447,6 +511,6 @@ class MicroBatcher:
             self._phase("serve.phase.coalesce", q1, t0, r.rid,
                         requests=len(batch), batch=serial)
             self._phase("serve.phase.solve", t0, t1, r.rid,
-                        queries=total, qpad=qpad, batch=serial)
+                        queries=f.total, qpad=f.qpad, batch=serial)
             self._phase("serve.phase.finalize", t1, t2, r.rid,
                         batch=serial)

@@ -49,6 +49,15 @@ inherited unchanged:
   the fold is sound in any order, and the boundary-hazard repair makes
   ties at the candidate boundary exact either way — carry on and off
   are byte-identical by construction (and proven in the A/B).
+- **A micro-batch in two halves.** ``begin_batch`` stages, prunes and
+  dispatches (everything that only ENQUEUES); ``finish_batch`` makes
+  the fence and does the host's share (hazard test, float64 finalize,
+  repair, gate bookkeeping). The batcher begins batch N + 1 before it
+  finishes batch N (when a batch's worth of queries is already queued),
+  so the chip folds while the host finalizes; what a
+  solve says of itself lives in its :class:`PendingBatch` until it
+  finishes. ``solve_batch`` alone is both halves back to back: same
+  answers, byte for byte.
 - **Wide-k multipass buckets.** A k-bucket whose candidate width
   exceeds the extraction kernel's single-pass window routes through
   the batch engine's multi-pass extraction driver AGAINST THE RESIDENT
@@ -62,6 +71,8 @@ inherited unchanged:
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -71,8 +82,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dmlp_tpu.config import EngineConfig
-from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, SingleChipEngine,
-                                    _extract_finalize,
+from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, PendingRun,
+                                    SingleChipEngine, _extract_finalize,
                                     _topk_blocks, active_precision,
                                     fit_blocks, np_staging_dtype,
                                     plan_chunks, resilient_get, resolve_kcap,
@@ -215,6 +226,71 @@ def _sweep_stack(q, stack, n_real, floor, **kern):
                         n_real=n_real, id_base=0, floor=floor, **kern)
 
 
+def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, int]:
+    """What ``serve.solve_extract`` and ``serve.warmup_bucket`` say of a
+    solve's kernel variant ``v`` (``last_variant``'s form): its tiles
+    and, where the engine pads it, the width a staged row holds."""
+    from dmlp_tpu.ops.pallas_extract import _TN
+    if not v:
+        return {}
+    return {"tile_n": _TN, **{k: v[k] for k in (
+        "tile_q", "tile_n", "ne", "a_pad") if k in v}}
+
+
+@dataclasses.dataclass(eq=False)
+class HeldBatch:
+    """What :meth:`ResidentServingCore.begin_batch` keeps of a
+    micro-batch whose engine solves it whole in ``finish_batch``."""
+
+    query_attrs: np.ndarray
+    ks: np.ndarray
+    batch: Optional[int] = None     # the batcher's serial: on every span
+    rids: Optional[str] = None      # comma-joined trace request ids
+    #: begun while another batch was in flight (never: nothing is begun)
+    overlapped: bool = False
+
+
+@dataclasses.dataclass(eq=False)
+class PendingBatch(PendingRun):
+    """One micro-batch of a :class:`ResidentEngine` between
+    ``begin_batch`` and ``finish_batch``: its work is on the device's
+    queue, nothing of it has been read back. Two are alive at once, so
+    everything a solve says of itself is written here and reaches the
+    engine's ``last_*`` fields when the batch finishes."""
+
+    batch: Optional[int] = None     # the batcher's serial: on every span
+    rids: Optional[str] = None      # comma-joined trace request ids
+    overlapped: bool = False        # begun while another was in flight
+    # -> _last_select, last_extract_impl, last_variant, last_kernel_calls,
+    # last_mp_passes, last_prune (ops.summaries.note_scan writes it here)
+    select: Optional[str] = None
+    extract_impl: Optional[str] = None
+    variant: Optional[Dict[str, Any]] = None
+    kernel_calls: int = 0
+    mp_passes: int = 0
+    last_prune: Optional[Dict[str, Any]] = None
+    # (gated-tile count still on the device, tiles the fold visited)
+    gate: Optional[Tuple] = None
+    # perf_counter at which the extract path's fold was dispatched (the
+    # start of the serve.solve_epilogue span)
+    epilogue_pc: Optional[float] = None
+    # the multipass driver's fence, not made yet: (perf_counter its
+    # enqueues began at, [valid counts] + the floor chain, span args)
+    mp_fence: Optional[Tuple] = None
+    # an OOM-class failure of either half: the batch re-runs whole
+    oom: Optional[BaseException] = None
+    # (results, None) | (None, exception) once the second half has run
+    outcome: Optional[Tuple] = None
+
+    @property
+    def query_attrs(self) -> np.ndarray:
+        return self.inp.query_attrs
+
+    @property
+    def ks(self) -> np.ndarray:
+        return self.inp.ks
+
+
 class _Bucket:
     """One (qpad, k-bucket) shape bucket: the resolved candidate width,
     the chosen path, and the AOT-compiled streaming program."""
@@ -246,6 +322,13 @@ class ResidentServingCore:
     obs.memwatch read. Single-sourced here so a fix to any of them
     cannot silently miss the other engine.
 
+    The batcher drives an engine through :meth:`begin_batch` and
+    :meth:`finish_batch` and may have begun one micro-batch behind the
+    one it finishes next. The pair here keeps the inputs and solves
+    whole in the second half (one batch in the engine at a time, as the
+    mesh engine needs); :class:`ResidentEngine` cuts its solve at the
+    fence and keeps two alive.
+
     Subclass contract: ``bucket_shape``/``_build_bucket``/``max_k``/
     ``solve_batch`` plus the resident state the hooks read; the
     subclass implements :meth:`mem_model` (its analytic per-device
@@ -255,37 +338,74 @@ class ResidentServingCore:
     state in :meth:`resident_state_key`.
     """
 
-    #: comma-joined rids of the micro-batch currently in solve_batch —
-    #: set/cleared by the batcher (single consumer thread, plain
-    #: attribute) so solve-internal spans can self-tag; None (the
-    #: class default) whenever untraced.
+    #: serial and comma-joined rids of the micro-batch whose half is
+    #: running NOW: installed by the engine for the length of a half
+    #: (one thread runs one half at a time), so that every span inside
+    #: tags itself with its own batch. None outside the batcher
+    #: (warm-up) and whenever untraced.
+    trace_batch: Optional[int] = None
     trace_rids: Optional[str] = None
 
-    #: serial of the micro-batch currently in solve_batch — set/cleared
-    #: by the batcher like ``trace_rids``; every span of one batch
-    #: carries it as ``batch``. None outside the batcher (warm-up).
-    trace_batch: Optional[int] = None
+    #: micro-batches whose lists can be on the device at once: what
+    #: admission multiplies one batch's price by
+    batches_resident = 1
+
+    #: the begun micro-batch finish_batch is handing to solve_batch
+    _handed = None
+
+    # -- a micro-batch in two halves (the batcher drives these) --------------
+
+    def begin_batch(self, query_attrs, ks, batch: Optional[int] = None,
+                    rids: Optional[str] = None):
+        """First half of a micro-batch: returns the record
+        :meth:`finish_batch` takes. The default pair keeps the inputs
+        and solves whole in the second half, so an engine that cannot
+        cut its solve (the mesh engine) runs through the same batcher,
+        one batch at a time."""
+        return HeldBatch(query_attrs, ks, batch, rids)
+
+    def finish_batch(self, pending) -> List[QueryResult]:
+        """Second half: the batch's results, or what it failed with.
+        It goes through :meth:`solve_batch`, the one call every served
+        answer comes out of (whoever wraps that call sees every
+        micro-batch); an engine whose ``begin_batch`` put work on the
+        device finds its record there, in ``_handed``."""
+        self._handed = pending
+        try:
+            with self._tagged(pending):
+                return self.solve_batch(pending.query_attrs, pending.ks)
+        finally:
+            self._handed = None
+
+    @contextlib.contextmanager
+    def _tagged(self, pending):
+        """The spans of one half carry the half's own batch."""
+        prev = self.trace_batch, self.trace_rids
+        self.trace_batch, self.trace_rids = pending.batch, pending.rids
+        try:
+            yield
+        finally:
+            self.trace_batch, self.trace_rids = prev
+
+    @staticmethod
+    def _overlap_stats() -> Dict[str, int]:
+        """Always-on counts of the batcher's pipeline: micro-batches
+        delivered, and those begun while another was in flight."""
+        reg = telemetry.registry()
+        return {"batches": int(reg.counter("serve.batches").total()),
+                "overlapped": int(reg.counter(
+                    "serve.batches_overlapped").total())}
 
     def _rid_args(self) -> Dict[str, Any]:
-        """Span-args rider carrying the current micro-batch's serial and
-        its rids — empty (and allocation-only) outside the batcher."""
+        """Span-args rider carrying the serial and the rids of the
+        micro-batch whose half is running — empty (and allocation-only)
+        outside the batcher."""
         out: Dict[str, Any] = {}
         if self.trace_batch is not None:
             out["batch"] = self.trace_batch
         if self.trace_rids:
             out["rids"] = self.trace_rids
         return out
-
-    def _variant_args(self) -> Dict[str, int]:
-        """What ``serve.solve_extract`` and ``serve.warmup_bucket`` say
-        of the kernel variant the last solve resolved: its tiles and,
-        where the engine pads it, the width a staged row holds."""
-        from dmlp_tpu.ops.pallas_extract import _TN
-        v = getattr(self, "last_variant", None)
-        if not v:
-            return {}
-        return {"tile_n": _TN, **{k: v[k] for k in (
-            "tile_q", "tile_n", "ne", "a_pad") if k in v}}
 
     def _bucket_entry(self, nq: int, kmax: int):
         """The bucket for (nq, kmax), building (and counting) it on
@@ -332,7 +452,8 @@ class ResidentServingCore:
             with obs_span("serve.warmup_bucket", qpad=key[0],
                           kb=key[1]) as sp:
                 self.solve_batch(q, ks)
-                sp.set(**self._variant_args())
+                sp.set(**_variant_args(
+                    getattr(self, "last_variant", None)))
             per[f"q{key[0]}k{key[1]}"] = round(
                 (time.perf_counter() - tb) * 1e3, 3)
         self.cold_start_compile_ms = round(
@@ -422,14 +543,14 @@ class ResidentServingCore:
 
     # -- gate effectiveness (the fused kernel's gated-tile count) ------------
 
-    def _flush_pending_gate(self, sp) -> None:
-        """Read back the batch's pending gated-tile count (a scalar, or
-        a mesh engine's one count a cell, summed here: a host sync,
-        after the result fetch) into the gate gauges and the span."""
-        if self._pending_gate is None:
+    def _flush_gate(self, sp, gate: Optional[Tuple]) -> None:
+        """Read back a batch's gated-tile count (``gate``: a scalar, or
+        a mesh engine's one count a cell, summed here, with the tiles
+        its fold visited: a host sync, after the result fetch) into the
+        gate gauges and the span."""
+        if gate is None:
             return
-        gz, ntiles = self._pending_gate
-        self._pending_gate = None
+        gz, ntiles = gate
         try:
             got = jax.device_get(gz)  # check: allow-host-sync
             gated = int(np.sum(got))
@@ -540,7 +661,17 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     ignored here — the daemon uses it to seed warm-up). ``capacity``
     is the ingest ceiling in rows (default: the corpus row count's
     power-of-two bucket, i.e. free headroom to the next boundary).
+
+    A micro-batch is solved in two halves (:meth:`begin_batch`: what
+    only enqueues; :meth:`finish_batch`: the fence and the host's
+    float64 work) so that the batcher can put batch N + 1 on the
+    device's queue before it reads batch N back: two batches are alive
+    at once, each in its :class:`PendingBatch`, and the engine's own
+    fields hold what outlives a batch (the corpus, the buckets, the
+    gate histogram, the ``last_*`` report of the last batch finished).
     """
+
+    batches_resident = 2
 
     def __init__(self, corpus: KNNInput, config: EngineConfig = None,
                  capacity: Optional[int] = None, gate_carry: bool = True):
@@ -628,13 +759,12 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.compile_count = 0
         self.cold_start_compile_ms: Optional[float] = None
         self.bucket_compile_ms: Dict[str, float] = {}
-        # perf_counter at which the extract path's fold was dispatched
-        # (the start of the serve.solve_epilogue span); None otherwise.
-        self._epilogue_pc: Optional[float] = None
-        # Cross-request gate state: per-chunk winner histogram + last
-        # batch's gated-tile stats (pending device scalar, tile count).
+        # Micro-batches begun and not finished, oldest first (at most
+        # two: the batcher begins one behind the one it finishes next).
+        # The batcher thread's alone, like everything a solve touches.
+        self._in_flight: List[PendingBatch] = []
+        # Cross-request gate state: per-chunk winner histogram.
         self._block_hits = np.zeros(max(self._ex_nchunks, 1), np.int64)
-        self._pending_gate: Optional[Tuple] = None
         # kernel calls the last solve's programs made: the chunks an
         # extract bucket folded; a multipass bucket's folds of pass 1
         # and one whole-stack sweep a further pass
@@ -919,7 +1049,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             np.asarray(ks, np.int32),
             np.asarray(query_attrs, np.float64))
 
-    def _solve_resident_stream(self, inp: KNNInput,
+    def _solve_resident_stream(self, pend: PendingBatch,
                                entry: _Bucket) -> Tuple[TopK, int]:
         if entry.stream is None:
             # An extract-path bucket degraded to streaming: build the
@@ -929,23 +1059,25 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             self.compile_count += 1
             self.bucket_compile_ms[entry.key + "_stream_fallback"] = \
                 round((time.perf_counter() - t0) * 1e3, 3)
+        inp = pend.inp
         nq = inp.params.num_queries
         na = self.num_attrs
         q = np.zeros((entry.qpad, na), np.float32)
         q[:nq] = inp.query_attrs
         q_blocks = stage_put(q.reshape(entry.nqb, entry.qb, na),
                              self._staging)
-        self._last_select = self._stream_select
+        pend.select = self._stream_select
+        # The enqueue alone, as serve.solve_extract is: the program's
+        # device time shows where the host first blocks, in single.fetch.
         with obs_span("serve.solve_stream", qpad=entry.qpad,
-                      kcap=entry.kcap, **self._rid_args()) as sp:
+                      kcap=entry.kcap, **self._rid_args()):
             out: TopK = entry.stream(self._d_attrs, self._d_labels,
                                      self._d_ids, q_blocks)
-            sp.fence(out.dists)
         # The AOT streaming program scans the whole resident buffer by
         # construction (static shapes): a dense scan, recorded as such.
         from dmlp_tpu.ops.summaries import note_scan
         dense = self.n_real * na * self._staging_itemsize()
-        note_scan(self, scanned_bytes=dense, dense_bytes=dense,
+        note_scan(pend, scanned_bytes=dense, dense_bytes=dense,
                   blocks_total=1, blocks_pruned=0)
         return TopK(out.dists.reshape(entry.qpad, -1),
                     out.labels.reshape(entry.qpad, -1),
@@ -956,7 +1088,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         device (ops.summaries.score_blocks — compiled once per bucket
         shape) and read back the tiny (blocks,) survivor mask. Active
         on the ladder's top ``prune`` rung in exact mode only; returns
-        (mask, stats) or (None, None) for a dense fold."""
+        (mask, stats) or (None, None) for a dense fold. The one place
+        ``begin_batch`` waits for the device: the scorer queues behind
+        the fold of the batch in flight."""
         from dmlp_tpu.ops import summaries as osum
         if (self._summ_dev is None
                 or self._degrade_rung not in ("lowp", "prune")
@@ -1008,10 +1142,11 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                              np.int32(self.n_real))), **kern)
         return od, oi, gated, len(order) * fold_tiles(kern, qpad, cr)
 
-    def _solve_resident_extract(self, inp: KNNInput, entry: _Bucket
+    def _solve_resident_extract(self, pend: PendingBatch, entry: _Bucket
                                 ) -> Optional[Tuple[TopK, int]]:
         from dmlp_tpu.ops import pallas_fused
         from dmlp_tpu.ops.summaries import note_scan
+        inp = pend.inp
         na = self.num_attrs
         cr = self._ex_chunk_rows
         with obs_span("serve.solve_stage", qpad=entry.qpad,
@@ -1035,52 +1170,56 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                 # Cannot happen with a sound mask (score_blocks keeps
                 # >= 1 block): fall back to a dense fold.
                 return None
-            self._last_select = "extract"
-            self.last_extract_impl = impl
-            self.last_variant = self._variant_stamp(
+            pend.select = "extract"
+            pend.extract_impl = impl
+            pend.variant = self._variant_stamp(
                 impl, entry.kcap, entry.qpad, prec)
         clock = time.perf_counter
         with obs_span("serve.solve_extract", qpad=entry.qpad,
                       kcap=entry.kcap, impl=impl,
                       carry=self.gate_carry, scheduled=len(order),
-                      **self._variant_args(), **self._rid_args()) as sp:
+                      **_variant_args(pend.variant),
+                      **self._rid_args()) as sp:
             t0 = clock()
             od, oi, gated, ntiles = self._fold_resident(
                 q_dev, order, impl, entry.kcap, prec)
             ms = (clock() - t0) * 1e3
-            self.last_phase_ms["dispatch"] = ms
+            pend.phase_ms["dispatch"] = ms
             # One program folds every scheduled chunk: the host only
             # enqueues it (nothing here waits for the device; the fold's
             # device time shows where the host first blocks, in
             # single.fetch).
             sp.set(dispatches=1, chunks=len(order),
                    kernel_dispatch_ms=round(ms, 3), throttle_wait_ms=0.0)
-        # Closed by _before_fetch, where SingleChipEngine._run starts
-        # the readback: the epilogue's enqueues run on into _run.
-        self._epilogue_pc = clock()
-        self._pending_gate = (gated, ntiles)
-        self.last_kernel_calls = len(order)
+        # Closed by _before_fetch, where _run_finish starts the
+        # readback: the epilogue's enqueues run on into _run_begin, and
+        # with another batch in flight the span also covers that batch's
+        # second half, which the host runs in between.
+        pend.epilogue_pc = clock()
+        pend.gate = (gated, ntiles)
+        pend.kernel_calls = len(order)
         item = self._staging_itemsize()
         scanned = sum(min(self.n_real - c * cr, cr) for c in order)
-        note_scan(self, scanned_bytes=scanned * na * item,
+        note_scan(pend, scanned_bytes=scanned * na * item,
                   dense_bytes=self.n_real * na * item,
                   blocks_total=(prune_stats or {}).get(
                       "blocks_total", -(-self.n_real // cr)),
                   blocks_pruned=(prune_stats or {}).get(
                       "blocks_pruned", 0))
-        self.last_prune_fraction = self.last_prune["pruned_fraction"]
         top = _extract_finalize(od, oi, self._d_labels, k=entry.kcap)
         return top, entry.qpad
 
-    def _before_fetch(self, t_pc: float) -> None:
-        e0, self._epilogue_pc = self._epilogue_pc, None
+    def _before_fetch(self, pend: PendingBatch, t_pc: float) -> None:
+        e0, pend.epilogue_pc = pend.epilogue_pc, None
         if e0 is not None:
             obs_trace.complete_at("serve.solve_epilogue", e0, t_pc,
                                   **self._rid_args())
+        if pend.mp_fence is not None:
+            self._mp_fetch(pend)
 
     # -- wide-k multipass serving (ROADMAP item (d)) --------------------------
 
-    def _solve_resident_multipass(self, inp: KNNInput, entry: _Bucket
+    def _solve_resident_multipass(self, pend: PendingBatch, entry: _Bucket
                                   ) -> Optional[Tuple[TopK, int]]:
         """k past the kernel's single-pass window, served on the
         existing multi-pass extraction driver (engine.single
@@ -1089,15 +1228,18 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         program (``_fold_stack``; no staging), passes 2+ re-sweep the
         same stack as one array (``_sweep_stack``) with the on-device
         floor chain (``_mp_floor``), and ``_mp_merge`` dedups and
-        composite-sorts to the bucket width. The driver's two loss
-        modes (tie-plateau stall / eps-window shortfall) set
-        ``_mp_hazard`` exactly like the batch engine, and run()'s
-        boundary repair makes them exact — byte-identical to the solo
-        multipass solve and the golden oracle."""
+        composite-sorts to the bucket width. Everything here ENQUEUES;
+        the driver's one fence, and its two loss modes (tie-plateau
+        stall / eps-window shortfall) read from it, are the batch's
+        second half's (``_mp_fetch``): they set ``pend.mp_hazard``
+        exactly like the batch engine, and the boundary repair makes
+        them exact — byte-identical to the solo multipass solve and the
+        golden oracle."""
         from dmlp_tpu.engine.single import (_mp_floor, _mp_merge,
                                             resolve_sweep_kernel)
         from dmlp_tpu.ops import pallas_fused
         from dmlp_tpu.ops.summaries import note_scan
+        inp = pend.inp
         kc = self._MP_KC
         kcap = entry.kcap
         if self._chunks is None or -(-kcap // kc) > self._MP_MAX_PASSES:
@@ -1122,85 +1264,101 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         n = self.n_real
         nchunks = -(-n // cr)
         q_dev = self._stage_batch_queries(inp, entry.qpad)
-        self._last_select = "extract"
-        self.last_extract_impl = impl
-        self.last_variant = self._variant_stamp(impl, kc, entry.qpad, prec)
+        pend.select = "extract"
+        pend.extract_impl = impl
+        pend.variant = self._variant_stamp(impl, kc, entry.qpad, prec)
         sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
                                 self._ex_attrs, prec, self._interpret)
         floor_args = dict(staging=self._staging, na=na, precision=prec)
         targs = self._rid_args()
-        with obs_span("serve.solve_multipass", qpad=entry.qpad,
-                      kcap=kcap, passes=npasses, impl=impl, queries=nq,
-                      chunks=nchunks, **targs) as sp:
-            # Every pass is enqueued without a readback (the floors
-            # chain on the device): serve.mp_pass and serve.mp_merge
-            # time the enqueue alone, and the device's time shows where
-            # the host first blocks, in serve.mp_fetch.
-            with obs_span("serve.mp_pass", kc=kc, rows=n, **{"pass": 1},
-                          **targs):
-                od, oi, _gated, _tiles = self._fold_resident(
-                    q_dev, range(nchunks), impl, kc, prec)
-            ods, ois = [od], [oi]
-            qn_host = np.zeros(entry.qpad, np.float64)
-            qn_host[:nq] = np.einsum("qa,qa->q", inp.query_attrs,
-                                     inp.query_attrs)
-            qn_dev = jax.device_put(np.asarray(qn_host, np.float32))
-            dn_dev, n_dev = jax.device_put((np.float32(self._dn_max()),
-                                            np.int32(n)))
-            fds = []
-            for p in range(2, npasses + 1):
-                with obs_span("serve.mp_pass", kc=kc, rows=n,
-                              **{"pass": p}, **targs):
-                    floor_dev, fd = _mp_floor(ods[-1], qn_dev, dn_dev,
-                                              **floor_args)
-                    fds.append(fd)
-                    od, oi, _its = _sweep_stack(q_dev, self._chunks,
-                                                n_dev, floor_dev, **sweep)
-                ods.append(od)
-                ois.append(oi)
-            with obs_span("serve.mp_merge", kcap=kcap,
-                          slots=npasses * kc, **targs):
-                fds.append(_mp_floor(ods[-1], qn_dev, dn_dev,
-                                     **floor_args)[1])
-                top, valid = _mp_merge(jnp.concatenate(ods, axis=1),
-                                       jnp.concatenate(ois, axis=1),
-                                       self._d_labels, kcap=kcap)
-            # One fence: fd chain (stall check) + final valid counts
-            # (shortfall check) — run()'s repair makes both exact.
-            with obs_span("serve.mp_fetch", **targs):
-                fetched = resilient_get([valid] + fds)
-            valid_h, fd_h = fetched[0], fetched[1:]
-            stalled = np.zeros(entry.qpad, bool)
-            for prev, cur in zip(fd_h, fd_h[1:]):
-                stalled |= np.isfinite(cur) & (cur <= prev)
-            stalled = stalled[:nq]
-            needed = np.minimum(inp.ks.astype(np.int64), n)
-            shortfall = np.asarray(valid_h)[:nq] < needed
-            self._mp_hazard = stalled | shortfall
-            counts = {"stalled": int(np.count_nonzero(stalled)),
-                      "shortfall": int(np.count_nonzero(shortfall))}
-            sp.set(flagged=int(np.count_nonzero(self._mp_hazard)),
-                   **counts)
-        self.last_mp_passes = npasses
-        self.last_kernel_calls = nchunks + npasses - 1
+        t_begin = time.perf_counter()
+        # Every pass is enqueued without a readback (the floors chain
+        # on the device): serve.mp_pass and serve.mp_merge time the
+        # enqueue alone, and the device's time shows where the host
+        # first blocks, in serve.mp_fetch.
+        with obs_span("serve.mp_pass", kc=kc, rows=n, **{"pass": 1},
+                      **targs):
+            od, oi, _gated, _tiles = self._fold_resident(
+                q_dev, range(nchunks), impl, kc, prec)
+        ods, ois = [od], [oi]
+        qn_host = np.zeros(entry.qpad, np.float64)
+        qn_host[:nq] = np.einsum("qa,qa->q", inp.query_attrs,
+                                 inp.query_attrs)
+        qn_dev = jax.device_put(np.asarray(qn_host, np.float32))
+        dn_dev, n_dev = jax.device_put((np.float32(self._dn_max()),
+                                        np.int32(n)))
+        fds = []
+        for p in range(2, npasses + 1):
+            with obs_span("serve.mp_pass", kc=kc, rows=n,
+                          **{"pass": p}, **targs):
+                floor_dev, fd = _mp_floor(ods[-1], qn_dev, dn_dev,
+                                          **floor_args)
+                fds.append(fd)
+                od, oi, _its = _sweep_stack(q_dev, self._chunks,
+                                            n_dev, floor_dev, **sweep)
+            ods.append(od)
+            ois.append(oi)
+        with obs_span("serve.mp_merge", kcap=kcap,
+                      slots=npasses * kc, **targs):
+            fds.append(_mp_floor(ods[-1], qn_dev, dn_dev,
+                                 **floor_args)[1])
+            top, valid = _mp_merge(jnp.concatenate(ods, axis=1),
+                                   jnp.concatenate(ois, axis=1),
+                                   self._d_labels, kcap=kcap)
+        # serve.solve_multipass runs from here to the end of the fence
+        # (_mp_fetch), so that it holds the batch's kernel events; with
+        # another batch in flight it holds that batch's second half too.
+        pend.mp_fence = (t_begin, [valid] + fds,
+                         dict(qpad=entry.qpad, kcap=kcap, passes=npasses,
+                              impl=impl, queries=nq, chunks=nchunks))
+        pend.mp_passes = npasses
+        pend.kernel_calls = nchunks + npasses - 1
+        # The multipass plan re-sweeps the whole resident corpus: a
+        # dense scan by design, staged bytes counted once.
+        dense = n * na * self._staging_itemsize()
+        note_scan(pend, scanned_bytes=dense, dense_bytes=dense,
+                  blocks_total=self._ex_nchunks, blocks_pruned=0)
+        return top, entry.qpad
+
+    def _mp_fetch(self, pend: PendingBatch) -> None:
+        """The multipass driver's ONE fence: the fd chain (stall check)
+        and the final valid counts (shortfall check); the boundary
+        repair makes both exact."""
+        (t_begin, fence, args), pend.mp_fence = pend.mp_fence, None
+        inp = pend.inp
+        nq = inp.params.num_queries
+        targs = self._rid_args()
+        with obs_span("serve.mp_fetch", **targs):
+            fetched = resilient_get(fence)
+        valid_h, fd_h = fetched[0], fetched[1:]
+        stalled = np.zeros(args["qpad"], bool)
+        for prev, cur in zip(fd_h, fd_h[1:]):
+            stalled |= np.isfinite(cur) & (cur <= prev)
+        stalled = stalled[:nq]
+        needed = np.minimum(inp.ks.astype(np.int64), inp.params.num_data)
+        shortfall = np.asarray(valid_h)[:nq] < needed
+        pend.mp_hazard = stalled | shortfall
+        counts = {"stalled": int(np.count_nonzero(stalled)),
+                  "shortfall": int(np.count_nonzero(shortfall))}
+        obs_trace.complete_at(
+            "serve.solve_multipass", t_begin, time.perf_counter(), **args,
+            flagged=int(np.count_nonzero(pend.mp_hazard)), **counts,
+            **targs)
         reg = telemetry.registry()
         reg.counter("serve.multipass_batches").inc()
-        reg.counter("serve.multipass_passes").inc(npasses)
+        reg.counter("serve.multipass_passes").inc(args["passes"])
         for label, count in counts.items():
             if count:
                 reg.counter("serve.multipass_flagged").inc(count,
                                                            label=label)
-        # The multipass plan re-sweeps the whole resident corpus: a
-        # dense scan by design, staged bytes counted once.
-        dense = n * na * self._staging_itemsize()
-        note_scan(self, scanned_bytes=dense, dense_bytes=dense,
-                  blocks_total=self._ex_nchunks, blocks_pruned=0)
-        return top, entry.qpad
 
     def _chunk_order(self) -> List[int]:
         """Fold order over the resident chunks: hottest (most past
         winners) first when gate carry-over is on, natural otherwise.
-        Stable sort: cold chunks keep their natural relative order."""
+        Stable sort: cold chunks keep their natural relative order.
+        The histogram is one batch staler with a batch in flight (its
+        winners are credited when it finishes): the order decides what
+        is gated, never what is answered."""
         with obs_span("serve.fold_schedule", chunks=self._ex_nchunks,
                       carry=self.gate_carry, **self._rid_args()):
             idx = range(self._ex_nchunks)
@@ -1211,13 +1369,12 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     # -- SingleChipEngine seam overrides --------------------------------------
 
-    def _solve(self, inp: KNNInput) -> Tuple[TopK, int]:
-        self.last_phase_ms = {}
-        self._pending_iters = []
-        self._epilogue_pc = None
-        self.last_extract_impl = self.last_variant = None
-        self.last_prune = None
-        self.last_kernel_calls = 0
+    def _enqueue(self, pend: PendingBatch) -> List[Tuple]:
+        """One segment a micro-batch (no hetk routing on the resident
+        paths: the per-request slicing stays trivial); a wide-k bucket
+        takes the multipass driver. Whatever the solve says of itself
+        goes to ``pend``, not to the engine: two batches are alive."""
+        inp = pend.inp
         if inp.params.num_data != self.n_real:
             raise ValueError(
                 f"resident solve got a foreign corpus "
@@ -1226,36 +1383,48 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         nq = inp.params.num_queries
         kmax = int(inp.ks.max()) if nq else 1
         entry = self._bucket_entry(nq, kmax)
-        if entry.path == "extract" and self._degrade_rung != "streaming":
-            out = self._solve_resident_extract(inp, entry)
-            if out is not None:
-                return out
-        if entry.path == "multipass" and self._degrade_rung != "streaming":
-            out = self._solve_resident_multipass(inp, entry)
-            if out is not None:
-                return out
-        return self._solve_resident_stream(inp, entry)
+        out = None
+        if self._degrade_rung != "streaming":
+            if entry.path == "extract":
+                out = self._solve_resident_extract(pend, entry)
+            elif entry.path == "multipass":
+                out = self._solve_resident_multipass(pend, entry)
+        top, qpad = out or self._solve_resident_stream(pend, entry)
+        return [(top, qpad, None, pend.select)]
 
-    def _solve_segments(self, inp: KNNInput, allow_multipass: bool = True):
-        # No hetk routing on the resident paths: one segment per
-        # micro-batch keeps the per-request slicing trivial. Wide-k
-        # buckets route through _solve_resident_multipass inside
-        # _solve (which sets _mp_hazard for run()'s exact repair).
-        self.last_hetk = None
-        self._mp_hazard = None
-        self.last_mp_passes = 0
-        top, qpad = self._solve(inp)
-        return [(top, qpad, None, self._last_select)]
+    def _run(self, inp: KNNInput) -> List[QueryResult]:
+        pend = PendingBatch(inp)
+        self._run_begin(pend)
+        return self._run_finish(pend)
 
-    def run(self, inp: KNNInput) -> List[QueryResult]:
+    def _run_finish(self, pend: PendingBatch) -> List[QueryResult]:
+        results = super()._run_finish(pend)
+        self._after_batch(pend, results)
+        # What `stats` reports of "the last solve" is the last batch
+        # FINISHED, whole: each field one assignment, read lock-free.
+        self._last_select = pend.select
+        self.last_extract_impl = pend.extract_impl
+        self.last_variant = pend.variant
+        self.last_kernel_calls = pend.kernel_calls
+        self.last_mp_passes = pend.mp_passes
+        self.last_prune = pend.last_prune
+        if pend.last_prune is not None:
+            self.last_prune_fraction = pend.last_prune["pruned_fraction"]
+        return results
+
+    def run(self, inp: KNNInput, first: int = 0) -> List[QueryResult]:
+        """One solve whole, on the degrade ladder from rung ``first``."""
         # No staging_for_k swap (the parent flips bf16->f32 staging for
         # wide k, which would mismatch the resident buffers): max_k
         # already refuses the shapes that swap existed for.
+        self._check_k(inp)
+        return rs_degrade.run_ladder(self, inp, self._run, first=first)
+
+    def _check_k(self, inp: KNNInput) -> None:
         kmax = int(inp.ks.max()) if inp.params.num_queries else 0
         if kmax > self.max_k:
             raise RequestShapeError(
                 f"k={kmax} beyond the serving cap {self.max_k}")
-        return rs_degrade.run_ladder(self, inp, self._run)
 
     # -- the serving entry ----------------------------------------------------
 
@@ -1263,17 +1432,89 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         """One coalesced micro-batch end to end: pad/bucket, solve on
         the compiled bucket program, float64-finalize + repair, update
         the cross-request gate state. Results carry query ids
-        0..nq-1 in batch order — the batcher slices per request."""
-        inp = self._batch_input(np.asarray(query_attrs, np.float64),
-                                np.asarray(ks, np.int32))
-        self._pending_gate = None
-        results = self.run(inp)
-        self._after_batch(results)
+        0..nq-1 in batch order — the batcher slices per request.
+
+        Called alone (warm-up, the tests, every caller outside the
+        batcher) it runs the two halves back to back. Called by
+        ``finish_batch`` it is the second half of the batch handed to
+        it, whose first half ``begin_batch`` ran earlier: the fence,
+        the hazard test, the float64 finalize + repair, the gate
+        bookkeeping. It raises what the batch failed with; a batch
+        begun behind it is untouched. Same answers either way."""
+        pend, self._handed = self._handed, None
+        if pend is None:
+            pend = self.begin_batch(query_attrs, ks)
+        if pend in self._in_flight:
+            self._in_flight.remove(pend)
+        if pend.outcome is None:
+            pend.outcome = self._outcome(pend)
+        results, error = pend.outcome
+        if error is not None:
+            raise error
         return results
 
-    def _after_batch(self, results: List[QueryResult]) -> None:
+    def begin_batch(self, query_attrs, ks, batch: Optional[int] = None,
+                    rids: Optional[str] = None) -> PendingBatch:
+        """The first half of a micro-batch: stage its queries, choose
+        and prune the fold order, dispatch the bucket's program(s).
+        Everything that only ENQUEUES: the batcher calls this for batch
+        N + 1 while the device still folds batch N, and only then
+        finishes N. (It waits for the device in one place, the prune
+        scorer's readback.) ``batch`` / ``rids`` tag the batch's spans.
+
+        An OOM-class failure here is kept in the record: the second
+        half re-runs the batch whole, a rung down. Any other raises."""
+        inp = self._batch_input(np.asarray(query_attrs, np.float64),
+                                np.asarray(ks, np.int32))
+        self._check_k(inp)
+        pend = PendingBatch(inp, batch=batch, rids=rids,
+                            overlapped=bool(self._in_flight))
+        try:
+            with self._tagged(pend), rs_degrade.top_rung(self):
+                self._run_begin(pend)
+        except Exception as e:
+            if not rs_degrade.steps_down(e):
+                raise
+            pend.oom = e
+        self._in_flight.append(pend)
+        return pend
+
+    def _outcome(self, pend: PendingBatch) -> Tuple:
+        """(results, None) or (None, the exception) of ``pend``'s second
+        half; an OOM-class failure in either half walks the ladder."""
+        try:
+            with self._tagged(pend):
+                if pend.oom is None:
+                    try:
+                        with rs_degrade.top_rung(self):
+                            return self._run_finish(pend), None
+                    except Exception as e:
+                        if not rs_degrade.steps_down(e):
+                            raise
+                        pend.oom = e
+                return self._rerun_alone(pend), None
+        except Exception as e:  # check: no-retry — solve_batch raises it
+            return None, e
+
+    def _rerun_alone(self, pend: PendingBatch) -> List[QueryResult]:
+        """The ladder's meaning with two batches alive: the batch that
+        ran out of memory runs again WHOLE from the next rung, with the
+        device to itself. Its own lists go first; the batch begun
+        behind it is finished (its outcome kept for its own second
+        half to return) before anything is dispatched again."""
+        pend.segments, pend.gate, pend.mp_fence = [], None, None
+        while self._in_flight:
+            other = self._in_flight.pop(0)
+            other.outcome = self._outcome(other)
+        rungs = rs_degrade.RUNGS
+        rs_degrade.note_step(rungs[0], rungs[1], pend.oom)
+        return self.run(pend.inp, first=1)
+
+    def _after_batch(self, pend: PendingBatch,
+                     results: List[QueryResult]) -> None:
         with obs_span("serve.after_batch", **self._rid_args()) as sp:
-            self._flush_pending_gate(sp)
+            self._flush_gate(sp, pend.gate)
+            pend.gate = None
             if self.gate_carry and self._ex_nchunks and results:
                 ids = np.concatenate(
                     [np.asarray(r.neighbor_ids, np.int64)
@@ -1339,9 +1580,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         # under the GIL; the engine stays single-writer.
         entries = list(self._buckets.values())
         # Same single-read discipline for last_prune: the batcher
-        # thread resets it to None at the start of every solve, so an
-        # isinstance check followed by a second attribute read could
-        # straddle that write and dict(None)-crash a stats handler.
+        # thread replaces it whenever a batch finishes, so one read
+        # serves both the isinstance check and the copy.
         lp = self.last_prune
         return {
             "buckets": sorted(e.key for e in entries),
@@ -1369,6 +1609,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             "last_kernel_calls": self.last_kernel_calls,
             "last_mp_passes": self.last_mp_passes,
             "multipass": self._multipass_stats(),
+            "overlap": self._overlap_stats(),
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,
             "last_prune_fraction": self.last_prune_fraction,

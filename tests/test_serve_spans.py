@@ -92,8 +92,13 @@ def traced():
             "stats": stats}
 
 
-def named(events, name):
-    return [e for e in events if e["name"] == name]
+def named(events, name, batch=None):
+    """The spans called ``name``; with ``batch``, those of that
+    micro-batch (two batches are alive at once under load, so a span
+    belongs to the batch its ``batch`` arg names, not to the
+    ``serve.micro_batch`` it happens to lie in)."""
+    return [e for e in events if e["name"] == name
+            and (batch is None or e["args"].get("batch") == batch)]
 
 
 def inside(child, parent):
@@ -118,15 +123,18 @@ def test_batcher_spans_tile_the_cycle_in_order(traced):
     for a, b in zip(cycle, cycle[1:]):
         assert a["ts"] + a["dur"] <= b["ts"]
     batch = cycle[1]
-    kids = [named(traced["served"], n)[0] for n in IN_BATCH]
+    assert batch["args"]["overlapped"] == 0     # nothing was in flight
+    kids = [named(traced["served"], n, batch["args"]["batch"])[0]
+            for n in IN_BATCH]
     assert {e["tid"] for e in kids} == {batch["tid"]}
     for k in kids:
         assert inside(k, batch), k["name"]
     for a, b in zip(kids, kids[1:]):                    # disjoint, in order
         assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
     for child, parent in NESTED.items():
-        assert inside(named(traced["served"], child)[0],
-                      named(traced["served"], parent)[0])
+        assert inside(
+            named(traced["served"], child, batch["args"]["batch"])[0],
+            named(traced["served"], parent, batch["args"]["batch"])[0])
 
 
 def test_span_args_say_what_the_work_was(traced):

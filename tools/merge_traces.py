@@ -372,8 +372,11 @@ def merge(trace_dir: str, align: bool = True,
 # -- fleet mode ---------------------------------------------------------------
 
 #: the replica-side request phases the reconcile sums; one rid's phases
-#: tile ITS OWN wall time (solve is the full micro-batch interval,
-#: attributed to every coalesced rid) — never sum across rids.
+#: tile ITS OWN wall time (solve is the full micro-batch interval, from
+#: the start of its first half to the end of its second, attributed to
+#: every coalesced rid; two micro-batches overlap in time when one was
+#: begun behind the other, so phases are matched by ``rid`` and never by
+#: the ``serve.micro_batch`` they lie in) — never sum across rids.
 FLEET_PHASES = ("queue", "coalesce", "solve", "finalize", "write")
 
 #: budget for the MEDIAN per-request residual (client_ms - lag_ms -
