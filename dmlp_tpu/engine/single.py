@@ -37,6 +37,7 @@ from dmlp_tpu.io.grammar import KNNInput, subset_queries
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import counters as obs_counters
 from dmlp_tpu.obs import memwatch, telemetry
+from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.ops.topk import TopK, init_topk, make_block_step, streaming_topk
 from dmlp_tpu.ops.vote import majority_vote, report_order
@@ -1123,7 +1124,6 @@ class SingleChipEngine:
         self.last_phase_ms["enqueue"] = (_time.perf_counter() - t0) * 1e3
         self.last_mp_passes = len(ods)
 
-        from dmlp_tpu.obs import trace as obs_trace
         obs_trace.instant("single.multipass_sweep", passes=len(ods),
                           kcap=kcap, chunks=n_staged)
         # The multipass plan keeps the dataset resident and re-sweeps
@@ -1432,7 +1432,7 @@ class SingleChipEngine:
             kcap = top.dists.shape[1]
 
             t0 = _time.perf_counter()
-            self._before_fetch(pend, t0)
+            self._before_fetch(pend)
             # NOTE: the "fetch" phase time includes the wait for all
             # enqueued device work (staging + solve), not just the readback
             # bytes — and past _CHUNK_WINDOW chunks the enqueue phase
@@ -1440,8 +1440,11 @@ class SingleChipEngine:
             # as "readback costs X ms".
             fetch = ([] if self.config.exact else [top.dists]) + [top.ids] \
                 + ([cols_dev] if cols_dev is not None else [])
+            # The whole call, retries and injected faults included, is
+            # one device wait of this thread (site: the span's own).
             with obs_span("single.fetch", select=select, kcap=kcap,
-                          **targs):
+                          site="fetch", **targs), \
+                    obs_trace.device_wait("fetch", span=False):
                 fetched = list(resilient_get(fetch))
             t1 = _time.perf_counter()
             fetch_ms += (t1 - t0) * 1e3
@@ -1548,12 +1551,10 @@ class SingleChipEngine:
                                     inp.data_attrs).max()) if n else 0.0,
                     False)
 
-    def _before_fetch(self, pend: PendingRun, t_pc: float) -> None:
+    def _before_fetch(self, pend: PendingRun) -> None:
         """Seam: everything of ``pend``'s solve is enqueued and its
-        readback starts at ``t_pc`` (perf_counter). The serving engine
-        closes its ``serve.solve_epilogue`` span here and, on a
-        multipass bucket, makes the driver's own fence; a batch solve
-        has neither."""
+        readback starts now. The serving engine makes a multipass
+        bucket's own fence here; a batch solve has none."""
 
     def run_device_full(self, inp: KNNInput) -> List[QueryResult]:
         """All-device pipeline (vote + report order on TPU); f32 ordering.

@@ -76,6 +76,7 @@ from dmlp_tpu.io.grammar import KNNInput, Params
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import counters as obs_counters
 from dmlp_tpu.obs import memwatch, telemetry
+from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.comms import engine_comms
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS, make_mesh
@@ -221,8 +222,6 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             self._build_summaries()
         # Gate-carry state: per-(shard, chunk) winner histogram.
         self._block_hits = np.zeros((r, max(self._nchunks, 1)), np.int64)
-        telemetry.registry().gauge("serve.gate.carry_enabled").set(
-            int(self.gate_carry))
 
         # -- bucket registry + compile bookkeeping ---------------------------
         self._buckets: Dict[Tuple[int, int], _MeshBucket] = {}
@@ -609,7 +608,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # timings behind `stats` are the same numbers the spans carry.)
         t_drain = clock()
         with obs_span("fleet.merge_drain", dispatches=1,
-                      **self._rid_args()):
+                      site="merge_drain", **self._rid_args()), \
+                obs_trace.device_wait("merge_drain", span=False):
             jax.block_until_ready((cd, ci))  # check: allow-host-sync
         merge_bytes = sum(t.bytes_total for t in self.last_comms)
         telemetry.registry().counter("fleet.merge_bytes").inc(merge_bytes)
@@ -618,7 +618,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                       strategy=self._merge_strategy, bytes=merge_bytes,
                       **self._rid_args()):
             top = merge_fn(cd, ci, self._lab_dev)
-            jax.block_until_ready(top.dists)  # check: allow-host-sync
+            with obs_trace.device_wait("merge", self.trace_batch):
+                jax.block_until_ready(top.dists)  # check: allow-host-sync
         self.last_phase_ms["dispatch"] = fold_ms + (t_merge - t_drain) * 1e3
         self.last_phase_ms["merge"] = (clock() - t_merge) * 1e3
         return top
@@ -703,6 +704,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                                "configured": self._precision_plan}
         memwatch.note_engine_model(self, inp)
         entry = self._bucket_entry(nq, kmax)
+        if self._handed is not None:    # a slow cycle's record names it
+            self._handed.path = entry.path
         if entry.path == "extract":
             top = self._solve_resident_chunks(inp, entry)
         else:
@@ -710,10 +713,11 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         self.last_repairs = 0
         clock = time.perf_counter
         t0 = clock()
-        with obs_span("fleet.fetch", **self._rid_args()):
+        with obs_span("fleet.fetch", site="fetch", **self._rid_args()):
             telemetry.sample_memory_now()
-            od, ol, oi = resilient_get((top.dists, top.labels, top.ids),
-                                       site="sharded.fetch")
+            with obs_trace.device_wait("fetch", span=False):
+                od, ol, oi = resilient_get(
+                    (top.dists, top.labels, top.ids), site="sharded.fetch")
             dists = np.asarray(od, np.float64)[:nq]
             labels = ol[:nq]
             ids = oi[:nq]

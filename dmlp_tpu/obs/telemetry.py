@@ -38,6 +38,10 @@ p50/p95/p99 / QPS / memory-headroom contract lands on:
   ``FLIGHT_<reason>.json`` artifact on crash, fatal-classified fault
   (resilience.retry), or SIGTERM — the post-mortem evidence the chaos
   harness's injected failures previously vanished without.
+- :class:`GcPauses` — the collector's pauses from one lock-free
+  ``gc.callbacks`` hook (``runtime.gc_pause_ms`` /
+  ``runtime.gc_collections`` by generation; ``runtime.gc`` spans),
+  installed for a serving daemon's lifetime.
 
 Span-derived phase latencies come from one seam: when a session is
 active, :mod:`dmlp_tpu.obs.trace` forwards every completed span and
@@ -837,6 +841,97 @@ def dump_on_crash(reason: str = "crash") -> Optional[str]:
         return s.flight.dump(s.flight_dir, reason)
     except Exception:  # check: no-retry — a failing dump must not mask
         return None    # the original crash
+
+
+# -- the runtime's pauses ------------------------------------------------------
+
+class GcPauses:
+    """The collector's pauses, from one ``gc.callbacks`` hook: counters
+    ``runtime.gc_pause_ms`` / ``runtime.gc_collections`` by generation
+    (``gen0``..``gen2``), a running total the micro-batcher reads a
+    cycle, and ``runtime.gc`` spans while a sink is installed.
+
+    The callback itself takes NO lock and touches no metric: a
+    collection starts inside whatever allocation triggered it, which may
+    be inside ``Registry._get``, ``Counter.inc`` or ``Tracer._append``
+    with their plain locks held by that very thread. It stamps two clock
+    reads and appends one tuple to a deque; :meth:`drain` (the batcher
+    at the end of a cycle, ``stats``, the daemon's close) turns the
+    tuples into counters and spans."""
+
+    def __init__(self):
+        #: seconds of collection since the process started watching
+        #: (written by whichever thread collects: the interpreter lock
+        #: serializes collections, so one writer at a time)
+        self.total_s = 0.0
+        self._t0 = 0.0
+        # bounded: a daemon that closes no cycle (ingest only) and is
+        # asked for no stats drops the oldest notes, not its memory
+        self._pending: deque = deque(maxlen=4096)
+        self._installs = 0
+        self._lock = threading.Lock()   # install / remove only
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        self.total_s += t1 - self._t0
+        self._pending.append((self._t0, t1, info["generation"],
+                              info["collected"], threading.get_ident()))
+
+    def install(self) -> None:
+        """Hook the collector (counted: the hook goes with the last
+        :meth:`remove`)."""
+        import gc
+        with self._lock:
+            self._installs += 1
+            if self._installs == 1:
+                gc.callbacks.append(self._on_gc)
+
+    def remove(self) -> None:
+        import gc
+        with self._lock:
+            if self._installs == 0:
+                return
+            self._installs -= 1
+            if self._installs == 0 and self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+        self.drain()
+
+    def drain(self) -> None:
+        """Collections noted since the last drain, into the counters
+        and (with a sink) ``runtime.gc`` spans. Any thread, any time
+        but from inside the callback."""
+        from dmlp_tpu.obs import trace as obs_trace
+        pending = self._pending
+        names = None
+        while pending:
+            try:
+                t0, t1, gen, collected, ident = pending.popleft()
+            except IndexError:      # another thread drained it
+                return
+            label = f"gen{gen}"
+            REGISTRY.counter("runtime.gc_pause_ms").inc(
+                (t1 - t0) * 1e3, label=label)
+            REGISTRY.counter("runtime.gc_collections").inc(label=label)
+            if obs_trace.sinks_active():
+                if names is None:
+                    names = {t.ident: t.name
+                             for t in threading.enumerate()}
+                obs_trace.complete_at(
+                    "runtime.gc", t0, t1, generation=gen,
+                    collected=collected,
+                    thread=names.get(ident, str(ident)))
+
+
+GC_PAUSES = GcPauses()
+
+
+def gc_pauses() -> GcPauses:
+    """The process-wide collector watch (``ServeDaemon.start`` installs
+    its hook, the daemon's close removes it)."""
+    return GC_PAUSES
 
 
 # -- background sampler -------------------------------------------------------

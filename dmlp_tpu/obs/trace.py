@@ -217,12 +217,6 @@ class Tracer:
                       "pid": self._pid, "tid": self._tid(),
                       **({"args": args} if args else {})})
 
-    def counter(self, name: str, **series) -> None:
-        """A counter sample (``ph: "C"``) — Perfetto renders a track."""
-        ts = (_clock() - self._epoch) * 1e6
-        self._append({"name": name, "ph": "C", "ts": ts, "pid": self._pid,
-                      "args": {k: float(v) for k, v in series.items()}})
-
     def sync_instant(self, name: str, **args) -> None:
         """A clock-sync marker pairing one perf_counter read with one
         wall-clock read taken back-to-back. Unbarriered fleet processes
@@ -327,12 +321,6 @@ def instant(name: str, **args) -> None:
         cb(name, args)
 
 
-def counter(name: str, **series) -> None:
-    t = _active
-    if t is not None:
-        t.counter(name, **series)
-
-
 def sinks_active() -> bool:
     """True when completed spans go anywhere (Tracer or telemetry
     observer). Request-phase instrumentation that must be zero-cost
@@ -356,3 +344,79 @@ def complete_at(name: str, t0: float, t1: float, **args) -> None:
     cb = _span_observer
     if cb is not None:
         cb(name, max((t1 - t0) * 1e3, 0.0), args)
+
+
+# -- host-sync bracket ---------------------------------------------------------
+# Where a thread blocks on the device (the ``# check: allow-host-sync``
+# seams of the served path) it says so through ``device_wait``: two clock
+# reads and a per-thread tally, always on. A seam that has a span of its
+# own around exactly the wait (``single.fetch``, ``serve.mp_fetch``,
+# ``fleet.fetch``, ``fleet.merge_drain``) names its ``site`` on that
+# span; the others (``prune_score``, ``gate``, ``merge``) get a
+# ``serve.wait.device`` span while a sink is installed. The
+# micro-batcher reads its own thread's tally once a cycle
+# (serve/batching.py).
+
+class WaitTally:
+    """One thread's device waits since its last :meth:`take`: seconds
+    in all, and by ``site``. Only its own thread touches it."""
+
+    __slots__ = ("seconds", "by_site")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.by_site: Dict[str, float] = {}
+
+    def take(self):
+        """(seconds, {site: seconds}) waited since the last take."""
+        out = self.seconds, self.by_site
+        self.seconds, self.by_site = 0.0, {}
+        return out
+
+
+_wait_local = threading.local()
+
+
+def wait_tally() -> WaitTally:
+    """The calling thread's tally (made on first use)."""
+    try:
+        return _wait_local.tally
+    except AttributeError:
+        tally = _wait_local.tally = WaitTally()
+        return tally
+
+
+class device_wait:  # noqa: N801 (used as ``with device_wait(site):``)
+    """Bracket one host sync: ``site`` names the seam (``prune_score``,
+    ``fetch``, ``mp_fetch``, ``gate``, ``merge_drain``, ``merge``).
+    Retries and injected faults of the call inside stay inside; an
+    exception still counts the wait. ``span=False`` where the caller's
+    own span already brackets the wait (and carries ``site``): the
+    tally alone; otherwise a ``serve.wait.device`` span with ``site``
+    and the micro-batch (``batch``) it belongs to."""
+
+    __slots__ = ("site", "batch", "span", "_t0")
+
+    def __init__(self, site: str, batch: Optional[int] = None,
+                 span: bool = True):
+        self.site = site
+        self.batch = batch
+        self.span = span
+        self._t0 = 0.0
+
+    def __enter__(self) -> "device_wait":
+        self._t0 = _clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = _clock()
+        tally = wait_tally()
+        tally.seconds += t1 - self._t0
+        tally.by_site[self.site] = \
+            tally.by_site.get(self.site, 0.0) + (t1 - self._t0)
+        if self.span and sinks_active():
+            args = {"site": self.site}
+            if self.batch is not None:
+                args["batch"] = self.batch
+            complete_at("serve.wait.device", self._t0, t1, **args)
+        return False

@@ -19,6 +19,16 @@ one is in flight) runs them one after the other, as a serial batcher
 would, so small requests keep collecting company until their fold can
 start.
 
+The batcher accounts for its own time. Its thread's timeline is cut at
+the end of every delivery into **cycles**, one a micro-batch, and each
+cycle is split exactly three ways: the thread's waits for requests
+(``queue_wait``), its waits on the device (``device_wait``: the
+engines' host syncs, through ``obs.trace.device_wait``) and the rest
+(``own``). Always on: histograms ``serve.cycle_ms{,.own,.device_wait,
+.queue_wait}`` and a ring of slow cycles (``stats.batcher``); with a
+sink, span ``serve.cycle`` and, around each wait, ``serve.wait.queue``
+/ ``serve.wait.device`` (or the seam's own span, with its ``site``).
+
 Single consumer thread: the engine (and its ingest path) is driven by
 exactly one thread, so resident-buffer updates never race a solve.
 Requests complete through a per-request event; connection handlers
@@ -44,6 +54,16 @@ from dmlp_tpu.serve.engine import ResidentEngine
 
 #: default batcher tick: how long a lone request waits for company
 TICK_S = 0.002
+
+#: the ring of slow cycles (``stats.batcher.slow_cycles``): a cycle is
+#: kept, whole, when it is longer than SLOW_FACTOR x the running median
+#: of ``serve.cycle_ms`` and at least SLOW_MIN_OVER_MS over it; none of
+#: a daemon's first SLOW_WARM_CYCLES (the median is not yet one); the
+#: ring holds the newest SLOW_RING
+SLOW_FACTOR = 3.0
+SLOW_MIN_OVER_MS = 50.0
+SLOW_WARM_CYCLES = 32
+SLOW_RING = 16
 
 
 @dataclasses.dataclass
@@ -103,7 +123,11 @@ class Request:
 @dataclasses.dataclass
 class _Flight:
     """A micro-batch between its two halves: begun (its device work is
-    enqueued), not finished."""
+    enqueued), not finished. With the pipeline engaged it is begun in
+    one cycle of the batcher and finished in the next, so the spans
+    that run from its begin to its finish (``serve.micro_batch``,
+    ``serve.solve_multipass``) cross the other batch's second half;
+    every other span of it lies inside one cycle."""
 
     requests: List[Request]
     total: int           # queries
@@ -120,7 +144,22 @@ class MicroBatcher:
     keeps up to two micro-batches in the engine: it begins the next one
     (when the queue already holds a batch's worth of queries) before it
     finishes and delivers the one in flight. ``ingest`` and ``corpus``
-    requests run with nothing in flight."""
+    requests run with nothing in flight.
+
+    **A cycle** is one delivered micro-batch's piece of the thread's
+    timeline: from the end of the previous ``_finish`` (delivery
+    included) to the end of this one. Pipelined that is ``_collect`` +
+    ``_begin(N + 1)`` + ``_finish(N)``; in the serial order ``_collect``
+    (blocking: the idle wait and the tick) + ``_begin(N)`` + an empty
+    ``_collect`` + ``_finish(N)``. No two cycles overlap and together
+    they tile the thread while batches flow (an ``ingest`` / ``corpus``
+    request, or a batch whose first half failed, lies in the next
+    batch's cycle). ``cycle = own + device_wait + queue_wait``, exactly:
+    ``queue_wait`` is the thread blocked in ``_cond.wait``,
+    ``device_wait`` its host syncs (the thread's ``WaitTally``), ``own``
+    the rest (Python and NumPy work, waits for the interpreter lock,
+    collector pauses). ``gc_ms`` beside them is the collector's pause
+    time, any thread's, that ended inside the cycle."""
 
     def __init__(self, engine: ResidentEngine,
                  admission: AdmissionController,
@@ -140,6 +179,16 @@ class MicroBatcher:
         # Serial of the last micro-batch begun: every span of one batch
         # carries its own as ``batch``. Consumer-thread-private.
         self._serial = 0
+        # The cycle in hand (consumer-thread-private): where it started
+        # (perf_counter), what the thread has waited for requests in it,
+        # the batch begun in it (0: none), the collector's total at its
+        # start. `cycles` and the ring are read by stats handlers.
+        self._cycle_t0 = 0.0
+        self._queue_wait_s = 0.0
+        self._begun = 0
+        self._gc_s = 0.0
+        self.cycles = 0
+        self._slow: deque = deque(maxlen=SLOW_RING)
 
     # -- producer side ---------------------------------------------------------
 
@@ -248,18 +297,34 @@ class MicroBatcher:
         batch in flight, as they would have waited through its whole
         solve; taken now they would be committed to a batch a device
         pass before their fold can start. An empty return sends the
-        caller to finish that batch."""
+        caller to finish that batch. The time blocked in ``_cond.wait``
+        (for work; for company) is the cycle's ``queue_wait``."""
+        waits: List[Tuple[float, float]] = []
+        taken = self._take(block, waits)
+        # outside the queue lock: the cycle's tally, and the spans
+        for w0, w1 in waits:
+            self._queue_wait_s += w1 - w0
+            obs_trace.complete_at("serve.wait.queue", w0, w1)
+        return taken
+
+    def _take(self, block: bool, waits: List[Tuple[float, float]]
+              ) -> Tuple[List[Request], float]:
+        clock = time.perf_counter
         with self._cond:
-            while block and not self._queue and not self._stop:
-                self._cond.wait(timeout=0.1)
+            if block and not self._queue and not self._stop:
+                w0 = clock()
+                while not self._queue and not self._stop:
+                    self._cond.wait(timeout=0.1)
+                waits.append((w0, clock()))
             if not self._queue or (
                     not block and self._queue[0].kind == "query"
                     and self._queued_queries < self.max_batch_queries):
                 return [], 0.0
-            wake_pc = time.perf_counter()
+            wake_pc = clock()
             if block and not self._stop and self.tick_s > 0 \
                     and self._queued_queries < self.max_batch_queries:
                 self._cond.wait(timeout=self.tick_s)
+                waits.append((wake_pc, clock()))
             batch: List[Request] = []
             total = 0
             while self._queue:
@@ -284,6 +349,9 @@ class MicroBatcher:
 
     def _run_loop(self) -> None:
         flight: Optional[_Flight] = None
+        self._cycle_t0 = time.perf_counter()
+        self._gc_s = telemetry.gc_pauses().total_s
+        obs_trace.wait_tally().take()
         while True:
             batch, wake_pc = self._collect(block=flight is None)
             if batch and batch[0].kind == "query":
@@ -385,7 +453,7 @@ class MicroBatcher:
         work is enqueued when this returns (None: it failed, and its
         requests are answered with the error)."""
         self._serial += 1
-        serial = self._serial
+        serial = self._begun = self._serial
         total = sum(r.nq for r in batch)
         with obs_span("serve.batch_assemble", batch=serial,
                       requests=len(batch), queries=total):
@@ -413,10 +481,17 @@ class MicroBatcher:
         return _Flight(batch, total, qpad, wake_pc, t0, pending)
 
     def _finish(self, f: _Flight) -> None:
-        """The second half of a micro-batch, and its delivery.
+        """The second half of a micro-batch, its delivery, and the end
+        of the batcher's cycle (:meth:`_end_cycle`).
         ``serve.micro_batch`` runs from the start of its first half to
-        the end of its second: two of them overlap in time when the
-        batch was begun behind another (``overlapped``)."""
+        the end of its second: it CROSSES batches (two of them overlap
+        in time when the batch was begun behind another:
+        ``overlapped``), as ``serve.solve_multipass`` does; the cycle
+        and every other span of the batcher thread do not."""
+        self._finish_batch(f)
+        self._end_cycle(f)
+
+    def _finish_batch(self, f: _Flight) -> None:
         results, error = None, None
         try:
             results = self.engine.finish_batch(f.pending)
@@ -434,6 +509,81 @@ class MicroBatcher:
         with obs_span("serve.batch_deliver", batch=f.pending.batch,
                       requests=len(f.requests), queries=f.total):
             self._deliver(f, results, t1)
+
+    def _end_cycle(self, f: _Flight) -> None:
+        """Close the cycle that delivered ``f`` and open the next: the
+        always-on histograms, the slow-cycle ring, the collector's
+        noted pauses into their counters, and (with a sink) the
+        ``serve.cycle`` span. Everything after the one clock read is
+        the next cycle's ``own``."""
+        t1 = time.perf_counter()
+        t0, self._cycle_t0 = self._cycle_t0, t1
+        wait_s, sites = obs_trace.wait_tally().take()
+        queue_s, self._queue_wait_s = self._queue_wait_s, 0.0
+        begun, self._begun = self._begun, 0
+        gcp = telemetry.gc_pauses()
+        gc_s, self._gc_s = gcp.total_s - self._gc_s, gcp.total_s
+        gcp.drain()
+        cycle_ms = (t1 - t0) * 1e3
+        device_ms, queue_ms, gc_ms = wait_s * 1e3, queue_s * 1e3, gc_s * 1e3
+        own_ms = cycle_ms - device_ms - queue_ms
+        reg = telemetry.registry()
+        h_cycle = reg.histogram("serve.cycle_ms", unit="ms")
+        h_cycle.observe(cycle_ms)
+        reg.histogram("serve.cycle_ms.own", unit="ms").observe(own_ms)
+        reg.histogram("serve.cycle_ms.device_wait",
+                      unit="ms").observe(device_ms)
+        reg.histogram("serve.cycle_ms.queue_wait",
+                      unit="ms").observe(queue_ms)
+        pend = f.pending
+        overlapped = int(pend.overlapped)
+        # A slow cycle is at least SLOW_MIN_OVER_MS long: the median is
+        # not asked for under that.
+        slow = None
+        if self.cycles >= SLOW_WARM_CYCLES and cycle_ms >= SLOW_MIN_OVER_MS:
+            median = h_cycle.quantile(0.5)
+            if cycle_ms > SLOW_FACTOR * median \
+                    and cycle_ms - median >= SLOW_MIN_OVER_MS:
+                slow = {
+                    "unix_time": round(time.time(), 3),
+                    "batch": pend.batch,
+                    "path": pend.path,
+                    "queries": f.total, "requests": len(f.requests),
+                    "overlapped": overlapped,
+                    "cycle_ms": round(cycle_ms, 3),
+                    "own_ms": round(own_ms, 3),
+                    "device_wait_ms": round(device_ms, 3),
+                    "queue_wait_ms": round(queue_ms, 3),
+                    "gc_ms": round(gc_ms, 3),
+                    "device_wait_sites_ms": {
+                        k: round(v * 1e3, 3) for k, v in sites.items()},
+                    "median_ms": round(median, 3)}
+        if slow is not None:
+            with self._cond:
+                slow["queue_depth"] = self._queued_queries
+                self._slow.append(slow)
+        self.cycles += 1    # one writer; stats handlers load one int
+        if slow is not None:
+            telemetry.flight_event("serve.slow_cycle", **slow)
+            tracer = obs_trace.active()
+            if tracer is not None:
+                tracer.instant("serve.slow_cycle", **slow)
+        if obs_trace.sinks_active():
+            obs_trace.complete_at(
+                "serve.cycle", t0, t1, batch=pend.batch, begun=begun,
+                queries=f.total, requests=len(f.requests),
+                overlapped=overlapped, own_ms=own_ms,
+                device_wait_ms=device_ms, queue_wait_ms=queue_ms,
+                gc_ms=gc_ms)
+
+    def cycle_stats(self) -> Dict[str, Any]:
+        """``stats.batcher``: cycles closed, and the ring of slow ones
+        (newest last), each whole: when, which batch on which path, its
+        three parts and ``gc_ms``, the device wait by site, the queue's
+        depth when it ended."""
+        with self._cond:
+            slow = [dict(c) for c in self._slow]
+        return {"cycles": self.cycles, "slow_cycles": slow}
 
     def _deliver(self, f: _Flight, results: List, t1: float) -> None:
         """A solved micro-batch back to its requests: the batch's

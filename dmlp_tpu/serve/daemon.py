@@ -45,9 +45,12 @@ from dmlp_tpu.serve.engine import ResidentEngine
 #: at the sites that time them (serve/daemon.py, serve/batching.py).
 #: NB ``request.finalize`` is the per-request DELIVERY after the solve
 #: (the ``serve.phase.finalize`` span); ``batch.finalize`` is the
-#: engine's float64 finalize.
+#: engine's float64 finalize. "cycle": the batcher thread's timeline
+#: cut a delivered micro-batch, ``cycle = own + device_wait +
+#: queue_wait`` (serve/batching.py; fed once per micro-batch).
 PHASE_HISTOGRAMS = {
-    "request": (("parse", "serve.phase_ms.parse"),
+    "request": (("read", "serve.phase_ms.read"),
+                ("parse", "serve.phase_ms.parse"),
                 ("queue", "serve.phase_ms.queue"),
                 ("coalesce", "serve.phase_ms.coalesce"),
                 ("solve", "serve.phase_ms.solve"),
@@ -59,6 +62,10 @@ PHASE_HISTOGRAMS = {
               ("fetch", "serve.batch_ms.fetch"),
               ("hazard", "serve.batch_ms.hazard"),
               ("finalize", "serve.batch_ms.finalize")),
+    "cycle": (("cycle", "serve.cycle_ms"),
+              ("own", "serve.cycle_ms.own"),
+              ("device_wait", "serve.cycle_ms.device_wait"),
+              ("queue_wait", "serve.cycle_ms.queue_wait")),
 }
 
 
@@ -84,6 +91,12 @@ class _Handler(socketserver.StreamRequestHandler):
             # the cap, so an oversized request line cannot balloon the
             # daemon's memory before rejection. A cap-exceeding read
             # has lost line framing — reject and drop the connection.
+            # The read is timed from the request's first byte (peek
+            # consumes nothing, and returns empty at the end of the
+            # stream, where readline still decides): an idle
+            # connection's wait is not in it.
+            self.rfile.peek(1)
+            t_first = time.perf_counter()
             raw = self.rfile.readline(protocol.MAX_LINE_BYTES + 1)
             if not raw:
                 break
@@ -114,6 +127,14 @@ class _Handler(socketserver.StreamRequestHandler):
                             "error": f"{type(e).__name__}: {e}"}
                 if resp is None:    # a blank line: nothing is due
                     continue
+                rid = resp.get("rid", "")
+                if req is not None and req.kind == "query":
+                    telemetry.registry().histogram(
+                        "serve.phase_ms.read", unit="ms").observe(
+                            (t_read - t_first) * 1e3)
+                obs_trace.complete_at(
+                    "serve.phase.read", t_first, t_read, bytes=len(raw),
+                    **({"rid": rid} if rid else {}), **_batch_arg(req))
                 w0 = time.perf_counter()
                 data = protocol.encode(resp)
                 self.wfile.write(data)
@@ -124,7 +145,6 @@ class _Handler(socketserver.StreamRequestHandler):
                         "serve.phase_ms.write", unit="ms").observe(
                             (w1 - w0) * 1e3)
                     daemon.record_respond(req, len(data))
-                rid = resp.get("rid", "")
                 obs_trace.complete_at(
                     "serve.phase.write", w0, w1, bytes=len(data),
                     **({"rid": rid} if rid else {}),
@@ -247,6 +267,7 @@ class ServeDaemon:
         self.warmup_ms: Dict[str, float] = {}
         self._sigterm_prev = None
         self._sigterm_handler = None
+        self._gc_hooked = False
         if self.session is not None:
             self.session.set_sigterm_drain(self._drain_event.set)
         else:
@@ -278,6 +299,10 @@ class ServeDaemon:
         """Warm the buckets, then open for traffic."""
         self.warmup_ms = self.engine.warmup(self._warm)
         self._native_loader.join()
+        # the collector's pauses, for as long as this daemon serves
+        # (drain() / close() take the hook off again)
+        telemetry.gc_pauses().install()
+        self._gc_hooked = True
         self.batcher.start()
         self._server_thread = threading.Thread(
             target=self._server.serve_forever, name="serve-accept",
@@ -408,6 +433,7 @@ class ServeDaemon:
         return resp, req
 
     def stats(self) -> Dict[str, Any]:
+        telemetry.gc_pauses().drain()   # runtime.gc_* up to date
         reg = telemetry.registry()
         eng = self.engine
         elapsed = (time.monotonic() - self._t_ready) \
@@ -430,6 +456,10 @@ class ServeDaemon:
             "queries_completed":
                 reg.counter("serve.queries_completed").total(),
             "batches": self.batcher.batches,
+            # the batcher thread's own account of its time: cycles
+            # closed and the ring of slow ones (phases_ms.cycle has the
+            # histograms)
+            "batcher": self.batcher.cycle_stats(),
             # which decode this daemon's query lines took (fallback:
             # json.loads of the whole line) and the converter the
             # scanner's library was built with (None: no library)
@@ -566,6 +596,7 @@ class ServeDaemon:
         # daemonized connection handlers to WRITE those responses — a
         # drain that exits mid-write loses the response on the floor.
         self._wait_inflight_drained()
+        self._unhook_gc()
         self._append_record()
         self._write_trace()
         if self.session is not None:
@@ -573,6 +604,13 @@ class ServeDaemon:
             self.session.close()     # writes the final snapshot
         self._restore_sigterm()
         self._server.server_close()
+
+    def _unhook_gc(self) -> None:
+        """Take this daemon's collector hook off (start() put it on);
+        what it noted last goes to the counters and the trace first."""
+        if self._gc_hooked:
+            self._gc_hooked = False
+            telemetry.gc_pauses().remove()
 
     def _write_trace(self) -> None:
         if self._tracer is None:
@@ -592,6 +630,7 @@ class ServeDaemon:
         self.admission.draining = True
         self._server.shutdown()
         self.batcher.stop(drain=False)
+        self._unhook_gc()
         self._write_trace()
         if self.session is not None:
             self.session.set_sigterm_drain(None)

@@ -248,6 +248,8 @@ class HeldBatch:
     rids: Optional[str] = None      # comma-joined trace request ids
     #: begun while another batch was in flight (never: nothing is begun)
     overlapped: bool = False
+    #: the bucket path its solve took, once finish_batch has run it
+    path: Optional[str] = None
 
 
 @dataclasses.dataclass(eq=False)
@@ -271,8 +273,9 @@ class PendingBatch(PendingRun):
     last_prune: Optional[Dict[str, Any]] = None
     # (gated-tile count still on the device, tiles the fold visited)
     gate: Optional[Tuple] = None
-    # perf_counter at which the extract path's fold was dispatched (the
-    # start of the serve.solve_epilogue span)
+    # perf_counter at which the fold (the multipass merge) had been
+    # dispatched: the start of the serve.solve_epilogue span, which
+    # ends with the first half
     epilogue_pc: Optional[float] = None
     # the multipass driver's fence, not made yet: (perf_counter its
     # enqueues began at, [valid counts] + the floor chain, span args)
@@ -289,6 +292,11 @@ class PendingBatch(PendingRun):
     @property
     def ks(self) -> np.ndarray:
         return self.inp.ks
+
+    @property
+    def path(self) -> Optional[str]:
+        """The path the solve took (a slow cycle's record names it)."""
+        return "multipass" if self.mp_passes else self.select
 
 
 class _Bucket:
@@ -546,20 +554,16 @@ class ResidentServingCore:
     def _flush_gate(self, sp, gate: Optional[Tuple]) -> None:
         """Read back a batch's gated-tile count (``gate``: a scalar, or
         a mesh engine's one count a cell, summed here, with the tiles
-        its fold visited: a host sync, after the result fetch) into the
-        gate gauges and the span."""
+        its fold visited: a host sync, after the result fetch) into
+        ``last_gated_fraction`` and the span."""
         if gate is None:
             return
         gz, ntiles = gate
         try:
-            got = jax.device_get(gz)  # check: allow-host-sync
+            with obs_trace.device_wait("gate", self.trace_batch):
+                got = jax.device_get(gz)  # check: allow-host-sync
             gated = int(np.sum(got))
-            frac = gated / max(ntiles, 1)
-            self.last_gated_fraction = frac
-            reg = telemetry.registry()
-            reg.gauge("serve.gate.gated_fraction").set(round(frac, 6))
-            reg.counter("serve.gate.tiles_total").inc(ntiles)
-            reg.counter("serve.gate.tiles_gated").inc(gated)
+            self.last_gated_fraction = gated / max(ntiles, 1)
             sp.set(gated=gated, tiles=ntiles)
         except Exception:  # check: no-retry — stats never fail a batch
             pass
@@ -628,8 +632,9 @@ class ResidentServingCore:
             # resident chunks the folds dispatch over, so the host must
             # read it before enqueueing them — O(blocks) bytes, priced
             # by the analytic score model, nothing like a result fetch.
-            return np.asarray(
-                jax.device_get(mask))  # check: allow-host-sync
+            with obs_trace.device_wait("prune_score", self.trace_batch):
+                return np.asarray(
+                    jax.device_get(mask))  # check: allow-host-sync
 
     # -- memory-model hooks (admission + memwatch read these) ---------------
 
@@ -782,7 +787,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         reg = telemetry.registry()
         reg.gauge("serve.corpus_rows").set(n)
         reg.gauge("serve.capacity_rows").set(self.capacity_rows)
-        reg.gauge("serve.gate.carry_enabled").set(int(self.gate_carry))
 
     # -- shape buckets --------------------------------------------------------
 
@@ -1191,10 +1195,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             # single.fetch).
             sp.set(dispatches=1, chunks=len(order),
                    kernel_dispatch_ms=round(ms, 3), throttle_wait_ms=0.0)
-        # Closed by _before_fetch, where _run_finish starts the
-        # readback: the epilogue's enqueues run on into _run_begin, and
-        # with another batch in flight the span also covers that batch's
-        # second half, which the host runs in between.
+        # serve.solve_epilogue: closed where the first half ends
+        # (_run_begin), after the epilogue's enqueues.
         pend.epilogue_pc = clock()
         pend.gate = (gated, ntiles)
         pend.kernel_calls = len(order)
@@ -1209,11 +1211,19 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         top = _extract_finalize(od, oi, self._d_labels, k=entry.kcap)
         return top, entry.qpad
 
-    def _before_fetch(self, pend: PendingBatch, t_pc: float) -> None:
+    def _run_begin(self, pend: PendingBatch) -> None:
+        """The first half, and the end of ``serve.solve_epilogue``:
+        from the fold's (the multipass merge's) dispatch to here the
+        host only enqueues (the label gather and sort, the boundary
+        columns). The span belongs to the first half alone: it never
+        reaches into a second half, its own or another batch's."""
+        super()._run_begin(pend)
         e0, pend.epilogue_pc = pend.epilogue_pc, None
         if e0 is not None:
-            obs_trace.complete_at("serve.solve_epilogue", e0, t_pc,
-                                  **self._rid_args())
+            obs_trace.complete_at("serve.solve_epilogue", e0,
+                                  time.perf_counter(), **self._rid_args())
+
+    def _before_fetch(self, pend: PendingBatch) -> None:
         if pend.mp_fence is not None:
             self._mp_fetch(pend)
 
@@ -1247,30 +1257,35 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         kern, impl = pallas_fused.resolve_topk_kernel(
             entry.qpad, self._ex_chunk_rows, self._ex_attrs, kc,
             rung=self._degrade_rung)
-        if kern is None:
+        if kern is None:    # before any span: the fallback opens its own
             return None
-        prec = active_precision(self)  # plan-clamped; outside the jits
-        cr = self._ex_chunk_rows
-        # Passes 2+ sweep the whole stack in ONE kernel call: the
-        # variant resolved for that row count must tile it, or the
-        # solve stops here, before anything is dispatched.
-        full_rows = self._ex_nchunks * cr
-        _kern_full, impl_full = resolve_sweep_kernel(
-            entry.qpad, full_rows, self._ex_attrs, kc, chunk_rows=cr,
-            rung=self._degrade_rung, precision=prec)
-        npasses = -(-kcap // kc)
-        nq = inp.params.num_queries
-        na = self.num_attrs
-        n = self.n_real
-        nchunks = -(-n // cr)
-        q_dev = self._stage_batch_queries(inp, entry.qpad)
-        pend.select = "extract"
-        pend.extract_impl = impl
-        pend.variant = self._variant_stamp(impl, kc, entry.qpad, prec)
-        sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
-                                self._ex_attrs, prec, self._interpret)
-        floor_args = dict(staging=self._staging, na=na, precision=prec)
         targs = self._rid_args()
+        # What the extract path's serve.solve_stage holds: the sweep's
+        # variant resolved and the batch's queries on the device,
+        # nothing dispatched yet.
+        with obs_span("serve.solve_stage", qpad=entry.qpad, **targs):
+            prec = active_precision(self)  # plan-clamped; outside the jits
+            cr = self._ex_chunk_rows
+            # Passes 2+ sweep the whole stack in ONE kernel call: the
+            # variant resolved for that row count must tile it, or the
+            # solve stops here, before anything is dispatched.
+            full_rows = self._ex_nchunks * cr
+            _kern_full, impl_full = resolve_sweep_kernel(
+                entry.qpad, full_rows, self._ex_attrs, kc, chunk_rows=cr,
+                rung=self._degrade_rung, precision=prec)
+            npasses = -(-kcap // kc)
+            nq = inp.params.num_queries
+            na = self.num_attrs
+            n = self.n_real
+            nchunks = -(-n // cr)
+            q_dev = self._stage_batch_queries(inp, entry.qpad)
+            pend.select = "extract"
+            pend.extract_impl = impl
+            pend.variant = self._variant_stamp(impl, kc, entry.qpad, prec)
+            sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
+                                    self._ex_attrs, prec, self._interpret)
+            floor_args = dict(staging=self._staging, na=na,
+                              precision=prec)
         t_begin = time.perf_counter()
         # Every pass is enqueued without a readback (the floors chain
         # on the device): serve.mp_pass and serve.mp_merge time the
@@ -1281,12 +1296,15 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             od, oi, _gated, _tiles = self._fold_resident(
                 q_dev, range(nchunks), impl, kc, prec)
         ods, ois = [od], [oi]
-        qn_host = np.zeros(entry.qpad, np.float64)
-        qn_host[:nq] = np.einsum("qa,qa->q", inp.query_attrs,
-                                 inp.query_attrs)
-        qn_dev = jax.device_put(np.asarray(qn_host, np.float32))
-        dn_dev, n_dev = jax.device_put((np.float32(self._dn_max()),
-                                        np.int32(n)))
+        # The floor chain's scalars (query norms, the corpus's largest
+        # norm, n), put while pass 1 runs: the device has its work first.
+        with obs_span("serve.mp_norms", **targs):
+            qn_host = np.zeros(entry.qpad, np.float64)
+            qn_host[:nq] = np.einsum("qa,qa->q", inp.query_attrs,
+                                     inp.query_attrs)
+            qn_dev = jax.device_put(np.asarray(qn_host, np.float32))
+            dn_dev, n_dev = jax.device_put((np.float32(self._dn_max()),
+                                            np.int32(n)))
         fds = []
         for p in range(2, npasses + 1):
             with obs_span("serve.mp_pass", kc=kc, rows=n,
@@ -1305,9 +1323,12 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             top, valid = _mp_merge(jnp.concatenate(ods, axis=1),
                                    jnp.concatenate(ois, axis=1),
                                    self._d_labels, kcap=kcap)
-        # serve.solve_multipass runs from here to the end of the fence
-        # (_mp_fetch), so that it holds the batch's kernel events; with
-        # another batch in flight it holds that batch's second half too.
+        # serve.solve_multipass runs from t_begin to the end of the
+        # fence (_mp_fetch), so that it holds the batch's kernel events:
+        # it CROSSES batches (with another batch in flight it holds that
+        # batch's second half too); serve.solve_epilogue, from here to
+        # the end of the first half, does not.
+        pend.epilogue_pc = time.perf_counter()
         pend.mp_fence = (t_begin, [valid] + fds,
                          dict(qpad=entry.qpad, kcap=kcap, passes=npasses,
                               impl=impl, queries=nq, chunks=nchunks))
@@ -1323,12 +1344,15 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     def _mp_fetch(self, pend: PendingBatch) -> None:
         """The multipass driver's ONE fence: the fd chain (stall check)
         and the final valid counts (shortfall check); the boundary
-        repair makes both exact."""
+        repair makes both exact. Closes ``serve.solve_multipass``,
+        which began with the batch's first enqueue in the FIRST half:
+        a span that crosses batches when another is in flight."""
         (t_begin, fence, args), pend.mp_fence = pend.mp_fence, None
         inp = pend.inp
         nq = inp.params.num_queries
         targs = self._rid_args()
-        with obs_span("serve.mp_fetch", **targs):
+        with obs_span("serve.mp_fetch", site="mp_fetch", **targs), \
+                obs_trace.device_wait("mp_fetch", span=False):
             fetched = resilient_get(fence)
         valid_h, fd_h = fetched[0], fetched[1:]
         stalled = np.zeros(args["qpad"], bool)

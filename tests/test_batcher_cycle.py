@@ -1,0 +1,583 @@
+"""The batcher's own account of its time (PR 35).
+
+The batcher thread's timeline is cut at the end of every delivery into
+cycles, one a micro-batch, each split exactly into the thread's own
+work, its waits on the device and its waits for requests; a ring keeps
+the slow ones; one ``gc.callbacks`` hook notes the collector's pauses
+without taking a lock; the handler times a request's line from its
+first byte. Held here by structure (what tiles what, which part a
+delay lands in), with injected delays large against the CPU's noise.
+"""
+
+from __future__ import annotations
+
+import gc
+import json
+import socket
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.io.grammar import KNNInput, Params
+from dmlp_tpu.obs import telemetry
+from dmlp_tpu.obs import trace as obs_trace
+from dmlp_tpu.resilience import inject
+from dmlp_tpu.resilience.inject import FaultSchedule
+from dmlp_tpu.serve import batching
+from dmlp_tpu.serve.admission import AdmissionController
+from dmlp_tpu.serve.batching import MicroBatcher, Request
+from dmlp_tpu.serve.daemon import PHASE_HISTOGRAMS, ServeDaemon
+from dmlp_tpu.serve.engine import ResidentEngine
+
+NA = 4
+NQ = 6          # queries a request, and the batch cap: a request a batch
+WAIT = 300
+PARTS = ("own_ms", "device_wait_ms", "queue_wait_ms")
+#: the second half of a micro-batch on the one-chip engine
+SECOND_HALF = ["single.fetch", "single.hazard", "single.finalize",
+               "serve.after_batch", "serve.batch_deliver"]
+
+
+def corpus_of(n: int, seed: int = 3) -> KNNInput:
+    rng = np.random.default_rng(seed)
+    return KNNInput(Params(n, 0, NA),
+                    rng.integers(0, 5, n).astype(np.int32),
+                    rng.uniform(-10, 10, (n, NA)),
+                    np.zeros(0, np.int32), np.zeros((0, NA)))
+
+
+def stream_engine() -> ResidentEngine:
+    return ResidentEngine(corpus_of(600), EngineConfig())
+
+
+def extract_engine() -> ResidentEngine:
+    return ResidentEngine(corpus_of(14000), EngineConfig(
+        select="extract", use_pallas=True, data_block=12800))
+
+
+def request(i: int, rng) -> Request:
+    return Request(kind="query", req_id=f"r{i}",
+                   query_attrs=rng.uniform(-10, 10, (NQ, NA)),
+                   ks=np.full(NQ, 4, np.int32))
+
+
+def batcher_for(eng) -> MicroBatcher:
+    return MicroBatcher(eng, AdmissionController(eng),
+                        max_batch_queries=NQ, tick_s=0.0)
+
+
+def serve_one(b: MicroBatcher, r: Request) -> Request:
+    assert b.submit(r)["verdict"] == "accept"
+    assert r.done.wait(timeout=WAIT) and r.error is None, r.error
+    return r
+
+
+def closed(b: MicroBatcher, n: int):
+    """``stats.batcher`` once the batcher has closed its n-th cycle (a
+    request is answered inside the cycle, a moment before its end)."""
+    deadline = time.monotonic() + 30
+    while b.cycles < n and time.monotonic() < deadline:
+        time.sleep(0.001)
+    return b.cycle_stats()
+
+
+def delays(site: str, ms: int, times: int = 1) -> FaultSchedule:
+    return FaultSchedule.from_dict({"schema": 1, "seed": 1, "faults": [
+        {"site": site, "kind": "delay", "ms": ms, "times": times,
+         "prob": 1.0}]})
+
+
+def spans_of(tracer):
+    return [e for e in tracer.events() if e.get("ph") == "X"]
+
+
+def named(spans, name, **args):
+    return [e for e in spans if e["name"] == name
+            and all(e["args"].get(k) == v for k, v in args.items())]
+
+
+def end(e) -> float:
+    return e["ts"] + e["dur"]
+
+
+# -- the split, and the tiling, in both orders --------------------------------
+
+@pytest.fixture(scope="module")
+def flows():
+    """The extract engine under a tracer, twice: four requests queued
+    before the batcher starts (the pipeline engages: batches 2..4 are
+    begun behind another), and four served one after the other with
+    the queue empty 60 ms in between (the serial order)."""
+    out = {}
+    rng = np.random.default_rng(11)
+    for order in ("pipelined", "serial"):
+        eng = extract_engine()
+        eng.warmup([(NQ, 4)])
+        b = batcher_for(eng)
+        reqs = [request(i, rng) for i in range(4)]
+        tracer = obs_trace.install(obs_trace.Tracer())
+        try:
+            if order == "pipelined":
+                for r in reqs:
+                    assert b.submit(r)["verdict"] == "accept"
+                b.start()
+                for r in reqs:
+                    assert r.done.wait(timeout=WAIT) and r.error is None
+            else:
+                b.start()
+                for r in reqs:
+                    serve_one(b, r)
+                    time.sleep(0.06)
+            b.stop(drain=True)
+        finally:
+            obs_trace.uninstall()
+        out[order] = {"spans": spans_of(tracer), "cycles": b.cycles}
+    return out
+
+
+@pytest.mark.parametrize("order", ["pipelined", "serial"])
+def test_a_cycle_is_split_exactly_three_ways(flows, order):
+    cycles = named(flows[order]["spans"], "serve.cycle")
+    assert len(cycles) == flows[order]["cycles"] == 4
+    for c in cycles:
+        a = c["args"]
+        assert abs(c["dur"] / 1e3 - sum(a[p] for p in PARTS)) < 1e-3
+        assert all(a[p] >= 0 for p in PARTS) and a["gc_ms"] >= 0
+        assert (a["queries"], a["requests"]) == (NQ, 1)
+
+
+@pytest.mark.parametrize("order", ["pipelined", "serial"])
+def test_cycles_tile_the_batcher_thread(flows, order):
+    """One cycle a batch, in the order of delivery, on one thread; the
+    next starts where the last ended (to the microsecond's rounding)."""
+    cycles = named(flows[order]["spans"], "serve.cycle")
+    assert [c["args"]["batch"] for c in cycles] == [1, 2, 3, 4]
+    assert len({c["tid"] for c in cycles}) == 1
+    for a, b in zip(cycles, cycles[1:]):
+        assert abs(b["ts"] - end(a)) < 1e-3, (end(a), b["ts"])
+
+
+def test_the_pipelined_order_begins_the_next_batch_inside_the_cycle(flows):
+    cycles = named(flows["pipelined"]["spans"], "serve.cycle")
+    assert [c["args"]["overlapped"] for c in cycles] == [0, 1, 1, 1]
+    # the first cycle began batch 1 and, behind it, batch 2; the last
+    # found nothing to begin
+    assert [c["args"]["begun"] for c in cycles] == [2, 3, 4, 0]
+    for c in cycles[:-1]:
+        (stage,) = named(flows["pipelined"]["spans"], "serve.solve_stage",
+                         batch=c["args"]["begun"])
+        assert c["ts"] <= stage["ts"] and end(stage) <= end(c)
+
+
+def test_the_serial_order_waits_for_requests_inside_the_cycle(flows):
+    cycles = named(flows["serial"]["spans"], "serve.cycle")
+    assert [c["args"]["overlapped"] for c in cycles] == [0, 0, 0, 0]
+    assert [c["args"]["begun"] for c in cycles] == [1, 2, 3, 4]
+    # the queue stood empty 60 ms before each of the later requests
+    for c in cycles[1:]:
+        assert c["args"]["queue_wait_ms"] >= 55
+        waits = [w for w in named(flows["serial"]["spans"],
+                                  "serve.wait.queue")
+                 if c["ts"] <= w["ts"] and end(w) <= end(c) + 1e-3]
+        assert waits and abs(sum(w["dur"] for w in waits) / 1e3
+                             - c["args"]["queue_wait_ms"]) < 1e-3
+
+
+@pytest.mark.parametrize("order", ["pipelined", "serial"])
+def test_device_waits_are_spans_with_their_site_and_sum_to_the_part(
+        flows, order):
+    """Every host sync lies under a span that names its ``site``: the
+    seam's own where it has one around exactly the wait
+    (``single.fetch``), ``serve.wait.device`` elsewhere; never both."""
+    spans = flows[order]["spans"]
+    for c in named(spans, "serve.cycle"):
+        waits = [w for w in spans if "site" in w.get("args", ())
+                 and w["tid"] == c["tid"] and c["ts"] <= w["ts"]
+                 and end(w) <= end(c) + 1e-3]
+        by_site = {w["args"]["site"]: w["name"] for w in waits}
+        assert by_site.items() >= {"fetch": "single.fetch",
+                                   "gate": "serve.wait.device"}.items()
+        waits.sort(key=lambda w: w["ts"])       # one span a wait
+        assert all(end(a) <= b["ts"] for a, b in zip(waits, waits[1:]))
+        assert all(w["args"]["batch"] in (c["args"]["batch"],
+                                          c["args"]["begun"])
+                   for w in waits)
+        # (a seam's own span opens a call before the bracket inside it)
+        assert 0 <= sum(w["dur"] for w in waits) / 1e3 \
+            - c["args"]["device_wait_ms"] < 0.2
+
+
+def test_the_epilogue_ends_with_the_first_half(flows):
+    """``serve.solve_epilogue`` of batch N ends before any span of batch
+    N - 1's second half starts: it no longer crosses batches."""
+    spans = flows["pipelined"]["spans"]
+    for n in (2, 3, 4):
+        (epi,) = named(spans, "serve.solve_epilogue", batch=n)
+        (stage,) = named(spans, "serve.solve_stage", batch=n)
+        assert end(stage) <= epi["ts"]
+        older = [e for name in SECOND_HALF
+                 for e in named(spans, name, batch=n - 1)]
+        assert len(older) == len(SECOND_HALF)
+        assert end(epi) <= min(e["ts"] for e in older)
+        # ... while the span that DOES cross still holds them
+        (mb,) = named(spans, "serve.micro_batch", batch=n)
+        assert mb["ts"] <= min(e["ts"] for e in older) \
+            and max(end(e) for e in older) <= end(mb)
+
+
+# -- the multipass driver's first half ------------------------------------------
+
+def multipass_engine() -> ResidentEngine:
+    """k = 300 over 900 rows: the k512 bucket's two passes."""
+    return ResidentEngine(corpus_of(900, seed=41), EngineConfig(
+        select="extract", use_pallas=True, dtype="float32"))
+
+
+def solve_traced(eng, k: int = 300):
+    rng = np.random.default_rng(7)
+    tracer = obs_trace.install(obs_trace.Tracer())
+    try:
+        eng.solve_batch(rng.uniform(-10, 10, (NQ, NA)),
+                        np.full(NQ, k, np.int32))
+    finally:
+        obs_trace.uninstall()
+    return spans_of(tracer)
+
+
+def test_the_multipass_first_half_is_tiled_in_the_order_it_enqueues():
+    """Staged, pass 1 dispatched, and only then the floor chain's
+    scalars put (the device has its work first), the later passes, the
+    merge, the epilogue: one span each, none inside another, all inside
+    the span that crosses batches."""
+    eng = multipass_engine()
+    eng.warmup([(NQ, 300)])
+    spans = solve_traced(eng)
+    assert eng.last_mp_passes == 2
+    chain = [e for e in spans if e["name"] in (
+        "serve.solve_stage", "serve.mp_pass", "serve.mp_norms",
+        "serve.mp_merge", "serve.solve_epilogue")]
+    chain.sort(key=lambda e: e["ts"])
+    assert [(e["name"], e.get("args", {}).get("pass")) for e in chain] == [
+        ("serve.solve_stage", None), ("serve.mp_pass", 1),
+        ("serve.mp_norms", None), ("serve.mp_pass", 2),
+        ("serve.mp_merge", None), ("serve.solve_epilogue", None)]
+    assert all(end(a) <= b["ts"] + 1e-3 for a, b in zip(chain, chain[1:]))
+    (whole,) = named(spans, "serve.solve_multipass")
+    assert end(chain[0]) <= whole["ts"] + 1e-3      # staged before it
+    assert whole["ts"] <= chain[1]["ts"] + 1e-3
+
+
+def test_a_refused_multipass_leaves_no_stage_span_behind(monkeypatch):
+    """No kernel for the bucket: the driver says so before it opens a
+    span, and the batch's spans are the fallback's alone."""
+    from dmlp_tpu.ops import pallas_fused
+    eng = multipass_engine()
+    monkeypatch.setattr(pallas_fused, "resolve_topk_kernel",
+                        lambda *a, **kw: (None, None))
+    spans = solve_traced(eng)
+    assert not named(spans, "serve.solve_stage")
+    assert not named(spans, "serve.mp_pass")
+    assert len(named(spans, "serve.solve_stream")) == 1
+
+
+# -- where a delay lands, and the ring -----------------------------------------
+
+@pytest.fixture(scope="module")
+def slowed():
+    """A batcher past its first 32 cycles, then one straggler at
+    ``serve.solve`` (the batcher's own 150 ms), one delayed readback
+    (``single.fetch``: 150 ms inside the host-sync bracket), then 20
+    more stragglers; a straggler among the first 32 before all that."""
+    eng = stream_engine()
+    eng.warmup([(NQ, 4)])
+    # as a daemon does: the running median is this lifetime's
+    telemetry.registry().reset(prefix="serve")
+    b = batcher_for(eng)
+    rng = np.random.default_rng(5)
+    out = {}
+    tracer = obs_trace.install(obs_trace.Tracer())
+    b.start()
+    try:
+        inject.install(delays("serve.solve", 150))
+        serve_one(b, request(0, rng))
+        for i in range(1, batching.SLOW_WARM_CYCLES):
+            serve_one(b, request(i, rng))
+        out["warm"] = closed(b, batching.SLOW_WARM_CYCLES)
+        inject.install(delays("serve.solve", 150))
+        out["own"] = serve_one(b, request(100, rng)).batch
+        inject.install(delays("single.fetch", 150))
+        out["device"] = serve_one(b, request(101, rng)).batch
+        out["two"] = closed(b, batching.SLOW_WARM_CYCLES + 2)
+        inject.install(delays("serve.solve", 60, times=20))
+        for i in range(20):
+            serve_one(b, request(200 + i, rng))
+        out["ring"] = closed(b, batching.SLOW_WARM_CYCLES + 22)
+    finally:
+        inject.uninstall()
+        b.stop(drain=True)
+        obs_trace.uninstall()
+    out["spans"] = spans_of(tracer)
+    out["instants"] = [e for e in tracer.events() if e.get("ph") == "i"]
+    return out
+
+
+def test_the_ring_is_empty_for_the_first_32_cycles(slowed):
+    assert slowed["warm"] == {"cycles": batching.SLOW_WARM_CYCLES,
+                              "slow_cycles": []}
+    (first,) = named(slowed["spans"], "serve.cycle", batch=1)
+    assert first["args"]["own_ms"] >= 150       # slow, and not kept
+
+
+def test_a_straggler_lands_in_own_and_in_the_ring(slowed):
+    (c,) = named(slowed["spans"], "serve.cycle", batch=slowed["own"])
+    assert c["args"]["own_ms"] >= 150
+    assert c["args"]["device_wait_ms"] < 100
+    rec = slowed["two"]["slow_cycles"][0]
+    assert rec["batch"] == slowed["own"] and rec["own_ms"] >= 150
+    assert rec["cycle_ms"] > 3 * rec["median_ms"]
+    assert rec["queries"] == NQ and rec["requests"] == 1
+    assert rec["overlapped"] == 0 and rec["queue_depth"] == 0
+    assert rec["path"] and rec["unix_time"] > 1e9 and rec["gc_ms"] >= 0
+    assert abs(rec["cycle_ms"] - sum(rec[p] for p in PARTS)) < 0.01
+    # with a Tracer the record is an instant too
+    assert [e for e in slowed["instants"]
+            if e["name"] == "serve.slow_cycle"
+            and e["args"]["batch"] == slowed["own"]]
+
+
+def test_a_delayed_readback_lands_in_device_wait_with_its_site(slowed):
+    (c,) = named(slowed["spans"], "serve.cycle", batch=slowed["device"])
+    assert c["args"]["device_wait_ms"] >= 150
+    assert c["args"]["own_ms"] < 100
+    (w,) = named(slowed["spans"], "single.fetch", site="fetch",
+                 batch=slowed["device"])
+    assert w["dur"] / 1e3 >= 150        # the injected fault is inside
+    rec = slowed["two"]["slow_cycles"][1]
+    assert rec["batch"] == slowed["device"]
+    assert rec["device_wait_ms"] >= 150
+    assert rec["device_wait_sites_ms"]["fetch"] >= 150
+
+
+def test_the_ring_is_bounded(slowed):
+    ring = slowed["ring"]["slow_cycles"]
+    assert len(ring) == batching.SLOW_RING == 16
+    assert slowed["ring"]["cycles"] == batching.SLOW_WARM_CYCLES + 22
+    serials = [r["batch"] for r in ring]
+    assert serials == sorted(serials) and serials[-1] == \
+        slowed["ring"]["cycles"]        # the newest 16, newest last
+
+
+# -- the collector --------------------------------------------------------------
+
+def ask(port, obj, idle_s: float = 0.0):
+    with socket.create_connection(("127.0.0.1", port), timeout=60) as s:
+        f = s.makefile("rwb")
+        if idle_s:
+            time.sleep(idle_s)
+        line = (json.dumps(obj) + "\n").encode()
+        f.write(line)
+        f.flush()
+        return json.loads(f.readline()), len(line)
+
+
+def query(corpus, nq=3):
+    return {"op": "query", "id": "q", "k": 4, "rid": "r-1",
+            "queries": corpus.data_attrs[:nq].tolist()}
+
+
+def gen2():
+    reg = telemetry.registry()
+    return (reg.counter("runtime.gc_pause_ms").value("gen2"),
+            reg.counter("runtime.gc_collections").value("gen2"))
+
+
+@pytest.fixture(scope="module")
+def traced_daemon():
+    """One daemon under a tracer: a request sent after 300 ms of an
+    open, idle connection, and a request whose second half forces a
+    full collection."""
+    corpus = corpus_of(600)
+    tracer = obs_trace.install(obs_trace.Tracer())
+    out = {"hook_before": telemetry.gc_pauses()._on_gc in gc.callbacks}
+    daemon = ServeDaemon(corpus, EngineConfig(), warm_buckets=[(3, 4)])
+    try:
+        daemon.start()
+        out["hook_serving"] = telemetry.gc_pauses()._on_gc in gc.callbacks
+        resp, out["line_bytes"] = ask(daemon.port, query(corpus),
+                                      idle_s=0.3)
+        assert resp["ok"], resp
+        finish = daemon.engine.finish_batch
+
+        def collecting(pending):
+            gc.collect()
+            return finish(pending)
+        daemon.engine.finish_batch = collecting
+        out["gen2_before"] = gen2()
+        resp, _ = ask(daemon.port, query(corpus))
+        assert resp["ok"], resp
+        daemon.engine.finish_batch = finish
+        closed(daemon.batcher, 2)
+        out["stats"] = daemon.stats()
+        out["gen2_after"] = gen2()
+        out["openmetrics"] = telemetry.registry().to_openmetrics()
+    finally:
+        daemon.close()
+        obs_trace.uninstall()
+    out["hook_after"] = telemetry.gc_pauses()._on_gc in gc.callbacks
+    out["spans"] = spans_of(tracer)
+    return out
+
+
+def test_a_forced_collection_shows_in_the_cycle_the_counters_and_a_span(
+        traced_daemon):
+    (c,) = named(traced_daemon["spans"], "serve.cycle", batch=2)
+    assert c["args"]["gc_ms"] > 0
+    assert c["args"]["gc_ms"] <= c["args"]["own_ms"]    # on this thread
+    (ms0, n0), (ms1, n1) = (traced_daemon["gen2_before"],
+                            traced_daemon["gen2_after"])
+    assert n1 >= n0 + 1 and ms1 > ms0
+    full = [g for g in named(traced_daemon["spans"], "runtime.gc",
+                             generation=2)
+            if c["ts"] <= g["ts"] and end(g) <= end(c)]
+    assert full and full[0]["args"]["thread"] == "serve-batcher"
+    assert full[0]["args"]["collected"] >= 0
+    assert abs(sum(g["dur"] for g in named(traced_daemon["spans"],
+                                           "runtime.gc")
+                   if c["ts"] <= end(g) <= end(c)) / 1e3
+               - c["args"]["gc_ms"]) < 1e-2
+
+
+def test_the_hook_lives_as_long_as_the_daemon_serves(traced_daemon):
+    assert traced_daemon["hook_serving"] is True
+    assert traced_daemon["hook_after"] is traced_daemon["hook_before"] \
+        is False
+
+
+def test_the_callback_takes_no_lock():
+    """A collection that starts under ``Registry._lock`` and the
+    tracer's lock, held by the collecting thread itself (an allocation
+    inside ``Registry._get`` or ``Tracer._append``), returns."""
+    tracer = obs_trace.install(obs_trace.Tracer())
+    watch = telemetry.gc_pauses()
+    watch.install()
+    done = threading.Event()
+
+    def collect_under_the_locks():
+        with telemetry.registry()._lock, tracer._lock:
+            gc.collect()
+        done.set()
+    try:
+        n0 = gen2()[1]
+        t = threading.Thread(target=collect_under_the_locks, daemon=True)
+        t.start()
+        assert done.wait(timeout=30), "the gc callback waits for a lock"
+        watch.drain()
+        assert gen2()[1] >= n0 + 1
+        assert named(spans_of(tracer), "runtime.gc", generation=2)
+    finally:
+        watch.remove()
+        obs_trace.uninstall()
+    assert watch._on_gc not in gc.callbacks
+
+
+def test_notes_nobody_drains_are_bounded():
+    """A daemon that closes no cycle (ingest only) and is asked for no
+    stats keeps the newest notes, not all of them."""
+    watch = telemetry.GcPauses()
+    for _ in range(5000):
+        watch._on_gc("start", {})
+        watch._on_gc("stop", {"generation": 0, "collected": 0})
+    assert len(watch._pending) == watch._pending.maxlen == 4096
+    assert watch.total_s > 0
+
+
+# -- the line read ---------------------------------------------------------------
+
+def test_the_read_phase_starts_at_the_first_byte(traced_daemon):
+    first, second = named(traced_daemon["spans"], "serve.phase.read",
+                          rid="r-1")
+    assert first["args"]["bytes"] == traced_daemon["line_bytes"]
+    assert first["args"]["batch"] == 1 and second["args"]["batch"] == 2
+    assert first["dur"] / 1e3 < 200     # 300 ms idle are not in it
+    (parse,) = named(traced_daemon["spans"], "serve.phase.parse", batch=1)
+    assert abs(parse["ts"] - end(first)) < 1e-3     # parse keeps its start
+    assert traced_daemon["stats"]["phases_ms"]["request"]["read"][
+        "count"] == 2
+
+
+# -- always on, and exported ----------------------------------------------------
+
+@pytest.fixture(scope="module")
+def untraced():
+    """Three micro-batches through a batcher with no sink installed,
+    every span constructor counted."""
+    assert not obs_trace.sinks_active()
+    built = []
+    inits = {cls: cls.__init__
+             for cls in (obs_trace.Span, obs_trace._TelemetrySpan)}
+
+    def counting(cls):
+        def init(self, *a, **kw):
+            built.append(cls.__name__)
+            inits[cls](self, *a, **kw)
+        return init
+    eng = stream_engine()
+    eng.warmup([(NQ, 4)])
+    telemetry.registry().reset(prefix="serve")
+    b = batcher_for(eng)
+    rng = np.random.default_rng(9)
+    for cls in inits:
+        cls.__init__ = counting(cls)
+    try:
+        b.start()
+        for i in range(3):
+            serve_one(b, request(i, rng))
+        b.stop(drain=True)
+        null = obs_trace.span("serve.cycle")
+    finally:
+        for cls, init in inits.items():
+            cls.__init__ = init
+    reg = telemetry.registry()
+    return {"built": built, "null": null, "stats": b.cycle_stats(),
+            "counts": {name: reg.get(name).count
+                       for _key, name in PHASE_HISTOGRAMS["cycle"]}}
+
+
+def test_no_sink_builds_no_span_and_the_histograms_still_count(untraced):
+    assert untraced["built"] == []
+    assert untraced["null"] is obs_trace.NULL_SPAN
+    assert untraced["stats"] == {"cycles": 3, "slow_cycles": []}
+    assert untraced["counts"] == {
+        "serve.cycle_ms": 3, "serve.cycle_ms.own": 3,
+        "serve.cycle_ms.device_wait": 3, "serve.cycle_ms.queue_wait": 3}
+
+
+@pytest.mark.parametrize("key", ["cycle", "own", "device_wait",
+                                 "queue_wait"])
+def test_stats_reports_the_cycle(traced_daemon, key):
+    stats = traced_daemon["stats"]
+    assert stats["batcher"] == {"cycles": 2, "slow_cycles": []}
+    got = stats["phases_ms"]["cycle"][key]
+    assert got["count"] == 2 and 0 <= got["p50"] <= got["p95"]
+    assert stats["phases_ms"]["cycle"]["cycle"]["p95"] >= got["p95"]
+
+
+@pytest.mark.parametrize("series", [
+    "serve_cycle_ms_count 2", "serve_cycle_ms_own_count 2",
+    "serve_cycle_ms_device_wait_count 2",
+    "serve_cycle_ms_queue_wait_count 2", "serve_phase_ms_read_count 2",
+    'runtime_gc_pause_ms_total{key="gen2"}',
+    'runtime_gc_collections_total{key="gen2"}'])
+def test_openmetrics_carries_the_new_series(traced_daemon, series):
+    text = traced_daemon["openmetrics"]
+    assert telemetry.validate_openmetrics(text) == []
+    assert any(line.startswith(series) for line in text.splitlines())
+
+
+def test_the_gate_series_and_the_tracer_counter_are_gone(traced_daemon):
+    assert "_gate_" not in traced_daemon["openmetrics"]
+    assert not hasattr(obs_trace.Tracer, "counter")
+    assert not hasattr(obs_trace, "counter")

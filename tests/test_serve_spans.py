@@ -10,6 +10,7 @@ import itertools
 import json
 import re
 import socket
+import time
 
 import jax
 import jax.numpy as jnp
@@ -263,8 +264,9 @@ def test_the_fold_is_one_program_a_batch(profiled_batch):
 
 
 def test_no_eager_gate_counter_between_stage_and_fetch(profiled_batch):
-    """From the start of serve.solve_stage to the readback (where
-    serve.solve_epilogue ends) the host dispatches the prune scorer,
+    """From the start of serve.solve_stage to the readback (the first
+    half, which serve.solve_epilogue closes) the host dispatches the
+    prune scorer,
     the fold, and the epilogue's two programs: no per-chunk
     ``jit_equal`` / ``jit__reduce_sum`` / ``jit_add``."""
     at = profiled_batch["at"]
@@ -292,7 +294,14 @@ def untraced():
         null_while_serving = obs_trace.span("serve.micro_batch")
         for _ in range(3):
             assert ask(daemon.port, query(corpus))["ok"]
-        stats = ask(daemon.port, {"op": "stats"})["stats"]
+        # a response reaches its client a moment before its handler
+        # notes the write and the batcher closes the cycle
+        for _ in range(200):
+            stats = ask(daemon.port, {"op": "stats"})["stats"]
+            if stats["phases_ms"]["request"]["write"]["count"] == 3 \
+                    and stats["batcher"]["cycles"] == 3:
+                break
+            time.sleep(0.01)
     finally:
         daemon.close()
     return {"stats": stats, "span": null_while_serving}
