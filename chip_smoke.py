@@ -8,6 +8,8 @@ binary (``oracle_capture/oracle_4.out``):
 
   generate  python -m dmlp_tpu.io.datagen            sha256 pinned
   batch     python -m dmlp_tpu --pallas ...          cmp oracle
+  batch.f32 ... --dtype float32                      cmp oracle; the first
+                                                     pass ran as "bf16x3"
   serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
                                                      stats, SIGTERM drain
   mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
@@ -21,7 +23,17 @@ from what the child itself wrote (the device stamp in the ``--metrics``
 summary, the daemon's ready file and ``stats`` reply). Any miss — a child
 on another platform, a Pallas kernel in interpret mode, a degrade-ladder
 rung below the first, a retry, a mesh that left the corpus on one
-device — is a non-zero exit naming the check, and no result line.
+device, a float32 first pass that did not take the three-pass form — is
+a non-zero exit naming the check, and no result line.
+
+``batch.f32`` stages float32, as the served cells of the benchmark do
+(the default on a chip stages bfloat16, which has no low half to split).
+An engine names "bf16x3" only after ops.pallas_extract.split_holds has
+run the split through Mosaic on THIS chip and found |x - hi - lo| <=
+2^-17 |x|; so the form in the child's record is that check's answer, and
+a compiler that starts folding the casts (as XLA:TPU does outside a
+kernel) fails here instead of running a one-pass error under the
+three-pass bound, which no checksum shows.
 
 The configs run in the order given (default ``1,4``): config 1 is the
 same path at a size that takes seconds, so a machine with no chip fails
@@ -214,9 +226,11 @@ def phase_generate(c: Config) -> List[str]:
 
 
 def phase_solve(c: Config, name: str, mode_args: List[str],
-                mesh: Optional[List[int]], ladder: bool
+                mesh: Optional[List[int]], ladder: bool,
+                form: Optional[str] = None
                 ) -> Tuple[List[str], Optional[Dict[str, Any]]]:
-    """A batch solve through ``python -m dmlp_tpu``; (misses, stamp)."""
+    """A batch solve through ``python -m dmlp_tpu``; (misses, stamp).
+    ``form``: the first-pass form the child's record must name."""
     metrics = c.log(f"{name}.metrics.jsonl")
     if os.path.exists(metrics):
         os.remove(metrics)
@@ -241,6 +255,12 @@ def phase_solve(c: Config, name: str, mode_args: List[str],
             bad.append("stdout differs from the captured reference "
                        "output")
     bad += check_stamp(stamp, mesh, ladder, c.cfg.num_data)
+    ran = (summary.get("precision") or {}).get("active")
+    if form is not None and ran != form:
+        bad.append(f"the first pass ran as {ran!r}, not {form!r}: the "
+                   "split check (ops.pallas_extract.split_holds) "
+                   f"refused it on this compiler:\n"
+                   f"{tail(c.log(name + '.err'))}")
     return bad, stamp if isinstance(stamp, dict) else None
 
 
@@ -345,6 +365,9 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
     misses += [f"config {config_id} batch: {m}" for m in bad]
     if stamp is None:
         return misses, None
+    bad, _ = phase_solve(c, "batch.f32", ["--dtype", "float32"], None,
+                         ladder=True, form="bf16x3")
+    misses += [f"config {config_id} batch.f32: {m}" for m in bad]
     misses += [f"config {config_id} serve: {m}" for m in phase_serve(c)]
     from dmlp_tpu.config import EngineConfig
     chips = stamp.get("device_count", 0)

@@ -73,16 +73,23 @@ class EngineConfig:
         build of the reference (common.cpp:72-78).
       use_pallas: use the fused Pallas distance kernel where available.
       precision: FIRST-PASS dot precision for the extract-path kernels
-        ("auto" | "f32" | "bf16"). "bf16" casts the streamed q/d tiles
-        before the MXU dot (one pass vs HIGHEST-precision f32's ~3)
-        with f32 accumulation kept; the engines widen every candidate
-        window / prune threshold / hazard test by the analytic
+        ("auto" | "f32" | "bf16"). "f32" is a float32 cross term. In
+        exact mode at float32 staging the kernel computes it from bf16
+        halves of its operands in THREE MXU passes with f32
+        accumulation (the "bf16x3" form, ops.pallas_extract._dot_cross)
+        where one ``HIGHEST`` dot makes Mosaic spend six; what the
+        three drop is at most 2^-15 of the scale a distance
+        (engine.finalize.LOWP_COEF). Fast mode, whose device ordering
+        IS the answer, keeps the one ``HIGHEST`` dot, and so do
+        operands staged in bfloat16 (a bf16 value has no low half).
+        "bf16" casts the streamed q/d tiles before the MXU dot: ONE
+        pass. Either way the engines widen every candidate window /
+        prune threshold / hazard test by the form's analytic
         engine.finalize.lowp_eps bound so the unchanged f64 rescore +
         boundary repair keeps results byte-identical to the f32 dense
-        scan. Active only in exact mode on the resilience ladder's top
-        "lowp" rung (fast mode's output IS the device ordering — no
-        repair backstop). "auto" resolves to "f32" (opt-in: the win is
-        MXU throughput, which a CPU container cannot show).
+        scan. "bf16" is active only in exact mode on the resilience
+        ladder's top "lowp" rung (fast mode has no repair backstop).
+        "auto" resolves to "f32" (the one-pass form is opt-in).
         $DMLP_TPU_PRECISION overrides at resolve time ("f32" = kill
         switch, "bf16" = force). int8 is the gated follow-on (ROADMAP):
         its bound needs data-dependent quantization scales.
@@ -133,24 +140,49 @@ class EngineConfig:
         return ("bfloat16" if jax.devices()[0].platform == "tpu"
                 else "float32")
 
-    def resolve_precision(self) -> str:
-        """Concrete first-pass precision ("f32" | "bf16") for this run,
-        env override included: ``$DMLP_TPU_PRECISION`` wins when set to
-        a legal value ("f32" doubles as the kill switch, "bf16" forces
-        the low-precision pass on), else the configured value, with
-        "auto" resolving to "f32". Read per call (no import-time
-        snapshot) so tests and operators can flip the env without
-        re-imports — the engines resolve it OUTSIDE every jit and key
-        their compiled programs on the result (R2 discipline). Fast
-        mode always runs "f32": the low-precision pass is only sound
-        with the f64 rescore + boundary repair behind it."""
+    def f32_form(self, staging: str | None = None) -> str:
+        """The form a float32 first pass takes ("bf16x3" | "f32"):
+        three bf16 MXU passes over split operands where a float64
+        rescore and a repair stand behind the pass (exact mode), the
+        operands have a low half to split (``staging`` "float32"; the
+        engine's, resolve_dtype() when None) and the backend's compiler
+        makes the split as written (ops.pallas_extract.split_holds:
+        one small kernel on the device, once a process); else the one
+        ``HIGHEST`` dot."""
+        if not self.exact:
+            return "f32"
+        if staging is None:
+            staging = self.resolve_dtype()
+        if staging != "float32":
+            return "f32"
+        from dmlp_tpu.ops.pallas_extract import split_holds
+        return "bf16x3" if split_holds() else "f32"
+
+    def resolve_precision(self, staging: str | None = None,
+                          allow_bf16: bool = True) -> str:
+        """Concrete first-pass form ("f32" | "bf16x3" | "bf16") for
+        this run, env override included: ``$DMLP_TPU_PRECISION`` wins
+        when set to a legal value ("f32" doubles as the kill switch,
+        "bf16" forces the one-pass form on), else the configured value,
+        with "auto" resolving to "f32"; and "f32" means f32_form's
+        answer for ``staging`` (an engine passes its own), which is
+        also what "bf16" gives way to where the caller cannot run it
+        (``allow_bf16`` False: off the ladder's top rung, or under
+        windows planned for another form). Read per
+        call (no import-time snapshot) so tests and operators can flip
+        the env without re-imports — the engines resolve it OUTSIDE
+        every jit and key their compiled programs on the result (R2
+        discipline). Fast mode always runs "f32", the one ``HIGHEST``
+        dot: a form that drops products is only sound with the f64
+        rescore + boundary repair behind it."""
         import os
         if not self.exact:
             return "f32"
         env = os.environ.get("DMLP_TPU_PRECISION")
-        if env in ("f32", "bf16"):
-            return env
-        return "f32" if self.precision == "auto" else self.precision
+        prec = env if env in ("f32", "bf16") else self.precision
+        if prec == "bf16" and allow_bf16:
+            return "bf16"
+        return self.f32_form(staging)
 
     def resolve_select(self, padded_rows: int) -> str:
         """Concrete selection strategy for a dataset of ``padded_rows``."""

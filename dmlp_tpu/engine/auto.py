@@ -328,7 +328,10 @@ class AutoShardedEngine(ShardedEngine):
         # Precision resolves outside the jit; run() already swapped the
         # staging dtype when the bf16 first pass applies, so the ACTIVE
         # record is whatever the solve actually stages with.
-        prec = self.config.resolve_precision()
+        # (No Pallas kernel here, so no split form: a float32 pass is
+        # ops.distance's one HIGHEST dot, "f32", in either mode.)
+        prec = "bf16" if self.config.resolve_precision() == "bf16" \
+            else "f32"
         self.last_precision = {
             "active": "bf16" if (prec == "bf16"
                                  and self._staging == "bfloat16")

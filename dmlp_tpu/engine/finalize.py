@@ -119,27 +119,65 @@ EPS_CANCEL_COEF = 3.0 * 2.0 ** -22
 #: a bound on the MAGNITUDE scale, independent of the distance itself
 #: (unlike staging_eps term 1, which shrinks with sqrt(dist)). The
 #: coefficient folds the 2u, the second-order u^2, and a 2x safety
-#: slack: 2^-6 = 8 * 2^-9 >= (2*2^-8 + 2^-16) * 2. f32 is the exact
-#: pass (zero cast error — the f32 accumulation itself is already
-#: covered by the EPS_CANCEL_COEF term everywhere this composes).
-#: tests/test_precision.py fuzzes the bound with directed adversarial
+#: slack: 2^-6 = 8 * 2^-9 >= (2*2^-8 + 2^-16) * 2. "f32" is the one
+#: ``HIGHEST`` dot (zero cast error — the f32 accumulation itself is
+#: already covered by the EPS_CANCEL_COEF term everywhere this
+#: composes).
+#:
+#: "bf16x3" (PR 36; ops.pallas_extract.split_bf16 and _dot_cross):
+#: each operand is x = hi + lo + r with hi = bf16(x), lo = bf16(x - hi)
+#: (the difference is exact in float32), both round-to-nearest. With
+#: 2^e <= |x| < 2^(e+1): |x - hi| <= 2^(e-8) (half a bf16 ulp), so
+#: |lo| <= 2^(e-8) too (rounding is monotone and 2^(e-8) is a bf16),
+#: and unless x - hi IS 2^(e-8) (then r = 0) it lies in a binade no
+#: higher than e - 9, whose half ulp is 2^(e-17): |x - hi| <= u|x|,
+#: |lo| <= u|x|, |r| <= (u^2 / 2)|x| with u = 2^-8 (checked over every
+#: float32 of a binade: 0.9961 u, 0.9961 u, 0.9980 u^2 / 2). The kernel
+#: sums q_hi.d_hi + q_hi.d_lo + q_lo.d_hi, whose bf16 x bf16 products
+#: are exact in float32, and drops
+#:     q_lo.d_lo + q_r.d + (q - q_r).d_r,
+#: at most (u^2 + u^2/2 + (1 + u^2/2) u^2/2)|q_i||d_i| an attribute,
+#: (2u^2 + u^4/4)|q||d| a dot (Cauchy-Schwarz), so the norm-expansion
+#: distance errs by at most (2u^2 + u^4/4)(qn + dn) (AM-GM, as above):
+#: 2^-15 of the scale, one-sided. Every test this composes into
+#: compares TWO such distances (the k-th candidate's and a missed
+#: row's; a threshold's and a tile's), so the coefficient is twice
+#: that, 2^-14 (1 + 2^-19), rounded up to 2^-14 (1 + 2^-16) and no
+#: further: it is the derivation's bound, not a calibration, and every
+#: slot of clearance it takes is taken from the hazard test (6.1e-5 of
+#: the scale beside the cancellation term's 9.3e-5 at 128 attributes,
+#: 6.9e-4 at 960). ISSUE 36 derived 3 * 2^-15 from |r| <= u^2 |x|; the
+#: half-ulp argument above halves r. The ACCUMULATION (3A products in
+#: float32, partial sums at most (1 + 2u)|q||d|) stays inside
+#: staging_eps term 2 even if every addition rounds the same way:
+#: 3A (1 + 2u) * 2^-24 (qn + dn) one-sided for the dot, (A + 3) * 2^-24
+#: for the two norms and the expansion's three additions; two-sided
+#: (8A + 6)(1 + 2u) * 2^-24 <= 12 (A + 2) * 2^-24 = EPS_CANCEL_COEF *
+#: (A + 2), a third to spare (the six passes' 6A products had none by
+#: this count: 14A + 6). (A float32 so small that x - hi underflows is
+#: flushed, an ABSOLUTE 2^-126 an attribute: no relative bound, here or
+#: in term 2, counts it.)
+#: tests/test_precision.py fuzzes both bounds with directed adversarial
 #: magnitude-cancellation corpora. int8 has NO entry: an int8 pass
 #: needs data-dependent quantization scales, so its bound cannot be a
 #: static coefficient — the ROADMAP follow-on.
-LOWP_COEF = {"f32": 0.0, "bf16": 2.0 ** -6}
+LOWP_COEF = {"f32": 0.0, "bf16x3": 2.0 ** -14 * (1.0 + 2.0 ** -16),
+             "bf16": 2.0 ** -6}
 
 
 def lowp_eps(precision: str, qn: np.ndarray, dn_max: float) -> np.ndarray:
     """Per-query bound on the distance perturbation a low-precision
-    FIRST PASS (ops.pallas_extract with ``precision != "f32"``) can add
+    FIRST PASS (ops.pallas_extract with ``precision != "f32"``: the
+    split "bf16x3" form every exact engine runs, or one "bf16" pass) can add
     on top of the staging/f32 terms: ``LOWP_COEF[precision] * (qn +
     dn_max)``. Composes ADDITIVELY with :func:`staging_eps` (the cast
     error of the pass dtype and the staging/accumulation errors act on
     the same computed distance, so their bounds sum) at every decision
     the low-precision distances feed: the truncation-hazard test, the
     prune thresholds, the MXU-gate bound, and the multi-pass floor.
-    Zero for the exact "f32" pass. Raises KeyError on a precision with
-    no static bound (int8 — see LOWP_COEF)."""
+    Zero for the one-dot "f32" pass; 2^-14 of the scale for the
+    three-pass "bf16x3" form, 2^-6 for one bf16 pass. Raises KeyError on
+    a precision with no static bound (int8 — see LOWP_COEF)."""
     coef = LOWP_COEF[precision]
     if not coef:
         return np.zeros_like(np.asarray(qn, np.float64))
@@ -174,9 +212,15 @@ def staging_eps(last: np.ndarray, qn: np.ndarray, dn_max: float,
     Comparing the k-th candidate against a potentially missed point
     doubles both bounds; the constants fold the doubling, sqrt(2), a
     >= 1.4x second-order slack, and (term 2) u32 = 2^-22 covering the
-    MXU's HIGHEST-precision 3-pass product error on top of f32
-    accumulation. ``dn_max`` (max squared data-row norm, f64) bounds
-    |x|^2 over every point, known or missed.
+    float32 ACCUMULATION of the cross term, the norms and the
+    expansion (LOWP_COEF's comment counts it for the 3A products of
+    the "bf16x3" form). It does NOT cover products the first pass
+    drops: an ``HIGHEST`` dot drops none (Mosaic emulates float32 in
+    six bf16 passes, not the three this comment once assumed), and
+    what the three-pass and one-pass forms drop is lowp_eps' to
+    bound, added to this at every site. ``dn_max`` (max squared
+    data-row norm, f64) bounds |x|^2 over every point, known or
+    missed.
     """
     rel = EPS_REL_BF16 if staging == "bfloat16" else EPS_REL_F32
     scale = qn + dn_max

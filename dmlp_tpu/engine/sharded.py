@@ -890,7 +890,7 @@ class ShardedEngine:
         # have no resilience ladder, so the config-resolved precision
         # (resolve_precision returns "f32" in fast mode) IS the active
         # one; _run widens its hazard eps to match.
-        prec = self.config.resolve_precision()
+        prec = self.config.resolve_precision(self._staging)
         self.last_precision = {"active": prec, "configured": prec}
         out = self._solve_chunked_extract(inp,
                                           allow_prune=self.config.exact,
@@ -1101,12 +1101,13 @@ class ShardedEngine:
                         np.asarray(dists[:, -1], np.float64), qn, dn_max,
                         self._staging, inp.params.num_attrs)
                     prec = (self.last_precision or {}).get("active", "f32")
-                    if prec == "bf16" and select == "extract":
-                        # The bf16 first pass perturbs device distances
-                        # beyond the staging model; the hazard test must
-                        # not trust a boundary the low-precision dot
-                        # could have reordered (finalize.lowp_eps).
-                        eps = eps + lowp_eps("bf16", qn, dn_max)
+                    if select == "extract":
+                        # A first pass that drops products ("bf16x3",
+                        # "bf16") perturbs device distances beyond the
+                        # staging model; the hazard test must not
+                        # trust a boundary it could have reordered
+                        # (finalize.lowp_eps of the form that ran).
+                        eps = eps + lowp_eps(prec, qn, dn_max)
                     suspects = np.nonzero(
                         boundary_overflow(dists, sub.ks, eps))[0]
                     if suspects.size:

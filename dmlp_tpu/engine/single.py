@@ -226,7 +226,7 @@ def resolve_kcap(cfg: EngineConfig, kmax: int, select: str, cap: int,
     slots of slack the cell's control repaired about a query a batch,
     ~4.5 s each."""
     if precision is None:
-        precision = cfg.resolve_precision()
+        precision = cfg.resolve_precision(staging)
     extra = cfg.margin if cfg.exact else 0
     if select in ("sort", "topk", "seg", "extract"):
         extra = max(extra, 8)
@@ -393,19 +393,27 @@ def staging_for_k(engine, kmax: int):
 
 
 def active_precision(engine) -> str:
-    """First-pass dot precision THIS dispatch actually runs at.
+    """First-pass form a solve ON the resilience ladder runs at
+    ("f32" | "bf16x3" | "bf16"; ops.pallas_extract._dot_cross): what
+    run() and the serving engines hand their solve.
 
-    "bf16" only when all three hold: the config resolves to it
-    (config.resolve_precision — ``$DMLP_TPU_PRECISION`` included), the
-    solve is exact (the f64 rescore + boundary repair are the backstop
-    that makes a lossy first pass sound; fast ordering has none), and
-    the resilience ladder still sits on its top "lowp" rung — the first
-    OOM step-down gives the low-precision pass (and, on the next plan,
-    its inflated window) back before anything else. Resolved OUTSIDE
-    every jit and passed as a static argument, so every compiled
-    program keys on the result (R2 discipline). Candidate windows
-    deliberately do NOT consult this: resolve_kcap plans from the
-    CONFIGURED precision so the window stays static across rungs.
+    A form that drops products needs the backstop that makes it sound,
+    the f64 rescore + boundary repair of an exact run.
+    candidates() and run_device_full() have none (their output is the
+    device ordering): they do not ask here and solve at the default,
+    the one ``HIGHEST`` dot, as fast mode does. An exact run's float32
+    pass takes config.f32_form's answer for the engine's staging:
+    three bf16 passes at float32 staging, on every rung. "bf16", ONE
+    pass, needs more: the config resolves to it
+    (config.resolve_precision — ``$DMLP_TPU_PRECISION`` included) and
+    the ladder still sits on its top "lowp" rung — the first OOM
+    step-down gives the one-pass form (and, on the next plan, its
+    inflated window) back before anything else, for the float32 form.
+    Resolved OUTSIDE every jit and passed as a static argument, so
+    every compiled program keys on the result (R2 discipline).
+    Candidate windows deliberately do NOT consult this: resolve_kcap
+    plans from the CONFIGURED precision so the window stays static
+    across rungs.
 
     Engines that freeze a precision PLAN at construction (the resident
     serving engines — their bucket kcaps and staged summary-eps
@@ -414,15 +422,10 @@ def active_precision(engine) -> str:
     under a server whose windows were planned f32 cannot run a lossy
     pass against uninflated windows. (The f32 flip under a bf16 plan
     is always safe: wider-than-needed windows only.)"""
-    if getattr(engine, "_degrade_rung", "fused") != "lowp":
-        return "f32"
-    cfg = engine.config
-    if not cfg.exact:
-        return "f32"
-    plan = getattr(engine, "_precision_plan", None)
-    if plan is not None and plan != "bf16":
-        return "f32"
-    return cfg.resolve_precision()
+    return engine.config.resolve_precision(
+        engine._staging,
+        allow_bf16=getattr(engine, "_degrade_rung", "fused") == "lowp"
+        and getattr(engine, "_precision_plan", "bf16") == "bf16")
 
 
 @functools.partial(jax.jit,
@@ -523,9 +526,10 @@ def _mp_floor(od, qn, dn_max, *, staging: str, na: int,
     readback (an inter-pass sync would serialize the passes). Ports
     finalize.staging_eps: floor = max(od) - eps(max(od)); exhausted rows
     (max = inf) get floor = +inf so later passes yield empty lists.
-    A "bf16" first pass deepens the eps by the finalize.lowp_eps term
-    (the floor must clear the cast error too, or a later pass could
-    skip a candidate the low-precision dot pushed below the boundary).
+    A first pass that drops products ("bf16x3", "bf16") deepens the
+    eps by its finalize.lowp_eps term (the floor must clear that error
+    too, or a later pass could skip a candidate the dot pushed below
+    the boundary).
     Returns (floor (Q, 1) f32, fd (Q,) f32 for post-hoc stall checks)."""
     from dmlp_tpu.engine.finalize import (EPS_CANCEL_COEF, EPS_REL_BF16,
                                           EPS_REL_F32, LOWP_COEF)
@@ -658,17 +662,18 @@ class SingleChipEngine:
     def _staging_itemsize(self) -> int:
         return 2 if self._staging == "bfloat16" else 4
 
-    def _plan_prune(self, inp: KNNInput, nchunks: int, chunk_rows: int):
+    def _plan_prune(self, inp: KNNInput, nchunks: int, chunk_rows: int,
+                    prec: str = "f32"):
         """Stage 0+1 of the pruned two-stage solve for a chunked
         driver: (survivor chunk schedule, plan stats | None). Active
         only on the resilience ladder's top ``lowp``/``prune`` rungs
         (run() enters at "lowp"; candidates()/run_device_full stay
         dense — fast ordering has no repair backstop), in exact mode,
         with the ``DMLP_TPU_PRUNE`` kill switch on, and when there is
-        more than one block to choose between. On the "lowp" rung with
-        precision resolving to "bf16" the prune thresholds widen by
-        the finalize.lowp_eps cast bound — a block must stay pruned
-        under the error the low-precision first pass could add. The
+        more than one block to choose between. The prune thresholds
+        widen by the finalize.lowp_eps bound of the form that will
+        run (active_precision) — a block must stay pruned under the
+        error the first pass could add. The
         schedule preserves natural chunk order, so ChunkThrottle
         backpressure and the affine-id contract are untouched — pruned
         blocks are simply never staged."""
@@ -687,7 +692,7 @@ class SingleChipEngine:
             summ = osum.build_summaries(inp.data_attrs, ranges)
             keep, stats = osum.prune_mask(inp.query_attrs, inp.ks, summ,
                                           staging=self._staging,
-                                          precision=active_precision(self))
+                                          precision=prec)
         schedule = [c for c in dense if keep[c]]
         if not schedule:       # belt: prune_mask guarantees a survivor
             return dense, None
@@ -744,7 +749,8 @@ class SingleChipEngine:
         return TopK(out.dists.reshape(qpad, -1), out.labels.reshape(qpad, -1),
                     out.ids.reshape(qpad, -1)), qpad
 
-    def _solve_pipelined(self, inp: KNNInput) -> Tuple[TopK, int]:
+    def _solve_pipelined(self, inp: KNNInput,
+                         prec: str = "f32") -> Tuple[TopK, int]:
         """Chunked staging + one fold dispatch per chunk ("topk"/"seg").
 
         The dataset is staged in ~chunk_rows-row pieces, each followed by
@@ -795,7 +801,8 @@ class SingleChipEngine:
         # survivor schedule (pruned two-stage solve) composes here: a
         # pruned chunk is never staged, so its bytes never cross the
         # host->device link at all.
-        schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows)
+        schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows,
+                                                 prec)
         carries = [init_topk(qsb, k) for _ in range(nqb)]
         src_attrs = np.ascontiguousarray(inp.data_attrs, np.float32)
         throttle = ChunkThrottle()
@@ -843,7 +850,8 @@ class SingleChipEngine:
         return TopK(*(jnp.concatenate(parts) for parts in
                       zip(*carries))), qpad
 
-    def _solve_extract(self, inp: KNNInput) -> Tuple[TopK, int] | None:
+    def _solve_extract(self, inp: KNNInput,
+                       prec: str = "f32") -> Tuple[TopK, int] | None:
         """Chunked staging + the fused extraction kernel (select="extract").
 
         Each ~50k-row chunk is staged asynchronously and folded into the
@@ -887,13 +895,13 @@ class SingleChipEngine:
         if kern is None:
             return None
         interpret = pallas_interpret()
-        prec = active_precision(self)
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
             impl, k, chunk_rows, qpad, na, prec)
 
-        schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows)
+        schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows,
+                                                 prec)
         live = [c for c in schedule if c * chunk_rows < n]
         q_attrs = np.zeros((qpad, na), np.float32)
         q_attrs[:nq] = inp.query_attrs
@@ -952,7 +960,7 @@ class SingleChipEngine:
     _MP_MAX_PASSES = 16
     _MP_KC = 512  # slots per pass — the kernel's widest tuned window
 
-    def _solve_extract_multipass(self, inp: KNNInput):
+    def _solve_extract_multipass(self, inp: KNNInput, prec: str = "f32"):
         """All-wide-k solve on the extraction kernel in P floor-raised
         passes (round-4 review item 2).
 
@@ -1022,7 +1030,6 @@ class SingleChipEngine:
         # (resolve_sweep_kernel asserts it, before anything is staged).
         n_staged = min(nchunks, -(-n // chunk_rows))
         full_rows = n_staged * chunk_rows
-        prec = active_precision(self)
         kern_full, impl_full = resolve_sweep_kernel(
             qpad, full_rows, na, kc, chunk_rows=chunk_rows,
             rung=self._degrade_rung, precision=prec)
@@ -1151,7 +1158,8 @@ class SingleChipEngine:
     def _flush_measured_iters(self) -> None:
         flush_measured_iters(self)
 
-    def _solve(self, inp: KNNInput) -> Tuple[TopK, int]:
+    def _solve(self, inp: KNNInput,
+               prec: str = "f32") -> Tuple[TopK, int]:
         self.last_phase_ms = {}  # no stale phases if a path is skipped
         self._pending_iters = []
         self.last_extract_impl = self.last_variant = None
@@ -1164,19 +1172,20 @@ class SingleChipEngine:
         # extract-kernel dispatch: the chunk-fold driver below holds no
         # running-list kernel state and its live tile is one slab.
         if select == "extract" and self._degrade_rung != "streaming":
-            out = self._solve_extract(inp)
+            out = self._solve_extract(inp, prec)
             if out is not None:
                 return out
             # shape untileable for the extraction kernel — fall through to
             # the chunk-fold driver on the best remaining path
-        return self._solve_pipelined(inp)
+        return self._solve_pipelined(inp, prec)
 
     def _plan_hetk(self, inp: KNNInput):
         return hetk_split(self.config, self._staging, inp.ks,
                           inp.params.num_data,
                           round_up(max(inp.params.num_data, 1), 8))
 
-    def _solve_extract_routed(self, inp: KNNInput, plan):
+    def _solve_extract_routed(self, inp: KNNInput, plan,
+                              prec: str = "f32"):
         """Split solve: extraction kernel for the bulk queries + streaming
         fold for the huge-k outliers, sharing one staging pass.
 
@@ -1212,7 +1221,6 @@ class SingleChipEngine:
         ko = resolve_kcap(cfg, int(inp.ks[outl].max()), select_out,
                           nchunks * chunk_rows, staging=self._staging)
         interpret = pallas_interpret()
-        prec = active_precision(self)
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
@@ -1235,7 +1243,8 @@ class SingleChipEngine:
         # The prune plan covers BOTH query sets (bulk and outliers ride
         # the same per-query ks), so the shared staging sweep may only
         # skip a chunk no query of either segment can need.
-        schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows)
+        schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows,
+                                                 prec)
         live_sched = [c for c in schedule if c * chunk_rows < n]
         carry_o = init_topk(qo_pad, ko)
         src_attrs = np.ascontiguousarray(inp.data_attrs, np.float32)
@@ -1282,7 +1291,8 @@ class SingleChipEngine:
         return [(top_b, qpad_b, bulk, "extract"),
                 (carry_o, qo_pad, outl, select_out)]
 
-    def _solve_segments(self, inp: KNNInput, allow_multipass: bool = True):
+    def _solve_segments(self, inp: KNNInput, allow_multipass: bool = True,
+                        prec: str = "f32"):
         """Solve as a list of (TopK, qpad, query_idx | None, select)
         segments — one segment for homogeneous k, two when the
         heterogeneous-k router splits huge-k outliers off the extraction
@@ -1306,15 +1316,15 @@ class SingleChipEngine:
         plan = None if streaming else self._plan_hetk(inp)
         if plan is not None:
             self.last_phase_ms = {}
-            segs = self._solve_extract_routed(inp, plan)
+            segs = self._solve_extract_routed(inp, plan, prec)
             if segs is not None:
                 return segs
         if allow_multipass and not streaming:
             self.last_phase_ms = {}
-            segs = self._solve_extract_multipass(inp)
+            segs = self._solve_extract_multipass(inp, prec)
             if segs is not None:
                 return segs
-        top, qpad = self._solve(inp)
+        top, qpad = self._solve(inp, prec)
         return [(top, qpad, None, self._last_select)]
 
     def candidates(self, inp: KNNInput) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -1365,7 +1375,7 @@ class SingleChipEngine:
         segments. A batch solve keeps its own state on the engine (one
         run is alive at a time) and hands the record what the second
         half reads of it."""
-        segments = self._solve_segments(pend.inp)
+        segments = self._solve_segments(pend.inp, prec=pend.prec)
         pend.mp_hazard = self._mp_hazard
         pend.phase_ms = self.last_phase_ms
         return segments
@@ -1377,6 +1387,7 @@ class SingleChipEngine:
         inp = pend.inp
         n = inp.params.num_data
         memwatch.note_engine_model(self, inp)
+        pend.prec = active_precision(self)
         segments = self._enqueue(pend)
         # Watermark tick at peak residency: the solve is enqueued, the
         # staged chunks/carries are live, nothing is fetched yet (no-op
@@ -1386,12 +1397,11 @@ class SingleChipEngine:
         # at, and how many window slots the bound inflation bought the
         # rescore (kcap minus what an f32-precision plan would have
         # sized — 0 whenever precision resolves to "f32").
-        pend.prec = active_precision(self)
         kcap0 = int(segments[0][0].dists.shape[1])
         kmax0 = int(inp.ks.max()) if inp.params.num_queries else 0
         pend.precision = {
             "active": pend.prec,
-            "configured": self.config.resolve_precision(),
+            "configured": self.config.resolve_precision(self._staging),
             "kcap": kcap0,
             "kcap_inflation": kcap0 - resolve_kcap(
                 self.config, kmax0, segments[0][3], kcap0,
@@ -1469,13 +1479,15 @@ class SingleChipEngine:
                                    sub.query_attrs)
                     eps = staging_eps(last, qn, dn_max, self._staging,
                                       inp.params.num_attrs)
-                    if prec == "bf16" and select == "extract":
-                        # The low-precision first pass perturbs device
-                        # distances by up to lowp_eps ON TOP of the
-                        # staging rounding; the hazard test must clear
-                        # both. Streaming-fallback segments never cast,
-                        # so their eps stays the staging bound alone.
-                        eps = eps + lowp_eps("bf16", qn, dn_max)
+                    if select == "extract":
+                        # A first pass that drops products ("bf16x3",
+                        # "bf16") perturbs device distances by up to
+                        # the lowp_eps of the form that ran ON TOP of
+                        # the staging rounding; the hazard test must
+                        # clear both. Streaming-fallback segments never
+                        # split or cast, so their eps stays the staging
+                        # bound alone.
+                        eps = eps + lowp_eps(prec, qn, dn_max)
                     flags = boundary_hazard(kth, last, eps)
                     # How many times its bound the window clears, for
                     # the batch's tightest query: 1 or less is a flag.

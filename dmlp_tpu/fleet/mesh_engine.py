@@ -148,7 +148,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # cast both derive from it (_active_prec clamps the per-batch
         # resolve to the plan), so an env flip mid-serve can disable
         # the bf16 pass but never run it against f32-planned windows.
-        self._precision_plan = cfg.resolve_precision()
+        self._precision_plan = cfg.resolve_precision(self._staging)
         self.last_precision = None
         # Cross-request fused-gate warm-up, mesh edition (ROADMAP
         # follow-on (e)): the single-chip hot-block histogram doesn't
@@ -428,8 +428,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         (env kill switch included, read per call) clamped to the
         construction-time plan. No resilience ladder here — the mesh
         engines solve without run_ladder — so there is no rung gate."""
-        prec = self.config.resolve_precision()
-        return prec if prec == self._precision_plan == "bf16" else "f32"
+        return self.config.resolve_precision(
+            self._staging, allow_bf16=self._precision_plan == "bf16")
 
     def _build_bucket(self, qpad: int, kb: int) -> _MeshBucket:
         _r, c = self.mesh.devices.shape
@@ -741,11 +741,13 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                 eps = staging_eps(
                     np.asarray(dists[:, -1], np.float64), qn, dn_max,
                     self._staging, self.num_attrs)
-                if prec == "bf16" and self._last_select == "extract":
-                    # The bf16 first pass perturbs device distances
-                    # beyond the staging model — widen the hazard test
-                    # by its analytic bound (finalize.lowp_eps).
-                    eps = eps + lowp_eps("bf16", qn, dn_max)
+                if self._last_select == "extract":
+                    # A first pass that drops products ("bf16x3",
+                    # "bf16") perturbs device distances beyond the
+                    # staging model — widen the hazard test by the
+                    # bound of the form that ran (finalize.lowp_eps;
+                    # zero for the one HIGHEST dot).
+                    eps = eps + lowp_eps(prec, qn, dn_max)
                 suspects = np.nonzero(
                     boundary_overflow(dists, inp.ks, eps))[0]
                 hz.set(flagged=int(suspects.size))

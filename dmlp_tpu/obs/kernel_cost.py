@@ -48,16 +48,23 @@ __all__ = ["extract_topk_cost", "extract_loop_cost", "fused_topk_cost",
            "two_pass_equivalent_cost", "fused_dist_segmin_cost",
            "summaries_score_cost", "analytic_cost", "MXU_PASSES"]
 
-#: MXU hardware passes per dot tile by first-pass precision: the MXU
-#: multiplies in bf16, so an f32 dot at HIGHEST preferred precision
-#: decomposes into ~3 bf16 product passes (the bf16x3 scheme), while a
-#: "bf16" first pass (ops.pallas_* ``precision="bf16"``, f32
-#: accumulation) issues ONE. The ``flops`` fields below deliberately do
+#: MXU hardware passes per dot tile by first-pass form: the MXU
+#: multiplies in bf16, so an f32 dot at HIGHEST preferred precision is
+#: emulated in SIX bf16 product passes (Mosaic's
+#: ``contract_precision<fp32>``; measured on v5e in PR 36: the dot
+#: alone, inside a pallas_call with the kernel's BlockSpecs, took
+#: 13.6 us at a (128, 128) x (12 800, 128) visit and 62.1 us at
+#: (128, 1024) x (6 400, 1024), 6.4 and 7.3 times one pass at the
+#: MXU's peak, where every form of three passes or one sat on the
+#: data block's DMA, 9.2 and 36.1 us: PERF.md section 6), the "bf16x3"
+#: form (ops.pallas_extract._dot_cross: bf16 halves of both operands)
+#: issues THREE, and a "bf16" first pass (``precision="bf16"``, f32
+#: accumulation) ONE. The ``flops`` fields below deliberately do
 #: NOT scale by this — they keep XLA's dot convention (2*Q*B*A
 #: regardless of precision) so flops stay comparable across arms and
 #: history; the pass count is reported alongside as ``mxu_passes`` /
 #: ``mxu_precision`` for roofline math that wants hardware-issue terms.
-MXU_PASSES = {"f32": 3, "bf16": 1}
+MXU_PASSES = {"f32": 6, "bf16x3": 3, "bf16": 1}
 
 
 def _variant_resolver(kernel: str):
@@ -142,14 +149,14 @@ def extract_topk_cost(qb: int, b: int, a: int, kc: int,
     ``iters_total`` the data-dependent while-loop is excluded
     (deterministic lower bound); with it, the measured extraction term
     (:func:`extract_loop_cost`) is added and the dict says so.
-    ``precision`` ("f32" | "bf16") keys the tile resolution and is
-    reported back with its MXU pass count (:data:`MXU_PASSES`) —
+    ``precision`` ("f32" | "bf16x3" | "bf16") keys the tile resolution
+    and is reported back with its MXU pass count (:data:`MXU_PASSES`) —
     ``flops`` itself keeps the precision-independent dot convention."""
     base = _streaming_cost(qb, b, a, kc, precision=precision)
     out = {"flops": base["flops"], "bytes_accessed": base["bytes_accessed"],
            "extraction_term": "modeled_lower_bound",
            "mxu_precision": precision,
-           "mxu_passes": MXU_PASSES.get(precision, 3)}
+           "mxu_passes": MXU_PASSES[precision]}
     if iters_total is not None:
         out["flops"] += extract_loop_cost(qb, b, a, kc, iters_total,
                                           precision=precision)
@@ -198,7 +205,7 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
         "flops": flops, "bytes_accessed": byts,
         "extraction_term": "modeled_lower_bound",
         "mxu_precision": precision,
-        "mxu_passes": MXU_PASSES.get(precision, 3),
+        "mxu_passes": MXU_PASSES[precision],
         "hbm_bytes_two_pass_equiv": tp["bytes_accessed"],
         "hbm_bytes_saved_vs_two_pass": tp["bytes_accessed"] - byts,
         "hbm_traffic_reduction_x": round(tp["bytes_accessed"] / byts, 2),

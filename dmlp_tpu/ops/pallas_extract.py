@@ -186,9 +186,9 @@ def _resolve_variant(kc: int, b: int, qb: int | None = None,
     bucket(a), kc, precision) (dmlp_tpu.tune.lookup_variant — never
     raises, and rejects entries whose ne-alignment cannot tile this b),
     else the deterministic heuristic. ``precision`` is a cache key
-    axis, never a tiling constraint: a bf16 first pass spends one MXU
-    pass per tile where f32 spends ~3, which moves the winning tile
-    but not what CAN tile, so the heuristic fallback is shared. When
+    axis, never a tiling constraint: one bf16 pass, the split form's
+    three or an ``HIGHEST`` dot's six (_dot_cross) move the winning
+    tile but not what CAN tile, so the heuristic fallback is shared. When
     the caller knows the full dispatch shape (qb, a), a cached variant
     must ALSO pass variant_supports (VMEM bound included) or
     resolution falls back — a cache entry may downgrade resolution to
@@ -241,26 +241,133 @@ def supports(qb: int, b: int, a: int, kc: int) -> bool:
     return variant_supports(qb, b, a, kc, _resolve_variant(kc, b, qb, a))
 
 
+#: first-pass forms the kernel knows (engine.finalize.LOWP_COEF has each
+#: one's bound; config.EngineConfig.resolve_precision picks one)
+PRECISIONS = ("f32", "bf16x3", "bf16")
+
+
+def split_bf16(x):
+    """``x`` (float32) as two bfloat16 halves (hi, lo): hi = bf16(x),
+    lo = bf16(x - hi) (the difference is exact), so x = hi + lo + r
+    with |r| <= 2^-17 |x| (engine.finalize.LOWP_COEF derives it).
+    For use INSIDE a kernel: Mosaic makes both casts as written
+    (measured on v5e, PR 36: residuals 0.996 * 2^-8 and 0.998 * 2^-17
+    of |x|). XLA:TPU does not: it takes f32 -> bf16 -> f32 for the
+    identity (excess precision), so the same lines in a jitted
+    prologue gave lo = 0 on the chip, the one-pass form's error under
+    the three-pass form's bound, while every CPU test passed."""
+    hi = x.astype(jnp.bfloat16)  # check: lowp-eps=lowp_eps
+    rest = x - hi.astype(jnp.float32)
+    return hi, rest.astype(jnp.bfloat16)  # check: lowp-eps=lowp_eps
+
+
+def _split_in_kernel(x, interpret: bool):
+    """split_bf16 of one (16, 128) float32 tile made by a kernel of its
+    own: what split_holds looks at."""
+    def kern(x_ref, hi_ref, lo_ref):
+        hi_ref[...], lo_ref[...] = split_bf16(x_ref[...])
+
+    half = jax.ShapeDtypeStruct(x.shape, jnp.bfloat16)
+    return pl.pallas_call(kern, out_shape=[half, half],
+                          name="dmlp_split_check", interpret=interpret)(x)
+
+
+@functools.lru_cache(maxsize=None)
+def split_holds() -> bool:
+    """Whether THIS process's backend makes split_bf16's casts as
+    written inside a kernel: the split run once through a pallas_call
+    (Mosaic on a chip, the interpreter on the cpu backend) over 2 048
+    float32 values of seventeen binades, and |x - hi - lo| <= 2^-17 |x|
+    held to on the host in float64. A compiler that folds f32 -> bf16
+    -> f32 to the identity, as XLA:TPU does, leaves lo = 0 and a
+    residual of up to 2^-9 |x|: the one-pass form's error under the
+    three-pass form's bound, which no CPU test and no checksum would
+    show (the float64 rescore hides it until a boundary is missed).
+    config.EngineConfig.f32_form asks before it names "bf16x3" and
+    falls back to the one HIGHEST dot, with a warning, where the
+    answer is no; chip_smoke.py's ``batch.f32`` phase fails on that
+    fallback."""
+    import warnings
+
+    import numpy as np
+
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
+    rng = np.random.default_rng(36)
+    shape = (16, 128)                      # one bf16 tile
+    x = (rng.uniform(1.0, 2.0, shape) * 2.0 ** rng.integers(-8, 9, shape)
+         * rng.choice([-1.0, 1.0], shape)).astype(np.float32)
+    hi, lo = jax.device_get(  # check: allow-host-sync
+        _split_in_kernel(jax.device_put(x), pallas_interpret()))
+    x64 = x.astype(np.float64)
+    rest = x64 - hi.astype(np.float64) - lo.astype(np.float64)
+    holds = bool(np.all(np.abs(rest) <= 2.0 ** -17 * np.abs(x64)))
+    if not holds:
+        warnings.warn(
+            "this backend's compiler does not make the bf16 split as "
+            f"written (residual {np.max(np.abs(rest / x64)):.3e} of |x|, "
+            "bound 2^-17): the exact engines keep the one HIGHEST dot "
+            "(six MXU passes) in place of the three-pass form",
+            RuntimeWarning, stacklevel=2)
+    return holds
+
+
 def _dot_cross(q, d, precision: str):
-    """The (tq, tn) cross-term block at the requested FIRST-PASS
-    precision. "f32": HIGHEST-precision f32 dot (the default would
-    truncate f32 to bf16 on the MXU — 1e-2 relative distance error
-    measured on v5e, breaks neighbor selection; HIGHEST decomposes into
-    ~3 bf16 passes instead). "bf16": ONE MXU pass on bf16-cast operands
-    with f32 accumulation kept — the cast's distance perturbation is
-    bounded by engine.finalize.lowp_eps, which every caller folds into
-    its candidate window, prune threshold, and gate bound so the
-    unchanged f64 rescore + boundary repair restores exact results."""
-    mxu = jax.lax.Precision.HIGHEST
+    """The (tq, tn) cross-term block of float32 blocks ``q`` (tq, a)
+    and ``d`` (tn, a) at the requested FIRST-PASS form, every form
+    accumulating in float32.
+
+    "f32": ONE dot at ``Precision.HIGHEST``, which Mosaic lowers to
+    ``contract_precision<fp32>``: full float32 emulation, SIX bf16 MXU
+    passes (measured on v5e, PR 36: the dot alone 13.6 us at a (128,
+    128) x (12 800, 128) visit where one pass is 2.1; the default would
+    truncate to one pass: 1e-2 relative distance error, breaks neighbor
+    selection). Fast mode's form: the device ordering IS its answer.
+
+    "bf16x3": both blocks split here into bf16 halves (split_bf16) and
+    THREE passes, q_hi.d_hi + q_hi.d_lo + q_lo.d_hi. bf16 x bf16
+    products are exact in float32; what is dropped (q_lo.d_lo and the
+    remainders' products) is at most 2^-15 |q||d| a dot:
+    engine.finalize.LOWP_COEF["bf16x3"] has the derivation. The exact
+    engines' form at float32 staging: the float64 rescore decides
+    every order behind it. The three are ONE dot over [q_hi | q_hi |
+    q_lo] . [d_hi | d_lo | d_hi], a contraction of 3a, at every width:
+    it accumulates in the MXU and pops the (tq, tn) result once where
+    three dots pop it three times and sum on the VPU (measured, PR 36:
+    the fold's visit at 128 attributes 18.6 us against 20.0; at 1 024
+    each dot already accumulates over eight MXU tiles and the two tie,
+    47.1 / 47.1). Mosaic compiles the stacked operands whether the row
+    is whole lanes or not (tests/test_tpu_aot.py: 64, 128, 960, 1 024
+    and 2 048 attributes, the wide ones at the tiles vmem_bytes picks).
+    The split is made a visit: beside the MXU's passes it is not seen
+    in the visit's time, and planes staged outside the kernel measured
+    slower (a shorter fold of the same call: 57.7 us a 1 024-wide visit
+    against 53.5).
+
+    "bf16": both blocks cast, ONE pass; the cast's distance
+    perturbation is bounded by engine.finalize.lowp_eps too, 256 x
+    wider.
+
+    Every caller folds the form's lowp_eps into its prune threshold,
+    hazard test, floor and gate bound (and, for "bf16", its candidate
+    window) so the unchanged f64 rescore + boundary repair restores
+    exact results."""
+    def contract(a, b, mxu=jax.lax.Precision.DEFAULT):
+        return jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())), precision=mxu,
+            preferred_element_type=jnp.float32)
+
+    if precision == "bf16x3":
+        q_hi, q_lo = split_bf16(q)
+        d_hi, d_lo = split_bf16(d)
+        return contract(jnp.concatenate([q_hi, q_hi, q_lo], axis=1),
+                        jnp.concatenate([d_hi, d_lo, d_hi], axis=1))
     if precision == "bf16":
-        q = q.astype(jnp.bfloat16)  # check: lowp-eps=lowp_eps
-        d = d.astype(jnp.bfloat16)  # check: lowp-eps=lowp_eps
         # bf16 operands ARE the single MXU pass; Mosaic rejects an fp32
         # contract precision on them ("Bad lhs type").
-        mxu = jax.lax.Precision.DEFAULT
-    return jax.lax.dot_general(
-        q, d, (((1,), (1,)), ((), ())), precision=mxu,
-        preferred_element_type=jnp.float32)
+        return contract(
+            q.astype(jnp.bfloat16),  # check: lowp-eps=lowp_eps
+            d.astype(jnp.bfloat16))  # check: lowp-eps=lowp_eps
+    return contract(q, d, jax.lax.Precision.HIGHEST)
 
 
 def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
@@ -489,14 +596,18 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     megakernel's norm-bound MXU tile gating (output-identical;
     ops.pallas_fused.fused_topk is the public face, which also resolves
     variants from the fused tune-cache namespace). ``precision``
-    ("f32" | "bf16") selects the FIRST-PASS dot dtype: "bf16" casts the
-    streamed q/d tiles before the MXU (one pass instead of HIGHEST's
-    ~3) with f32 accumulation kept — candidate lists then deviate from
-    the f32 pass by at most engine.finalize.lowp_eps per distance, and
-    callers MUST widen their candidate window / prune / hazard bounds
-    by that margin (resolve_kcap + staging_eps composition do) for the
-    exact pipeline to stay byte-identical. Static: part of the jit
-    cache key, resolved by callers OUTSIDE every jit (R2 discipline).
+    ("f32" | "bf16x3" | "bf16": PRECISIONS) selects the FIRST-PASS
+    form (_dot_cross): "f32" is one ``HIGHEST`` dot, six MXU passes
+    (measured on v5e, PR 36: PERF.md section 6); "bf16x3" splits the
+    streamed q/d tiles into bf16 halves in the kernel and spends
+    three; "bf16" casts them and spends one; all accumulate in f32.
+    The candidate lists of the last two deviate from the f32 pass by
+    at most that form's
+    engine.finalize.lowp_eps per distance, and callers MUST widen
+    their prune / hazard / floor bounds by that margin (and, for
+    "bf16", the candidate window: resolve_kcap) for the exact pipeline
+    to stay byte-identical. Static: part of the jit cache key, resolved
+    by callers OUTSIDE every jit (R2 discipline).
 
     Gate on supports() first. Output lists are NOT sorted; callers sort by
     the composite key (ops.topk.select_topk) if order matters.
@@ -513,7 +624,7 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         n_real = jax.device_put(_onp.int32(n_real))
     if isinstance(id_base, (int, _onp.integer)):
         id_base = jax.device_put(_onp.int32(id_base))
-    if precision not in ("f32", "bf16"):
+    if precision not in PRECISIONS:
         raise ValueError(f"unsupported first-pass precision {precision!r} "
                          "(int8 is the gated follow-on — see ROADMAP)")
     return _extract_topk_jit(

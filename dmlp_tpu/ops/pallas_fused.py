@@ -129,8 +129,8 @@ def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     gate on and variants resolved from the fused tune-cache namespace.
     Same signature, same (dists, ids, iters) outputs, bit-identical
     results; ``iters`` reports 0 for blocks either gate elided.
-    ``precision`` ("f32" | "bf16") selects the first-pass dot dtype
-    exactly as in extract_topk — the MXU-gate bound widens by the
+    ``precision`` ("f32" | "bf16x3" | "bf16") selects the first-pass
+    form exactly as in extract_topk — the MXU-gate bound widens by the
     engine.finalize.lowp_eps margin in-kernel, so gating stays sound
     under the low-precision pass.
 

@@ -370,8 +370,9 @@ def prune_mask(queries: np.ndarray, ks: np.ndarray,
     (engine.finalize.staging_eps, evaluated at the threshold), which
     dominates both the f64 rounding of the bound arithmetic and the
     staging-dtype/f32 perturbation of any distance the exact stage
-    will later compare. A "bf16" first pass (engine "lowp" rung)
-    additionally widens eps by the finalize.lowp_eps cast bound: the
+    will later compare. A first pass that drops products ("bf16x3",
+    the exact engines' form; "bf16" on the "lowp" rung)
+    additionally widens eps by its finalize.lowp_eps bound: the
     survivor scan's device distances then err by cast + staging, and a
     pruned block must clear both. By construction at least one block
     survives per query with a finite threshold (the block/piece

@@ -9,16 +9,21 @@ crashing, and every rung preserves the contract checksums exactly:
                     solve COMPOSED with the low-precision first pass
                     (``config.precision``/``$DMLP_TPU_PRECISION``
                     resolving to "bf16"): one MXU pass per tile
-                    instead of HIGHEST-precision f32's ~3, candidate
-                    windows and every prune/gate threshold widened by
-                    the analytic ``engine.finalize.lowp_eps`` bound.
+                    instead of the float32 form's three (six where
+                    it is one ``HIGHEST`` dot: measured, PR 36),
+                    candidate windows and every prune/gate threshold
+                    widened by the analytic
+                    ``engine.finalize.lowp_eps`` bound.
                     With precision resolving to "f32" (the default and
                     the ``DMLP_TPU_PRECISION=f32`` kill switch) this
                     rung is exactly the pruned solve — the kill switch
                     pins the precision without consuming a ladder
-                    step. An OOM steps down to the f32 first pass (a
+                    step. An OOM steps down to the float32 form (a
                     bf16-inflated candidate window is the first
-                    allocation to give back).
+                    allocation to give back); that form, three bf16
+                    passes over split operands in exact mode at
+                    float32 staging ("bf16x3"), is what every rung
+                    below runs too.
 2. ``prune``      — the bound-based pruned two-stage solve
                     (ops.summaries) over the fused megakernel at f32 —
                     only survivor blocks are staged/folded. The
@@ -70,8 +75,9 @@ def _rung_context(engine, rung: str):
     """Configure the engine for one rung. ``_degrade_rung`` is consulted
     by engine.single._solve/_solve_segments (``streaming`` skips every
     extract-kernel path; the top ``lowp``/``prune`` rungs may run the
-    bound-based scan pruning, and only ``lowp`` may run the bf16 first
-    pass) and by ops.pallas_fused.resolve_topk_kernel (the ``lowp``/
+    bound-based scan pruning, and only ``lowp`` may run the one-pass
+    bf16 form; every rung runs the three-pass one) and by
+    ops.pallas_fused.resolve_topk_kernel (the ``lowp``/
     ``prune``/``fused`` rungs may dispatch the fused megakernel);
     ``heuristic`` suppresses autotuner cache lookups for the
     duration."""

@@ -698,7 +698,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         # so an env flip mid-serve can disable the bf16 pass (windows
         # merely stay wider than needed) but can never run it against
         # windows that were planned f32.
-        self._precision_plan = cfg.resolve_precision()
+        self._precision_plan = cfg.resolve_precision(self._staging)
 
         # -- plan the streaming layout once, at capacity shape ---------------
         self._stream_select = cfg.resolve_streaming_select(

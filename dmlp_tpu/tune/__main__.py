@@ -17,10 +17,14 @@ megakernel (ops.pallas_fused) caches under its own namespace, and
 ``prune_score`` sweeps the HOST block-scoring chunk that
 ops.summaries.resolve_score_variant reads (satellite of the
 low-precision first pass: the measured tiling replaces the guessed
-_SCORE_BLOCK_CHUNK default). ``--precision f32|bf16|both`` (default
-f32) re-sweeps the device kernels per first-pass dot precision — a
-bf16 pass changes the MXU pass count per tile, so the winning tiles
-differ and persist under the precision key axis (cache schema 3).
+_SCORE_BLOCK_CHUNK default). ``--precision f32|bf16x3|bf16|both``
+(default bf16x3, the form the exact engines run at float32 staging;
+"f32" is fast mode's one HIGHEST dot, and what an exact engine runs
+over operands staged in bfloat16; both = every form, the name kept
+from when there were two) re-sweeps the
+device kernels per first-pass form — the MXU pass count per tile
+(six, three, one) moves the winning tiles, which persist under the
+precision key axis (cache schema 3).
 
 ``--smoke`` runs a tiny-shape sweep (CPU interpret mode works) over a
 4-variant slice PER KERNEL — the ``make tune-smoke`` CI gate that
@@ -56,10 +60,12 @@ def main(argv=None) -> int:
                          "fused megakernel caches under its own "
                          "namespace; prune_score sweeps the host "
                          "block-scoring chunk; all = every kernel)")
-    ap.add_argument("--precision", choices=("f32", "bf16", "both"),
-                    default="f32",
-                    help="first-pass dot precision(s) to sweep the "
-                         "device kernels at — winners persist under "
+    ap.add_argument("--precision",
+                    choices=("f32", "bf16x3", "bf16", "both"),
+                    default="bf16x3",
+                    help="first-pass form(s) to sweep the device "
+                         "kernels at (both = every form) — winners "
+                         "persist under "
                          "the cache's precision key axis (prune_score "
                          "is host f64 and ignores this)")
     ap.add_argument("--seed", type=int, default=0)
@@ -126,7 +132,7 @@ def main(argv=None) -> int:
     kernels = {"both": ("extract", "fused"),
                "all": ("extract", "fused", "prune_score")}.get(
         args.kernel, (args.kernel,))
-    precisions = ("f32", "bf16") if args.precision == "both" \
+    precisions = ("f32", "bf16x3", "bf16") if args.precision == "both" \
         else (args.precision,)
     print(f"tune: sweeping {'+'.join(kernels)} variants at n={n} q={nq} "
           f"a={a} kcs={kcs} reps={reps} "

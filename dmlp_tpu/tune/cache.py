@@ -9,9 +9,12 @@ on the hot path by ``ops.pallas_extract._resolve_variant`` (kernel
 per-entry kernel namespace: the fused megakernel's MXU gate shifts
 which tiles win, so the two kernels sweep and cache independently.
 Schema 3 added the first-pass precision axis: a bf16 dot spends one
-MXU pass per tile where HIGHEST-precision f32 spends ~3, which moves
-the compute/traffic balance — and hence the winning tile — so the two
-precisions sweep and cache independently. Old files still LOAD:
+MXU pass per tile where the split "bf16x3" form spends three and one
+HIGHEST-precision f32 dot six (measured on v5e, PR 36: PERF.md
+section 6), which moves the compute/traffic balance — and hence the
+winning tile — so the forms sweep and cache independently (an "f32"
+entry is a measurement of the one HIGHEST dot: the exact engines, which
+run "bf16x3" at float32 staging, do not look under it). Old files still LOAD:
 schema-1 keys upgrade to the extract namespace, schema-1 AND schema-2
 keys take the "f32" precision suffix in memory (every pre-schema-3
 measurement WAS an f32-pass measurement); saves always write schema 3.
@@ -53,7 +56,7 @@ CACHE_SCHEMA = 3
 
 #: legal first-pass precision key segments (config.EngineConfig
 #: .precision resolved; int8 is the gated ROADMAP follow-on)
-_PRECISIONS = ("f32", "bf16")
+_PRECISIONS = ("f32", "bf16x3", "bf16")
 
 #: the schema-2 envelope family; per-entry keys carry the concrete kernel
 _KERNEL_FAMILY = "pallas_topk"
@@ -283,6 +286,9 @@ class VariantCache:
 # -- hot-path lookup (memoized, never raises) --------------------------------
 _memo: Dict[str, Optional[VariantCache]] = {}
 _device_kind_memo: Dict[str, str] = {}
+#: cache files already said to hold "f32" winners only for a shape the
+#: exact engines look up under "bf16x3" (lookup_variant says it once)
+_orphans_noted: set = set()
 
 #: >0 = lookups disabled (the degradation ladder's "heuristic" rung:
 #: after a device OOM the first thing to give back is a swept variant's
@@ -307,6 +313,7 @@ def clear_lookup_memo() -> None:
     rewrites the file mid-process)."""
     _memo.clear()
     _device_kind_memo.clear()
+    _orphans_noted.clear()
 
 
 def _current_device_kind() -> str:
@@ -356,5 +363,23 @@ def lookup_variant(kc: int, b: int, a: Optional[int] = None,
         return None
     if device_kind is None:
         device_kind = _current_device_kind()
-    return cache.get(device_kind, b, kc, a=a, dtype=dtype, kernel=kernel,
-                     precision=precision)
+    hit = cache.get(device_kind, b, kc, a=a, dtype=dtype, kernel=kernel,
+                    precision=precision)
+    if hit is None and precision == "bf16x3" and path not in _orphans_noted \
+            and cache.get(device_kind, b, kc, a=a, dtype=dtype,
+                          kernel=kernel, precision="f32") is not None:
+        # A file swept before the split form existed (or with
+        # --precision f32) measured the one HIGHEST dot: six passes a
+        # tile, another balance. The exact engines do not run its
+        # winner; they say so once a file, instead of silently taking
+        # the heuristic on the "tuned" rung.
+        _orphans_noted.add(path)
+        import warnings
+        warnings.warn(
+            f"tune cache {path}: {kernel} at kc={kc} b={b} a={a} is "
+            'measured under "f32" (one HIGHEST dot) only; the exact '
+            'engines\' float32 pass is "bf16x3" and takes the heuristic '
+            "tiles until `python -m dmlp_tpu.tune` (default "
+            "--precision bf16x3) has swept it", RuntimeWarning,
+            stacklevel=2)
+    return hit

@@ -152,9 +152,9 @@ def sweep_extract(n: int, nq: int, a: int, kcs: Sequence[int],
 
     Queries pad to whole query tiles. ``kernel`` ("extract" | "fused")
     selects which kernel the variants drive; winners persist under that
-    kernel's cache namespace. ``precision`` ("f32" | "bf16") selects
-    the first-pass dot dtype the variants are timed WITH — a bf16
-    first pass changes MXU pass count and hence which tile shapes win,
+    kernel's cache namespace. ``precision`` ("f32" | "bf16x3" | "bf16")
+    selects the first-pass form the variants are timed WITH — the MXU
+    pass count (six, three, one) moves which tile shapes win,
     so winners carry the precision and persist under that key axis of
     the cache (schema 3). ``winners`` is a list of
     {"kernel", "kc", "b", "qb", "variant", "precision", "measured_ms",

@@ -1095,6 +1095,49 @@ class TestR9AutoShard:
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# R8 — low-precision MXU contract (the Pallas kernel modules)
+# ---------------------------------------------------------------------------
+
+SPLIT_SRC = """
+    import jax.numpy as jnp
+    def split_bf16(x):
+        hi = x.astype(jnp.bfloat16){note}
+        rest = x - hi.astype(jnp.float32)
+        return hi, rest.astype(jnp.bfloat16){note}
+"""
+
+
+class TestR8LowPrec:
+    """The "bf16x3" form's split (ops/pallas_extract.py:split_bf16) is
+    two casts below float32: each must name the bound that covers it."""
+
+    def test_r802_flags_the_split_written_without_its_bound(self, tmp_path):
+        write(tmp_path, "dmlp_tpu/ops/pallas_x.py",
+              SPLIT_SRC.format(note=""))
+        fs = run_check(tmp_path, ["R8"])
+        assert rules_of(fs) == ["R802", "R802"]
+        assert all("bfloat16" in f.message for f in fs)
+
+    def test_r802_annotated_split_is_clean(self, tmp_path):
+        write(tmp_path, "dmlp_tpu/ops/pallas_x.py",
+              SPLIT_SRC.format(note="  # check: lowp-eps=lowp_eps"))
+        assert run_check(tmp_path, ["R8"]) == []
+
+    def test_r803_the_named_bound_must_exist(self, tmp_path):
+        write(tmp_path, "dmlp_tpu/engine/finalize.py", """
+            def lowp_eps(precision, qn, dn_max):
+                return 0.0
+        """)
+        write(tmp_path, "dmlp_tpu/ops/pallas_x.py",
+              SPLIT_SRC.format(note="  # check: lowp-eps=split_eps"))
+        assert rules_of(run_check(tmp_path, ["R8"])) == ["R803", "R803"]
+
+    def test_r8_scope_is_the_kernel_modules(self, tmp_path):
+        write(tmp_path, "dmlp_tpu/engine/x.py", SPLIT_SRC.format(note=""))
+        assert run_check(tmp_path, ["R8"]) == []
+
+
 class TestStaleAllows:
     def test_dead_directive_reported_live_one_kept(self, tmp_path):
         from dmlp_tpu.check.analyzer import (analyze_paths_tracking,

@@ -30,11 +30,15 @@ def test_chip_smoke_dry_run_matches_oracle_and_refuses_cpu():
     assert "FAIL config 1 batch: platform is cpu" in out
     assert "FAIL config 1 serve: stats reply: platform is cpu" in out
     assert "FAIL config 1 batch: pallas_interpret is True" in out
+    # the float32-staged child ran too, on the three-pass form
+    assert "FAIL config 1 batch.f32: platform is cpu" in out
+    assert "the first pass ran as" not in out
     # both children ran to the end and answered byte-identically
     assert "serve: 3 requests x 256 queries" in out
     assert "differ" not in out and "exited" not in out
     # ... on the path the smoke is about, from their own stamps
-    for child in ("batch", "serve: ready file", "serve: stats reply"):
+    for child in ("batch", "batch.f32", "serve: ready file",
+                  "serve: stats reply"):
         for check in ("select is", "extract_impl is", "degrade rung is",
                       "degradations recorded", "retries recorded",
                       "kernel variant"):
@@ -92,6 +96,48 @@ def test_check_stamp_names_every_miss():
         assert want in misses
     assert cs.check_stamp(None, None, True, 1) == [
         "child wrote no device stamp"]
+
+
+@pytest.mark.parametrize("ran, named", [("bf16x3", False), ("f32", True),
+                                        (None, True)])
+def test_batch_f32_names_a_first_pass_that_did_not_split(
+        tmp_path, monkeypatch, ran, named):
+    """The float32-staged child's record names the form its first pass
+    ran at; "bf16x3" there means the engine's split check passed on the
+    device the child ran on. Anything else is a miss that says so."""
+    cs = _load_chip_smoke()
+    monkeypatch.setattr(cs, "LOGS", str(tmp_path))
+    c = cs.Config(1)
+    stamp = {"platform": "tpu", "device_kind": "TPU v5 lite",
+             "peak_flops_known": True, "device_count": 1, "mesh": None,
+             "select": "extract", "extract_impl": "fused",
+             "pallas_interpret": False, "degrade_rung": "lowp",
+             "kernel_variant": {"tile_q": 64, "from_tune_cache": False},
+             "degradations": [], "retries": 0}
+
+    def child(argv, stdin_path, out_path, err_path):
+        assert argv[-2:] == ["--dtype", "float32"]
+        with open(out_path, "w") as f:
+            f.writelines(c.oracle_lines)
+        with open(err_path, "w") as f:
+            f.write("RuntimeWarning: this backend's compiler ...\n")
+        summary = {"event": "summary", "device": stamp}
+        if ran is not None:
+            summary["precision"] = {"active": ran}
+        with open(argv[argv.index("--metrics") + 1], "w") as f:
+            f.write(json.dumps(summary) + "\n")
+        return 0, 1.0
+
+    monkeypatch.setattr(cs, "run_child", child)
+    bad, got = cs.phase_solve(c, "batch.f32", ["--dtype", "float32"],
+                              None, ladder=True, form="bf16x3")
+    assert got == stamp
+    if named:
+        (miss,) = bad
+        assert f"the first pass ran as {ran!r}, not 'bf16x3'" in miss
+        assert "split_holds" in miss and "RuntimeWarning" in miss
+    else:
+        assert bad == []
 
 
 # -- the removed fallbacks -----------------------------------------------------

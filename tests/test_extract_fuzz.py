@@ -116,7 +116,49 @@ def test_extract_engine_all_huge_k_multipass():
     assert eng._last_select == "extract"
     assert eng.last_hetk is None
     assert eng.last_mp_passes >= 2
+    assert eng.last_precision["active"] == "bf16x3"
     assert_same_results(got, knn_golden(inp), check_dists=False)
+
+
+@pytest.mark.parametrize("path", ["single", "multipass", "sharded", "ring"])
+def test_split_form_paths_byte_identical_to_the_oracle(path):
+    """Reals at BIGANN's coordinate scale (not bf16 values: the low
+    planes work), every exact engine path on the three-pass form:
+    labels, checksums and the debug distances are the float64
+    oracle's, one kernel pass, floor-raised passes (k past 512) and the
+    mesh engines alike."""
+    from dmlp_tpu.engine.sharded import ShardedEngine
+    from dmlp_tpu.io.report import format_results
+    rng = np.random.default_rng({"single": 1, "multipass": 2,
+                                 "sharded": 3, "ring": 4}[path])
+    n, nq, na = 1500, 6, 7
+    data = (rng.random((n, na), dtype=np.float32) * 255.0)
+    queries = (rng.random((nq, na), dtype=np.float32) * 255.0)
+    ks = (np.array([600, 700, 1500, 997, 513, 1024], np.int32)
+          if path == "multipass"
+          else rng.integers(1, 49, nq).astype(np.int32))
+    inp = KNNInput(Params(n, nq, na),
+                   rng.integers(0, 4, n).astype(np.int32),
+                   data.astype(np.float64), ks, queries.astype(np.float64))
+    if path in ("single", "multipass"):
+        eng = SingleChipEngine(EngineConfig(select="extract",
+                                            use_pallas=True))
+    else:
+        import jax
+        from dmlp_tpu.engine.ring import RingEngine
+        if len(jax.devices()) < 8:
+            pytest.skip("needs 8 devices")
+        cls = RingEngine if path == "ring" else ShardedEngine
+        eng = cls(EngineConfig(mode=path, select="extract",
+                               use_pallas=True))
+    got = eng.run(inp)
+    gold = knn_golden(inp)
+    assert eng._last_select == "extract"
+    assert eng.last_precision["active"] == "bf16x3"
+    if path == "multipass":
+        assert eng.last_mp_passes >= 2
+    assert_same_results(got, gold)
+    assert format_results(got, debug=True) == format_results(gold, debug=True)
 
 
 @pytest.mark.parametrize("seed", [201, 202, 203])
@@ -342,8 +384,10 @@ def test_extract_engine_tie_heavy_dup_rows_block_boundaries_vs_golden(
     # the engine prefers the fused megakernel (fused_topk namespace) —
     # pin BOTH namespaces so the multi-block variant drives whichever
     # kernel the dispatch resolves
-    cache.put("cpu", 12800, kc, pinned, a=na)
-    cache.put("cpu", 12800, kc, pinned, a=na, kernel="fused_topk")
+    # (under the form the exact engine runs: the split "bf16x3")
+    cache.put("cpu", 12800, kc, pinned, a=na, precision="bf16x3")
+    cache.put("cpu", 12800, kc, pinned, a=na, kernel="fused_topk",
+              precision="bf16x3")
     cache.save(path)
     clear_lookup_memo()
     from dmlp_tpu.obs import trace as obs_trace

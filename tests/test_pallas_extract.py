@@ -37,12 +37,17 @@ def _oracle_topk_dists(q, chunks_real, kc):
     return out
 
 
-def _check(q, chunks, nreals, kc):
+#: every first-pass form: small integers are bf16 values, so the split
+#: form's low planes are zero and one bf16 pass is exact too
+FORMS = pytest.mark.parametrize("precision", ["f32", "bf16x3", "bf16"])
+
+
+def _check(q, chunks, nreals, kc, precision="f32"):
     od = oi = None
     base = 0
     for d, nr in zip(chunks, nreals):
         od, oi, _ = extract_topk(q, d, od, oi, n_real=nr, id_base=base,
-                                 kc=kc, interpret=True)
+                                 kc=kc, interpret=True, precision=precision)
         base += nr
     od, oi = np.asarray(od), np.asarray(oi)
     ref = _oracle_topk_dists(q, [np.asarray(d)[:nr]
@@ -60,34 +65,77 @@ def _check(q, chunks, nreals, kc):
                           np.where(valid, od.astype(np.float64), np.inf))
 
 
-def test_fresh_single_chunk():
+@FORMS
+def test_fresh_single_chunk(precision):
     rng = np.random.default_rng(7)
     q = _int_attrs(rng, (64, 8))
     d = _int_attrs(rng, (1024, 8))
     assert supports(64, 1024, 8, 16)
-    _check(q, [d], [900], 16)
+    _check(q, [d], [900], 16, precision)
 
 
-def test_carry_across_chunks():
+@FORMS
+def test_carry_across_chunks(precision):
     rng = np.random.default_rng(3)
     q = _int_attrs(rng, (16, 4))
     _check(q, [_int_attrs(rng, (1024, 4)), _int_attrs(rng, (1536, 4))],
-           [1000, 1536], 24)
+           [1000, 1536], 24, precision)
 
 
-def test_duplicate_heavy_ties():
+@FORMS
+def test_duplicate_heavy_ties(precision):
     rng = np.random.default_rng(5)
     q = jnp.asarray(rng.integers(0, 3, (16, 4)), jnp.float32)
     d = jnp.asarray(rng.integers(0, 3, (1024, 4)), jnp.float32)
-    _check(q, [d], [1024], 24)
+    _check(q, [d], [1024], 24, precision)
 
 
-def test_fewer_real_rows_than_kc():
+@FORMS
+def test_fewer_real_rows_than_kc(precision):
     rng = np.random.default_rng(9)
     q = _int_attrs(rng, (16, 4))
-    _check(q, [_int_attrs(rng, (512, 4))], [10], 24)
+    _check(q, [_int_attrs(rng, (512, 4))], [10], 24, precision)
     _check(q, [_int_attrs(rng, (512, 4)), _int_attrs(rng, (512, 4))],
-           [10, 12], 24)
+           [10, 12], 24, precision)
+
+
+@pytest.mark.parametrize("na", [24, 128, 256])
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_stacked_passes_agree_with_the_one_dot_on_exact_operands(gate, na):
+    """The "bf16x3" form's three passes are ONE dot over the halves
+    stacked along the contraction, whether the row is whole lanes (128,
+    256) or not (24). On rows whose halves and partial sums are exact in
+    float32 (integers below 2^9: hi holds 8 bits, lo the ninth) it drops
+    nothing, and gives the one-dot "f32" form's lists to the bit,
+    carried and floored alike."""
+    rng = np.random.default_rng(17)
+    q = np.zeros((16, na), np.float32)
+    d = np.zeros((1280, na), np.float32)
+    q[:, :24] = rng.integers(0, 256, (16, 24))
+    d[:, :24] = rng.integers(0, 512, (1280, 24))
+    q, d = jnp.asarray(q), jnp.asarray(d)
+    floor = jnp.asarray(rng.uniform(0, 4e5, (16, 1)), jnp.float32)
+    lists = {}
+    for prec in ("f32", "bf16x3"):
+        od, oi, _ = extract_topk(q, d[:768], n_real=700, kc=24,
+                                 interpret=True, mxu_gate=gate,
+                                 precision=prec)
+        od, oi, _ = extract_topk(q, d[768:], od, oi, n_real=512,
+                                 id_base=700, kc=24, interpret=True,
+                                 mxu_gate=gate, floor=floor,
+                                 precision=prec)
+        lists[prec] = (np.asarray(od), np.asarray(oi))
+    assert np.isfinite(lists["f32"][0]).any()
+    for a, b in zip(lists["f32"], lists["bf16x3"]):
+        assert np.array_equal(a, b)
+
+
+def test_unknown_form_is_refused():
+    q = jnp.zeros((8, 4), jnp.float32)
+    d = jnp.zeros((256, 4), jnp.float32)
+    with pytest.raises(ValueError, match="first-pass precision"):
+        extract_topk(q, d, n_real=256, kc=8, interpret=True,
+                     precision="int8")
 
 
 def test_supports_gates():
@@ -106,6 +154,8 @@ def test_engine_extract_matches_golden():
     eng = _engine(data_block=512)
     got = eng.run(inp)
     assert eng._last_select == "extract"
+    # the exact engine's float32 pass is the three-pass split form
+    assert eng.last_precision["active"] == "bf16x3"
     assert_same_results(got, knn_golden(inp))
 
 
@@ -115,7 +165,38 @@ def test_engine_extract_multichunk_matches_golden():
     eng = _engine(data_block=8192)   # 2 chunks with carry folding
     got = eng.run(inp)
     assert eng._last_select == "extract"
+    assert eng.last_precision["active"] == "bf16x3"
     assert_same_results(got, knn_golden(inp))
+
+
+@pytest.mark.parametrize("scale", [1.0, 255.0])
+def test_engine_extract_split_form_debug_output_is_the_oracles(scale):
+    """Byte identity where the values are NOT bf16-exact: uniform reals
+    at the cells' coordinate scales, checksums and the human-readable
+    (debug) distances against the float64 oracle, the three-pass form
+    against fast mode's one dot on the same input."""
+    from dmlp_tpu.io.report import format_results
+    rng = np.random.default_rng(int(scale) + 40)
+    n, nq, na = 9000, 24, 16
+    data = rng.uniform(0, scale, (n, na)).astype(np.float32)
+    queries = rng.uniform(0, scale, (nq, na)).astype(np.float32)
+    inp = KNNInput(Params(n, nq, na),
+                   rng.integers(0, 5, n).astype(np.int32),
+                   data.astype(np.float64),
+                   rng.integers(1, 33, nq).astype(np.int32),
+                   queries.astype(np.float64))
+    gold = knn_golden(inp)
+    eng = _engine(data_block=4096)
+    got = eng.run(inp)
+    assert eng._last_select == "extract"
+    assert eng.last_precision["active"] == "bf16x3"
+    assert_same_results(got, gold)
+    assert format_results(got, debug=True) \
+        == format_results(gold, debug=True)
+    fast = _engine(exact=False, data_block=4096)
+    got_fast = fast.run(inp)
+    assert fast.last_precision["active"] == "f32"
+    assert_same_results(got_fast, gold, check_dists=False)
 
 
 def test_engine_extract_duplicate_ties_fast_mode():

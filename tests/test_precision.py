@@ -1,6 +1,9 @@
 """Low-precision first pass: analytic bound + byte-identity fuzz.
 
-Two halves of the ``precision="bf16"`` contract get hardened here:
+The forms that drop products ("bf16x3": three bf16 MXU passes over split
+operands, what every exact engine runs at float32 staging; "bf16": one
+pass) are sound only under their bounds; two halves of that contract get
+hardened here:
 
 - the :func:`~dmlp_tpu.engine.finalize.lowp_eps` cast bound actually
   upper-bounds the bf16-vs-f32 cross-term error, fuzzed on directed
@@ -39,6 +42,11 @@ def _bf16(x: np.ndarray) -> np.ndarray:
 def test_lowp_eps_zero_for_f32_and_no_silent_int8():
     qn = np.array([1.0, 4.0])
     assert finalize.lowp_eps("f32", qn, 9.0).tolist() == [0.0, 0.0]
+    # the split form's coefficient is its derivation's, to the bit
+    assert finalize.LOWP_COEF["bf16x3"] == 2.0 ** -14 * (1 + 2.0 ** -16)
+    assert finalize.lowp_eps("bf16x3", qn, 9.0).tolist() == [
+        finalize.LOWP_COEF["bf16x3"] * 10.0,
+        finalize.LOWP_COEF["bf16x3"] * 13.0]
     with pytest.raises(KeyError):
         finalize.lowp_eps("int8", qn, 9.0)
 
@@ -68,6 +76,151 @@ def test_lowp_eps_bounds_bf16_cross_term_error(seed):
     bound = finalize.lowp_eps("bf16", qn, dn_max)[:, None]
     assert np.all(err <= bound), \
         f"cast error {err.max()} exceeds lowp_eps {bound.min()}"
+
+
+def _split(x: np.ndarray):
+    """ops.pallas_extract.split_bf16 in NumPy: (hi, lo) as float64."""
+    hi = _bf16(x)               # x holds float32 values: one rounding
+    return hi, _bf16(x - hi)    # the difference is exact
+
+
+def _worst_split(rng, shape, scale):
+    """float32 values a hair under a bf16 rounding midpoint whose
+    residual is a hair under ITS midpoint: |x - hi| and |x - hi - lo|
+    both at their largest, all of one sign, so the dropped products
+    add up instead of cancelling."""
+    e = np.floor(np.log2(rng.uniform(0.5, 1.0, shape) * scale))
+    mant = rng.integers(0, 8, shape)      # low bf16 bits: |x| near 2^e
+    x = (1 + mant / 128 + 2.0 ** -8 - 2.0 ** -17 - 2.0 ** -23) * 2.0 ** e
+    return x.astype(np.float32).astype(np.float64)
+
+
+@pytest.mark.parametrize("scale", [1.0, 255.0])
+@pytest.mark.parametrize("na", [16, 128, 960])
+@pytest.mark.parametrize("corpus", ["worst_split", "cancel"])
+def test_lowp_eps_bounds_the_products_bf16x3_drops(corpus, na, scale):
+    """What the three passes leave out (q_lo.d_lo and the remainders'
+    products), exactly: 2 |q.d - (q_hi.d_hi + q_hi.d_lo + q_lo.d_hi)| in
+    float64 stays inside HALF of lowp_eps (the coefficient covers two
+    erring distances), on operands built to make the residuals as large
+    as rounding allows and on the magnitude-cancellation corpus."""
+    rng = np.random.default_rng(1000 + na + int(scale))
+    if corpus == "worst_split":
+        data = _worst_split(rng, (200, na), scale)
+        queries = _worst_split(rng, (16, na), scale)
+    else:
+        center = rng.uniform(-1, 1, na) * scale
+        data = np.vstack([center + rng.normal(0, 1e-3 * scale, (100, na)),
+                          rng.uniform(-scale, scale, (100, na))])
+        queries = center + rng.normal(0, 1e-3 * scale, (16, na))
+        data = data.astype(np.float32).astype(np.float64)
+        queries = queries.astype(np.float32).astype(np.float64)
+    qh, ql = _split(queries)
+    dh, dl = _split(data)
+    # the split itself: the half-ulp bounds LOWP_COEF's derivation uses
+    for x, hi, lo in ((queries, qh, ql), (data, dh, dl)):
+        ax = np.abs(x)
+        assert np.all(np.abs(x - hi) <= 2.0 ** -8 * ax)
+        assert np.all(np.abs(lo) <= 2.0 ** -8 * ax)
+        assert np.all(np.abs(x - hi - lo) <= 2.0 ** -17 * ax)
+    cross3 = qh @ dh.T + qh @ dl.T + ql @ dh.T          # exact in f64
+    err = 2.0 * np.abs(queries @ data.T - cross3)
+    qn = np.einsum("ij,ij->i", queries, queries)
+    dn_max = float(np.max(np.einsum("ij,ij->i", data, data)))
+    half = finalize.lowp_eps("bf16x3", qn, dn_max)[:, None] / 2.0
+    assert np.all(err <= half), (err / half).max()
+    if corpus == "worst_split":
+        # the coefficient is tight: the directed operands reach 0.94 of it
+        assert (err / half).max() > 0.9, (err / half).max()
+
+
+@pytest.mark.parametrize("scale", [1.0, 255.0])
+@pytest.mark.parametrize("na", [16, 128, 960])
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_bf16x3_kernel_distance_inside_staging_plus_lowp_eps(gate, na,
+                                                             scale):
+    """The interpreted KERNEL's "bf16x3" distances against float64, on
+    the adversarial corpus (tight cluster at a large norm: true
+    distances ~1e-6 of the scale, cross terms of the scale's size):
+    every candidate's distance within staging_eps + lowp_eps, and the
+    same candidates as the one-dot "f32" form's wherever the bound
+    cannot have reordered them."""
+    import jax.numpy as jnp
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    rng = np.random.default_rng(7000 + na + int(scale) + gate)
+    center = rng.uniform(0.25, 1, na) * scale
+    data = np.vstack([center + rng.normal(0, 1e-3 * scale, (384, na)),
+                      rng.uniform(0, scale, (128, na))])
+    data = data.astype(np.float32)
+    queries = (center + rng.normal(0, 1e-3 * scale, (16, na))
+               ).astype(np.float32)
+    kc = 16
+    got = {}
+    for prec in ("f32", "bf16x3"):
+        od, oi, _ = extract_topk(jnp.asarray(queries), jnp.asarray(data),
+                                 n_real=500, kc=kc, interpret=True,
+                                 mxu_gate=gate, precision=prec)
+        got[prec] = (np.asarray(od, np.float64), np.asarray(oi))
+    q64, d64 = queries.astype(np.float64), data.astype(np.float64)
+    qn = np.einsum("ij,ij->i", q64, q64)
+    dn_max = float(np.max(np.einsum("ij,ij->i", d64[:500], d64[:500])))
+    for prec, (od, oi) in got.items():
+        assert (oi >= 0).all() and (oi < 500).all()
+        diff = d64[oi] - q64[:, None, :]
+        true = np.einsum("qka,qka->qk", diff, diff)
+        eps = finalize.staging_eps(true.max(axis=1), qn, dn_max,
+                                   "float32", na) \
+            + finalize.lowp_eps(prec, qn, dn_max)
+        assert np.all(np.abs(od - true) <= eps[:, None]), prec
+    # the k nearest by float64 are in the split form's window wherever
+    # the window clears its bound (what the hazard test asks)
+    full = ((q64[:, None, :] - d64[None, :500]) ** 2).sum(-1)
+    order = np.argsort(full, axis=1)
+    od, oi = got["bf16x3"]
+    eps = finalize.staging_eps(od.max(axis=1), qn, dn_max, "float32", na) \
+        + finalize.lowp_eps("bf16x3", qn, dn_max)
+    for qi in range(len(queries)):
+        safe = full[qi, order[qi]] + eps[qi] < od[qi].max()
+        want = order[qi][safe][:kc]
+        assert set(want) <= set(oi[qi]), qi
+
+
+def test_dot_cross_forms_are_what_they_say():
+    """"f32" is ONE dot at Precision.HIGHEST on float32 operands and
+    nothing else (fast mode's form, byte for byte the parent's);
+    "bf16x3" is ONE bf16 x bf16 dot accumulated in float32 over a
+    contraction of three times the width, the halves split in the
+    kernel and stacked, at every width; "bf16" one."""
+    import jax
+    import jax.numpy as jnp
+    from dmlp_tpu.ops.pallas_extract import PRECISIONS, _dot_cross
+    assert PRECISIONS == ("f32", "bf16x3", "bf16") \
+        == tuple(finalize.LOWP_COEF)
+
+    def dots(precision, na):
+        q = jnp.ones((8, na), jnp.float32)
+        d = jnp.ones((256, na), jnp.float32)
+        eqns = jax.make_jaxpr(
+            lambda q, d: _dot_cross(q, d, precision))(q, d).jaxpr.eqns
+        return ([e for e in eqns if e.primitive.name == "dot_general"],
+                [e.primitive.name for e in eqns])
+
+    for na in (64, 128, 1024):
+        one, names = dots("f32", na)
+        assert names == ["dot_general"]
+        assert "HIGHEST" in str(one[0].params["precision"])
+        assert [v.aval.dtype for v in one[0].invars] == [jnp.float32] * 2
+        single, _ = dots("bf16", na)
+        assert len(single) == 1
+        assert [v.aval.dtype for v in single[0].invars] \
+            == [jnp.bfloat16] * 2
+
+    for na in (64, 128, 960, 1024):
+        (e,), names = dots("bf16x3", na)
+        assert names.count("concatenate") == 2, na
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert [v.aval.shape[1] for v in e.invars] == [3 * na] * 2
+        assert e.params["preferred_element_type"] == jnp.float32
 
 
 # -- engine byte-identity under the forced bf16 pass --------------------------
@@ -112,9 +265,12 @@ def test_single_engine_reports_active_precision_and_inflation():
     rec = eng.last_precision
     assert rec["active"] == "bf16" and rec["configured"] == "bf16"
     assert rec["kcap_inflation"] > 0      # the window actually widened
+    # "f32" in exact mode at float32 staging MEANS the three-pass form,
+    # and that form widens no window
     eng_f = SingleChipEngine(_cfg("f32"))
     eng_f.run(inp)
-    assert eng_f.last_precision["active"] == "f32"
+    assert eng_f.last_precision["active"] == "bf16x3"
+    assert eng_f.last_precision["configured"] == "bf16x3"
     assert eng_f.last_precision["kcap_inflation"] == 0
 
 
@@ -175,15 +331,204 @@ def test_env_kill_switch_and_force(monkeypatch):
     monkeypatch.setenv("DMLP_TPU_PRECISION", "f32")
     eng = SingleChipEngine(_cfg("bf16"))
     assert format_results(eng.run(inp)) == format_results(knn_golden(inp))
-    assert eng.last_precision["active"] == "f32"
+    assert eng.last_precision["active"] == "bf16x3"   # the f32 FORM
     monkeypatch.setenv("DMLP_TPU_PRECISION", "bf16")
     eng2 = SingleChipEngine(_cfg("auto"))
     assert format_results(eng2.run(inp)) == format_results(knn_golden(inp))
     assert eng2.last_precision["active"] == "bf16"
 
 
-def test_fast_mode_never_runs_lowp():
-    """The bf16 pass is only sound with the f64 rescore behind it —
-    fast (non-exact) mode must pin the pass to f32."""
-    cfg = _cfg("bf16", exact=False)
-    assert cfg.resolve_precision() == "f32"
+@pytest.mark.parametrize("precision", ["auto", "f32", "bf16"])
+def test_fast_mode_never_runs_lowp(precision):
+    """A pass that drops products is only sound with the f64 rescore
+    behind it — fast (non-exact) mode must pin the pass to the one
+    HIGHEST dot, whatever is configured or staged."""
+    cfg = _cfg(precision, exact=False)
+    assert cfg.resolve_precision() == cfg.resolve_precision("float32") \
+        == cfg.f32_form("float32") == "f32"
+    inp = _case(889)
+    eng = SingleChipEngine(cfg)
+    eng.run(inp)
+    assert eng.last_precision["active"] == "f32"
+
+
+def test_resolve_precision_forms():
+    """The form follows what the engine knows: exact mode at float32
+    staging splits; operands staged in bfloat16 have no low half and
+    keep the one dot; "bf16" stays the one-pass form."""
+    exact = EngineConfig()
+    assert exact.resolve_precision("float32") == "bf16x3"
+    assert exact.resolve_precision("bfloat16") == "f32"
+    assert EngineConfig(precision="f32").resolve_precision("float32") \
+        == "bf16x3"
+    assert EngineConfig(precision="bf16").resolve_precision("float32") \
+        == EngineConfig(precision="bf16").resolve_precision("bfloat16") \
+        == "bf16"
+    # staging left out: the config's own (float32 on the cpu backend)
+    assert EngineConfig(dtype="float32").resolve_precision() == "bf16x3"
+    assert EngineConfig(dtype="bfloat16").resolve_precision() == "f32"
+
+
+@pytest.fixture
+def fresh_split_check():
+    """split_holds asks the backend once a process: a test that swaps
+    the split asks again, and leaves the real answer behind it."""
+    from dmlp_tpu.ops import pallas_extract
+    pallas_extract.split_holds.cache_clear()
+    yield pallas_extract
+    pallas_extract.split_holds.cache_clear()
+
+
+def test_the_split_holds_through_the_kernel_on_this_backend(
+        fresh_split_check):
+    """The guard every engine asks before it names "bf16x3": the split
+    run through a pallas_call leaves |x - hi - lo| <= 2^-17 |x|."""
+    import warnings
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert fresh_split_check.split_holds() is True
+
+
+@pytest.mark.parametrize("fold", ["lo_is_zero", "lo_is_short"])
+def test_a_compiler_that_folds_the_split_gets_the_one_dot(
+        fresh_split_check, monkeypatch, fold):
+    """What XLA:TPU did to the split in a jitted prologue (f32 -> bf16
+    -> f32 taken for the identity: lo = 0), and a low half that is
+    there but a quarter short (the guard holds the bound, not lo != 0):
+    it says no, with a warning; f32_form then names the one
+    HIGHEST dot, and an exact engine runs it and answers as the
+    oracle."""
+    import jax.numpy as jnp
+    pe = fresh_split_check
+    real = pe.split_bf16
+
+    def folded(x):
+        hi, lo = real(x)
+        if fold == "lo_is_zero":
+            return hi, jnp.zeros_like(lo)
+        return hi, (lo.astype(jnp.float32) * 0.75).astype(jnp.bfloat16)
+
+    monkeypatch.setattr(pe, "split_bf16", folded)
+    with pytest.warns(RuntimeWarning, match="does not make the bf16 split"):
+        assert pe.split_holds() is False
+    cfg = _cfg("auto")
+    assert cfg.f32_form("float32") == cfg.resolve_precision("float32") \
+        == "f32"
+    assert EngineConfig(precision="bf16").resolve_precision("float32") \
+        == "bf16"          # the one-pass form splits nothing
+    inp = _case(405)
+    eng = SingleChipEngine(cfg)
+    assert format_results(eng.run(inp)) == format_results(knn_golden(inp))
+    assert (eng.last_precision["active"],
+            eng.last_precision["configured"]) == ("f32", "f32")
+
+
+def test_only_a_run_hands_its_solve_a_form_that_drops_products(
+        monkeypatch):
+    """candidates() and run_device_full() report the device ordering,
+    with no rescore behind it: they solve at the default, the one
+    HIGHEST dot, even in an exact config. run() hands its solve
+    active_precision's answer, on every rung."""
+    from dmlp_tpu.engine.single import active_precision
+    from dmlp_tpu.ops import pallas_fused
+    from dmlp_tpu.resilience import degrade
+    seen = []
+    real = pallas_fused.variant_stamp
+
+    def spy(impl, kc, b, qb, a, precision="f32"):
+        seen.append(precision)
+        return real(impl, kc, b, qb, a, precision)
+
+    monkeypatch.setattr(pallas_fused, "variant_stamp", spy)
+    inp = _case(404)
+    eng = SingleChipEngine(_cfg("auto"))
+    eng.candidates(inp)
+    eng.run_device_full(inp)
+    assert seen == ["f32", "f32"]
+    eng.run(inp)
+    assert seen[2:] == ["bf16x3"] and \
+        eng.last_precision["active"] == "bf16x3"
+    for rung in ("lowp", "prune", "fused", "tuned", "heuristic"):
+        with degrade._rung_context(eng, rung):
+            assert active_precision(eng) == "bf16x3", rung
+    # the one-pass form gives way to the float32 FORM below its rung
+    eng_b = SingleChipEngine(_cfg("bf16"))
+    with degrade._rung_context(eng_b, "lowp"):
+        assert active_precision(eng_b) == "bf16"
+    with degrade._rung_context(eng_b, "prune"):
+        assert active_precision(eng_b) == "bf16x3"
+    # bfloat16 staging: nothing to split
+    eng_s = SingleChipEngine(_cfg("auto", dtype="bfloat16"))
+    with degrade._rung_context(eng_s, "lowp"):
+        assert active_precision(eng_s) == "f32"
+
+
+@pytest.mark.parametrize("engine", ["single", "sharded", "mesh"])
+def test_hazard_eps_takes_the_form_that_ran(engine, monkeypatch):
+    """The three hazard tests that once hard-coded lowp_eps("bf16", ...)
+    under an == "bf16" test widen by the ACTIVE form's bound."""
+    import importlib
+    mod = importlib.import_module({
+        "single": "dmlp_tpu.engine.single",
+        "sharded": "dmlp_tpu.engine.sharded",
+        "mesh": "dmlp_tpu.fleet.mesh_engine"}[engine])
+    seen = []
+    real = mod.lowp_eps
+
+    def spy(precision, qn, dn_max):
+        seen.append(precision)
+        return real(precision, qn, dn_max)
+
+    monkeypatch.setattr(mod, "lowp_eps", spy)
+    rng = np.random.default_rng(5)
+    n, na = 600, 5
+    data = rng.uniform(-10, 10, (n, na))
+    labels = rng.integers(0, 4, n).astype(np.int32)
+    q = rng.uniform(-10, 10, (6, na))
+    ks = np.array([1, 3, 8, 17, 32, 5], np.int32)
+    inp = KNNInput(Params(n, len(ks), na), labels, data, ks, q)
+    for precision, want in (("auto", "bf16x3"), ("bf16", "bf16")):
+        del seen[:]
+        if engine == "single":
+            eng = SingleChipEngine(_cfg(precision))
+            got = eng.run(inp)
+        elif engine == "sharded":
+            eng = ShardedEngine(EngineConfig(
+                mode="sharded", select="extract", precision=precision,
+                data_block=64))
+            got = eng.run(inp)
+        else:
+            from dmlp_tpu.fleet.mesh_engine import MeshResidentEngine
+            corpus = KNNInput(Params(n, 0, na), labels, data,
+                              np.zeros(0, np.int32), np.zeros((0, na)))
+            eng = MeshResidentEngine(
+                corpus, EngineConfig(mode="sharded", select="extract",
+                                     use_pallas=True, data_block=256,
+                                     precision=precision),
+                mesh_shape=(2, 1))
+            assert eng._precision_plan == eng._active_prec() == want
+            got = eng.solve_batch(q, ks)
+        assert seen and set(seen) == {want}, (engine, precision, seen)
+        assert eng.last_precision["active"] == want
+        assert format_results(got) == format_results(knn_golden(inp))
+
+
+def test_resident_engine_stats_name_the_form():
+    """stats.engine.precision_plan / last_precision.active say which
+    form every batch ran: three passes in exact mode, the one dot in
+    fast mode (the benchmark's --control)."""
+    rng = np.random.default_rng(12)
+    n, na = 600, 5
+    corpus = KNNInput(Params(n, 0, na),
+                      rng.integers(0, 4, n).astype(np.int32),
+                      rng.uniform(-10, 10, (n, na)),
+                      np.zeros(0, np.int32), np.zeros((0, na)))
+    q = rng.uniform(-10, 10, (5, na))
+    ks = np.array([1, 3, 8, 17, 5], np.int32)
+    for exact, want in ((True, "bf16x3"), (False, "f32")):
+        eng = ResidentEngine(corpus, EngineConfig(
+            select="extract", use_pallas=True, exact=exact))
+        eng.solve_batch(q, ks)
+        st = eng.bucket_stats()
+        assert st["precision_plan"] == want
+        assert st["last_precision"]["active"] == want

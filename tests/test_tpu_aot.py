@@ -39,7 +39,7 @@ def v5e():
 
 
 @pytest.mark.parametrize("carry", [False, True], ids=["fresh", "carry"])
-@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("precision", ["f32", "bf16x3", "bf16"])
 @pytest.mark.parametrize("mxu_gate", [True, False],
                          ids=["fused", "two_pass"])
 def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
@@ -50,8 +50,10 @@ def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
 
     lists = ((spec((QPAD, KCAP), jnp.float32),
               spec((QPAD, KCAP), jnp.int32)) if carry else (None, None))
+    # the split form is float32 staging's (a bf16 value has no low half)
+    staged = jnp.float32 if precision == "bf16x3" else jnp.bfloat16
     compiled = _extract_topk_jit.lower(
-        spec((QPAD, NA), jnp.bfloat16), spec((CHUNK, NA), jnp.bfloat16),
+        spec((QPAD, NA), staged), spec((CHUNK, NA), staged),
         *lists, n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
         kc=KCAP, interpret=False, block_skip=True, mxu_gate=mxu_gate,
         floor=None, precision=precision, **VARIANT).compile()
@@ -65,7 +67,28 @@ def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
         call[:120]
 
 
-def test_resident_fold_program_compiles_for_v5e(v5e):
+def test_split_check_kernel_compiles_for_v5e(v5e):
+    """The kernel ops.pallas_extract.split_holds runs once a process
+    before an engine names "bf16x3": Mosaic takes it, one bf16 tile in
+    and out. (What the casts come out as, only the chip says: that is
+    the check's whole point.)"""
+    import functools
+    from dmlp_tpu.ops.pallas_extract import _split_in_kernel
+    sh = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(functools.partial(
+        _split_in_kernel, interpret=False)).lower(
+        jax.ShapeDtypeStruct((16, 128), jnp.float32,
+                             sharding=sh)).compile()
+    assert "dmlp_split_check" in compiled.as_text()
+
+
+#: the resident programs' two float32 forms: the exact engines' three
+#: passes over split operands, and fast mode's one HIGHEST dot
+F32_FORMS = pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+
+
+@F32_FORMS
+def test_resident_fold_program_compiles_for_v5e(v5e, precision):
     """The serving engine's one-program fold at ``bigann.bulk``'s shape
     (q1024, 82 resident chunks of 51 200 x 128 float32, kcap 32): a
     while loop whose trip count is data, the ``_fresh`` kernel before
@@ -80,7 +103,7 @@ def test_resident_fold_program_compiles_for_v5e(v5e):
     compiled = _fold_stack.lower(
         spec((1024, 128), jnp.float32), spec((82, 51200, 128), jnp.float32),
         spec((82,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
-        **_kernel_statics("fused", 32, 51200, 1024, 128, "f32", False)
+        **_kernel_statics("fused", 32, 51200, 1024, 128, precision, False)
     ).compile()
     hlo = compiled.as_text()
     calls = [line.lstrip().removeprefix("ROOT ").split(" ", 1)[0]
@@ -96,7 +119,8 @@ def test_resident_fold_program_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
 
 
-def test_wide_row_fold_program_compiles_for_v5e(v5e):
+@F32_FORMS
+def test_wide_row_fold_program_compiles_for_v5e(v5e, precision):
     """The same program at ``gist.bulk``'s shape: q1024, 21 resident
     chunks of 51 200 rows of 960 attributes, staged on whole lanes
     (1024), kcap 40 (the window the width deepens:
@@ -116,7 +140,7 @@ def test_wide_row_fold_program_compiles_for_v5e(v5e):
     a = lane_padded(960)
     kc = resolve_kcap(EngineConfig(), 16, "extract", 1 << 20, na=960)
     assert kc == 40
-    kern = _kernel_statics("fused", kc, 51200, 1024, a, "f32", False)
+    kern = _kernel_statics("fused", kc, 51200, 1024, a, precision, False)
     assert (a, kern["tile_q"], kern["tile_n"], kern["ne"]) \
         == (1024, 128, 6400, 2)
     compiled = _fold_stack.lower(
@@ -135,7 +159,8 @@ def test_wide_row_fold_program_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < 1.1 * 51200 * a * 4
 
 
-def test_wide_k_programs_compile_for_v5e(v5e):
+@F32_FORMS
+def test_wide_k_programs_compile_for_v5e(v5e, precision):
     """The multipass driver's two kernel programs at
     ``bigann-gt1000.bulk``'s shape (q1024, 82 resident chunks of
     51 200 x 128 float32, k = 1000: bucket 1024, 1152 slots, 3 passes
@@ -143,7 +168,8 @@ def test_wide_k_programs_compile_for_v5e(v5e):
     fold with 512-wide lists, every further pass ONE kernel call over
     the stack as a (4 198 400, 128) array above a per-query floor. The
     reshape is free inside the program: the sweep allocates no
-    temporary, so no second corpus."""
+    temporary, so no second corpus: in the split form too, whose
+    halves are made in the kernel, a visit."""
     from dmlp_tpu.engine.single import resolve_kcap, resolve_sweep_kernel
     from dmlp_tpu.serve.engine import (_fold_stack, _kernel_statics,
                                        _sweep_stack, k_bucket)
@@ -158,9 +184,10 @@ def test_wide_k_programs_compile_for_v5e(v5e):
     rows = 82 * 51200
     _kern, impl = resolve_sweep_kernel(1024, rows, 128, 512,
                                        chunk_rows=51200, rung="fused",
-                                       precision="f32")
-    fold = _kernel_statics("fused", 512, 51200, 1024, 128, "f32", False)
-    sweep = _kernel_statics(impl, 512, rows, 1024, 128, "f32", False)
+                                       precision=precision)
+    fold = _kernel_statics("fused", 512, 51200, 1024, 128, precision,
+                           False)
+    sweep = _kernel_statics(impl, 512, rows, 1024, 128, precision, False)
     assert impl == "fused" and fold == sweep
     assert (fold["tile_q"], fold["tile_n"], fold["ne"]) == (64, 12800, 4)
     stack = spec((82, 51200, 128), jnp.float32)
@@ -182,25 +209,32 @@ def test_wide_k_programs_compile_for_v5e(v5e):
         assert mem.temp_size_in_bytes < 2 * 51200 * 128 * 4
 
 
-def test_extract_kernel_compiles_for_v5e_at_2048_attributes(v5e):
+@F32_FORMS
+@pytest.mark.parametrize("na, tile_n", [(2048, 2560), (960, 6400)])
+def test_extract_kernel_compiles_for_v5e_at_wide_rows(v5e, precision, na,
+                                                      tile_n):
     """The width rule's far end (ROADMAP R7): a 2048-attribute row tiles
-    51 200 rows by 2 560, and Mosaic compiles the carried kernel."""
+    51 200 rows by 2 560, and Mosaic compiles the carried kernel; and a
+    row that is not whole lanes, as the batch engines stage it (960: the
+    split form's stacked operands then join off a lane boundary)."""
     from dmlp_tpu.serve.engine import _kernel_statics
     sh = SingleDeviceSharding(v5e[0])
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    kern = _kernel_statics("fused", 32, 51200, 1024, 2048, "f32", False)
-    assert kern["tile_n"] == 2560
+    kern = _kernel_statics("fused", 32, 51200, 1024, na, precision,
+                           False)
+    assert kern["tile_n"] == tile_n
     _extract_topk_jit.lower(
-        spec((1024, 2048), jnp.float32), spec((51200, 2048), jnp.float32),
+        spec((1024, na), jnp.float32), spec((51200, na), jnp.float32),
         spec((1024, 32), jnp.float32), spec((1024, 32), jnp.int32),
         n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
         block_skip=True, floor=None, **kern).compile()
 
 
-def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e):
+@F32_FORMS
+def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e, precision):
     """The mesh daemon's one-program fold at ``bigann-mesh4.bulk``'s
     shape (q1024, a 4x1 mesh, 164 resident chunks of 4 x 51 200 x 128
     float32, kcap 32): the one-chip program's body under ``shard_map``.
@@ -226,7 +260,7 @@ def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e):
     eng._chunk_rows, eng._shard_rows = cr, 2 ** 23
     stack = spec((t, 4 * cr, na), jnp.float32, None, DATA_AXIS, None)
     compiled = eng._resident_fold_fn(
-        _kernel_statics("fused", 32, cr, 1024, na, "f32", False)).lower(
+        _kernel_statics("fused", 32, cr, 1024, na, precision, False)).lower(
         spec((1024, na), jnp.float32, QUERY_AXIS, None), stack,
         spec((t,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
         spec((4, t), jnp.int32, DATA_AXIS, None)).compile()

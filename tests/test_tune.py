@@ -89,6 +89,61 @@ def test_schema1_cache_loads_leniently_into_extract_namespace(
                           kernel="fused_topk") is None
 
 
+def test_an_f32_only_cache_says_once_that_exact_engines_pass_it_by(
+        tune_cache_path):
+    """A file swept before the split form existed holds "f32" winners
+    (older schemas upgrade to that suffix): measurements of the one
+    HIGHEST dot, which an exact engine's "bf16x3" lookup does not take.
+    It misses, and says so once a file; a shape with no entry under
+    either key, and a file that has the "bf16x3" entry, say nothing."""
+    import warnings
+    v = {"tile_q": 64, "ne": 4, "unroll": 1}
+    with open(tune_cache_path, "w") as f:
+        json.dump({"schema": 1, "kernel": "extract_topk",
+                   "entries": {"cpu|b16384|a8|kc16|float32":
+                               {"variant": v}}}, f)
+    clear_lookup_memo()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # no entry at all: silent
+        assert lookup_variant(32, 12800, a=8, device_kind="cpu",
+                              precision="bf16x3") is None
+    with pytest.warns(RuntimeWarning, match='under "f32"') as said:
+        assert lookup_variant(16, 12800, a=8, device_kind="cpu",
+                              precision="bf16x3") is None
+    assert tune_cache_path in str(said[0].message)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # ... once
+        assert lookup_variant(16, 12800, a=8, device_kind="cpu",
+                              precision="bf16x3") is None
+        assert lookup_variant(16, 12800, a=8, device_kind="cpu") == v
+        cache = VariantCache.load(tune_cache_path)
+        cache.put("cpu", 12800, 16, v, a=8, precision="bf16x3")
+        cache.save(tune_cache_path)
+        clear_lookup_memo()
+        assert lookup_variant(16, 12800, a=8, device_kind="cpu",
+                              precision="bf16x3") == v
+
+
+def test_precision_both_sweeps_every_form(tune_cache_path, monkeypatch,
+                                          capsys):
+    """``--precision both`` kept its name from when there were two: it
+    sweeps the form the exact engines look up as well."""
+    from dmlp_tpu.tune import __main__ as tune_main
+    from dmlp_tpu.tune import sweep as tune_sweep
+    swept = []
+
+    def sweep(n, nq, a, kcs, *, kernel, precision, **kw):
+        swept.append((kernel, precision))
+        return [], []
+
+    monkeypatch.setattr(tune_sweep, "sweep_extract", sweep)
+    assert tune_main.main(["--smoke", "--kernel", "extract",
+                           "--precision", "both",
+                           "--out", tune_cache_path]) == 1   # no winner
+    assert swept == [("extract", "f32"), ("extract", "bf16x3"),
+                     ("extract", "bf16")]
+
+
 def test_fused_namespace_is_keyed_separately(tune_cache_path):
     """Winners cached under kernel="fused_topk" resolve only through the
     fused lookup; the extract namespace at the same (device, b, a, kc)
@@ -269,13 +324,19 @@ def test_written_cache_drives_engine_resolution_and_parity(
     # The engine prefers the fused megakernel, which resolves through
     # the fused_topk namespace — pin BOTH so whichever kernel dispatches
     # sees the tuned tiles (and the span proves which one resolved).
-    cache.put("cpu", 12800, kc, pinned, a=na)
-    cache.put("cpu", 12800, kc, pinned, a=na, kernel="fused_topk")
+    # The precision axis: the exact engine's float32 pass is the split
+    # "bf16x3" form, which looks under its own key (an "f32" entry is a
+    # measurement of fast mode's one HIGHEST dot).
+    cache.put("cpu", 12800, kc, pinned, a=na, precision="bf16x3")
+    cache.put("cpu", 12800, kc, pinned, a=na, kernel="fused_topk",
+              precision="bf16x3")
     cache.save(tune_cache_path)
     clear_lookup_memo()
 
-    assert resolve_variant(kc, 12800, 128, na) == pinned
-    assert resolve_variant(kc, 12800, 128, na) != tuned_variant(kc)
+    assert resolve_variant(kc, 12800, 128, na, "bf16x3") == pinned
+    assert resolve_variant(kc, 12800, 128, na, "bf16x3") \
+        != tuned_variant(kc)
+    assert resolve_variant(kc, 12800, 128, na, "f32") == tuned_variant(kc)
     from dmlp_tpu.obs import trace as obs_trace
     tracer = obs_trace.install(obs_trace.Tracer())
     try:

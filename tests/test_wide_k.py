@@ -155,6 +155,10 @@ def test_the_multipass_path_says_what_it_did(served, kind, name):
     assert eng["extract_chunks"] == 1
     assert eng["last_mp_passes"] == passes
     assert eng["last_kernel_calls"] == 1 + passes - 1
+    # every pass, the fold and the sweeps, at the exact engines' form:
+    # three bf16 MXU passes over split operands (PR 36)
+    assert eng["precision_plan"] == "bf16x3"
+    assert eng["last_precision"]["active"] == "bf16x3"
     mp, mp0 = eng["multipass"], before["multipass"]
     assert mp["batches"] == mp0["batches"] + 1
     assert mp["passes"] == mp0["passes"] + passes

@@ -12,8 +12,9 @@ Four invariants, each a hard failure:
 2. **Non-vacuity** — the bf16 arm's metrics summary must carry a
    ``precision`` block reporting ``active == "bf16"`` with a strictly
    positive ``kcap_inflation`` (the candidate window really widened by
-   the lowp_eps margin); the f32 arm must report ``active == "f32"``
-   with zero inflation. A "bf16" arm that silently ran f32 is an
+   the lowp_eps margin); the f32 arm must report the float32 form
+   (``active == "bf16x3"``, or ``"f32"`` under bfloat16 staging) with
+   zero inflation. A "bf16" arm that silently ran f32 is an
    identical-code A/B masquerading as a feature.
 3. **Ladder recovery** — under a seeded ``oom`` schedule the solve
    must step off the top ``lowp`` rung (``lowp -> prune`` in the
@@ -161,7 +162,10 @@ def main(argv=None) -> int:
     if not prec["bf16"].get("kcap_inflation", 0) > 0:
         fail("bf16 arm reports zero kcap inflation — the lowp_eps "
              "margin never reached the candidate window")
-    if prec["f32"].get("active") != "f32" \
+    # (the f32 arm's pass is the float32 FORM: three bf16 passes over
+    # split operands in exact mode at float32 staging, "bf16x3"; the one
+    # HIGHEST dot, "f32", where the staging is bfloat16)
+    if prec["f32"].get("active") not in ("bf16x3", "f32") \
             or prec["f32"].get("kcap_inflation", 0) != 0:
         fail(f"f32 kill-switch arm reports {prec['f32']!r}")
     print(f"precision_smoke: bf16 arm active with kcap "
