@@ -93,6 +93,19 @@ class EngineConfig:
         $DMLP_TPU_PRECISION overrides at resolve time ("f32" = kill
         switch, "bf16" = force). int8 is the gated follow-on (ROADMAP):
         its bound needs data-dependent quantization scales.
+      boundary_retry: where the serving engine repairs a query the
+        boundary-hazard test flagged. True (the default): the flagged
+        queries of a micro-batch are solved again ON THE DEVICE over
+        the resident stack at the kernel's widest single-pass window
+        (serve.engine.ResidentEngine._retry_begin) and only what that
+        window does not clear goes to the host oracle
+        (engine.finalize.repair_boundary_overflow: a pass over the
+        whole float64 host corpus on the batcher thread, 13 s a batch
+        at 10^7 x 128 rows). False: the oracle alone, and no retry
+        program compiled or priced. Answers are identical either way.
+        A batch solve and a mesh daemon have the oracle alone whatever
+        this says (they keep nothing resident to fold again, or no
+        one-chip stack).
     """
 
     AUTO_SELECT_THRESHOLD = 8192
@@ -108,6 +121,7 @@ class EngineConfig:
     debug: bool = False
     use_pallas: bool = False
     precision: str = "auto"
+    boundary_retry: bool = True
 
     def __post_init__(self) -> None:
         if self.mode not in ("single", "sharded", "ring", "auto"):

@@ -198,7 +198,20 @@ def resolve_kcap(cfg: EngineConfig, kmax: int, select: str, cap: int,
     shape: a 32-slot window leaves 3453/10000 queries flagged, 64 slots
     71, 96 slots 0 — the constant is that measurement plus headroom; the
     (vectorized-oracle) repair stays as the sound backstop for inputs
-    whose distance density outruns it.
+    whose distance density outruns it. Measured again where the default
+    dtype serves a published slice (PR 38, TPU v5 lite, 10^7 uniform
+    [0, 255) rows of 128 attributes, k = 10 in the k16 bucket's
+    120-slot window, 16 384 distinct queries a seed): 0 to 5 queries
+    flagged by seed, 12 of 147 456 over nine seeds (about 1 in 10^4),
+    and the batch's tightest query clears its bound 1.06-1.07 x in the
+    median batch of 1024 (0.99-1.00 x in the worst): the window sits AT
+    the bound there, not 2.3 x over it as the reckoning from a normal
+    tail had it. The constant stays: 96 slots more would clear ~1.5 x
+    and triple the float64 gather of every query to spare one in 10^4
+    a retry, and the serving engine now repairs a flagged query on the
+    device at 512 slots (serve.engine.ResidentEngine._retry_begin: every
+    flagged query of those runs cleared there) for about a fifth of a
+    batch's cycle (~35 ms of 188: PERF.md section 5).
 
     ``precision`` is the first-pass dot precision the window must clear
     (config.resolve_precision when None — the inflation is planned from
@@ -608,7 +621,8 @@ class PendingRun:
     # the multipass driver's per-query loss flags (stall / shortfall)
     mp_hazard: Optional[np.ndarray] = None
     phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
-    repairs: int = 0              # queries the boundary repair recomputed
+    repairs: int = 0              # queries the hazard test flagged
+    retry_cleared: int = 0        # of them, repaired by the device retry
 
 
 class SingleChipEngine:
@@ -1477,17 +1491,8 @@ class SingleChipEngine:
                     hz.set(dn_max_cached=cached)
                     qn = np.einsum("qa,qa->q", sub.query_attrs,
                                    sub.query_attrs)
-                    eps = staging_eps(last, qn, dn_max, self._staging,
-                                      inp.params.num_attrs)
-                    if select == "extract":
-                        # A first pass that drops products ("bf16x3",
-                        # "bf16") perturbs device distances by up to
-                        # the lowp_eps of the form that ran ON TOP of
-                        # the staging rounding; the hazard test must
-                        # clear both. Streaming-fallback segments never
-                        # split or cast, so their eps stays the staging
-                        # bound alone.
-                        eps = eps + lowp_eps(prec, qn, dn_max)
+                    eps = self._hazard_eps(last, qn, dn_max, select, prec,
+                                           inp.params.num_attrs)
                     flags = boundary_hazard(kth, last, eps)
                     # How many times its bound the window clears, for
                     # the batch's tightest query: 1 or less is a flag.
@@ -1515,19 +1520,31 @@ class SingleChipEngine:
             with obs_span("single.finalize", exact=self.config.exact,
                           gather_bytes=ids.size * inp.params.num_attrs * 8
                           if self.config.exact else 0, **targs) as sp:
+                suspects = np.nonzero(flags)[0] if flags is not None \
+                    else np.zeros(0, np.intp)
+                # The flagged queries go back to the device first, where
+                # the engine keeps its corpus there (_retry_begin only
+                # enqueues, so the host finalizes the batch meanwhile);
+                # what the wider window does not clear, and every
+                # flagged query of an engine without a retry, is the
+                # host oracle's.
+                retry = self._retry_begin(pend, sub, suspects, select,
+                                          kcap) if suspects.size else None
                 results = finalize_host(dists, labels, ids, sub.ks,
                                         sub.query_attrs, sub.data_attrs,
                                         exact=self.config.exact,
                                         query_ids=idx)
-                if flags is not None:
-                    suspects = np.nonzero(flags)[0]
-                    if suspects.size:
-                        with obs_span("single.repair",
-                                      queries=int(suspects.size), **targs):
-                            repair_boundary_overflow(results, suspects,
-                                                     sub)
-                        pend.repairs += int(suspects.size)
-                        sp.set(repairs=int(suspects.size))
+                if suspects.size:
+                    # ``repairs`` = queries FLAGGED, wherever repaired
+                    pend.repairs += int(suspects.size)
+                    sp.set(repairs=int(suspects.size))
+                    if retry is not None:
+                        suspects = self._retry_finish(pend, sub, retry,
+                                                      results, dn_max)
+                if suspects.size:
+                    with obs_span("single.repair",
+                                  queries=int(suspects.size), **targs):
+                        repair_boundary_overflow(results, suspects, sub)
             if idx is None:
                 merged = results
             else:
@@ -1549,6 +1566,37 @@ class SingleChipEngine:
         the serving core (serve.engine.ResidentServingCore) tags the
         micro-batch it is solving."""
         return {}
+
+    def _hazard_eps(self, last, qn, dn_max: float, select: str,
+                    prec: str, na: int) -> np.ndarray:
+        """The hazard test's per-query bound for a candidate list whose
+        last distance is ``last``: the staging rounding and, on the
+        extract path, what the first pass's form drops ON TOP of it
+        ("bf16x3", "bf16": finalize.lowp_eps; the test must clear
+        both). Streaming-fallback segments never split or cast, so
+        their bound stays the staging one alone."""
+        eps = staging_eps(last, qn, dn_max, self._staging, na)
+        if select == "extract":
+            eps = eps + lowp_eps(prec, qn, dn_max)
+        return eps
+
+    def _retry_begin(self, pend: PendingRun, sub: KNNInput,
+                     suspects: np.ndarray, select: str, kcap: int):
+        """Seam: enqueue a device retry of the flagged queries
+        ``suspects`` (positions in ``sub``) at a wider window and
+        return its record for :meth:`_retry_finish`, or None where the
+        host oracle repairs them. A batch solve stages its corpus for
+        one run and keeps nothing to fold again: it keeps the oracle
+        (as the mesh engines do); the serving engine
+        (serve.engine.ResidentEngine) folds its resident stack."""
+        return None
+
+    def _retry_finish(self, pend: PendingRun, sub: KNNInput, retry,
+                      results: List[QueryResult],
+                      dn_max: float) -> np.ndarray:
+        """Seam: read ``retry`` back, put the answers it cleared into
+        ``results`` and return the positions still flagged."""
+        raise NotImplementedError
 
     def _corpus_dn_max(self, inp: KNNInput) -> Tuple[float, bool]:
         """Seam: the largest squared data-row norm of ``inp``'s corpus in

@@ -762,6 +762,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                               **self._rid_args()):
                     repair_boundary_overflow(results, suspects, inp)
                 self.last_repairs += int(suspects.size)
+                # every flagged query is the host oracle's here: the
+                # device retry is the one-chip engine's
+                self._note_flagged(int(suspects.size))
             sp.set(repairs=int(suspects.size))
         t3 = clock()
         self.last_phase_ms["fetch"] = (t1 - t0) * 1e3
@@ -903,6 +906,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             "gate_carry": self.gate_carry,
             "last_gated_fraction": self.last_gated_fraction,
             "overlap": self._overlap_stats(),
+            "repairs": self._repair_stats(),
             "extract_chunks": (self._nchunks if self._chunks is not None
                                else 0),
             "summary_blocks": (r * self._nchunks if self._summ else 0),

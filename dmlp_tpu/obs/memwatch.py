@@ -298,7 +298,8 @@ def serve_engine_model(capacity_rows: int, na: int,
                        chunk_rows: int = 0,
                        summary_blocks: int = 0,
                        chunk_attrs: int = 0,
-                       mp_slots: int = 0) -> Dict[str, Any]:
+                       mp_slots: int = 0,
+                       retry: tuple = (0, 0)) -> Dict[str, Any]:
     """Peak resident device bytes for the serving layer's
     :class:`~dmlp_tpu.serve.engine.ResidentEngine`: the capacity-padded
     resident corpus (+ labels/ids mask arrays), the extract path's
@@ -316,7 +317,12 @@ def serve_engine_model(capacity_rows: int, na: int,
     (compiled for a v5e at 1024 x 1536: 26.8 MB of temporaries for
     12.6 MB of lists): four times the lists, beside the merged
     (qpad, kcap) result that ``topk_carries`` prices. The sweeps read
-    the resident stack itself: no term of the corpus's size."""
+    the resident stack itself: no term of the corpus's size. ``retry``
+    is the (query rows, slots) of the device retry of the bucket's
+    flagged queries ((0, 0): the bucket has none): one more padded
+    query block and one more pair of candidate lists, of ONE group (a
+    later group's reuse the first's once it is read back), beside the
+    batch's own; it folds the resident stack too."""
     item = _staging_itemsize(staging)
     ca = chunk_attrs or na
     terms: Dict[str, int] = {
@@ -336,6 +342,9 @@ def serve_engine_model(capacity_rows: int, na: int,
         if mp_slots:
             # a list slot is a float32 distance and an int32 id
             terms["multipass_lists"] = 4 * qpad * mp_slots * 8
+        if retry[0]:
+            terms["retry_lists"] = retry[0] * ca * item \
+                + 2 * retry[0] * retry[1] * _TOPK_ITEMSIZE
     return _finish(terms, kind="serve", capacity_rows=capacity_rows,
                    staging=staging)
 

@@ -352,8 +352,8 @@ def complete_at(name: str, t0: float, t1: float, **args) -> None:
 # reads and a per-thread tally, always on. A seam that has a span of its
 # own around exactly the wait (``single.fetch``, ``serve.mp_fetch``,
 # ``fleet.fetch``, ``fleet.merge_drain``) names its ``site`` on that
-# span; the others (``prune_score``, ``gate``, ``merge``) get a
-# ``serve.wait.device`` span while a sink is installed. The
+# span; the others (``prune_score``, ``gate``, ``merge``, ``retry``)
+# get a ``serve.wait.device`` span while a sink is installed. The
 # micro-batcher reads its own thread's tally once a cycle
 # (serve/batching.py).
 
@@ -388,7 +388,8 @@ def wait_tally() -> WaitTally:
 
 class device_wait:  # noqa: N801 (used as ``with device_wait(site):``)
     """Bracket one host sync: ``site`` names the seam (``prune_score``,
-    ``fetch``, ``mp_fetch``, ``gate``, ``merge_drain``, ``merge``).
+    ``fetch``, ``mp_fetch``, ``gate``, ``merge_drain``, ``merge``,
+    ``retry``).
     Retries and injected faults of the call inside stay inside; an
     exception still counts the wait. ``span=False`` where the caller's
     own span already brackets the wait (and carries ``site``): the

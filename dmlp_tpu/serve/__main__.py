@@ -84,6 +84,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "(plan frozen at startup; $DMLP_TPU_PRECISION "
                         "=f32 is the live kill switch). Fleet replicas "
                         "inherit this through --spawn-flags.")
+    p.add_argument("--boundary-retry", choices=["on", "off"], default="on",
+                   help="repair a query the boundary-hazard test flags "
+                        "on the device first (one more fold of the "
+                        "resident stack at a 512-slot window) and on "
+                        "the host only what that does not clear; off = "
+                        "the host oracle alone; answers are identical "
+                        "either way")
     p.add_argument("--data-block", type=int, default=None)
     p.add_argument("--warm-buckets", default=None, metavar="NQxK,...",
                    help="extra shape buckets to compile before ready")
@@ -164,7 +171,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     config = EngineConfig(dtype=args.dtype, select=args.select,
                           use_pallas=args.pallas,
                           data_block=args.data_block,
-                          precision=args.precision)
+                          precision=args.precision,
+                          boundary_retry=args.boundary_retry == "on")
     mesh_shape = None
     if args.mesh:
         try:

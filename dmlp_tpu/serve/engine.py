@@ -82,13 +82,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.engine.finalize import boundary_hazard, finalize_host
 from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, PendingRun,
-                                    SingleChipEngine, _extract_finalize,
-                                    _topk_blocks, active_precision,
-                                    fit_blocks, np_staging_dtype,
-                                    plan_chunks, resilient_get, resolve_kcap,
-                                    round_up, stage_put)
-from dmlp_tpu.io.grammar import KNNInput, Params
+                                    SingleChipEngine, _boundary_cols,
+                                    _extract_finalize, _topk_blocks,
+                                    active_precision, fit_blocks,
+                                    np_staging_dtype, plan_chunks,
+                                    resilient_get, resolve_kcap, round_up,
+                                    stage_put)
+from dmlp_tpu.io.grammar import KNNInput, Params, subset_queries
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import telemetry
 from dmlp_tpu.obs import trace as obs_trace
@@ -396,6 +398,29 @@ class ResidentServingCore:
             self.trace_batch, self.trace_rids = prev
 
     @staticmethod
+    def _note_flagged(flagged: int, device: int = 0) -> None:
+        """Always-on counts of the boundary repair: queries the hazard
+        test flagged, and where each was repaired (``device``: cleared
+        by the retry at a wider window; the rest by the host oracle)."""
+        if not flagged:
+            return
+        reg = telemetry.registry()
+        reg.counter("serve.flagged_queries").inc(flagged)
+        for label, count in (("device", device),
+                             ("host", flagged - device)):
+            if count:
+                reg.counter("serve.repairs").inc(count, label=label)
+
+    @staticmethod
+    def _repair_stats() -> Dict[str, int]:
+        reg = telemetry.registry()
+        repairs = reg.counter("serve.repairs")
+        return {"flagged_queries": int(reg.counter(
+                    "serve.flagged_queries").total()),
+                "device": int(repairs.value("device")),
+                "host": int(repairs.value("host"))}
+
+    @staticmethod
     def _overlap_stats() -> Dict[str, int]:
         """Always-on counts of the batcher's pipeline: micro-batches
         delivered, and those begun while another was in flight."""
@@ -464,11 +489,17 @@ class ResidentServingCore:
                     getattr(self, "last_variant", None)))
             per[f"q{key[0]}k{key[1]}"] = round(
                 (time.perf_counter() - tb) * 1e3, 3)
+        per.update(self._warm_more())
         self.cold_start_compile_ms = round(
             (time.perf_counter() - t0) * 1e3, 3)
         telemetry.registry().gauge("serve.cold_start_compile_ms").set(
             self.cold_start_compile_ms)
         return per
+
+    def _warm_more(self) -> Dict[str, float]:
+        """Seam: programs beside the buckets' own that warm-up
+        front-loads ({name: wall ms}); none by default."""
+        return {}
 
     # -- corpus signature (fleet consistency checking reads this) -----------
 
@@ -768,6 +799,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         # two: the batcher begins one behind the one it finishes next).
         # The batcher thread's alone, like everything a solve touches.
         self._in_flight: List[PendingBatch] = []
+        # the device retry's programs have compiled (_retry_begin)
+        self._retry_built = False
         # Cross-request gate state: per-chunk winner histogram.
         self._block_hits = np.zeros(max(self._ex_nchunks, 1), np.int64)
         # kernel calls the last solve's programs made: the chunks an
@@ -1376,6 +1409,164 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                 reg.counter("serve.multipass_flagged").inc(count,
                                                            label=label)
 
+    # -- the device retry of flagged queries (boundary repair, stage 1) -------
+
+    #: query rows of one retry fold: ONE short query tile (a bfloat16
+    #: block's 16 sublanes). A flagged batch holds one or two flagged
+    #: queries (about 1 in 10^4 under bfloat16 staging); measured over
+    #: 10^7 x 128 bf16 rows at 512 slots, fold + epilogue + readback
+    #: (PR 38, TPU v5 lite; PERF.md section 5): 8 rows 19.0 ms, 16 rows
+    #: 19.6, 32 rows 20.9, 64 rows 24.3, the buckets' own granule of
+    #: 128 rows (two tiles of 64) 38.4. More flagged queries than this
+    #: go in groups: the corpus is read once a group.
+    _RETRY_QUERIES = 16
+
+    def _retry_kernel(self, kcap: int, select: Optional[str] = "extract"):
+        """(impl, qpad, kc) of the device retry of a bucket whose first
+        window held ``kcap`` slots, or None where the host oracle
+        repairs instead: the flagged queries, _RETRY_QUERIES of them at
+        a time, folded again over the resident stack at the kernel's
+        widest single-pass window. A bucket that already planned that
+        window or more (a multipass bucket's loss flags too), a solve
+        off the extract path and an engine off its kernel rungs keep the
+        oracle, as does an engine told to (``boundary_retry`` False).
+        The fold is the buckets' own program (``_fold_stack``) at one
+        more shape, whatever the bucket: one compile an engine."""
+        kc = self._MP_KC
+        if (not self.config.boundary_retry or select != "extract"
+                or self._chunks is None or kcap >= kc
+                or self._degrade_rung == "streaming"):
+            return None
+        from dmlp_tpu.ops import pallas_fused
+        qpad = self._RETRY_QUERIES
+        kern, impl = pallas_fused.resolve_topk_kernel(
+            qpad, self._ex_chunk_rows, self._ex_attrs, kc,
+            rung=self._degrade_rung)
+        return None if kern is None else (impl, qpad, kc)
+
+    def _retry_begin(self, pend: PendingBatch, sub: KNNInput,
+                     suspects: np.ndarray, select: str, kcap: int):
+        """ENQUEUE the retry of the flagged queries: each group of
+        _RETRY_QUERIES padded like a micro-batch, every chunk that holds
+        rows folded in natural order (no pruning, no gate order: the
+        answer does not depend on either), the lists sorted and their
+        boundary columns taken on the device. Nothing waits here: the
+        host finalizes the batch while the device works through the
+        batch begun behind this one and then through this."""
+        plan = None if pend.mp_passes else self._retry_kernel(kcap, select)
+        if plan is None:
+            return None
+        impl, qpad, kc = plan
+        t0 = time.perf_counter()
+        first = not self._retry_built
+        order = range(-(-self.n_real // self._ex_chunk_rows))
+        groups = []
+        with obs_span("single.retry_begin", queries=int(suspects.size),
+                      kcap=kc, **self._rid_args()):
+            for g0 in range(0, suspects.size, qpad):
+                idx = suspects[g0:g0 + qpad]
+                gin = subset_queries(sub, idx)
+                od, oi, _gated, _tiles = self._fold_resident(
+                    self._stage_batch_queries(gin, qpad), order, impl,
+                    kc, pend.prec)
+                top = _extract_finalize(od, oi, self._d_labels, k=kc)
+                ks_pad = np.ones(qpad, np.int32)
+                ks_pad[:idx.size] = gin.ks
+                cols = _boundary_cols(top.dists, jax.device_put(ks_pad))
+                groups.append((idx, gin, ([] if self.config.exact
+                                          else [top.dists])
+                               + [top.ids, cols]))
+        if first:
+            # the retry's programs compile on their first dispatch:
+            # warm-up's, where the daemon warmed a bucket (_warm_more)
+            self._retry_built = True
+            self.compile_count += 1
+            self.bucket_compile_ms["retry"] = round(
+                (time.perf_counter() - t0) * 1e3, 3)
+        return t0, groups, kc
+
+    def _retry_finish(self, pend: PendingBatch, sub: KNNInput, retry,
+                      results: List[QueryResult],
+                      dn_max: float) -> np.ndarray:
+        """The retry's fence and the host's share: the SAME hazard test
+        (the first pass's bounds, at the wider list's last distance) on
+        each retried query, the float64 rescore of the wider lists
+        (device distances in fast mode), and the answers of the queries
+        that cleared put where the first window's stood. Returns the
+        positions still flagged: the host oracle's."""
+        t0, groups, kc = retry
+        n = sub.params.num_data
+        exact = self.config.exact
+        nq = sum(int(idx.size) for idx, _gin, _dev in groups)
+        left = []
+        clock = time.perf_counter
+        t1 = clock()
+        with obs_span("single.retry", queries=nq, kcap=kc, passes=1,
+                      enqueue_ms=round((t1 - t0) * 1e3, 3),
+                      **self._rid_args()) as sp:
+            # The fold this waits for queued behind the fold of the
+            # batch begun meanwhile: up to a whole fold of sleep, which
+            # is that batch's device time and not the retry's
+            # (``wait_ms``; ``host_ms`` is the rest of the span).
+            tw = clock()
+            with obs_trace.device_wait("retry", self.trace_batch):
+                fetched = resilient_get([dev for _i, _g, dev in groups])
+            t2 = clock()
+            for (idx, gin, _dev), got in zip(groups, fetched):
+                m = int(idx.size)
+                got = list(got)
+                dists = None if exact \
+                    else np.asarray(got.pop(0), np.float64)[:m]
+                ids = got.pop(0)[:m]
+                kth, last = np.asarray(got.pop(0), np.float64)[:, :m]
+                qn = np.einsum("qa,qa->q", gin.query_attrs,
+                               gin.query_attrs)
+                still = boundary_hazard(kth, last, self._hazard_eps(
+                    last, qn, dn_max, "extract", pend.prec,
+                    sub.params.num_attrs))
+                labels = np.where(
+                    ids >= 0, sub.labels[np.clip(ids, 0, n - 1)], -1)
+                fixed = finalize_host(
+                    dists, labels, ids, gin.ks, gin.query_attrs,
+                    sub.data_attrs, exact=exact,
+                    query_ids=np.asarray(
+                        [results[int(qi)].query_id for qi in idx]))
+                for j, qi in enumerate(idx):
+                    if not still[j]:
+                        results[int(qi)] = fixed[j]
+                left.append(idx[still])
+            left = np.concatenate(left)
+            pend.retry_cleared += nq - int(left.size)
+            sp.set(cleared=nq - int(left.size),
+                   fell_through=int(left.size),
+                   wait_ms=round((t2 - tw) * 1e3, 3),
+                   host_ms=round((clock() - t2) * 1e3, 3))
+        return left
+
+    def _warm_more(self) -> Dict[str, float]:
+        """The retry's programs, front-loaded with the buckets' own
+        wherever a warmed bucket has a retry (_retry_kernel): under
+        bfloat16 staging about one query in 10^4 is flagged on uniform
+        rows (PERF.md), so a daemon meets its first within seconds; under
+        float32 staging no measured cell has flagged one, and the first
+        that does must not compile on the batcher thread either."""
+        kcaps = [e.kcap for e in self._buckets.values()
+                 if e.path == "extract"]
+        plan = self._retry_kernel(min(kcaps)) if kcaps else None
+        if plan is None or self._retry_built:
+            return {}
+        t0 = time.perf_counter()
+        with obs_span("serve.warmup_retry", qpad=plan[1], kcap=plan[2]):
+            inp = self._batch_input(self._host_attrs[:1],
+                                    np.ones(1, np.int32))
+            pend = PendingBatch(inp, prec=active_precision(self))
+            retry = self._retry_begin(pend, inp, np.zeros(1, np.intp),
+                                      "extract", 0)
+            self._retry_finish(pend, inp, retry, [QueryResult(
+                0, 1, -1, np.full(1, -1, np.int64), np.full(1, np.inf))],
+                self._dn_max())
+        return {"retry": round((time.perf_counter() - t0) * 1e3, 3)}
+
     def _chunk_order(self) -> List[int]:
         """Fold order over the resident chunks: hottest (most past
         winners) first when gate carry-over is on, natural otherwise.
@@ -1423,6 +1614,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     def _run_finish(self, pend: PendingBatch) -> List[QueryResult]:
         results = super()._run_finish(pend)
+        self._note_flagged(pend.repairs, pend.retry_cleared)
         self._after_batch(pend, results)
         # What `stats` reports of "the last solve" is the last batch
         # FINISHED, whole: each field one assignment, read lock-free.
@@ -1567,12 +1759,17 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             chunk_rows=self._ex_chunk_rows, chunk_attrs=self._ex_attrs,
             summary_blocks=(self._ex_nchunks
                             if self._summ_dev is not None else 0),
-            mp_slots=self._mp_passes(kcap) * self._MP_KC)
+            mp_slots=self._mp_passes(kcap) * self._MP_KC,
+            # the retry's lists, wherever _retry_kernel can give one
+            retry=(self._RETRY_QUERIES, self._MP_KC)
+            if self.config.boundary_retry and self._chunks is not None
+            and 0 < kcap < self._MP_KC else (0, 0))
 
     def batch_model_bytes(self, nq: int, kmax: int) -> int:
         terms = self.mem_model(nq, kmax)["terms"]
         return int(terms["query_blocks"] + terms["topk_carries"]
-                   + terms.get("multipass_lists", 0))
+                   + terms.get("multipass_lists", 0)
+                   + terms.get("retry_lists", 0))
 
     def resident_state_key(self):
         # The floor moves when the extract chunks stage (wide-k sweeps
@@ -1634,6 +1831,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             "last_mp_passes": self.last_mp_passes,
             "multipass": self._multipass_stats(),
             "overlap": self._overlap_stats(),
+            # queries the hazard test flagged, and where each was
+            # repaired (the device retry / the host oracle)
+            "repairs": self._repair_stats(),
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,
             "last_prune_fraction": self.last_prune_fraction,

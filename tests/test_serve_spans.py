@@ -32,7 +32,12 @@ CYCLE = ["serve.batch_assemble", "serve.micro_batch", "serve.batch_deliver"]
 IN_BATCH = ["serve.solve_stage", "serve.solve_extract",
             "serve.solve_epilogue", "single.fetch", "single.hazard",
             "single.finalize", "serve.after_batch"]
-NESTED = {"single.repair": "single.finalize"}
+#: a flagged batch's device retry (PR 38): its enqueue before the
+#: batch's own float64 finalize, its fence and rescore after, both
+#: inside the span; what the retry does not clear would add
+#: single.repair there (tests/test_serve_retry.py)
+NESTED = {"single.retry_begin": "single.finalize",
+          "single.retry": "single.finalize"}
 #: the whole-corpus norm pass: set-up's since PR 26, no batch's child
 DN_MAX = "single.dn_max"
 REQUEST = ["parse", "queue", "coalesce", "solve", "finalize", "respond",
@@ -42,7 +47,7 @@ REQUEST = ["parse", "queue", "coalesce", "solve", "finalize", "respond",
 def tied_corpus(copies=40, points=60, seed=5) -> KNNInput:
     """Every point ``copies`` times: a query AT a point finds more rows
     at distance 0 than the candidate window holds, so the boundary test
-    flags it and the host repair runs."""
+    flags it and the device retry runs (its 512 slots hold the 40)."""
     rng = np.random.default_rng(seed)
     pts = rng.integers(-8, 9, (points, NA)).astype(np.float64)
     rows = np.repeat(pts, copies, axis=0)
@@ -158,7 +163,15 @@ def test_span_args_say_what_the_work_was(traced):
     # every query sits on 40 copies of its point: all three are flagged
     assert ev["single.hazard"]["flagged"] == 3
     assert ev["single.finalize"]["repairs"] == 3
-    assert ev["single.repair"]["queries"] == 3
+    assert ev["single.retry_begin"]["queries"] == 3
+    retry = ev["single.retry"]
+    assert (retry["queries"], retry["kcap"], retry["passes"]) == (3, 512, 1)
+    assert (retry["cleared"], retry["fell_through"]) == (3, 0)
+    assert not named(traced["served"], "single.repair")
+    # always on, a process's: warm-up's batch of corpus rows flags too
+    counts = traced["stats"]["engine"]["repairs"]
+    assert counts["flagged_queries"] == counts["device"] + counts["host"]
+    assert counts["device"] >= 3
     assert ev["serve.after_batch"]["tiles"] >= 1
     assert ev["serve.phase.parse"]["queries"] == 3
     assert ev["serve.phase.parse"]["bytes"] > 0

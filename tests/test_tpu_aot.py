@@ -119,6 +119,44 @@ def test_resident_fold_program_compiles_for_v5e(v5e, precision):
     assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
 
 
+@pytest.mark.parametrize(
+    "staged,precision,chunks,attrs",
+    [(jnp.bfloat16, "f32", 328, 128), (jnp.float32, "bf16x3", 82, 128),
+     (jnp.float32, "bf16x3", 21, 1024)],
+    ids=["bigann-10m_bf16", "bigann_f32", "gist_f32"])
+def test_retry_fold_program_compiles_for_v5e(v5e, staged, precision, chunks,
+                                             attrs):
+    """The device retry of flagged queries (PR 38), which warm-up
+    compiles with every extract bucket: ONE short query tile of 16 rows
+    (a bfloat16 block's sublanes) at the kernel's widest window, 512
+    slots, over the resident stack of each one-chip cell: the buckets'
+    own fold program at one more shape. ``bigann-10m.bulk``'s 328
+    chunks of 51 200 x 128 under the default bfloat16 staging (whose
+    rows take the one ``HIGHEST`` dot), ``bigann.bulk``'s 82 under
+    float32, ``gist.bulk``'s 21 of 960 attributes on 1024 lanes."""
+    from dmlp_tpu.serve.engine import (ResidentEngine, _fold_stack,
+                                       _kernel_statics)
+    q, kc = ResidentEngine._RETRY_QUERIES, ResidentEngine._MP_KC
+    assert (q, kc) == (16, 512)
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    statics = _kernel_statics("fused", kc, 51200, q, attrs, precision,
+                              False)
+    assert statics["tile_q"] >= q           # one tile: all 16 rows
+    compiled = _fold_stack.lower(
+        spec((q, attrs), staged), spec((chunks, 51200, attrs), staged),
+        spec((chunks,), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32), **statics).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.1 * 51200 * attrs * 4
+
+
 @F32_FORMS
 def test_wide_row_fold_program_compiles_for_v5e(v5e, precision):
     """The same program at ``gist.bulk``'s shape: q1024, 21 resident
