@@ -10,6 +10,9 @@ binary (``oracle_capture/oracle_4.out``):
   batch     python -m dmlp_tpu --pallas ...          cmp oracle
   batch.f32 ... --dtype float32                      cmp oracle; the first
                                                      pass ran as "bf16x3"
+  fold.bf16 python chip_smoke.py --fold-child        one resident fold of
+                                                     bf16 rows: one MXU
+                                                     pass, HIGHEST's lists
   serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
                                                      stats, SIGTERM drain
   mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
@@ -34,6 +37,19 @@ run the split through Mosaic on THIS chip and found |x - hi - lo| <=
 a compiler that starts folding the casts (as XLA:TPU does outside a
 kernel) fails here instead of running a one-pass error under the
 three-pass bound, which no checksum shows.
+
+``fold.bf16`` is the same kind of proof for rows staged in bfloat16 (the
+default on a chip). The kernel is handed the bf16 block itself and
+contracts it in ONE MXU pass, on the argument that bf16 x bf16 products
+are exact in float32 and the MXU accumulates in float32, so the pass
+drops nothing of what the six-pass ``HIGHEST`` dot computes from the
+same values, and widens no bound for it (LOWP_COEF["f32"] = 0). The
+child folds one seeded stack through the serving engine's own program
+twice, as bfloat16 and as the same values in float32 (the ``HIGHEST``
+dot), and fails unless the compiled program hands the kernel bfloat16
+rows, ``mxu_passes`` reads 1 and the two runs' lists are equal to the
+bit: a chip whose single pass rounded differently would show here and
+nowhere on a CPU, whose two dots are one float32 loop.
 
 The configs run in the order given (default ``1,4``): config 1 is the
 same path at a size that takes seconds, so a machine with no chip fails
@@ -172,7 +188,8 @@ def stamp_line(stamp: Dict[str, Any], cache: Dict[str, Any]) -> str:
             f"impl={stamp.get('extract_impl')} "
             f"interpret={stamp.get('pallas_interpret')} "
             f"rung={stamp.get('degrade_rung')} "
-            f"variant=tq{v.get('tile_q')}/ne{v.get('ne')}/kc{v.get('kc')} "
+            f"variant=tq{v.get('tile_q')}/ne{v.get('ne')}/kc{v.get('kc')}"
+            f"/mxu_passes{v.get('mxu_passes')} "
             f"repairs={stamp.get('repairs')}\n"
             f"    compile (smoke timing): backend "
             f"{cache.get('backend_compile_ms')} ms; cache {hit}")
@@ -227,10 +244,12 @@ def phase_generate(c: Config) -> List[str]:
 
 def phase_solve(c: Config, name: str, mode_args: List[str],
                 mesh: Optional[List[int]], ladder: bool,
-                form: Optional[str] = None
+                form: Optional[str] = None, passes: Optional[int] = None
                 ) -> Tuple[List[str], Optional[Dict[str, Any]]]:
     """A batch solve through ``python -m dmlp_tpu``; (misses, stamp).
-    ``form``: the first-pass form the child's record must name."""
+    ``form``: the first-pass form the child's record must name;
+    ``passes``: the MXU passes a visit its variant stamp must (a child
+    on a chip: the default staging dtype follows the platform)."""
     metrics = c.log(f"{name}.metrics.jsonl")
     if os.path.exists(metrics):
         os.remove(metrics)
@@ -261,7 +280,141 @@ def phase_solve(c: Config, name: str, mode_args: List[str],
                    "split check (ops.pallas_extract.split_holds) "
                    f"refused it on this compiler:\n"
                    f"{tail(c.log(name + '.err'))}")
+    if passes is not None and isinstance(stamp, dict) \
+            and stamp.get("platform") == "tpu":
+        got = (stamp.get("kernel_variant") or {}).get("mxu_passes")
+        if got != passes:
+            bad.append(f"the cross term took {got} MXU passes a visit, "
+                       f"not {passes}")
     return bad, stamp if isinstance(stamp, dict) else None
+
+
+FOLD_SHAPE = dict(queries=1024, attrs=128, kc=120, chunks=3)
+
+
+def fold_child(out_path: str) -> int:
+    """The ``fold.bf16`` child: one process, the chip its own. Seeded
+    reals in [0, 255) rounded to bfloat16, folded by
+    serve.engine._fold_stack at ``bigann-10m.bulk``'s dispatch shape
+    (q1024, kc 120, chunks of 51 200 x 128; 1 024-row chunks where the
+    kernel runs interpreted, the dry run) as bfloat16 and as the same
+    values in float32; what it found goes to ``out_path`` as JSON."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dmlp_tpu.obs.hlo import kernel_operand_types
+    from dmlp_tpu.obs.run import device_stamp
+    from dmlp_tpu.ops import pallas_fused
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    interpret = pallas_interpret()
+    nq, na, kc, chunks = (FOLD_SHAPE[k] for k in (
+        "queries", "attrs", "kc", "chunks"))
+    rows = 1024 if interpret else 51200
+    rng = np.random.default_rng(39)
+    q16 = jnp.asarray(rng.uniform(0, 255, (nq, na)), jnp.bfloat16)
+    d16 = jnp.asarray(rng.uniform(0, 255, (chunks, rows, na)), jnp.bfloat16)
+    kern = _kernel_statics("fused", kc, rows, nq, na, "f32", interpret)
+    order = jnp.arange(chunks, dtype=jnp.int32)
+    n_real = chunks * rows - 77           # the last block holds sentinels
+    lists, data_operands = {}, None
+    for name, cast in (("bfloat16", jnp.bfloat16), ("float32", jnp.float32)):
+        args = (q16.astype(cast), d16.astype(cast), order,
+                jnp.int32(chunks), jnp.int32(n_real))
+        if name == "bfloat16" and not interpret:
+            hlo = _fold_stack.lower(*args, **kern).compile().as_text()
+            # operand 2 of each kernel call: the data BlockSpec's
+            data_operands = [ops[2] for ops in kernel_operand_types(hlo)]
+        od, oi, _gated = _fold_stack(*args, **kern)
+        od, oi = jax.device_get((od, oi))
+        # a list's slots fill in the order its distances compare:
+        # judged in one order, by (distance, id)
+        slot = np.lexsort((oi, od), axis=1)
+        lists[name] = (np.take_along_axis(od, slot, 1),
+                       np.take_along_axis(oi, slot, 1))
+    (od16, oi16), (od32, oi32) = lists["bfloat16"], lists["float32"]
+    # the lists against float64, as a share of the scale |q|^2 + |d|^2
+    q64 = np.asarray(q16.astype(jnp.float32), np.float64)
+    d64 = np.asarray(d16.astype(jnp.float32), np.float64).reshape(-1, na)
+    diff = d64[oi16] - q64[:, None, :]
+    true = np.einsum("qka,qka->qk", diff, diff)
+    scale = np.einsum("qa,qa->q", q64, q64).max() \
+        + np.einsum("na,na->n", d64, d64).max()
+    with open(out_path, "w") as f:
+        json.dump({
+            "device": device_stamp(None),
+            "shape": dict(FOLD_SHAPE, chunk_rows=rows, n_real=n_real),
+            "mxu_passes": {
+                st: pallas_fused.variant_stamp(
+                    "fused", kc, rows, nq, na, "f32", st)["mxu_passes"]
+                for st in ("bfloat16", "float32")},
+            "kernel_data_operands": data_operands,
+            "ids_equal": bool(np.array_equal(oi16, oi32)),
+            "dists_equal": bool(np.array_equal(od16, od32)),
+            "dists_differ": int(np.count_nonzero(od16 != od32)),
+            "dists_max_abs_diff": float(np.max(np.abs(
+                od16.astype(np.float64) - od32))),
+            "err_over_scale_vs_float64": float(
+                np.max(np.abs(od16 - true)) / scale),
+            "ids_valid": bool((oi16 >= 0).all() and (oi16 < n_real).all()),
+        }, f)
+    return 0
+
+
+def phase_fold_bf16(c: Config) -> List[str]:
+    """``fold.bf16``: rows staged in bfloat16 reach the MXU as bfloat16,
+    in one pass, and give the ``HIGHEST`` dot's lists."""
+    out = c.log("fold.bf16.json")
+    if os.path.exists(out):
+        os.remove(out)
+    rc, wall = run_child([os.path.abspath(__file__), "--fold-child", out],
+                         None, c.log("fold.bf16.out"),
+                         c.log("fold.bf16.err"))
+    if rc != 0 or not os.path.exists(out):
+        return [f"child exited {rc}:\n{tail(c.log('fold.bf16.err'))}"]
+    with open(out) as f:
+        got = json.load(f)
+    say(f"  fold.bf16: wall {wall:.1f} s (smoke timing); {got['shape']}; "
+        f"mxu_passes {got['mxu_passes']}; the kernel's data operands "
+        f"{got['kernel_data_operands']}; lists against the float32 "
+        f"HIGHEST run: ids equal {got['ids_equal']}, distances unequal "
+        f"{got['dists_differ']} (largest gap "
+        f"{got['dists_max_abs_diff']:g}); "
+        f"against float64 {got['err_over_scale_vs_float64']:.3g} of the "
+        "scale")
+    return fold_misses(got)
+
+
+def fold_misses(got: Dict[str, Any]) -> List[str]:
+    """Every miss in the ``fold.bf16`` child's record, named."""
+    stamp = got.get("device") or {}
+    bad = []
+    if stamp.get("platform") != "tpu":
+        bad.append(f"platform is {stamp.get('platform')}")
+    if stamp.get("pallas_interpret") is not False:
+        bad.append(f"pallas_interpret is {stamp.get('pallas_interpret')}")
+    if got.get("mxu_passes") != {"bfloat16": 1, "float32": 6}:
+        bad.append(f"mxu_passes is {got.get('mxu_passes')}, not 1 for "
+                   "bfloat16 rows and 6 for float32's HIGHEST dot")
+    ops = got.get("kernel_data_operands")
+    if stamp.get("platform") == "tpu" and (
+            not ops or any(not o.startswith("bf16[") for o in ops)):
+        bad.append(f"the compiled fold hands the kernel {ops}, not the "
+                   "bfloat16 rows")
+    if not got.get("ids_valid"):
+        bad.append("the fold's lists hold ids outside the corpus")
+    # (judged on the chip alone: a CPU's two dots are float32 loops
+    # that sum in different orders, and no MXU)
+    if stamp.get("platform") == "tpu" \
+            and not (got.get("ids_equal") and got.get("dists_equal")):
+        bad.append(
+            "one pass over bfloat16 rows is NOT the HIGHEST dot's value "
+            f"on this device: ids equal {got.get('ids_equal')}, "
+            f"{got.get('dists_differ')} distances differ (largest "
+            f"{got.get('dists_max_abs_diff')}): LOWP_COEF['f32'] = 0 "
+            "does not hold for it")
+    return bad
 
 
 def read_queries(c: Config, count: int) -> Tuple[List[int], List[list]]:
@@ -361,13 +514,16 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
               for m in phase_generate(c)]
     if misses:
         return misses, None
-    bad, stamp = phase_solve(c, "batch", [], None, ladder=True)
+    # the default dtype on a chip stages bfloat16: one pass
+    bad, stamp = phase_solve(c, "batch", [], None, ladder=True, passes=1)
     misses += [f"config {config_id} batch: {m}" for m in bad]
     if stamp is None:
         return misses, None
     bad, _ = phase_solve(c, "batch.f32", ["--dtype", "float32"], None,
-                         ladder=True, form="bf16x3")
+                         ladder=True, form="bf16x3", passes=3)
     misses += [f"config {config_id} batch.f32: {m}" for m in bad]
+    misses += [f"config {config_id} fold.bf16: {m}"
+               for m in phase_fold_bf16(c)]
     misses += [f"config {config_id} serve: {m}" for m in phase_serve(c)]
     from dmlp_tpu.config import EngineConfig
     chips = stamp.get("device_count", 0)
@@ -406,7 +562,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--configs", default="1,4",
                     help="bench configs to run, in order (default 1,4: "
                          "the same path small, then at full width)")
+    ap.add_argument("--fold-child", metavar="OUT", default=None,
+                    help=argparse.SUPPRESS)   # the fold.bf16 phase's child
     args = ap.parse_args(argv)
+    if args.fold_child:
+        return fold_child(args.fold_child)
     if not os.path.isdir(os.path.join(REPO, "dmlp_tpu")):
         print(f"chip_smoke: no dmlp_tpu package beside {__file__}",
               file=sys.stderr)

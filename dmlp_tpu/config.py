@@ -80,8 +80,14 @@ class EngineConfig:
         where one ``HIGHEST`` dot makes Mosaic spend six; what the
         three drop is at most 2^-15 of the scale a distance
         (engine.finalize.LOWP_COEF). Fast mode, whose device ordering
-        IS the answer, keeps the one ``HIGHEST`` dot, and so do
-        operands staged in bfloat16 (a bf16 value has no low half).
+        IS the answer, keeps the one ``HIGHEST`` dot. Operands staged
+        in bfloat16 (a bf16 value has no low half to split) reach the
+        kernel AS bfloat16 and take ONE pass in either mode, still
+        under the name "f32": bf16 x bf16 products are exact in
+        float32 and the MXU accumulates in float32, so the pass is the
+        ``HIGHEST`` dot's value from the same operands and drops
+        nothing (ops.pallas_extract.mxu_passes says which dot a
+        staging and a form give: 1, 3 or 6; the engines stamp it).
         "bf16" casts the streamed q/d tiles before the MXU dot: ONE
         pass. Either way the engines widen every candidate window /
         prune threshold / hazard test by the form's analytic
@@ -161,8 +167,11 @@ class EngineConfig:
         operands have a low half to split (``staging`` "float32"; the
         engine's, resolve_dtype() when None) and the backend's compiler
         makes the split as written (ops.pallas_extract.split_holds:
-        one small kernel on the device, once a process); else the one
-        ``HIGHEST`` dot."""
+        one small kernel on the device, once a process); else "f32",
+        a cross term exact to float32 accumulation: the one
+        ``HIGHEST`` dot over float32 operands, ONE pass over operands
+        staged in bfloat16 (the kernel is handed the bf16 block:
+        ops.pallas_extract._dot_cross)."""
         if not self.exact:
             return "f32"
         if staging is None:
@@ -186,9 +195,10 @@ class EngineConfig:
         call (no import-time snapshot) so tests and operators can flip
         the env without re-imports — the engines resolve it OUTSIDE
         every jit and key their compiled programs on the result (R2
-        discipline). Fast mode always runs "f32", the one ``HIGHEST``
-        dot: a form that drops products is only sound with the f64
-        rescore + boundary repair behind it."""
+        discipline). Fast mode always runs "f32" (the one ``HIGHEST``
+        dot; one exact pass over bfloat16 rows): a form that drops
+        products is only sound with the f64 rescore + boundary repair
+        behind it."""
         import os
         if not self.exact:
             return "f32"

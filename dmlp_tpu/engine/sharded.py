@@ -43,6 +43,7 @@ from dmlp_tpu.obs import memwatch, telemetry
 from dmlp_tpu.obs.comms import engine_comms
 from dmlp_tpu.obs.run import rows_per_device
 from dmlp_tpu.obs.trace import span as obs_span
+from dmlp_tpu.ops.pallas_extract import mxu_passes
 from dmlp_tpu.ops.topk import TopK, select_topk, streaming_topk
 from dmlp_tpu.parallel.collectives import allgather_merge_topk, ring_allreduce_topk
 from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS, make_mesh
@@ -199,7 +200,8 @@ class ShardedEngine:
         self.last_extract_impl = impl
         self.last_variant = variant_stamp(
             impl, k, b, qb, a,
-            (self.last_precision or {}).get("active", "f32"))
+            (self.last_precision or {}).get("active", "f32"),
+            self._staging)
         return impl
 
     def corpus_rows_per_device(self) -> Dict[str, int]:
@@ -891,7 +893,9 @@ class ShardedEngine:
         # (resolve_precision returns "f32" in fast mode) IS the active
         # one; _run widens its hazard eps to match.
         prec = self.config.resolve_precision(self._staging)
-        self.last_precision = {"active": prec, "configured": prec}
+        self.last_precision = {
+            "active": prec, "configured": prec,
+            "mxu_passes": mxu_passes(prec, self._staging)}
         out = self._solve_chunked_extract(inp,
                                           allow_prune=self.config.exact,
                                           precision=prec)

@@ -416,7 +416,9 @@ def active_precision(engine) -> str:
     device ordering): they do not ask here and solve at the default,
     the one ``HIGHEST`` dot, as fast mode does. An exact run's float32
     pass takes config.f32_form's answer for the engine's staging:
-    three bf16 passes at float32 staging, on every rung. "bf16", ONE
+    three bf16 passes at float32 staging, on every rung (bfloat16
+    staging keeps the name "f32", and its kernel spends one exact
+    pass over the bf16 rows: ops.pallas_extract.mxu_passes). "bf16", ONE
     pass, needs more: the config resolves to it
     (config.resolve_precision — ``$DMLP_TPU_PRECISION`` included) and
     the ladder still sits on its top "lowp" rung — the first OOM
@@ -912,7 +914,7 @@ class SingleChipEngine:
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
-            impl, k, chunk_rows, qpad, na, prec)
+            impl, k, chunk_rows, qpad, na, prec, self._staging)
 
         schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows,
                                                  prec)
@@ -1051,7 +1053,7 @@ class SingleChipEngine:
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
-            impl, kc, chunk_rows, qpad, na, prec)
+            impl, kc, chunk_rows, qpad, na, prec, self._staging)
         rs_inject.fire("single.extract_solve", rung=self._degrade_rung,
                        path="multipass")
 
@@ -1238,7 +1240,7 @@ class SingleChipEngine:
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
-            impl, kb, chunk_rows, qpad_b, na, prec)
+            impl, kb, chunk_rows, qpad_b, na, prec, self._staging)
         self.last_hetk = (int(bulk.size), int(outl.size))
         rs_inject.fire("single.extract_solve", rung=self._degrade_rung,
                        path="routed")
@@ -1408,13 +1410,17 @@ class SingleChipEngine:
         # without a telemetry session).
         telemetry.sample_memory_now()
         # Precision record for metrics/bench: what the first pass ran
-        # at, and how many window slots the bound inflation bought the
+        # at, the MXU passes the kernel's cross term takes a visit at
+        # that form over this staging (ops.pallas_extract.mxu_passes),
+        # and how many window slots the bound inflation bought the
         # rescore (kcap minus what an f32-precision plan would have
         # sized — 0 whenever precision resolves to "f32").
+        from dmlp_tpu.ops.pallas_extract import mxu_passes
         kcap0 = int(segments[0][0].dists.shape[1])
         kmax0 = int(inp.ks.max()) if inp.params.num_queries else 0
         pend.precision = {
             "active": pend.prec,
+            "mxu_passes": mxu_passes(pend.prec, self._staging),
             "configured": self.config.resolve_precision(self._staging),
             "kcap": kcap0,
             "kcap_inflation": kcap0 - resolve_kcap(

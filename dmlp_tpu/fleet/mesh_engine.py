@@ -79,6 +79,7 @@ from dmlp_tpu.obs import memwatch, telemetry
 from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.comms import engine_comms
 from dmlp_tpu.obs.trace import span as obs_span
+from dmlp_tpu.ops.pallas_extract import mxu_passes
 from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS, make_mesh
 from dmlp_tpu.serve.engine import (_KERNEL_STATICS, CapacityError,
                                    ResidentServingCore, _kernel_statics,
@@ -700,8 +701,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         self.last_prune_fraction = None
         self._pending_gate = None
         prec = self._active_prec()
-        self.last_precision = {"active": prec,
-                               "configured": self._precision_plan}
+        self.last_precision = {
+            "active": prec, "configured": self._precision_plan,
+            "mxu_passes": mxu_passes(prec, self._staging)}
         memwatch.note_engine_model(self, inp)
         entry = self._bucket_entry(nq, kmax)
         if self._handed is not None:    # a slow cycle's record names it

@@ -403,6 +403,24 @@ def guess_axis(group_size: int,
     return hits[0] if len(hits) == 1 else "unknown"
 
 
+def kernel_operand_types(hlo_text: str) -> List[List[str]]:
+    """The operand types ("bf16[51200,128]") of every Pallas kernel
+    call (``tpu_custom_call``) in a compiled program's text, in
+    argument order, a list a call: read from the call's
+    ``operand_layout_constraints``, which name each operand as the
+    kernel's BlockSpecs see it (chip_smoke.py's ``fold.bf16`` phase and
+    tests/test_tpu_aot.py ask whether bfloat16 rows reach the kernel
+    as bfloat16)."""
+    calls = []
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        layouts = line.split("operand_layout_constraints={", 1)[1]
+        calls.append([op.split("{")[0]
+                      for op in layouts.split("}}", 1)[0].split("}, ")])
+    return calls
+
+
 # -- the per-executable record ------------------------------------------------
 
 def fingerprint_text(hlo_text: str) -> str:

@@ -46,25 +46,30 @@ from typing import Dict, Optional
 
 __all__ = ["extract_topk_cost", "extract_loop_cost", "fused_topk_cost",
            "two_pass_equivalent_cost", "fused_dist_segmin_cost",
-           "summaries_score_cost", "analytic_cost", "MXU_PASSES"]
+           "summaries_score_cost", "analytic_cost"]
 
-#: MXU hardware passes per dot tile by first-pass form: the MXU
-#: multiplies in bf16, so an f32 dot at HIGHEST preferred precision is
-#: emulated in SIX bf16 product passes (Mosaic's
-#: ``contract_precision<fp32>``; measured on v5e in PR 36: the dot
-#: alone, inside a pallas_call with the kernel's BlockSpecs, took
-#: 13.6 us at a (128, 128) x (12 800, 128) visit and 62.1 us at
-#: (128, 1024) x (6 400, 1024), 6.4 and 7.3 times one pass at the
-#: MXU's peak, where every form of three passes or one sat on the
-#: data block's DMA, 9.2 and 36.1 us: PERF.md section 6), the "bf16x3"
-#: form (ops.pallas_extract._dot_cross: bf16 halves of both operands)
-#: issues THREE, and a "bf16" first pass (``precision="bf16"``, f32
-#: accumulation) ONE. The ``flops`` fields below deliberately do
-#: NOT scale by this — they keep XLA's dot convention (2*Q*B*A
-#: regardless of precision) so flops stay comparable across arms and
-#: history; the pass count is reported alongside as ``mxu_passes`` /
-#: ``mxu_precision`` for roofline math that wants hardware-issue terms.
-MXU_PASSES = {"f32": 6, "bf16x3": 3, "bf16": 1}
+# MXU hardware passes per dot tile by first-pass form
+# (ops.pallas_extract.mxu_passes, the test the kernel branches on): the MXU
+# multiplies in bf16, so an f32 dot at HIGHEST preferred precision is
+# emulated in SIX bf16 product passes (Mosaic's
+# ``contract_precision<fp32>``; measured on v5e in PR 36: the dot
+# alone, inside a pallas_call with the kernel's BlockSpecs, took
+# 13.6 us at a (128, 128) x (12 800, 128) visit and 62.1 us at
+# (128, 1024) x (6 400, 1024), 6.4 and 7.3 times one pass at the
+# MXU's peak, where every form of three passes or one sat on the
+# data block's DMA, 9.2 and 36.1 us: PERF.md section 6), the "bf16x3"
+# form (ops.pallas_extract._dot_cross: bf16 halves of both operands)
+# issues THREE, and a "bf16" first pass (``precision="bf16"``, f32
+# accumulation) ONE. The ``flops`` fields below deliberately do
+# NOT scale by this — they keep XLA's dot convention (2*Q*B*A
+# regardless of precision) so flops stay comparable across arms and
+# history; the pass count is reported alongside as ``mxu_passes`` /
+# ``mxu_precision`` for roofline math that wants hardware-issue terms.
+# Those are the counts over FLOAT32 blocks; a dispatch whose operands
+# both arrive bfloat16 streams the bf16 data block itself and takes
+# ONE pass whatever the form (PR 39), and the models below weigh its
+# data panel at two bytes a value (``data_dtype`` "bfloat16":
+# _operand_dtype).
 
 
 def _variant_resolver(kernel: str):
@@ -111,7 +116,8 @@ def extract_loop_cost(qb: int, b: int, a: int, kc: int,
 
 def _streaming_cost(qb: int, b: int, a: int, kc: int,
                     kernel: str = "extract",
-                    precision: str = "f32") -> Dict[str, float]:
+                    precision: str = "f32",
+                    data_dtype: str = "float32") -> Dict[str, float]:
     """The SHARED deterministic model of one streaming top-k dispatch
     (the (qb, b) distance tile lives only in VMEM): flops + HBM bytes
     at the tiles the ``kernel`` namespace ("extract" | "fused")
@@ -119,8 +125,11 @@ def _streaming_cost(qb: int, b: int, a: int, kc: int,
     megakernel adds only its gate term on top — so a future fix to any
     shared term cannot drift between the two models. ``precision``
     keys the variant resolution (per-precision winners) but does NOT
-    change the modeled flops/bytes — operands stream at their staged
-    width either way and the in-VMEM cast is free of HBM traffic."""
+    change the modeled flops/bytes — the in-VMEM cast is free of HBM
+    traffic. ``data_dtype`` does: the kernel streams float32 blocks
+    (a converted copy, for operands of mixed dtypes) unless both
+    operands arrive bfloat16, and then the data panel, the term that
+    dominates, weighs half (the query panel stays float32)."""
     from dmlp_tpu.ops.pallas_distance import _tile
     from dmlp_tpu.ops.pallas_extract import _TN
 
@@ -131,8 +140,9 @@ def _streaming_cost(qb: int, b: int, a: int, kc: int,
              + 2.0 * (qb + b) * a  # |q|^2 / |d|^2 norm reductions
              + 4.0 * qb * b        # expansion + clamp + floor/sentinel masks
              + 1.0 * qb * b)       # block-skip prefilter min, one VPU pass
-    byts = 4.0 * ((qb // tq) * b * a    # data panel, once per query tile
-                  + (b // tn) * qb * a  # query panel, once per data block
+    width = 2.0 if data_dtype == "bfloat16" else 4.0
+    byts = width * (qb // tq) * b * a   # data panel, once per query tile
+    byts += 4.0 * ((b // tn) * qb * a   # query panel, once per data block
                   + (qb // tq) * b      # dn row, once per query tile
                   + (b // tn) * qb      # qn column, once per data block
                   + 2 * qb * kc         # running (dists, ids) lists out
@@ -143,20 +153,25 @@ def _streaming_cost(qb: int, b: int, a: int, kc: int,
 
 def extract_topk_cost(qb: int, b: int, a: int, kc: int,
                       iters_total: Optional[int] = None,
-                      precision: str = "f32") -> Dict[str, float]:
+                      precision: str = "f32",
+                      data_dtype: str = "float32") -> Dict[str, float]:
     """Cost of one ``ops.pallas_extract.extract_topk`` dispatch at
     (queries (qb, a), data (b, a), list width kc). Without
     ``iters_total`` the data-dependent while-loop is excluded
     (deterministic lower bound); with it, the measured extraction term
     (:func:`extract_loop_cost`) is added and the dict says so.
     ``precision`` ("f32" | "bf16x3" | "bf16") keys the tile resolution
-    and is reported back with its MXU pass count (:data:`MXU_PASSES`) —
+    and is reported back with its MXU pass count
+    (ops.pallas_extract.mxu_passes: one where the operands arrive as
+    ``data_dtype`` "bfloat16") —
     ``flops`` itself keeps the precision-independent dot convention."""
-    base = _streaming_cost(qb, b, a, kc, precision=precision)
+    from dmlp_tpu.ops.pallas_extract import mxu_passes
+    base = _streaming_cost(qb, b, a, kc, precision=precision,
+                           data_dtype=data_dtype)
     out = {"flops": base["flops"], "bytes_accessed": base["bytes_accessed"],
            "extraction_term": "modeled_lower_bound",
            "mxu_precision": precision,
-           "mxu_passes": MXU_PASSES[precision]}
+           "mxu_passes": mxu_passes(precision, data_dtype)}
     if iters_total is not None:
         out["flops"] += extract_loop_cost(qb, b, a, kc, iters_total,
                                           precision=precision)
@@ -167,7 +182,8 @@ def extract_topk_cost(qb: int, b: int, a: int, kc: int,
 
 def fused_topk_cost(qb: int, b: int, a: int, kc: int,
                     iters_total: Optional[int] = None,
-                    precision: str = "f32") -> Dict[str, float]:
+                    precision: str = "f32",
+                    data_dtype: str = "float32") -> Dict[str, float]:
     """Cost of one ``ops.pallas_fused.fused_topk`` dispatch — the fused
     distance→top-k streaming megakernel. Same one-pass HBM structure as
     :func:`extract_topk_cost` (the (qb, b) distance tile lives only in
@@ -189,8 +205,9 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
     ``precision`` keys the tile resolution (both sides of the delta)
     and reports its MXU pass count; ``flops`` stays convention-stable.
     """
+    from dmlp_tpu.ops.pallas_extract import mxu_passes
     base = _streaming_cost(qb, b, a, kc, kernel="fused",
-                           precision=precision)
+                           precision=precision, data_dtype=data_dtype)
     tq, tn = base["tq"], base["tn"]
     flops = (base["flops"]
              # The MXU gate itself, per (tq, tn) grid cell: ~3 block
@@ -200,12 +217,13 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
              # blocks skip the matmul entirely.)
              + (qb // tq) * (b // tn) * (3.0 * tn + 8.0 * tq))
     byts = base["bytes_accessed"]
-    tp = two_pass_equivalent_cost(qb, b, a, kc, precision=precision)
+    tp = two_pass_equivalent_cost(qb, b, a, kc, precision=precision,
+                                  data_dtype=data_dtype)
     out: Dict[str, float] = {
         "flops": flops, "bytes_accessed": byts,
         "extraction_term": "modeled_lower_bound",
         "mxu_precision": precision,
-        "mxu_passes": MXU_PASSES[precision],
+        "mxu_passes": mxu_passes(precision, data_dtype),
         "hbm_bytes_two_pass_equiv": tp["bytes_accessed"],
         "hbm_bytes_saved_vs_two_pass": tp["bytes_accessed"] - byts,
         "hbm_traffic_reduction_x": round(tp["bytes_accessed"] / byts, 2),
@@ -221,7 +239,9 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
 
 def two_pass_equivalent_cost(qb: int, b: int, a: int, kc: int,
                              kernel: str = "fused",
-                             precision: str = "f32") -> Dict[str, float]:
+                             precision: str = "f32",
+                             data_dtype: str = "float32"
+                             ) -> Dict[str, float]:
     """What the SAME dispatch costs when the (qb, b) distance matrix
     round-trips HBM between a distance kernel and a selection pass —
     the pre-fused hot path's two passes over its dominant term:
@@ -232,7 +252,7 @@ def two_pass_equivalent_cost(qb: int, b: int, a: int, kc: int,
     round-trip delta by construction (same tiles on both sides), and
     ``precision`` keys that shared resolution too."""
     base = _streaming_cost(qb, b, a, kc, kernel=kernel,
-                           precision=precision)
+                           precision=precision, data_dtype=data_dtype)
     return {"flops": base["flops"],
             "bytes_accessed": base["bytes_accessed"]
             + 4.0 * 2.0 * qb * b}
@@ -280,6 +300,15 @@ def summaries_score_cost(qb: int, nblocks: int, a: int
     return {"flops": flops, "bytes_accessed": byts}
 
 
+def _operand_dtype(leaves) -> str:
+    """"bfloat16" where the dispatch's query and data operands BOTH
+    arrive bfloat16 (the kernel then streams the bf16 rows), else
+    "float32": extract_topk's own test."""
+    both = all(str(getattr(x, "dtype", "")) == "bfloat16"
+               for x in leaves[:2])
+    return "bfloat16" if both else "float32"
+
+
 def _extract_entry(specs, statics) -> Optional[Dict[str, float]]:
     try:
         import jax
@@ -290,7 +319,8 @@ def _extract_entry(specs, statics) -> Optional[Dict[str, float]]:
         return None
     return extract_topk_cost(qb, b, a, kc,
                              precision=str(statics.get("precision",
-                                                       "f32")))
+                                                       "f32")),
+                             data_dtype=_operand_dtype(leaves))
 
 
 def _fused_entry(specs, statics) -> Optional[Dict[str, float]]:
@@ -303,7 +333,8 @@ def _fused_entry(specs, statics) -> Optional[Dict[str, float]]:
         return None
     return fused_topk_cost(qb, b, a, kc,
                            precision=str(statics.get("precision",
-                                                     "f32")))
+                                                     "f32")),
+                           data_dtype=_operand_dtype(leaves))
 
 
 def _segmin_entry(specs, statics) -> Optional[Dict[str, float]]:

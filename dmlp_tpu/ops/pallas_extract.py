@@ -311,10 +311,37 @@ def split_holds() -> bool:
     return holds
 
 
+def mxu_passes(precision: str, dtype) -> int:
+    """bf16 MXU passes _dot_cross spends a (tq, tn) visit at first-pass
+    form ``precision`` over a data block of ``dtype`` (an array dtype
+    or a staging name, "float32" | "bfloat16"): 1 where the block
+    arrives as bfloat16 or the form is "bf16", 3 for "bf16x3", 6 for
+    the one ``HIGHEST`` dot. The ONE test of which dot runs: the
+    kernel branches on it and the engines stamp it (``mxu_passes`` in
+    ``last_variant`` and ``last_precision``) from their staging dtype,
+    so a trace and a daemon's ``stats`` say what a fold ran."""
+    if jnp.dtype(dtype) == jnp.bfloat16 or precision == "bf16":
+        return 1
+    return 3 if precision == "bf16x3" else 6
+
+
 def _dot_cross(q, d, precision: str):
-    """The (tq, tn) cross-term block of float32 blocks ``q`` (tq, a)
-    and ``d`` (tn, a) at the requested FIRST-PASS form, every form
-    accumulating in float32.
+    """The (tq, tn) cross-term block of the float32 query block ``q``
+    (tq, a) and the data block ``d`` (tn, a) at the requested
+    FIRST-PASS form, every form accumulating in float32.
+
+    A bfloat16 ``d`` (rows staged in bfloat16, streamed as they are:
+    _extract_topk_jit hands the kernel a bfloat16 block only where the
+    queries hold bfloat16 values too) takes ONE pass whatever the
+    form: ``q`` is cast back (lossless for bf16 values; Mosaic makes
+    the cast as written, split_bf16) and bf16 x bf16 contracts at the
+    default MXU precision. The products are exact in float32 and the
+    accumulation is the MXU's float32 one: the value the ``HIGHEST``
+    dot computes from the same operands with five all-zero partial
+    products beside it, at a sixth of the passes and half the block's
+    DMA. Nothing is dropped, so this is the "f32" form's own value
+    (LOWP_COEF["f32"] = 0 stands; chip_smoke.py's ``fold.bf16`` phase
+    holds the chip to it). For float32 blocks:
 
     "f32": ONE dot at ``Precision.HIGHEST``, which Mosaic lowers to
     ``contract_precision<fp32>``: full float32 emulation, SIX bf16 MXU
@@ -356,17 +383,19 @@ def _dot_cross(q, d, precision: str):
             a, b, (((1,), (1,)), ((), ())), precision=mxu,
             preferred_element_type=jnp.float32)
 
-    if precision == "bf16x3":
+    passes = mxu_passes(precision, d.dtype)
+    if passes == 1:
+        # bf16 operands ARE the single MXU pass; Mosaic rejects an fp32
+        # contract precision on them ("Bad lhs type"). A no-op on a
+        # block that arrived bfloat16, the "bf16" form's cast otherwise.
+        return contract(
+            q.astype(jnp.bfloat16),  # check: lowp-eps=lowp_eps
+            d.astype(jnp.bfloat16))  # check: lowp-eps=lowp_eps
+    if passes == 3:
         q_hi, q_lo = split_bf16(q)
         d_hi, d_lo = split_bf16(d)
         return contract(jnp.concatenate([q_hi, q_hi, q_lo], axis=1),
                         jnp.concatenate([d_hi, d_lo, d_hi], axis=1))
-    if precision == "bf16":
-        # bf16 operands ARE the single MXU pass; Mosaic rejects an fp32
-        # contract precision on them ("Bad lhs type").
-        return contract(
-            q.astype(jnp.bfloat16),  # check: lowp-eps=lowp_eps
-            d.astype(jnp.bfloat16))  # check: lowp-eps=lowp_eps
     return contract(q, d, jax.lax.Precision.HIGHEST)
 
 
@@ -601,6 +630,12 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     (measured on v5e, PR 36: PERF.md section 6); "bf16x3" splits the
     streamed q/d tiles into bf16 halves in the kernel and spends
     three; "bf16" casts them and spends one; all accumulate in f32.
+    Where BOTH operands arrive bfloat16 (rows and queries staged in
+    bfloat16) the data BlockSpec streams the bf16 rows as they are,
+    half the bytes a visit and no float32 copy of the chunk, and the
+    cross term takes ONE pass whatever the form: the "f32" form's own
+    value, nothing dropped (mxu_passes; any other pair of dtypes is
+    converted to float32 and takes the form as named).
     The candidate lists of the last two deviate from the f32 pass by
     at most that form's
     engine.finalize.lowp_eps per distance, and callers MUST widen
@@ -660,10 +695,20 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
         raise ValueError(
             f"untileable (qb={qb}, b={b}, kc={kc}, tq={tq}, tn={tn}, ne={ne})")
 
+    # Rows staged in bfloat16 reach the kernel AS bfloat16 where the
+    # queries hold bfloat16 values too (both operands arrive bfloat16:
+    # the engines stage both through one dtype): half the block's DMA,
+    # one MXU pass (_dot_cross), no float32 copy of the chunk. The
+    # query block is small and resident across the data axis: it stays
+    # float32 in HBM (lossless) and is cast back a visit, which spares
+    # short query tiles bfloat16's 16-sublane tile. Any other pair of
+    # dtypes is converted, as before.
     q32 = q_attrs.astype(jnp.float32)
     d32 = d_attrs.astype(jnp.float32)
     qn = jnp.sum(q32 * q32, axis=-1, keepdims=True)
     dn = jnp.sum(d32 * d32, axis=-1)[None, :]
+    d_in = d_attrs if q_attrs.dtype == d_attrs.dtype == jnp.bfloat16 \
+        else d32
 
     fresh = carry_d is None
     if fresh:
@@ -716,5 +761,5 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=96 * 2**20),
         interpret=interpret,
-    )(scalars, q32, d32, qn, dn, floor, carry_d, carry_i)
+    )(scalars, q32, d_in, qn, dn, floor, carry_d, carry_i)
     return out_d, out_i, out_iters[::tq]

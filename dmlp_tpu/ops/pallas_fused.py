@@ -106,17 +106,23 @@ def variant_for(impl: str, kc: int, b: int, qb: int | None = None,
 
 
 def variant_stamp(impl: str, kc: int, b: int, qb: int, a: int,
-                  precision: str = "f32") -> dict:
+                  precision: str = "f32",
+                  staging: str = "float32") -> dict:
     """:func:`variant_for` plus where the variant came from — the
     device stamp's ``kernel_variant`` (obs.run.device_stamp): the tiles
-    this dispatch runs with and whether a tune-cache FILE (state outside
-    the checkout) supplied them rather than the committed heuristic."""
+    this dispatch runs with, whether a tune-cache FILE (state outside
+    the checkout) supplied them rather than the committed heuristic,
+    and ``mxu_passes``: how many bf16 MXU passes the cross term takes
+    a visit at this form over operands staged as ``staging``
+    (ops.pallas_extract.mxu_passes, the test the kernel branches on)."""
+    from dmlp_tpu.ops.pallas_extract import mxu_passes
     from dmlp_tpu.tune import lookup_variant
     v = variant_for(impl, kc, b, qb, a, precision)
     cached = lookup_variant(
         kc, b, a=a, precision=precision,
         kernel=FUSED_KERNEL if impl == "fused" else "extract_topk")
-    return {**v, "kc": kc, "from_tune_cache": cached == v}
+    return {**v, "kc": kc, "from_tune_cache": cached == v,
+            "mxu_passes": mxu_passes(precision, staging)}
 
 
 def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,

@@ -230,13 +230,14 @@ def _sweep_stack(q, stack, n_real, floor, **kern):
 
 def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, int]:
     """What ``serve.solve_extract`` and ``serve.warmup_bucket`` say of a
-    solve's kernel variant ``v`` (``last_variant``'s form): its tiles
-    and, where the engine pads it, the width a staged row holds."""
+    solve's kernel variant ``v`` (``last_variant``'s form): its tiles,
+    where the engine pads it the width a staged row holds, and the MXU
+    passes its cross term takes a visit (1, 3 or 6)."""
     from dmlp_tpu.ops.pallas_extract import _TN
     if not v:
         return {}
     return {"tile_n": _TN, **{k: v[k] for k in (
-        "tile_q", "tile_n", "ne", "a_pad") if k in v}}
+        "tile_q", "tile_n", "ne", "a_pad", "mxu_passes") if k in v}}
 
 
 @dataclasses.dataclass(eq=False)
@@ -1157,8 +1158,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         with the width the stack holds a row at."""
         from dmlp_tpu.ops import pallas_fused
         return {**pallas_fused.variant_stamp(
-            impl, kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec),
-            "a_pad": self._ex_attrs}
+            impl, kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec,
+            self._staging), "a_pad": self._ex_attrs}
 
     def _fold_resident(self, q_dev, order, impl: str, kc: int,
                        prec: str):

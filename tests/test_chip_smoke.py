@@ -33,6 +33,14 @@ def test_chip_smoke_dry_run_matches_oracle_and_refuses_cpu():
     # the float32-staged child ran too, on the three-pass form
     assert "FAIL config 1 batch.f32: platform is cpu" in out
     assert "the first pass ran as" not in out
+    # the bf16-staged fold ran too (interpreted, at its toy chunk):
+    # only the platform and the interpreter are named
+    assert "fold.bf16: wall" in out and "'chunk_rows': 1024" in out
+    assert "mxu_passes {'bfloat16': 1, 'float32': 6}" in out
+    assert "FAIL config 1 fold.bf16: platform is cpu" in out
+    assert "FAIL config 1 fold.bf16: pallas_interpret is True" in out
+    assert out.count("FAIL config 1 fold.bf16") == 2
+    assert "MXU passes a visit" not in out
     # both children ran to the end and answered byte-identically
     assert "serve: 3 requests x 256 queries" in out
     assert "differ" not in out and "exited" not in out
@@ -141,6 +149,79 @@ def test_batch_f32_names_a_first_pass_that_did_not_split(
 
 
 # -- the removed fallbacks -----------------------------------------------------
+
+_FOLD_OK = {
+    "device": {"platform": "tpu", "pallas_interpret": False},
+    "mxu_passes": {"bfloat16": 1, "float32": 6},
+    "kernel_data_operands": ["bf16[51200,128]", "bf16[51200,128]"],
+    "ids_equal": True, "dists_equal": True, "dists_differ": 0,
+    "dists_max_abs_diff": 0.0, "ids_valid": True}
+
+
+@pytest.mark.parametrize("change, named", [
+    ({}, None),
+    ({"mxu_passes": {"bfloat16": 6, "float32": 6}}, "mxu_passes is"),
+    ({"kernel_data_operands": ["f32[51200,128]", "bf16[51200,128]"]},
+     "not the bfloat16 rows"),
+    ({"kernel_data_operands": None}, "not the bfloat16 rows"),
+    ({"dists_equal": False, "dists_differ": 3}, "NOT the HIGHEST dot's"),
+    ({"ids_equal": False}, "NOT the HIGHEST dot's"),
+    ({"ids_valid": False}, "outside the corpus"),
+    ({"device": {"platform": "tpu", "pallas_interpret": True}},
+     "pallas_interpret is True"),
+    ({"device": {"platform": "cpu", "pallas_interpret": False},
+      "kernel_data_operands": None, "dists_equal": False},
+     "platform is cpu")])
+def test_fold_bf16_names_every_miss(change, named):
+    """The ``fold.bf16`` phase's verdict on its child's record: bfloat16
+    rows in the compiled program's kernel calls, ``mxu_passes`` 1, and
+    the float32 ``HIGHEST`` run's lists to the bit, on the chip."""
+    cs = _load_chip_smoke()
+    misses = cs.fold_misses(dict(_FOLD_OK, **change))
+    if named is None:
+        assert misses == []
+    else:
+        assert len(misses) == 1 and named in misses[0], misses
+
+
+@pytest.mark.parametrize("dtype_args, passes, got, named", [
+    ([], 1, 1, False), ([], 1, 6, True),
+    (["--dtype", "float32"], 3, 3, False),
+    (["--dtype", "float32"], 3, 6, True)])
+def test_batch_phases_name_a_cross_term_of_other_passes(
+        tmp_path, monkeypatch, dtype_args, passes, got, named):
+    """A batch child on a chip stamps the MXU passes its kernel's cross
+    term took a visit: 1 under the default (bfloat16) staging, 3 under
+    float32; another count is a miss that says so."""
+    cs = _load_chip_smoke()
+    monkeypatch.setattr(cs, "LOGS", str(tmp_path))
+    c = cs.Config(1)
+    stamp = {"platform": "tpu", "device_kind": "TPU v5 lite",
+             "peak_flops_known": True, "device_count": 1, "mesh": None,
+             "select": "extract", "extract_impl": "fused",
+             "pallas_interpret": False, "degrade_rung": "lowp",
+             "kernel_variant": {"tile_q": 64, "from_tune_cache": False,
+                                "mxu_passes": got},
+             "degradations": [], "retries": 0}
+
+    def child(argv, stdin_path, out_path, err_path):
+        with open(out_path, "w") as f:
+            f.writelines(c.oracle_lines)
+        open(err_path, "w").close()
+        with open(argv[argv.index("--metrics") + 1], "w") as f:
+            f.write(json.dumps({"event": "summary", "device": stamp})
+                    + "\n")
+        return 0, 1.0
+
+    monkeypatch.setattr(cs, "run_child", child)
+    bad, _ = cs.phase_solve(c, "batch", dtype_args, None, ladder=True,
+                            passes=passes)
+    if named:
+        assert bad == [f"the cross term took {got} MXU passes a visit, "
+                       f"not {passes}"]
+    else:
+        assert bad == []
+
 
 def test_pallas_interpret_is_chosen_from_the_platform(monkeypatch):
     """Interpret mode is a statement about the backend — cpu: yes,

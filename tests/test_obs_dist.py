@@ -389,6 +389,33 @@ def test_probe_resolves_extract_topk_analytically():
     assert got["per_site"]["single.extract_topk"]["dispatches"] == 2
 
 
+@pytest.mark.parametrize("q_dtype, d_dtype, passes, halved", [
+    ("bfloat16", "bfloat16", 1, True), ("float32", "bfloat16", 6, False),
+    ("bfloat16", "float32", 6, False), ("float32", "float32", 6, False)])
+def test_analytic_cost_weighs_a_bfloat16_pair_as_the_kernel_streams_it(
+        q_dtype, d_dtype, passes, halved):
+    """Operands that BOTH arrive bfloat16 stream the bf16 data block
+    (half the data panel's bytes, one MXU pass); any other pair is
+    converted to float32 and costs as before: extract_topk's own test,
+    read from the recorded specs' dtypes."""
+    import jax.numpy as jnp
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    from dmlp_tpu.ops.pallas_fused import fused_topk
+    qb, b, a, kc = 128, 25600, 64, 40
+    specs = (jnp.zeros((qb, a), q_dtype), jnp.zeros((b, a), d_dtype))
+    for fn, model in ((extract_topk, kernel_cost.extract_topk_cost),
+                      (fused_topk, kernel_cost.fused_topk_cost)):
+        got = kernel_cost.analytic_cost(fn, specs, dict(kc=kc))
+        f32 = model(qb, b, a, kc)
+        assert got["mxu_passes"] == passes and f32["mxu_passes"] == 6
+        assert got["flops"] == f32["flops"]
+        panel = 4.0 * b * a            # tile_q 128: one query tile
+        assert f32["bytes_accessed"] - got["bytes_accessed"] \
+            == (panel / 2 if halved else 0.0)
+    assert kernel_cost.extract_topk_cost(
+        qb, b, a, kc, precision="bf16x3")["mxu_passes"] == 3
+
+
 def test_analytic_cost_unknown_fn_is_none():
     assert kernel_cost.analytic_cost(lambda x: x, (), {}) is None
 

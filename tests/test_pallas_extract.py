@@ -130,6 +130,171 @@ def test_stacked_passes_agree_with_the_one_dot_on_exact_operands(gate, na):
         assert np.array_equal(a, b)
 
 
+# -- rows staged in bfloat16 reach the MXU as bfloat16 (PR 39) -----------------
+
+def _bf16_valued(rng, shape, scale):
+    """Reals in [0, scale) rounded to bfloat16, as float32: not
+    integers, so a dot's float32 sum rounds."""
+    x = jnp.asarray(rng.uniform(0, scale, shape), jnp.float32)
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _two_chunks(q, d, floor, gate, precision="f32"):
+    """A fresh fold of d's first 768 rows (700 real), then the rest
+    carried, above ``floor``: (dists, ids) of both steps."""
+    od, oi, _ = extract_topk(q, d[:768], n_real=700, kc=24, interpret=True,
+                             mxu_gate=gate, precision=precision)
+    od2, oi2, _ = extract_topk(q, d[768:], od, oi, n_real=500, id_base=700,
+                               kc=24, interpret=True, mxu_gate=gate,
+                               floor=floor, precision=precision)
+    return [np.asarray(x) for x in (od, oi, od2, oi2)]
+
+
+@pytest.mark.parametrize("na", [64, 128, 960])
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_bf16_rows_in_one_pass_give_the_highest_dots_lists(gate, na):
+    """Operands that ARRIVE bfloat16 take one MXU pass over the bf16
+    block itself; the same values handed over as float32 take the one
+    HIGHEST dot (six). bf16 x bf16 products are exact in float32 and
+    both accumulate in float32, so nothing is dropped: the same ids,
+    fresh and carried and above a floor, with the MXU gate on and off,
+    and the same distances (to the float32 accumulation term where a
+    backend sums the two dots in a different order)."""
+    from dmlp_tpu.engine.finalize import EPS_CANCEL_COEF
+    rng = np.random.default_rng(3900 + na + gate)
+    scale = 255.0
+    q32 = _bf16_valued(rng, (16, na), scale)
+    d32 = _bf16_valued(rng, (1280, na), scale)
+    floor = jnp.asarray(rng.uniform(0, 0.1 * na * scale ** 2 / 6, (16, 1)),
+                        jnp.float32)
+    want = _two_chunks(q32, d32, floor, gate)
+    got = _two_chunks(q32.astype(jnp.bfloat16), d32.astype(jnp.bfloat16),
+                      floor, gate)
+    assert np.isfinite(want[0]).all() and np.isfinite(want[2]).any()
+    norms = [float(jnp.max(jnp.sum(x * x, axis=1))) for x in (q32, d32)]
+    tol = EPS_CANCEL_COEF * (na + 2) * sum(norms)
+    for step in (0, 2):
+        # the same candidates (a list's slots fill in the order its
+        # distances compare, so a last-bit difference may permute them)
+        assert np.array_equal(np.sort(got[step + 1], axis=1),
+                              np.sort(want[step + 1], axis=1)), step
+        assert np.allclose(np.sort(got[step], axis=1),
+                           np.sort(want[step], axis=1), rtol=0,
+                           atol=tol), step
+    # the same lists to the bit where the backend's two dots agree
+    # (chip_smoke.py's fold.bf16 phase holds the chip to that)
+    if np.array_equal(got[0], want[0]):
+        assert all(np.array_equal(a, b) for a, b in zip(got, want))
+
+
+def _parent_dot_cross(q, d, precision):
+    """ops.pallas_extract._dot_cross as PR 38 left it, verbatim."""
+    from dmlp_tpu.ops.pallas_extract import split_bf16
+
+    def contract(a, b, mxu=jax.lax.Precision.DEFAULT):
+        return jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())), precision=mxu,
+            preferred_element_type=jnp.float32)
+
+    if precision == "bf16x3":
+        q_hi, q_lo = split_bf16(q)
+        d_hi, d_lo = split_bf16(d)
+        return contract(jnp.concatenate([q_hi, q_hi, q_lo], axis=1),
+                        jnp.concatenate([d_hi, d_lo, d_hi], axis=1))
+    if precision == "bf16":
+        return contract(q.astype(jnp.bfloat16), d.astype(jnp.bfloat16))
+    return contract(q, d, jax.lax.Precision.HIGHEST)
+
+
+def _untraced_fold(q, d, precision, gate):
+    """One carried kernel call through the wrapper's own body, untraced
+    (``__wrapped__``: no jit cache between a run and its patched twin)."""
+    from dmlp_tpu.ops.pallas_extract import _extract_topk_jit
+    rng = np.random.default_rng(5)
+    cd = jnp.asarray(np.sort(rng.uniform(0, 9e4, (q.shape[0], 24))),
+                     jnp.float32)
+    ci = jnp.asarray(rng.integers(0, 512, cd.shape), jnp.int32)
+    od, oi, _ = _extract_topk_jit.__wrapped__(
+        q, d, cd, ci, n_real=jnp.int32(500), id_base=jnp.int32(512), kc=24,
+        interpret=True, tile_q=128, tile_n=12800, ne=2, unroll=1,
+        block_skip=True, mxu_gate=gate, floor=None, precision=precision)
+    return np.asarray(od), np.asarray(oi)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16x3", "bf16"])
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_float32_operands_take_the_parents_form_to_the_bit(
+        monkeypatch, gate, precision):
+    """float32 staging bypasses the mechanism: on reals that are NOT
+    bfloat16 values, every form's lists are the parent's ``_dot_cross``'s
+    bit for bit."""
+    from dmlp_tpu.ops import pallas_extract
+    rng = np.random.default_rng(39)
+    q = jnp.asarray(rng.uniform(0, 255, (16, 128)), jnp.float32)
+    d = jnp.asarray(rng.uniform(0, 255, (512, 128)), jnp.float32)
+    got = _untraced_fold(q, d, precision, gate)
+    monkeypatch.setattr(pallas_extract, "_dot_cross", _parent_dot_cross)
+    want = _untraced_fold(q, d, precision, gate)
+    assert np.isfinite(want[0]).all()
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+
+
+def _kernel_operand_dtypes(q, d, precision="f32"):
+    """(query, data) dtypes of the pallas_call's operands in the
+    wrapper's traced program."""
+    from dmlp_tpu.ops.pallas_extract import _extract_topk_jit
+    jaxpr = jax.make_jaxpr(lambda q, d: _extract_topk_jit(
+        q, d, None, None, n_real=jnp.int32(200), id_base=jnp.int32(0),
+        kc=24, interpret=False, tile_q=128, tile_n=12800, ne=2, unroll=1,
+        block_skip=True, mxu_gate=True, floor=None,
+        precision=precision))(q, d)
+
+    def calls(jp):
+        for e in jp.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    (call,) = calls(jaxpr.jaxpr)
+    return tuple(v.aval.dtype for v in call.invars[1:3])
+
+
+def test_only_a_bfloat16_pair_hands_the_kernel_a_bfloat16_block():
+    """The test is the operands' dtypes at the boundary: both bfloat16,
+    and the data block is streamed as it is (the small resident query
+    block stays float32 in HBM and is cast back in the kernel); any
+    other pair is converted, as before. bfloat16 rows under float32
+    queries that are NOT bfloat16 values never take the one pass: their
+    lists are the float32-converted HIGHEST run's to the bit, and not
+    what rounding the queries would give."""
+    from dmlp_tpu.ops.pallas_extract import mxu_passes
+    f32, bf16 = jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.uniform(0, 255, (16, 64)), jnp.float32)
+    d = _bf16_valued(rng, (256, 64), 255.0)
+    assert _kernel_operand_dtypes(q.astype(bf16), d.astype(bf16)) \
+        == (f32, bf16)
+    assert _kernel_operand_dtypes(q, d.astype(bf16)) == (f32, f32)
+    assert _kernel_operand_dtypes(q.astype(bf16), d) == (f32, f32)
+    assert _kernel_operand_dtypes(q, d, "bf16x3") == (f32, f32)
+    assert [mxu_passes(p, t) for p, t in (
+        ("f32", "bfloat16"), ("bf16x3", bf16), ("bf16", "bfloat16"),
+        ("bf16", "float32"), ("bf16x3", f32), ("f32", "float32"))] \
+        == [1, 1, 1, 1, 3, 6]
+
+    def lists(q, d):
+        od, oi, _ = extract_topk(q, d, n_real=256, kc=24, interpret=True)
+        return np.asarray(od), np.asarray(oi)
+
+    mixed, old = lists(q, d.astype(bf16)), lists(q, d)
+    rounded = lists(q.astype(bf16), d.astype(bf16))
+    assert np.array_equal(mixed[0], old[0]) \
+        and np.array_equal(mixed[1], old[1])
+    assert not np.array_equal(mixed[0], rounded[0])
+
+
 def test_unknown_form_is_refused():
     q = jnp.zeros((8, 4), jnp.float32)
     d = jnp.zeros((256, 4), jnp.float32)

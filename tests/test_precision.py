@@ -265,6 +265,7 @@ def test_single_engine_reports_active_precision_and_inflation():
     rec = eng.last_precision
     assert rec["active"] == "bf16" and rec["configured"] == "bf16"
     assert rec["kcap_inflation"] > 0      # the window actually widened
+    assert rec["mxu_passes"] == 1 == eng.last_variant["mxu_passes"]
     # "f32" in exact mode at float32 staging MEANS the three-pass form,
     # and that form widens no window
     eng_f = SingleChipEngine(_cfg("f32"))
@@ -272,6 +273,7 @@ def test_single_engine_reports_active_precision_and_inflation():
     assert eng_f.last_precision["active"] == "bf16x3"
     assert eng_f.last_precision["configured"] == "bf16x3"
     assert eng_f.last_precision["kcap_inflation"] == 0
+    assert eng_f.last_precision["mxu_passes"] == 3
 
 
 def test_bf16_tie_grid_across_block_boundary():
@@ -421,6 +423,8 @@ def test_a_compiler_that_folds_the_split_gets_the_one_dot(
     assert format_results(eng.run(inp)) == format_results(knn_golden(inp))
     assert (eng.last_precision["active"],
             eng.last_precision["configured"]) == ("f32", "f32")
+    assert eng.last_precision["mxu_passes"] == 6 \
+        == eng.last_variant["mxu_passes"]
 
 
 def test_only_a_run_hands_its_solve_a_form_that_drops_products(
@@ -435,9 +439,9 @@ def test_only_a_run_hands_its_solve_a_form_that_drops_products(
     seen = []
     real = pallas_fused.variant_stamp
 
-    def spy(impl, kc, b, qb, a, precision="f32"):
+    def spy(impl, kc, b, qb, a, precision="f32", staging="float32"):
         seen.append(precision)
-        return real(impl, kc, b, qb, a, precision)
+        return real(impl, kc, b, qb, a, precision, staging)
 
     monkeypatch.setattr(pallas_fused, "variant_stamp", spy)
     inp = _case(404)
@@ -525,10 +529,104 @@ def test_resident_engine_stats_name_the_form():
                       np.zeros(0, np.int32), np.zeros((0, na)))
     q = rng.uniform(-10, 10, (5, na))
     ks = np.array([1, 3, 8, 17, 5], np.int32)
-    for exact, want in ((True, "bf16x3"), (False, "f32")):
+    for exact, want, passes in ((True, "bf16x3", 3), (False, "f32", 6)):
         eng = ResidentEngine(corpus, EngineConfig(
             select="extract", use_pallas=True, exact=exact))
         eng.solve_batch(q, ks)
         st = eng.bucket_stats()
         assert st["precision_plan"] == want
         assert st["last_precision"]["active"] == want
+        assert st["last_precision"]["mxu_passes"] == passes
+
+
+# -- rows staged in bfloat16: one pass, the "f32" form's own value (PR 39) ----
+
+def test_dot_cross_on_a_bfloat16_block_is_one_default_dot():
+    """A data block that arrives bfloat16 is contracted as it is, at
+    the default MXU precision with float32 accumulation, against the
+    float32 query block cast back (its values are bfloat16's): one
+    ``convert_element_type`` and one dot, whatever the form's name."""
+    import jax
+    import jax.numpy as jnp
+    from dmlp_tpu.ops.pallas_extract import PRECISIONS, _dot_cross
+    for precision in PRECISIONS:
+        for na in (64, 128, 960):
+            eqns = jax.make_jaxpr(
+                lambda q, d: _dot_cross(q, d, precision))(
+                jnp.ones((8, na), jnp.float32),
+                jnp.ones((256, na), jnp.bfloat16)).jaxpr.eqns
+            assert [e.primitive.name for e in eqns] \
+                == ["convert_element_type", "dot_general"], precision
+            dot = eqns[1]
+            assert [v.aval.dtype for v in dot.invars] \
+                == [jnp.bfloat16] * 2
+            assert [v.aval.shape[1] for v in dot.invars] == [na] * 2
+            assert "HIGHEST" not in str(dot.params["precision"])
+            assert dot.params["preferred_element_type"] == jnp.float32
+
+
+def _staged_corpus(kind: str):
+    """9216 rows of 16 attributes (past the switch to the extract
+    path): tests/test_serve_retry.py's planted corpus (near-duplicates
+    whose queries flag, a 600-row tie plateau, an integer grid that
+    ties across the fold), or uniform reals in [0, 255)."""
+    from tests.test_serve_retry import (CLUSTER, GRID, N, NA, planted_corpus,
+                                        queries_at)
+    corpus = planted_corpus()
+    if kind == "tie_heavy":
+        q = np.vstack([queries_at(corpus, CLUSTER, 1)[:4],
+                       queries_at(corpus, GRID, 2)[:4]])
+    else:
+        rng = np.random.default_rng(390)
+        corpus = KNNInput(
+            Params(N, 0, NA), corpus.labels,
+            rng.random((N, NA), dtype=np.float32).astype(np.float64) * 255,
+            np.zeros(0, np.int32), np.zeros((0, NA)))
+        q = rng.random((8, NA), dtype=np.float32).astype(np.float64) * 255
+    return corpus, q
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kind", ["tie_heavy", "uniform"])
+@pytest.mark.parametrize("engine", ["resident", "single"])
+def test_staged_engines_answer_as_the_oracle_and_say_their_passes(
+        engine, kind, dtype):
+    """Under bfloat16 staging the kernel is handed the bf16 rows and
+    spends ONE pass: the answers stay the float64 oracle's to the
+    byte, flagged queries included (the device retry in a resident
+    engine, the host repair in a batch one), and the variant stamp,
+    the precision record, ``stats`` and the solve's span say 1; under
+    float32 staging they say 3, the split form's."""
+    from dmlp_tpu.obs import trace as obs_trace
+    corpus, q = _staged_corpus(kind)
+    ks = np.array([10, 1, 16, 10, 10, 3, 16, 10], np.int32)
+    inp = KNNInput(Params(corpus.params.num_data, len(ks),
+                          corpus.params.num_attrs),
+                   corpus.labels, corpus.data_attrs, ks, q)
+    cfg = EngineConfig(dtype=dtype, use_pallas=True)
+    form, passes = ("f32", 1) if dtype == "bfloat16" else ("bf16x3", 3)
+    tracer = obs_trace.install(obs_trace.Tracer())
+    try:
+        if engine == "resident":
+            eng = ResidentEngine(corpus, cfg)
+            got = eng.solve_batch(q, ks)
+        else:
+            eng = SingleChipEngine(cfg)
+            got = eng.run(inp)
+    finally:
+        obs_trace.uninstall()
+    gold = knn_golden(inp)
+    assert format_results(got) == format_results(gold)
+    assert_same_results(got, gold)
+    assert eng._last_select == "extract"
+    assert eng.last_precision["active"] == form
+    assert eng.last_precision["mxu_passes"] == passes \
+        == eng.last_variant["mxu_passes"]
+    if engine == "resident":
+        st = eng.bucket_stats()
+        assert st["last_precision"]["mxu_passes"] == passes
+        (solve,) = [e for e in tracer.events() if e.get("ph") == "X"
+                    and e["name"] == "serve.solve_extract"]
+        assert solve["args"]["mxu_passes"] == passes
+        if kind == "tie_heavy":     # its planted queries flag
+            assert st["repairs"]["device"] >= 2

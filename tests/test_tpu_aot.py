@@ -50,7 +50,9 @@ def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
 
     lists = ((spec((QPAD, KCAP), jnp.float32),
               spec((QPAD, KCAP), jnp.int32)) if carry else (None, None))
-    # the split form is float32 staging's (a bf16 value has no low half)
+    # the split form is float32 staging's (a bf16 value has no low
+    # half); under the other two names the kernel is handed the
+    # bfloat16 block itself and spends one pass (_dot_cross)
     staged = jnp.float32 if precision == "bf16x3" else jnp.bfloat16
     compiled = _extract_topk_jit.lower(
         spec((QPAD, NA), staged), spec((CHUNK, NA), staged),
@@ -119,6 +121,44 @@ def test_resident_fold_program_compiles_for_v5e(v5e, precision):
     assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
 
 
+def _kernel_calls(hlo: str):
+    return [line for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _materialized(hlo: str, shape: str):
+    """The program's instructions of result type ``shape`` that are NOT
+    inside a fused computation: arrays the program writes out (what a
+    fusion computes on the way to its outputs is never stored)."""
+    found, fused = [], False
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split(" ", 1)[0]
+        elif not fused and f" = {shape}" in line:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _assert_bf16_rows_reach_the_kernel(compiled, chunk: str):
+    """The fold of a bfloat16 stack hands BOTH kernel calls the chunk
+    as bfloat16 (operand 2 of the custom call: the data BlockSpec's),
+    writes no float32 copy of it, and allocates at most one chunk of
+    bfloat16 beside its arguments."""
+    from dmlp_tpu.obs.hlo import kernel_operand_types
+    hlo = compiled.as_text()
+    calls = kernel_operand_types(hlo)
+    assert len(calls) == 2
+    for operands in calls:
+        assert len(operands) == 8, operands
+        assert operands[2] == f"bf16[{chunk}]", operands
+        assert operands[1].startswith("f32["), operands
+    assert f"bf16[{chunk}]" in hlo
+    assert _materialized(hlo, f"f32[{chunk}]") == []
+    rows, attrs = (int(x) for x in chunk.split(","))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.1 * rows * attrs * 2
+
+
 @pytest.mark.parametrize(
     "staged,precision,chunks,attrs",
     [(jnp.bfloat16, "f32", 328, 128), (jnp.float32, "bf16x3", 82, 128),
@@ -128,12 +168,13 @@ def test_retry_fold_program_compiles_for_v5e(v5e, staged, precision, chunks,
                                              attrs):
     """The device retry of flagged queries (PR 38), which warm-up
     compiles with every extract bucket: ONE short query tile of 16 rows
-    (a bfloat16 block's sublanes) at the kernel's widest window, 512
-    slots, over the resident stack of each one-chip cell: the buckets'
-    own fold program at one more shape. ``bigann-10m.bulk``'s 328
-    chunks of 51 200 x 128 under the default bfloat16 staging (whose
-    rows take the one ``HIGHEST`` dot), ``bigann.bulk``'s 82 under
-    float32, ``gist.bulk``'s 21 of 960 attributes on 1024 lanes."""
+    at the kernel's widest window, 512 slots, over the resident stack
+    of each one-chip cell: the buckets' own fold program at one more
+    shape. ``bigann-10m.bulk``'s 328 chunks of 51 200 x 128 under the
+    default bfloat16 staging (whose rows reach the kernel as bfloat16
+    and take ONE MXU pass, PR 39: no float32 copy of a chunk),
+    ``bigann.bulk``'s 82 under float32, ``gist.bulk``'s 21 of 960
+    attributes on 1024 lanes."""
     from dmlp_tpu.serve.engine import (ResidentEngine, _fold_stack,
                                        _kernel_statics)
     q, kc = ResidentEngine._RETRY_QUERIES, ResidentEngine._MP_KC
@@ -151,10 +192,54 @@ def test_retry_fold_program_compiles_for_v5e(v5e, staged, precision, chunks,
         spec((chunks,), jnp.int32), spec((), jnp.int32),
         spec((), jnp.int32), **statics).compile()
     hlo = compiled.as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert len(_kernel_calls(hlo)) == 2
     assert " while(" in hlo
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.1 * 51200 * attrs * 4
+    if staged == jnp.bfloat16:
+        _assert_bf16_rows_reach_the_kernel(compiled, f"51200,{attrs}")
+    else:
+        assert _materialized(hlo, f"bf16[51200,{attrs}]") == []
+
+
+def test_default_dtype_fold_program_compiles_for_v5e(v5e):
+    """``bigann-10m.bulk``'s own fold: q1024 at the 120-slot window its
+    bucket plans under bfloat16 staging (tile_q 64: a data block is
+    read 16 times a chunk), over 328 resident chunks of 51 200 x 128
+    bfloat16. The kernel streams the stack's rows as they are; a
+    float32 query panel beside bfloat16 rows (no engine stages that)
+    keeps the converted chunk, the parent's program."""
+    from dmlp_tpu.engine.single import resolve_kcap
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = EngineConfig(dtype="bfloat16", use_pallas=True)
+    assert cfg.resolve_precision("bfloat16") == "f32"
+    kc = resolve_kcap(cfg, 16, "extract", 1 << 24, staging="bfloat16",
+                      precision="f32", na=128)
+    kern = _kernel_statics("fused", kc, 51200, 1024, 128, "f32", False)
+    assert (kc, kern["tile_q"], kern["tile_n"], kern["ne"]) \
+        == (120, 64, 12800, 4)
+
+    def fold(q_dtype):
+        return _fold_stack.lower(
+            spec((1024, 128), q_dtype),
+            spec((328, 51200, 128), jnp.bfloat16), spec((328,), jnp.int32),
+            spec((), jnp.int32), spec((), jnp.int32), **kern).compile()
+
+    compiled = fold(jnp.bfloat16)
+    hlo = compiled.as_text()
+    assert sorted(c.lstrip().removeprefix("ROOT ").split(".", 1)[0]
+                  for c in _kernel_calls(hlo)) == [
+        "%dmlp_topk_fused", "%dmlp_topk_fused_fresh"]
+    assert " while(" in hlo
+    _assert_bf16_rows_reach_the_kernel(compiled, "51200,128")
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        >= 328 * 51200 * 128 * 2
+    assert _materialized(fold(jnp.float32).as_text(), "f32[51200,128]")
 
 
 @F32_FORMS

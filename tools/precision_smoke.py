@@ -163,8 +163,8 @@ def main(argv=None) -> int:
         fail("bf16 arm reports zero kcap inflation — the lowp_eps "
              "margin never reached the candidate window")
     # (the f32 arm's pass is the float32 FORM: three bf16 passes over
-    # split operands in exact mode at float32 staging, "bf16x3"; the one
-    # HIGHEST dot, "f32", where the staging is bfloat16)
+    # split operands in exact mode at float32 staging, "bf16x3"; "f32",
+    # one exact pass over the bf16 rows, where the staging is bfloat16)
     if prec["f32"].get("active") not in ("bf16x3", "f32") \
             or prec["f32"].get("kcap_inflation", 0) != 0:
         fail(f"f32 kill-switch arm reports {prec['f32']!r}")
