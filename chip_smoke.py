@@ -13,6 +13,11 @@ binary (``oracle_capture/oracle_4.out``):
   fold.bf16 python chip_smoke.py --fold-child        one resident fold of
                                                      bf16 rows: one MXU
                                                      pass, HIGHEST's lists
+  fold.narrow python chip_smoke.py --narrow-child    one resident fold of
+                                                     100-wide signed bf16
+                                                     rows staged on 128
+                                                     lanes: float64's
+                                                     candidates
   serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
                                                      stats, SIGTERM drain
   mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
@@ -50,6 +55,16 @@ dot), and fails unless the compiled program hands the kernel bfloat16
 rows, ``mxu_passes`` reads 1 and the two runs' lists are equal to the
 bit: a chip whose single pass rounded differently would show here and
 nowhere on a CPU, whose two dots are one float32 loop.
+
+``fold.narrow`` (PR 40) holds the chip to rows that are not whole lanes:
+signed reals of 100 attributes (MS Turing-ANNS's width) rounded to
+bfloat16, staged as the serving engine stages them (zero-padded to
+``lane_padded(100)`` = 128) and folded by the same program. It fails
+unless the compiled program hands the kernel the padded bfloat16 chunk
+and allocates less than one chunk beside the stack (left 100 wide the
+compiler re-lays-out the whole stack every fold), every list holds the
+float64 brute force's nearest candidates over the same values, and the
+listed distances are float64's within the engine's own float32 bound.
 
 The configs run in the order given (default ``1,4``): config 1 is the
 same path at a size that takes seconds, so a machine with no chip fails
@@ -362,19 +377,39 @@ def fold_child(out_path: str) -> int:
     return 0
 
 
+def run_fold_child(c: Config, name: str, flag: str
+                   ) -> Tuple[Optional[Dict[str, Any]], float, List[str]]:
+    """One fold phase's child (this file under ``flag``), run to its
+    end: (the JSON record it wrote, its wall seconds, the miss if it
+    wrote none)."""
+    out = c.log(f"{name}.json")
+    if os.path.exists(out):
+        os.remove(out)
+    rc, wall = run_child([os.path.abspath(__file__), flag, out], None,
+                         c.log(f"{name}.out"), c.log(f"{name}.err"))
+    if rc != 0 or not os.path.exists(out):
+        return None, wall, [
+            f"child exited {rc}:\n{tail(c.log(f'{name}.err'))}"]
+    with open(out) as f:
+        return json.load(f), wall, []
+
+
+def device_misses(stamp: Dict[str, Any]) -> List[str]:
+    """What a fold child's own device stamp says against it."""
+    bad = []
+    if stamp.get("platform") != "tpu":
+        bad.append(f"platform is {stamp.get('platform')}")
+    if stamp.get("pallas_interpret") is not False:
+        bad.append(f"pallas_interpret is {stamp.get('pallas_interpret')}")
+    return bad
+
+
 def phase_fold_bf16(c: Config) -> List[str]:
     """``fold.bf16``: rows staged in bfloat16 reach the MXU as bfloat16,
     in one pass, and give the ``HIGHEST`` dot's lists."""
-    out = c.log("fold.bf16.json")
-    if os.path.exists(out):
-        os.remove(out)
-    rc, wall = run_child([os.path.abspath(__file__), "--fold-child", out],
-                         None, c.log("fold.bf16.out"),
-                         c.log("fold.bf16.err"))
-    if rc != 0 or not os.path.exists(out):
-        return [f"child exited {rc}:\n{tail(c.log('fold.bf16.err'))}"]
-    with open(out) as f:
-        got = json.load(f)
+    got, wall, bad = run_fold_child(c, "fold.bf16", "--fold-child")
+    if got is None:
+        return bad
     say(f"  fold.bf16: wall {wall:.1f} s (smoke timing); {got['shape']}; "
         f"mxu_passes {got['mxu_passes']}; the kernel's data operands "
         f"{got['kernel_data_operands']}; lists against the float32 "
@@ -389,11 +424,7 @@ def phase_fold_bf16(c: Config) -> List[str]:
 def fold_misses(got: Dict[str, Any]) -> List[str]:
     """Every miss in the ``fold.bf16`` child's record, named."""
     stamp = got.get("device") or {}
-    bad = []
-    if stamp.get("platform") != "tpu":
-        bad.append(f"platform is {stamp.get('platform')}")
-    if stamp.get("pallas_interpret") is not False:
-        bad.append(f"pallas_interpret is {stamp.get('pallas_interpret')}")
+    bad = device_misses(stamp)
     if got.get("mxu_passes") != {"bfloat16": 1, "float32": 6}:
         bad.append(f"mxu_passes is {got.get('mxu_passes')}, not 1 for "
                    "bfloat16 rows and 6 for float32's HIGHEST dot")
@@ -414,6 +445,133 @@ def fold_misses(got: Dict[str, Any]) -> List[str]:
             f"{got.get('dists_differ')} distances differ (largest "
             f"{got.get('dists_max_abs_diff')}): LOWP_COEF['f32'] = 0 "
             "does not hold for it")
+    return bad
+
+
+#: ``msturing-10m.bulk``'s dispatch shape; ``sure``: the nearest rows by
+#: float64 every 120-slot list must hold (the last 8 slots may trade
+#: places with their neighbours outside by a float32 rounding)
+NARROW_SHAPE = dict(queries=1024, attrs=100, kc=120, chunks=3, sure=112)
+
+
+def narrow_child(out_path: str) -> int:
+    """The ``fold.narrow`` child: seeded reals in [-1, 1) of 100
+    attributes rounded to bfloat16, zero-padded to ``lane_padded(100)``
+    as ``ResidentEngine._restage_chunk`` pads them, folded by
+    serve.engine._fold_stack (chunks of 51 200 rows; 1 024 where the
+    kernel runs interpreted), against a float64 brute force over the
+    same values; what it found goes to ``out_path`` as JSON."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dmlp_tpu.engine.finalize import EPS_CANCEL_COEF
+    from dmlp_tpu.obs.hlo import kernel_operand_types
+    from dmlp_tpu.obs.run import device_stamp
+    from dmlp_tpu.ops.pallas_distance import pallas_interpret
+    from dmlp_tpu.ops.pallas_extract import lane_padded
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    interpret = pallas_interpret()
+    nq, na, kc, chunks, sure = (NARROW_SHAPE[k] for k in (
+        "queries", "attrs", "kc", "chunks", "sure"))
+    rows = 1024 if interpret else 51200
+    a_pad = lane_padded(na)
+    rng = np.random.default_rng(40)
+    pad = ((0, 0),) * 2 + ((0, a_pad - na),)
+    q16 = jnp.asarray(rng.uniform(-1, 1, (nq, na)), jnp.bfloat16)
+    d16 = jnp.asarray(rng.uniform(-1, 1, (chunks, rows, na)), jnp.bfloat16)
+    kern = _kernel_statics("fused", kc, rows, nq, a_pad, "f32", interpret)
+    n_real = chunks * rows - 77           # the last block holds sentinels
+    args = (jnp.pad(q16, pad[1:]), jnp.pad(d16, pad),
+            jnp.arange(chunks, dtype=jnp.int32), jnp.int32(chunks),
+            jnp.int32(n_real))
+    data_operands = temp_bytes = None
+    if not interpret:
+        compiled = _fold_stack.lower(*args, **kern).compile()
+        data_operands = [ops[2] for ops in kernel_operand_types(
+            compiled.as_text())]
+        temp_bytes = int(compiled.memory_analysis().temp_size_in_bytes)
+    od, oi, _gated = jax.device_get(_fold_stack(*args, **kern))
+    # float64 over the same bfloat16 values, a block of queries at a time
+    q64 = np.asarray(q16.astype(jnp.float32), np.float64)
+    d64 = np.asarray(d16.astype(jnp.float32),
+                     np.float64).reshape(-1, na)[:n_real]
+    dn = np.einsum("na,na->n", d64, d64)
+    qn = np.einsum("qa,qa->q", q64, q64)
+    missing = 0
+    for lo in range(0, nq, 128):
+        dist = qn[lo:lo + 128, None] + dn[None, :] \
+            - 2.0 * q64[lo:lo + 128] @ d64.T
+        near = np.argpartition(dist, sure, axis=1)[:, :sure]
+        missing += sum(int(np.setdiff1d(n, got).size)
+                       for n, got in zip(near, oi[lo:lo + 128]))
+    valid = bool((oi >= 0).all() and (oi < n_real).all())
+    diff = d64[np.clip(oi, 0, n_real - 1)] - q64[:, None, :]
+    true = np.einsum("qka,qka->qk", diff, diff)
+    scale = qn.max() + dn.max()
+    with open(out_path, "w") as f:
+        json.dump({
+            "device": device_stamp(None),
+            "shape": dict(NARROW_SHAPE, chunk_rows=rows, n_real=n_real),
+            "a_pad": a_pad,
+            "kernel_data_operands": data_operands,
+            "temp_bytes": temp_bytes,
+            "chunk_bytes": rows * a_pad * 2,
+            "sure_missing": missing,
+            "ids_valid": valid,
+            "err_over_scale_vs_float64": float(
+                np.max(np.abs(od - true)) / scale),
+            "err_bound_over_scale": EPS_CANCEL_COEF * (na + 2),
+        }, f)
+    return 0
+
+
+def phase_fold_narrow(c: Config) -> List[str]:
+    """``fold.narrow``: rows of 100 signed attributes staged on whole
+    lanes fold to the float64 brute force's candidates, and the program
+    holds no copy of the stack."""
+    got, wall, bad = run_fold_child(c, "fold.narrow", "--narrow-child")
+    if got is None:
+        return bad
+    say(f"  fold.narrow: wall {wall:.1f} s (smoke timing); {got['shape']}; "
+        f"staged {got['a_pad']} wide; the kernel's data operands "
+        f"{got['kernel_data_operands']}; temporaries {got['temp_bytes']} B "
+        f"beside the stack; float64's nearest {got['shape']['sure']} "
+        f"missing from a list: {got['sure_missing']}; distances against "
+        f"float64 {got['err_over_scale_vs_float64']:.3g} of the scale "
+        f"(bound {got['err_bound_over_scale']:.3g})")
+    return narrow_misses(got)
+
+
+def narrow_misses(got: Dict[str, Any]) -> List[str]:
+    """Every miss in the ``fold.narrow`` child's record, named."""
+    stamp = got.get("device") or {}
+    on_chip = stamp.get("platform") == "tpu"
+    bad = device_misses(stamp)
+    if got.get("a_pad") != 128:
+        bad.append(f"100-wide rows are staged {got.get('a_pad')} wide, "
+                   "not on one whole lane vector")
+    ops = got.get("kernel_data_operands")
+    if on_chip and (not ops or any(
+            not o.startswith("bf16[") or not o.endswith(",128]")
+            for o in ops)):
+        bad.append(f"the compiled fold hands the kernel {ops}, not the "
+                   "bfloat16 rows on 128 lanes")
+    if on_chip and not (got.get("temp_bytes") is not None
+                        and got["temp_bytes"] < 1.1 * got["chunk_bytes"]):
+        bad.append(f"the compiled fold allocates {got.get('temp_bytes')} "
+                   "B beside the stack: more than one chunk, a copy of "
+                   "the stack is back")
+    if not got.get("ids_valid"):
+        bad.append("the fold's lists hold ids outside the corpus")
+    if got.get("sure_missing"):
+        bad.append(f"{got['sure_missing']} of float64's nearest "
+                   "candidates are missing from the fold's lists")
+    if not got.get("err_over_scale_vs_float64", 1.0) \
+            <= got.get("err_bound_over_scale", 0.0):
+        bad.append("the fold's distances are off float64's by "
+                   f"{got.get('err_over_scale_vs_float64')} of the scale, "
+                   f"over the bound {got.get('err_bound_over_scale')}")
     return bad
 
 
@@ -524,6 +682,8 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
     misses += [f"config {config_id} batch.f32: {m}" for m in bad]
     misses += [f"config {config_id} fold.bf16: {m}"
                for m in phase_fold_bf16(c)]
+    misses += [f"config {config_id} fold.narrow: {m}"
+               for m in phase_fold_narrow(c)]
     misses += [f"config {config_id} serve: {m}" for m in phase_serve(c)]
     from dmlp_tpu.config import EngineConfig
     chips = stamp.get("device_count", 0)
@@ -564,9 +724,13 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "the same path small, then at full width)")
     ap.add_argument("--fold-child", metavar="OUT", default=None,
                     help=argparse.SUPPRESS)   # the fold.bf16 phase's child
+    ap.add_argument("--narrow-child", metavar="OUT", default=None,
+                    help=argparse.SUPPRESS)   # the fold.narrow phase's child
     args = ap.parse_args(argv)
     if args.fold_child:
         return fold_child(args.fold_child)
+    if args.narrow_child:
+        return narrow_child(args.narrow_child)
     if not os.path.isdir(os.path.join(REPO, "dmlp_tpu")):
         print(f"chip_smoke: no dmlp_tpu package beside {__file__}",
               file=sys.stderr)

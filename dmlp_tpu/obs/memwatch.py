@@ -333,9 +333,10 @@ def serve_engine_model(capacity_rows: int, na: int,
         terms["extract_chunks"] = extract_chunks * chunk_rows * ca * item
     if summary_blocks:
         # Device-resident block summaries of the pruned two-stage
-        # solve (ops.summaries.stage_summaries): two (B, A) f32 boxes,
-        # two (B,) f32 norm bands, one (B,) i32 count vector.
-        terms["resident_summaries"] = summary_blocks * (8 * na + 12)
+        # solve (ops.summaries.stage_summaries): two (B, A) f32 boxes
+        # at the stack's width, two (B,) f32 norm bands, one (B,) i32
+        # count vector.
+        terms["resident_summaries"] = summary_blocks * (8 * ca + 12)
     if qpad:
         terms["query_blocks"] = qpad * ca * item
         terms["topk_carries"] = 2 * qpad * kcap * _TOPK_ITEMSIZE

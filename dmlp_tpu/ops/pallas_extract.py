@@ -122,16 +122,47 @@ def _whole_lanes(a: int) -> int:
 
 def lane_padded(a: int) -> int:
     """The attribute width a RESIDENT stack holds on the device for
-    rows of ``a`` attributes: whole 128-lane vectors once a row is
-    wider than one (960 -> 1024; a <= 128 stays as it is). Zeros are
-    exact for a squared L2 and for a norm, and cost nothing the chip
-    would not spend anyway: a (tile_n, 960) block takes 1024 lanes in
-    VMEM and the MXU contracts 128 at a time. What they buy is the
-    layout: XLA keeps an array whose minor axis is not whole lanes
-    rows-minor, and the fold then copies every chunk twice (the slice,
+    rows of ``a`` attributes: whole 128-lane vectors once a row fills
+    more than half of one (65..127 -> 128, 960 -> 1024; a <= 64 stays
+    as it is). Zeros are exact for a squared L2 and for a norm, and
+    cost nothing the chip would not spend anyway: a (tile_n, a) block
+    takes whole lanes in VMEM and the MXU contracts 128 at a time (the
+    kernel's time is the same at every staged width: below). What they
+    buy is the layout: XLA keeps an array whose minor axis is not whole
+    lanes rows-minor, and the fold then copies every chunk (the slice,
     then a relayout for the kernel) where the padded stack is copied
-    once, by the pass that computes the row norms."""
-    return a if a <= 128 else _whole_lanes(a)
+    once, by the pass that computes the row norms.
+
+    Above one lane vector the rule is PR 31's (960 against 1024:
+    tuned_variant). Below it, measured on the chip (PR 40, TPU v5
+    lite; ``_fold_stack`` alone, q1024, kc 120, 196 of 328 chunks of
+    51 200 rows, bfloat16; device time a fold from the profiler, five
+    folds; PERF.md section 6), the stack as it is against the same
+    values zero-padded to 128 (kernel 99.28 ms, everything beside it
+    5.0, stack 4.30 GB, no temporary):
+
+    - 100 wide: the compiler keeps the stack attribute-major and
+      re-lays-out ALL of it every fold into a temporary the size of
+      the 128-wide stack (``%copy = bf16[328,51200,100]``, 12.39 ms;
+      temp_size_in_bytes 4.30 GB beside a 3.36 GB stack; so at every
+      width from 65 to 127 that is not a multiple of 8, by the
+      compiler's account): kernel 99.56, beside it 17.4: a fold 12.2%
+      longer, and MORE memory than padded.
+    - 96 wide: no temporary, a slice and a transposing copy a chunk:
+      kernel 99.64, beside it 9.2: 4.3% longer for a stack 25% smaller.
+    - 64 wide: the same: kernel 99.54, beside it 8.1: 3.2% longer for
+      HALF the stack; 20 wide: 99.59 and 10.3: 5.4% longer for a stack
+      6.4 times smaller.
+    - float32 staging at 100 wide (98 of 164 chunks, kc 32): no
+      temporary, but the slice of the attribute-major stack alone is
+      21.1 ms and the kernel 57.05 against 52.15: a fold 48% longer
+      as it is (83.15 against 56.20 ms).
+
+    Hence half a lane vector: past it whole lanes cost at most twice
+    the stack and cure the widths that hold a second stack in flight;
+    at 64 and below a few percent of a fold do not pay for two to six
+    times the corpus."""
+    return a if a <= 64 else _whole_lanes(a)
 
 
 #: what the kernel may hold in VMEM by its own reckoning (vmem_bytes);
@@ -363,8 +394,10 @@ def _dot_cross(q, d, precision: str):
     the fold's visit at 128 attributes 18.6 us against 20.0; at 1 024
     each dot already accumulates over eight MXU tiles and the two tie,
     47.1 / 47.1). Mosaic compiles the stacked operands whether the row
-    is whole lanes or not (tests/test_tpu_aot.py: 64, 128, 960, 1 024
-    and 2 048 attributes, the wide ones at the tiles vmem_bytes picks).
+    is whole lanes or not (tests/test_tpu_aot.py: 64, 96, 100, 128,
+    960, 1 024 and 2 048 attributes, the wide ones at the tiles
+    vmem_bytes picks; the one-pass form over bfloat16 blocks at 64, 96,
+    100 and 128).
     The split is made a visit: beside the MXU's passes it is not seen
     in the visit's time, and planes staged outside the kernel measured
     slower (a shorter fold of the same call: 57.7 us a 1 024-wide visit

@@ -779,8 +779,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self._dn_max()
 
         # -- the resident staged corpus (the streaming paths' view) ----------
+        # (the streaming paths' copy holds a row at its own width)
         with obs_span("serve.stage_resident", rows=self.capacity_rows,
-                      na=na):
+                      na=na, a_pad=na, pad_bytes=0):
             sdt = np_staging_dtype(self._staging)
             attrs = np.zeros((self.capacity_rows, na), sdt)
             attrs[:n] = corpus.data_attrs
@@ -933,7 +934,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             return
         cr = self._ex_chunk_rows
         with obs_span("serve.stage_chunks", chunks=self._ex_nchunks,
-                      chunk_rows=cr):
+                      chunk_rows=cr, na=self.num_attrs,
+                      a_pad=self._ex_attrs, pad_bytes=self._pad_bytes()):
             # Allocated on the device, then filled a chunk at a time by
             # a donated update: the host never holds a second corpus.
             self._chunks = jnp.zeros((self._ex_nchunks, cr, self._ex_attrs),
@@ -941,6 +943,12 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             for c in range(self._ex_nchunks):
                 self._restage_chunk(c)
         self._build_summaries()
+
+    def _pad_bytes(self) -> int:
+        """Bytes of zero columns the staged stack holds beyond the
+        corpus' own width (lane_padded); 0 on whole-lane rows."""
+        return self._ex_nchunks * self._ex_chunk_rows \
+            * (self._ex_attrs - self.num_attrs) * self._staging_itemsize()
 
     # -- resident block summaries (pruned two-stage solve, stage 0) -----------
 
@@ -1818,6 +1826,10 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             "cold_start_compile_ms": self.cold_start_compile_ms,
             "corpus_rows": self.n_real,
             "capacity_rows": self.capacity_rows,
+            # a row's own width, and the width the extract path's
+            # stack holds it at on the device (lane_padded)
+            "num_attrs": self.num_attrs,
+            "staged_attrs": self._ex_attrs,
             "gate_carry": self.gate_carry,
             "last_gated_fraction": self.last_gated_fraction,
             # the resident chunks that hold rows: what a dense fold

@@ -41,6 +41,13 @@ def test_chip_smoke_dry_run_matches_oracle_and_refuses_cpu():
     assert "FAIL config 1 fold.bf16: pallas_interpret is True" in out
     assert out.count("FAIL config 1 fold.bf16") == 2
     assert "MXU passes a visit" not in out
+    # and the fold of 100-wide signed rows staged on 128 lanes: float64's
+    # candidates in every list, nothing named but platform and interpreter
+    assert "fold.narrow: wall" in out and "staged 128 wide" in out
+    assert "missing from a list: 0" in out
+    assert "FAIL config 1 fold.narrow: platform is cpu" in out
+    assert "FAIL config 1 fold.narrow: pallas_interpret is True" in out
+    assert out.count("FAIL config 1 fold.narrow") == 2
     # both children ran to the end and answered byte-identically
     assert "serve: 3 requests x 256 queries" in out
     assert "differ" not in out and "exited" not in out
@@ -178,6 +185,45 @@ def test_fold_bf16_names_every_miss(change, named):
     the float32 ``HIGHEST`` run's lists to the bit, on the chip."""
     cs = _load_chip_smoke()
     misses = cs.fold_misses(dict(_FOLD_OK, **change))
+    if named is None:
+        assert misses == []
+    else:
+        assert len(misses) == 1 and named in misses[0], misses
+
+
+_NARROW_OK = {
+    "device": {"platform": "tpu", "pallas_interpret": False},
+    "a_pad": 128,
+    "kernel_data_operands": ["bf16[51200,128]", "bf16[51200,128]"],
+    "temp_bytes": 13107200, "chunk_bytes": 13107200, "sure_missing": 0,
+    "ids_valid": True, "err_over_scale_vs_float64": 2e-7,
+    "err_bound_over_scale": 7.3e-5}
+
+
+@pytest.mark.parametrize("change, named", [
+    ({}, None),
+    ({"a_pad": 100}, "not on one whole lane vector"),
+    ({"kernel_data_operands": ["bf16[51200,100]", "bf16[51200,100]"]},
+     "not the bfloat16 rows on 128 lanes"),
+    ({"kernel_data_operands": ["f32[51200,128]", "bf16[51200,128]"]},
+     "not the bfloat16 rows on 128 lanes"),
+    ({"temp_bytes": 4300468224}, "a copy of the stack is back"),
+    ({"temp_bytes": None}, "a copy of the stack is back"),
+    ({"sure_missing": 3}, "3 of float64's nearest candidates"),
+    ({"ids_valid": False}, "outside the corpus"),
+    ({"err_over_scale_vs_float64": 1e-3}, "over the bound"),
+    ({"device": {"platform": "tpu", "pallas_interpret": True}},
+     "pallas_interpret is True"),
+    ({"device": {"platform": "cpu", "pallas_interpret": False},
+      "kernel_data_operands": None, "temp_bytes": None},
+     "platform is cpu")])
+def test_fold_narrow_names_every_miss(change, named):
+    """The ``fold.narrow`` phase's verdict on its child's record: rows
+    of 100 attributes on 128 lanes in the compiled program's kernel
+    calls, no copy of the stack beside it, float64's candidates in
+    every list."""
+    cs = _load_chip_smoke()
+    misses = cs.narrow_misses(dict(_NARROW_OK, **change))
     if named is None:
         assert misses == []
     else:

@@ -242,6 +242,100 @@ def test_default_dtype_fold_program_compiles_for_v5e(v5e):
     assert _materialized(fold(jnp.float32).as_text(), "f32[51200,128]")
 
 
+def _narrow_fold(v5e, q: int, kc: int, attrs: int):
+    """``_fold_stack`` at ``msturing-10m.bulk``'s stack (328 chunks of
+    51 200 rows, bfloat16 staging) with rows ``attrs`` wide and ``q``
+    query rows at ``kc`` slots, compiled for one v5e chip."""
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    kern = _kernel_statics("fused", kc, 51200, q, attrs, "f32", False)
+    return _fold_stack.lower(
+        spec((q, attrs), jnp.bfloat16),
+        spec((328, 51200, attrs), jnp.bfloat16), spec((328,), jnp.int32),
+        spec((), jnp.int32), spec((), jnp.int32), **kern).compile()
+
+
+@pytest.mark.parametrize("program", ["fold", "retry"])
+def test_narrow_row_fold_programs_hold_no_copy_of_the_stack(v5e, program):
+    """``msturing-10m.bulk``'s two programs (PR 40): the bucket's fold
+    (q1024 at the 120-slot window bfloat16 staging plans from 100
+    attributes) and the device retry (16 rows at 512 slots), over 328
+    resident chunks of 51 200 rows at the width ``lane_padded(100)``
+    gives. The kernel is handed the staged bfloat16 chunk and beside
+    the 4.3 GB stack the program allocates less than one chunk: left
+    100 wide the compiler keeps the stack attribute-major and
+    re-lays-out ALL of it every fold (the next test), and that copy
+    must not come back unseen."""
+    from dmlp_tpu.engine.single import resolve_kcap
+    from dmlp_tpu.ops.pallas_extract import lane_padded
+    from dmlp_tpu.serve.engine import ResidentEngine
+    a = lane_padded(100)
+    assert a == 128
+    cfg = EngineConfig(dtype="bfloat16", use_pallas=True)
+    kc = resolve_kcap(cfg, 16, "extract", 1 << 24, staging="bfloat16",
+                      precision="f32", na=100)
+    assert kc == 120
+    q, kc = {"fold": (1024, kc),
+             "retry": (ResidentEngine._RETRY_QUERIES,
+                       ResidentEngine._MP_KC)}[program]
+    compiled = _narrow_fold(v5e, q, kc, a)
+    hlo = compiled.as_text()
+    assert len(_kernel_calls(hlo)) == 2 and " while(" in hlo
+    _assert_bf16_rows_reach_the_kernel(compiled, f"51200,{a}")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 328 * 51200 * a * 2
+    assert mem.temp_size_in_bytes < 51200 * a * 2 * 1.1
+    assert not any(" copy(" in line for line in _materialized(
+        hlo, f"bf16[328,51200,{a}]"))
+
+
+def test_a_stack_left_100_wide_is_relaid_out_in_full(v5e):
+    """Why ``lane_padded`` reaches below one lane vector: the same fold
+    with the stack left 100 wide. The compiler gives the argument an
+    attribute-major layout and the program copies the whole stack into
+    a row-major temporary the size of the 128-wide stack, every fold
+    (12.4 ms of the chip's time a batch, measured in PR 40: PERF.md
+    section 6). The day this stops holding, the rule can be looked at
+    again."""
+    compiled = _narrow_fold(v5e, 1024, 120, 100)
+    copies = _materialized(compiled.as_text(), "bf16[328,51200,100]")
+    assert any(" copy(" in line for line in copies), copies
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 328 * 51200 * 128 * 2
+    assert mem.temp_size_in_bytes >= 328 * 51200 * 128 * 2
+
+
+@pytest.mark.parametrize("na", [100, 96])
+@pytest.mark.parametrize("staged,precision", [
+    (jnp.bfloat16, "f32"), (jnp.float32, "bf16x3")],
+    ids=["bf16_one_pass", "bf16x3"])
+def test_extract_kernel_compiles_for_v5e_at_narrow_rows(v5e, staged,
+                                                        precision, na):
+    """The kernel alone on rows that are not whole lanes below 128, as
+    the batch engines and the mesh daemon still stage them (MS
+    Turing's and MS SPACEV's 100, DEEP's 96): the one-pass form over
+    bfloat16 blocks, and the split form, whose stacked operands
+    (a contraction of 300 or 288) then join off a lane boundary."""
+    from dmlp_tpu.serve.engine import _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    kern = _kernel_statics("fused", 32, 51200, 1024, na, precision, False)
+    assert kern["tile_n"] == 12800
+    compiled = _extract_topk_jit.lower(
+        spec((1024, na), staged), spec((51200, na), staged),
+        spec((1024, 32), jnp.float32), spec((1024, 32), jnp.int32),
+        n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
+        block_skip=True, floor=None, **kern).compile()
+    assert len(_kernel_calls(compiled.as_text())) == 1
+
+
 @F32_FORMS
 def test_wide_row_fold_program_compiles_for_v5e(v5e, precision):
     """The same program at ``gist.bulk``'s shape: q1024, 21 resident

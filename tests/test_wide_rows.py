@@ -106,7 +106,8 @@ def test_variant_supports_table(qb, b, a, kc, v, priced, want):
         assert (priced <= 64 * 2**20) is want
 
 
-def test_lane_padding_is_for_rows_wider_than_a_lane_vector():
+def test_lane_padding_at_the_widths_pr31_pinned():
+    # (since PR 40 the rule reaches down to 65: tests/test_narrow_rows.py)
     assert [lane_padded(a) for a in (1, 16, 64, 128, 129, 200, 960, 1024,
                                      2048)] == [1, 16, 64, 128, 256, 256,
                                                 1024, 1024, 2048]
