@@ -83,8 +83,9 @@ from dmlp_tpu.ops.pallas_extract import mxu_passes
 from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS, make_mesh
 from dmlp_tpu.serve.engine import (_KERNEL_STATICS, CapacityError,
                                    ResidentServingCore, _kernel_statics,
-                                   _update_chunk, fold_chunks, fold_tiles,
-                                   k_bucket, query_bucket)
+                                   _update_chunk, _variant_args,
+                                   fold_chunks, fold_tiles, k_bucket,
+                                   query_bucket)
 from dmlp_tpu.tune.cache import shape_bucket
 from dmlp_tpu.utils.compat import shard_map
 
@@ -200,12 +201,17 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # -- resident device state -------------------------------------------
         self._csh = NamedSharding(self.mesh, P(DATA_AXIS, None))
         self._ssh = NamedSharding(self.mesh, P(None, DATA_AXIS, None))
+        self._nsh = NamedSharding(self.mesh, P(None, None, DATA_AXIS))
         self._lsh = NamedSharding(self.mesh, P(DATA_AXIS))
         self._qsh = NamedSharding(self.mesh, P(QUERY_AXIS, None))
         self._rsh = NamedSharding(self.mesh, P())
         self._lab_dev = jax.device_put(
             np.ascontiguousarray(self._host_labels), self._rsh)
         self._chunks: Optional[jax.Array] = None   # the (T, R*cr, A) stack
+        # its rows' squared norms (T, 1, R*cr), sharded like it, and the
+        # chunks whose norms were (re)written since start
+        self._norms: Optional[jax.Array] = None
+        self.norm_restages = 0
         self._live_dense = None        # (R, T) all-ones live mask
         self._mono = None              # (attrs, labels, ids) when staged
         if self._extract_ok:
@@ -286,7 +292,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
     def _stage_chunks(self) -> None:
         r, _ = self.mesh.devices.shape
         with obs_span("fleet.stage_resident", chunks=self._nchunks,
-                      mesh=list(self.mesh.devices.shape)):
+                      mesh=list(self.mesh.devices.shape),
+                      norm_bytes=self._nchunks * r * self._chunk_rows * 4):
             # Allocated on the devices, then filled a chunk at a time by
             # a donated update (ResidentEngine._ensure_chunks' way): a
             # stack of separately staged chunks would hold the corpus
@@ -294,17 +301,23 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             self._chunks = jnp.zeros(
                 (self._nchunks, r * self._chunk_rows, self.num_attrs),
                 _np_staging_dtype(self._staging), device=self._ssh)
+            self._norms = jnp.zeros(
+                (self._nchunks, 1, r * self._chunk_rows), jnp.float32,
+                device=self._nsh)
             for t in range(self._nchunks):
                 self._restage_chunk(t)
         self._live_dense = jax.device_put(
             np.ones((r, self._nchunks), np.int32), self._csh)
 
     def _restage_chunk(self, t: int) -> None:
-        """Write chunk ``t``'s current host rows into the stack, in
-        place: same shapes before and after, so no solve recompiles."""
-        self._chunks = _update_chunk(
-            self._chunks, jax.device_put(self._chunk_host(t), self._csh),
+        """Write chunk ``t``'s current host rows into the stack, and
+        their norms beside it, in place: same shapes before and after,
+        so no solve recompiles."""
+        self._chunks, self._norms = _update_chunk(
+            self._chunks, self._norms,
+            jax.device_put(self._chunk_host(t), self._csh),
             jax.device_put(np.int32(t), self._rsh))
+        self.norm_restages += 1
 
     def _ensure_monolithic(self) -> None:
         """The streaming paths' resident layout: full capacity-padded
@@ -492,13 +505,13 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         if key not in self._fns:
             cr, sr = self._chunk_rows, self._shard_rows
 
-            def local(q_attrs, stack, order, nfold, n, live):
+            def local(q_attrs, stack, norms, order, nfold, n, live):
                 def span(t):
                     id_base, n_real = _chunk_span((n, t * cr, sr), cr)
                     return id_base, jnp.where(live[0, t] > 0, n_real, 0)
 
                 od, oi, gated, iters = fold_chunks(
-                    q_attrs, stack, order, nfold, span, **kern)
+                    q_attrs, stack, norms, order, nfold, span, **kern)
                 # Per cell: gated tiles and summed kernel iterations,
                 # (R, C) after shard_map, read back once a batch.
                 return (od[None], oi[None], gated[None, None],
@@ -507,7 +520,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             sharded = shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(QUERY_AXIS, None), P(None, DATA_AXIS, None),
-                          P(), P(), P(), P(DATA_AXIS, None)),
+                          P(None, None, DATA_AXIS), P(), P(), P(),
+                          P(DATA_AXIS, None)),
                 out_specs=(P(DATA_AXIS, QUERY_AXIS, None),
                            P(DATA_AXIS, QUERY_AXIS, None),
                            P(DATA_AXIS, QUERY_AXIS),
@@ -515,8 +529,10 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                 check_vma=False)
 
             # Named for the device trace, like the merge.
-            def dmlp_mesh_fold(q_attrs, stack, order, nfold, n, live):
-                return sharded(q_attrs, stack, order, nfold, n, live)
+            def dmlp_mesh_fold(q_attrs, stack, norms, order, nfold, n,
+                               live):
+                return sharded(q_attrs, stack, norms, order, nfold, n,
+                               live)
 
             self._fns[key] = jax.jit(dmlp_mesh_fold)
         return self._fns[key]
@@ -533,6 +549,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         with obs_span("fleet.stage_queries", qpad=entry.qpad,
                       **self._rid_args()):
             impl = self._extract_impl("extract", entry.qloc, cr, na, k)
+            self.last_variant = {**self.last_variant, "norms": "staged"}
             prec = self._active_prec()  # resolved outside the jits (R2)
             kern = _kernel_statics(impl, k, cr, entry.qloc, na, prec,
                                    self._interpret)
@@ -557,7 +574,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         clock = time.perf_counter
         with obs_span("fleet.solve_resident", qpad=entry.qpad, kcap=k,
                       scheduled=len(order), impl=impl, mesh=[r, c],
-                      carry=self.gate_carry, **self._rid_args()) as sp:
+                      carry=self.gate_carry,
+                      **_variant_args(self.last_variant),
+                      **self._rid_args()) as sp:
             t0 = clock()
             padded = np.zeros(self._nchunks, np.int32)
             padded[:len(order)] = order
@@ -565,7 +584,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             # it was pruned: the scorer usually prunes none)
             mask = self._live_dense if live.sum() == np.count_nonzero(rows) \
                 else jax.device_put(live.astype(np.int32), self._csh)
-            args = (q_dev, self._chunks,
+            args = (q_dev, self._chunks, self._norms,
                     *jax.device_put((padded, np.int32(len(order)),
                                      np.int32(self.n_real)), self._rsh),
                     mask)
@@ -913,6 +932,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                                else 0),
             "summary_blocks": (r * self._nchunks if self._summ else 0),
             "summary_rebuilds": self.summary_rebuilds,
+            "norm_restages": self.norm_restages,
             "last_prune_fraction": self.last_prune_fraction,
             "last_prune": dict(lp) if isinstance(lp, dict) else None,
             "precision_plan": self._precision_plan,

@@ -331,6 +331,9 @@ def serve_engine_model(capacity_rows: int, na: int,
     }
     if extract_chunks:
         terms["extract_chunks"] = extract_chunks * chunk_rows * ca * item
+        # the stack's rows' squared norms, staged beside it: one dense
+        # float32 row a chunk (serve.engine._update_chunk)
+        terms["chunk_norms"] = extract_chunks * chunk_rows * 4
     if summary_blocks:
         # Device-resident block summaries of the pruned two-stage
         # solve (ops.summaries.stage_summaries): two (B, A) f32 boxes
@@ -374,6 +377,7 @@ def fleet_engine_model(mesh_shape, shard_rows: int, na: int,
     }
     if chunks:
         terms["resident_chunks"] = chunks * chunk_rows * na * item
+        terms["chunk_norms"] = chunks * chunk_rows * 4
     if monolithic:
         terms["monolithic_shard"] = shard_rows * na * item
         terms["labels_ids_shard"] = shard_rows * 8

@@ -130,16 +130,22 @@ def lane_padded(a: int) -> int:
     kernel's time is the same at every staged width: below). What they
     buy is the layout: XLA keeps an array whose minor axis is not whole
     lanes rows-minor, and the fold then copies every chunk (the slice,
-    then a relayout for the kernel) where the padded stack is copied
-    once, by the pass that computes the row norms.
+    then a relayout for the kernel), or the whole stack, where the
+    kernel reads the padded stack's blocks in place (since PR 41, by a
+    prefetched chunk index: extract_topk's stack form; the compiler's
+    answer at 100 wide is the same whole-stack re-layout with the
+    direct read as without it: tests/test_tpu_aot.py).
 
     Above one lane vector the rule is PR 31's (960 against 1024:
     tuned_variant). Below it, measured on the chip (PR 40, TPU v5
     lite; ``_fold_stack`` alone, q1024, kc 120, 196 of 328 chunks of
     51 200 rows, bfloat16; device time a fold from the profiler, five
-    folds; PERF.md section 6), the stack as it is against the same
-    values zero-padded to 128 (kernel 99.28 ms, everything beside it
-    5.0, stack 4.30 GB, no temporary):
+    folds; PERF.md section 6; measured WITH the pass in place that
+    computed the row norms and copied the chunk out of the stack a
+    kernel call, which PR 41 took out of every row of this table
+    alike: "norms" and ~3.9 of "beside it"), the stack as it is
+    against the same values zero-padded to 128 (kernel 99.28 ms,
+    everything beside it 5.0, stack 4.30 GB, no temporary):
 
     - 100 wide: the compiler keeps the stack attribute-major and
       re-lays-out ALL of it every fold into a temporary the size of
@@ -627,10 +633,23 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
     del nj
 
 
+def row_norms(d: jax.Array) -> jax.Array:
+    """Squared L2 norms of the rows of ``d`` (..., A) as the kernel's
+    distance expansion reads them: float32, of the STAGED values (under
+    bfloat16 staging the norm of the bf16 row, which is what the cross
+    term sees; never the host's float64 norm). The ONE expression: a
+    resident engine stages these beside its stack with each chunk
+    (serve.engine._update_chunk) and extract_topk computes them for a
+    caller that hands none in."""
+    d32 = d.astype(jnp.float32)
+    return jnp.sum(d32 * d32, axis=-1)
+
+
 def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
                  carry_d: jax.Array | None = None,
                  carry_i: jax.Array | None = None, *, n_real,
-                 id_base=0, kc: int, interpret: bool = False,
+                 id_base=0, chunk=None, d_norms: jax.Array | None = None,
+                 kc: int, interpret: bool = False,
                  tile_q: int | None = None, tile_n: int | None = None,
                  ne: int | None = None, unroll: int | None = None,
                  block_skip: bool = True, mxu_gate: bool = False,
@@ -639,6 +658,26 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     unsorted, ids (Qb, kc) i32, iters (Qb/tq, B/tn) i32 loop counts; 0 =
     the threshold prefilter skipped that block).
     Rows >= n_real are sentinels; data row j has global id id_base + j.
+
+    The data comes in one of two forms, told apart by its rank. A
+    (B, A) block, as a caller that stages a chunk a call holds it (the
+    batch engines, the tune sweep). Or a RESIDENT (nchunks, B, A) stack
+    with ``chunk``, a traced index into it (the resident engines'
+    fold): the index rides with ``n_real`` and ``id_base`` in the
+    grid's scalar prefetch and the data BlockSpec's index map reads it,
+    so the kernel's DMA fetches its (tile_n, A) blocks straight out of
+    the stack and the program holds no copy of the chunk (``stack[c]``
+    cost one, every chunk of every fold: PERF.md section 6, PR 41). A
+    block is the stack form with one chunk and index 0: one
+    pallas_call either way.
+    ``d_norms`` are the data rows' squared norms (:func:`row_norms` of
+    the staged values): (B,) beside a block, (nchunks, 1, B) beside a
+    stack, read by the same chunk index. (The unit axis keeps a chunk's
+    norms one dense row on the chip, ``T(1,128)``, 4 B a row: as
+    (nchunks, B) eight chunks share a tile and Mosaic cannot cut the
+    kernel's (1, tile_n) block out of it.) A resident engine stages
+    them once with each chunk; without them they are computed here
+    from the chunk, a pass over it a call.
     Optional carry (prior running lists, e.g. from a previous chunk) is
     folded in; without it slots pad (+inf, -1). Optional ``floor``
     ((Qb, 1) f32): per-row distance floor — candidates with
@@ -680,7 +719,11 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     Gate on supports() first. Output lists are NOT sorted; callers sort by
     the composite key (ops.topk.select_topk) if order matters.
     """
-    v = _resolve_variant(kc, d_attrs.shape[0], q_attrs.shape[0],
+    if (d_attrs.ndim == 3) != (chunk is not None):
+        raise ValueError("a (nchunks, B, A) stack takes a chunk index, a "
+                         f"(B, A) block none (got {d_attrs.shape}, "
+                         f"chunk={chunk!r})")
+    v = _resolve_variant(kc, d_attrs.shape[-2], q_attrs.shape[0],
                          q_attrs.shape[1], precision)
     # Eager callers pass plain ints for the traced SMEM scalars; under
     # the sanitizer's transfer guard the jit argument conversion would
@@ -692,12 +735,15 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         n_real = jax.device_put(_onp.int32(n_real))
     if isinstance(id_base, (int, _onp.integer)):
         id_base = jax.device_put(_onp.int32(id_base))
+    if isinstance(chunk, (int, _onp.integer)):
+        chunk = jax.device_put(_onp.int32(chunk))
     if precision not in PRECISIONS:
         raise ValueError(f"unsupported first-pass precision {precision!r} "
                          "(int8 is the gated follow-on — see ROADMAP)")
     return _extract_topk_jit(
         q_attrs, d_attrs, carry_d, carry_i, n_real=n_real,
-        id_base=id_base, kc=kc, interpret=interpret,
+        id_base=id_base, chunk=chunk, d_norms=d_norms, kc=kc,
+        interpret=interpret,
         tile_q=v["tile_q"] if tile_q is None else tile_q,
         tile_n=v.get("tile_n", _TN) if tile_n is None else tile_n,
         ne=v["ne"] if ne is None else ne,
@@ -713,9 +759,10 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
 def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
                       id_base, kc: int, interpret: bool, tile_q: int,
                       tile_n: int, ne: int, unroll: int, block_skip: bool,
-                      mxu_gate: bool, floor, precision: str = "f32"):
+                      mxu_gate: bool, floor, precision: str = "f32",
+                      chunk=None, d_norms=None):
     qb, a = q_attrs.shape
-    b = d_attrs.shape[0]
+    b = d_attrs.shape[-2]
     tq = _tile(qb, tile_q, 8)
     tn = _tile(b, tile_n, 128 * ne)
     # Validate the ACTUAL tiling (supports() only covers the defaults):
@@ -728,20 +775,35 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
         raise ValueError(
             f"untileable (qb={qb}, b={b}, kc={kc}, tq={tq}, tn={tn}, ne={ne})")
 
+    # One form inside: a (nchunks, B, A) stack, its (nchunks, 1, B)
+    # norms and a chunk index. A (B, A) block is a stack of one, index 0.
+    stack, norms = d_attrs, d_norms
+    if stack.ndim == 2:
+        stack, chunk = stack[None], 0
+    if norms is not None:
+        norms = norms.reshape(stack.shape[0], 1, b)
     # Rows staged in bfloat16 reach the kernel AS bfloat16 where the
     # queries hold bfloat16 values too (both operands arrive bfloat16:
     # the engines stage both through one dtype): half the block's DMA,
     # one MXU pass (_dot_cross), no float32 copy of the chunk. The
     # query block is small and resident across the data axis: it stays
     # float32 in HBM (lossless) and is cast back a visit, which spares
-    # short query tiles bfloat16's 16-sublane tile. Any other pair of
-    # dtypes is converted, as before.
+    # short query tiles bfloat16's 16-sublane tile. Float32 rows are
+    # read as they are. Any other pair of dtypes is converted, as
+    # before.
     q32 = q_attrs.astype(jnp.float32)
-    d32 = d_attrs.astype(jnp.float32)
     qn = jnp.sum(q32 * q32, axis=-1, keepdims=True)
-    dn = jnp.sum(d32 * d32, axis=-1)[None, :]
-    d_in = d_attrs if q_attrs.dtype == d_attrs.dtype == jnp.bfloat16 \
-        else d32
+    convert = stack.dtype != jnp.float32 \
+        and not q_attrs.dtype == stack.dtype == jnp.bfloat16
+    if convert or norms is None:
+        # Nothing staged to read in place (no norms handed in, or a
+        # pair of dtypes no engine stages): the chunk alone is taken
+        # out of the stack, a stack of one again, never the stack.
+        blk = stack[chunk]
+        norms = row_norms(blk) if norms is None else norms[chunk, 0]
+        if convert:
+            blk = blk.astype(jnp.float32)
+        stack, norms, chunk = blk[None], norms[None, None], 0
 
     fresh = carry_d is None
     if fresh:
@@ -750,7 +812,9 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     if floor is None:
         floor = jnp.full((qb, 1), -jnp.inf, jnp.float32)
 
-    scalars = jnp.asarray([[n_real, id_base]], jnp.int32)     # (1, 2) SMEM
+    # The grid's scalar prefetch (SMEM): what the kernel reads (n_real,
+    # id_base) and what the data's and the norms' index maps read (chunk).
+    scalars = jnp.asarray([[n_real, id_base, chunk]], jnp.int32)
     grid = (qb // tq, b // tn)
     kern = functools.partial(_kernel, kc=kc, fresh=fresh, ne=ne,
                              unroll=unroll, block_skip=block_skip,
@@ -764,35 +828,38 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     out_d, out_i, out_iters = pl.pallas_call(
         kern,
         name=name,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((tq, a), lambda i, j: (i, 0)),
-            pl.BlockSpec((tn, a), lambda i, j: (j, 0)),
-            pl.BlockSpec((tq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tn), lambda i, j: (0, j)),
-            pl.BlockSpec((tq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, kc), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, kc), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tq, kc), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, kc), lambda i, j: (i, 0)),
-            # One iters block per query tile (row 0 carries the counts)
-            # keeps dim 0 safely "parallel" — a single shared block would
-            # be clobbered across megacore cores.
-            pl.BlockSpec((tq, b // tn), lambda i, j: (i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((tq, a), lambda i, j, sc: (i, 0)),
+                pl.BlockSpec((None, tn, a),
+                             lambda i, j, sc: (sc[0, 2], j, 0)),
+                pl.BlockSpec((tq, 1), lambda i, j, sc: (i, 0)),
+                pl.BlockSpec((None, 1, tn),
+                             lambda i, j, sc: (sc[0, 2], 0, j)),
+                pl.BlockSpec((tq, 1), lambda i, j, sc: (i, 0)),
+                pl.BlockSpec((tq, kc), lambda i, j, sc: (i, 0)),
+                pl.BlockSpec((tq, kc), lambda i, j, sc: (i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((tq, kc), lambda i, j, sc: (i, 0)),
+                pl.BlockSpec((tq, kc), lambda i, j, sc: (i, 0)),
+                # One iters block per query tile (row 0 carries the
+                # counts) keeps dim 0 safely "parallel" — a single shared
+                # block would be clobbered across megacore cores.
+                pl.BlockSpec((tq, b // tn), lambda i, j, sc: (i, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((tq, tn), jnp.float32)],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((qb, kc), jnp.float32),
             jax.ShapeDtypeStruct((qb, kc), jnp.int32),
             jax.ShapeDtypeStruct((qb, b // tn), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((tq, tn), jnp.float32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=96 * 2**20),
         interpret=interpret,
-    )(scalars, q32, d_in, qn, dn, floor, carry_d, carry_i)
+    )(scalars, q32, stack, qn, norms, floor, carry_d, carry_i)
     return out_d, out_i, out_iters[::tq]

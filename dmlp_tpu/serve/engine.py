@@ -32,9 +32,14 @@ inherited unchanged:
   dispatches it once and first blocks in the readback
   (``single.fetch``); there is no per-chunk loop, no eager gate
   counter and no ``ChunkThrottle`` (which bounds STAGED chunks in
-  flight; nothing here is staged). Indexing the stack costs a copy of
-  the chunk on the device, made by the pass that computes its row
-  norms (``%multiply_reduce_fusion``'s second output).
+  flight; nothing here is staged). The fold reads, it does not
+  derive: the kernel's DMA fetches its blocks straight out of the stack
+  by a prefetched chunk index, and the rows' squared norms are resident
+  beside the stack (nchunks, 1, chunk_rows), written with each chunk by
+  the same donated update (``_update_chunk``), so a fold's device loop
+  holds the kernel and nothing else (until PR 41 a pass beside every
+  kernel call computed the chunk's norms and copied the chunk out of
+  the stack, ``%multiply_reduce_fusion``: 3 ms a batch at 82 chunks).
   :attr:`compile_count` counts bucket builds — a replay whose buckets
   were all warmed must leave it unchanged, the serving layer's
   no-per-request-recompilation proof.
@@ -134,9 +139,18 @@ def _update_rows_1d(buf, blk, start):
     return jax.lax.dynamic_update_slice(buf, blk, (start,))
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _update_chunk(stack, blk, c):
-    return jax.lax.dynamic_update_index_in_dim(stack, blk, c, 0)
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _update_chunk(stack, norms, blk, c):
+    """Chunk ``c`` of the resident ``stack`` written in place and, in
+    the same program, its rows' squared norms into ``norms`` (nchunks,
+    1, chunk_rows): computed on the device from the STAGED values by the
+    kernel wrapper's own expression (``row_norms``), so staging, an
+    ingest and the consistency repair's re-ingest keep rows and norms
+    in step by construction. Both buffers are donated."""
+    from dmlp_tpu.ops.pallas_extract import row_norms
+    return (jax.lax.dynamic_update_index_in_dim(stack, blk, c, 0),
+            jax.lax.dynamic_update_index_in_dim(
+                norms, row_norms(blk)[None], c, 0))
 
 
 #: what picks the compiled kernel: every one is part of the two
@@ -161,14 +175,18 @@ def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
                 precision=precision)
 
 
-def fold_chunks(q, stack, order, nfold, span, **kern):
+def fold_chunks(q, stack, norms, order, nfold, span, **kern):
     """The resident fold's ONE body, traced by both resident engines
     (``_fold_stack`` here as a plain jit; the mesh engine per shard,
     under ``shard_map``): fold ``nfold`` chunks of the resident
     ``stack`` (nchunks, chunk_rows, A), in the order ``order[:nfold]``
     gives, into one running top-k: the kernel once a chunk, the first
     with no carry (the ``_fresh`` form), the rest carried in a device
-    loop. ``span(c)`` gives chunk ``c``'s (id_base, n_real) as traced
+    loop. The kernel reads chunk ``c``'s blocks out of ``stack`` and
+    its rows' staged squared norms out of ``norms`` (nchunks, 1,
+    chunk_rows) by the index itself (``extract_topk``'s stack form):
+    nothing is sliced, copied or recomputed a chunk. ``span(c)`` gives
+    chunk ``c``'s (id_base, n_real) as traced
     values: the one thing the two engines derive differently.
     Returns (dists, ids, gated, iters): ``gated`` counts the (query
     tile, data block) pairs either gate elided (0 recorded
@@ -177,8 +195,9 @@ def fold_chunks(q, stack, order, nfold, span, **kern):
 
     def fold(c, od, oi):
         id_base, n_real = span(c)
-        return extract_topk(q, stack[c], od, oi, n_real=n_real,
-                            id_base=id_base, **kern)
+        return extract_topk(q, stack, od, oi, n_real=n_real,
+                            id_base=id_base, chunk=c, d_norms=norms,
+                            **kern)
 
     od, oi, its = fold(order[0], None, None)
 
@@ -200,7 +219,7 @@ def fold_tiles(kern: Dict[str, Any], qb: int, cr: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
-def _fold_stack(q, stack, order, nfold, n_real, **kern):
+def _fold_stack(q, stack, norms, order, nfold, n_real, **kern):
     """``fold_chunks`` over one chip's stack: chunk ``c`` holds rows
     ``c * chunk_rows`` on, real up to ``n_real``. ``order`` (padded to
     a fixed length), ``nfold`` and ``n_real`` are device data, so a new
@@ -212,32 +231,38 @@ def _fold_stack(q, stack, order, nfold, n_real, **kern):
         lo = c * cr
         return lo, jnp.minimum(n_real - lo, cr)
 
-    od, oi, gated, _iters = fold_chunks(q, stack, order, nfold, span,
-                                        **kern)
+    od, oi, gated, _iters = fold_chunks(q, stack, norms, order, nfold,
+                                        span, **kern)
     return od, oi, gated
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
-def _sweep_stack(q, stack, n_real, floor, **kern):
+def _sweep_stack(q, stack, norms, n_real, floor, **kern):
     """One kernel call over the whole ``stack`` as one (nchunks *
-    chunk_rows, A) array (a multipass re-sweep above ``floor``). The
-    reshape is free inside the program; an eager one would copy the
-    corpus."""
+    chunk_rows, A) array, its staged ``norms`` as one row beside it (a
+    multipass re-sweep above ``floor``). The reshapes are free inside
+    the program; an eager one would copy the corpus."""
     from dmlp_tpu.ops.pallas_extract import extract_topk
     return extract_topk(q, stack.reshape(-1, stack.shape[-1]),
-                        n_real=n_real, id_base=0, floor=floor, **kern)
+                        n_real=n_real, id_base=0, floor=floor,
+                        d_norms=norms.reshape(-1), **kern)
 
 
-def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, int]:
-    """What ``serve.solve_extract`` and ``serve.warmup_bucket`` say of a
-    solve's kernel variant ``v`` (``last_variant``'s form): its tiles,
-    where the engine pads it the width a staged row holds, and the MXU
-    passes its cross term takes a visit (1, 3 or 6)."""
+def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    """What ``serve.solve_extract``, ``serve.warmup_bucket`` and
+    ``fleet.solve_resident`` say of a solve's kernel variant ``v``
+    (``last_variant``'s form): its tiles, where the engine pads it the
+    width a staged row holds, the MXU passes its cross term takes a
+    visit (1, 3 or 6), and where the kernel's row norms came from:
+    ``norms`` "staged" (resident beside the stack, written with each
+    chunk: the resident folds) or "computed" (a pass over the chunk a
+    kernel call: a caller that holds nothing resident)."""
     from dmlp_tpu.ops.pallas_extract import _TN
     if not v:
         return {}
-    return {"tile_n": _TN, **{k: v[k] for k in (
-        "tile_q", "tile_n", "ne", "a_pad", "mxu_passes") if k in v}}
+    return {"tile_n": _TN, "norms": "computed", **{k: v[k] for k in (
+        "tile_q", "tile_n", "ne", "a_pad", "mxu_passes", "norms")
+        if k in v}}
 
 
 @dataclasses.dataclass(eq=False)
@@ -761,6 +786,11 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         from dmlp_tpu.ops.pallas_extract import lane_padded
         self._ex_attrs = lane_padded(na)
         self._chunks = None
+        # The stack's rows' squared norms, (nchunks, 1, chunk_rows) float32
+        # beside it, and the chunks whose norms were (re)written since
+        # start: every chunk once when the stack stages, one a restage.
+        self._norms = None
+        self.norm_restages = 0
         host_rows = max(self.capacity_rows, self._ex_rows)
 
         # -- host originals (float64 finalize rescore reads these) -----------
@@ -935,11 +965,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         cr = self._ex_chunk_rows
         with obs_span("serve.stage_chunks", chunks=self._ex_nchunks,
                       chunk_rows=cr, na=self.num_attrs,
-                      a_pad=self._ex_attrs, pad_bytes=self._pad_bytes()):
+                      a_pad=self._ex_attrs, pad_bytes=self._pad_bytes(),
+                      norm_bytes=self._ex_nchunks * cr * 4):
             # Allocated on the device, then filled a chunk at a time by
             # a donated update: the host never holds a second corpus.
             self._chunks = jnp.zeros((self._ex_nchunks, cr, self._ex_attrs),
                                      np_staging_dtype(self._staging))
+            self._norms = jnp.zeros((self._ex_nchunks, 1, cr), jnp.float32)
             for c in range(self._ex_nchunks):
                 self._restage_chunk(c)
         self._build_summaries()
@@ -999,9 +1031,10 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         a = np.zeros((cr, self._ex_attrs), sdt)
         if hi > lo:
             a[:hi - lo, :self.num_attrs] = self._host_attrs[lo:hi]
-        self._chunks = _update_chunk(
-            self._chunks, stage_put(a, self._staging),
+        self._chunks, self._norms = _update_chunk(
+            self._chunks, self._norms, stage_put(a, self._staging),
             jax.device_put(np.int32(c)))
+        self.norm_restages += 1
 
     # -- incremental ingestion ------------------------------------------------
 
@@ -1167,7 +1200,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         from dmlp_tpu.ops import pallas_fused
         return {**pallas_fused.variant_stamp(
             impl, kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec,
-            self._staging), "a_pad": self._ex_attrs}
+            self._staging), "a_pad": self._ex_attrs, "norms": "staged"}
 
     def _fold_resident(self, q_dev, order, impl: str, kc: int,
                        prec: str):
@@ -1183,7 +1216,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         padded = np.zeros(self._ex_nchunks, np.int32)
         padded[:len(order)] = order
         od, oi, gated = _fold_stack(
-            q_dev, self._chunks,
+            q_dev, self._chunks, self._norms,
             *jax.device_put((padded, np.int32(len(order)),
                              np.int32(self.n_real))), **kern)
         return od, oi, gated, len(order) * fold_tiles(kern, qpad, cr)
@@ -1355,7 +1388,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                                           **floor_args)
                 fds.append(fd)
                 od, oi, _its = _sweep_stack(q_dev, self._chunks,
-                                            n_dev, floor_dev, **sweep)
+                                            self._norms, n_dev, floor_dev,
+                                            **sweep)
             ods.append(od)
             ois.append(oi)
         with obs_span("serve.mp_merge", kcap=kcap,
@@ -1849,6 +1883,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             "repairs": self._repair_stats(),
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,
+            # chunks whose staged row norms were written since start:
+            # every chunk once when the stack stages, one a restage
+            "norm_restages": self.norm_restages,
             "last_prune_fraction": self.last_prune_fraction,
             "last_prune": dict(lp) if isinstance(lp, dict) else None,
             "precision_plan": self._precision_plan,

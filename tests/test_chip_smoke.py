@@ -195,7 +195,7 @@ _NARROW_OK = {
     "device": {"platform": "tpu", "pallas_interpret": False},
     "a_pad": 128,
     "kernel_data_operands": ["bf16[51200,128]", "bf16[51200,128]"],
-    "temp_bytes": 13107200, "chunk_bytes": 13107200, "sure_missing": 0,
+    "temp_bytes": 1052672, "chunk_bytes": 13107200, "sure_missing": 0,
     "ids_valid": True, "err_over_scale_vs_float64": 2e-7,
     "err_bound_over_scale": 7.3e-5}
 
@@ -207,8 +207,9 @@ _NARROW_OK = {
      "not the bfloat16 rows on 128 lanes"),
     ({"kernel_data_operands": ["f32[51200,128]", "bf16[51200,128]"]},
      "not the bfloat16 rows on 128 lanes"),
-    ({"temp_bytes": 4300468224}, "a copy of the stack is back"),
-    ({"temp_bytes": None}, "a copy of the stack is back"),
+    ({"temp_bytes": 4300468224}, "of the stack is back"),
+    ({"temp_bytes": 13107200}, "of the stack is back"),
+    ({"temp_bytes": None}, "of the stack is back"),
     ({"sure_missing": 3}, "3 of float64's nearest candidates"),
     ({"ids_valid": False}, "outside the corpus"),
     ({"err_over_scale_vs_float64": 1e-3}, "over the bound"),

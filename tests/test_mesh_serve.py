@@ -357,6 +357,21 @@ def test_the_mesh_spans_carry_what_the_metrics_read(traced):
         == sum(t.bytes_total for t in traced["comms"]) > 0
 
 
+def test_the_mesh_fold_says_its_norms_were_staged(traced):
+    """PR 41: ``fleet.solve_resident`` names the kernel's variant and
+    where its row norms came from; ``fleet.stage_resident`` what the
+    norm array weighs over the mesh; ``stats.engine`` the chunks whose
+    norms were written (each once: nothing was ingested)."""
+    fold = named(traced["served"], "fleet.solve_resident")[0]["args"]
+    assert fold["norms"] == "staged"
+    assert fold["tile_q"] >= 8 and fold["mxu_passes"] in (1, 3, 6)
+    stage = named(traced["all"], "fleet.stage_resident")[0]["args"]
+    eng = traced["stats"]["engine"]
+    assert eng["norm_restages"] == stage["chunks"]
+    assert stage["norm_bytes"] % (stage["chunks"] * 4 * 4) == 0
+    assert stage["norm_bytes"] >= eng["capacity_rows"] * 4
+
+
 @pytest.mark.parametrize("name", SETUP)
 def test_set_up_spans_are_emitted_once_and_outside_any_batch(traced, name):
     spans = named(traced["all"], name)
@@ -516,7 +531,7 @@ def mesh_fold_journey():
                             rng.integers(1, 7, nq).astype(np.int32))
             call = calls.pop()
             assert not calls and call["fn"] in fns
-            q_dev, _stack, order, nfold, n, live = call["args"]
+            q_dev, _stack, _norms, order, nfold, n, live = call["args"]
             order = [int(t) for t in np.asarray(order)[:int(nfold)]]
             live = np.asarray(live) > 0
             od, oi, gated, _iters = call["out"]

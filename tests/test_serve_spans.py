@@ -179,6 +179,22 @@ def test_span_args_say_what_the_work_was(traced):
     assert ev["serve.phase.respond"]["rid"] == "r-1"
 
 
+@pytest.mark.parametrize("name", ["serve.solve_extract",
+                                  "serve.warmup_bucket"])
+def test_a_resident_solve_says_its_norms_were_staged(traced, name):
+    """PR 41: the fold reads the rows' norms from beside the stack;
+    the spans that name the kernel's variant say so, set-up's staging
+    span says what the norm array weighs, and ``stats.engine`` counts
+    the chunks whose norms were written (each once: nothing was
+    ingested)."""
+    args = named(traced["all"], name)[-1]["args"]
+    assert args["norms"] == "staged"
+    assert args["tile_q"] >= 8 and args["mxu_passes"] in (1, 3, 6)
+    stage = named(traced["all"], "serve.stage_chunks")[0]["args"]
+    assert stage["norm_bytes"] == stage["chunks"] * stage["chunk_rows"] * 4
+    assert traced["stats"]["engine"]["norm_restages"] == stage["chunks"]
+
+
 @pytest.mark.parametrize("name", ["serve.init.host_copy",
                                   "serve.init.row_hashes",
                                   DN_MAX,

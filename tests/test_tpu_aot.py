@@ -104,6 +104,7 @@ def test_resident_fold_program_compiles_for_v5e(v5e, precision):
 
     compiled = _fold_stack.lower(
         spec((1024, 128), jnp.float32), spec((82, 51200, 128), jnp.float32),
+        spec((82, 1, 51200), jnp.float32),
         spec((82,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
         **_kernel_statics("fused", 32, 51200, 1024, 128, precision, False)
     ).compile()
@@ -114,11 +115,49 @@ def test_resident_fold_program_compiles_for_v5e(v5e, precision):
     assert sorted(c.rsplit(".", 1)[0] for c in calls) == [
         "%dmlp_topk_fused", "%dmlp_topk_fused_fresh"], calls
     assert " while(" in hlo
-    # the stack is an argument, not a constant, and nothing else of its
-    # size is allocated: the program holds no second corpus
+    # the stack is an argument, not a constant; the kernel reads its
+    # blocks out of it and its rows' norms out of the array beside it
+    # (16.8 MB: a dense row a chunk), so the program holds neither a
+    # second corpus nor a copy of a chunk
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes >= 82 * 51200 * 128 * 4
-    assert mem.temp_size_in_bytes < 4 * 51200 * 128 * 4
+    assert 82 * 51200 * (128 + 1) * 4 <= mem.argument_size_in_bytes \
+        < 82 * 51200 * (128 + 1) * 4 + 2 ** 20
+    _assert_fold_reads_the_stack(compiled, "f32", 82, 51200, 128)
+
+
+def _result_type(line: str) -> str:
+    """The result type of one HLO instruction line, a tuple's whole
+    ("(f32[51200]{0}, bf16[51200,128]{1,0}) fusion(...)" gives both)."""
+    rest = line.split(" = ", 1)[-1] if " = " in line else ""
+    end = rest.find(") ") + 1 if rest.startswith("(") else rest.find(" ")
+    return rest[:max(end, 0)]
+
+
+def _assert_fold_reads_the_stack(compiled, dtype: str, chunks: int,
+                                 rows: int, attrs: int):
+    """The resident fold reads, it does not derive (PR 41): both kernel
+    calls take the (chunks, rows, attrs) stack itself (operand 2) and
+    the staged (chunks, 1, rows) norms (operand 4), no instruction of
+    the program has a chunk's shape or a chunk's row count as its
+    result (the pass that computed the norms and copied the chunk out
+    of the stack, ``%multiply_reduce_fusion``, cannot come back
+    unseen), and beside its arguments the program allocates under a
+    quarter of one chunk."""
+    from dmlp_tpu.obs.hlo import kernel_operand_types
+    hlo = compiled.as_text()
+    calls = kernel_operand_types(hlo)
+    assert len(calls) == 2
+    for operands in calls:
+        assert len(operands) == 8, operands
+        assert operands[2] == f"{dtype}[{chunks},{rows},{attrs}]", operands
+        assert operands[4] == f"f32[{chunks},1,{rows}]", operands
+    for shape in (f"[{rows},{attrs}]", f"[{rows}]", f"[1,{rows}]"):
+        made = [line.strip()[:160] for line in hlo.splitlines()
+                if f"{shape}{{" in _result_type(line)]
+        assert made == [], made
+    itemsize = {"f32": 4, "bf16": 2}[dtype]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < rows * attrs * itemsize // 4
 
 
 def _kernel_calls(hlo: str):
@@ -139,24 +178,18 @@ def _materialized(hlo: str, shape: str):
     return found
 
 
-def _assert_bf16_rows_reach_the_kernel(compiled, chunk: str):
-    """The fold of a bfloat16 stack hands BOTH kernel calls the chunk
-    as bfloat16 (operand 2 of the custom call: the data BlockSpec's),
-    writes no float32 copy of it, and allocates at most one chunk of
-    bfloat16 beside its arguments."""
+def _assert_bf16_rows_reach_the_kernel(compiled, chunks: int, chunk: str):
+    """The fold of a bfloat16 stack hands BOTH kernel calls the rows
+    as bfloat16 (operand 2 of the custom call, the data BlockSpec's:
+    the stack itself), the queries as float32, writes no float32 copy
+    of a chunk, and allocates under a quarter of a chunk of bfloat16
+    beside its arguments."""
     from dmlp_tpu.obs.hlo import kernel_operand_types
-    hlo = compiled.as_text()
-    calls = kernel_operand_types(hlo)
-    assert len(calls) == 2
-    for operands in calls:
-        assert len(operands) == 8, operands
-        assert operands[2] == f"bf16[{chunk}]", operands
-        assert operands[1].startswith("f32["), operands
-    assert f"bf16[{chunk}]" in hlo
-    assert _materialized(hlo, f"f32[{chunk}]") == []
     rows, attrs = (int(x) for x in chunk.split(","))
-    assert compiled.memory_analysis().temp_size_in_bytes \
-        < 1.1 * rows * attrs * 2
+    _assert_fold_reads_the_stack(compiled, "bf16", chunks, rows, attrs)
+    for operands in kernel_operand_types(compiled.as_text()):
+        assert operands[1].startswith("f32["), operands
+    assert _materialized(compiled.as_text(), f"f32[{chunk}]") == []
 
 
 @pytest.mark.parametrize(
@@ -189,16 +222,17 @@ def test_retry_fold_program_compiles_for_v5e(v5e, staged, precision, chunks,
     assert statics["tile_q"] >= q           # one tile: all 16 rows
     compiled = _fold_stack.lower(
         spec((q, attrs), staged), spec((chunks, 51200, attrs), staged),
+        spec((chunks, 1, 51200), jnp.float32),
         spec((chunks,), jnp.int32), spec((), jnp.int32),
         spec((), jnp.int32), **statics).compile()
     hlo = compiled.as_text()
     assert len(_kernel_calls(hlo)) == 2
     assert " while(" in hlo
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 1.1 * 51200 * attrs * 4
     if staged == jnp.bfloat16:
-        _assert_bf16_rows_reach_the_kernel(compiled, f"51200,{attrs}")
+        _assert_bf16_rows_reach_the_kernel(compiled, chunks,
+                                           f"51200,{attrs}")
     else:
+        _assert_fold_reads_the_stack(compiled, "f32", chunks, 51200, attrs)
         assert _materialized(hlo, f"bf16[51200,{attrs}]") == []
 
 
@@ -208,7 +242,7 @@ def test_default_dtype_fold_program_compiles_for_v5e(v5e):
     read 16 times a chunk), over 328 resident chunks of 51 200 x 128
     bfloat16. The kernel streams the stack's rows as they are; a
     float32 query panel beside bfloat16 rows (no engine stages that)
-    keeps the converted chunk, the parent's program."""
+    converts the chunk, and only the chunk, to float32 first."""
     from dmlp_tpu.engine.single import resolve_kcap
     from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
     sh = SingleDeviceSharding(v5e[0])
@@ -227,7 +261,8 @@ def test_default_dtype_fold_program_compiles_for_v5e(v5e):
     def fold(q_dtype):
         return _fold_stack.lower(
             spec((1024, 128), q_dtype),
-            spec((328, 51200, 128), jnp.bfloat16), spec((328,), jnp.int32),
+            spec((328, 51200, 128), jnp.bfloat16),
+            spec((328, 1, 51200), jnp.float32), spec((328,), jnp.int32),
             spec((), jnp.int32), spec((), jnp.int32), **kern).compile()
 
     compiled = fold(jnp.bfloat16)
@@ -236,10 +271,10 @@ def test_default_dtype_fold_program_compiles_for_v5e(v5e):
                   for c in _kernel_calls(hlo)) == [
         "%dmlp_topk_fused", "%dmlp_topk_fused_fresh"]
     assert " while(" in hlo
-    _assert_bf16_rows_reach_the_kernel(compiled, "51200,128")
+    _assert_bf16_rows_reach_the_kernel(compiled, 328, "51200,128")
     assert compiled.memory_analysis().argument_size_in_bytes \
         >= 328 * 51200 * 128 * 2
-    assert _materialized(fold(jnp.float32).as_text(), "f32[51200,128]")
+    assert _materialized(fold(jnp.float32).as_text(), "f32[1,51200,128]")
 
 
 def _narrow_fold(v5e, q: int, kc: int, attrs: int):
@@ -255,7 +290,8 @@ def _narrow_fold(v5e, q: int, kc: int, attrs: int):
     kern = _kernel_statics("fused", kc, 51200, q, attrs, "f32", False)
     return _fold_stack.lower(
         spec((q, attrs), jnp.bfloat16),
-        spec((328, 51200, attrs), jnp.bfloat16), spec((328,), jnp.int32),
+        spec((328, 51200, attrs), jnp.bfloat16),
+        spec((328, 1, 51200), jnp.float32), spec((328,), jnp.int32),
         spec((), jnp.int32), spec((), jnp.int32), **kern).compile()
 
 
@@ -265,8 +301,8 @@ def test_narrow_row_fold_programs_hold_no_copy_of_the_stack(v5e, program):
     (q1024 at the 120-slot window bfloat16 staging plans from 100
     attributes) and the device retry (16 rows at 512 slots), over 328
     resident chunks of 51 200 rows at the width ``lane_padded(100)``
-    gives. The kernel is handed the staged bfloat16 chunk and beside
-    the 4.3 GB stack the program allocates less than one chunk: left
+    gives. The kernel is handed the staged bfloat16 stack and beside
+    its 4.3 GB the program allocates under a quarter of a chunk: left
     100 wide the compiler keeps the stack attribute-major and
     re-lays-out ALL of it every fold (the next test), and that copy
     must not come back unseen."""
@@ -285,10 +321,9 @@ def test_narrow_row_fold_programs_hold_no_copy_of_the_stack(v5e, program):
     compiled = _narrow_fold(v5e, q, kc, a)
     hlo = compiled.as_text()
     assert len(_kernel_calls(hlo)) == 2 and " while(" in hlo
-    _assert_bf16_rows_reach_the_kernel(compiled, f"51200,{a}")
+    _assert_bf16_rows_reach_the_kernel(compiled, 328, f"51200,{a}")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 328 * 51200 * a * 2
-    assert mem.temp_size_in_bytes < 51200 * a * 2 * 1.1
     assert not any(" copy(" in line for line in _materialized(
         hlo, f"bf16[328,51200,{a}]"))
 
@@ -344,8 +379,10 @@ def test_wide_row_fold_program_compiles_for_v5e(v5e, precision):
     ``resolve_kcap``). The data block follows the width (6 400 rows: the
     parent's 12 800 priced 106 MB of VMEM and the bucket fell to the
     streaming select), Mosaic takes it, and beside the 4.4 GB stack the
-    program allocates one chunk's copy, not the two (a slice, then a
-    relayout) it needs when the stack is left 960 wide."""
+    program allocates under a quarter of a chunk: the kernel's DMA
+    reads its (6 400, 1 024) blocks out of the stack itself (PR 41;
+    one chunk's copy until then, two when the stack was left 960
+    wide)."""
     from dmlp_tpu.ops.pallas_extract import lane_padded
     from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
     sh = SingleDeviceSharding(v5e[0])
@@ -362,6 +399,7 @@ def test_wide_row_fold_program_compiles_for_v5e(v5e, precision):
         == (1024, 128, 6400, 2)
     compiled = _fold_stack.lower(
         spec((1024, a), jnp.float32), spec((21, 51200, a), jnp.float32),
+        spec((21, 1, 51200), jnp.float32),
         spec((21,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
         **kern).compile()
     hlo = compiled.as_text()
@@ -373,7 +411,7 @@ def test_wide_row_fold_program_compiles_for_v5e(v5e, precision):
     assert " while(" in hlo
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 21 * 51200 * a * 4
-    assert mem.temp_size_in_bytes < 1.1 * 51200 * a * 4
+    _assert_fold_reads_the_stack(compiled, "f32", 21, 51200, a)
 
 
 @F32_FORMS
@@ -408,22 +446,30 @@ def test_wide_k_programs_compile_for_v5e(v5e, precision):
     assert impl == "fused" and fold == sweep
     assert (fold["tile_q"], fold["tile_n"], fold["ne"]) == (64, 12800, 4)
     stack = spec((82, 51200, 128), jnp.float32)
+    norms = spec((82, 1, 51200), jnp.float32)
     folded = _fold_stack.lower(
-        spec((1024, 128), jnp.float32), stack, spec((82,), jnp.int32),
-        spec((), jnp.int32), spec((), jnp.int32), **fold).compile()
+        spec((1024, 128), jnp.float32), stack, norms,
+        spec((82,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        **fold).compile()
     assert " while(" in folded.as_text()
+    _assert_fold_reads_the_stack(folded, "f32", 82, 51200, 128)
     swept = _sweep_stack.lower(
-        spec((1024, 128), jnp.float32), stack, spec((), jnp.int32),
+        spec((1024, 128), jnp.float32), stack, norms, spec((), jnp.int32),
         spec((1024, 1), jnp.float32), **sweep).compile()
     calls = [line.lstrip().removeprefix("ROOT ").split(" ", 1)[0]
              for line in swept.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert [c.rsplit(".", 1)[0] for c in calls] \
         == ["%dmlp_topk_fused_fresh"], calls
+    # the sweep reads the stack and the staged norms as one array each
+    # (free reshapes): no norm pass over the corpus, no temporary
+    assert not any("reduce" in line.split(" = ", 1)[0]
+                   for line in swept.as_text().splitlines()
+                   if f"f32[{rows}]" in line or f"f32[1,{rows}]" in line)
     for compiled in (folded, swept):
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes >= rows * 128 * 4
-        assert mem.temp_size_in_bytes < 2 * 51200 * 128 * 4
+        assert mem.temp_size_in_bytes < 51200 * 128 * 4 // 4
 
 
 @F32_FORMS
@@ -458,8 +504,8 @@ def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e, precision):
     A shard's 4.3 GB of the stack is an argument and nothing near a
     chunk's size is allocated beside it; no collective runs in the fold
     (the merge is its own program); and the donated chunk update that
-    builds and restages the stack aliases the whole of it, shard by
-    shard."""
+    builds and restages the stack, and writes the chunk's row norms
+    beside it, aliases the whole of both, shard by shard."""
     from dmlp_tpu.fleet.mesh_engine import MeshResidentEngine
     from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS
     from dmlp_tpu.serve.engine import _kernel_statics, _update_chunk
@@ -476,9 +522,10 @@ def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e, precision):
     eng.mesh, eng._fns = mesh, {}
     eng._chunk_rows, eng._shard_rows = cr, 2 ** 23
     stack = spec((t, 4 * cr, na), jnp.float32, None, DATA_AXIS, None)
+    norms = spec((t, 1, 4 * cr), jnp.float32, None, None, DATA_AXIS)
     compiled = eng._resident_fold_fn(
         _kernel_statics("fused", 32, cr, 1024, na, precision, False)).lower(
-        spec((1024, na), jnp.float32, QUERY_AXIS, None), stack,
+        spec((1024, na), jnp.float32, QUERY_AXIS, None), stack, norms,
         spec((t,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
         spec((4, t), jnp.int32, DATA_AXIS, None)).compile()
     hlo = compiled.as_text()
@@ -492,12 +539,18 @@ def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e, precision):
     assert "all-reduce" not in hlo and "all-gather" not in hlo
     mem = compiled.memory_analysis()          # of one device
     assert mem.argument_size_in_bytes >= t * cr * na * 4
-    assert mem.temp_size_in_bytes < 4 * cr * na * 4
+    # a shard's program: its (t, cr, na) of the stack, its (t, 1, cr)
+    # of the norms, nothing of a chunk's shape made, under a quarter of
+    # a chunk allocated
+    _assert_fold_reads_the_stack(compiled, "f32", t, cr, na)
     update = _update_chunk.lower(
-        stack, spec((4 * cr, na), jnp.float32, DATA_AXIS, None),
+        stack, norms, spec((4 * cr, na), jnp.float32, DATA_AXIS, None),
         spec((), jnp.int32)).compile()
-    assert update.memory_analysis().alias_size_in_bytes == t * cr * na * 4
-    assert update.output_shardings.spec == P(None, DATA_AXIS, None)
+    # stack and norms both written in place, each shard its own piece
+    assert update.memory_analysis().alias_size_in_bytes \
+        == t * cr * (na + 1) * 4
+    assert [s.spec for s in update.output_shardings] \
+        == [P(None, DATA_AXIS, None), P(None, None, DATA_AXIS)]
     assert "all-" not in update.as_text()
 
 
