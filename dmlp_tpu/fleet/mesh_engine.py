@@ -32,6 +32,15 @@ ways a persistent multi-chip server needs:
   host float64 finalize + boundary-hazard repair, so every served
   response is byte-identical to the solo solve over the same corpus AND
   the golden oracle.
+- **A micro-batch in two halves** (``ResidentServingCore``'s pair, the
+  one-chip engine's too). ``_first_half`` stages, scores and dispatches
+  the fold AND the merge (everything that only enqueues);
+  ``_second_half`` starts at the first host read: the fence
+  (``fleet.merge_drain``, ``fleet.merge``: what is left of each), the
+  fetch, the hazard test, the float64 finalize + repair, the gate
+  bookkeeping. The batcher begins batch N + 1 before it finishes batch
+  N, so the chips fold while the host finalizes; what a solve says of
+  itself lives in its :class:`MeshPendingBatch` until it finishes.
 - **Resident per-(shard, chunk) summaries.** The pruned two-stage
   solve's block summaries (PR 13) are built once over the shard-local
   chunk ranges and kept resident, replicated over the mesh; each
@@ -55,6 +64,7 @@ inside it). Both layouts share the one global-row-id contract.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -80,12 +90,13 @@ from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.comms import engine_comms
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.ops.pallas_extract import mxu_passes
+from dmlp_tpu.ops.topk import TopK
 from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS, make_mesh
 from dmlp_tpu.serve.engine import (_KERNEL_STATICS, CapacityError,
-                                   ResidentServingCore, _kernel_statics,
-                                   _update_chunk, _variant_args,
-                                   fold_chunks, fold_tiles, k_bucket,
-                                   query_bucket)
+                                   PendingBatch, ResidentServingCore,
+                                   _kernel_statics, _update_chunk,
+                                   _variant_args, fold_chunks, fold_tiles,
+                                   k_bucket, query_bucket)
 from dmlp_tpu.tune.cache import shape_bucket
 from dmlp_tpu.utils.compat import shard_map
 
@@ -106,6 +117,23 @@ class _MeshBucket:
     @property
     def key(self) -> str:
         return f"q{self.qpad}k{self.kb}"
+
+
+@dataclasses.dataclass(eq=False)
+class MeshPendingBatch(PendingBatch):
+    """What a mesh micro-batch's record holds beside the one-chip
+    engine's: the handles its second half fences and fetches, and the
+    parts of the engine's ``last_*`` report only a mesh solve has."""
+
+    # the fold's per-shard lists (cd, ci), still on the devices: what
+    # fleet.merge_drain waits for (None on the stream path)
+    lists: Optional[Tuple] = None
+    top: Optional[TopK] = None      # the merged lists, on the devices
+    # -> last_comms (obs.comms traffic of the merge)
+    comms: list = dataclasses.field(default_factory=list)
+    # MeasuredIters queues the fold's iteration sums here (flushed
+    # after the batch's fence, in fleet.after_batch)
+    _pending_iters: list = dataclasses.field(default_factory=list)
 
 
 class MeshResidentEngine(ResidentServingCore, ShardedEngine):
@@ -152,6 +180,10 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # the bf16 pass but never run it against f32-planned windows.
         self._precision_plan = cfg.resolve_precision(self._staging)
         self.last_precision = None
+        # (with last_phase_ms, last_comms, last_prune, last_variant and
+        # last_extract_impl: the report of the last batch FINISHED)
+        self._last_select = None
+        self.last_repairs = 0
         # Cross-request fused-gate warm-up, mesh edition (ROADMAP
         # follow-on (e)): the single-chip hot-block histogram doesn't
         # port 1:1 — here heat is tracked PER (shard, chunk), and the
@@ -163,7 +195,6 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # (boundary repair makes candidate-edge ties exact).
         self.gate_carry = bool(gate_carry)
         self.last_gated_fraction = None
-        self._pending_gate: Optional[Tuple] = None
 
         # -- per-shard chunk plan at CAPACITY shape (fixed for life) ---------
         self._extract_ok = (cfg.use_pallas and cfg.resolve_select(
@@ -384,7 +415,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         stats, or (None, None) for a dense fold. Sound per
         ops.summaries: a pruned block provably contributes nothing
         below the staging-eps margin, and the exact stage is
-        unchanged."""
+        unchanged. The one place ``begin_batch`` waits for the chips:
+        the scorer queues behind the fold of the batch in flight."""
         from dmlp_tpu.ops import summaries as osum
         if (self._summ_dev is None or not self.config.exact
                 or not osum.prune_enabled()
@@ -398,8 +430,6 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             return None, None   # belt: score_blocks keeps >= 1 block
         total = int(np.count_nonzero(self._summ.counts > 0))
         pruned = total - int(np.count_nonzero(keep))
-        self.last_prune_fraction = round(pruned / total, 6) if total \
-            else 0.0
         return keep.reshape(r, self._nchunks), {
             "blocks_total": total, "blocks_pruned": pruned}
 
@@ -537,20 +567,30 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             self._fns[key] = jax.jit(dmlp_mesh_fold)
         return self._fns[key]
 
-    def _solve_resident_chunks(self, inp: KNNInput, entry: _MeshBucket):
-        """The mesh-resident hot path: ONE program folds every scheduled
-        resident chunk on every shard (pruned pieces masked per the live
-        mask, chunks no shard needs left out of the order), then the
-        merge across the data axis. Python dispatches the fold once and
-        first blocks in ``fleet.merge_drain``."""
+    def _solve_resident_chunks(self, pend: MeshPendingBatch,
+                               entry: _MeshBucket) -> None:
+        """The mesh-resident hot path, its first half: ONE program folds
+        every scheduled resident chunk on every shard (pruned pieces
+        masked per the live mask, chunks no shard needs left out of the
+        order), and the merge across the data axis is dispatched behind
+        it. Python only enqueues both; the batch's second half first
+        blocks in ``fleet.merge_drain``."""
+        from dmlp_tpu.ops import pallas_fused
         from dmlp_tpu.ops.summaries import note_scan
+        inp = pend.inp
         r, c = self.mesh.devices.shape
         k, cr, na = entry.kcap, self._chunk_rows, self.num_attrs
+        prec = pend.prec                # resolved outside the jits (R2)
         with obs_span("fleet.stage_queries", qpad=entry.qpad,
                       **self._rid_args()):
-            impl = self._extract_impl("extract", entry.qloc, cr, na, k)
-            self.last_variant = {**self.last_variant, "norms": "staged"}
-            prec = self._active_prec()  # resolved outside the jits (R2)
+            # (the bucket took this path because a kernel tiles it)
+            impl = pallas_fused.resolve_topk_kernel(
+                entry.qloc, cr, na, k)[1] or "extract"
+            pend.select = "extract"
+            pend.extract_impl = impl
+            pend.variant = {**pallas_fused.variant_stamp(
+                impl, k, cr, entry.qloc, na, prec, self._staging),
+                "norms": "staged"}
             kern = _kernel_statics(impl, k, cr, entry.qloc, na, prec,
                                    self._interpret)
             q_dev = self._stage_queries(inp, entry.qpad)
@@ -570,12 +610,11 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             order = [t for t in self._chunk_order() if live[:, t].any()]
             sp.set(scheduled=len(order))
         item = np.dtype(self._np_dtype()).itemsize
-        self._last_select = "extract"
         clock = time.perf_counter
         with obs_span("fleet.solve_resident", qpad=entry.qpad, kcap=k,
                       scheduled=len(order), impl=impl, mesh=[r, c],
                       carry=self.gate_carry,
-                      **_variant_args(self.last_variant),
+                      **_variant_args(pend.variant),
                       **self._rid_args()) as sp:
             t0 = clock()
             padded = np.zeros(self._nchunks, np.int32)
@@ -590,59 +629,45 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                     mask)
             obs_counters.record_dispatch(fold, args, site="fleet.chunk_fold")
             cd, ci, gated, iters = fold(*args)
-            fold_ms = (clock() - t0) * 1e3
-            # One program folds every scheduled chunk: the host only
-            # enqueues it (serve.solve_extract's convention); the fold's
-            # device time shows in fleet.merge_drain.
-            sp.set(dispatches=1, chunks=len(order),
-                   kernel_dispatch_ms=round(fold_ms, 3),
-                   throttle_wait_ms=0.0)
-            mi = MeasuredIters(self, "fleet.chunk_fold",
-                               (entry.qloc, cr, na, k), kernel=impl)
-            mi.add(iters)
-            mi.done()
-            # Gate effectiveness: a 0-iteration tile was gated (or
-            # skip-gated) outright — counted a cell inside the program,
-            # read back once per micro-batch in _after_batch. The tile
-            # COUNT is static shape arithmetic, no transfer.
-            self._pending_gate = (
-                gated,
-                len(order) * r * c * fold_tiles(kern, entry.qloc, cr))
-            note_scan(self,
-                      scanned_bytes=int(rows[live].sum()) * na * item,
-                      dense_bytes=self.n_real * na * item,
-                      blocks_total=(prune_stats or {}).get(
-                          "blocks_total", int(np.count_nonzero(rows))),
-                      blocks_pruned=(prune_stats or {}).get(
-                          "blocks_pruned", 0))
-            self.last_comms = engine_comms(self._merge_strategy, (r, c),
-                                           entry.qpad // c, k)
+            t1 = clock()
+            # The merge program goes onto the devices' queues behind
+            # the fold (its dispatch, the collective, the re-select):
+            # nothing here waits for either.
             merge_fn = self._chunk_merge_fn(k)
             obs_counters.record_dispatch(merge_fn,
                                          (cd, ci, self._lab_dev),
                                          site="fleet.chunk_merge")
-        # The fold finishes here, so that fleet.merge times the merge
-        # program alone (its dispatch, the collective, the re-select)
-        # and not the fold.
-        # (blocked on whether or not a tracer is installed: the phase
-        # timings behind `stats` are the same numbers the spans carry.)
-        t_drain = clock()
-        with obs_span("fleet.merge_drain", dispatches=1,
-                      site="merge_drain", **self._rid_args()), \
-                obs_trace.device_wait("merge_drain", span=False):
-            jax.block_until_ready((cd, ci))  # check: allow-host-sync
-        merge_bytes = sum(t.bytes_total for t in self.last_comms)
-        telemetry.registry().counter("fleet.merge_bytes").inc(merge_bytes)
-        t_merge = clock()
-        with obs_span("fleet.merge", mesh=[r, c], kc=k,
-                      strategy=self._merge_strategy, bytes=merge_bytes,
-                      **self._rid_args()):
-            top = merge_fn(cd, ci, self._lab_dev)
-            with obs_trace.device_wait("merge", self.trace_batch):
-                jax.block_until_ready(top.dists)  # check: allow-host-sync
-        self.last_phase_ms["dispatch"] = fold_ms + (t_merge - t_drain) * 1e3
-        self.last_phase_ms["merge"] = (clock() - t_merge) * 1e3
-        return top
+            pend.lists = (cd, ci)
+            pend.top = merge_fn(cd, ci, self._lab_dev)
+            t2 = clock()
+            # One program folds every scheduled chunk: the host only
+            # enqueues it (serve.solve_extract's convention); the fold's
+            # device time shows where the host first blocks, in the
+            # second half's fleet.merge_drain.
+            sp.set(dispatches=1, chunks=len(order),
+                   kernel_dispatch_ms=round((t1 - t0) * 1e3, 3),
+                   merge_dispatch_ms=round((t2 - t1) * 1e3, 3),
+                   throttle_wait_ms=0.0)
+        pend.phase_ms["dispatch"] = (t2 - t0) * 1e3
+        mi = MeasuredIters(pend, "fleet.chunk_fold",
+                           (entry.qloc, cr, na, k), kernel=impl)
+        mi.add(iters)
+        mi.done()
+        # Gate effectiveness: a 0-iteration tile was gated (or
+        # skip-gated) outright — counted a cell inside the program,
+        # read back once per micro-batch in _after_batch. The tile
+        # COUNT is static shape arithmetic, no transfer.
+        pend.gate = (gated,
+                     len(order) * r * c * fold_tiles(kern, entry.qloc, cr))
+        note_scan(pend,
+                  scanned_bytes=int(rows[live].sum()) * na * item,
+                  dense_bytes=self.n_real * na * item,
+                  blocks_total=(prune_stats or {}).get(
+                      "blocks_total", int(np.count_nonzero(rows))),
+                  blocks_pruned=(prune_stats or {}).get(
+                      "blocks_pruned", 0))
+        pend.comms = engine_comms(self._merge_strategy, (r, c),
+                                  entry.qpad // c, k)
 
     def _chunk_order(self) -> List[int]:
         """Fold order over the resident chunks: step ``i`` of the
@@ -656,15 +681,16 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         heat = self._block_hits.sum(axis=0)
         return list(np.argsort(-heat[:self._nchunks], kind="stable"))
 
-    def _after_batch(self, results: List[QueryResult]) -> None:
+    def _after_batch(self, pend: MeshPendingBatch,
+                     results: List[QueryResult]) -> None:
         """Cross-request gate bookkeeping (the single-chip resident
-        engine's discipline, per-shard): flush the pending gated-tile
+        engine's discipline, per-shard): flush the batch's gated-tile
         readback, then credit each winner row's owning (shard, chunk)
         block in the carried histogram."""
         with obs_span("fleet.after_batch", **self._rid_args()) as sp:
-            flush_measured_iters(self)
-            gate, self._pending_gate = self._pending_gate, None
-            self._flush_gate(sp, gate)
+            flush_measured_iters(pend)
+            self._flush_gate(sp, pend.gate)
+            pend.gate = None
             if self.gate_carry and self._nchunks and results:
                 ids = np.concatenate(
                     [np.asarray(r.neighbor_ids, np.int64)
@@ -678,61 +704,102 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                                        minlength=r * self._nchunks)
                     self._block_hits += hits.reshape(r, self._nchunks)
 
-    def _solve_resident_stream(self, inp: KNNInput, entry: _MeshBucket):
-        """Streaming fallback on the resident MONOLITHIC arrays: the
-        engines' merged program (collective epilogue inside the jit)."""
+    def _solve_resident_stream(self, pend: MeshPendingBatch,
+                               entry: _MeshBucket) -> None:
+        """Streaming fallback on the resident MONOLITHIC arrays, its
+        first half: the engines' merged program (collective epilogue
+        inside the jit), enqueued."""
         from dmlp_tpu.ops.summaries import note_scan
+        inp = pend.inp
         self._ensure_monolithic()
         d_attrs, d_labels, d_ids = self._mono
         with obs_span("fleet.stage_queries", qpad=entry.qpad,
                       **self._rid_args()):
             q_dev = self._stage_queries(inp, entry.qpad)
+        # solve_global reports on the engine (the batch engines' way):
+        # what it says is this batch's, and the engine's own report
+        # stays the last batch FINISHED.
+        report = (self._last_select, self.last_extract_impl,
+                  self.last_variant, self._pending_iters)
+        self._pending_iters = []
         t0 = time.perf_counter()
-        with obs_span("fleet.solve_stream", qpad=entry.qpad,
-                      kcap=entry.kcap, **self._rid_args()):
-            top = self.solve_global(d_attrs, d_labels, d_ids, q_dev,
-                                    kmax=entry.kb)
-        self.last_phase_ms["dispatch"] = (time.perf_counter() - t0) * 1e3
+        try:
+            with obs_span("fleet.solve_stream", qpad=entry.qpad,
+                          kcap=entry.kcap, **self._rid_args()):
+                pend.top = self.solve_global(d_attrs, d_labels, d_ids,
+                                             q_dev, kmax=entry.kb)
+            pend.phase_ms["dispatch"] = (time.perf_counter() - t0) * 1e3
+            pend.select, pend.extract_impl, pend.variant = (
+                self._last_select, self.last_extract_impl,
+                self.last_variant)
+            pend._pending_iters = self._pending_iters
+        finally:
+            (self._last_select, self.last_extract_impl, self.last_variant,
+             self._pending_iters) = report
         dense = self.n_real * self.num_attrs \
             * np.dtype(self._np_dtype()).itemsize
-        note_scan(self, scanned_bytes=dense, dense_bytes=dense,
+        note_scan(pend, scanned_bytes=dense, dense_bytes=dense,
                   blocks_total=self.mesh.devices.shape[0],
                   blocks_pruned=0)
-        return top
 
-    # -- the serving entry ----------------------------------------------------
+    # -- a micro-batch's two halves (ResidentServingCore drives them) ---------
 
-    def solve_batch(self, query_attrs, ks) -> List[QueryResult]:
-        """One coalesced micro-batch end to end over the mesh: bucket,
-        fold the resident shards, merge across "data", fetch, float64
-        finalize + boundary repair. Results carry query ids 0..nq-1 in
-        batch order — the batcher slices per request."""
-        inp = self._batch_input(np.asarray(query_attrs, np.float64),
-                                np.asarray(ks, np.int32))
-        n = self.n_real
+    _pending_type = MeshPendingBatch
+
+    def _first_half(self, pend: MeshPendingBatch) -> None:
+        """Bucket, stage the queries, score and schedule, dispatch the
+        fold and the merge across "data" (or the merged stream
+        program). No resilience ladder here: a failure raises and the
+        batch fails alone."""
+        inp = pend.inp
         nq = inp.params.num_queries
-        kmax = int(inp.ks.max()) if nq else 1
-        self.last_phase_ms = {}
-        self.last_comms = []
-        self._pending_iters = []
-        self.last_extract_impl = self.last_variant = None
-        self.last_prune = None
-        self.last_prune_fraction = None
-        self._pending_gate = None
-        prec = self._active_prec()
-        self.last_precision = {
-            "active": prec, "configured": self._precision_plan,
-            "mxu_passes": mxu_passes(prec, self._staging)}
+        pend.prec = self._active_prec()
+        pend.precision = {
+            "active": pend.prec, "configured": self._precision_plan,
+            "mxu_passes": mxu_passes(pend.prec, self._staging)}
         memwatch.note_engine_model(self, inp)
-        entry = self._bucket_entry(nq, kmax)
-        if self._handed is not None:    # a slow cycle's record names it
-            self._handed.path = entry.path
+        entry = self._bucket_entry(nq, int(inp.ks.max()) if nq else 1)
         if entry.path == "extract":
-            top = self._solve_resident_chunks(inp, entry)
+            self._solve_resident_chunks(pend, entry)
         else:
-            top = self._solve_resident_stream(inp, entry)
-        self.last_repairs = 0
+            self._solve_resident_stream(pend, entry)
+
+    def _second_half(self, pend: MeshPendingBatch) -> List[QueryResult]:
+        """From the first host read on: the fence (what is left of the
+        fold, then of the merge), fetch, float64 finalize + boundary
+        repair, gate bookkeeping; then the engine's ``last_*`` report
+        becomes this batch's."""
+        inp, prec, top = pend.inp, pend.prec, pend.top
+        n = inp.params.num_data
+        nq = inp.params.num_queries
         clock = time.perf_counter
+        if pend.lists is not None:
+            # The fold finishes here, so that fleet.merge times what is
+            # left of the merge program alone (the collective, the
+            # re-select) and not the fold. With a batch begun behind
+            # this one the host arrives after the chips and both wait
+            # for nothing.
+            # (blocked on whether or not a tracer is installed: the
+            # phase timings behind `stats` are the same numbers the
+            # spans carry.)
+            t_drain = clock()
+            with obs_span("fleet.merge_drain", dispatches=1,
+                          site="merge_drain", **self._rid_args()), \
+                    obs_trace.device_wait("merge_drain", span=False):
+                jax.block_until_ready(pend.lists)  # check: allow-host-sync
+            merge_bytes = sum(t.bytes_total for t in pend.comms)
+            telemetry.registry().counter("fleet.merge_bytes").inc(
+                merge_bytes)
+            t_merge = clock()
+            with obs_span("fleet.merge",
+                          mesh=list(self.mesh.devices.shape),
+                          kc=int(top.dists.shape[1]),
+                          strategy=self._merge_strategy, bytes=merge_bytes,
+                          **self._rid_args()):
+                with obs_trace.device_wait("merge", self.trace_batch):
+                    jax.block_until_ready(top.dists)  # check: allow-host-sync
+            pend.phase_ms["dispatch"] += (t_merge - t_drain) * 1e3
+            pend.phase_ms["merge"] = (clock() - t_merge) * 1e3
         t0 = clock()
         with obs_span("fleet.fetch", site="fetch", **self._rid_args()):
             telemetry.sample_memory_now()
@@ -749,7 +816,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # float64 host corpus inside this span (a daemon's first batch).
         suspects = np.zeros(0, np.int64)
         with obs_span("fleet.hazard", rows=n, **self._rid_args()) as hz:
-            if self._last_select in ("sort", "topk", "seg", "extract") \
+            if pend.select in ("sort", "topk", "seg", "extract") \
                     and dists.shape[1] < n:
                 # Same per-shard-truncation hazard test as the batch
                 # mesh engines (engine.sharded._run): the merged kcap-th
@@ -762,7 +829,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                 eps = staging_eps(
                     np.asarray(dists[:, -1], np.float64), qn, dn_max,
                     self._staging, self.num_attrs)
-                if self._last_select == "extract":
+                if pend.select == "extract":
                     # A first pass that drops products ("bf16x3",
                     # "bf16") perturbs device distances beyond the
                     # staging model — widen the hazard test by the
@@ -782,16 +849,20 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                 with obs_span("fleet.repair", queries=int(suspects.size),
                               **self._rid_args()):
                     repair_boundary_overflow(results, suspects, inp)
-                self.last_repairs += int(suspects.size)
+                pend.repairs += int(suspects.size)
                 # every flagged query is the host oracle's here: the
                 # device retry is the one-chip engine's
                 self._note_flagged(int(suspects.size))
             sp.set(repairs=int(suspects.size))
         t3 = clock()
-        self.last_phase_ms["fetch"] = (t1 - t0) * 1e3
-        self.last_phase_ms["hazard"] = (t2 - t1) * 1e3
-        self.last_phase_ms["finalize"] = (t3 - t2) * 1e3
-        self._after_batch(results)
+        pend.phase_ms.update(fetch=(t1 - t0) * 1e3, hazard=(t2 - t1) * 1e3,
+                             finalize=(t3 - t2) * 1e3)
+        self._after_batch(pend, results)
+        self._report_finished(pend)
+        self.last_phase_ms = pend.phase_ms
+        self.last_comms = pend.comms
+        self.last_precision = pend.precision
+        self.last_repairs = pend.repairs
         return results
 
     # -- incremental shard-routed ingestion -----------------------------------

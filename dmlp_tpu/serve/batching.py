@@ -10,14 +10,15 @@ the golden oracle by the finalize/repair contract).
 
 A micro-batch is solved in two halves (``engine.begin_batch``: what
 only enqueues device work; ``engine.finish_batch``: the fence and the
-host's float64 finalize), and the batcher runs the first half of batch
-N + 1 BEFORE the second half of batch N whenever a batch's worth of
-queries is already waiting: the device folds N + 1 while the host
-finalizes N, and the host finalizes while the device folds. At most
-two batches are alive; a light load (less than a batch queued while
-one is in flight) runs them one after the other, as a serial batcher
-would, so small requests keep collecting company until their fold can
-start.
+host's float64 finalize; one pair for both resident engines,
+``serve.engine.ResidentServingCore``'s), and the batcher runs the first
+half of batch N + 1 BEFORE the second half of batch N whenever a
+batch's worth of queries is already waiting: the device folds N + 1
+while the host finalizes N, and the host finalizes while the device
+folds. At most two batches are alive; a light load (less than a batch
+queued while one is in flight) runs them one after the other, as a
+serial batcher would, so small requests keep collecting company until
+their fold can start.
 
 The batcher accounts for its own time. Its thread's timeline is cut at
 the end of every delivery into **cycles**, one a micro-batch, and each
@@ -50,7 +51,7 @@ from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.resilience import inject as rs_inject
 from dmlp_tpu.serve.admission import ACCEPT, AdmissionController
-from dmlp_tpu.serve.engine import ResidentEngine
+from dmlp_tpu.serve.engine import ResidentServingCore
 
 #: default batcher tick: how long a lone request waits for company
 TICK_S = 0.002
@@ -161,7 +162,7 @@ class MicroBatcher:
     collector pauses). ``gc_ms`` beside them is the collector's pause
     time, any thread's, that ended inside the cycle."""
 
-    def __init__(self, engine: ResidentEngine,
+    def __init__(self, engine: ResidentServingCore,
                  admission: AdmissionController,
                  max_batch_queries: int = 1024,
                  tick_s: float = TICK_S):

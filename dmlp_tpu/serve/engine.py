@@ -266,27 +266,13 @@ def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, Any]:
 
 
 @dataclasses.dataclass(eq=False)
-class HeldBatch:
-    """What :meth:`ResidentServingCore.begin_batch` keeps of a
-    micro-batch whose engine solves it whole in ``finish_batch``."""
-
-    query_attrs: np.ndarray
-    ks: np.ndarray
-    batch: Optional[int] = None     # the batcher's serial: on every span
-    rids: Optional[str] = None      # comma-joined trace request ids
-    #: begun while another batch was in flight (never: nothing is begun)
-    overlapped: bool = False
-    #: the bucket path its solve took, once finish_batch has run it
-    path: Optional[str] = None
-
-
-@dataclasses.dataclass(eq=False)
 class PendingBatch(PendingRun):
-    """One micro-batch of a :class:`ResidentEngine` between
-    ``begin_batch`` and ``finish_batch``: its work is on the device's
-    queue, nothing of it has been read back. Two are alive at once, so
-    everything a solve says of itself is written here and reaches the
-    engine's ``last_*`` fields when the batch finishes."""
+    """One micro-batch of a resident engine between ``begin_batch``
+    and ``finish_batch``: its work is on the device's queue, nothing of
+    it has been read back. Two are alive at once, so everything a solve
+    says of itself is written here and reaches the engine's ``last_*``
+    fields when the batch finishes. (The mesh engine's record adds what
+    only a mesh has: ``fleet.mesh_engine.MeshPendingBatch``.)"""
 
     batch: Optional[int] = None     # the batcher's serial: on every span
     rids: Optional[str] = None      # comma-joined trace request ids
@@ -360,13 +346,16 @@ class ResidentServingCore:
 
     The batcher drives an engine through :meth:`begin_batch` and
     :meth:`finish_batch` and may have begun one micro-batch behind the
-    one it finishes next. The pair here keeps the inputs and solves
-    whole in the second half (one batch in the engine at a time, as the
-    mesh engine needs); :class:`ResidentEngine` cuts its solve at the
-    fence and keeps two alive.
+    one it finishes next, so two are alive at once. The pair, the list
+    of batches in flight and ``solve_batch``'s handed-record logic are
+    here, once; an engine supplies the two halves themselves
+    (:meth:`_first_half`: what only enqueues; :meth:`_second_half`: the
+    fence and the host's share) and keeps what a solve says of itself
+    in the batch's :class:`PendingBatch`.
 
     Subclass contract: ``bucket_shape``/``_build_bucket``/``max_k``/
-    ``solve_batch`` plus the resident state the hooks read; the
+    ``_batch_input``/``_first_half``/``_second_half`` plus the resident
+    state the hooks read; the
     subclass implements :meth:`mem_model` (its analytic per-device
     model, batch terms included iff ``nq > 0``) and
     :meth:`batch_model_bytes` (the marginal per-batch terms — the
@@ -382,36 +371,116 @@ class ResidentServingCore:
     trace_batch: Optional[int] = None
     trace_rids: Optional[str] = None
 
-    #: micro-batches whose lists can be on the device at once: what
-    #: admission multiplies one batch's price by
-    batches_resident = 1
+    #: micro-batches whose lists can be on the device at once (the one
+    #: read back and the one begun behind it): what admission multiplies
+    #: one batch's price by
+    batches_resident = 2
+
+    #: the record a batch's state lives in (an engine may extend it)
+    _pending_type = PendingBatch
 
     #: the begun micro-batch finish_batch is handing to solve_batch
     _handed = None
 
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        # Micro-batches begun and not finished, oldest first (at most
+        # two: the batcher begins one behind the one it finishes next).
+        # The batcher thread's alone, like everything a solve touches.
+        self._in_flight: List[PendingBatch] = []
+
     # -- a micro-batch in two halves (the batcher drives these) --------------
 
     def begin_batch(self, query_attrs, ks, batch: Optional[int] = None,
-                    rids: Optional[str] = None):
-        """First half of a micro-batch: returns the record
-        :meth:`finish_batch` takes. The default pair keeps the inputs
-        and solves whole in the second half, so an engine that cannot
-        cut its solve (the mesh engine) runs through the same batcher,
-        one batch at a time."""
-        return HeldBatch(query_attrs, ks, batch, rids)
+                    rids: Optional[str] = None) -> PendingBatch:
+        """The first half of a micro-batch: stage its queries, choose
+        and prune the fold order, dispatch the bucket's program(s).
+        Everything that only ENQUEUES: the batcher calls this for batch
+        N + 1 while the device still folds batch N, and only then
+        finishes N. (It waits for the device in one place, the prune
+        scorer's readback.) ``batch`` / ``rids`` tag the batch's spans.
+        Returns the record :meth:`finish_batch` takes."""
+        inp = self._batch_input(np.asarray(query_attrs, np.float64),
+                                np.asarray(ks, np.int32))
+        self._check_k(inp)
+        pend = self._pending_type(inp, batch=batch, rids=rids,
+                                  overlapped=bool(self._in_flight))
+        with self._tagged(pend):
+            self._first_half(pend)
+        self._in_flight.append(pend)
+        return pend
 
-    def finish_batch(self, pending) -> List[QueryResult]:
+    def finish_batch(self, pending: PendingBatch) -> List[QueryResult]:
         """Second half: the batch's results, or what it failed with.
         It goes through :meth:`solve_batch`, the one call every served
         answer comes out of (whoever wraps that call sees every
-        micro-batch); an engine whose ``begin_batch`` put work on the
-        device finds its record there, in ``_handed``."""
+        micro-batch), which finds the begun record in ``_handed``."""
         self._handed = pending
         try:
             with self._tagged(pending):
                 return self.solve_batch(pending.query_attrs, pending.ks)
         finally:
             self._handed = None
+
+    def solve_batch(self, query_attrs, ks) -> List[QueryResult]:
+        """One coalesced micro-batch end to end: pad/bucket, solve on
+        the compiled bucket program(s), float64-finalize + repair,
+        update the cross-request gate state. Results carry query ids
+        0..nq-1 in batch order — the batcher slices per request.
+
+        Called alone (warm-up, the tests, every caller outside the
+        batcher) it runs the two halves back to back. Called by
+        ``finish_batch`` it is the second half of the batch handed to
+        it, whose first half ``begin_batch`` ran earlier: the fence,
+        the hazard test, the float64 finalize + repair, the gate
+        bookkeeping. It raises what the batch failed with; a batch
+        begun behind it is untouched. Same answers either way."""
+        pend, self._handed = self._handed, None
+        if pend is None:
+            pend = self.begin_batch(query_attrs, ks)
+        if pend in self._in_flight:
+            self._in_flight.remove(pend)
+        if pend.outcome is None:
+            pend.outcome = self._outcome(pend)
+        results, error = pend.outcome
+        if error is not None:
+            raise error
+        return results
+
+    def _outcome(self, pend: PendingBatch) -> Tuple:
+        """(results, None) or (None, the exception) of ``pend``'s second
+        half: a failure is that batch's alone."""
+        try:
+            with self._tagged(pend):
+                return self._second_half(pend), None
+        except Exception as e:  # check: no-retry — solve_batch raises it
+            return None, e
+
+    def _first_half(self, pend: PendingBatch) -> None:
+        """Enqueue ``pend``'s device work; block on nothing but the
+        prune scorer's mask."""
+        raise NotImplementedError
+
+    def _second_half(self, pend: PendingBatch) -> List[QueryResult]:
+        """``pend``'s fence and the host's share of it; writes the
+        engine's ``last_*`` report."""
+        raise NotImplementedError
+
+    def _report_finished(self, pend: PendingBatch) -> None:
+        """What `stats` reports of "the last solve" is the last batch
+        FINISHED, whole: each field one assignment, read lock-free."""
+        self._last_select = pend.select
+        self.last_extract_impl = pend.extract_impl
+        self.last_variant = pend.variant
+        self.last_prune = pend.last_prune
+        if pend.last_prune is not None:
+            self.last_prune_fraction = pend.last_prune["pruned_fraction"]
+
+    def _check_k(self, inp: KNNInput) -> None:
+        kmax = int(inp.ks.max()) if inp.params.num_queries else 0
+        if kmax > self.max_k:
+            raise RequestShapeError(
+                f"k={kmax} beyond the serving cap {self.max_k}")
 
     @contextlib.contextmanager
     def _tagged(self, pending):
@@ -733,8 +802,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     gate histogram, the ``last_*`` report of the last batch finished).
     """
 
-    batches_resident = 2
-
     def __init__(self, corpus: KNNInput, config: EngineConfig = None,
                  capacity: Optional[int] = None, gate_carry: bool = True):
         super().__init__(config or EngineConfig())
@@ -827,10 +894,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self.compile_count = 0
         self.cold_start_compile_ms: Optional[float] = None
         self.bucket_compile_ms: Dict[str, float] = {}
-        # Micro-batches begun and not finished, oldest first (at most
-        # two: the batcher begins one behind the one it finishes next).
-        # The batcher thread's alone, like everything a solve touches.
-        self._in_flight: List[PendingBatch] = []
         # the device retry's programs have compiled (_retry_begin)
         self._retry_built = False
         # Cross-request gate state: per-chunk winner histogram.
@@ -1659,16 +1722,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         results = super()._run_finish(pend)
         self._note_flagged(pend.repairs, pend.retry_cleared)
         self._after_batch(pend, results)
-        # What `stats` reports of "the last solve" is the last batch
-        # FINISHED, whole: each field one assignment, read lock-free.
-        self._last_select = pend.select
-        self.last_extract_impl = pend.extract_impl
-        self.last_variant = pend.variant
+        self._report_finished(pend)
         self.last_kernel_calls = pend.kernel_calls
         self.last_mp_passes = pend.mp_passes
-        self.last_prune = pend.last_prune
-        if pend.last_prune is not None:
-            self.last_prune_fraction = pend.last_prune["pruned_fraction"]
         return results
 
     def run(self, inp: KNNInput, first: int = 0) -> List[QueryResult]:
@@ -1679,81 +1735,30 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         self._check_k(inp)
         return rs_degrade.run_ladder(self, inp, self._run, first=first)
 
-    def _check_k(self, inp: KNNInput) -> None:
-        kmax = int(inp.ks.max()) if inp.params.num_queries else 0
-        if kmax > self.max_k:
-            raise RequestShapeError(
-                f"k={kmax} beyond the serving cap {self.max_k}")
-
     # -- the serving entry ----------------------------------------------------
 
-    def solve_batch(self, query_attrs, ks) -> List[QueryResult]:
-        """One coalesced micro-batch end to end: pad/bucket, solve on
-        the compiled bucket program, float64-finalize + repair, update
-        the cross-request gate state. Results carry query ids
-        0..nq-1 in batch order — the batcher slices per request.
-
-        Called alone (warm-up, the tests, every caller outside the
-        batcher) it runs the two halves back to back. Called by
-        ``finish_batch`` it is the second half of the batch handed to
-        it, whose first half ``begin_batch`` ran earlier: the fence,
-        the hazard test, the float64 finalize + repair, the gate
-        bookkeeping. It raises what the batch failed with; a batch
-        begun behind it is untouched. Same answers either way."""
-        pend, self._handed = self._handed, None
-        if pend is None:
-            pend = self.begin_batch(query_attrs, ks)
-        if pend in self._in_flight:
-            self._in_flight.remove(pend)
-        if pend.outcome is None:
-            pend.outcome = self._outcome(pend)
-        results, error = pend.outcome
-        if error is not None:
-            raise error
-        return results
-
-    def begin_batch(self, query_attrs, ks, batch: Optional[int] = None,
-                    rids: Optional[str] = None) -> PendingBatch:
-        """The first half of a micro-batch: stage its queries, choose
-        and prune the fold order, dispatch the bucket's program(s).
-        Everything that only ENQUEUES: the batcher calls this for batch
-        N + 1 while the device still folds batch N, and only then
-        finishes N. (It waits for the device in one place, the prune
-        scorer's readback.) ``batch`` / ``rids`` tag the batch's spans.
-
-        An OOM-class failure here is kept in the record: the second
+    def _first_half(self, pend: PendingBatch) -> None:
+        """An OOM-class failure here is kept in the record: the second
         half re-runs the batch whole, a rung down. Any other raises."""
-        inp = self._batch_input(np.asarray(query_attrs, np.float64),
-                                np.asarray(ks, np.int32))
-        self._check_k(inp)
-        pend = PendingBatch(inp, batch=batch, rids=rids,
-                            overlapped=bool(self._in_flight))
         try:
-            with self._tagged(pend), rs_degrade.top_rung(self):
+            with rs_degrade.top_rung(self):
                 self._run_begin(pend)
         except Exception as e:
             if not rs_degrade.steps_down(e):
                 raise
             pend.oom = e
-        self._in_flight.append(pend)
-        return pend
 
-    def _outcome(self, pend: PendingBatch) -> Tuple:
-        """(results, None) or (None, the exception) of ``pend``'s second
-        half; an OOM-class failure in either half walks the ladder."""
-        try:
-            with self._tagged(pend):
-                if pend.oom is None:
-                    try:
-                        with rs_degrade.top_rung(self):
-                            return self._run_finish(pend), None
-                    except Exception as e:
-                        if not rs_degrade.steps_down(e):
-                            raise
-                        pend.oom = e
-                return self._rerun_alone(pend), None
-        except Exception as e:  # check: no-retry — solve_batch raises it
-            return None, e
+    def _second_half(self, pend: PendingBatch) -> List[QueryResult]:
+        """An OOM-class failure in either half walks the ladder."""
+        if pend.oom is None:
+            try:
+                with rs_degrade.top_rung(self):
+                    return self._run_finish(pend)
+            except Exception as e:
+                if not rs_degrade.steps_down(e):
+                    raise
+                pend.oom = e
+        return self._rerun_alone(pend)
 
     def _rerun_alone(self, pend: PendingBatch) -> List[QueryResult]:
         """The ladder's meaning with two batches alive: the batch that

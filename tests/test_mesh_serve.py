@@ -492,9 +492,9 @@ def mesh_fold_journey():
                 return out
             return spy
 
-        def _after_batch(self, results):
-            calls[-1]["tiles"] = self._pending_gate[1]
-            super()._after_batch(results)
+        def _after_batch(self, pend, results):
+            calls[-1]["tiles"] = pend.gate[1]
+            super()._after_batch(pend, results)
 
     rng = np.random.default_rng(95)
     corpus = make_corpus(rng.uniform(-10, 10, (FOLD_N, FOLD_NA)),
