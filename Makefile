@@ -5,7 +5,7 @@
 CXX ?= g++
 CXXFLAGS ?= -O3 -Wall -shared -fPIC
 
-.PHONY: all native test tier1 obs-smoke obs-dist-smoke tune-smoke \
+.PHONY: all native test tier1 obs-smoke obs-dist-smoke \
 	check lint chaos-smoke telemetry-smoke serve-smoke \
 	race-smoke prune-smoke precision-smoke fleet-smoke \
 	fleet-chaos-smoke fleet-trace-smoke slo-smoke auto-smoke \
@@ -18,7 +18,7 @@ native: native/_fastparse.so
 native/_fastparse.so: native/fastparse.cpp
 	$(CXX) $(CXXFLAGS) -o $@ $<
 
-test: obs-smoke obs-dist-smoke tune-smoke check lint \
+test: obs-smoke obs-dist-smoke check lint \
 	chaos-smoke telemetry-smoke serve-smoke race-smoke prune-smoke \
 	precision-smoke fleet-smoke fleet-chaos-smoke fleet-trace-smoke \
 	slo-smoke auto-smoke hlo-smoke
@@ -105,19 +105,6 @@ obs-smoke:
 # per-rank timestamps).
 obs-dist-smoke:
 	JAX_PLATFORMS=cpu python tools/obs_dist_smoke.py --dir outputs/dist_obs
-
-# Autotuner smoke: a tiny-shape measured sweep on CPU (interpret-mode
-# kernel) through the real `python -m dmlp_tpu.tune` CLI into a
-# scratch cache, then an explicit schema validation of the file it
-# wrote — proves measure -> pick -> persist -> reload end to end
-# without touching any developer's real variant cache.
-tune-smoke:
-	mkdir -p outputs
-	rm -f outputs/tune_smoke_cache.json
-	JAX_PLATFORMS=cpu DMLP_TPU_TUNE_CACHE=outputs/tune_smoke_cache.json \
-	  python -m dmlp_tpu.tune --smoke --record outputs/TUNE_SMOKE.json
-	JAX_PLATFORMS=cpu python -m dmlp_tpu.tune \
-	  --validate outputs/tune_smoke_cache.json
 
 # Chaos smoke (README "Resilience & chaos testing"): bench config 1 and
 # a short --nan-guard train run replayed under three seeded fault

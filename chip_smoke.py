@@ -108,12 +108,9 @@ def say(msg: str) -> None:
 
 def child_env() -> Dict[str, str]:
     """The caller's environment (platform and compile-cache placement
-    included) plus the checkout on the path — and the tune cache pointed
-    at a file that does not exist, so kernel variants are the committed
-    heuristic and nothing outside the checkout is read."""
+    included) plus the checkout on the path."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["DMLP_TPU_TUNE_CACHE"] = os.path.join(WORK, "no_tune_cache.json")
     return env
 
 
@@ -168,10 +165,6 @@ def check_stamp(stamp: Any, mesh: Optional[List[int]], ladder: bool,
     if stamp.get("pallas_interpret") is not False:
         bad.append("pallas_interpret is "
                    f"{stamp.get('pallas_interpret')}")
-    variant = stamp.get("kernel_variant") or {}
-    if variant.get("from_tune_cache") is not False:
-        bad.append(f"kernel variant {variant} did not come from the "
-                   "committed heuristic")
     if ladder and stamp.get("degrade_rung") != "lowp":
         bad.append(f"degrade rung is {stamp.get('degrade_rung')}")
     if stamp.get("degradations"):
@@ -367,7 +360,7 @@ def fold_child(out_path: str) -> int:
             "shape": dict(FOLD_SHAPE, chunk_rows=rows, n_real=n_real),
             "mxu_passes": {
                 st: pallas_fused.variant_stamp(
-                    "fused", kc, rows, nq, na, "f32", st)["mxu_passes"]
+                    kc, rows, nq, na, "f32", st)["mxu_passes"]
                 for st in ("bfloat16", "float32")},
             "kernel_data_operands": data_operands,
             "ids_equal": bool(np.array_equal(oi16, oi32)),

@@ -1,11 +1,10 @@
 """R2 — recompilation and stale-trace hazards around ``jax.jit``.
 
 The family exists because of a real review bug (PR 3): the extract
-kernel's measured-variant resolution originally ran *inside* the jitted
-body, so a mid-process tuner sweep changed the cache but the jit kept
-replaying the trace baked with the old variant. The fix — resolve
-outside, make the concrete variant part of the jit cache key — is now a
-lint (R203), together with its relatives:
+kernel's variant resolution originally ran *inside* the jitted body, so
+what it read at trace time was baked into a trace the jit kept
+replaying. The fix — resolve outside, make the concrete variant part of
+the jit cache key — is now a lint (R203), together with its relatives:
 
 - **R201** non-hashable (mutable) default arguments on jitted
   functions: jax hashes static arguments; a ``[]``/``{}`` default
@@ -14,8 +13,8 @@ lint (R203), together with its relatives:
   string building is a smell that host state (names, config reprs) is
   leaking into the traced program — except in ``raise``/``assert``
   error paths, which run once at trace time and abort.
-- **R203** variant/config resolution (``resolve_*``,
-  ``lookup_variant``) inside traced bodies — the PR 3 bug class.
+- **R203** variant/config resolution (``resolve_*``) inside traced
+  bodies — the PR 3 bug class.
 - **R204** keyword-only parameters with obviously-static names
   (``select``, ``use_pallas``, ``kc`` ...) missing from
   ``static_argnames``: tracing them as arrays either fails or bakes a
@@ -35,13 +34,13 @@ from dmlp_tpu.check.findings import Finding
 
 #: resolution calls that must happen OUTSIDE traced bodies (R203)
 RESOLUTION_FNS = {
-    "resolve_variant", "_resolve_variant", "lookup_variant",
+    "resolve_variant", "_resolve_variant",
     "resolve_select", "resolve_streaming_select", "resolve_dtype",
     "resolve_granule", "resolve_data_block", "resolve_kcap",
     # the fused-megakernel selection surface (ops.pallas_fused): which
     # kernel runs — and the env kill switch that flips it — must be
     # baked into the jit cache key, never read inside a traced body
-    "resolve_topk_kernel", "fused_enabled", "variant_for",
+    "resolve_topk_kernel", "fused_enabled",
 }
 
 #: keyword-only parameter names that are plainly Python-level config —

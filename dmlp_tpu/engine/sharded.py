@@ -105,10 +105,9 @@ class ShardedEngine:
         # Which kernel the last extract-select solve baked into its mesh
         # programs ("fused" | "extract" | None) — artifacts report it.
         self.last_extract_impl = None
-        # Its tiles and whether a tune-cache file supplied them
-        # (ops.pallas_fused.variant_stamp), and the corpus rows each
-        # device was staged in the last solve — the device stamp
-        # (obs.run.device_stamp) reports both.
+        # Its tiles (ops.pallas_fused.variant_stamp), and the corpus
+        # rows each device was staged in the last solve — the device
+        # stamp (obs.run.device_stamp) reports both.
         self.last_variant = None
         self._rows_staged: Dict[str, int] = {}
         # (site, device iters-sum scalar, shape) queue for the measured
@@ -199,7 +198,7 @@ class ShardedEngine:
         impl = impl or "extract"  # plan already validated ex_supports
         self.last_extract_impl = impl
         self.last_variant = variant_stamp(
-            impl, k, b, qb, a,
+            k, b, qb, a,
             (self.last_precision or {}).get("active", "f32"),
             self._staging)
         return impl
@@ -707,11 +706,11 @@ class ShardedEngine:
         src = np.ascontiguousarray(inp.data_attrs, np.float32)
         throttle = ChunkThrottle()
         mi = MeasuredIters(self, "sharded.chunk_fold",
-                           (qloc, chunk_rows, na, k), kernel=impl)
-        from dmlp_tpu.ops.pallas_fused import variant_for
+                           (qloc, chunk_rows, na, k))
+        from dmlp_tpu.ops.pallas_extract import resolve_variant
         with obs_span("sharded.enqueue_chunked", chunks=nchunks,
                       scheduled=n_disp, mesh=[r, c], kc=k, impl=impl,
-                      variant=variant_for(impl, k, chunk_rows, qloc, na)):
+                      variant=resolve_variant(k, chunk_rows, qloc, na)):
             for t in range(nchunks):
                 live_col = None if keep_m is None else keep_m[:, t]
                 if live_col is not None and not live_col.any():
@@ -857,21 +856,17 @@ class ShardedEngine:
             sp.fence(top.dists)
         self._queue_iters("sharded.solve_merge", select, its,
                           q_attrs.shape[0] // c, d_attrs.shape[0] // r,
-                          d_attrs.shape[1], k, impl=impl)
+                          d_attrs.shape[1], k)
         return top
 
     def _queue_iters(self, site: str, select: str, its,
-                     qloc: int, shard_rows: int, na: int, k: int,
-                     impl: str = "extract") -> None:
+                     qloc: int, shard_rows: int, na: int, k: int) -> None:
         """Queue a mesh program's per-shard kernel iters (summed over
         cells) for the post-fence measured-extraction-term flush; no-op
-        for non-extract selects or without an installed probe. ``impl``
-        tags the shape so the measured term is costed at the dispatched
-        kernel's own resolved tiles (fused namespace when fused ran)."""
+        for non-extract selects or without an installed probe."""
         if select != "extract":
             return
-        mi = MeasuredIters(self, site, (qloc, shard_rows, na, k),
-                           kernel=impl)
+        mi = MeasuredIters(self, site, (qloc, shard_rows, na, k))
         mi.add(its)
         mi.done()
 
@@ -937,7 +932,7 @@ class ShardedEngine:
         top, its = rs_retry.call_with_retry(_op, "sharded.solve")
         self._queue_iters("sharded.solve_global", select, its,
                           q_attrs.shape[0] // c, d_attrs.shape[0] // r,
-                          d_attrs.shape[1], k, impl=impl)
+                          d_attrs.shape[1], k)
         return top
 
     def _plan_shard(self, d_attrs, q_attrs, kmax: int, merged_width: bool):
@@ -1040,7 +1035,7 @@ class ShardedEngine:
             top, its = rs_retry.call_with_retry(_op, "sharded.solve")
         self._queue_iters("sharded.solve_local_shards", select, its,
                           q_attrs.shape[0] // c, d_attrs.shape[0] // r,
-                          d_attrs.shape[1], k, impl=impl)
+                          d_attrs.shape[1], k)
         return top
 
     def run(self, inp: KNNInput) -> List[QueryResult]:
@@ -1250,7 +1245,7 @@ class ShardedEngine:
             sp.fence(d)
         self._queue_iters("sharded.device_full", select, its,
                           qpad // c, d_attrs.shape[0] // r,
-                          d_attrs.shape[1], k, impl=impl)
+                          d_attrs.shape[1], k)
         p, i, d = resilient_get((p, i, d), site="sharded.fetch")
         preds = p[:nq]
         rids = i[:nq]

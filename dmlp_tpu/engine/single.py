@@ -326,17 +326,13 @@ class MeasuredIters:
     per dispatch (no-op unless a cost probe is installed), ``done()``
     queues the site's device scalar on ``engine._pending_iters`` for the
     post-fence flush (engine._flush_measured_iters) — ONE copy of the
-    protocol for the extract paths instead of one per path. ``kernel``
-    ("extract" | "fused") rides along so the measured extraction term
-    costs its iterations at the kernel's OWN resolved tiles (the fused
-    tune-cache namespace can pin different ones)."""
+    protocol for the extract paths instead of one per path."""
 
     def __init__(self, engine, site: str,
-                 shape: Tuple[int, int, int, int],
-                 kernel: str = "extract"):
+                 shape: Tuple[int, int, int, int]):
         self._on = obs_counters.active() is not None
         self._engine, self._site = engine, site
-        self._shape, self._kernel = tuple(shape), kernel
+        self._shape = tuple(shape)
         self._sum = None
 
     def add(self, iters) -> None:
@@ -347,7 +343,7 @@ class MeasuredIters:
     def done(self) -> None:
         if self._sum is not None:
             self._engine._pending_iters.append(
-                (self._site, self._sum, self._shape, self._kernel))
+                (self._site, self._sum, self._shape))
 
 
 def flush_measured_iters(engine) -> None:
@@ -362,10 +358,10 @@ def flush_measured_iters(engine) -> None:
     engine._pending_iters = []
     if not pend:
         return
-    for site, s, shape, kernel in pend:
+    for site, s, shape in pend:
         try:
             obs_counters.record_measured_iters(  # check: allow-host-sync
-                site, int(jax.device_get(s)), shape, kernel=kernel)
+                site, int(jax.device_get(s)), shape)
         except Exception:  # check: no-retry
             pass  # observability must never fail the solve
 
@@ -501,7 +497,7 @@ def _extract_finalize(od, oi, glabels, *, k):
 
 
 def resolve_sweep_kernel(qpad: int, full_rows: int, na: int, kc: int, *,
-                         chunk_rows: int, rung: str, precision: str):
+                         chunk_rows: int, rung: str):
     """(kernel, impl) for a multipass plan's passes 2+, which sweep the
     whole staged array (``full_rows`` = its chunks' rows together) in
     ONE kernel call, ASSERTED to tile it. Both multipass drivers (the
@@ -509,25 +505,19 @@ def resolve_sweep_kernel(qpad: int, full_rows: int, na: int, kc: int, *,
     any pass is dispatched. Pass 1 was checked at ``chunk_rows``; that
     the 128 * ne divisibility and the tile caps carry from a chunk to
     its multiples is true of today's variants and of nothing else: the
-    variant resolves per row count (a tune-cache entry may pin one at
-    one bucket only), so the carry is checked here, on the variant the
-    sweep will run with, and a tuning change that breaks it fails
-    loudly instead of mis-tiling every pass after the first. The fused
-    / two-pass choice resolves independently per row count too, so
-    pass 1 and the sweeps may legally run different kernels: each is
-    bit-identical, so their union is."""
+    variant resolves per row count, so the carry is checked here, on
+    the variant the sweep will run with (resolve_topk_kernel gates on
+    supports(): whole 128 * ne sub-blocks, a tile no narrower than kc,
+    VMEM room: everything the sweep's one call needs), and a tuning
+    change that breaks it fails loudly instead of mis-tiling every
+    pass after the first."""
     from dmlp_tpu.ops import pallas_fused
-    from dmlp_tpu.ops.pallas_extract import variant_supports
     kern, impl = pallas_fused.resolve_topk_kernel(
         qpad, full_rows, na, kc, rung=rung)
-    v = None if kern is None else pallas_fused.variant_for(
-        impl, kc, full_rows, qpad, na, precision)
-    # variant_supports: whole 128 * ne sub-blocks, a tile no narrower
-    # than kc, VMEM room: everything the sweep's one call needs
-    if v is None or not variant_supports(qpad, full_rows, na, kc, v):
+    if kern is None:
         raise AssertionError(
             f"multi-pass extract: full-array sweep shape (qb={qpad}, "
-            f"rows={full_rows}, a={na}, kc={kc}, variant={v}) is "
+            f"rows={full_rows}, a={na}, kc={kc}) is "
             f"untileable even though the per-chunk shape "
             f"(rows={chunk_rows}) tiles — supports() invariants diverged "
             "between the chunked pass 1 and the resident passes 2+")
@@ -645,13 +635,12 @@ class SingleChipEngine:
         # Which kernel the last extract-path solve dispatched
         # ("fused" | "extract" | None) — bench/artifacts report it.
         self.last_extract_impl = None
-        # The tiles that dispatch ran with and whether a tune-cache file
-        # supplied them (ops.pallas_fused.variant_stamp); the device
-        # stamp reports it.
+        # The tiles that dispatch ran with
+        # (ops.pallas_fused.variant_stamp); the device stamp reports it.
         self.last_variant = None
         self.last_repairs = 0
         # Degradation-ladder rung (resilience.degrade): "fused" (the
-        # default) allows the fused megakernel; "tuned" drops to the
+        # default) allows the fused megakernel; "heuristic" drops to the
         # two-pass extraction kernel; "streaming" forces the chunk-fold
         # driver (no extract-kernel dispatch at all);
         # last_degrade_rung reports the rung the last run() settled on.
@@ -897,7 +886,7 @@ class SingleChipEngine:
         # Queries pad to a whole query tile for the same reason data pads
         # to whole extraction blocks: an awkward qb (e.g. 8 * prime) would
         # force a degenerate 8-row query tile.
-        from dmlp_tpu.ops.pallas_extract import QUERY_TILE
+        from dmlp_tpu.ops.pallas_extract import QUERY_TILE, resolve_variant
         qpad = round_up(nq, QUERY_TILE)
         kmax = int(inp.ks.max())
         k = resolve_kcap(cfg, kmax, "extract", nchunks * chunk_rows,
@@ -914,7 +903,7 @@ class SingleChipEngine:
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
-            impl, k, chunk_rows, qpad, na, prec, self._staging)
+            k, chunk_rows, qpad, na, prec, self._staging)
 
         schedule, prune_stats = self._plan_prune(inp, nchunks, chunk_rows,
                                                  prec)
@@ -926,12 +915,11 @@ class SingleChipEngine:
         od = oi = None
         scanned = 0
         mi = MeasuredIters(self, "single.extract_topk",
-                           (qpad, chunk_rows, na, k), kernel=impl)
+                           (qpad, chunk_rows, na, k))
         throttle = ChunkThrottle()
         with obs_span("single.enqueue_extract", chunks=nchunks, kc=k,
                       impl=impl, scheduled=len(live),
-                      variant=pallas_fused.variant_for(
-                          impl, k, chunk_rows, qpad, na, prec)):
+                      variant=resolve_variant(k, chunk_rows, qpad, na)):
             for c in live:    # survivor schedule; pruned blocks are
                 # never staged — the beyond-HBM payoff is exactly that
                 # their bytes never leave host DRAM
@@ -1046,14 +1034,14 @@ class SingleChipEngine:
         # (resolve_sweep_kernel asserts it, before anything is staged).
         n_staged = min(nchunks, -(-n // chunk_rows))
         full_rows = n_staged * chunk_rows
-        kern_full, impl_full = resolve_sweep_kernel(
+        kern_full, _ = resolve_sweep_kernel(
             qpad, full_rows, na, kc, chunk_rows=chunk_rows,
-            rung=self._degrade_rung, precision=prec)
+            rung=self._degrade_rung)
         interpret = pallas_interpret()
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
-            impl, kc, chunk_rows, qpad, na, prec, self._staging)
+            kc, chunk_rows, qpad, na, prec, self._staging)
         rs_inject.fire("single.extract_solve", rung=self._degrade_rung,
                        path="multipass")
 
@@ -1068,7 +1056,7 @@ class SingleChipEngine:
         chunks: List[Tuple] = []
         od = oi = None
         mi = MeasuredIters(self, "single.extract_mp_pass1",
-                           (qpad, chunk_rows, na, kc), kernel=impl)
+                           (qpad, chunk_rows, na, kc))
         throttle = ChunkThrottle()
         for c in range(nchunks):
             lo, hi = c * chunk_rows, min((c + 1) * chunk_rows, n)
@@ -1124,7 +1112,7 @@ class SingleChipEngine:
                 count=npasses - 1, site="single.extract_mp_resident")
         fds = []
         mir = MeasuredIters(self, "single.extract_mp_resident",
-                            (qpad, full_rows, na, kc), kernel=impl_full)
+                            (qpad, full_rows, na, kc))
         for _p in range(1, npasses):
             floor_dev, fd = _mp_floor(ods[-1], qn_dev, dn_dev,
                                       staging=self._staging, na=na,
@@ -1240,7 +1228,7 @@ class SingleChipEngine:
         self._last_select = "extract"
         self.last_extract_impl = impl
         self.last_variant = pallas_fused.variant_stamp(
-            impl, kb, chunk_rows, qpad_b, na, prec, self._staging)
+            kb, chunk_rows, qpad_b, na, prec, self._staging)
         self.last_hetk = (int(bulk.size), int(outl.size))
         rs_inject.fire("single.extract_solve", rung=self._degrade_rung,
                        path="routed")
@@ -1267,7 +1255,7 @@ class SingleChipEngine:
         od = oi = None
         scanned = 0
         mi = MeasuredIters(self, "single.extract_bulk",
-                           (qpad_b, chunk_rows, na, kb), kernel=impl)
+                           (qpad_b, chunk_rows, na, kb))
         throttle = ChunkThrottle()
         for c in live_sched:
             lo, hi = c * chunk_rows, min((c + 1) * chunk_rows, n)
@@ -1377,7 +1365,7 @@ class SingleChipEngine:
         kmax = int(inp.ks.max()) if inp.params.num_queries else 0
         with staging_for_k(self, kmax):
             # Degradation ladder (resilience.degrade): on device OOM —
-            # injected or real — the solve steps tuned -> heuristic ->
+            # injected or real — the solve steps heuristic ->
             # streaming -> host-f64, every rung checksum-preserving.
             return rs_degrade.run_ladder(self, inp, self._run)
 

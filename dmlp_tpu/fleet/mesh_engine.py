@@ -96,8 +96,7 @@ from dmlp_tpu.serve.engine import (_KERNEL_STATICS, CapacityError,
                                    PendingBatch, ResidentServingCore,
                                    _kernel_statics, _update_chunk,
                                    _variant_args, fold_chunks, fold_tiles,
-                                   k_bucket, query_bucket)
-from dmlp_tpu.tune.cache import shape_bucket
+                                   k_bucket, query_bucket, shape_bucket)
 from dmlp_tpu.utils.compat import shard_map
 
 
@@ -589,7 +588,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             pend.select = "extract"
             pend.extract_impl = impl
             pend.variant = {**pallas_fused.variant_stamp(
-                impl, k, cr, entry.qloc, na, prec, self._staging),
+                k, cr, entry.qloc, na, prec, self._staging),
                 "norms": "staged"}
             kern = _kernel_statics(impl, k, cr, entry.qloc, na, prec,
                                    self._interpret)
@@ -650,7 +649,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                    throttle_wait_ms=0.0)
         pend.phase_ms["dispatch"] = (t2 - t0) * 1e3
         mi = MeasuredIters(pend, "fleet.chunk_fold",
-                           (entry.qloc, cr, na, k), kernel=impl)
+                           (entry.qloc, cr, na, k))
         mi.add(iters)
         mi.done()
         # Gate effectiveness: a 0-iteration tile was gated (or

@@ -47,7 +47,7 @@ from dmlp_tpu.fleet import consistency as ccs
 from dmlp_tpu.fleet.router import Replica
 from dmlp_tpu.obs import telemetry
 from dmlp_tpu.obs.trace import instant as obs_instant
-from dmlp_tpu.tune.cache import shape_bucket
+from dmlp_tpu.serve.engine import shape_bucket
 
 
 def grown_capacity(capacity_rows: int, rows: int,

@@ -139,17 +139,15 @@ class CostProbe:
         return [tuple(e) for e in self._entries.values()]
 
     def record_measured_iters(self, site: str, iters_total: int,
-                              shape: Tuple[int, int, int, int],
-                              kernel: str = "extract") -> None:
+                              shape: Tuple[int, int, int, int]) -> None:
         """Attach MEASURED extraction-loop iteration counts to ``site``
         (summed over the kernel's iters output across that site's
         dispatches at this shape). ``shape`` is the per-dispatch
-        (qb, b, a, kc); ``kernel`` ("extract" | "fused") names which
-        top-k kernel dispatched, so the collect pass costs each (site,
-        shape, kernel)'s count at that kernel's own resolved tiles
+        (qb, b, a, kc): the collect pass costs each (site, shape)'s
+        count at that shape's resolved tiles
         (obs.kernel_cost.extract_loop_cost) and the site's total is no
         longer just the deterministic lower bound."""
-        key = (site, tuple(shape), kernel)
+        key = (site, tuple(shape))
         self._measured_iters[key] = \
             self._measured_iters.get(key, 0) + int(iters_total)
 
@@ -198,17 +196,15 @@ class CostProbe:
                 except Exception:
                     pass
             return out
-        # Measured extraction terms: fold each (site, shape, kernel)'s
+        # Measured extraction terms: fold each (site, shape)'s
         # read-back iters count into the totals (count-independent — the
         # engines already summed across that site's dispatches at the
-        # shape); ``kernel`` picks the tune-cache namespace the tiles
-        # cost at (the fused megakernel may resolve different ones).
+        # shape).
         iters_all = 0
-        for (site, shape, kern), iters_total in \
-                self._measured_iters.items():
+        for (site, shape), iters_total in self._measured_iters.items():
             try:
                 loop_flops = kernel_cost.extract_loop_cost(
-                    *shape, iters_total=iters_total, kernel=kern)
+                    *shape, iters_total=iters_total)
             except Exception:
                 continue
             flops += loop_flops
@@ -295,10 +291,9 @@ def record_dispatch(fn, args: tuple, statics: Optional[dict] = None,
 
 
 def record_measured_iters(site: str, iters_total: int,
-                          shape: Tuple[int, int, int, int],
-                          kernel: str = "extract") -> None:
+                          shape: Tuple[int, int, int, int]) -> None:
     """Post-fence hook: measured extract-loop iters for ``site``
     (see CostProbe.record_measured_iters); no-op without a probe."""
     p = _active
     if p is not None:
-        p.record_measured_iters(site, iters_total, shape, kernel=kernel)
+        p.record_measured_iters(site, iters_total, shape)

@@ -72,21 +72,8 @@ __all__ = ["extract_topk_cost", "extract_loop_cost", "fused_topk_cost",
 # _operand_dtype).
 
 
-def _variant_resolver(kernel: str):
-    """The ``_resolve_variant`` of the tune-cache namespace ``kernel``
-    ("extract" | "fused") costs its tiles through — ONE mapping so a
-    new kernel namespace (or a rename) cannot update one model and
-    silently leave another costing at the wrong namespace's tiles."""
-    if kernel == "fused":
-        from dmlp_tpu.ops.pallas_fused import _resolve_variant
-    else:
-        from dmlp_tpu.ops.pallas_extract import _resolve_variant
-    return _resolve_variant
-
-
 def extract_loop_cost(qb: int, b: int, a: int, kc: int,
-                      iters_total: int, kernel: str = "extract",
-                      precision: str = "f32") -> float:
+                      iters_total: int) -> float:
     """MEASURED extraction-loop FLOPs for ``iters_total`` recorded loop
     iterations (summed over the kernel's (Qb/tq, B/tn) ``iters`` output,
     possibly across many dispatches at the same shape).
@@ -97,17 +84,12 @@ def extract_loop_cost(qb: int, b: int, a: int, kc: int,
     (2*tq*w), and the threshold/insert ops on the (tq, kc) lists
     (~4*tq*kc) — so ~5*tq*tn + 4*ne*tq*kc FLOPs per round. ``a`` (the
     attribute width) does not enter the loop arithmetic but DOES enter
-    variant resolution (the tuner cache keys on it and the VMEM gate
-    scales with it), so it must match the dispatch. ``kernel``
-    ("extract" | "fused") selects WHICH tune-cache namespace the tiles
-    resolve through — the fused megakernel may run different tiles, so
-    its measured iterations must be costed at its own resolution (and
-    ``precision`` keys the same resolution — per-precision winners may
-    pin different tiles)."""
+    variant resolution (the row width picks tile_n), so it must match
+    the dispatch. Both kernel forms run the same tiles."""
     from dmlp_tpu.ops.pallas_distance import _tile
-    from dmlp_tpu.ops.pallas_extract import _TN
+    from dmlp_tpu.ops.pallas_extract import _TN, resolve_variant
 
-    v = _variant_resolver(kernel)(kc, b, qb, a, precision)
+    v = resolve_variant(kc, b, qb, a)
     tq = _tile(qb, v["tile_q"], 8)
     tn = _tile(b, v.get("tile_n", _TN), 128 * v["ne"])
     round_flops = 5.0 * tq * tn + 4.0 * v["ne"] * tq * kc
@@ -115,25 +97,22 @@ def extract_loop_cost(qb: int, b: int, a: int, kc: int,
 
 
 def _streaming_cost(qb: int, b: int, a: int, kc: int,
-                    kernel: str = "extract",
-                    precision: str = "f32",
                     data_dtype: str = "float32") -> Dict[str, float]:
     """The SHARED deterministic model of one streaming top-k dispatch
     (the (qb, b) distance tile lives only in VMEM): flops + HBM bytes
-    at the tiles the ``kernel`` namespace ("extract" | "fused")
-    resolves for this shape. One body for both kernels — the fused
-    megakernel adds only its gate term on top — so a future fix to any
-    shared term cannot drift between the two models. ``precision``
-    keys the variant resolution (per-precision winners) but does NOT
-    change the modeled flops/bytes — the in-VMEM cast is free of HBM
-    traffic. ``data_dtype`` does: the kernel streams float32 blocks
-    (a converted copy, for operands of mixed dtypes) unless both
-    operands arrive bfloat16, and then the data panel, the term that
-    dominates, weighs half (the query panel stays float32)."""
+    at the tiles resolved for this shape. One body for both kernels —
+    the fused megakernel adds only its gate term on top — so a future
+    fix to any shared term cannot drift between the two models. The
+    first-pass precision does not change the modeled flops/bytes (the
+    in-VMEM cast is free of HBM traffic). ``data_dtype`` does: the
+    kernel streams float32 blocks (a converted copy, for operands of
+    mixed dtypes) unless both operands arrive bfloat16, and then the
+    data panel, the term that dominates, weighs half (the query panel
+    stays float32)."""
     from dmlp_tpu.ops.pallas_distance import _tile
-    from dmlp_tpu.ops.pallas_extract import _TN
+    from dmlp_tpu.ops.pallas_extract import _TN, resolve_variant
 
-    v = _variant_resolver(kernel)(kc, b, qb, a, precision)
+    v = resolve_variant(kc, b, qb, a)
     tq = _tile(qb, v["tile_q"], 8)
     tn = _tile(b, v.get("tile_n", _TN), 128 * v["ne"])
     flops = (2.0 * qb * b * a      # MXU cross-term block
@@ -160,21 +139,19 @@ def extract_topk_cost(qb: int, b: int, a: int, kc: int,
     ``iters_total`` the data-dependent while-loop is excluded
     (deterministic lower bound); with it, the measured extraction term
     (:func:`extract_loop_cost`) is added and the dict says so.
-    ``precision`` ("f32" | "bf16x3" | "bf16") keys the tile resolution
-    and is reported back with its MXU pass count
+    ``precision`` ("f32" | "bf16x3" | "bf16") is reported back with
+    its MXU pass count
     (ops.pallas_extract.mxu_passes: one where the operands arrive as
     ``data_dtype`` "bfloat16") —
     ``flops`` itself keeps the precision-independent dot convention."""
     from dmlp_tpu.ops.pallas_extract import mxu_passes
-    base = _streaming_cost(qb, b, a, kc, precision=precision,
-                           data_dtype=data_dtype)
+    base = _streaming_cost(qb, b, a, kc, data_dtype=data_dtype)
     out = {"flops": base["flops"], "bytes_accessed": base["bytes_accessed"],
            "extraction_term": "modeled_lower_bound",
            "mxu_precision": precision,
            "mxu_passes": mxu_passes(precision, data_dtype)}
     if iters_total is not None:
-        out["flops"] += extract_loop_cost(qb, b, a, kc, iters_total,
-                                          precision=precision)
+        out["flops"] += extract_loop_cost(qb, b, a, kc, iters_total)
         out["extraction_term"] = "measured"
         out["extract_iters_total"] = int(iters_total)
     return out
@@ -187,8 +164,8 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
     """Cost of one ``ops.pallas_fused.fused_topk`` dispatch — the fused
     distance→top-k streaming megakernel. Same one-pass HBM structure as
     :func:`extract_topk_cost` (the (qb, b) distance tile lives only in
-    VMEM), with tiles resolved from the FUSED tune-cache namespace and
-    the per-block norm-bound MXU gate added to the deterministic FLOPs
+    VMEM), at the same tiles, with the per-block norm-bound MXU gate
+    added to the deterministic FLOPs
     (one VPU pass over the block's dn row + a per-row bound: the price
     of being able to skip the matmul outright).
 
@@ -198,16 +175,12 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
     ``hbm_bytes_two_pass_equiv`` / ``hbm_bytes_saved_vs_two_pass`` /
     ``hbm_traffic_reduction_x`` — the ROADMAP's "one HBM pass for the
     whole hot path" claim as a checked number, not prose. Both sides of
-    that delta resolve through the SAME (fused) tile namespace, so the
-    saved bytes are EXACTLY the 2·4·qb·b distance round-trip — a cached
-    fused variant with different tiles than the extract namespace
-    cannot leak tile-resolution differences into the metric.
-    ``precision`` keys the tile resolution (both sides of the delta)
-    and reports its MXU pass count; ``flops`` stays convention-stable.
+    that delta run the same tiles, so the saved bytes are EXACTLY the
+    2·4·qb·b distance round-trip. ``precision`` reports its MXU pass
+    count; ``flops`` stays convention-stable.
     """
     from dmlp_tpu.ops.pallas_extract import mxu_passes
-    base = _streaming_cost(qb, b, a, kc, kernel="fused",
-                           precision=precision, data_dtype=data_dtype)
+    base = _streaming_cost(qb, b, a, kc, data_dtype=data_dtype)
     tq, tn = base["tq"], base["tn"]
     flops = (base["flops"]
              # The MXU gate itself, per (tq, tn) grid cell: ~3 block
@@ -217,8 +190,7 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
              # blocks skip the matmul entirely.)
              + (qb // tq) * (b // tn) * (3.0 * tn + 8.0 * tq))
     byts = base["bytes_accessed"]
-    tp = two_pass_equivalent_cost(qb, b, a, kc, precision=precision,
-                                  data_dtype=data_dtype)
+    tp = two_pass_equivalent_cost(qb, b, a, kc, data_dtype=data_dtype)
     out: Dict[str, float] = {
         "flops": flops, "bytes_accessed": byts,
         "extraction_term": "modeled_lower_bound",
@@ -229,30 +201,23 @@ def fused_topk_cost(qb: int, b: int, a: int, kc: int,
         "hbm_traffic_reduction_x": round(tp["bytes_accessed"] / byts, 2),
     }
     if iters_total is not None:
-        out["flops"] += extract_loop_cost(qb, b, a, kc, iters_total,
-                                          kernel="fused",
-                                          precision=precision)
+        out["flops"] += extract_loop_cost(qb, b, a, kc, iters_total)
         out["extraction_term"] = "measured"
         out["extract_iters_total"] = int(iters_total)
     return out
 
 
 def two_pass_equivalent_cost(qb: int, b: int, a: int, kc: int,
-                             kernel: str = "fused",
-                             precision: str = "f32",
                              data_dtype: str = "float32"
                              ) -> Dict[str, float]:
     """What the SAME dispatch costs when the (qb, b) distance matrix
     round-trips HBM between a distance kernel and a selection pass —
     the pre-fused hot path's two passes over its dominant term:
     everything the streaming kernel reads anyway, PLUS one full write
-    and one full re-read of the f32 distance tile. ``kernel`` picks the
-    tile namespace of the streaming base; it defaults to "fused" so the
-    fused model's ``hbm_bytes_saved_vs_two_pass`` is exactly the
-    round-trip delta by construction (same tiles on both sides), and
-    ``precision`` keys that shared resolution too."""
-    base = _streaming_cost(qb, b, a, kc, kernel=kernel,
-                           precision=precision, data_dtype=data_dtype)
+    and one full re-read of the f32 distance tile. The streaming base
+    is the fused model's own, so its ``hbm_bytes_saved_vs_two_pass`` is
+    exactly the round-trip delta by construction."""
+    base = _streaming_cost(qb, b, a, kc, data_dtype=data_dtype)
     return {"flops": base["flops"],
             "bytes_accessed": base["bytes_accessed"]
             + 4.0 * 2.0 * qb * b}

@@ -33,16 +33,14 @@ engine.cpp:233-257, with a threshold-gated extraction):
   measured SLOWER inside the loop — the gate is a single reduction
   before the loop, not predication of every pass.)
 
-Variant selection (tile_q / tile_n / ne / unroll) resolves through the
-measured autotuner cache (dmlp_tpu.tune) when an entry exists for this
-(device kind, shape bucket, kc, dtype); otherwise the deterministic
-heuristic below: tile_q and ne from the list width kc, and the data
-block tile_n from the ROW width (the double-buffered (tile_n, a) block
-is what fills VMEM: 12 800 rows up to 512 attributes, 6 400 at 960,
-2 560 at 2048). An absent cache (CPU, CI) at a <= 512 is bit-identical
-to the pre-tuner behavior. An attribute-axis grid that keeps 12 800
-rows and accumulates the cross term over attribute blocks was measured
-against this on v5e and lost by 25% (tuned_variant's docstring).
+Variant selection (tile_q / tile_n / ne / unroll) is one function of
+the dispatch shape (resolve_variant): tile_q and ne from the list width
+kc (tuned_variant), and the data block tile_n from the ROW width (the
+double-buffered (tile_n, a) block is what fills VMEM: 12 800 rows up to
+512 attributes, 6 400 at 960, 2 560 at 2048). An attribute-axis grid
+that keeps 12 800 rows and accumulates the cross term over attribute
+blocks was measured against this on v5e and lost by 25%
+(tuned_variant's docstring).
 
 Ties are kept by lowest global position (strict `m < T` extraction +
 lowest-lane argmin), i.e. the same semantics as the "topk"/"seg" selects;
@@ -74,7 +72,7 @@ from dmlp_tpu.ops.pallas_distance import _tile
 # (512, 8192, 4) default.
 _TQ = 128    # query rows per tile
 _TN = 12800  # data rows per block, at most (rows past 512 attributes
-#              take a shorter one: _heuristic_variant)
+#              take a shorter one: resolve_variant)
 _E = 2       # extraction candidates per loop iteration (half-block minima)
 
 # Public padding contract for callers (engine.single, bench): pad data to
@@ -102,7 +100,7 @@ def tuned_variant(kc: int) -> dict:
     chunks of 51 200 x 960 float32 at q1024, kc 32, the kernel's device
     time a fold; ``chiprun_out/pr31/contest.json`` of that PR's builder,
     summarised in PERF.md section 6): the row width picks tile_n
-    (_heuristic_variant), and (tq 128, tn 6 400) takes 88.50 ms whether
+    (resolve_variant), and (tq 128, tn 6 400) takes 88.50 ms whether
     the stack is 960 wide or zero-padded to 1 024 (the padded stack
     saves 16.8 ms a fold of chunk copies beside the kernel:
     lane_padded). tq 256 at the same tn: 87.62 (-1.0%, twice the
@@ -184,23 +182,31 @@ def vmem_bytes(tq: int, tn: int, a: int, kc: int) -> int:
     return (tq * tn + 2 * (tq + tn) * _whole_lanes(a) + 4 * tq * kc) * 4
 
 
-def _heuristic_variant(kc: int, b: int, qb: int | None = None,
-                       a: int | None = None) -> dict:
-    """The deterministic fallback: the kc-tuned variant, unless ITS
-    ne-alignment can't tile this b (wide-k wants ne=4 → b % 512; a
-    caller with pre-shaped shards, e.g. the multi-host feed, may only
-    satisfy the ne=2 alignment) — then the default variant keeps kernel
-    coverage at r3 tuning rather than silently dropping to the
-    streaming select.
+def resolve_variant(kc: int, b: int, qb: int | None = None,
+                    a: int | None = None) -> dict:
+    """The tiles extract_topk runs with at this dispatch shape, for
+    both kernel forms (the MXU gate adds per-block scalars, no tiling
+    constraint). A pure function of its arguments: supports(),
+    extract_topk, the engines' jit keys and spans and the analytic
+    cost model (obs.kernel_cost) all call it with the same shape, so
+    gate, kernel and counters can never disagree. Always carries
+    tile_q/ne/unroll, plus tile_n where the row width shortens the
+    block.
+
+    tile_q and ne are the kc-tuned variant's, unless ITS ne-alignment
+    can't tile this b (wide-k wants ne=4 → b % 512; a caller with
+    pre-shaped shards, e.g. the multi-host feed, may only satisfy the
+    ne=2 alignment) — then the default variant keeps kernel coverage
+    at r3 tuning rather than silently dropping to the streaming select.
 
     The data block follows the row width: ``tile_n`` is the largest
     tile of ``b`` (a 128 * ne multiple that divides it) no longer than
     _TN whose double-buffered (tile_n, a) block still fits
     :func:`vmem_bytes`' bound beside the scratch. Up to a = 512 at
     (tq 128, kc 32) that is _TN itself and the variant carries no
-    ``tile_n``, as before the width entered; a = 960 tiles 51 200 rows
-    by 6 400, a = 2048 by 2 560. Without the dispatch shape (qb, a)
-    the width is unknown and the block stays _TN."""
+    ``tile_n``; a = 960 tiles 51 200 rows by 6 400, a = 2048 by 2 560.
+    Without the dispatch shape (qb, a) the width is unknown and the
+    block stays _TN."""
     v = tuned_variant(kc)
     if b % (128 * v["ne"]) != 0 and b % (128 * _E) == 0:
         v = {"tile_q": _TQ, "ne": _E, "unroll": 1}
@@ -215,52 +221,11 @@ def _heuristic_variant(kc: int, b: int, qb: int | None = None,
     return v
 
 
-def _resolve_variant(kc: int, b: int, qb: int | None = None,
-                     a: int | None = None,
-                     precision: str = "f32") -> dict:
-    """The variant actually used for (kc, b): the measured autotuner
-    cache entry when one exists for this (device kind, bucket(b),
-    bucket(a), kc, precision) (dmlp_tpu.tune.lookup_variant — never
-    raises, and rejects entries whose ne-alignment cannot tile this b),
-    else the deterministic heuristic. ``precision`` is a cache key
-    axis, never a tiling constraint: one bf16 pass, the split form's
-    three or an ``HIGHEST`` dot's six (_dot_cross) move the winning
-    tile but not what CAN tile, so the heuristic fallback is shared. When
-    the caller knows the full dispatch shape (qb, a), a cached variant
-    must ALSO pass variant_supports (VMEM bound included) or
-    resolution falls back — a cache entry may downgrade resolution to
-    the heuristic but can never flip supports() False and disable the
-    kernel. supports(), extract_topk, and the analytic cost model
-    (obs.kernel_cost) resolve through this same function with the same
-    shape arguments, so gate, kernel and counters can never
-    disagree."""
-    from dmlp_tpu.tune import lookup_variant
-    cached = lookup_variant(kc, b, a=a, precision=precision)
-    if cached is not None:
-        if qb is None or a is None \
-                or variant_supports(qb, b, a, kc, cached):
-            return cached
-    return _heuristic_variant(kc, b, qb, a)
-
-
-def resolve_variant(kc: int, b: int, qb: int | None = None,
-                    a: int | None = None,
-                    precision: str = "f32") -> dict:
-    """Public form of the variant resolution (engines/bench/tools report
-    it in spans and artifacts): the dict extract_topk will run with —
-    always carries tile_q/ne/unroll, plus tile_n when the tuner cache
-    pinned one. Pass the full (qb, a) dispatch shape where known so the
-    reported variant matches the kernel's own resolution exactly."""
-    return dict(_resolve_variant(kc, b, qb, a, precision))
-
-
 def variant_supports(qb: int, b: int, a: int, kc: int, v: dict) -> bool:
-    """supports() with an EXPLICIT variant — the gate the tuner sweep
-    shares with extract_topk's own validation, so the sweep can never
-    persist a variant the kernel would reject: whole lane-width
-    sub-blocks (b % (128 * ne)), query tiles of 8, kc no wider than one
-    block, and VMEM room for the distance scratch + double-buffered q/d
-    blocks."""
+    """supports() with an EXPLICIT variant — extract_topk's own
+    validation as a predicate: whole lane-width sub-blocks
+    (b % (128 * ne)), query tiles of 8, kc no wider than one block, and
+    VMEM room for the distance scratch + double-buffered q/d blocks."""
     if qb % 8 != 0 or b % (128 * v["ne"]) != 0:
         return False
     tn = _tile(b, v.get("tile_n", _TN), 128 * v["ne"])
@@ -272,10 +237,8 @@ def variant_supports(qb: int, b: int, a: int, kc: int, v: dict) -> bool:
 
 def supports(qb: int, b: int, a: int, kc: int) -> bool:
     """Shapes the kernel can tile WITH the variant resolved for this
-    full dispatch shape (tuner cache entry or heuristic — same
-    resolution extract_topk uses, VMEM-checked cache fallback
-    included)."""
-    return variant_supports(qb, b, a, kc, _resolve_variant(kc, b, qb, a))
+    full dispatch shape (the same resolution extract_topk uses)."""
+    return variant_supports(qb, b, a, kc, resolve_variant(kc, b, qb, a))
 
 
 #: first-pass forms the kernel knows (engine.finalize.LOWP_COEF has each
@@ -661,7 +624,7 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
 
     The data comes in one of two forms, told apart by its rank. A
     (B, A) block, as a caller that stages a chunk a call holds it (the
-    batch engines, the tune sweep). Or a RESIDENT (nchunks, B, A) stack
+    batch engines). Or a RESIDENT (nchunks, B, A) stack
     with ``chunk``, a traced index into it (the resident engines'
     fold): the index rides with ``n_real`` and ``id_base`` in the
     grid's scalar prefetch and the data BlockSpec's index map reads it,
@@ -684,19 +647,17 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     dist < floor are masked out (the multi-pass wide-k driver raises it
     to the previous pass's max − eps each pass).
 
-    tile_q/tile_n/ne/unroll default to the resolved variant (the tuner
-    cache entry when one exists, else the kc-tuned heuristic); pass them
-    explicitly only to override (the tune sweep does). The resolution
-    happens OUT HERE, before the jit boundary, so the CONCRETE variant
-    is part of the jit cache key — a cache update mid-process (a sweep
-    just ran) changes which compiled kernel the next call uses instead
-    of silently reusing a trace baked with the old variant.
+    tile_q/tile_n/ne/unroll default to the resolved variant
+    (resolve_variant); pass them explicitly only to override (a
+    resident engine's program passes the statics that key its jit, a
+    test a tiling of its own). The resolution happens OUT HERE, before
+    the jit boundary, so the CONCRETE variant is part of the jit cache
+    key.
     ``block_skip`` toggles the threshold-gated block prefilter
     (output-identical either way; off only for A/B measurement).
     ``mxu_gate`` enables the fused
     megakernel's norm-bound MXU tile gating (output-identical;
-    ops.pallas_fused.fused_topk is the public face, which also resolves
-    variants from the fused tune-cache namespace). ``precision``
+    ops.pallas_fused.fused_topk is the public face). ``precision``
     ("f32" | "bf16x3" | "bf16": PRECISIONS) selects the FIRST-PASS
     form (_dot_cross): "f32" is one ``HIGHEST`` dot, six MXU passes
     (measured on v5e, PR 36: PERF.md section 6); "bf16x3" splits the
@@ -723,8 +684,8 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         raise ValueError("a (nchunks, B, A) stack takes a chunk index, a "
                          f"(B, A) block none (got {d_attrs.shape}, "
                          f"chunk={chunk!r})")
-    v = _resolve_variant(kc, d_attrs.shape[-2], q_attrs.shape[0],
-                         q_attrs.shape[1], precision)
+    v = resolve_variant(kc, d_attrs.shape[-2], q_attrs.shape[0],
+                        q_attrs.shape[1])
     # Eager callers pass plain ints for the traced SMEM scalars; under
     # the sanitizer's transfer guard the jit argument conversion would
     # be an implicit host->device transfer — make it explicit here (a

@@ -47,12 +47,7 @@ resilience ladder's top ``lowp``/``prune`` rungs (resilience.degrade)
 and on exact mode — fast mode's output IS the device ordering and has no
 repair backstop, so it always scans densely.
 
-The scoring pass has its own tune-cache namespace (``prune_score``,
-:data:`PRUNE_KERNEL`): :func:`resolve_score_variant` reads a measured
-entry for the block-chunk tiling when one exists and otherwise uses
-the deterministic default, exactly the extract/fused resolution
-contract. Import-light: jax loads only when the device scorer is
-actually used.
+Import-light: jax loads only when the device scorer is actually used.
 """
 
 from __future__ import annotations
@@ -64,9 +59,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from dmlp_tpu.engine.finalize import lowp_eps, staging_eps
-
-#: tune-cache namespace of the block-scoring pass (dmlp_tpu.tune)
-PRUNE_KERNEL = "prune_score"
 
 #: sub-block pieces per block (median split on the max-spread
 #: attribute). Whole-block boxes go VACUOUS on uniform corpora — every
@@ -82,9 +74,8 @@ PRUNE_KERNEL = "prune_score"
 #: whole-block bounds are a sound (merely looser) fallback.
 PIECES = 2
 
-#: default host-scoring block chunk (blocks per vectorized slab) when
-#: no measured prune_score variant pins one: bounds the (Q, chunk, A)
-#: f64 temp at ~tens of MB for bench-scale query counts
+#: host-scoring block chunk (blocks per vectorized slab): bounds the
+#: (Q, chunk, A) f64 temp at ~tens of MB for bench-scale query counts
 _SCORE_BLOCK_CHUNK = 128
 
 
@@ -92,18 +83,6 @@ def prune_enabled() -> bool:
     """The prune-path kill switch ($DMLP_TPU_PRUNE=0 disables) — read
     per call so tests and operators can flip it without re-imports."""
     return os.environ.get("DMLP_TPU_PRUNE", "1") != "0"
-
-
-def resolve_score_variant(n_blocks: int, a: int) -> dict:
-    """Scoring-pass tiling: the measured ``prune_score`` tune-cache
-    entry when one exists (its ``tile_q`` is the host block-chunk),
-    else the deterministic default — an absent cache is bit-identical
-    CI, the shared resolution contract of every tuned kernel."""
-    from dmlp_tpu.tune import lookup_variant
-    cached = lookup_variant(8, n_blocks, a=a, kernel=PRUNE_KERNEL)
-    if cached is not None:
-        return dict(cached)
-    return {"tile_q": _SCORE_BLOCK_CHUNK, "ne": 1, "unroll": 1}
 
 
 @dataclasses.dataclass
@@ -258,8 +237,7 @@ def update_block(summ: BlockSummaries, b: int, rows: np.ndarray,
             summ.plo[b, p], summ.phi[b, p] = cl, ch
 
 
-def block_bounds(queries: np.ndarray, summ: BlockSummaries,
-                 block_chunk: Optional[int] = None
+def block_bounds(queries: np.ndarray, summ: BlockSummaries
                  ) -> Tuple[np.ndarray, np.ndarray]:
     """Per-(query, block) distance bounds, f64: ``lb[q, b]`` a LOWER
     bound on the squared distance from query q to ANY real row of
@@ -268,12 +246,12 @@ def block_bounds(queries: np.ndarray, summ: BlockSummaries,
     farthest-box-corner and norm-sum bounds; +inf for empty blocks).
     Chunked over blocks so the (Q, chunk, A) temp stays bounded."""
     q = np.asarray(queries, np.float64)
-    nq, na = q.shape
+    nq = q.shape[0]
     nb = summ.n_blocks
     qnorm = np.sqrt(np.einsum("qa,qa->q", q, q))
     lb = np.empty((nq, nb))
     ub = np.empty((nq, nb))
-    chunk = block_chunk or resolve_score_variant(nb, na)["tile_q"]
+    chunk = _SCORE_BLOCK_CHUNK
     for b0 in range(0, nb, chunk):
         b1 = min(b0 + chunk, nb)
         nmin, nmax = summ.nmin[b0:b1], summ.nmax[b0:b1]
@@ -296,8 +274,7 @@ def block_bounds(queries: np.ndarray, summ: BlockSummaries,
     return lb, ub
 
 
-def piece_bounds(queries: np.ndarray, summ: BlockSummaries,
-                 block_chunk: Optional[int] = None
+def piece_bounds(queries: np.ndarray, summ: BlockSummaries
                  ) -> Tuple[np.ndarray, np.ndarray]:
     """Per-(query, block, piece) bounds, f64: the block_bounds formulas
     over the PIECE norm bands / boxes. ``plb[q, b, p]`` lower-bounds
@@ -308,7 +285,7 @@ def piece_bounds(queries: np.ndarray, summ: BlockSummaries,
     non-vacuity the split buys. Requires the split format
     (``summ.pcounts is not None``)."""
     q = np.asarray(queries, np.float64)
-    nq_, na = q.shape
+    nq_ = q.shape[0]
     nb = summ.n_blocks
     npieces = summ.pcounts.shape[1]
     qnorm = np.sqrt(np.einsum("qa,qa->q", q, q))
@@ -316,8 +293,7 @@ def piece_bounds(queries: np.ndarray, summ: BlockSummaries,
     pub = np.empty((nq_, nb, npieces))
     # Same chunking as block_bounds, halved: the (Q, chunk, P, A) temp
     # is P times the whole-block slab.
-    chunk = block_chunk or max(
-        1, resolve_score_variant(nb, na)["tile_q"] // npieces)
+    chunk = max(1, _SCORE_BLOCK_CHUNK // npieces)
     for b0 in range(0, nb, chunk):
         b1 = min(b0 + chunk, nb)
         nmin, nmax = summ.pnmin[b0:b1], summ.pnmax[b0:b1]   # (c, P)

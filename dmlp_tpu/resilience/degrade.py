@@ -36,19 +36,15 @@ crashing, and every rung preserves the contract checksums exactly:
                     The ``DMLP_TPU_FUSED=0`` kill switch (mirroring
                     ``DMLP_TPU_RESILIENCE``) pins this rung to the
                     two-pass kernel without consuming a ladder step.
-4. ``tuned``      — the two-pass extraction kernel with the autotuner's
-                    cached variant (dmlp_tpu.tune): the fused kernel's
-                    (identical-size, but separately-tuned) tiles are
-                    the first thing to give back on a fused-path OOM.
-5. ``heuristic``  — the extraction kernel with the heuristic variant
-                    (tune-cache lookups suppressed): a swept variant's
-                    larger tiles are the next allocation to give back;
-                    results are bit-identical by the PR 3 contract.
-6. ``streaming``  — the chunked multipass streaming fold
+4. ``heuristic``  — the two-pass extraction kernel (the MXU gate off),
+                    at the tiles ops.pallas_extract.resolve_variant
+                    gives every dispatch: the fused kernel's per-block
+                    gate state is what a fused-path OOM gives back.
+5. ``streaming``  — the chunked multipass streaming fold
                     (engine.single._solve_pipelined): no running-list
                     kernel state, the live tile shrinks to one
                     (query_block x chunk) slab.
-7. ``host``       — the float64 golden solve on the host
+6. ``host``       — the float64 golden solve on the host
                     (golden.fast.knn_golden_fast): zero device memory;
                     it IS the oracle the contract diffs against, so
                     byte-identity is by construction.
@@ -66,8 +62,7 @@ from typing import Callable, List
 from dmlp_tpu.resilience import stats
 from dmlp_tpu.resilience.retry import classify, resilience_enabled
 
-RUNGS = ("lowp", "prune", "fused", "tuned", "heuristic", "streaming",
-         "host")
+RUNGS = ("lowp", "prune", "fused", "heuristic", "streaming", "host")
 
 
 @contextlib.contextmanager
@@ -78,29 +73,22 @@ def _rung_context(engine, rung: str):
     bound-based scan pruning, and only ``lowp`` may run the one-pass
     bf16 form; every rung runs the three-pass one) and by
     ops.pallas_fused.resolve_topk_kernel (the ``lowp``/
-    ``prune``/``fused`` rungs may dispatch the fused megakernel);
-    ``heuristic`` suppresses autotuner cache lookups for the
-    duration."""
+    ``prune``/``fused`` rungs may dispatch the fused megakernel)."""
     prev = getattr(engine, "_degrade_rung", "fused")
     engine._degrade_rung = rung
-    # Live rung gauge: numeric ladder position (0 = lowp ... 6 = host)
+    # Live rung gauge: numeric ladder position (0 = lowp ... 5 = host)
     # so a scrape mid-incident sees WHERE the solve currently sits.
     from dmlp_tpu.obs import telemetry
     telemetry.registry().gauge("resilience.degrade_rung").set(
         RUNGS.index(rung))
     try:
-        if rung == "heuristic":
-            from dmlp_tpu.tune import cache as tune_cache
-            with tune_cache.suppressed():
-                yield
-        else:
-            yield
+        yield
     finally:
         engine._degrade_rung = prev
 
 
 def _host_fallback(inp) -> List:
-    """Rung 4: the float64 host oracle (exact by construction)."""
+    """The last rung: the float64 host oracle (exact by construction)."""
     from dmlp_tpu.golden.fast import knn_golden_fast
     from dmlp_tpu.obs.trace import span as obs_span
     with obs_span("resilience.host_fallback",

@@ -33,7 +33,7 @@ loss); ``times`` bounds total fires (default 1), ``after`` skips the
 first N eligible hits, ``prob`` fires probabilistically — drawn from the
 schedule's own seeded PRNG in hit order, so runs are bit-reproducible —
 and ``when`` restricts to hits whose context matches (e.g.
-``{"step": 5}`` or ``{"rung": "tuned"}``).
+``{"step": 5}`` or ``{"rung": "heuristic"}``).
 
 Hooks are near-free when no schedule is installed: :func:`fire` is a
 module-global None check, exactly the obs.trace pattern.

@@ -9,7 +9,7 @@ ONCE and then serves query streams at throughput:
   parsed and staged once into a capacity-padded resident device buffer
   behind a row-count mask (incremental ingestion appends rows with NO
   recompilation), and each engine path is compiled once per
-  power-of-two (qpad, k) shape bucket (``tune.cache.shape_bucket`` is
+  power-of-two (qpad, k) shape bucket (``serve.engine.shape_bucket`` is
   the template) — ahead of the first request when warmed, with the
   compile counter proving steady-state serving never recompiles.
 - :mod:`dmlp_tpu.serve.batching` — continuous micro-batching: an

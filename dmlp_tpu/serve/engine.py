@@ -9,7 +9,7 @@ inherited unchanged:
 
 - **Resident corpus behind a row-count mask.** The corpus is staged to
   device ONCE at construction, padded to a power-of-two capacity
-  (``tune.cache.shape_bucket``); rows beyond ``n_real`` carry the
+  (:func:`shape_bucket`); rows beyond ``n_real`` carry the
   engines' standard ``id = -1`` sentinel, which every select path
   already masks to +inf. :meth:`ingest` appends rows by
   ``dynamic_update_slice`` into the fixed-shape buffer (row counts
@@ -102,7 +102,6 @@ from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.ops.topk import TopK
 from dmlp_tpu.resilience import degrade as rs_degrade
-from dmlp_tpu.tune.cache import shape_bucket
 
 
 class CapacityError(RuntimeError):
@@ -113,6 +112,14 @@ class CapacityError(RuntimeError):
 class RequestShapeError(ValueError):
     """A request shape the resident engine cannot serve (k beyond the
     serving cap) — admission rejects these before the solve."""
+
+
+def shape_bucket(b: int) -> int:
+    """Row count -> power-of-two bucket (the smallest power of two
+    >= b). 12800 and 16000 share a bucket; 12800 and 51200 do not."""
+    if b <= 1:
+        return 1
+    return 1 << (b - 1).bit_length()
 
 
 def query_bucket(nq: int, granule: int = 8) -> int:
@@ -166,9 +173,8 @@ def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
     (qb, b, a): exactly what ``fused_topk`` / ``extract_topk`` would
     resolve for themselves, made concrete here so that it keys the
     enclosing program's jit cache."""
-    from dmlp_tpu.ops import pallas_fused
-    from dmlp_tpu.ops.pallas_extract import _TN
-    v = pallas_fused.variant_for(impl, kc, b, qb, a, precision)
+    from dmlp_tpu.ops.pallas_extract import _TN, resolve_variant
+    v = resolve_variant(kc, b, qb, a)
     return dict(kc=kc, interpret=interpret, tile_q=v["tile_q"],
                 tile_n=v.get("tile_n", _TN), ne=v["ne"],
                 unroll=v["unroll"], mxu_gate=impl == "fused",
@@ -1255,14 +1261,14 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         q[:inp.params.num_queries, :self.num_attrs] = inp.query_attrs
         return stage_put(q, self._staging)
 
-    def _variant_stamp(self, impl: str, kc: int, qpad: int,
+    def _variant_stamp(self, kc: int, qpad: int,
                        prec: str) -> Dict[str, Any]:
         """The kernel variant a fold of the resident stack runs with
         (pallas_fused.variant_stamp at the stack's dispatch shape),
         with the width the stack holds a row at."""
         from dmlp_tpu.ops import pallas_fused
         return {**pallas_fused.variant_stamp(
-            impl, kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec,
+            kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec,
             self._staging), "a_pad": self._ex_attrs, "norms": "staged"}
 
     def _fold_resident(self, q_dev, order, impl: str, kc: int,
@@ -1315,7 +1321,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             pend.select = "extract"
             pend.extract_impl = impl
             pend.variant = self._variant_stamp(
-                impl, entry.kcap, entry.qpad, prec)
+                entry.kcap, entry.qpad, prec)
         clock = time.perf_counter
         with obs_span("serve.solve_extract", qpad=entry.qpad,
                       kcap=entry.kcap, impl=impl,
@@ -1410,7 +1416,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             full_rows = self._ex_nchunks * cr
             _kern_full, impl_full = resolve_sweep_kernel(
                 entry.qpad, full_rows, self._ex_attrs, kc, chunk_rows=cr,
-                rung=self._degrade_rung, precision=prec)
+                rung=self._degrade_rung)
             npasses = -(-kcap // kc)
             nq = inp.params.num_queries
             na = self.num_attrs
@@ -1419,7 +1425,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             q_dev = self._stage_batch_queries(inp, entry.qpad)
             pend.select = "extract"
             pend.extract_impl = impl
-            pend.variant = self._variant_stamp(impl, kc, entry.qpad, prec)
+            pend.variant = self._variant_stamp(kc, entry.qpad, prec)
             sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
                                     self._ex_attrs, prec, self._interpret)
             floor_args = dict(staging=self._staging, na=na,

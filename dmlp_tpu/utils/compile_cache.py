@@ -1,5 +1,5 @@
 """Persistent XLA compilation cache — one rule, applied at every entry
-point (engine CLI, serve daemon, train loop, tuner) before the
+point (engine CLI, serve daemon, train loop) before the
 first compile:
 
 - ``$JAX_COMPILATION_CACHE_DIR`` set: jax itself reads it at import and

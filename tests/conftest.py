@@ -15,16 +15,7 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
-# The suite must be hermetic w.r.t. the autotuner's variant cache: with
-# the default path a developer who ever ran `python -m dmlp_tpu.tune` on
-# this machine would silently flip every extract test onto their swept
-# variants (~/.cache/dmlp_tpu/extract_variants.json). Point the lookup
-# at a path that cannot exist; tests that exercise the cache override
-# this per-test (monkeypatch.setenv + tune.clear_lookup_memo).
-os.environ["DMLP_TPU_TUNE_CACHE"] = os.path.join(
-    os.sep, "nonexistent", "dmlp-tpu-test-tune-cache.json")
-
-# Same hermeticity for the static-analysis fingerprint cache
+# The suite must be hermetic w.r.t. the static-analysis fingerprint cache
 # (dmlp_tpu.check.cache): tests that shell out to `python -m
 # dmlp_tpu.check` must neither read a developer's warm ~/.cache verdict
 # nor pollute it with fixture-tree entries. Content-hash keying makes

@@ -194,8 +194,7 @@ class TestDispatchCost:
             from dmlp_tpu.ops.pallas_fused import fused_topk
 
             def drive(eng, q, d):
-                mi = MeasuredIters(eng, "s", (1, 2, 3, 4),
-                                   kernel="fused")
+                mi = MeasuredIters(eng, "s", (1, 2, 3, 4))
                 obs_counters.record_dispatch(fused_topk, (q, d), site="s")
                 od, oi, it = fused_topk(q, d, n_real=4, kc=8)
                 mi.add(it)
@@ -357,14 +356,14 @@ class TestR2Recompile:
         assert run_check(tmp_path, ["R2"]) == []
 
     def test_r203_variant_resolution_inside_jit(self, tmp_path):
-        # The PR 3 review bug, reduced: lookup_variant consulted inside
-        # the traced body -> stale-trace reuse after a cache update.
+        # The PR 3 review bug, reduced: the variant resolved inside the
+        # traced body -> baked into a trace the jit keeps replaying.
         write(tmp_path, "dmlp_tpu/ops/x.py", """
             import jax
-            from dmlp_tpu.tune import lookup_variant
+            from dmlp_tpu.ops.pallas_extract import resolve_variant
             @jax.jit
             def f(x):
-                v = lookup_variant(8, x.shape[0])
+                v = resolve_variant(8, x.shape[0])
                 return x * v["ne"]
         """)
         assert "R203" in rules_of(run_check(tmp_path, ["R2"]))
@@ -372,12 +371,12 @@ class TestR2Recompile:
     def test_r203_resolution_outside_jit_clean(self, tmp_path):
         write(tmp_path, "dmlp_tpu/ops/x.py", """
             import jax
-            from dmlp_tpu.tune import lookup_variant
+            from dmlp_tpu.ops.pallas_extract import resolve_variant
             @jax.jit
             def _impl(x, ne):
                 return x * ne
             def f(x):
-                v = lookup_variant(8, x.shape[0])
+                v = resolve_variant(8, x.shape[0])
                 return _impl(x, v["ne"])
         """)
         assert run_check(tmp_path, ["R2"]) == []

@@ -93,7 +93,7 @@ def test_check_stamp_names_every_miss():
             "peak_flops_known": True, "device_count": 4, "mesh": [4, 1],
             "select": "extract", "extract_impl": "fused",
             "pallas_interpret": False, "degrade_rung": None,
-            "kernel_variant": {"tile_q": 64, "from_tune_cache": False},
+            "kernel_variant": {"tile_q": 64},
             "degradations": [], "retries": 0,
             "corpus_rows_per_device": {str(d): 51200 for d in range(4)}}
     assert cs.check_stamp(good, [4, 1], False, 200000) == []
@@ -102,12 +102,10 @@ def test_check_stamp_names_every_miss():
     assert "not one equal share" in cs.check_stamp(
         one, [4, 1], False, 200000)[0]
     bad = dict(good, degrade_rung="host", degradations=["lowp->prune"],
-               retries=2, extract_impl="extract",
-               kernel_variant={"from_tune_cache": True})
+               retries=2, extract_impl="extract")
     misses = " | ".join(cs.check_stamp(bad, [4, 1], True, 200000))
     for want in ("degrade rung is host", "degradations recorded",
-                 "retries recorded: 2", "extract_impl is extract",
-                 "committed heuristic"):
+                 "retries recorded: 2", "extract_impl is extract"):
         assert want in misses
     assert cs.check_stamp(None, None, True, 1) == [
         "child wrote no device stamp"]
@@ -127,7 +125,7 @@ def test_batch_f32_names_a_first_pass_that_did_not_split(
              "peak_flops_known": True, "device_count": 1, "mesh": None,
              "select": "extract", "extract_impl": "fused",
              "pallas_interpret": False, "degrade_rung": "lowp",
-             "kernel_variant": {"tile_q": 64, "from_tune_cache": False},
+             "kernel_variant": {"tile_q": 64},
              "degradations": [], "retries": 0}
 
     def child(argv, stdin_path, out_path, err_path):
@@ -247,8 +245,7 @@ def test_batch_phases_name_a_cross_term_of_other_passes(
              "peak_flops_known": True, "device_count": 1, "mesh": None,
              "select": "extract", "extract_impl": "fused",
              "pallas_interpret": False, "degrade_rung": "lowp",
-             "kernel_variant": {"tile_q": 64, "from_tune_cache": False,
-                                "mxu_passes": got},
+             "kernel_variant": {"tile_q": 64, "mxu_passes": got},
              "degradations": [], "retries": 0}
 
     def child(argv, stdin_path, out_path, err_path):
@@ -411,7 +408,8 @@ def test_cli_summary_says_where_it_ran(tmp_path):
     assert stamp["extract_impl"] == "fused"
     assert stamp["degrade_rung"] == "lowp"
     assert stamp["degradations"] == [] and stamp["retries"] == 0
-    assert stamp["kernel_variant"]["from_tune_cache"] is False
+    assert set(stamp["kernel_variant"]) == {
+        "tile_q", "ne", "unroll", "kc", "mxu_passes"}
     assert summary["parser"] == "python"
     assert set(summary["compile_cache"]) == {
         "dir", "requests", "hits", "misses", "backend_compile_ms"}

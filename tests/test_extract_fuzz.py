@@ -351,15 +351,15 @@ def test_extract_kernel_tie_rows_straddling_block_boundary():
 
 
 def test_extract_engine_tie_heavy_dup_rows_block_boundaries_vs_golden(
-        tmp_path, monkeypatch):
-    """Engine-level tie regression for block skipping: a tuner cache
-    entry pins a small tile_n (many in-kernel block boundaries), the
+        monkeypatch):
+    """Engine-level tie regression for block skipping: the resolver is
+    made to give a small tile_n (many in-kernel block boundaries), the
     dataset repeats whole row-groups so tie groups straddle those
     boundaries, and the full run() must still equal the float64 golden
     model exactly — block skipping cannot silently change
     lowest-global-position tie breaking."""
     from dmlp_tpu.engine.single import resolve_kcap
-    from dmlp_tpu.tune import VariantCache, clear_lookup_memo
+    from dmlp_tpu.ops import pallas_extract
 
     rng = np.random.default_rng(91)
     n_base, nq, na = 160, 14, 3
@@ -369,27 +369,16 @@ def test_extract_engine_tie_heavy_dup_rows_block_boundaries_vs_golden(
     queries = rng.integers(0, 3, (nq, na)).astype(np.float64)
     labels = rng.integers(0, 4, n).astype(np.int32)
     # kmax stays small so kcap (40) fits the pinned tile_n — a wider k
-    # would route to multipass at a different kcap and the cache entry
-    # would never resolve, making the whole test vacuous.
+    # would route to multipass, whose lists the pinned block cannot hold.
     ks = rng.integers(1, 33, nq).astype(np.int32)
     inp = KNNInput(Params(n, nq, na), labels, data, ks, queries)
 
     kc = resolve_kcap(EngineConfig(), int(ks.max()), "extract", 1 << 30,
                       staging="float32")
     pinned = {"tile_q": 32, "tile_n": 256, "ne": 2, "unroll": 1}
-    assert kc <= pinned["tile_n"]          # the entry must be resolvable
-    path = str(tmp_path / "variants.json")
-    monkeypatch.setenv("DMLP_TPU_TUNE_CACHE", path)
-    cache = VariantCache()
-    # the engine prefers the fused megakernel (fused_topk namespace) —
-    # pin BOTH namespaces so the multi-block variant drives whichever
-    # kernel the dispatch resolves
-    # (under the form the exact engine runs: the split "bf16x3")
-    cache.put("cpu", 12800, kc, pinned, a=na, precision="bf16x3")
-    cache.put("cpu", 12800, kc, pinned, a=na, kernel="fused_topk",
-              precision="bf16x3")
-    cache.save(path)
-    clear_lookup_memo()
+    assert kc <= pinned["tile_n"]          # the kernel must take the tiles
+    monkeypatch.setattr(pallas_extract, "resolve_variant",
+                        lambda kc, b, qb=None, a=None: dict(pinned))
     from dmlp_tpu.obs import trace as obs_trace
     tracer = obs_trace.install(obs_trace.Tracer())
     try:
@@ -398,12 +387,12 @@ def test_extract_engine_tie_heavy_dup_rows_block_boundaries_vs_golden(
         got = eng.run(inp)
     finally:
         obs_trace.uninstall()
-        clear_lookup_memo()
     assert eng._last_select == "extract"
     # prove the pinned multi-block variant actually drove the kernel
     spans = [e for e in tracer.to_dict()["traceEvents"]
              if e.get("name") == "single.enqueue_extract"]
     assert spans and spans[0]["args"]["variant"] == pinned
+    assert eng.last_variant["tile_n"] == 256
     assert_same_results(got, knn_golden(inp), check_dists=False)
 
 

@@ -155,10 +155,9 @@ def test_resolve_topk_kernel_degrade_rung_pins_two_pass():
     must dispatch the two-pass kernel even with the switch on."""
     from dmlp_tpu.ops import pallas_fused
 
-    for rung in ("tuned", "heuristic"):
-        _, impl = pallas_fused.resolve_topk_kernel(128, 12800, 8, 32,
-                                                   rung=rung)
-        assert impl == "extract", rung
+    _, impl = pallas_fused.resolve_topk_kernel(128, 12800, 8, 32,
+                                               rung="heuristic")
+    assert impl == "extract"
 
 
 def test_resolve_topk_kernel_unsupported_shape_falls_through():
@@ -248,7 +247,7 @@ def test_sharded_engine_fused_on_off_byte_identical(monkeypatch):
 
 def test_fused_rung_degrades_to_two_pass_on_oom(monkeypatch, tmp_path):
     """Resilience integration: a fused-path OOM steps the ladder down
-    to the tuned two-pass kernel (one rung, not a crash), the degrade
+    to the two-pass kernel (one rung, not a crash), the degrade
     event lands in the resilience stats block, and the output is
     byte-identical to the unfaulted run."""
     import json
@@ -276,11 +275,11 @@ def test_fused_rung_degrades_to_two_pass_on_oom(monkeypatch, tmp_path):
         inject.uninstall()
         monkeypatch.delenv("DMLP_TPU_FAULTS")
     assert got == golden
-    assert eng.last_degrade_rung == "tuned"
+    assert eng.last_degrade_rung == "heuristic"
     assert eng.last_extract_impl == "extract"
     snap = stats.snapshot()["degradations"]
     assert "lowp->prune" in snap and "prune->fused" in snap \
-        and "fused->tuned" in snap
+        and "fused->heuristic" in snap
 
 
 # -- analytic cost model -----------------------------------------------------

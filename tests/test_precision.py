@@ -439,9 +439,9 @@ def test_only_a_run_hands_its_solve_a_form_that_drops_products(
     seen = []
     real = pallas_fused.variant_stamp
 
-    def spy(impl, kc, b, qb, a, precision="f32", staging="float32"):
+    def spy(kc, b, qb, a, precision="f32", staging="float32"):
         seen.append(precision)
-        return real(impl, kc, b, qb, a, precision, staging)
+        return real(kc, b, qb, a, precision, staging)
 
     monkeypatch.setattr(pallas_fused, "variant_stamp", spy)
     inp = _case(404)
@@ -452,7 +452,7 @@ def test_only_a_run_hands_its_solve_a_form_that_drops_products(
     eng.run(inp)
     assert seen[2:] == ["bf16x3"] and \
         eng.last_precision["active"] == "bf16x3"
-    for rung in ("lowp", "prune", "fused", "tuned", "heuristic"):
+    for rung in ("lowp", "prune", "fused", "heuristic"):
         with degrade._rung_context(eng, rung):
             assert active_precision(eng) == "bf16x3", rung
     # the one-pass form gives way to the float32 FORM below its rung

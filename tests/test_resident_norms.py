@@ -103,7 +103,7 @@ def test_the_batch_engines_kernel_still_computes_its_norms():
     q = d[:16]
     od, oi, _ = fused_topk(q, d, n_real=ROWS, kc=32, interpret=True)
     assert np.array_equal(np.sort(np.asarray(od), 1)[:, 0], np.zeros(16))
-    stamp = variant_stamp("fused", 32, ROWS, 16, 128)
+    stamp = variant_stamp(32, ROWS, 16, 128)
     assert _variant_args(stamp)["norms"] == "computed"
     assert _variant_args({**stamp, "norms": "staged"})["norms"] == "staged"
     assert _variant_args(None) == {}

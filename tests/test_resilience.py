@@ -327,18 +327,17 @@ def test_ladder_steps_down_per_oom():
 
     def solve(inp):
         seen.append(eng._degrade_rung)
-        if len(seen) < 6:
+        if len(seen) < 5:
             raise SimulatedResourceExhausted("RESOURCE_EXHAUSTED")
         return "answer"
 
     assert degrade.run_ladder(eng, None, solve) == "answer"
-    assert seen == ["lowp", "prune", "fused", "tuned", "heuristic",
-                    "streaming"]
+    assert seen == ["lowp", "prune", "fused", "heuristic", "streaming"]
     assert eng.last_degrade_rung == "streaming"
     assert eng._degrade_rung == "fused"       # restored after the run
     assert stats.snapshot()["degradations"] == \
-        ["lowp->prune", "prune->fused", "fused->tuned",
-         "tuned->heuristic", "heuristic->streaming"]
+        ["lowp->prune", "prune->fused", "fused->heuristic",
+         "heuristic->streaming"]
 
 
 def test_ladder_propagates_non_oom():
@@ -350,24 +349,6 @@ def test_ladder_propagates_non_oom():
     with pytest.raises(ValueError):
         degrade.run_ladder(eng, None, solve)
     assert stats.snapshot()["degradations"] == []
-
-
-def test_ladder_heuristic_rung_suppresses_tune_cache():
-    from dmlp_tpu.tune import cache as tune_cache
-    eng = _FakeEngine()
-    seen = []
-
-    def solve(inp):
-        seen.append(tune_cache.lookup_variant(32, 1024, a=8))
-        if len(seen) <= 3:
-            raise SimulatedResourceExhausted("RESOURCE_EXHAUSTED")
-        return "ok"
-
-    degrade.run_ladder(eng, None, solve)
-    # The prune/fused/tuned rungs may consult the cache (None here:
-    # conftest pins a nonexistent path); the heuristic rung must not
-    # even try.
-    assert len(seen) == 4 and seen[3] is None
 
 
 # -- engine-level byte-identical recovery ------------------------------------
@@ -396,10 +377,9 @@ def test_engine_recovers_transients_byte_identical():
 
 @pytest.mark.parametrize("times,rung", [(1, "prune"),
                                         (2, "fused"),
-                                        (3, "tuned"),
-                                        (4, "heuristic"),
-                                        (5, "streaming"),
-                                        (6, "host")])
+                                        (3, "heuristic"),
+                                        (4, "streaming"),
+                                        (5, "host")])
 def test_engine_ladder_byte_identical(times, rung):
     inp = _small_input()
     golden = format_results(knn_golden(inp))

@@ -28,7 +28,8 @@ from dmlp_tpu.serve.admission import AdmissionController
 from dmlp_tpu.serve.batching import MicroBatcher, Request
 from dmlp_tpu.serve.daemon import ServeDaemon
 from dmlp_tpu.serve.engine import (CapacityError, RequestShapeError,
-                                   ResidentEngine, k_bucket, query_bucket)
+                                   ResidentEngine, k_bucket, query_bucket,
+                                   shape_bucket)
 
 
 def make_corpus(n=600, na=5, labels=4, seed=3) -> KNNInput:
@@ -52,6 +53,13 @@ def solo_and_golden(corpus: KNNInput, q, ks, config=None):
 
 
 # -- buckets ------------------------------------------------------------------
+
+def test_shape_bucket_keying():
+    assert [shape_bucket(b) for b in (0, 1, 2, 3)] == [1, 1, 2, 4]
+    assert shape_bucket(12800) == shape_bucket(16000) == 16384
+    assert shape_bucket(16384) == 16384 and shape_bucket(16385) == 32768
+    assert shape_bucket(51200) == 65536
+
 
 def test_shape_buckets_are_powers_of_two():
     assert [query_bucket(v) for v in (1, 7, 8, 9, 17)] == \

@@ -582,7 +582,7 @@ class TestSession:
         rs_stats.reset()
         rs_stats.record_retry("single.stage_put")
         rs_stats.record_retry("single.stage_put")
-        rs_stats.record_degradation("fused", "tuned")
+        rs_stats.record_degradation("fused", "heuristic")
         rs_stats.record_rollback()
         # one source of truth: the registry counters ARE the snapshot
         assert telemetry.REGISTRY.counter(
@@ -590,7 +590,7 @@ class TestSession:
         snap = rs_stats.snapshot()
         assert snap["retries"] == 2
         assert snap["retry_sites"] == {"single.stage_put": 2}
-        assert snap["degradations"] == ["fused->tuned"]
+        assert snap["degradations"] == ["fused->heuristic"]
         assert snap["rollbacks"] == 1
         assert rs_stats.any_activity()
         rs_stats.reset()

@@ -438,8 +438,7 @@ def test_wide_k_programs_compile_for_v5e(v5e, precision):
     assert (k_bucket(1000), kcap, -(-kcap // 512)) == (1024, 1152, 3)
     rows = 82 * 51200
     _kern, impl = resolve_sweep_kernel(1024, rows, 128, 512,
-                                       chunk_rows=51200, rung="fused",
-                                       precision=precision)
+                                       chunk_rows=51200, rung="fused")
     fold = _kernel_statics("fused", 512, 51200, 1024, 128, precision,
                            False)
     sweep = _kernel_statics(impl, 512, rows, 1024, 128, precision, False)

@@ -224,6 +224,41 @@ def test_package_does_not_import(outside):
     assert not bad, f"dmlp_tpu imports {outside}: {bad}"
 
 
+# -- (d1) the kernel's tiles come from the checkout alone ----------------------
+
+def test_no_tune_cache_and_the_resolver_reads_nothing_outside_its_arguments():
+    """The tiles a dispatch runs with are ``ops.pallas_extract
+    .resolve_variant`` of its shape: no file outside the checkout, no
+    variable, can pick another kernel for a cell (PR 43 deleted the tune
+    cache that could). The environment switches that remain
+    (DMLP_TPU_FUSED, DMLP_TPU_PRUNE) live in pallas_fused / summaries."""
+    gone = ("DMLP_TPU_TUNE_CACHE", "lookup_variant", "dmlp_tpu.tune",
+            "dmlp_tpu/tune")
+    assert not os.path.exists(os.path.join(ROOT, "dmlp_tpu", "tune"))
+    files = [os.path.join(ROOT, f) for f in ("chip_smoke.py", "Makefile",
+                                             "tests/conftest.py")]
+    for rel_dir in ("dmlp_tpu", "tools"):
+        for base, _dirs, names in os.walk(os.path.join(ROOT, rel_dir)):
+            if "__pycache__" not in base:
+                files += [os.path.join(base, f) for f in names]
+    bad = []
+    for path in files:
+        with open(path, errors="ignore") as f:
+            text = f.read()
+        bad += [f"{os.path.relpath(path, ROOT)}: {name}"
+                for name in gone if name in text]
+    assert not bad, bad
+    tree = ast.parse(_read("dmlp_tpu/ops/pallas_extract.py"))
+    modules = {a.name.split(".")[0] for node in ast.walk(tree)
+               if isinstance(node, ast.Import) for a in node.names} | {
+        (node.module or "").split(".")[0] for node in ast.walk(tree)
+        if isinstance(node, ast.ImportFrom)}
+    assert not modules & {"os", "io", "pathlib", "json"}
+    named = {n.id for n in ast.walk(tree) if isinstance(n, ast.Name)} | {
+        n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)}
+    assert not named & {"open", "environ", "getenv"}
+
+
 # -- (d2) one resident fold body -----------------------------------------------
 
 def _imported_names(tree):

@@ -235,29 +235,30 @@ def _untileable_sweep(monkeypatch, full_rows: int):
     """The variant of the whole-array row count alone becomes one whose
     sub-blocks (7 a block, 896 rows) cannot tile it; a chunk's stays as
     it is."""
-    from dmlp_tpu.ops import pallas_fused
-    real = pallas_fused.variant_for
+    from dmlp_tpu.ops import pallas_extract
+    real = pallas_extract.resolve_variant
 
-    def variant_for(impl, kc, b, qb=None, a=None, precision="f32"):
-        v = dict(real(impl, kc, b, qb, a, precision))
+    def resolve_variant(kc, b, qb=None, a=None):
+        v = real(kc, b, qb, a)
         if b == full_rows:
             v["ne"] = 7
         return v
-    monkeypatch.setattr(pallas_fused, "variant_for", variant_for)
+    monkeypatch.setattr(pallas_extract, "resolve_variant", resolve_variant)
 
 
 def test_resident_sweep_assertion_fires_before_any_dispatch(monkeypatch):
     from dmlp_tpu.serve import engine as serve_engine
     from dmlp_tpu.serve.engine import ResidentEngine
     rng = np.random.default_rng(5)
-    corpus = KNNInput(Params(1408, 0, 4),
-                      rng.integers(0, 4, 1408).astype(np.int32),
-                      rng.uniform(0, 60, (1408, 4)),
+    # (two chunks: the sweep's row count is not a chunk's)
+    corpus = KNNInput(Params(14080, 0, 4),
+                      rng.integers(0, 4, 14080).astype(np.int32),
+                      rng.uniform(0, 60, (14080, 4)),
                       np.zeros(0, np.int32), np.zeros((0, 4)))
     eng = ResidentEngine(corpus, EngineConfig(
         select="extract", use_pallas=True, data_block=512))
     full_rows = eng._ex_nchunks * eng._ex_chunk_rows
-    assert full_rows % (128 * 7) != 0
+    assert eng._ex_nchunks == 2 and full_rows % (128 * 7) != 0
     _untileable_sweep(monkeypatch, full_rows)
     dispatched = []
     monkeypatch.setattr(serve_engine, "_fold_stack",

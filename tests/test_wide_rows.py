@@ -1,7 +1,7 @@
 """Rows wider than one lane vector on the extract path (PR 31).
 
 The kernel's data block follows the row width
-(``ops.pallas_extract._heuristic_variant``), the resident stack holds a
+(``ops.pallas_extract.resolve_variant``), the resident stack holds a
 row on whole lanes (``lane_padded``), and everything a client or the
 benchmark sees keeps the corpus' own width. On the CPU the kernel runs
 in interpret mode, so these tests hold the path and the answers, not a
@@ -24,8 +24,8 @@ from dmlp_tpu.ops.pallas_extract import (_TN, lane_padded, variant_supports,
                                          vmem_bytes)
 from dmlp_tpu.serve.engine import ResidentEngine
 
-#: the variants the committed heuristic resolved before the width
-#: entered it: what the accepted cells' Mosaic programs were built from
+#: the variants the rule gave before the width entered it: what the
+#: accepted cells' Mosaic programs were built from
 NARROW = {"tile_q": 128, "ne": 2, "unroll": 1}
 WIDE_K = {"tile_q": 64, "ne": 4, "unroll": 1}
 
@@ -46,9 +46,6 @@ def corpus_of(n: int, na: int, seed: int) -> KNNInput:
 
 # -- the rule ------------------------------------------------------------------
 
-@pytest.mark.parametrize("resolver", [pallas_extract._resolve_variant,
-                                      pallas_fused._resolve_variant],
-                         ids=["extract", "fused"])
 @pytest.mark.parametrize("kc,b,qb,a,want", [
     (32, 51200, 1024, 128, NARROW),    # bigann.bulk, bigann-mesh4.bulk
     (32, 51200, 128, 128, NARROW),     # bigann.steady's buckets
@@ -60,8 +57,8 @@ def corpus_of(n: int, na: int, seed: int) -> KNNInput:
 ], ids=["bulk", "steady128", "steady256", "steady512", "config4", "toy",
         "a512"])
 def test_narrow_rows_resolve_to_the_variants_they_always_did(
-        monkeypatch, resolver, kc, b, qb, a, want):
-    monkeypatch.setenv("DMLP_TPU_TUNE_CACHE", "/nonexistent/variants.json")
+        kc, b, qb, a, want):
+    resolver = pallas_extract.resolve_variant
     assert resolver(kc, b, qb, a) == want      # no tile_n key at all
     assert resolver(kc, b) == want             # shape unknown: as before
 
@@ -69,11 +66,10 @@ def test_narrow_rows_resolve_to_the_variants_they_always_did(
 @pytest.mark.parametrize("a,tile_n", [(513, 10240), (960, 6400),
                                       (1024, 6400), (2048, 2560),
                                       (4096, 1280)])
-def test_the_data_block_follows_the_width(monkeypatch, a, tile_n):
-    monkeypatch.setenv("DMLP_TPU_TUNE_CACHE", "/nonexistent/variants.json")
-    v = pallas_fused._resolve_variant(32, 51200, 1024, a)
+def test_the_data_block_follows_the_width(a, tile_n):
+    v = pallas_extract.resolve_variant(32, 51200, 1024, a)
     assert v == {**NARROW, "tile_n": tile_n}
-    assert pallas_fused.supports(1024, 51200, a, 32)
+    assert pallas_extract.supports(1024, 51200, a, 32)
     kern, impl = pallas_fused.resolve_topk_kernel(1024, 51200, a, 32)
     assert impl == "fused" and kern is pallas_fused.fused_topk
     # the largest tile of 51 200 rows that fits: the next one up does not
@@ -135,7 +131,7 @@ def test_served_extract_path_is_exact_at_every_width(na):
     assert eng._chunks.shape == (2, 12800, lane_padded(na))
     assert eng.bucket_stats()["extract_chunks"] == 2
     v = eng.last_variant
-    assert v["a_pad"] == lane_padded(na) and v["from_tune_cache"] is False
+    assert v["a_pad"] == lane_padded(na) and v["norms"] == "staged"
     assert v.get("tile_n", _TN) == {128: _TN, 960: 6400, 2048: 2560}[na]
 
     inp = KNNInput(Params(n, nq, na), corpus.labels, corpus.data_attrs,

@@ -108,9 +108,9 @@ def make_schedule(kind: str, seed: int) -> dict:
             {"site": "io.parse", "kind": "corrupt"},
         ]
     elif kind == "oom":
-        # times = how deep the ladder steps from the prune top rung:
-        # 1 -> dense fused, 2 -> tuned two-pass kernel,
-        # 3 -> heuristic variant.
+        # times = how deep the ladder steps from its top (lowp) rung:
+        # 1 -> pruned f32, 2 -> dense fused, 3 -> two-pass kernel
+        # (heuristic).
         faults = [{"site": "single.stage_put", "kind": "oom",
                    "times": rng.randint(1, 3)}]
     else:
