@@ -7,17 +7,22 @@ Three numbers, each beside its limit in every run's output:
   configurations guarantee exact answers.
 - ``dist_rel_err_max``: over the sampled answers that carry distances
   (every batch answer; served answers of ``debug`` requests), the
-  largest |served - reference| / reference. An ordering taken from a
-  float32 pass is right on almost every query at these sizes, so the
-  checksums alone would let it through; its distances are off by 1e-7.
+  largest |served - reference| / scale, the scale the reference's to
+  state (``dist_scale``; ``max(|reference|, tiny)`` where it states
+  none). An ordering taken from a float32 pass is right on almost every
+  query at these sizes, so the checksums alone would let it through;
+  its distances are off by 1e-7.
 - ``reference_plain_mismatches``: the reference the other two are held
-  to is ``reference.knn_exact``, a screened search; a few of the sampled
-  queries, drawn from the seed, are searched again by the plain brute
-  force ``reference.knn_plain`` at the cell's own size, and the two must
-  give the same label, ids, checksum and distances. Limit 0.
+  to is its ``knn_exact``, a screened search; a few of the sampled
+  queries, drawn from the seed, are searched again by its plain brute
+  force ``knn_plain`` at the cell's own size, and the two must give the
+  same label, ids, checksum and distances. Limit 0.
 
-Samples are drawn from the seed once the window has closed, from what
-the window itself produced, the longest request among them.
+The reference is the module the cell's configuration names
+(``spec.Cell.reference``: ``references/<name>.py``, or
+``benchmark/reference.py`` where it names none); nothing here imports
+one. Samples are drawn from the seed once the window has closed, from
+what the window itself produced, the longest request among them.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from benchmark import data, reference
+from benchmark import data
 
 
 def sample_requests(records: Sequence[Dict[str, Any]], requests: int,
@@ -58,28 +63,37 @@ def sample_requests(records: Sequence[Dict[str, Any]], requests: int,
     return out
 
 
-def _rel_err(got: np.ndarray, want: np.ndarray) -> float:
+def dist_scale(want: np.ndarray) -> np.ndarray:
+    """The denominator of ``dist_rel_err_max`` under a reference that
+    states none: the reference's own distance, which squared L2 keeps
+    above zero wherever the query is not a row."""
+    return np.maximum(np.abs(want), np.finfo(np.float64).tiny)
+
+
+def _rel_err(got: np.ndarray, want: np.ndarray, scale=dist_scale) -> float:
     both = np.isfinite(want) & np.isfinite(got)
     if (np.isfinite(want) != np.isfinite(got)).any():
         return float("inf")
     if not both.any():
         return 0.0
-    scale = np.maximum(np.abs(want[both]), np.finfo(np.float64).tiny)
-    return float((np.abs(got[both] - want[both]) / scale).max())
+    return float((np.abs(got[both] - want[both])
+                  / scale(want[both])).max())
 
 
 class Verdict:
     """Accumulates the compared numbers; ``lines()`` prints each beside
-    its limit, ``correct`` holds them all."""
+    its limit, ``correct`` holds them all. ``scale`` is the reference
+    module's ``dist_scale`` where it has one."""
 
-    def __init__(self, limits: Dict[str, float]):
+    def __init__(self, limits: Dict[str, float], scale=dist_scale):
         self.limits = limits
+        self.scale = scale
         self.queries = self.mismatches = self.distances = 0
         self.plain = self.plain_mismatches = 0
         self.rel_err = 0.0
 
-    def add(self, ref: reference.Answer, label: int, checksum: int,
-            dists=None) -> None:
+    def add(self, ref, label: int, checksum: int, dists=None) -> None:
+        """One served answer against the reference's ``Answer``."""
         self.queries += 1
         if int(label) != ref.label or int(checksum) != ref.checksum:
             self.mismatches += 1
@@ -89,15 +103,15 @@ class Verdict:
             if got.shape != ref.dists.shape:
                 self.rel_err = float("inf")
             else:
-                self.rel_err = max(self.rel_err, _rel_err(got, ref.dists))
+                self.rel_err = max(self.rel_err,
+                                   _rel_err(got, ref.dists, self.scale))
 
-    def add_plain(self, ref: reference.Answer,
-                  plain: reference.Answer) -> None:
+    def add_plain(self, ref, plain) -> None:
         """One query of the screened reference, held to the plain one."""
         self.plain += 1
         if (ref.label != plain.label or ref.checksum != plain.checksum
                 or not np.array_equal(ref.ids, plain.ids)
-                or _rel_err(ref.dists, plain.dists)
+                or _rel_err(ref.dists, plain.dists, self.scale)
                 > self.limits["dist_rel_err_max"]):
             self.plain_mismatches += 1
 
@@ -123,10 +137,13 @@ class Verdict:
                 for k, v in self.numbers.items()]
 
 
-def check_served(cfg, rows, labels, records, answers, k, requests,
-                 per_request, plain_queries, seed, limits) -> Verdict:
+def check_served(reference, cfg, rows, labels, records, answers, k,
+                 requests, per_request, plain_queries, seed,
+                 limits) -> Verdict:
+    """The window's sampled answers against ``reference``, the module
+    the cell's configuration names."""
     picks = sample_requests(records, requests, per_request, seed)
-    v = Verdict(limits)
+    v = Verdict(limits, getattr(reference, "dist_scale", dist_scale))
     if not picks:
         return v
     qs = np.concatenate([

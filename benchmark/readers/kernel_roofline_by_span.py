@@ -11,8 +11,8 @@ from benchmark import kernel_cost
 from benchmark.readers import kernel_ms_by_span
 
 
-def read(ctx, pattern: str, span: str):
-    got = kernel_ms_by_span.whole_batches(ctx, pattern, span)
+def read(ctx, pattern: str, span: str, cycle: str = "serve.cycle"):
+    got = kernel_ms_by_span.whole_batches(ctx, pattern, span, cycle)
     if not got or ctx.scan_shape is None:
         return None
     seconds = sum(b["seconds"] for b in got) / len(got)

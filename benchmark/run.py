@@ -6,9 +6,11 @@ as arrays, builds the daemon in-process through the constructors the
 program's entry point uses, warms the cell's own shapes
 (compiled programs come from the persistent cache, ``<checkout>/.jax_cache``
 by ``dmlp_tpu.utils.compile_cache``'s rule), measures for ``--seconds``,
-checks a seeded sample of the window's own answers against
-``benchmark.reference`` outside the window, and prints one JSON object as
-its last line. ``--trace 0`` reports the cell's end-to-end metrics,
+checks a seeded sample of the window's own answers against the reference
+its configuration names (``benchmark/reference.py`` where it names none)
+outside the window, and prints one JSON object as its last line, each
+number compared beside its limit under ``checks``, its last key (and as
+the last lines on standard error). ``--trace 0`` reports the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics and a breakdown.
 
 A run that finds no TPU, fewer chips than the cell asks for, a device
@@ -27,6 +29,7 @@ from __future__ import annotations
 import argparse
 import glob
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -73,7 +76,6 @@ class Context:
         self.registry_before: Dict[str, Dict[str, float]] = {}
         self.registry_after: Dict[str, Dict[str, float]] = {}
         self.trace: Optional[Dict[str, Any]] = None
-        self.kernel_dispatches = 0
         self.scan_shape: Optional[Dict[str, int]] = None
         self.peaks: Dict[str, Any] = {}
         self.notes: Dict[str, Any] = {}
@@ -167,17 +169,20 @@ class LoadGen:
     """The generator's process: started early, told the port when the
     daemon is ready, always reaped."""
 
+    COMMAND = [sys.executable, "-m", "benchmark.loadgen"]
+
     def __init__(self, cell: spec.Cell, params: Dict[str, Any], seed: int,
                  seconds: float):
         env = dict(os.environ)
         env["PYTHONPATH"] = spec.ROOT + os.pathsep + env.get("PYTHONPATH",
                                                             "")
         self.proc = subprocess.Popen(
-            [sys.executable, "-m", "benchmark.loadgen"], cwd=spec.ROOT,
+            self.COMMAND, cwd=spec.ROOT,
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, env=env,
             text=True)
-        job = {"config": {k: cell.config[k] for k in ("num_attrs",
-                                                      "values")},
+        job = {"config": {k: cell.config[k]
+                          for k in ("num_attrs", "values", "modules")
+                          if k in cell.config},
                "kind": cell.kind_name, "params": params,
                "seed": seed, "seconds": seconds}
         self.proc.stdin.write(json.dumps(job) + "\n")
@@ -298,18 +303,18 @@ def _run_served(cell, args, ctx, gen, t_setup) -> Dict[str, Any]:
         stats1 = daemon.stats()
         _check_stamp(stats1["device"], cell)
         eng = stats1["engine"]
-        ctx.kernel_dispatches = int(eng.get("extract_chunks") or 0)
+        chunks = int(eng.get("extract_chunks") or 0)   # that hold rows
         k = int(cell.params["k"])
         lp = eng.get("last_prune") or {}
         qpad, _kb, kcap = daemon.engine.bucket_plan(
             max(r["nq"] for r in res["requests"]) if res["requests"]
             else 1, k)
-        if lp.get("dense_bytes") and ctx.kernel_dispatches:
+        if lp.get("dense_bytes") and chunks:
             n = int(eng["corpus_rows"])
             ctx.scan_shape = {
                 "nq": int(qpad), "n": n, "na": na, "kc": int(kcap),
                 "itemsize": int(round(lp["dense_bytes"] / (n * na))),
-                "dispatches": ctx.kernel_dispatches}
+                "dispatches": chunks}
         peak = _memory_peak()
         say(event="served", requests=len(res["requests"]),
             batches=stats1["batches"] - stats0["batches"],
@@ -318,7 +323,8 @@ def _run_served(cell, args, ctx, gen, t_setup) -> Dict[str, Any]:
             - compiles0, buckets=eng["buckets"], paths=eng["paths"],
             compile_count=eng["compile_count"],
             compile_cache=compile_cache.stats(),
-            last_prune=lp, scan_shape=ctx.scan_shape)
+            last_prune=lp, scan_shape=ctx.scan_shape,
+            **_cycle_account(stats0, stats1))
         daemon.drain()
         drained = True
     finally:
@@ -361,13 +367,35 @@ def _run_served(cell, args, ctx, gen, t_setup) -> Dict[str, Any]:
     t = time.perf_counter()
     wl = cell.workload["check"]
     verdict = check.check_served(
-        cfg, rows, labels, recs, res["answers"], cell.params["k"],
+        cell.reference, cfg, rows, labels, recs, res["answers"],
+        cell.params["k"],
         int(wl["requests"]), int(wl["per_request"]),
         int(wl["plain_queries"]), args.seed, wl["limits"])
     say(event="reference", seconds=time.perf_counter() - t)
     return {"metrics": metrics, "attempted": len(recs), "failed": failed,
             "verdict": verdict, "stamp": stats1["device"],
             "memory_peak_bytes": peak}
+
+
+def _cycle_account(stats0: Dict[str, Any], stats1: Dict[str, Any]
+                   ) -> Dict[str, Any]:
+    """What names an untraced run's stall and its flagged queries, from
+    the program's always-on account, read once the window has closed:
+    ``stats.batcher`` (cycles closed, of them before the window, and the
+    ring of slow ones), ``stats.phases_ms.cycle`` with the longest cycle
+    since the daemon started, and ``stats.engine.repairs``. A program
+    that lacks one of them prints null there."""
+    from dmlp_tpu.obs import telemetry
+    batcher = stats1.get("batcher")
+    if batcher is not None:
+        batcher = dict(batcher, cycles_before_window=(
+            stats0.get("batcher") or {}).get("cycles"))
+    cycle = (stats1.get("phases_ms") or {}).get("cycle")
+    h = telemetry.registry().get("serve.cycle_ms")
+    if cycle is not None and h is not None and h.count:
+        cycle = dict(cycle, max_ms=h.snapshot().get("max"))
+    return {"batcher": batcher, "cycle_ms": cycle,
+            "repairs": stats1["engine"].get("repairs")}
 
 
 def _memory_peak() -> int:
@@ -401,6 +429,16 @@ def _breakdown(ctx: Context) -> Optional[Dict[str, Any]]:
     gaps = trace_reduce.busy(ctx.trace)["gaps_ns"]
     return {"device_ops": trace_reduce.top_ops(ctx.trace),
             "idle_gaps": trace_reduce.attribute_gaps(gaps, spans)}
+
+
+def checks_of(lines: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """``Verdict.lines()`` for the result line: each number compared,
+    its limit and how many were compared (a number that is not finite
+    goes as a string: the line is JSON)."""
+    return {c["number"]: {"value": c["value"] if math.isfinite(c["value"])
+                          else str(c["value"]),
+                          "limit": c["limit"], "compared": c["compared"]}
+            for c in lines}
 
 
 def main(argv=None) -> int:
@@ -453,7 +491,8 @@ def _main(args) -> int:
             obs_trace.uninstall()
     say(event="setup_parts", **t_setup)
     verdict = out["verdict"]
-    for line in verdict.lines():
+    checks = verdict.lines()
+    for line in checks:
         say(**line)
 
     prefix = "rehearsal." if args.rehearse else ""
@@ -494,7 +533,13 @@ def _main(args) -> int:
         line["rehearsal"] = True
     if args.control:
         line["control"] = True
+    # each number compared beside its limit: the line's last key, and
+    # the last lines on standard error
+    line["checks"] = checks_of(checks)
     print(json.dumps(line), flush=True)
+    for c in checks:
+        print(f"check {c['number']}: {c['value']!r} (limit {c['limit']!r},"
+              f" {c['compared']} compared)", file=sys.stderr, flush=True)
     return 0
 
 

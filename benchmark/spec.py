@@ -4,7 +4,10 @@ Everything that belongs to one configuration, one traffic mix, one cell or
 one per-layer metric is a file of its own; the harness holds no table of
 them. A later PR adds entries and files and edits none:
 
-    configs/<config>.json          sizes, program options, guarantees, control
+    configs/<config>.json          sizes, program options, guarantees, control,
+                                   and (``modules``) its reference and generator
+    references/<name>.py           a plain reference a configuration names
+    generators/<name>.py           a value generator a configuration names
     traffic/<mix>.json             a traffic kind and its parameters
     traffic/<kind>.py              the generator of that kind
     workloads/<cell>.json          config + mix + what is particular to the pair
@@ -18,6 +21,7 @@ import copy
 import importlib
 import json
 import os
+import pkgutil
 import re
 from typing import Any, Dict, List, Optional
 
@@ -83,22 +87,45 @@ def _toy(doc: Dict[str, Any], path: str) -> Dict[str, Any]:
     return toy
 
 
-def traffic_kind(kind: str):
-    if not NAME_RE.match(kind):
-        raise SpecError(f"illegal traffic kind {kind!r}")
+def _named(package: str, name: str, what: str):
+    """Module ``benchmark.<package>.<name>``; an unknown name lists the
+    package's modules."""
+    if not isinstance(name, str) or not NAME_RE.match(name):
+        raise SpecError(f"illegal {what} {name!r}")
+    pkg = importlib.import_module(f"benchmark.{package}")
     try:
-        return importlib.import_module(f"benchmark.traffic.{kind}")
+        return importlib.import_module(f"{pkg.__name__}.{name}")
     except ImportError as e:
-        raise SpecError(f"no traffic kind {kind!r}: {e}") from None
+        has = sorted(m.name for m in pkgutil.iter_modules(pkg.__path__))
+        raise SpecError(f"no {what} {name!r} under benchmark/{package}/ "
+                        f"(has: {has}): {e}") from None
+
+
+def traffic_kind(kind: str):
+    return _named("traffic", kind, "traffic kind")
 
 
 def reader(name: str):
-    if not NAME_RE.match(name):
-        raise SpecError(f"illegal reader {name!r}")
-    try:
-        return importlib.import_module(f"benchmark.readers.{name}")
-    except ImportError as e:
-        raise SpecError(f"no reader {name!r}: {e}") from None
+    return _named("readers", name, "reader")
+
+
+def reference(name: Optional[str] = None):
+    """The plain reference a configuration names under ``modules``
+    (``references/<name>.py``); unnamed, ``benchmark/reference.py``:
+    float64 brute force under squared L2. The contract is what
+    ``check.py`` uses: ``Answer``, ``knn_exact(rows, labels, queries,
+    ks)``, ``knn_plain(...)`` and, optionally, ``dist_scale(want)``,
+    the denominator of ``dist_rel_err_max``."""
+    if name is None:
+        return importlib.import_module("benchmark.reference")
+    return _named("references", name, "reference")
+
+
+def generator(name: str):
+    """The value generator a configuration names under ``modules``
+    (``generators/<name>.py``: ``draw(rng, shape, values, seed)``,
+    NumPy only); a configuration that names none gets ``data.draw``."""
+    return _named("generators", name, "generator")
 
 
 class Cell:
@@ -148,6 +175,11 @@ class Cell:
                                 "control")
             self.config = merge(self.config, self.config["control"]["set"])
         self.kind = traffic_kind(self.kind_name)
+        # an unknown name is refused here, before any set-up
+        modules = self.config.get("modules", {})
+        self.reference = reference(modules.get("reference"))
+        if "generator" in modules:
+            generator(modules["generator"])
 
     def end_to_end(self) -> List[Dict[str, Any]]:
         return [m for m in self.bench["end_to_end"]

@@ -3,15 +3,10 @@ the benchmark already had (``span_ms``, ``span_arg``, ``span_self_pct``)
 over spans made by hand, with hand-computed answers, and what they read
 of a program that records none of the new spans (nothing).
 
-The twelve files are in the tree and were read on the chip (``PERF.md``
-section 5), but ``BENCHMARK.json`` does not list them:
-``test_parse_native_metric.py`` (PR 32) holds ``parse_native_pct.bulk``
-to be the LAST per-layer entry, and the driver takes an entry put before
-it as a change to what was there, so a per-layer entry can only be added
-by a PR that may edit that file (``PERF.md`` section 7; PR 33's six
-``*.widek`` files wait for the same). Hence the files are loaded here by
-path, as ``test_widek_readers.py`` does, not through
-``spec.Cell.per_layer``; ``WANT`` names the cells each is for."""
+The twelve are entries of ``BENCHMARK.json`` since PR 45 (until then
+a test held ``parse_native_pct.bulk`` to be the LAST per-layer entry and
+nothing could be listed); the files are still loaded here by path, and
+``WANT`` names the cells each must at least be listed for."""
 
 import json
 import os
@@ -161,10 +156,15 @@ def test_children_leave_out_the_spans_that_cross_batches():
             "serve.batch_deliver", "fleet.merge_drain"} <= set(kids)
 
 
-def test_the_entry_an_older_test_pins_last_is_where_it_was():
-    # nothing here holds the list's length, any other place in it or
-    # whether the twelve are in it: a PR that may edit the older test
-    # lists them without an edit to this file
-    names = [m["name"] for m in spec.benchmark()["per_layer"]]
-    assert names[-1] == "parse_native_pct.bulk"
+def test_the_twelve_are_listed_by_name_wherever_they_stand():
+    # nothing here holds the list's length or any entry's place in it:
+    # a further entry appended after any other leaves this as it is
+    bench = spec.benchmark()
+    names = [m["name"] for m in bench["per_layer"]]
     assert len(names) == len(set(names))
+    qps = next(m for m in bench["end_to_end"] if m["name"] == "qps")
+    for name, (cells, _) in WANT.items():
+        entry = next(m for m in bench["per_layer"] if m["name"] == name)
+        assert set(cells) <= set(entry["workloads"])
+        if entry["moves"] == "qps":
+            assert set(entry["workloads"]) <= set(qps["workloads"])

@@ -24,13 +24,24 @@ SHAPE = {"nq": 1024, "n": 4_000_000, "na": 128, "kc": 32, "itemsize": 4,
          "dispatches": 2}
 
 
+def folds(ctx, n):
+    """The window's fold spans, each saying it made ``n`` kernel calls:
+    what the readers count a chip's micro-batches by."""
+    ctx.window_pc = (0.0, 100.0)
+    ctx.spans = [
+        {"name": "fleet.solve_resident", "t0": 1.0, "t1": 1.1,
+         "args": {"scheduled": n, "chunks": n}},
+        {"name": "serve.solve_extract", "t0": 1.0, "t1": 1.1,
+         "args": {"chunks": n}}]
+
+
 @pytest.fixture()
 def ctx():
     with open(os.path.join(spec.HERE, "testdata", "trace_mesh4.json")) as f:
         trace = json.load(f)
     c = Context()
     c.trace = trace
-    c.kernel_dispatches = 2
+    folds(c, 2)
     c.scan_shape = dict(SHAPE)
     c.peaks = dict(PEAKS)
     return c
@@ -138,9 +149,9 @@ def test_without_a_trace_a_reader_returns_nothing(reader, args):
 
 
 def test_less_than_one_whole_batch_on_a_chip_is_not_read(ctx):
-    ctx.kernel_dispatches = 164
+    folds(ctx, 164)
     assert kernel_ms_mesh.read(ctx, KERNEL, MESH) is None
-    ctx.kernel_dispatches = 2
+    folds(ctx, 2)
     ctx.scan_shape = None
     assert kernel_roofline_mesh.read(ctx, KERNEL, MESH) is None
 

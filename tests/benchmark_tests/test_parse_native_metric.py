@@ -65,17 +65,22 @@ def test_no_parse_span_in_the_window_reads_nothing():
     assert read([("serve.micro_batch", 1, 2, {"queries": 1024})]) is None
 
 
-def test_listed_for_the_one_chip_bulk_cells_only():
+def test_listed_for_cells_that_report_qps():
+    """Found by NAME: nothing here holds the entry's place in the list
+    or closes its cells against additions (PR 45: an entry pinned last
+    shut every later per-layer entry out for twelve PRs). A further
+    entry appended after any other leaves this as it is."""
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert len(names) == len(set(names))
     entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
-    assert entry == {"name": NAME, "unit": "%", "better": "higher",
-                     "source": "program_span", "layer": "front end",
-                     "moves": "qps",
-                     "workloads": ["bigann.bulk", "gist.bulk"]}
-    assert bench["per_layer"][-1] is entry      # appended, nothing moved
+    assert {k: entry[k] for k in entry if k != "workloads"} == {
+        "name": NAME, "unit": "%", "better": "higher",
+        "source": "program_span", "layer": "front end", "moves": "qps"}
+    assert {"bigann.bulk", "gist.bulk"} <= set(entry["workloads"])
     for w in bench["workloads"]:
-        names = [m["name"] for m in spec.Cell(w["name"]).per_layer()]
-        assert (NAME in names) == (w["name"] in entry["workloads"])
+        listed = [m["name"] for m in spec.Cell(w["name"]).per_layer()]
+        assert (NAME in listed) == (w["name"] in entry["workloads"])
     qps = next(m for m in bench["end_to_end"] if m["name"] == "qps")
     assert set(entry["workloads"]) <= set(qps["workloads"])

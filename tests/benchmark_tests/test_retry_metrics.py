@@ -9,12 +9,14 @@ readers ``kernel_ms`` and ``kernel_roofline`` under a narrower
 ``pattern``), on a hand-made trace on which ``kernel_ms.bulk`` itself
 reads low.
 
-The files are in the tree and were read on the chip (``PERF.md``
-section 5), but ``BENCHMARK.json`` does not list them:
-``test_parse_native_metric.py`` (PR 32) holds ``parse_native_pct.bulk``
-to be the LAST per-layer entry (``PERF.md`` section 7; PR 33's six
-``*.widek`` and PR 35's twelve cycle files wait for the same). Hence
-they are loaded here by path, as ``test_cycle_metrics.py`` does."""
+``kernel_ms.fold`` and ``kernel_roofline.fold`` are entries of
+``BENCHMARK.json`` since PR 45 (until then a test held
+``parse_native_pct.bulk`` to be the LAST per-layer entry). The three
+``retry_*`` stay files: they read only in a window that holds a flagged
+query, which about one seed in three does not draw, and a listed metric
+has to be on every traced line of its cell (``test_open_list.py`` names
+them with that reason). All five are loaded here by path, as
+``test_cycle_metrics.py`` does."""
 
 import json
 import os
@@ -142,7 +144,10 @@ def traced(events=EVENTS):
     ctx = Context()
     ctx.trace = {"events": events, "sync_ns": 0.0,
                  "window_ns": [0.0, 10_000 * MS]}
-    ctx.kernel_dispatches = CHUNKS
+    # the window's fold spans say how many kernel calls a batch makes
+    ctx.window_pc = (0.0, 100.0)
+    ctx.spans = [{"name": "serve.solve_extract", "t0": t, "t1": t + 0.001,
+                  "args": {"chunks": CHUNKS}} for t in (1.0, 1.2, 1.5)]
     ctx.peaks = {"flops_per_s": 100e12, "hbm_bytes_per_s": 1e12}
     ctx.scan_shape = {"nq": 1024, "n": 10_000_000, "na": 128, "kc": 120,
                       "itemsize": 2, "dispatches": CHUNKS}
@@ -199,5 +204,11 @@ def test_the_files_are_legal_entries_of_layers_the_benchmark_has(name):
     layers = {m["layer"] for m in bench["per_layer"]}
     assert doc["layer"] in layers
     assert callable(spec.reader(doc["reader"]).read)
-    # not listed yet: a listed name must agree with its file (spec.Cell)
-    assert name not in {m["name"] for m in bench["per_layer"]}
+    # the two of the fold are listed since PR 45, and an entry agrees
+    # with its file (spec.Cell); the retry's three are not (docstring)
+    entry = next((m for m in bench["per_layer"] if m["name"] == name), None)
+    assert (entry is None) == name.startswith("retry_")
+    if entry is not None:
+        assert all(entry[k] == doc[k] for k in ("unit", "better", "source",
+                                                "layer", "moves"))
+        assert "bigann-10m.bulk" in entry["workloads"]
