@@ -18,6 +18,11 @@ binary (``oracle_capture/oracle_4.out``):
                                                      rows staged on 128
                                                      lanes: float64's
                                                      candidates
+  ip        python chip_smoke.py --ip-child          a resident corpus
+                                                     ranked by inner
+                                                     product: the golden
+                                                     model's answers, ties
+                                                     included
   serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
                                                      stats, SIGTERM drain
   mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
@@ -67,6 +72,15 @@ quarter of a chunk beside the stack (left 100 wide the compiler
 re-lays-out the whole stack every fold), every list holds the
 float64 brute force's nearest candidates over the same values, and the
 listed distances are float64's within the engine's own float32 bound.
+
+``ip`` (PR 46) holds the chip to the second score: one child builds the
+serving engine (``ResidentEngine``, ``score="ip"``, the default staging
+dtype, 200 attributes staged on 256 lanes) over seeded reals with a block
+of integer rows a hundred copies each, solves one micro-batch and fails
+unless every answer is the golden model's under ip (labels, ids in
+order, products within 1e-11 of |q| max|x|), the stamp says select
+``extract`` and score ``ip``, and the tied queries were flagged and
+repaired (the device retry, then the host oracle).
 
 The configs run in the order given (default ``1,4``): config 1 is the
 same path at a size that takes seconds, so a machine with no chip fails
@@ -574,6 +588,109 @@ def narrow_misses(got: Dict[str, Any]) -> List[str]:
     return bad
 
 
+#: the ``ip`` phase's corpus: uniform rows, then ``points`` integer rows
+#: ``copies`` times each (their products tie by the hundred)
+IP_SHAPE = dict(rows=20000, attrs=200, points=6, copies=100, queries=128,
+                k=10)
+
+
+def ip_child(out_path: str) -> int:
+    """The ``ip`` child: a resident engine under ``score="ip"`` over a
+    seeded corpus whose last rows are integer points a hundred copies
+    each, one micro-batch of uniform queries and of the points
+    themselves (so the best products tie past the candidate window),
+    against golden.fast under ip; what it found goes to ``out_path``."""
+    import numpy as np
+
+    from dmlp_tpu.config import EngineConfig
+    from dmlp_tpu.golden.fast import knn_golden_fast
+    from dmlp_tpu.io.grammar import KNNInput, Params
+    from dmlp_tpu.obs.run import device_stamp
+    from dmlp_tpu.serve.engine import ResidentEngine
+    n, na, points, copies, nq, k = (IP_SHAPE[x] for x in (
+        "rows", "attrs", "points", "copies", "queries", "k"))
+    rng = np.random.default_rng(46)
+    rows = rng.uniform(-1, 1, (n, na)).astype(np.float32).astype(np.float64)
+    pts = rng.integers(-2, 3, (points, na)).astype(np.float64)
+    rows[n - points * copies:] = np.repeat(pts, copies, axis=0)
+    labels = rng.integers(0, 10, n).astype(np.int32)
+    queries = rng.uniform(-1, 1, (nq, na)).astype(np.float32).astype(
+        np.float64)
+    queries[:points] = pts
+    ks = np.full(nq, k, np.int32)
+    corpus = KNNInput(Params(n, 0, na), labels, rows, np.zeros(0, np.int32),
+                      np.zeros((0, na)))
+    eng = ResidentEngine(corpus, EngineConfig(use_pallas=True, score="ip"))
+    got = eng.solve_batch(queries, ks)
+    want = knn_golden_fast(KNNInput(Params(n, nq, na), labels, rows, ks,
+                                    queries), score="ip")
+    scale = np.linalg.norm(queries, axis=1) * np.linalg.norm(
+        rows, axis=1).max()
+    wrong = sum(int(g.predicted_label != w.predicted_label
+                    or not np.array_equal(g.neighbor_ids, w.neighbor_ids))
+                for g, w in zip(got, want))
+    err = max(float(np.max(np.abs(g.neighbor_dists - w.neighbor_dists))
+                    / s) for g, w, s in zip(got, want, scale))
+    tied = sum(int(len(set(g.neighbor_dists.tolist())) == 1)
+               for g in got[:points])
+    stats = eng.bucket_stats()
+    with open(out_path, "w") as f:
+        json.dump({
+            "device": device_stamp(eng), "shape": IP_SHAPE,
+            "staging": eng._staging, "staged_attrs": stats["staged_attrs"],
+            "paths": stats["paths"], "wrong": wrong,
+            "err_over_scale": err, "tied_answers": tied,
+            "repairs": stats["repairs"],
+        }, f)
+    return 0
+
+
+def phase_ip(c: Config) -> List[str]:
+    """``ip``: a resident corpus ranked by inner product answers as the
+    golden model does, ties included, on the extract path."""
+    got, wall, bad = run_fold_child(c, "ip", "--ip-child")
+    if got is None:
+        return bad
+    stamp = got.get("device") or {}
+    say(f"  ip: wall {wall:.1f} s (smoke timing); {got['shape']}; staged "
+        f"{got['staging']} on {got['staged_attrs']} lanes; paths "
+        f"{got['paths']}; score {stamp.get('score')}; answers off the "
+        f"golden model's: {got['wrong']}; products against float64 "
+        f"{got['err_over_scale']:.3g} of |q| max|x|; repairs "
+        f"{got['repairs']}")
+    return ip_misses(got)
+
+
+def ip_misses(got: Dict[str, Any]) -> List[str]:
+    """Every miss in the ``ip`` child's record, named."""
+    stamp = got.get("device") or {}
+    bad = device_misses(stamp)
+    if stamp.get("score") != "ip" or stamp.get("select") != "extract":
+        bad.append(f"the stamp says score {stamp.get('score')!r}, select "
+                   f"{stamp.get('select')!r}, not ip on the extract path")
+    if set((got.get("paths") or {}).values()) != {"extract"}:
+        bad.append(f"bucket paths are {got.get('paths')}, not extract")
+    if stamp.get("platform") == "tpu" and got.get("staging") != "bfloat16":
+        bad.append(f"the default dtype staged {got.get('staging')} on a "
+                   "chip, not bfloat16")
+    if got.get("staged_attrs") != 256:
+        bad.append(f"200-wide rows are staged {got.get('staged_attrs')} "
+                   "wide, not on two whole lane vectors")
+    if got.get("wrong"):
+        bad.append(f"{got['wrong']} answers differ from the golden "
+                   "model's under ip")
+    if not got.get("err_over_scale", 1.0) <= 1e-11:
+        bad.append(f"the products are off float64's by "
+                   f"{got.get('err_over_scale')} of |q| max|x|")
+    if got.get("tied_answers") != IP_SHAPE["points"]:
+        bad.append(f"{got.get('tied_answers')} of {IP_SHAPE['points']} "
+                   "tied queries came back as one tie group")
+    repairs = got.get("repairs") or {}
+    if repairs.get("flagged_queries", 0) < IP_SHAPE["points"]:
+        bad.append(f"repairs {repairs}: the tied queries were not flagged")
+    return bad
+
+
 def read_queries(c: Config, count: int) -> Tuple[List[int], List[list]]:
     """The first ``count`` queries of the input's query section, with
     their own k — straight from the text the batch child parsed."""
@@ -683,6 +800,7 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
                for m in phase_fold_bf16(c)]
     misses += [f"config {config_id} fold.narrow: {m}"
                for m in phase_fold_narrow(c)]
+    misses += [f"config {config_id} ip: {m}" for m in phase_ip(c)]
     misses += [f"config {config_id} serve: {m}" for m in phase_serve(c)]
     from dmlp_tpu.config import EngineConfig
     chips = stamp.get("device_count", 0)
@@ -725,11 +843,15 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help=argparse.SUPPRESS)   # the fold.bf16 phase's child
     ap.add_argument("--narrow-child", metavar="OUT", default=None,
                     help=argparse.SUPPRESS)   # the fold.narrow phase's child
+    ap.add_argument("--ip-child", metavar="OUT", default=None,
+                    help=argparse.SUPPRESS)   # the ip phase's child
     args = ap.parse_args(argv)
     if args.fold_child:
         return fold_child(args.fold_child)
     if args.narrow_child:
         return narrow_child(args.narrow_child)
+    if args.ip_child:
+        return ip_child(args.ip_child)
     if not os.path.isdir(os.path.join(REPO, "dmlp_tpu")):
         print(f"chip_smoke: no dmlp_tpu package beside {__file__}",
               file=sys.stderr)

@@ -16,7 +16,8 @@ summary to stderr after the contract line.
 Usage::
 
     python -m dmlp_tpu [--mode single|sharded|ring|auto] [--debug] [--fast]
-                       [--engine jax|golden|auto] [--phase-times]
+                       [--engine jax|golden|auto] [--score l2|ip]
+                       [--phase-times]
                        [--compile-cache DIR] [--hlo-report FILE]
                        [--trace FILE] [--metrics FILE] [--counters] < input.in
 """
@@ -198,6 +199,14 @@ def main(argv: Optional[Sequence[str]] = None,
     parser.add_argument("--select", default="auto",
                         choices=["auto", "sort", "topk", "seg", "extract"],
                         help="device k-selection strategy")
+    parser.add_argument("--score", default="l2", choices=["l2", "ip"],
+                        help="what the corpus is ranked by: l2 = smallest "
+                             "squared distance; ip = LARGEST inner product "
+                             "(larger id first on ties; --debug prints the "
+                             "products). The golden model (--engine golden) "
+                             "and the serving daemon's one-chip extract "
+                             "path have the ip form; the batch engines "
+                             "refuse it by name")
     parser.add_argument("--phase-times", action="store_true",
                         help="per-phase ms breakdown on stderr (extension)")
     parser.add_argument("--pallas", action="store_true",
@@ -325,7 +334,7 @@ def _run_cli(parser, args, stdin, stdout, stderr, tracer, probe) -> int:
                           exact=not args.fast, data_block=args.data_block,
                           query_block=args.query_block, dtype=args.dtype,
                           select=args.select, use_pallas=args.pallas,
-                          mesh_shape=mesh_shape)
+                          mesh_shape=mesh_shape, score=args.score)
 
     timer = EngineTimer()
     with timer.phase("parse"), obs_span("cli.parse"):
@@ -339,7 +348,7 @@ def _run_cli(parser, args, stdin, stdout, stderr, tracer, probe) -> int:
         timer.start()
         from dmlp_tpu.golden.reference import knn_golden
         with obs_span("cli.solve", engine="golden"):
-            results = knn_golden(inp)
+            results = knn_golden(inp, score=config.score)
     else:
         from dmlp_tpu.utils.compile_cache import enable_compile_cache
         enable_compile_cache(args.compile_cache)  # before any compile

@@ -13,6 +13,17 @@ import dataclasses
 from typing import Optional, Tuple
 
 
+#: what a corpus may be ranked by (EngineConfig.score; the kernel's
+#: ``score`` static, ops.pallas_extract): THE single definition
+SCORES = ("l2", "ip")
+
+
+def score_of(engine) -> str:
+    """What ``engine`` ranks by: its configuration's score, squared L2
+    for an object that carries no configuration (a test's stand-in)."""
+    return getattr(getattr(engine, "config", None), "score", "l2")
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Configuration for the KNN engines.
@@ -112,6 +123,17 @@ class EngineConfig:
         A batch solve and a mesh daemon have the oracle alone whatever
         this says (they keep nothing resident to fold again, or no
         one-chip stack).
+      score: what a corpus is ranked by, "l2" (the default: smallest
+        squared Euclidean distance) or "ip" (LARGEST inner product
+        s(q, x) = sum_a q_a x_a, float64; neighbours by (s descending,
+        id DESCENDING on ties); golden.reference has the contract).
+        A property of the corpus, never of a request: every program an
+        engine compiles is keyed on it, and inside the program the
+        ordered quantity under "ip" is -s so that every list still
+        ascends. The one-chip serving engine's extract path
+        (serve.engine.ResidentEngine) and the golden model have the
+        "ip" form; every other engine refuses it at construction by
+        name (require_score): none answers an ip corpus in L2.
     """
 
     AUTO_SELECT_THRESHOLD = 8192
@@ -128,8 +150,12 @@ class EngineConfig:
     use_pallas: bool = False
     precision: str = "auto"
     boundary_retry: bool = True
+    score: str = "l2"
 
     def __post_init__(self) -> None:
+        if self.score not in SCORES:
+            raise ValueError(f"unknown score {self.score!r} "
+                             f"(one of {SCORES})")
         if self.mode not in ("single", "sharded", "ring", "auto"):
             raise ValueError(f"unknown mode {self.mode!r}")
         if self.dtype not in ("auto", "float32", "bfloat16"):
@@ -145,6 +171,20 @@ class EngineConfig:
             raise ValueError("block sizes must be positive")
         if self.margin < 0:
             raise ValueError("margin must be >= 0")
+
+    def require_score(self, engine: str,
+                      scores: Tuple[str, ...] = ("l2",)) -> None:
+        """Refuse, by name, an engine that lacks this configuration's
+        score: ``engine`` ranks by ``scores`` alone (squared L2 unless
+        it says otherwise), and answering an inner-product corpus in
+        L2 would be a wrong answer, not a slow one."""
+        if self.score not in scores:
+            raise ValueError(
+                f"{engine} has no score={self.score!r} form (it ranks by "
+                f"{' | '.join(scores)}): serve an inner-product corpus "
+                "through the one-chip daemon's extract path "
+                "(python -m dmlp_tpu.serve --pallas --score ip) or the "
+                "golden model (--engine golden)")
 
     def resolve_dtype(self) -> str:
         """Concrete staging dtype ("float32" | "bfloat16") for this run.

@@ -65,10 +65,14 @@ def rescore_block(k: int, num_attrs: int) -> int:
 
 
 def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
-                data_attrs: np.ndarray, block: int | None = None
-                ) -> np.ndarray:
+                data_attrs: np.ndarray, block: int | None = None,
+                score: str = "l2") -> np.ndarray:
     """Exact float64 distances for candidate ids (difference form, like
     computeDistance at engine.cpp:12-18). ids < 0 map to +inf.
+    Under ``score`` "ip" the ordered quantity is the NEGATED inner
+    product -(q . x) of the gathered rows (so that it ascends like a
+    distance; finalize_host hands the wire the product itself): one
+    pass over the buffer where the difference form takes two.
 
     ``block`` queries are rescored at a time, in ONE (block, K, A)
     buffer that holds the gathered rows and then their difference; left
@@ -96,9 +100,15 @@ def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
         q1 = min(q0 + block, q)
         rows = buf[:q1 - q0]                                     # (b, K, A)
         np.take(data_attrs, safe[q0:q1], axis=0, out=rows, mode="clip")
+        if score == "ip":
+            np.einsum("qka,qa->qk", rows, query_attrs[q0:q1],
+                      out=out[q0:q1])
+            continue
         diff = np.subtract(rows, query_attrs[q0:q1, None, :],
                            out=rows if inplace else None)
         out[q0:q1] = np.einsum("qka,qka->qk", diff, diff)
+    if score == "ip":
+        np.negative(out, out=out)
     out[cand_ids < 0] = np.inf
     return out
 
@@ -164,8 +174,71 @@ EPS_CANCEL_COEF = 3.0 * 2.0 ** -22
 LOWP_COEF = {"f32": 0.0, "bf16x3": 2.0 ** -14 * (1.0 + 2.0 ** -16),
              "bf16": 2.0 ** -6}
 
+#: The inner-product score's bounds (config.EngineConfig.score "ip";
+#: the device orders by t~ = -fl32(q~ . x~), q~ and x~ the operands
+#: cast to the staging dtype, the host by t = -(q . x) in float64).
+#: ONE term, no cancellation: nothing of magnitude (qn + dn) is formed
+#: and subtracted, so the bound is a multiple of |q||x| and does not
+#: depend on the score itself.
+#:
+#: CAST. q~_a = q_a (1 + d_a), x~_a = x_a (1 + e_a), |d_a|, |e_a| <= u,
+#: u the dtype's unit roundoff (2^-8 for bfloat16's 8 significant
+#: bits: the worst case sits just above a power of two, where half a
+#: spacing of 2^-7 is 2^-8 of the value; 2^-24 for float32). Then
+#:     |q~ . x~ - q . x| <= (2u + u^2) sum_a |q_a x_a|
+#:                       <= (2u + u^2) |q||x|        (Cauchy-Schwarz).
+#: The hazard test compares TWO device scores (the k-th candidate's
+#: and a row's the list may have missed: boundary_hazard), each off by
+#: that much in either direction, so the coefficient is twice it:
+#: 2 (2u + u^2) = 2^-6 (1 + 2^-9) for bfloat16 and 2^-22 (1 + 2^-25)
+#: for float32, each rounded up to (1 + 2^-8) and no further: the
+#: derivation's bound, not a calibration. |x| <= sqrt(dn_max) over
+#: every row, known or missed.
+#:
+#: ACCUMULATION. The MXU sums the A products of a pass in float32
+#: (exact products for bfloat16 operands; 3A products for the
+#: "bf16x3" form, 6A for the one ``HIGHEST`` dot), every partial sum
+#: at most (1 + u)^2 |q||x|: one-sided nprod * 2^-24 (1 + u)^2 |q||x|
+#: if every addition rounds the same way, two-sided 12 A * 2^-24 for
+#: the six passes, which is EPS_CANCEL_COEF * A: the cancellation
+#: term's own count of the dot (LOWP_COEF's comment), taken here with
+#: |q||x| where the distance form has (qn + dn) / 2 >= |q||x|. The
+#: + 2 of (A + 2) is kept as the slack for (1 + u)^2 and for the
+#: kernel's float32 norms, which the MXU gate's bound is made of.
+#:
+#: FIRST PASS. What "bf16x3" drops is (2u^2 + u^4/4)|q||x| a dot
+#: (LOWP_COEF's derivation), two-sided LOWP_COEF["bf16x3"]: the same
+#: constant. One "bf16" pass casts both blocks again: the CAST term
+#: of bfloat16, EPS_IP_REL["bfloat16"] (LOWP_COEF["bf16"] = 2^-6
+#: folds a slack in where this needs the u^2 too). "f32" drops
+#: nothing.
+#:
+#: ip_coef sums the three: two device scores compare as their float64
+#: values do unless they lie within ip_coef * |q| * sqrt(dn_max) of
+#: each other. staging_eps(..., score="ip") is terms one and two,
+#: lowp_eps(..., score="ip") the third, so every site that composes
+#: the two under "l2" composes them under "ip" unchanged.
+EPS_IP_REL = {"bfloat16": 2.0 ** -6 * (1.0 + 2.0 ** -8),
+              "float32": 2.0 ** -22 * (1.0 + 2.0 ** -8)}
 
-def lowp_eps(precision: str, qn: np.ndarray, dn_max: float) -> np.ndarray:
+
+def _ip_lowp_coef(precision: str) -> float:
+    return EPS_IP_REL["bfloat16"] if precision == "bf16" \
+        else LOWP_COEF[precision]
+
+
+def ip_coef(staging: str, na: int, precision: str = "f32") -> float:
+    """The "ip" bound's whole coefficient of |q| max|x| for operands
+    staged as ``staging`` ("float32" | "bfloat16"), ``na`` attributes
+    and first-pass form ``precision`` (EPS_IP_REL's comment derives
+    it). The kernel's MXU gate deflates its bound by it (there the
+    norms are the staged values' own: ``staging`` "float32")."""
+    return (EPS_IP_REL[staging] + EPS_CANCEL_COEF * (na + 2)
+            + _ip_lowp_coef(precision))
+
+
+def lowp_eps(precision: str, qn: np.ndarray, dn_max: float,
+             score: str = "l2") -> np.ndarray:
     """Per-query bound on the distance perturbation a low-precision
     FIRST PASS (ops.pallas_extract with ``precision != "f32"``: the
     split "bf16x3" form every exact engine runs, or one "bf16" pass) can add
@@ -177,15 +250,21 @@ def lowp_eps(precision: str, qn: np.ndarray, dn_max: float) -> np.ndarray:
     prune thresholds, the MXU-gate bound, and the multi-pass floor.
     Zero for the one-dot "f32" pass; 2^-14 of the scale for the
     three-pass "bf16x3" form, 2^-6 for one bf16 pass. Raises KeyError on
-    a precision with no static bound (int8 — see LOWP_COEF)."""
-    coef = LOWP_COEF[precision]
+    a precision with no static bound (int8 — see LOWP_COEF). Under
+    ``score`` "ip" the scale is |q| sqrt(dn_max) and the coefficient
+    the form's inner-product one (EPS_IP_REL's comment)."""
+    qn = np.asarray(qn, np.float64)
+    coef = _ip_lowp_coef(precision) if score == "ip" \
+        else LOWP_COEF[precision]
     if not coef:
-        return np.zeros_like(np.asarray(qn, np.float64))
-    return coef * (np.asarray(qn, np.float64) + dn_max)
+        return np.zeros_like(qn)
+    if score == "ip":
+        return coef * np.sqrt(qn * dn_max)
+    return coef * (qn + dn_max)
 
 
 def staging_eps(last: np.ndarray, qn: np.ndarray, dn_max: float,
-                staging: str, na: int) -> np.ndarray:
+                staging: str, na: int, score: str = "l2") -> np.ndarray:
     """Per-query bound on the distance perturbation the device pipeline
     can introduce, for the truncation-hazard test. Two terms:
 
@@ -221,7 +300,16 @@ def staging_eps(last: np.ndarray, qn: np.ndarray, dn_max: float,
     bound, added to this at every site. ``dn_max`` (max squared
     data-row norm, f64) bounds |x|^2 over every point, known or
     missed.
+
+    Under ``score`` "ip" (the device orders by -q.x) there is ONE term
+    and no cancellation: (EPS_IP_REL[staging] + EPS_CANCEL_COEF *
+    (na + 2)) * |q| * sqrt(dn_max), the cast of both operands and the
+    float32 accumulation of the dot, whatever ``last`` is (EPS_IP_REL's
+    comment derives both).
     """
+    if score == "ip":
+        return ip_coef(staging, na) * np.sqrt(
+            np.asarray(qn, np.float64) * dn_max)
     rel = EPS_REL_BF16 if staging == "bfloat16" else EPS_REL_F32
     scale = qn + dn_max
     return (rel * np.sqrt(np.maximum(last, 0.0) * scale)
@@ -278,7 +366,8 @@ def boundary_overflow(device_dists: np.ndarray, ks: np.ndarray,
 
 
 def repair_boundary_overflow(results: List[QueryResult],
-                             suspect_idx: np.ndarray, inp) -> None:
+                             suspect_idx: np.ndarray, inp,
+                             score: str = "l2") -> None:
     """Recompute the flagged queries exactly (golden model) in place.
 
     ``suspect_idx`` holds local query indices (positions in ``results`` /
@@ -293,7 +382,8 @@ def repair_boundary_overflow(results: List[QueryResult],
     from dmlp_tpu.golden.fast import knn_golden_fast
     from dmlp_tpu.io.grammar import subset_queries
 
-    fixed_all = knn_golden_fast(subset_queries(inp, suspect_idx))
+    fixed_all = knn_golden_fast(subset_queries(inp, suspect_idx),
+                                score=score)
     for j, qi in enumerate(np.asarray(suspect_idx)):
         fixed = fixed_all[j]
         results[qi] = QueryResult(results[qi].query_id, fixed.k,
@@ -305,7 +395,8 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
                   cand_ids: np.ndarray, ks: np.ndarray,
                   query_attrs: np.ndarray, data_attrs: np.ndarray,
                   exact: bool = True,
-                  query_ids: np.ndarray | None = None) -> List[QueryResult]:
+                  query_ids: np.ndarray | None = None,
+                  score: str = "l2") -> List[QueryResult]:
     """Candidate lists -> final per-query results.
 
     Args:
@@ -317,6 +408,11 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
       query_attrs/data_attrs: float64 originals, used only when ``exact``.
       exact: rescore candidates in float64 and re-select (parity mode).
       query_ids: (Q,) global query ids; defaults to arange (single process).
+      score: "l2" | "ip". Under "ip" every distance in here is the
+        NEGATED inner product (``cand_dists`` too), so the one
+        (dist asc, id desc) order is (s desc, id desc); the results
+        carry s itself, the contract's value (padding -inf), which is
+        what the wire and the golden model report.
     """
     q, kcap = cand_ids.shape
     ks = np.asarray(ks, np.int64)
@@ -324,8 +420,8 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
         raise ValueError(f"candidate width {kcap} < max k {ks.max()}")
     cand_ids = np.asarray(cand_ids, np.int64)
     cand_labels = np.asarray(cand_labels, np.int64)
-    d = rescore_f64(cand_ids, query_attrs, data_attrs) if exact \
-        else np.asarray(cand_dists, np.float64)
+    d = rescore_f64(cand_ids, query_attrs, data_attrs, score=score) \
+        if exact else np.asarray(cand_dists, np.float64)
 
     # Re-derive the selection order (dist asc, id desc — the measured
     # label-free oracle-binary comparator, golden.reference); after
@@ -348,6 +444,8 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
     # ~9.5 s at the 10240 x 4608 wide-k shape).
     rd = np.where(valid, d, np.inf)
     rids = np.where(valid, ids, -1)
+    if score == "ip":
+        rd = -rd          # the product itself; -(-0.0) is +0.0
 
     if query_ids is None:
         query_ids = np.arange(q, dtype=np.int64)

@@ -93,6 +93,11 @@ class ShardedEngine:
 
     def __init__(self, config: EngineConfig = EngineConfig(mode="sharded"),
                  mesh: Optional[Mesh] = None):
+        # The mesh engines (this one, the ring and the compiler-sharded
+        # one, the mesh daemon's, the multi-host feed) rank by squared
+        # L2 alone: none answers an inner-product corpus.
+        config.require_score(
+            f"{type(self).__module__}.{type(self).__name__}")
         self.config = config
         self.mesh = mesh if mesh is not None else make_mesh(config.mesh_shape)
         self._staging = config.resolve_dtype()

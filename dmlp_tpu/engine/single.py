@@ -32,7 +32,7 @@ import numpy as np
 from dmlp_tpu.config import EngineConfig
 from dmlp_tpu.engine.finalize import (boundary_hazard, finalize_host,
                                       lowp_eps, repair_boundary_overflow,
-                                      staging_eps)
+                                      rescore_f64, staging_eps)
 from dmlp_tpu.io.grammar import KNNInput, subset_queries
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import counters as obs_counters
@@ -624,7 +624,17 @@ class SingleChipEngine:
     #: the resident serving engine gives one (its ``na`` argument)
     _kcap_attrs: int | None = None
 
+    #: the scores this engine ranks by (config.EngineConfig.score): the
+    #: batch solve's streaming selects, hetk routing and multipass
+    #: driver know squared L2 alone; the resident serving engine's
+    #: extract path has the inner product too
+    _scores: Tuple[str, ...] = ("l2",)
+
     def __init__(self, config: EngineConfig = EngineConfig()):
+        config.require_score(
+            f"{type(self).__module__}.{type(self).__name__}"
+            + (" (the batch solve)" if type(self) is SingleChipEngine
+               else ""), self._scores)
         self.config = config
         self._staging = config.resolve_dtype()
         self._dtype = (jnp.bfloat16 if self._staging == "bfloat16"
@@ -1434,6 +1444,7 @@ class SingleChipEngine:
         inp = pend.inp
         n = inp.params.num_data
         prec = pend.prec
+        score = self.config.score
         self.last_comms = []   # one chip: no collectives (obs.comms)
         merged: List[QueryResult] = [None] * inp.params.num_queries
         # Max squared data-row norm (f64): scales the staging-dtype
@@ -1471,7 +1482,8 @@ class SingleChipEngine:
             # dn_max_cached says whether the test's corpus-wide scalar was
             # at hand (a resident engine's, or an earlier segment's) or
             # cost a pass over the WHOLE host corpus inside this span.
-            with obs_span("single.hazard", rows=n, **targs) as hz:
+            with obs_span("single.hazard", rows=n, score=score,
+                          **targs) as hz:
                 dists = None if self.config.exact \
                     else np.asarray(fetched.pop(0), np.float64)[:nq]
                 ids = fetched.pop(0)[:nq]
@@ -1511,9 +1523,10 @@ class SingleChipEngine:
             hazard_ms += (t0 - t1) * 1e3
             # gather_bytes: the float64 rows the rescore gathers, one a
             # candidate (Q x kcap x A x 8 B; none in fast mode).
+            gather = ids.size * inp.params.num_attrs * 8 \
+                if self.config.exact else 0
             with obs_span("single.finalize", exact=self.config.exact,
-                          gather_bytes=ids.size * inp.params.num_attrs * 8
-                          if self.config.exact else 0, **targs) as sp:
+                          gather_bytes=gather, score=score, **targs) as sp:
                 suspects = np.nonzero(flags)[0] if flags is not None \
                     else np.zeros(0, np.intp)
                 # The flagged queries go back to the device first, where
@@ -1524,10 +1537,22 @@ class SingleChipEngine:
                 # host oracle's.
                 retry = self._retry_begin(pend, sub, suspects, select,
                                           kcap) if suspects.size else None
+                if self.config.exact:
+                    # The float64 gather-and-score, under a span of its
+                    # own: the part of the finalize the score changes
+                    # (difference form; under "ip" the product alone).
+                    # finalize_host takes the rescored distances as it
+                    # takes fast mode's device ones.
+                    with obs_span("single.rescore", queries=nq,
+                                  slots=kcap, bytes=gather, score=score,
+                                  **targs):
+                        dists = rescore_f64(np.asarray(ids, np.int64),
+                                            sub.query_attrs,
+                                            sub.data_attrs, score=score)
                 results = finalize_host(dists, labels, ids, sub.ks,
                                         sub.query_attrs, sub.data_attrs,
-                                        exact=self.config.exact,
-                                        query_ids=idx)
+                                        exact=False, query_ids=idx,
+                                        score=score)
                 if suspects.size:
                     # ``repairs`` = queries FLAGGED, wherever repaired
                     pend.repairs += int(suspects.size)
@@ -1538,7 +1563,8 @@ class SingleChipEngine:
                 if suspects.size:
                     with obs_span("single.repair",
                                   queries=int(suspects.size), **targs):
-                        repair_boundary_overflow(results, suspects, sub)
+                        repair_boundary_overflow(results, suspects, sub,
+                                                 score=score)
             if idx is None:
                 merged = results
             else:
@@ -1569,9 +1595,10 @@ class SingleChipEngine:
         ("bf16x3", "bf16": finalize.lowp_eps; the test must clear
         both). Streaming-fallback segments never split or cast, so
         their bound stays the staging one alone."""
-        eps = staging_eps(last, qn, dn_max, self._staging, na)
+        score = self.config.score
+        eps = staging_eps(last, qn, dn_max, self._staging, na, score)
         if select == "extract":
-            eps = eps + lowp_eps(prec, qn, dn_max)
+            eps = eps + lowp_eps(prec, qn, dn_max, score)
         return eps
 
     def _retry_begin(self, pend: PendingRun, sub: KNNInput,

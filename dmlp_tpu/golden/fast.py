@@ -15,6 +15,11 @@ produces *identical results* orders of magnitude faster:
 The fallback makes the result exact regardless of the bound's tightness —
 the bound only decides how often the slow path runs (measure-zero for
 continuous data, possible for adversarial duplicates).
+
+Under ``score="ip"`` (golden.reference has the contract) the coarse value
+is the dgemm's -q.x alone and the rescore the same product by einsum over
+the gathered rows; the safety check stands with the dot's own bound
+(A products of magnitude at most |q||x|, no cancellation).
 """
 
 from __future__ import annotations
@@ -30,17 +35,23 @@ from dmlp_tpu.io.report import QueryResult
 
 
 def _strict_row(inp: KNNInput, qi: int, data: np.ndarray,
-                labels: np.ndarray, ids: np.ndarray) -> QueryResult:
+                labels: np.ndarray, ids: np.ndarray,
+                score: str = "l2") -> QueryResult:
     """Exact full-row solve for one query (the knn_golden inner loop)."""
-    diff = data - inp.query_attrs[qi][None, :]
-    drow = np.einsum("na,na->n", diff, diff)
-    return finalize_query(drow, labels, ids, int(inp.ks[qi]), qi)
+    if score == "ip":
+        drow = -np.einsum("na,a->n", data, inp.query_attrs[qi])
+    else:
+        diff = data - inp.query_attrs[qi][None, :]
+        drow = np.einsum("na,na->n", diff, diff)
+    return finalize_query(drow, labels, ids, int(inp.ks[qi]), qi, score)
 
 
 def knn_golden_fast(inp: KNNInput, margin: int = 64,
                     query_block: int = 1024,
-                    stats: Optional[dict] = None) -> List[QueryResult]:
-    """Same results as knn_golden(inp) (float64), benchmark-scale fast.
+                    stats: Optional[dict] = None,
+                    score: str = "l2") -> List[QueryResult]:
+    """Same results as knn_golden(inp, score=score) (float64),
+    benchmark-scale fast.
 
     ``stats``, if given, receives {"fallbacks": <count of queries routed
     to the strict full-row path>} so the safety valve's cost is observable.
@@ -67,17 +78,23 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
         # form allocates ~4 (Qb, N) f64 temporaries, which measured ~10x
         # the dgemm itself at benchmark scale (page faults on fresh GBs).
         coarse = q @ data.T
-        coarse *= -2.0
-        coarse += qn[:, None]
-        coarse += dn[None, :]
+        if score == "ip":
+            np.negative(coarse, out=coarse)
+        else:
+            coarse *= -2.0
+            coarse += qn[:, None]
+            coarse += dn[None, :]
 
         if kcand < nd:
             cand = np.argpartition(coarse, kcand - 1, axis=1)[:, :kcand]
         else:
             cand = np.broadcast_to(ids[None, :], (q1 - q0, nd))
         # Exact difference-form rescore of the candidates only.
-        diff = data[cand] - q[:, None, :]
-        exact = np.einsum("qka,qka->qk", diff, diff)
+        if score == "ip":
+            exact = -np.einsum("qka,qa->qk", data[cand], q)
+        else:
+            diff = data[cand] - q[:, None, :]
+            exact = np.einsum("qka,qka->qk", diff, diff)
 
         ks_blk = inp.ks[q0:q1].astype(np.int64)
         if kcand < nd:
@@ -122,10 +139,12 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
                 [cand_i, np.full(shape, -1, np.int64)], axis=1)
         blk = finalize_host(exact_f, cand_l, cand_i, ks_blk,
                             inp.query_attrs, inp.data_attrs, exact=False,
-                            query_ids=np.arange(q0, q1, dtype=np.int64))
+                            query_ids=np.arange(q0, q1, dtype=np.int64),
+                            score=score)
         results[q0:q1] = blk
         for row in np.nonzero(~ok)[0]:
-            results[q0 + row] = _strict_row(inp, q0 + row, data, labels, ids)
+            results[q0 + row] = _strict_row(inp, q0 + row, data, labels,
+                                            ids, score)
             fallbacks += 1
     if stats is not None:
         stats["fallbacks"] = fallbacks

@@ -23,6 +23,20 @@ binaries' observed contract, not the author engine.cpp's:
 - pad with the id = -1 sentinel when fewer than k candidates exist
   (common.cpp:66); padded entries carry dist = +inf and do not vote.
 
+Under ``score="ip"`` (config.EngineConfig.score; a corpus ranked by
+inner product, as FAISS ``IndexFlatIP`` ranks it) the same contract with
+one quantity changed:
+
+- s(q, x) = sum_a q_a x_a, float64;
+- neighbours are the k rows of LARGEST s, ordered by (s descending, tie ->
+  **larger id** first): the selection order with -s in the distance's place,
+  which is how every function here computes it;
+- vote, padding (id = -1, which does not vote) and the FNV-1a checksum over
+  the label and the ids are unchanged;
+- the reported ``neighbor_dists`` carry s itself, in that order; padded
+  slots carry -inf (the worst possible score, as +inf is the worst
+  distance).
+
 On tie-free inputs — every graded benchmark input; continuous draws tie with
 probability ~0 — the label-free and label-aware orders coincide, which is
 why all 21,000 captured benchmark checksums match either way
@@ -70,8 +84,11 @@ def vote(labels: np.ndarray) -> int:
 
 
 def finalize_query(drow: np.ndarray, labels: np.ndarray, ids: np.ndarray,
-                   k: int, qi: int) -> QueryResult:
+                   k: int, qi: int, score: str = "l2") -> QueryResult:
     """Candidate distances for one query -> its final QueryResult.
+    Under ``score`` "ip" ``drow`` holds the NEGATED inner products (so
+    the one order below is (s desc, id desc)) and the result reports s
+    itself, padded with -inf.
 
     THE definition of the output contract, shared by the strict and fast
     oracles: select by (dist asc, id desc), vote (tie -> larger
@@ -91,13 +108,16 @@ def finalize_query(drow: np.ndarray, labels: np.ndarray, ids: np.ndarray,
         pad = k - out_ids.size
         out_ids = np.concatenate([out_ids, np.full(pad, -1, np.int64)])
         out_dists = np.concatenate([out_dists, np.full(pad, np.inf)])
+    out_dists = out_dists.astype(np.float64)
     return QueryResult(qi, k, predicted, out_ids.astype(np.int64),
-                       out_dists.astype(np.float64))
+                       -out_dists if score == "ip" else out_dists)
 
 
 def knn_golden(inp: KNNInput, dtype=np.float64,
-               query_block: int = 256) -> List[QueryResult]:
+               query_block: int = 256,
+               score: str = "l2") -> List[QueryResult]:
     """Solve a problem instance exactly; returns per-query results in id order.
+    ``score`` "l2" | "ip" (the module docstring has both contracts).
 
     ``dtype`` controls the distance arithmetic (float64 = reference parity;
     float32 mirrors the on-device engines for like-for-like differential
@@ -121,17 +141,22 @@ def knn_golden(inp: KNNInput, dtype=np.float64,
         dists = np.empty((q1 - q0, nd), dtype)
         for n0 in range(0, nd, data_block):
             n1 = min(n0 + data_block, nd)
+            if score == "ip":
+                dists[:, n0:n1] = -np.einsum("qa,na->qn", queries[q0:q1],
+                                             data[n0:n1])
+                continue
             diff = queries[q0:q1, None, :] - data[None, n0:n1, :]
             dists[:, n0:n1] = np.einsum("qna,qna->qn", diff, diff)
         for qi in range(q0, q1):
             results.append(finalize_query(dists[qi - q0], labels, ids,
-                                          int(inp.ks[qi]), qi))
+                                          int(inp.ks[qi]), qi, score))
     return results
 
 
 def solve_text(text: str, dtype=np.float64, debug: bool = False,
-               inp: Optional[KNNInput] = None) -> str:
+               inp: Optional[KNNInput] = None, score: str = "l2") -> str:
     """End-to-end oracle: input grammar text -> stdout channel text."""
     if inp is None:
         inp = parse_input_text(text)
-    return format_results(knn_golden(inp, dtype=dtype), debug=debug)
+    return format_results(knn_golden(inp, dtype=dtype, score=score),
+                          debug=debug)

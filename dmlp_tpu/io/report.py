@@ -28,6 +28,13 @@ class QueryResult:
     ``neighbor_ids``/``neighbor_dists`` are length-k, sorted by
     (distance asc, tie -> larger id) per engine.cpp:334-338, padded with the
     id = -1 sentinel (common.cpp:66) when fewer than k candidates exist.
+
+    Of a corpus ranked by inner product (config.EngineConfig.score "ip";
+    golden.reference has the contract) ``neighbor_dists`` carries the
+    inner products s themselves, sorted by (s DESCENDING, tie -> larger
+    id), padded slots -inf; the debug report prints them in the
+    distance's place. The checksum is over the label and the ids either
+    way.
     """
 
     query_id: int

@@ -32,6 +32,8 @@ import re
 import time
 from typing import Any, Dict, List, Optional
 
+from dmlp_tpu.config import score_of
+
 #: bump on any backward-incompatible field change; consumers key on this
 SCHEMA_VERSION = 2
 
@@ -83,6 +85,8 @@ def device_stamp(engine=None) -> Dict[str, Any]:
         stamp.update(
             mesh=list(mesh.devices.shape) if mesh is not None else None,
             select=getattr(engine, "_last_select", None),
+            # what the corpus is ranked by ("l2" | "ip")
+            score=score_of(engine),
             extract_impl=getattr(engine, "last_extract_impl", None),
             # None where the engine has no ladder (the mesh engines)
             degrade_rung=getattr(engine, "last_degrade_rung", None),

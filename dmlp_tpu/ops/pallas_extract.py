@@ -61,6 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dmlp_tpu.config import SCORES
 from dmlp_tpu.utils.compat import tpu_compiler_params
 
 from dmlp_tpu.ops.pallas_distance import _tile
@@ -401,10 +402,27 @@ def _dot_cross(q, d, precision: str):
     return contract(q, d, jax.lax.Precision.HIGHEST)
 
 
+def _score_block(qn, dn, cross, score: str):
+    """The (tq, tn) block the running lists order, ASCENDING, from the
+    cross term and the two norm planes. "l2": the squared distance by
+    the norm expansion |q|^2 + |d|^2 - 2 q.d, clamped at 0 (rounding
+    can push a near-duplicate's below). "ip": -q.d alone, so that the
+    largest inner product is the smallest entry and the extraction, the
+    floor, the masks and every list downstream stay as they are: no
+    norm plane is added and there is NO clamp (the best rows' entries
+    are negative; a row orthogonal to the query reads 0, a zero-padded
+    sentinel row too, which the ``n_real`` mask sends to +inf as
+    under "l2")."""
+    if score == "ip":
+        return -cross
+    return jnp.maximum(qn + dn - 2.0 * cross, 0.0)
+
+
 def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
             od_ref, oi_ref, it_ref, dist_s, *, kc: int, fresh: bool, ne: int,
             unroll: int = 1, block_skip: bool = True,
-            mxu_gate: bool = False, precision: str = "f32"):
+            mxu_gate: bool = False, precision: str = "f32",
+            score: str = "l2"):
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     tq, tn = dist_s.shape
@@ -418,8 +436,7 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
     gate_on = None
     if not mxu_gate:
         cross = _dot_cross(q_ref[:], d_ref[:], precision)
-        dist = qn_ref[:] + dn_ref[:] - 2.0 * cross
-        dist = jnp.maximum(dist, 0.0)
+        dist = _score_block(qn_ref[:], dn_ref[:], cross, score)
         # Per-row floor (multi-pass extraction, engine.single
         # ._solve_extract_multipass): candidates strictly below the floor
         # were captured by an earlier pass — mask them so this pass
@@ -465,31 +482,49 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
                 od_ref[:] = cd_ref[:]
                 oi_ref[:] = ci_ref[:]
         from dmlp_tpu.engine.finalize import (EPS_CANCEL_COEF,
-                                              EPS_REL_F32, LOWP_COEF)
+                                              EPS_REL_F32, LOWP_COEF,
+                                              ip_coef)
         na = q_ref.shape[1]
         lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
         real = (j * tn + lane1) < n_real
         dn = dn_ref[:]
         sdn = jnp.sqrt(jnp.maximum(dn, 0.0))
-        mn = jnp.min(jnp.where(real, sdn, jnp.inf))
-        mx = jnp.max(jnp.where(real, sdn, -jnp.inf))
-        dn_hi = jnp.max(jnp.where(real, dn, 0.0))
-        qn = qn_ref[:]
-        sq = jnp.sqrt(jnp.maximum(qn, 0.0))
-        gap = jnp.maximum(jnp.maximum(mn - sq, sq - mx), 0.0)
-        lb = gap * gap                                     # (tq, 1)
-        scale = jnp.maximum(qn, 0.0) + dn_hi
-        # A low-precision pass perturbs the COMPUTED distances the gate
-        # reasons about by up to lowp_eps more than f32 rounding alone,
-        # so the deflation margin widens by LOWP_COEF * scale (the
-        # device form of engine.finalize.lowp_eps — same composition
-        # the host prune/hazard tests apply).
-        eps = (EPS_REL_F32 * jnp.sqrt(lb * scale)
-               + (EPS_CANCEL_COEF * (na + 2)
-                  + LOWP_COEF[precision]) * scale)
-        # All-sentinel blocks drive lb (and hence eps) to +inf; the
-        # inf - inf NaN compares False below, which IS the correct skip.
-        lb_safe = jnp.maximum(lb - eps, 0.0)
+        if score == "ip":
+            # No entry of the block is below -|q| max|d| (Cauchy-
+            # Schwarz over the block's real rows), deflated by what the
+            # pass can add to a COMPUTED inner product. The norms are
+            # the staged values' own, so that is the float32
+            # coefficient (the norms' and the dot's accumulation) and
+            # the form's: engine.finalize.ip_coef, the host bound's
+            # constants. An all-sentinel block reads +inf (NaN for a
+            # zero query): either compares False below, the correct
+            # skip.
+            mx = jnp.max(jnp.where(real, sdn, -jnp.inf))
+            sq = jnp.sqrt(jnp.maximum(qn_ref[:], 0.0))
+            lb_safe = -(sq * mx) * (1.0 + ip_coef("float32", na,
+                                                  precision))
+        else:
+            mn = jnp.min(jnp.where(real, sdn, jnp.inf))
+            mx = jnp.max(jnp.where(real, sdn, -jnp.inf))
+            dn_hi = jnp.max(jnp.where(real, dn, 0.0))
+            qn = qn_ref[:]
+            sq = jnp.sqrt(jnp.maximum(qn, 0.0))
+            gap = jnp.maximum(jnp.maximum(mn - sq, sq - mx), 0.0)
+            lb = gap * gap                                     # (tq, 1)
+            scale = jnp.maximum(qn, 0.0) + dn_hi
+            # A low-precision pass perturbs the COMPUTED distances the
+            # gate reasons about by up to lowp_eps more than f32
+            # rounding alone, so the deflation margin widens by
+            # LOWP_COEF * scale (the device form of
+            # engine.finalize.lowp_eps — same composition the host
+            # prune/hazard tests apply).
+            eps = (EPS_REL_F32 * jnp.sqrt(lb * scale)
+                   + (EPS_CANCEL_COEF * (na + 2)
+                      + LOWP_COEF[precision]) * scale)
+            # All-sentinel blocks drive lb (and hence eps) to +inf; the
+            # inf - inf NaN compares False below, which IS the correct
+            # skip.
+            lb_safe = jnp.maximum(lb - eps, 0.0)
         t_cur = jnp.max(od_ref[:], axis=1, keepdims=True)  # (tq, 1)
         gate_on = jnp.max((lb_safe < t_cur).astype(jnp.int32)) > 0
         if fresh:
@@ -501,8 +536,7 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
         @pl.when(gate_on)
         def _():
             cross = _dot_cross(q_ref[:], d_ref[:], precision)
-            dist = qn_ref[:] + dn_ref[:] - 2.0 * cross
-            dist = jnp.maximum(dist, 0.0)
+            dist = _score_block(qn_ref[:], dn_ref[:], cross, score)
             dist = jnp.where(dist < f_ref[:], jnp.inf, dist)
             pos = j * tn + lane
             dist = jnp.where(pos >= n_real, jnp.inf, dist)
@@ -616,7 +650,8 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
                  tile_q: int | None = None, tile_n: int | None = None,
                  ne: int | None = None, unroll: int | None = None,
                  block_skip: bool = True, mxu_gate: bool = False,
-                 floor: jax.Array | None = None, precision: str = "f32"):
+                 floor: jax.Array | None = None, precision: str = "f32",
+                 score: str = "l2"):
     """(queries (Qb, A), data (B, A)) -> (dists (Qb, kc) f32 ascending-ish
     unsorted, ids (Qb, kc) i32, iters (Qb/tq, B/tn) i32 loop counts; 0 =
     the threshold prefilter skipped that block).
@@ -676,6 +711,12 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     "bf16", the candidate window: resolve_kcap) for the exact pipeline
     to stay byte-identical. Static: part of the jit cache key, resolved
     by callers OUTSIDE every jit (R2 discipline).
+    ``score`` ("l2" | "ip": SCORES) is what the lists are ordered by
+    (_score_block): the squared distance, or -q.d under "ip", where
+    "dists" holds the NEGATED inner products, ascending like any other
+    list (the norm planes are still read: the MXU gate's bound is made
+    of them). Static like ``precision``: a corpus has one score, its
+    engine passes it to every program.
 
     Gate on supports() first. Output lists are NOT sorted; callers sort by
     the composite key (ops.topk.select_topk) if order matters.
@@ -701,6 +742,8 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     if precision not in PRECISIONS:
         raise ValueError(f"unsupported first-pass precision {precision!r} "
                          "(int8 is the gated follow-on — see ROADMAP)")
+    if score not in SCORES:
+        raise ValueError(f"unknown score {score!r} (one of {SCORES})")
     return _extract_topk_jit(
         q_attrs, d_attrs, carry_d, carry_i, n_real=n_real,
         id_base=id_base, chunk=chunk, d_norms=d_norms, kc=kc,
@@ -710,18 +753,18 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         ne=v["ne"] if ne is None else ne,
         unroll=v["unroll"] if unroll is None else unroll,
         block_skip=block_skip, mxu_gate=mxu_gate, floor=floor,
-        precision=precision)
+        precision=precision, score=score)
 
 
 @functools.partial(
     jax.jit, static_argnames=("kc", "interpret", "tile_q", "tile_n", "ne",
                               "unroll", "block_skip", "mxu_gate",
-                              "precision"))
+                              "precision", "score"))
 def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
                       id_base, kc: int, interpret: bool, tile_q: int,
                       tile_n: int, ne: int, unroll: int, block_skip: bool,
                       mxu_gate: bool, floor, precision: str = "f32",
-                      chunk=None, d_norms=None):
+                      chunk=None, d_norms=None, score: str = "l2"):
     qb, a = q_attrs.shape
     b = d_attrs.shape[-2]
     tq = _tile(qb, tile_q, 8)
@@ -779,7 +822,8 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     grid = (qb // tq, b // tn)
     kern = functools.partial(_kernel, kc=kc, fresh=fresh, ne=ne,
                              unroll=unroll, block_skip=block_skip,
-                             mxu_gate=mxu_gate, precision=precision)
+                             mxu_gate=mxu_gate, precision=precision,
+                             score=score)
     # The name is what a profiler capture and the compiled HLO show for
     # this custom call (``%dmlp_topk_fused.1 = ... custom-call(...)``):
     # it states the form, so a trace tells the MXU-gated kernel from the

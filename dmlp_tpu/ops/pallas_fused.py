@@ -67,7 +67,8 @@ def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
                carry_i: jax.Array | None = None, *, n_real,
                id_base=0, kc: int, interpret: bool = False,
                block_skip: bool = True,
-               floor: jax.Array | None = None, precision: str = "f32"):
+               floor: jax.Array | None = None, precision: str = "f32",
+               score: str = "l2"):
     """Drop-in for ops.pallas_extract.extract_topk with the MXU tile
     gate on. Same signature, same (dists, ids, iters) outputs,
     bit-identical results; ``iters`` reports 0 for blocks either gate
@@ -75,7 +76,10 @@ def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     ``precision`` ("f32" | "bf16x3" | "bf16") selects the first-pass
     form exactly as in extract_topk — the MXU-gate bound widens by the
     engine.finalize.lowp_eps margin in-kernel, so gating stays sound
-    under the low-precision pass.
+    under the low-precision pass. ``score`` ("l2" | "ip") as in
+    extract_topk; under "ip" the gate's bound is the inner product's
+    (no entry of a block is below -|q| max|d|), deflated in-kernel by
+    engine.finalize.ip_coef.
 
     extract_topk resolves the tiles outside its jit boundary, so the
     concrete fused/two-pass choice AND the concrete tiles are part of
@@ -86,7 +90,7 @@ def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         q_attrs, d_attrs, carry_d, carry_i, n_real=n_real,
         id_base=id_base, kc=kc, interpret=interpret,
         block_skip=block_skip, mxu_gate=True, floor=floor,
-        precision=precision)
+        precision=precision, score=score)
 
 
 def resolve_topk_kernel(qb: int, b: int, a: int, kc: int,

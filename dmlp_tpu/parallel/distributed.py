@@ -495,6 +495,11 @@ def distributed_contract_run(path: str, engine, out=None, err=None,
 
     out = out if out is not None else sys.stdout
     err = err if err is not None else sys.stderr
+    # whatever engine a caller hands in: the per-shard exact rescore
+    # and the host merge here are squared L2's
+    engine.config.require_score(
+        "parallel.distributed.distributed_contract_run (the multi-host "
+        "feed)")
 
     # Parse outside the timed region (the reference starts its timer after
     # rank-0 stdin ingest, common.cpp:119-124); device placement — the

@@ -43,7 +43,8 @@ crashing, and every rung preserves the contract checksums exactly:
 5. ``streaming``  — the chunked multipass streaming fold
                     (engine.single._solve_pipelined): no running-list
                     kernel state, the live tile shrinks to one
-                    (query_block x chunk) slab.
+                    (query_block x chunk) slab. Squared L2 alone: an
+                    engine that ranks by inner product skips it.
 6. ``host``       — the float64 golden solve on the host
                     (golden.fast.knn_golden_fast): zero device memory;
                     it IS the oracle the contract diffs against, so
@@ -59,6 +60,7 @@ from __future__ import annotations
 import contextlib
 from typing import Callable, List
 
+from dmlp_tpu.config import score_of
 from dmlp_tpu.resilience import stats
 from dmlp_tpu.resilience.retry import classify, resilience_enabled
 
@@ -87,13 +89,14 @@ def _rung_context(engine, rung: str):
         engine._degrade_rung = prev
 
 
-def _host_fallback(inp) -> List:
-    """The last rung: the float64 host oracle (exact by construction)."""
+def _host_fallback(inp, score: str = "l2") -> List:
+    """The last rung: the float64 host oracle (exact by construction),
+    under the engine's score."""
     from dmlp_tpu.golden.fast import knn_golden_fast
     from dmlp_tpu.obs.trace import span as obs_span
     with obs_span("resilience.host_fallback",
                   nq=inp.params.num_queries, n=inp.params.num_data):
-        return knn_golden_fast(inp)
+        return knn_golden_fast(inp, score=score)
 
 
 def steps_down(e: BaseException) -> bool:
@@ -140,16 +143,20 @@ def run_ladder(engine, inp, solve: Callable, first: int = 0):
     if not resilience_enabled():
         with top_rung(engine):
             return solve(inp)
-    for i in range(first, len(RUNGS)):
-        rung = RUNGS[i]
+    # The streaming fold ranks by squared L2 alone: an engine under
+    # another score (config.EngineConfig.score) steps from the kernel
+    # rungs straight to the host oracle, which has its form.
+    score = score_of(engine)
+    rungs = [r for r in RUNGS[first:] if r != "streaming" or score == "l2"]
+    for i, rung in enumerate(rungs):
         try:
             engine.last_degrade_rung = rung
             if rung == "host":
-                return _host_fallback(inp)
+                return _host_fallback(inp, score)
             with _rung_context(engine, rung):
                 return solve(inp)
         except Exception as e:
-            if classify(e) != "oom" or i + 1 >= len(RUNGS):
+            if classify(e) != "oom" or i + 1 >= len(rungs):
                 raise
-            note_step(rung, RUNGS[i + 1], e)
+            note_step(rung, rungs[i + 1], e)
     raise AssertionError("unreachable: the host rung returns or raises")

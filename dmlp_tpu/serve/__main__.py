@@ -6,7 +6,7 @@ Usage::
         [--capacity ROWS] [--max-k K] [--max-batch-queries N]
         [--max-queue-queries N] [--tick-ms MS] [--gate-carry on|off]
         [--hbm-budget BYTES|auto] [--pallas] [--select auto|...]
-        [--dtype auto|float32|bfloat16] [--data-block N]
+        [--dtype auto|float32|bfloat16] [--score l2|ip] [--data-block N]
         [--warm-buckets NQxK,NQxK,...] [--compile-cache DIR]
         [--telemetry FILE] [--telemetry-port PORT] [--record FILE]
         [--snapshot-every-s S] [--ready-file PATH] [--faults FILE]
@@ -91,6 +91,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "the host only what that does not clear; off = "
                         "the host oracle alone; answers are identical "
                         "either way")
+    p.add_argument("--score", choices=["l2", "ip"], default="l2",
+                   help="what the corpus is ranked by: l2 = smallest "
+                        "squared Euclidean distance; ip = LARGEST inner "
+                        "product (s descending, larger id first on "
+                        "ties; 'dists' then carries s itself). The "
+                        "one-chip extract path (--pallas, more than "
+                        "8192 rows or --select extract) has the ip "
+                        "form; --mesh and the streaming select refuse "
+                        "it by name")
     p.add_argument("--data-block", type=int, default=None)
     p.add_argument("--warm-buckets", default=None, metavar="NQxK,...",
                    help="extra shape buckets to compile before ready")
@@ -172,7 +181,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                           use_pallas=args.pallas,
                           data_block=args.data_block,
                           precision=args.precision,
-                          boundary_retry=args.boundary_retry == "on")
+                          boundary_retry=args.boundary_retry == "on",
+                          score=args.score)
     mesh_shape = None
     if args.mesh:
         try:

@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from dmlp_tpu.config import score_of
 from dmlp_tpu.obs import telemetry
 from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.obs.trace import span as obs_span
@@ -600,7 +601,9 @@ class MicroBatcher:
             # while this consumer increments it — guard the write so
             # the field has one discipline (reads are single int loads)
             self.batches += 1
-        reg.counter("serve.batches").inc()
+        # labelled by what the corpus is ranked by, so that a scrape of
+        # a fleet holding both kinds tells them apart (readers sum)
+        reg.counter("serve.batches").inc(label=score_of(self.engine))
         # The pipeline's own count: batches begun while another was in
         # flight (stats.engine.overlap).
         reg.counter("serve.batches_overlapped").inc(

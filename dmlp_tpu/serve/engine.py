@@ -163,22 +163,24 @@ def _update_chunk(stack, norms, blk, c):
 #: what picks the compiled kernel: every one is part of the two
 #: programs' jit cache key, resolved by their caller OUTSIDE the jit
 _KERNEL_STATICS = ("kc", "interpret", "tile_q", "tile_n", "ne", "unroll",
-                   "mxu_gate", "precision")
+                   "mxu_gate", "precision", "score")
 
 
 def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
-                    precision: str, interpret: bool) -> Dict[str, Any]:
+                    precision: str, interpret: bool,
+                    score: str = "l2") -> Dict[str, Any]:
     """The static arguments of ``extract_topk`` for the kernel ``impl``
     ("fused" | "extract", from resolve_topk_kernel) at dispatch shape
     (qb, b, a): exactly what ``fused_topk`` / ``extract_topk`` would
     resolve for themselves, made concrete here so that it keys the
-    enclosing program's jit cache."""
+    enclosing program's jit cache; ``score`` is the engine's (a corpus
+    has one)."""
     from dmlp_tpu.ops.pallas_extract import _TN, resolve_variant
     v = resolve_variant(kc, b, qb, a)
     return dict(kc=kc, interpret=interpret, tile_q=v["tile_q"],
                 tile_n=v.get("tile_n", _TN), ne=v["ne"],
                 unroll=v["unroll"], mxu_gate=impl == "fused",
-                precision=precision)
+                precision=precision, score=score)
 
 
 def fold_chunks(q, stack, norms, order, nfold, span, **kern):
@@ -267,7 +269,7 @@ def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, Any]:
     if not v:
         return {}
     return {"tile_n": _TN, "norms": "computed", **{k: v[k] for k in (
-        "tile_q", "tile_n", "ne", "a_pad", "mxu_passes", "norms")
+        "tile_q", "tile_n", "ne", "a_pad", "mxu_passes", "norms", "score")
         if k in v}}
 
 
@@ -485,8 +487,11 @@ class ResidentServingCore:
     def _check_k(self, inp: KNNInput) -> None:
         kmax = int(inp.ks.max()) if inp.params.num_queries else 0
         if kmax > self.max_k:
-            raise RequestShapeError(
-                f"k={kmax} beyond the serving cap {self.max_k}")
+            raise RequestShapeError(self._k_refusal(kmax))
+
+    def _k_refusal(self, kmax: int) -> str:
+        """What a request past the serving cap is told."""
+        return f"k={kmax} beyond the serving cap {self.max_k}"
 
     @contextlib.contextmanager
     def _tagged(self, pending):
@@ -546,8 +551,7 @@ class ResidentServingCore:
         first use — warm-up pre-drives this so steady-state serving
         takes the dict hit only."""
         if kmax > self.max_k:
-            raise RequestShapeError(
-                f"k={kmax} beyond the serving cap {self.max_k}")
+            raise RequestShapeError(self._k_refusal(kmax))
         key = self.bucket_shape(nq, kmax)
         entry = self._buckets.get(key)
         if entry is None:
@@ -806,7 +810,21 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     at once, each in its :class:`PendingBatch`, and the engine's own
     fields hold what outlives a batch (the corpus, the buckets, the
     gate histogram, the ``last_*`` report of the last batch finished).
+
+    **Score** (``config.score``): the extract path's buckets rank by
+    squared L2 or by inner product ("ip": the kernel orders -q.x, the
+    hazard test and the device retry take the ip bound, the float64
+    rescore the product; golden.reference has the contract). The
+    streaming select, the wide-k multipass driver and the block-prune
+    scorer know squared L2 alone: under "ip" an engine whose corpus
+    does not take the extract path is refused at construction, a k
+    whose window passes the kernel's 512 slots at admission
+    (:attr:`max_k`), the ladder's ``streaming`` rung is skipped
+    (resilience.degrade) and the scorer does not run, so that no path
+    answers an inner-product corpus in L2.
     """
+
+    _scores = ("l2", "ip")
 
     def __init__(self, corpus: KNNInput, config: EngineConfig = None,
                  capacity: Optional[int] = None, gate_carry: bool = True):
@@ -850,6 +868,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             from dmlp_tpu.ops.pallas_distance import pallas_interpret
             self._interpret = pallas_interpret()
         else:
+            # every bucket would take the streaming select, which ranks
+            # by squared L2 alone
+            cfg.require_score(
+                "serve.engine.ResidentEngine's streaming select (a "
+                "corpus that does not take the extract path: no "
+                "use_pallas, or no more than "
+                f"{cfg.AUTO_SELECT_THRESHOLD} rows under select='auto')")
             self._ex_nchunks = self._ex_chunk_rows = self._ex_rows = 0
             self._interpret = True
         # The extract path's resident copy: ONE device array (nchunks,
@@ -939,10 +964,30 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         cap = self.capacity_rows
         if self._staging == "bfloat16":
             cap = min(cap, _BF16_AUTO_K_CAP)
+        if self.config.score != "l2":
+            cap = min(cap, self._one_pass_max_k)
         return cap
+
+    @functools.cached_property
+    def _one_pass_max_k(self) -> int:
+        """The largest k whose bucket still plans one kernel pass (a
+        window of at most _MP_KC slots): under a score the multipass
+        driver and the streaming select lack, the serving cap."""
+        return max((k for k in (1 << p for p in range(
+            self._MP_KC.bit_length())) if self._kcap_for(k)
+            <= self._MP_KC), default=0)
 
     def bucket_shape(self, nq: int, kmax: int) -> Tuple[int, int]:
         return (query_bucket(nq, self.query_granule), k_bucket(kmax))
+
+    def _k_refusal(self, kmax: int) -> str:
+        msg = super()._k_refusal(kmax)
+        if self.config.score != "l2" and kmax <= self.capacity_rows:
+            msg += (f" under score={self.config.score!r}: "
+                    "serve.engine.ResidentEngine's multipass driver and "
+                    "streaming select (a window past "
+                    f"{self._MP_KC} slots) rank by squared L2 alone")
+        return msg
 
     def _kcap_for(self, kb: int) -> int:
         return resolve_kcap(self.config, kb, self._stream_select,
@@ -992,7 +1037,14 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                 self._ensure_chunks()
         entry = _Bucket(qpad, kb, kcap, path, qb, nqb)
         if path == "stream":
+            self.config.require_score(
+                "serve.engine.ResidentEngine's streaming select (bucket "
+                f"{entry.key}: {kcap} slots the kernel does not tile)")
             self._compile_stream(entry)
+        elif path == "multipass":
+            self.config.require_score(
+                "serve.engine.ResidentEngine's multipass driver (bucket "
+                f"{entry.key}: {kcap} slots)")
         return entry
 
     def _compile_stream(self, entry: _Bucket) -> None:
@@ -1035,7 +1087,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         with obs_span("serve.stage_chunks", chunks=self._ex_nchunks,
                       chunk_rows=cr, na=self.num_attrs,
                       a_pad=self._ex_attrs, pad_bytes=self._pad_bytes(),
-                      norm_bytes=self._ex_nchunks * cr * 4):
+                      norm_bytes=self._ex_nchunks * cr * 4,
+                      score=self.config.score):
             # Allocated on the device, then filled a chunk at a time by
             # a donated update: the host never holds a second corpus.
             self._chunks = jnp.zeros((self._ex_nchunks, cr, self._ex_attrs),
@@ -1062,8 +1115,11 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         resident extract chunk, host f64 + device-resident f32 copies
         (tiny: O(blocks * a))."""
         from dmlp_tpu.ops import summaries as osum
+        # The norm band and the box gap are lower bounds of a squared
+        # L2: under another score the scorer does not run (no
+        # serve.prune_score in the cycle) and every fold is dense.
         if not self._extract_ok or self._ex_nchunks <= 1 \
-                or not osum.prune_enabled():
+                or not osum.prune_enabled() or self.config.score != "l2":
             return
         with obs_span("serve.summary_build", blocks=self._ex_nchunks):
             self._summ = osum.build_summaries(
@@ -1199,6 +1255,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     def _solve_resident_stream(self, pend: PendingBatch,
                                entry: _Bucket) -> Tuple[TopK, int]:
+        # no path answers an inner-product corpus in L2
+        self.config.require_score(
+            "serve.engine.ResidentEngine's streaming select")
         if entry.stream is None:
             # An extract-path bucket degraded to streaming: build the
             # fallback program once (counted honestly as a compile).
@@ -1269,7 +1328,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         from dmlp_tpu.ops import pallas_fused
         return {**pallas_fused.variant_stamp(
             kc, self._ex_chunk_rows, qpad, self._ex_attrs, prec,
-            self._staging), "a_pad": self._ex_attrs, "norms": "staged"}
+            self._staging), "a_pad": self._ex_attrs, "norms": "staged",
+            "score": self.config.score}
 
     def _fold_resident(self, q_dev, order, impl: str, kc: int,
                        prec: str):
@@ -1281,7 +1341,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         cr = self._ex_chunk_rows
         qpad = q_dev.shape[0]
         kern = _kernel_statics(impl, kc, cr, qpad, self._ex_attrs, prec,
-                               self._interpret)
+                               self._interpret, self.config.score)
         padded = np.zeros(self._ex_nchunks, np.int32)
         padded[:len(order)] = order
         od, oi, gated = _fold_stack(
@@ -1427,7 +1487,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             pend.extract_impl = impl
             pend.variant = self._variant_stamp(kc, entry.qpad, prec)
             sweep = _kernel_statics(impl_full, kc, full_rows, entry.qpad,
-                                    self._ex_attrs, prec, self._interpret)
+                                    self._ex_attrs, prec, self._interpret,
+                                    self.config.score)
             floor_args = dict(staging=self._staging, na=na,
                               precision=prec)
         t_begin = time.perf_counter()
@@ -1574,7 +1635,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         order = range(-(-self.n_real // self._ex_chunk_rows))
         groups = []
         with obs_span("single.retry_begin", queries=int(suspects.size),
-                      kcap=kc, **self._rid_args()):
+                      kcap=kc, score=self.config.score,
+                      **self._rid_args()):
             for g0 in range(0, suspects.size, qpad):
                 idx = suspects[g0:g0 + qpad]
                 gin = subset_queries(sub, idx)
@@ -1642,7 +1704,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                     dists, labels, ids, gin.ks, gin.query_attrs,
                     sub.data_attrs, exact=exact,
                     query_ids=np.asarray(
-                        [results[int(qi)].query_id for qi in idx]))
+                        [results[int(qi)].query_id for qi in idx]),
+                    score=self.config.score)
                 for j, qi in enumerate(idx):
                     if not still[j]:
                         results[int(qi)] = fixed[j]

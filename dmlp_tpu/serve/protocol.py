@@ -9,7 +9,14 @@ the micro-batcher coalesces across all of them). Shapes:
   "latency_ms"}`` (+ ``"neighbors"``/``"dists"`` with ``"debug": true``).
   ``checksums`` are the engines' contract FNV-1a values — the replay
   client reassembles the exact contract stdout (``Query N checksum:
-  C``) and byte-compares it against the golden oracle.
+  C``) and byte-compares it against the golden oracle. What ``dists``
+  carries is the daemon's score (``--score``, echoed as ``device.score``
+  in ``stats``): squared L2 distances, ascending, padded slots
+  ``Infinity``; of a corpus ranked by inner product the products s
+  themselves, DESCENDING (FAISS ``IndexFlatIP``'s convention), padded
+  slots ``-Infinity``. ``neighbors`` follows the same order, larger id
+  first on ties. A score is a property of the corpus, never of a
+  request.
 - ``{"op": "ingest", "labels": [...], "rows": [[...]], "start"?: S}``
   -> ``{"ok": true, "corpus_rows": N}``; capacity overflow is a clean
   ``ok: false`` with the reason. ``start`` makes the write an

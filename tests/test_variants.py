@@ -32,6 +32,11 @@ TABLE = {
     # bigann-10m, and msturing-10m at its staged 128 lanes
     "bigann-10m.bulk": ((1024, 51200, 128, 120), (64, 4, 12800)),
     "msturing-10m.unpadded100": ((1024, 51200, 100, 120), (64, 4, 12800)),
+    # text2image-10m at its staged 256 lanes (PR 46), and its retry
+    "text2image-10m.bulk": ((1024, 51200, 256, 120), (64, 4, 12800)),
+    "text2image-10m.unpadded200": ((1024, 51200, 200, 120),
+                                   (64, 4, 12800)),
+    "text2image-10m.retry": ((16, 51200, 256, 512), (64, 4, 12800)),
     "bigann-gt1000.pass": ((1024, 51200, 128, 512), (64, 4, 12800)),
     "bigann-gt1000.sweep": ((1024, 82 * 51200, 128, 512), (64, 4, 12800)),
     "retry.q16": ((16, 51200, 128, 512), (64, 4, 12800)),
@@ -78,3 +83,7 @@ def test_resolve_variant_table(shape, tiles):
     assert fused["mxu_gate"] is True
     assert _kernel_statics("extract", kc, b, qb, a, "f32", False) == {
         **fused, "mxu_gate": False}
+    # the score keys the jit beside them and picks no tile
+    assert fused["score"] == "l2"
+    assert _kernel_statics("fused", kc, b, qb, a, "f32", False, "ip") == {
+        **fused, "score": "ip"}
