@@ -335,9 +335,13 @@ class MeasuredIters:
         self._shape = tuple(shape)
         self._sum = None
 
-    def add(self, iters) -> None:
+    def add(self, iters, wide_iters=None) -> None:
+        """``iters``: a dispatch's recorded iterations; ``wide_iters``:
+        those of them that ran at full width (the kernel's two-level
+        selection: ``iters * wide``), where the caller has them."""
         if self._on:
-            s = jnp.sum(iters)
+            s = jnp.stack([jnp.sum(iters),
+                           0 if wide_iters is None else jnp.sum(wide_iters)])
             self._sum = s if self._sum is None else self._sum + s
 
     def done(self) -> None:
@@ -360,8 +364,9 @@ def flush_measured_iters(engine) -> None:
         return
     for site, s, shape in pend:
         try:
-            obs_counters.record_measured_iters(  # check: allow-host-sync
-                site, int(jax.device_get(s)), shape)
+            total, wide = jax.device_get(s)  # check: allow-host-sync
+            obs_counters.record_measured_iters(site, int(total), shape,
+                                               int(wide))
         except Exception:  # check: no-retry
             pass  # observability must never fail the solve
 
@@ -947,10 +952,10 @@ class SingleChipEngine:
                                                         precision=prec),
                         count=len(live),
                         site="single.extract_topk")
-                od, oi, _iters = kern(
+                od, oi, _iters, _wide = kern(
                     q_dev, da, od, oi, n_real=hi - lo, id_base=lo, kc=k,
-                    interpret=interpret, precision=prec)
-                mi.add(_iters)
+                    interpret=interpret, precision=prec, with_wide=True)
+                mi.add(_iters, _iters * _wide)
                 throttle.tick(od)
                 telemetry.sample_memory_now()   # staging window live
         mi.done()

@@ -539,11 +539,12 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                     id_base, n_real = _chunk_span((n, t * cr, sr), cr)
                     return id_base, jnp.where(live[0, t] > 0, n_real, 0)
 
-                od, oi, gated, iters = fold_chunks(
+                od, oi, gate, iters = fold_chunks(
                     q_attrs, stack, norms, order, nfold, span, **kern)
-                # Per cell: gated tiles and summed kernel iterations,
-                # (R, C) after shard_map, read back once a batch.
-                return (od[None], oi[None], gated[None, None],
+                # Per cell: the gate counts and the summed kernel
+                # iterations, a pair each, (R, C, 2) after shard_map,
+                # read back once a batch.
+                return (od[None], oi[None], gate[None, None],
                         iters[None, None])
 
             sharded = shard_map(
@@ -553,8 +554,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                           P(DATA_AXIS, None)),
                 out_specs=(P(DATA_AXIS, QUERY_AXIS, None),
                            P(DATA_AXIS, QUERY_AXIS, None),
-                           P(DATA_AXIS, QUERY_AXIS),
-                           P(DATA_AXIS, QUERY_AXIS)),
+                           P(DATA_AXIS, QUERY_AXIS, None),
+                           P(DATA_AXIS, QUERY_AXIS, None)),
                 check_vma=False)
 
             # Named for the device trace, like the merge.
@@ -627,7 +628,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                                      np.int32(self.n_real)), self._rsh),
                     mask)
             obs_counters.record_dispatch(fold, args, site="fleet.chunk_fold")
-            cd, ci, gated, iters = fold(*args)
+            cd, ci, gate, iters = fold(*args)
             t1 = clock()
             # The merge program goes onto the devices' queues behind
             # the fold (its dispatch, the collective, the re-select):
@@ -650,13 +651,14 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         pend.phase_ms["dispatch"] = (t2 - t0) * 1e3
         mi = MeasuredIters(pend, "fleet.chunk_fold",
                            (entry.qloc, cr, na, k))
-        mi.add(iters)
+        mi.add(iters[..., 0], iters[..., 1])
         mi.done()
         # Gate effectiveness: a 0-iteration tile was gated (or
-        # skip-gated) outright — counted a cell inside the program,
-        # read back once per micro-batch in _after_batch. The tile
-        # COUNT is static shape arithmetic, no transfer.
-        pend.gate = (gated,
+        # skip-gated) outright, a full-width one found a bucket hiding
+        # a second candidate — counted a cell inside the program, read
+        # back once per micro-batch in _after_batch. The tile COUNT is
+        # static shape arithmetic, no transfer.
+        pend.gate = (gate,
                      len(order) * r * c * fold_tiles(kern, entry.qloc, cr))
         note_scan(pend,
                   scanned_bytes=int(rows[live].sum()) * na * item,

@@ -30,7 +30,7 @@ Import-light: jax is imported lazily, only when a probe is actually used.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["CostProbe", "normalize_cost", "lowered_cost", "roofline",
            "install", "uninstall", "active", "record_dispatch",
@@ -97,12 +97,13 @@ class CostProbe:
     def __init__(self) -> None:
         # key -> [fn, spec_args, static_kwargs, count, site]
         self._entries: Dict[Tuple, list] = {}
-        # (site, (qb, b, a, kc)) -> iters_total — measured extract-loop
-        # iteration counts the engines read back post-fence, keyed by
+        # (site, (qb, b, a, kc)) -> [iters_total, wide_iters] — measured
+        # extract-loop iteration counts (and how many of them ran at
+        # full width) the engines read back post-fence, keyed by
         # dispatch shape like the dispatch records themselves (two
         # solves at different shapes under one site must cost their
         # iterations at their own tiles, not the first shape's)
-        self._measured_iters: Dict[Tuple, int] = {}
+        self._measured_iters: Dict[Tuple, List[int]] = {}
 
     def reset(self) -> None:
         """Drop recorded dispatches — callers bracket untimed work (e.g.
@@ -139,17 +140,20 @@ class CostProbe:
         return [tuple(e) for e in self._entries.values()]
 
     def record_measured_iters(self, site: str, iters_total: int,
-                              shape: Tuple[int, int, int, int]) -> None:
+                              shape: Tuple[int, int, int, int],
+                              wide_iters: int = 0) -> None:
         """Attach MEASURED extraction-loop iteration counts to ``site``
         (summed over the kernel's iters output across that site's
-        dispatches at this shape). ``shape`` is the per-dispatch
+        dispatches at this shape; ``wide_iters`` of them ran at full
+        width under the kernel's two-level selection, where the caller
+        read that back too). ``shape`` is the per-dispatch
         (qb, b, a, kc): the collect pass costs each (site, shape)'s
         count at that shape's resolved tiles
         (obs.kernel_cost.extract_loop_cost) and the site's total is no
         longer just the deterministic lower bound."""
-        key = (site, tuple(shape))
-        self._measured_iters[key] = \
-            self._measured_iters.get(key, 0) + int(iters_total)
+        got = self._measured_iters.setdefault((site, tuple(shape)), [0, 0])
+        got[0] += int(iters_total)
+        got[1] += int(wide_iters)
 
     def collect(self) -> Dict[str, Any]:
         """Resolve every recorded signature through cost analysis.
@@ -201,10 +205,11 @@ class CostProbe:
         # engines already summed across that site's dispatches at the
         # shape).
         iters_all = 0
-        for (site, shape), iters_total in self._measured_iters.items():
+        for (site, shape), (iters_total, wide_iters) \
+                in self._measured_iters.items():
             try:
                 loop_flops = kernel_cost.extract_loop_cost(
-                    *shape, iters_total=iters_total)
+                    *shape, iters_total=iters_total, wide_iters=wide_iters)
             except Exception:
                 continue
             flops += loop_flops
@@ -291,9 +296,10 @@ def record_dispatch(fn, args: tuple, statics: Optional[dict] = None,
 
 
 def record_measured_iters(site: str, iters_total: int,
-                          shape: Tuple[int, int, int, int]) -> None:
+                          shape: Tuple[int, int, int, int],
+                          wide_iters: int = 0) -> None:
     """Post-fence hook: measured extract-loop iters for ``site``
     (see CostProbe.record_measured_iters); no-op without a probe."""
     p = _active
     if p is not None:
-        p.record_measured_iters(site, iters_total, shape)
+        p.record_measured_iters(site, iters_total, shape, wide_iters)

@@ -73,27 +73,42 @@ __all__ = ["extract_topk_cost", "extract_loop_cost", "fused_topk_cost",
 
 
 def extract_loop_cost(qb: int, b: int, a: int, kc: int,
-                      iters_total: int) -> float:
+                      iters_total: int, wide_iters: int = 0) -> float:
     """MEASURED extraction-loop FLOPs for ``iters_total`` recorded loop
     iterations (summed over the kernel's (Qb/tq, B/tn) ``iters`` output,
     possibly across many dispatches at the same shape).
 
     One recorded iteration runs ``unroll`` extraction rounds over one
-    (tq, tn) tile; each round does, per ne-quarter of width w = tn/ne:
-    the quarter min (tq*w), the argmin iota-select (2*tq*w), the mask-out
-    (2*tq*w), and the threshold/insert ops on the (tq, kc) lists
-    (~4*tq*kc) — so ~5*tq*tn + 4*ne*tq*kc FLOPs per round. ``a`` (the
-    attribute width) does not enter the loop arithmetic but DOES enter
-    variant resolution (the row width picks tile_n), so it must match
-    the dispatch. Both kernel forms run the same tiles."""
+    (tq, tn) tile. A FULL-WIDTH round does, per ne-quarter of width
+    w = tn/ne: the quarter min (tq*w), the argmin iota-select (2*tq*w),
+    the mask-out (2*tq*w), and the threshold/insert ops on the (tq, kc)
+    lists (~4*tq*kc) — so ~5*tq*tn + 4*ne*tq*kc FLOPs per round. Where
+    the shape takes the two-level selection (``fold`` = F slabs:
+    ops.pallas_extract.fold_slabs) a round runs over the folded
+    (tq, tn/F) array and takes one candidate a row: ~5*tq*tn/F +
+    4*tq*kc; only the ``wide_iters`` of the iterations that a visit
+    ran at full width (a bucket hid a second candidate: the kernel's
+    ``wide`` output says which visits) cost the full-width round. A
+    caller that cannot tell them apart passes 0 and gets the lower
+    bound. (The fold pass itself is deterministic, a visit: it stands
+    in :func:`_streaming_cost`.) ``a`` (the attribute width) does not
+    enter the loop arithmetic but DOES enter variant resolution (the
+    row width picks tile_n), so it must match the dispatch. Both
+    kernel forms run the same tiles."""
     from dmlp_tpu.ops.pallas_distance import _tile
     from dmlp_tpu.ops.pallas_extract import _TN, resolve_variant
 
     v = resolve_variant(kc, b, qb, a)
     tq = _tile(qb, v["tile_q"], 8)
     tn = _tile(b, v.get("tile_n", _TN), 128 * v["ne"])
-    round_flops = 5.0 * tq * tn + 4.0 * v["ne"] * tq * kc
-    return float(iters_total) * v.get("unroll", 1) * round_flops
+    wide_round = 5.0 * tq * tn + 4.0 * v["ne"] * tq * kc
+    fold = v.get("fold", 0)
+    if not fold:
+        wide_iters = iters_total
+    narrow_round = 5.0 * tq * (tn // fold) + 4.0 * tq * kc if fold else 0.0
+    return v.get("unroll", 1) * (
+        float(wide_iters) * wide_round
+        + float(iters_total - wide_iters) * narrow_round)
 
 
 def _streaming_cost(qb: int, b: int, a: int, kc: int,
@@ -115,10 +130,15 @@ def _streaming_cost(qb: int, b: int, a: int, kc: int,
     v = resolve_variant(kc, b, qb, a)
     tq = _tile(qb, v["tile_q"], 8)
     tn = _tile(b, v.get("tile_n", _TN), 128 * v["ne"])
+    # the block-skip prefilter: one VPU min pass, or, where the
+    # selection is two-level, the fold pass that stands in for it
+    # (compare, two selects, max, min an element)
+    prefilter = 5.0 if v.get("fold", 0) else 1.0
     flops = (2.0 * qb * b * a      # MXU cross-term block
              + 2.0 * (qb + b) * a  # |q|^2 / |d|^2 norm reductions
-             + 4.0 * qb * b        # expansion + clamp + floor/sentinel masks
-             + 1.0 * qb * b)       # block-skip prefilter min, one VPU pass
+             + 3.0 * qb * b        # expansion (two adds) + clamp; the
+             #                       sentinel mask rides in the norm row
+             + prefilter * qb * b)
     width = 2.0 if data_dtype == "bfloat16" else 4.0
     byts = width * (qb // tq) * b * a   # data panel, once per query tile
     byts += 4.0 * ((b // tn) * qb * a   # query panel, once per data block

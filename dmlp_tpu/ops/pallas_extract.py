@@ -33,9 +33,22 @@ engine.cpp:233-257, with a threshold-gated extraction):
   measured SLOWER inside the loop — the gate is a single reduction
   before the loop, not predication of every pass.)
 
-Variant selection (tile_q / tile_n / ne / unroll) is one function of
-the dispatch shape (resolve_variant): tile_q and ne from the list width
-kc (tuned_variant), and the data block tile_n from the ROW width (the
+- Two-level selection (ISSUE 47): a warm row gains about one entry a
+  block, yet every round of the loop above sweeps all (tq, tn)
+  elements about eight times to take out at most ne a row. So ONE pass
+  folds the block's slabs of F lane vectors (fold_slabs) to per-bucket
+  minima, their block positions and the buckets' second smallest; the
+  rounds then run over the folded (tq, tn / F) array, one candidate a
+  row a round, at 1 / F of a round's cost, and are exact unless some
+  bucket hides a second entry under its row's threshold. Such a tile
+  (the first blocks of a fold, which insert tens of entries a row, and
+  a few percent of the warm ones) takes the full-width loop, untouched,
+  whole. The block-skip minimum is the folded array's: that pass goes.
+
+Variant selection (tile_q / tile_n / ne / unroll / fold) is one function
+of the dispatch shape (resolve_variant): ne from the list width kc
+(tuned_variant), the slabs of the fold pass from the block (fold_slabs),
+and the data block tile_n from the ROW width (the
 double-buffered (tile_n, a) block is what fills VMEM: 12 800 rows up to
 512 attributes, 6 400 at 960, 2 560 at 2048). An attribute-axis grid
 that keeps 12 800 rows and accumulates the cross term over attribute
@@ -43,8 +56,11 @@ blocks was measured against this on v5e and lost by 25%
 (tuned_variant's docstring).
 
 Ties are kept by lowest global position (strict `m < T` extraction +
-lowest-lane argmin), i.e. the same semantics as the "topk"/"seg" selects;
-the engines' boundary-overflow detection + host repair applies unchanged.
+lowest-lane argmin; the rounds over the folded array compare block
+positions, not lanes), i.e. the same semantics as the "topk"/"seg"
+selects, up to the order of the full-width loop's ne sub-blocks inside
+one block; the engines' boundary-overflow detection + host repair
+applies unchanged.
 
 The kernel requires affine data ids: row j of `d` has global id
 ``id_base + j``, rows at positions >= n_real are sentinels (masked to +inf,
@@ -75,6 +91,7 @@ _TQ = 128    # query rows per tile
 _TN = 12800  # data rows per block, at most (rows past 512 attributes
 #              take a shorter one: resolve_variant)
 _E = 2       # extraction candidates per loop iteration (half-block minima)
+_FOLD_W = 10   # lane vectors the folded array keeps, at least (fold_slabs)
 
 # Public padding contract for callers (engine.single, bench): pad data to
 # whole BLOCK_ROWS blocks and queries to whole QUERY_TILE tiles so _tile
@@ -84,18 +101,32 @@ QUERY_TILE = _TQ
 
 
 def tuned_variant(kc: int) -> dict:
-    """Per-list-width kernel tuning (tile_q, ne), measured on v5e at
-    204800 x 10240 x 64 (a pre-round sweep, its record gone; not
-    re-measured by any cell):
+    """Per-list-width kernel tuning (tile_q, ne).
 
-    - narrow lists (kc <= 64): the r3 default (tq=128, ne=2) — 101.7 ms
-      at kc=64; ne=4 ties (101.3), tq/ne changes within noise.
-    - wide lists (kc > 64): (tq=64, ne=4) wins consistently — 139 vs
-      151 ms at kc=136, 188 vs 215 at kc=256, 306 vs 373 at kc=512
-      (-18%). Wider lists make each insert pass O(tq * kc); smaller query
-      tiles cut the max-over-rows wasted iterations and ne=4 inserts
-      4 candidates per threshold scan. ne=8 / tq=32 / unroll=2 all
-      measured worse (refinement rows in the same artifact).
+    - narrow lists (kc <= 64): (tq 128, ne 2), the r3 default: 101.7 ms
+      at kc 64 against ne 4's 101.3 on v5e at 204800 x 10240 x 64 (a
+      pre-round sweep, its record gone), tq / ne changes within noise.
+    - wide lists (kc > 64): (tq 128, ne 4). Until PR 47 tq was 64 here,
+      chosen on that same sweep because "smaller query tiles cut the
+      max-over-rows wasted iterations" of the full-width loop (139 vs
+      151 ms at kc 136, 306 vs 373 at kc 512); those rounds now run
+      over the folded array (fold_slabs) at a tenth of the cost, and a
+      64-row tile reads every data block twice as often. Re-measured
+      with the fold pass in (TPU v5 lite, PR 47, one resident fold,
+      q1024, chunks of 51 200 x 128, host clock around five folds,
+      lists equal to the bit, F 10 unless said): bf16 rows, kc 120, 196
+      chunks: (64, 4) 67.32 ms, on its block's DMA; (128, 4) 50.29;
+      (128, 2) 50.38 against (128, 4) 50.30 at F 20. bf16 rows at 256
+      lanes under ip (``text2image-10m.bulk``), F 20: (64, 4) 119.52,
+      (128, 4) 70.84. kc 144 / 256, 98 chunks: (64, 4) 41.52 / 50.84,
+      (128, 4) 36.23 / 49.64. kc 512, the float32 multipass sweep over
+      82 chunks: (64, 4) 87.85, (128, 4) 87.45; at F 20 (64, 4) 92.40,
+      (128, 4) 93.05, (128, 2) 100.21, (64, 2) 103.76: ne 4 at every
+      width past 64, and tq 128 wins or ties (0.7%) at every one, so
+      the table has no row for it. WITHOUT the pass the old row still
+      stands (kc 120: (64, 4) 100.14, (128, 4) 106.15; kc 512 sweep:
+      105.97, 109.98): the two were chosen together. ne 8 / tq 32 /
+      unroll 2 all measured worse on the pre-round sweep.
 
     Measured at 960 attributes (PR 31, TPU v5 lite, one fold of 20
     chunks of 51 200 x 960 float32 at q1024, kc 32, the kernel's device
@@ -110,9 +141,7 @@ def tuned_variant(kc: int) -> dict:
     attribute blocks, 130.35 with 256, 97.40 at tq 256: every form
     slower than the shorter data block, so the kernel has no such axis.
     """
-    if kc <= 64:
-        return {"tile_q": _TQ, "ne": _E, "unroll": 1}
-    return {"tile_q": 64, "ne": 4, "unroll": 1}
+    return {"tile_q": _TQ, "ne": _E if kc <= 64 else 4, "unroll": 1}
 
 
 def _whole_lanes(a: int) -> int:
@@ -175,12 +204,17 @@ def lane_padded(a: int) -> int:
 _VMEM_BOUND = 64 * 2**20
 
 
-def vmem_bytes(tq: int, tn: int, a: int, kc: int) -> int:
+def vmem_bytes(tq: int, tn: int, a: int, kc: int, fold: int = 0) -> int:
     """VMEM the kernel's blocks take at tiles (tq, tn): the (tq, tn)
-    distance scratch, the double-buffered q and d blocks and the
-    running lists. A block holds whole 128-lane vectors, so a row of
-    ``a`` attributes weighs ``a`` rounded up to lanes (960 -> 1024)."""
-    return (tq * tn + 2 * (tq + tn) * _whole_lanes(a) + 4 * tq * kc) * 4
+    distance scratch, the double-buffered q and d blocks, the running
+    lists and, where the selection is two-level (``fold`` slabs:
+    fold_slabs), the folded minima and their positions, two (tq,
+    tn / fold) scratches. A block holds whole 128-lane vectors, so a
+    row of ``a`` attributes weighs ``a`` rounded up to lanes
+    (960 -> 1024)."""
+    folded = 2 * tq * (tn // fold) if fold else 0
+    return (tq * tn + folded + 2 * (tq + tn) * _whole_lanes(a)
+            + 4 * tq * kc) * 4
 
 
 def resolve_variant(kc: int, b: int, qb: int | None = None,
@@ -191,14 +225,15 @@ def resolve_variant(kc: int, b: int, qb: int | None = None,
     extract_topk, the engines' jit keys and spans and the analytic
     cost model (obs.kernel_cost) all call it with the same shape, so
     gate, kernel and counters can never disagree. Always carries
-    tile_q/ne/unroll, plus tile_n where the row width shortens the
-    block.
+    tile_q/ne/unroll and ``fold`` (fold_slabs of the block in force: 0
+    where the selection stays one-level), plus tile_n where the row
+    width shortens the block.
 
     tile_q and ne are the kc-tuned variant's, unless ITS ne-alignment
     can't tile this b (wide-k wants ne=4 → b % 512; a caller with
     pre-shaped shards, e.g. the multi-host feed, may only satisfy the
-    ne=2 alignment) — then the default variant keeps kernel coverage
-    at r3 tuning rather than silently dropping to the streaming select.
+    ne=2 alignment) — then ne 2 keeps kernel coverage rather than
+    silently dropping to the streaming select.
 
     The data block follows the row width: ``tile_n`` is the largest
     tile of ``b`` (a 128 * ne multiple that divides it) no longer than
@@ -210,16 +245,65 @@ def resolve_variant(kc: int, b: int, qb: int | None = None,
     block stays _TN."""
     v = tuned_variant(kc)
     if b % (128 * v["ne"]) != 0 and b % (128 * _E) == 0:
-        v = {"tile_q": _TQ, "ne": _E, "unroll": 1}
+        v["ne"] = _E
     gran = 128 * v["ne"]
-    if qb is not None and a is not None and b % gran == 0:
+    tn = _tile(b, _TN, gran) if b % gran == 0 else 0
+    if qb is not None and a is not None and tn:
         tq = _tile(qb, v["tile_q"], 8)
-        tn = widest = _tile(b, _TN, gran)
-        while tn > gran and vmem_bytes(tq, tn, a, kc) > _VMEM_BOUND:
+        widest = tn
+        while tn > gran and vmem_bytes(tq, tn, a, kc,
+                                       fold_slabs(tn)) > _VMEM_BOUND:
             tn = _tile(b, tn - gran, gran)    # the next tile of b down
         if tn < widest:
             v["tile_n"] = tn
+    v["fold"] = fold_slabs(tn) if tn else 0
     return v
+
+
+def fold_slabs(tn: int) -> int:
+    """F, the lane vectors a bucket of the two-level selection folds
+    (_kernel: a block of tn lanes is made in tn / (128 F) slabs of F
+    lane vectors, each slab folds to ONE vector of per-bucket minima,
+    and the extraction rounds run over the folded (tq, tn / F) array),
+    or 0 where the block is one lane vector and the kernel keeps the
+    full-width loop alone. A function of the dispatch shape, like every
+    tile: resolve_variant carries it as ``fold``.
+
+    The largest divisor of the block's tn / 128 lane vectors that
+    leaves the folded array _FOLD_W = 10 of them (1 280 buckets a row:
+    F 10 at 12 800 rows a block, 5 at 6 400, 2 at 2 560); a shorter
+    block folds in two. The pass costs the same at every F, a round
+    1 / F of a full-width one, and two of a row's n entries under its
+    threshold share a bucket with probability ~n(n - 1) F / 2 tn, so
+    the folded WIDTH is what the contest chose (TPU v5 lite, PR 47:
+    one resident fold a case, q1024, chunks of 51 200 rows, host clock
+    around five folds, every variant's sorted lists equal to PR 46's
+    kernel's to the bit; ``wide`` = the visits that took the full-width
+    loop; every figure here and in tuned_variant with all of a block's
+    slabs written out, before they became the loop of _kernel, which
+    adds 0-6% to each: 30.00 -> 30.71 and 50.25 -> 53.22 ms at the
+    first two shapes below):
+
+    - float32 128-d, kc 32, 82 chunks (``bigann.bulk``): PR 46's kernel
+      47.55 ms; F 10 29.96 (wide 4.0%), F 20 29.83 (5.6%), F 25 29.73
+      (5.9%): flat to 0.8%, the widest folded array falls back least.
+    - bf16 128-d, kc 120, 196 chunks (``bigann-10m.bulk``) at (tq 128,
+      ne 4): F 10 49.95 (5.9%), F 20 50.30 (8.2%), F 25 50.78 (9.3%);
+      PR 46's (tq 64, ne 4) kernel 103.84.
+    - float32 128-d, kc 512, the multipass sweep over 82 chunks: no
+      pass 106.14, F 10 87.91, F 20 92.40 at (tq 64, ne 4); 109.98,
+      87.45, 92.94 at (128, 4); the retry (q16, bf16, 196 chunks):
+      8.29, F 10 7.26, F 20 7.27.
+    - kc 144 / 256 (bf16, 98 chunks, tq 128): no pass 66.86 / 79.89,
+      F 10 36.23 / 49.64 (wide 13.6 / 23.3%): the pass wins at every
+      list width the kernel takes, so the list width does not enter.
+    - 1 024 lanes a row (``gist.bulk``, 6 400 rows a block, 20 chunks):
+      48.27 without, 47.51 at F 5 (wide 8.2%), 47.84 at F 10 (12.9%),
+      47.99 at F 25 (21.3%): the visit is its block's DMA either way."""
+    lanes = tn // 128
+    f = max(f for f in range(1, max(2, lanes // _FOLD_W) + 1)
+            if lanes % f == 0)
+    return f if f > 1 else 0
 
 
 def variant_supports(qb: int, b: int, a: int, kc: int, v: dict) -> bool:
@@ -233,7 +317,7 @@ def variant_supports(qb: int, b: int, a: int, kc: int, v: dict) -> bool:
     tq = _tile(qb, v["tile_q"], 8)
     if kc > tn or kc > 512:
         return False
-    return vmem_bytes(tq, tn, a, kc) <= _VMEM_BOUND
+    return vmem_bytes(tq, tn, a, kc, v.get("fold", 0)) <= _VMEM_BOUND
 
 
 def supports(qb: int, b: int, a: int, kc: int) -> bool:
@@ -402,27 +486,89 @@ def _dot_cross(q, d, precision: str):
     return contract(q, d, jax.lax.Precision.HIGHEST)
 
 
-def _score_block(qn, dn, cross, score: str):
-    """The (tq, tn) block the running lists order, ASCENDING, from the
-    cross term and the two norm planes. "l2": the squared distance by
-    the norm expansion |q|^2 + |d|^2 - 2 q.d, clamped at 0 (rounding
-    can push a near-duplicate's below). "ip": -q.d alone, so that the
-    largest inner product is the smallest entry and the extraction, the
-    floor, the masks and every list downstream stay as they are: no
-    norm plane is added and there is NO clamp (the best rows' entries
-    are negative; a row orthogonal to the query reads 0, a zero-padded
-    sentinel row too, which the ``n_real`` mask sends to +inf as
-    under "l2")."""
+#: what the query block is multiplied by before the cross term, by
+#: score: the block the lists order is then norms + cross ("l2") or the
+#: cross term itself ("ip"), one multiply an element fewer. A power of
+#: two and a sign: exact in every first-pass form (the bf16 halves of
+#: -2 q are -2 times q's, every product and every float32 partial sum
+#: scales with them), so the scores are the unscaled form's to the bit.
+_Q_SCALE = {"l2": -2.0, "ip": -1.0}
+
+
+def _score_block(qn, dn, cross, real, score: str):
+    """The (tq, sw) block the running lists order, ASCENDING, from the
+    cross term of the SCALED queries (_Q_SCALE), the two norm planes
+    and ``real`` (1, sw), which lanes hold rows of the corpus: the
+    others (sentinels, positions >= n_real) read +inf, at no cost an
+    element. "l2": the squared distance by the norm expansion |q|^2 +
+    |d|^2 - 2 q.d, clamped at 0 (rounding can push a near-duplicate's
+    below); a sentinel's |d|^2 is read as +inf (its zero-padded row's
+    cross term is 0, so the sum is +inf). "ip": -q.d alone, so that
+    the largest inner product is the smallest entry and the
+    extraction, the floor, the masks and every list downstream stay as
+    they are: no norm plane is added and there is NO clamp (the best
+    rows' entries are negative; a row orthogonal to the query reads 0);
+    one ``max`` against a row of -inf (real) and +inf (sentinel) is the
+    mask."""
     if score == "ip":
-        return -cross
-    return jnp.maximum(qn + dn - 2.0 * cross, 0.0)
+        return jnp.maximum(cross, jnp.where(real, -jnp.inf, jnp.inf))
+    return jnp.maximum(qn + jnp.where(real, dn, jnp.inf) + cross, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("precision", "score"))
+def _slab_scores(q, qn, d, dn, nlive, floor, *, precision: str, score: str):
+    """One slab of a block's scores, (tq, sw), from the scaled query
+    block, the slab's rows and norms: the cross term, the expansion
+    with the sentinel mask riding in the norm row (lanes from ``nlive``
+    on hold no row of the corpus: _score_block) and the per-row floor
+    of the multi-pass extraction (engine.single
+    ._solve_extract_multipass: candidates strictly below it were
+    captured by an earlier pass; None where the caller has none).
+
+    A jitted function, like _fold_slab below: the kernels a process
+    traces (a fresh and a carried one a fold program, a program a
+    bucket) then share ONE trace of a slab (measured, PR 47: with the
+    slab's work written inline in ``jnp`` calls, ten slabs a kernel,
+    the warm-up of every process took 4.7 s longer on the chip's host,
+    compile cache warm, all of it tracing and lowering)."""
+    sw = d.shape[0]
+    cross = _dot_cross(q, d, precision)
+    live = jax.lax.broadcasted_iota(jnp.int32, (1, sw), 1) < nlive
+    dist = _score_block(qn, dn, cross, live, score)
+    if floor is not None:
+        dist = jnp.where(dist < floor, jnp.inf, dist)
+    return dist
+
+
+@functools.partial(jax.jit, static_argnames="fold")
+def _fold_slab(dist, *, fold: int):
+    """A slab's (tq, 128 * fold) scores folded to one lane vector: a
+    bucket's minimum, the slab position it came from (a strict `<` over
+    ascending lane vectors keeps the lowest among equals) and its
+    second smallest, each (tq, 128). In lax primitives: the steps are
+    unrolled, and a ``jnp`` call (an operator on a traced value too)
+    is a jit trace of its own."""
+    lax = jax.lax
+    tq = dist.shape[0]
+    r = lax.slice(dist, (0, 0), (tq, 128))
+    g = lax.full((tq, 128), 0, jnp.int32)
+    r2 = lax.full((tq, 128), jnp.inf, jnp.float32)
+    for v in range(1, fold):
+        e = lax.slice(dist, (0, v * 128), (tq, (v + 1) * 128))
+        lt = lax.lt(e, r)
+        r2 = lax.min(r2, lax.max(r, e))
+        r = lax.select(lt, e, r)
+        g = lax.select(lt, lax.full((tq, 128), v, jnp.int32), g)
+    lane = lax.broadcasted_iota(jnp.int32, (tq, 128), 1)
+    return r, lax.add(lax.mul(g, lax.full((tq, 128), 128, jnp.int32)),
+                      lane), r2
 
 
 def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
-            od_ref, oi_ref, it_ref, dist_s, *, kc: int, fresh: bool, ne: int,
-            unroll: int = 1, block_skip: bool = True,
+            od_ref, oi_ref, it_ref, dist_s, *fold_s, kc: int, fresh: bool,
+            ne: int, unroll: int = 1, block_skip: bool = True,
             mxu_gate: bool = False, precision: str = "f32",
-            score: str = "l2"):
+            score: str = "l2", fold: int = 0, floored: bool = True):
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     tq, tn = dist_s.shape
@@ -431,37 +577,142 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
     # Mosaic kernel once per chunk — id_base differs every chunk).
     n_real = sc_ref[0, 0]
     id_base = sc_ref[0, 1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (tq, tn), 1)
 
-    gate_on = None
-    if not mxu_gate:
-        cross = _dot_cross(q_ref[:], d_ref[:], precision)
-        dist = _score_block(qn_ref[:], dn_ref[:], cross, score)
-        # Per-row floor (multi-pass extraction, engine.single
-        # ._solve_extract_multipass): candidates strictly below the floor
-        # were captured by an earlier pass — mask them so this pass
-        # extracts the NEXT kc-wide slab. Single-pass callers pass -inf
-        # (no-op).
-        dist = jnp.where(dist < f_ref[:], jnp.inf, dist)
-        pos = j * tn + lane
-        dist = jnp.where(pos >= n_real, jnp.inf, dist)
+    # Under the two-level selection the block's scores are made a SLAB
+    # at a time, a dot a slab: ``fold`` lane vectors (128 * fold lanes)
+    # each, tn / (128 * fold) of them (one slab, the block, without
+    # it). A slab's expansion, masks and fold then sit beside the NEXT
+    # slab's MXU passes in the schedule (within a round of the slab loop
+    # below), where one dot over the block left the VPU's share of them
+    # until the last pass had popped (the compiler's own schedule,
+    # PR 47: of a float32 128-d visit's 13 244 bundles before the
+    # loops, 2 000 ran with the MXU idle).
+    sw = 128 * fold if fold else tn
+    nslab = tn // sw
+    slane = jax.lax.broadcasted_iota(jnp.int32, (tq, sw), 1)
 
-        if fresh:
-            # First block seeds the running list with its first kc columns
-            # (cheaper than extracting kc entries one loop pass at a time).
-            @pl.when(j == 0)
-            def _():
-                od_ref[:] = jax.lax.slice(dist, (0, 0), (tq, kc))
-                kpos = jax.lax.broadcasted_iota(jnp.int32, tq_kc, 1)
-                oi_ref[:] = jnp.where(kpos < n_real, id_base + kpos, -1)
-            dist = jnp.where((j == 0) & (lane < kc), jnp.inf, dist)
+    def scores():
+        """The visit's first half: the block's scores into the scratch
+        (the first block of a fresh fold seeds the lists on the way)
+        and what the selection needs to know of them against the rows'
+        thresholds AT THE VISIT'S START, t0 (a threshold only falls
+        during a visit, so every entry the visit can insert is strictly
+        under it), two int32 scalars:
+
+        - ``go``: some row has an entry under its threshold (else the
+          block is skipped: 0 recorded iterations);
+        - ``hid``: the rounds must run at FULL width. Always, without
+          the fold pass. With it, a slab's ``fold`` lane vectors fold
+          to ONE: bucket l of slab f holds the entries at block
+          positions f sw + l, f sw + 128 + l, ...; the pass keeps its
+          minimum ``r``, the block position it came from ``g`` (a
+          strict `<` over ascending vectors keeps the lowest among
+          equals) and its second smallest ``r2``, all three in
+          registers while the slab lasts (accumulators that live
+          across the block go through VMEM every step, and the one
+          store a cycle then bounds the pass). No bucket with r2 < t0:
+          every entry under the threshold is its bucket's minimum, the
+          rounds over the folded (tq, tn / fold) array are exact and a
+          masked bucket needs no refill. Some bucket hides a second
+          entry under its minimum (as many entries under t0 in the
+          block as in ``r`` is the same test, counted; ``max`` and
+          ``min`` are one operation an element fewer): the tile takes
+          the full-width loop over the untouched scratch, whole (its
+          rows share one loop)."""
+        q = q_ref[:] * _Q_SCALE[score]
+        qn = qn_ref[:]
+        floor = f_ref[:] if floored else None
+        nlive = n_real - j * tn     # the block's rows of the corpus
+        def slab(f, acc):
+            """Slab ``f`` (a Python int, or the index of the loop below)
+            into the scratch and, folded, into the two folded arrays;
+            ``acc``: the minima of r and r2 over the slabs so far."""
+            static = isinstance(f, int)
+            lo = f * sw if static else pl.multiple_of(f * sw, sw)
+            cols = slice(lo, lo + sw) if static else pl.ds(lo, sw)
+            # Sentinel rows are the block positions from n_real on
+            dist = _slab_scores(q, qn, d_ref[cols, :], dn_ref[:, cols],
+                                nlive - lo, floor, precision=precision,
+                                score=score)
+            if fresh and static and lo < kc:
+                # The first block seeds the running lists with its first
+                # kc columns (cheaper than extracting kc entries one loop
+                # pass at a time): this slab's share of them, all of
+                # them unless the list is wider than a slab.
+                n = min(kc - lo, sw)
+
+                @pl.when(j == 0)
+                def _():
+                    kpos = lo + jax.lax.broadcasted_iota(jnp.int32, (tq, n),
+                                                         1)
+                    od_ref[:, lo:lo + n] = jax.lax.slice(dist, (0, 0),
+                                                         (tq, n))
+                    oi_ref[:, lo:lo + n] = jnp.where(kpos < n_real,
+                                                     id_base + kpos, -1)
+                dist = jnp.where((j == 0) & (slane < n), jnp.inf, dist)
+            dist_s[:, cols] = dist
+            if not fold:
+                return dist, None
+            r, pos, r2 = _fold_slab(dist, fold=fold)
+            r_s, g_s = fold_s
+            at = slice(f * 128, (f + 1) * 128) if static \
+                else pl.ds(pl.multiple_of(f * 128, 128), 128)
+            r_s[:, at] = r
+            g_s[:, at] = pos + lo
+            if acc is None:
+                return r, r2
+            return jax.lax.min(acc[0], r), jax.lax.min(acc[1], r2)
+
+        # The slabs that seed a fresh fold's lists are written out (the
+        # first, unless the list is wider than a slab); the others run
+        # as a loop, up to five a round, its body traced and lowered
+        # once. Written out ten times a kernel they doubled the time a
+        # process spends in Python on its programs, compile cache warm
+        # (PR 47, the chip's host: +0.8 s a fold program, +4.3 s of
+        # ``bigann.steady``'s set-up of 48), and the compiler overlaps
+        # one slab's VPU work with the next one's MXU passes only inside
+        # a round: ten slabs in two rounds are ~5% more bundles a visit
+        # than all ten written out, one a round 23-49% more
+        # (tools/kernel_bundles.py).
+        peeled = min(-(-kc // sw), nslab) if fresh else 0 if fold else 1
+        inf = jnp.full((tq, 128), jnp.inf, jnp.float32)
+        acc = None if peeled else (inf, inf)
+        for f in range(peeled):
+            acc = slab(f, acc)
+        rest = nslab - peeled
+        if fold and rest > 0:
+            group = next(g for g in (5, 4, 3, 2, 1) if rest % g == 0)
+
+            def slabs(i, acc):
+                for f in range(group):
+                    acc = slab(peeled + i * group + f, acc)
+                return acc
+
+            acc = jax.lax.fori_loop(0, rest // group, slabs, acc)
+        rmin, r2min = acc
+        t0 = jnp.max(od_ref[:], axis=1, keepdims=True)      # (tq, 1)
+        if fold:
+            hid = jnp.max((jnp.min(r2min, axis=1, keepdims=True)
+                           < t0).astype(jnp.int32))
         else:
-            @pl.when(j == 0)
-            def _():
-                od_ref[:] = cd_ref[:]
-                oi_ref[:] = ci_ref[:]
+            # the block minimum of the block-skip test is the block's
+            # (under the fold it is the folded array's: that pass goes)
+            hid = jnp.int32(1)
+        if not block_skip:
+            return jnp.int32(1), hid
+        # Threshold-gated block skipping: strict `<` matches the
+        # extraction's `m < T`, so a skipped block is exactly a block
+        # whose first round would have inserted nothing.
+        bmin = jnp.min(rmin, axis=1, keepdims=True)         # (tq, 1)
+        return jnp.max((bmin < t0).astype(jnp.int32)), hid
 
-        dist_s[:] = dist
+    if not fresh:
+        @pl.when(j == 0)
+        def _():
+            od_ref[:] = cd_ref[:]
+            oi_ref[:] = ci_ref[:]
+    if not mxu_gate:
+        go, hid = scores()
     else:
         # Fused streaming megakernel (ops.pallas_fused): the current
         # k-th-best thresholds gate the MXU TILE itself, not just the
@@ -476,11 +727,6 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
         # whose extraction would have inserted nothing, and the kernel
         # skips the matmul, the scan, and the scratch store outright
         # (0 recorded iterations) — block skipping made free.
-        if not fresh:
-            @pl.when(j == 0)
-            def _():
-                od_ref[:] = cd_ref[:]
-                oi_ref[:] = ci_ref[:]
         from dmlp_tpu.engine.finalize import (EPS_CANCEL_COEF,
                                               EPS_REL_F32, LOWP_COEF,
                                               ip_coef)
@@ -533,27 +779,26 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
             # meaningless — forced on, its value never matters).
             gate_on = gate_on | (j == 0)
 
-        @pl.when(gate_on)
-        def _():
-            cross = _dot_cross(q_ref[:], d_ref[:], precision)
-            dist = _score_block(qn_ref[:], dn_ref[:], cross, score)
-            dist = jnp.where(dist < f_ref[:], jnp.inf, dist)
-            pos = j * tn + lane
-            dist = jnp.where(pos >= n_real, jnp.inf, dist)
-            dist_s[:] = dist
+        go, hid = jax.lax.cond(
+            gate_on, scores, lambda: (jnp.int32(0), jnp.int32(0)))
 
-        if fresh:
-            @pl.when(j == 0)
-            def _():
-                d0 = dist_s[:]
-                od_ref[:] = jax.lax.slice(d0, (0, 0), (tq, kc))
-                kpos = jax.lax.broadcasted_iota(jnp.int32, tq_kc, 1)
-                oi_ref[:] = jnp.where(kpos < n_real, id_base + kpos, -1)
-                dist_s[:] = jnp.where(lane < kc, jnp.inf, d0)
-
-    kiota = jax.lax.broadcasted_iota(jnp.int32, tq_kc, 1)
     w = tn // ne
     wlane = jax.lax.broadcasted_iota(jnp.int32, (tq, w), 1)
+    kiota = jax.lax.broadcasted_iota(jnp.int32, tq_kc, 1)
+
+    def insert(m, pos):
+        """The running lists take the candidate ``m`` (tq, 1), at block
+        position ``pos``, in place of a row's current k-th best wherever
+        it is strictly better; returns where it was."""
+        rd = od_ref[:]
+        t = jnp.max(rd, axis=1, keepdims=True)              # (tq, 1)
+        better = m < t                                      # (tq, 1)
+        wi = jnp.min(jnp.where(rd == t, kiota, kc), axis=1,
+                     keepdims=True)
+        ins = better & (kiota == wi)
+        od_ref[:] = jnp.where(ins, m, rd)
+        oi_ref[:] = jnp.where(ins, id_base + j * tn + pos, oi_ref[:])
+        return better
 
     def round_():
         # Each quarter independently: find its min, insert if it beats the
@@ -569,61 +814,60 @@ def _kernel(sc_ref, q_ref, d_ref, qn_ref, dn_ref, f_ref, cd_ref, ci_ref,
             m = jnp.min(qd, axis=1, keepdims=True)          # (tq, 1)
             am = jnp.min(jnp.where(qd == m, wlane, w), axis=1,
                          keepdims=True)                     # (tq, 1)
-            rd = od_ref[:]
-            t = jnp.max(rd, axis=1, keepdims=True)          # (tq, 1)
-            better = m < t                                  # (tq, 1)
-            wi = jnp.min(jnp.where(rd == t, kiota, kc), axis=1,
-                         keepdims=True)
-            ins = better & (kiota == wi)
-            od_ref[:] = jnp.where(ins, m, rd)
-            gid = id_base + j * tn + e * w + am
-            oi_ref[:] = jnp.where(ins, gid, oi_ref[:])
+            better = insert(m, e * w + am)
             dist_s[:, e * w:(e + 1) * w] = jnp.where(
                 better & (wlane == am), jnp.inf, qd)
             go = go + jnp.max(better.astype(jnp.int32))
         return go
 
-    def body(state):
-        it, _ = state
-        # `unroll` extraction rounds per loop-condition sync. Correctness
-        # needs only the LAST round's found-any flag: if that round found
-        # nothing, no remaining candidate beats any row's threshold.
-        for _u in range(unroll - 1):
-            round_()
-        go = round_()
-        return it + 1, go > 0
+    def narrow_round():
+        # The same round over the folded array: the row minimum, the
+        # lowest BLOCK POSITION among equals (not the lowest lane),
+        # insert, mask the bucket. One candidate a row a round.
+        r_s, g_s = fold_s
+        r, g = r_s[:], g_s[:]
+        m = jnp.min(r, axis=1, keepdims=True)
+        am = jnp.min(jnp.where(r == m, g, tn), axis=1, keepdims=True)
+        better = insert(m, am)
+        r_s[:] = jnp.where(better & (g == am), jnp.inf, r)
+        return jnp.max(better.astype(jnp.int32))
 
-    if block_skip:
-        # Threshold-gated block skipping: one VPU min over the block per
-        # row, against the row's CURRENT k-th best. Strict `<` matches
-        # the extraction's `m < T`, so a skipped block is exactly a
-        # block whose first round would have inserted nothing — the
-        # while-loop below then never starts (0 recorded iterations)
-        # and the no-improve cost drops from a full ne-pass round to
-        # this one reduction.
-        t0 = jnp.max(od_ref[:], axis=1, keepdims=True)      # (tq, 1)
-        # The MXU-gated kernel has no local `dist` value (the compute is
-        # predicated); read the scratch it conditionally stored — stale
-        # contents when the gate fired are masked out by the AND below.
-        bmin = jnp.min(dist_s[:] if mxu_gate else dist, axis=1,
-                       keepdims=True)                       # (tq, 1)
-        go0 = jnp.max((bmin < t0).astype(jnp.int32)) > 0
-    else:
-        go0 = True
-    if gate_on is not None:
-        go0 = gate_on & go0
-    iters, _ = jax.lax.while_loop(
-        lambda s: s[1] & (s[0] <= tn), body, (jnp.int32(0), go0))
-    # Diagnostic loop counts: lane j of this tile's block (row 0 is read
-    # back; an iota-select avoids dynamic-lane scalar stores). With
-    # block_skip, 0 means the prefilter skipped the block entirely.
+    def loop(one_round, go0, width):
+        """Rounds of ``one_round`` until one inserts nothing (at most
+        ``width`` + 1: a round that inserts masks an entry); never
+        entered where ``go0`` is false. Returns the rounds run."""
+        def body(state):
+            it, _ = state
+            # `unroll` extraction rounds per loop-condition sync.
+            # Correctness needs only the LAST round's found-any flag: if
+            # that round found nothing, no remaining candidate beats any
+            # row's threshold.
+            for _u in range(unroll - 1):
+                one_round()
+            return it + 1, one_round() > 0
+
+        return jax.lax.while_loop(
+            lambda s: s[1] & (s[0] <= width), body, (jnp.int32(0), go0))[0]
+
+    # One of the two loops runs, or neither (0 recorded iterations: a
+    # gate or the block-skip test let the visit run no round).
+    wide = ((go > 0) & (hid > 0)).astype(jnp.int32)
+    iters = loop(round_, wide > 0, tn)
+    if fold:
+        iters = iters + loop(narrow_round, (go > 0) & (hid == 0),
+                             tn // fold)
+    # Diagnostics: lane j of this tile's block holds, in row 0, the
+    # rounds the visit ran and, in row 1, whether it ran them at full
+    # width (an iota-select avoids dynamic-lane scalar stores).
     njs = it_ref.shape[1]
     ji = jax.lax.broadcasted_iota(jnp.int32, (tq, njs), 1)
+    ri = jax.lax.broadcasted_iota(jnp.int32, (tq, njs), 0)
 
     @pl.when(j == 0)
     def _():
         it_ref[:] = jnp.zeros((tq, njs), jnp.int32)
-    it_ref[:] = jnp.where(ji == j, iters, it_ref[:])
+    it_ref[:] = jnp.where(ji == j, jnp.where(ri == 1, wide, iters),
+                          it_ref[:])
 
     # Output blocks map to (i, 0): they stay VMEM-resident across the
     # data-block sweep and flush once after the last block.
@@ -649,12 +893,17 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
                  kc: int, interpret: bool = False,
                  tile_q: int | None = None, tile_n: int | None = None,
                  ne: int | None = None, unroll: int | None = None,
+                 fold: int | None = None,
                  block_skip: bool = True, mxu_gate: bool = False,
                  floor: jax.Array | None = None, precision: str = "f32",
-                 score: str = "l2"):
+                 score: str = "l2", with_wide: bool = False):
     """(queries (Qb, A), data (B, A)) -> (dists (Qb, kc) f32 ascending-ish
     unsorted, ids (Qb, kc) i32, iters (Qb/tq, B/tn) i32 loop counts; 0 =
-    the threshold prefilter skipped that block).
+    the threshold prefilter skipped that block) and, under
+    ``with_wide``, a fourth: wide (Qb/tq, B/tn) i32, 1 where the visit's
+    rounds ran at FULL width (the two-level selection fell back, or the
+    shape takes no fold pass), 0 where they ran over the folded array
+    or not at all.
     Rows >= n_real are sentinels; data row j has global id id_base + j.
 
     The data comes in one of two forms, told apart by its rank. A
@@ -682,8 +931,9 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     dist < floor are masked out (the multi-pass wide-k driver raises it
     to the previous pass's max − eps each pass).
 
-    tile_q/tile_n/ne/unroll default to the resolved variant
-    (resolve_variant); pass them explicitly only to override (a
+    tile_q/tile_n/ne/unroll/fold default to the resolved variant
+    (resolve_variant; ``fold`` to fold_slabs of the block the tiles in
+    force make); pass them explicitly only to override (a
     resident engine's program passes the statics that key its jit, a
     test a tiling of its own). The resolution happens OUT HERE, before
     the jit boundary, so the CONCRETE variant is part of the jit cache
@@ -739,32 +989,41 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         id_base = jax.device_put(_onp.int32(id_base))
     if isinstance(chunk, (int, _onp.integer)):
         chunk = jax.device_put(_onp.int32(chunk))
+    tile_q = v["tile_q"] if tile_q is None else tile_q
+    tile_n = v.get("tile_n", _TN) if tile_n is None else tile_n
+    ne = v["ne"] if ne is None else ne
+    if fold is None:
+        # of the block the tiles in force make of this dispatch (the
+        # resolved variant's, unless the caller passed tiles of its own)
+        b = d_attrs.shape[-2]
+        fold = fold_slabs(_tile(b, tile_n, 128 * ne)) \
+            if b % (128 * ne) == 0 else 0
     if precision not in PRECISIONS:
         raise ValueError(f"unsupported first-pass precision {precision!r} "
                          "(int8 is the gated follow-on — see ROADMAP)")
     if score not in SCORES:
         raise ValueError(f"unknown score {score!r} (one of {SCORES})")
-    return _extract_topk_jit(
+    out = _extract_topk_jit(
         q_attrs, d_attrs, carry_d, carry_i, n_real=n_real,
         id_base=id_base, chunk=chunk, d_norms=d_norms, kc=kc,
         interpret=interpret,
-        tile_q=v["tile_q"] if tile_q is None else tile_q,
-        tile_n=v.get("tile_n", _TN) if tile_n is None else tile_n,
-        ne=v["ne"] if ne is None else ne,
-        unroll=v["unroll"] if unroll is None else unroll,
+        tile_q=tile_q, tile_n=tile_n, ne=ne,
+        unroll=v["unroll"] if unroll is None else unroll, fold=fold,
         block_skip=block_skip, mxu_gate=mxu_gate, floor=floor,
         precision=precision, score=score)
+    return out if with_wide else out[:3]
 
 
 @functools.partial(
     jax.jit, static_argnames=("kc", "interpret", "tile_q", "tile_n", "ne",
-                              "unroll", "block_skip", "mxu_gate",
+                              "unroll", "fold", "block_skip", "mxu_gate",
                               "precision", "score"))
 def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
                       id_base, kc: int, interpret: bool, tile_q: int,
                       tile_n: int, ne: int, unroll: int, block_skip: bool,
                       mxu_gate: bool, floor, precision: str = "f32",
-                      chunk=None, d_norms=None, score: str = "l2"):
+                      chunk=None, d_norms=None, score: str = "l2",
+                      fold: int = 0):
     qb, a = q_attrs.shape
     b = d_attrs.shape[-2]
     tq = _tile(qb, tile_q, 8)
@@ -773,11 +1032,13 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     # the fresh-seed slice and quarter layout need kc <= tn, and the
     # distance scratch + double-buffered blocks must fit VMEM.
     if not (qb % 8 == 0 and b % (128 * ne) == 0 and kc <= tn
-            and kc <= 512 and vmem_bytes(tq, tn, a, kc) <= _VMEM_BOUND):
+            and kc <= 512 and tn % (128 * max(fold, 1)) == 0
+            and vmem_bytes(tq, tn, a, kc, fold) <= _VMEM_BOUND):
         # ValueError, not assert: a caller that skipped supports() must
         # fail loudly under ``python -O`` too, not compute garbage.
         raise ValueError(
-            f"untileable (qb={qb}, b={b}, kc={kc}, tq={tq}, tn={tn}, ne={ne})")
+            f"untileable (qb={qb}, b={b}, kc={kc}, tq={tq}, tn={tn}, "
+            f"ne={ne}, fold={fold})")
 
     # One form inside: a (nchunks, B, A) stack, its (nchunks, 1, B)
     # norms and a chunk index. A (B, A) block is a stack of one, index 0.
@@ -809,7 +1070,7 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
             blk = blk.astype(jnp.float32)
         stack, norms, chunk = blk[None], norms[None, None], 0
 
-    fresh = carry_d is None
+    fresh, floored = carry_d is None, floor is not None
     if fresh:
         carry_d = jnp.full((qb, kc), jnp.inf, jnp.float32)
         carry_i = jnp.full((qb, kc), -1, jnp.int32)
@@ -823,7 +1084,7 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
     kern = functools.partial(_kernel, kc=kc, fresh=fresh, ne=ne,
                              unroll=unroll, block_skip=block_skip,
                              mxu_gate=mxu_gate, precision=precision,
-                             score=score)
+                             score=score, fold=fold, floored=floored)
     # The name is what a profiler capture and the compiled HLO show for
     # this custom call (``%dmlp_topk_fused.1 = ... custom-call(...)``):
     # it states the form, so a trace tells the MXU-gated kernel from the
@@ -855,7 +1116,11 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
                 # block would be clobbered across megacore cores.
                 pl.BlockSpec((tq, b // tn), lambda i, j, sc: (i, 0)),
             ],
-            scratch_shapes=[pltpu.VMEM((tq, tn), jnp.float32)],
+            # the distance block and, under the two-level selection,
+            # its folded minima and their block positions
+            scratch_shapes=[pltpu.VMEM((tq, tn), jnp.float32)] + (
+                [pltpu.VMEM((tq, tn // fold), jnp.float32),
+                 pltpu.VMEM((tq, tn // fold), jnp.int32)] if fold else []),
         ),
         out_shape=[
             jax.ShapeDtypeStruct((qb, kc), jnp.float32),
@@ -867,4 +1132,4 @@ def _extract_topk_jit(q_attrs, d_attrs, carry_d, carry_i, *, n_real,
             vmem_limit_bytes=96 * 2**20),
         interpret=interpret,
     )(scalars, q32, stack, qn, norms, floor, carry_d, carry_i)
-    return out_d, out_i, out_iters[::tq]
+    return out_d, out_i, out_iters[::tq], out_iters[1::tq]

@@ -68,11 +68,11 @@ def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
                id_base=0, kc: int, interpret: bool = False,
                block_skip: bool = True,
                floor: jax.Array | None = None, precision: str = "f32",
-               score: str = "l2"):
+               score: str = "l2", with_wide: bool = False):
     """Drop-in for ops.pallas_extract.extract_topk with the MXU tile
-    gate on. Same signature, same (dists, ids, iters) outputs,
-    bit-identical results; ``iters`` reports 0 for blocks either gate
-    elided.
+    gate on. Same signature, same (dists, ids, iters) outputs (and
+    ``wide`` behind them under ``with_wide``), bit-identical results;
+    ``iters`` reports 0 for blocks either gate elided.
     ``precision`` ("f32" | "bf16x3" | "bf16") selects the first-pass
     form exactly as in extract_topk — the MXU-gate bound widens by the
     engine.finalize.lowp_eps margin in-kernel, so gating stays sound
@@ -90,7 +90,7 @@ def fused_topk(q_attrs: jax.Array, d_attrs: jax.Array,
         q_attrs, d_attrs, carry_d, carry_i, n_real=n_real,
         id_base=id_base, kc=kc, interpret=interpret,
         block_skip=block_skip, mxu_gate=True, floor=floor,
-        precision=precision, score=score)
+        precision=precision, score=score, with_wide=with_wide)
 
 
 def resolve_topk_kernel(qb: int, b: int, a: int, kc: int,

@@ -163,7 +163,7 @@ def _update_chunk(stack, norms, blk, c):
 #: what picks the compiled kernel: every one is part of the two
 #: programs' jit cache key, resolved by their caller OUTSIDE the jit
 _KERNEL_STATICS = ("kc", "interpret", "tile_q", "tile_n", "ne", "unroll",
-                   "mxu_gate", "precision", "score")
+                   "fold", "mxu_gate", "precision", "score")
 
 
 def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
@@ -179,7 +179,8 @@ def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
     v = resolve_variant(kc, b, qb, a)
     return dict(kc=kc, interpret=interpret, tile_q=v["tile_q"],
                 tile_n=v.get("tile_n", _TN), ne=v["ne"],
-                unroll=v["unroll"], mxu_gate=impl == "fused",
+                unroll=v["unroll"], fold=v.get("fold", 0),
+                mxu_gate=impl == "fused",
                 precision=precision, score=score)
 
 
@@ -196,26 +197,31 @@ def fold_chunks(q, stack, norms, order, nfold, span, **kern):
     nothing is sliced, copied or recomputed a chunk. ``span(c)`` gives
     chunk ``c``'s (id_base, n_real) as traced
     values: the one thing the two engines derive differently.
-    Returns (dists, ids, gated, iters): ``gated`` counts the (query
-    tile, data block) pairs either gate elided (0 recorded
-    iterations), ``iters`` sums the recorded iterations."""
+    Returns (dists, ids, gate, iters). ``gate`` counts (query tile,
+    data block) pairs, an int32 pair: [0] those either gate elided (0
+    recorded iterations), [1] those whose extraction ran at full width
+    (the kernel's two-level selection found a bucket hiding a second
+    candidate, or the shape takes no fold pass:
+    ``ops.pallas_extract.fold_slabs``). ``iters`` sums the recorded
+    iterations, a pair too: [0] all of them, [1] those of the
+    full-width visits (``obs.kernel_cost.extract_loop_cost`` prices
+    the two apart)."""
     from dmlp_tpu.ops.pallas_extract import extract_topk
 
     def fold(c, od, oi):
         id_base, n_real = span(c)
-        return extract_topk(q, stack, od, oi, n_real=n_real,
-                            id_base=id_base, chunk=c, d_norms=norms,
-                            **kern)
-
-    od, oi, its = fold(order[0], None, None)
+        od, oi, its, wide = extract_topk(
+            q, stack, od, oi, n_real=n_real, id_base=id_base, chunk=c,
+            d_norms=norms, with_wide=True, **kern)
+        return od, oi, jnp.stack([jnp.sum(its == 0), jnp.sum(wide)]), \
+            jnp.stack([jnp.sum(its), jnp.sum(its * wide)])
 
     def body(i, carry):
-        od, oi, gated, iters = carry
-        od, oi, its = fold(order[i], od, oi)
-        return od, oi, gated + jnp.sum(its == 0), iters + jnp.sum(its)
+        od, oi, gate, iters = carry
+        od, oi, g, its = fold(order[i], od, oi)
+        return od, oi, gate + g, iters + its
 
-    return jax.lax.fori_loop(
-        1, nfold, body, (od, oi, jnp.sum(its == 0), jnp.sum(its)))
+    return jax.lax.fori_loop(1, nfold, body, fold(order[0], None, None))
 
 
 def fold_tiles(kern: Dict[str, Any], qb: int, cr: int) -> int:
@@ -232,16 +238,14 @@ def _fold_stack(q, stack, norms, order, nfold, n_real, **kern):
     ``c * chunk_rows`` on, real up to ``n_real``. ``order`` (padded to
     a fixed length), ``nfold`` and ``n_real`` are device data, so a new
     schedule, a pruned chunk or an ingest runs the same executable.
-    Returns (dists, ids, gated)."""
+    Returns (dists, ids, gate): ``fold_chunks``' first three."""
     cr = stack.shape[1]
 
     def span(c):
         lo = c * cr
         return lo, jnp.minimum(n_real - lo, cr)
 
-    od, oi, gated, _iters = fold_chunks(q, stack, norms, order, nfold,
-                                        span, **kern)
-    return od, oi, gated
+    return fold_chunks(q, stack, norms, order, nfold, span, **kern)[:3]
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
@@ -269,7 +273,8 @@ def _variant_args(v: Optional[Dict[str, Any]]) -> Dict[str, Any]:
     if not v:
         return {}
     return {"tile_n": _TN, "norms": "computed", **{k: v[k] for k in (
-        "tile_q", "tile_n", "ne", "a_pad", "mxu_passes", "norms", "score")
+        "tile_q", "tile_n", "ne", "fold", "a_pad", "mxu_passes", "norms",
+        "score")
         if k in v}}
 
 
@@ -293,7 +298,8 @@ class PendingBatch(PendingRun):
     kernel_calls: int = 0
     mp_passes: int = 0
     last_prune: Optional[Dict[str, Any]] = None
-    # (gated-tile count still on the device, tiles the fold visited)
+    # (fold_chunks' gate counts still on the device, tiles the fold
+    # visited)
     gate: Optional[Tuple] = None
     # perf_counter at which the fold (the multipass merge) had been
     # dispatched: the start of the serve.solve_epilogue span, which
@@ -688,19 +694,23 @@ class ResidentServingCore:
     # -- gate effectiveness (the fused kernel's gated-tile count) ------------
 
     def _flush_gate(self, sp, gate: Optional[Tuple]) -> None:
-        """Read back a batch's gated-tile count (``gate``: a scalar, or
-        a mesh engine's one count a cell, summed here, with the tiles
-        its fold visited: a host sync, after the result fetch) into
-        ``last_gated_fraction`` and the span."""
+        """Read back a batch's gate counts (``gate``: ``fold_chunks``'
+        [gated, wide] pair, or a mesh engine's one pair a cell, summed
+        here, with the tiles its fold visited: a host sync, after the
+        result fetch) into ``last_gated_fraction`` and the span: of
+        ``tiles`` visits, ``gated`` extracted nothing and ``wide``
+        (``wide_pct`` of them) extracted at full width."""
         if gate is None:
             return
         gz, ntiles = gate
         try:
             with obs_trace.device_wait("gate", self.trace_batch):
                 got = jax.device_get(gz)  # check: allow-host-sync
-            gated = int(np.sum(got))
+            gated, wide = (int(n) for n in
+                           np.asarray(got).reshape(-1, 2).sum(axis=0))
             self.last_gated_fraction = gated / max(ntiles, 1)
-            sp.set(gated=gated, tiles=ntiles)
+            sp.set(gated=gated, tiles=ntiles, wide=wide,
+                   wide_pct=round(100.0 * wide / max(ntiles, 1), 3))
         except Exception:  # check: no-retry — stats never fail a batch
             pass
 
@@ -1335,7 +1345,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                        prec: str):
         """Dispatch ONE program that folds the resident chunks
         ``order`` names, in that order (_fold_stack). Returns the
-        running lists, the gated-tile count (all three still on the
+        running lists, the gate counts (all three still on the
         device) and the number of (query tile, data block) pairs the
         fold visited."""
         cr = self._ex_chunk_rows

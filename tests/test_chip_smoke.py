@@ -451,7 +451,7 @@ def test_cli_summary_says_where_it_ran(tmp_path):
     assert stamp["degrade_rung"] == "lowp"
     assert stamp["degradations"] == [] and stamp["retries"] == 0
     assert set(stamp["kernel_variant"]) == {
-        "tile_q", "ne", "unroll", "kc", "mxu_passes"}
+        "tile_q", "ne", "unroll", "fold", "kc", "mxu_passes"}
     assert summary["parser"] == "python"
     assert set(summary["compile_cache"]) == {
         "dir", "requests", "hits", "misses", "backend_compile_ms"}

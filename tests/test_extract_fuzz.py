@@ -426,9 +426,56 @@ def test_extract_block_skip_output_identical_fuzz(seed):
     assert outs[True][2] <= outs[False][2]
 
 
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+@pytest.mark.parametrize("seed", range(601, 609))
+def test_two_level_selection_fuzz(seed, gate):
+    """Direct-kernel A/B over the fuzz distribution (duplicate-heavy
+    grids included), with and without the fold pass (PR 47: blocks of
+    256 rows fold to 2 slabs of 128): a fresh call and a warm carried
+    one. The same score multiset a row always; the same (score, id)
+    pairs wherever the row's last score is not tied with a score left
+    out; every id reproduces its score; and a visit is wide only if it
+    ran a round."""
+    import jax.numpy as jnp
+
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+
+    inp = _case(seed)
+    kc = 16
+    d, q, n_real, _ = _pad_stage(inp.data_attrs, inp.query_attrs)
+    outs = {}
+    for fold in (2, 0):
+        kw = dict(kc=kc, interpret=True, tile_n=256, fold=fold,
+                  mxu_gate=gate, with_wide=True)
+        od, oi, it1, w1 = extract_topk(q, d, n_real=n_real, **kw)
+        od, oi, it2, w2 = extract_topk(q, d, od, oi, n_real=n_real,
+                                       id_base=d.shape[0], **kw)
+        outs[fold] = (np.asarray(od), np.asarray(oi))
+        for it, w in ((it1, w1), (it2, w2)):
+            assert not (np.asarray(w) & (np.asarray(it) == 0)).any()
+    (od, oi), (od0, oi0) = outs[2], outs[0]
+    assert np.array_equal(np.sort(od, axis=1), np.sort(od0, axis=1))
+    # ids reproduce their scores: both copies of the corpus
+    rows = np.concatenate([np.asarray(d), np.asarray(d)])
+    rec = ((np.asarray(q)[:, None, :] - rows[np.clip(oi, 0, None)]) ** 2
+           ).sum(-1)
+    assert np.allclose(np.where(oi >= 0, rec, 0),
+                       np.where(oi >= 0, od, 0), rtol=1e-5, atol=1e-3)
+    assert np.array_equal(oi >= 0, np.isfinite(od))
+    # the same pairs where no tie straddles the list's end
+    dist = ((np.asarray(q, np.float64)[:, None, :]
+             - np.asarray(d, np.float64)[None, :n_real]) ** 2).sum(-1)
+    for r in range(q.shape[0]):
+        last = np.max(od[r])
+        if np.isfinite(last) and 2 * int((dist[r] <= last * (1 + 1e-6)
+                                          ).sum()) == kc:
+            assert sorted(zip(od[r].tolist(), oi[r].tolist())) \
+                == sorted(zip(od0[r].tolist(), oi0[r].tolist())), r
+
+
 def test_extract_engine_wide_k_tuned_variant():
-    """k > 64 routes to the wide-list tuned variant (tq=64, ne=4,
-    SWEEP_WIDEK_r04); parity must hold there too."""
+    """k > 64 routes to the wide-list tuned variant (ne=4; tq=128 since
+    PR 47, with the fold pass); parity must hold there too."""
     rng = np.random.default_rng(79)
     n, nq, na = 1400, 9, 5
     data = rng.uniform(-15, 15, (n, na))

@@ -346,6 +346,11 @@ def test_the_mesh_spans_carry_what_the_metrics_read(traced):
     assert traced["stats"]["engine"]["extract_chunks"] == fold["chunks"]
     after = named(traced["served"], "fleet.after_batch")[0]["args"]
     assert 0 <= after["gated"] <= after["tiles"] and after["tiles"] > 0
+    # the kernel's two-level selection: visits that extracted at full
+    # width, what select_wide_pct.mesh reads
+    assert 0 <= after["wide"] <= after["tiles"] - after["gated"]
+    assert after["wide_pct"] == pytest.approx(
+        100.0 * after["wide"] / after["tiles"], abs=1e-3)
     hz = named(traced["served"], "fleet.hazard")[0]["args"]
     assert hz["rows"] == 100000 and hz["flagged"] == 0
     assert hz["dn_max_cached"] is True      # warm-up's batch made the pass
@@ -455,21 +460,22 @@ def _reference_mesh_fold(eng, q_dev, order, live, n, kc, prec):
     ods, ois, gated, tiles = [], [], [], 0
     for rr in range(r):
         od = oi = None
-        g = 0
+        g = np.zeros(2, np.int64)   # gated, at full width
         for t in order:
             id_base = rr * sr + t * cr
             n_real = int(np.clip(min(n - id_base, sr - t * cr), 0, cr))
             if not live[rr, t]:
                 n_real = 0
-            od, oi, its = kern(
+            od, oi, its, wd = kern(
                 q, jax.device_put(stack[t, rr * cr:(rr + 1) * cr]), od, oi,
                 n_real=n_real, id_base=id_base, kc=kc,
-                interpret=eng._interpret, precision=prec)
-            g += int(np.count_nonzero(np.asarray(its) == 0))
+                interpret=eng._interpret, precision=prec, with_wide=True)
+            g += np.asarray([np.count_nonzero(np.asarray(its) == 0),
+                             np.asarray(wd).sum()])
             tiles += its.size
         ods.append(np.array(od))
         ois.append(np.array(oi))
-        gated.append(g)
+        gated.append(g.tolist())
     return np.stack(ods), np.stack(ois), gated, tiles
 
 

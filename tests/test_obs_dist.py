@@ -436,6 +436,49 @@ def test_extract_cost_measured_iters_term():
     assert m1["bytes_accessed"] == base["bytes_accessed"]
 
 
+def test_extract_loop_cost_prices_narrow_and_full_width_rounds_apart():
+    """PR 47: where the shape takes the two-level selection a recorded
+    iteration runs over the folded (tq, tn / F) array unless its visit
+    fell back; ``wide_iters`` of them cost the full-width round, and
+    the fold pass itself is deterministic, in the streaming base."""
+    from dmlp_tpu.ops.pallas_extract import resolve_variant
+    qb, b, a = 1024, 51200, 128
+    v = resolve_variant(32, b, qb, a)
+    tq, tn, f = v["tile_q"], 12800, v["fold"]
+    assert (tq, v["ne"], f) == (128, 2, 10)
+    wide = 5.0 * tq * tn + 4.0 * 2 * tq * 32
+    narrow = 5.0 * tq * (tn // f) + 4.0 * tq * 32
+    assert kernel_cost.extract_loop_cost(qb, b, a, 32, 100) \
+        == pytest.approx(100 * narrow)
+    assert kernel_cost.extract_loop_cost(qb, b, a, 32, 100, wide_iters=30) \
+        == pytest.approx(70 * narrow + 30 * wide)
+    assert narrow < wide / 8
+    # a block of one lane vector has no fold pass: every iteration is
+    # full width, whatever the caller says of it
+    assert resolve_variant(8, 128, 8, 8)["fold"] == 0
+    full = 5.0 * 8 * 128 + 4.0 * 2 * 8 * 8
+    assert kernel_cost.extract_loop_cost(8, 128, 8, 8, 10) \
+        == kernel_cost.extract_loop_cost(8, 128, 8, 8, 10, wide_iters=3) \
+        == pytest.approx(10 * full)
+    # the pass rides the deterministic base: 5 operations an element
+    # where the block-skip minimum takes 1
+    for (q_, b_, a_, kc_), prefilter in (((qb, b, a, 32), 5.0),
+                                         ((8, 128, 8, 8), 1.0)):
+        assert kernel_cost.extract_topk_cost(q_, b_, a_, kc_)["flops"] \
+            == pytest.approx(2.0 * q_ * b_ * a_ + 2.0 * (q_ + b_) * a_
+                             + (3.0 + prefilter) * q_ * b_)
+    from dmlp_tpu.ops.pallas_extract import extract_topk
+    probe = obs_counters.CostProbe()
+    probe.record(extract_topk, (jnp.zeros((qb, a), jnp.float32),
+                                jnp.zeros((b, a), jnp.float32)),
+                 statics=dict(kc=32), site="s")
+    probe.record_measured_iters("s", 100, (qb, b, a, 32), wide_iters=30)
+    probe.record_measured_iters("s", 50, (qb, b, a, 32))
+    assert probe.collect()["flops"] == pytest.approx(
+        kernel_cost.extract_topk_cost(qb, b, a, 32)["flops"]
+        + 120 * narrow + 30 * wide)
+
+
 def test_probe_folds_measured_iters_into_site():
     from dmlp_tpu.ops.pallas_extract import extract_topk
 

@@ -214,7 +214,7 @@ def _untraced_fold(q, d, precision, gate):
     cd = jnp.asarray(np.sort(rng.uniform(0, 9e4, (q.shape[0], 24))),
                      jnp.float32)
     ci = jnp.asarray(rng.integers(0, 512, cd.shape), jnp.int32)
-    od, oi, _ = _extract_topk_jit.__wrapped__(
+    od, oi, *_ = _extract_topk_jit.__wrapped__(
         q, d, cd, ci, n_real=jnp.int32(500), id_base=jnp.int32(512), kc=24,
         interpret=True, tile_q=128, tile_n=12800, ne=2, unroll=1,
         block_skip=True, mxu_gate=gate, floor=None, precision=precision)
@@ -293,6 +293,196 @@ def test_only_a_bfloat16_pair_hands_the_kernel_a_bfloat16_block():
     assert np.array_equal(mixed[0], old[0]) \
         and np.array_equal(mixed[1], old[1])
     assert not np.array_equal(mixed[0], rounded[0])
+
+
+# -- two-level selection: fold to per-bucket minima, rounds over them (PR 47) --
+
+def _run(q, d, carry=(None, None), **kw):
+    """One kernel call at (tile_n 512, 4 slabs of 128 lanes, kc 24
+    unless given): numpy (dists, ids, iters, wide)."""
+    kw = {"kc": 24, "tile_n": 512, "fold": 4, "interpret": True, **kw}
+    return [np.asarray(x) for x in extract_topk(q, d, *carry,
+                                                with_wide=True, **kw)]
+
+
+def _score_ids(od, oi):
+    """A row's list as a sorted list of (score, id) pairs."""
+    return [sorted(zip(r.tolist(), i.tolist())) for r, i in zip(od, oi)]
+
+
+@pytest.mark.parametrize("values", ["reals", "grid"])
+@pytest.mark.parametrize("score", ["l2", "ip"])
+@FORMS
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_two_level_lists_are_the_full_width_loops(gate, precision, score,
+                                                  values):
+    """Every form the kernel has (gated and not, the three first-pass
+    forms, both scores), a fresh call over 4 blocks with sentinel rows
+    and a carried one above a floor: with the fold pass (4 slabs of 128
+    lanes) the lists hold the full-width loop's scores, and the same
+    (score, id) pairs wherever no score is tied (reals); on a grid of
+    massive ties the same score multiset, every id reproducing its
+    score. Both loops ran in the two-level runs: the early blocks fall
+    back, the warm ones do not."""
+    rng = np.random.default_rng(470 + gate)
+    if values == "reals":
+        q = jnp.asarray(rng.uniform(0, 255, (16, 8)), jnp.float32)
+        d = jnp.asarray(rng.uniform(0, 255, (4096, 8)), jnp.float32)
+    else:
+        q = jnp.asarray(rng.integers(0, 3, (16, 8)), jnp.float32)
+        d = jnp.asarray(rng.integers(0, 3, (4096, 8)), jnp.float32)
+    floor = jnp.asarray(np.where(rng.random((16, 1)) < 0.5, -np.inf,
+                                 -30.0 if score == "ip" else 4.0),
+                        jnp.float32)
+    kw = dict(mxu_gate=gate, precision=precision, score=score)
+    seen = {}
+    for fold in (4, 0):
+        a = _run(q, d[:2048], n_real=1900, fold=fold, **kw)
+        b = _run(q, d[2048:], (a[0], a[1]), n_real=2048, id_base=1900,
+                 floor=floor, fold=fold, **kw)
+        seen[fold] = (a, b)
+    for step in (0, 1):
+        (od, oi, it, wide), (od0, oi0, it0, wide0) = (seen[4][step],
+                                                      seen[0][step])
+        assert np.array_equal(np.sort(od, axis=1), np.sort(od0, axis=1))
+        if values == "reals":
+            assert _score_ids(od, oi) == _score_ids(od0, oi0)
+        # full width is the only loop without the pass; with it a
+        # visit that ran no round is not wide
+        assert np.array_equal(wide0, (it0 > 0).astype(np.int32))
+        assert not (wide & (it == 0)).any()
+    wide = np.concatenate([seen[4][0][3], seen[4][1][3]], axis=1)
+    it = np.concatenate([seen[4][0][2], seen[4][1][2]], axis=1)
+    assert wide[0, 0] == 1                   # the first block seeds
+    if values == "reals":
+        assert ((it > 0) & (wide == 0)).any()    # the narrow rounds ran
+    # every id reproduces its score from the staged rows (where the
+    # form computes it to float32: a grid's small integers, or "f32")
+    od, oi = seen[4][1][:2]
+    rows = np.concatenate([np.asarray(d[:1900]), np.asarray(d[2048:])])
+    qq, dd = np.asarray(q, np.float64), rows[np.clip(oi, 0, None)]
+    want = -(qq[:, None, :] * dd).sum(-1) if score == "ip" \
+        else ((qq[:, None, :] - dd) ** 2).sum(-1)
+    if precision == "f32" or values == "grid":
+        assert np.allclose(np.where(oi >= 0, od, 0),
+                           np.where(oi >= 0, want, 0), rtol=1e-4, atol=1e-2)
+
+
+def _line(vals):
+    """Rows on a line: attribute 0 holds ``vals``, the rest are 0, so a
+    query at x scores (x - v)^2 exactly (small integers)."""
+    d = np.zeros((len(vals), 4), np.float32)
+    d[:, 0] = vals
+    return jnp.asarray(d)
+
+
+def _carry(rows):
+    """Running lists of 8 slots from one list of scores a row, ids
+    900 + slot."""
+    cd = jnp.asarray(rows, jnp.float32)
+    return cd, jnp.asarray(900 + np.arange(8)[None] + 0 * np.asarray(rows),
+                           jnp.int32)
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_a_bucket_that_hides_a_second_candidate_takes_the_full_width_loop(
+        gate):
+    """One block of 512 rows in 4 slabs of 128: positions p and p + 128
+    share a bucket. Two entries under the row's threshold there: the
+    fold pass sees one of them, the second-smallest test sees the
+    other, the tile falls back (``wide`` 1) and both are inserted. The
+    same two entries one lane apart: no bucket hides anything, the
+    rounds over the folded array insert both (``wide`` 0)."""
+    q = _line([0.0] * 8)
+    carry = _carry([[1, 2, 3, 4, 5, 6, 400, 500]] * 8)
+    for second, want_wide in ((70 + 128, 1), (71 + 128, 0)):
+        vals = np.full(512, 100.0)
+        vals[70], vals[second] = 3.0, 4.0          # scores 9 and 16
+        od, oi, it, wide = _run(q, _line(vals), carry, n_real=512,
+                                id_base=1000, kc=8, mxu_gate=gate)
+        assert wide.tolist() == [[want_wide]] and it[0, 0] >= 2
+        for r in range(8):
+            assert sorted(zip(od[r].tolist(), oi[r].tolist())) == [
+                (1.0, 900), (2.0, 901), (3.0, 902), (4.0, 903),
+                (5.0, 904), (6.0, 905), (9.0, 1070), (16.0, 1000 + second)]
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_equal_scores_keep_the_lowest_positions(gate):
+    """Four entries of one score in four buckets, room for two: the
+    rounds over the folded array take the lowest BLOCK POSITIONS (5 and
+    130), not the lowest lanes (384 sits in lane 0, 257 in lane 1). The
+    full-width loop takes one a half, 5 and 257: the order ISSUE 47
+    names as the one thing that may differ, among scores tied at the
+    list's last value inside one block."""
+    q = _line([0.0] * 8)
+    carry = _carry([[1, 2, 3, 4, 5, 6, 400, 500]] * 8)
+    vals = np.full(512, 100.0)
+    vals[[5, 130, 257, 384]] = 3.0                  # score 9, four times
+    kept = {}
+    for fold in (4, 0):
+        od, oi, _it, wide = _run(q, _line(vals), carry, n_real=512,
+                                 id_base=1000, kc=8, mxu_gate=gate,
+                                 fold=fold)
+        assert wide[0, 0] == (0 if fold else 1)
+        assert np.sort(od, axis=1).tolist() == [
+            [1, 2, 3, 4, 5, 6, 9, 9]] * 8
+        kept[fold] = [sorted(i for i in row.tolist() if i >= 1000)
+                      for row in oi]
+    assert kept[4] == [[1005, 1130]] * 8
+    assert kept[0] == [[1005, 1257]] * 8
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["two_pass", "fused"])
+def test_a_tile_whose_rows_disagree_falls_back_whole(gate):
+    """Row 0 has two candidates in one bucket, row 1 one candidate of
+    its own, the other rows none: one loop a tile, so the tile takes
+    the full-width loop and every row's list is right."""
+    qx = np.full(8, 5000.0)
+    qx[0], qx[1] = 0.0, 1000.0
+    warm = [[1, 2, 3, 4, 5, 6, 400, 500]] * 8
+    vals = np.full(512, 3000.0)
+    vals[70], vals[198] = 3.0, 4.0     # one bucket; row 0 scores 9, 16
+    vals[300] = 999.0                  # row 1 scores 1
+    od, oi, _it, wide = _run(_line(qx), _line(vals), _carry(warm),
+                             n_real=512, id_base=1000, kc=8, mxu_gate=gate)
+    assert wide.tolist() == [[1]]
+    assert sorted(oi[0].tolist())[-2:] == [1070, 1198]
+    assert sorted(od[0].tolist()) == [1, 2, 3, 4, 5, 6, 9, 16]
+    assert sorted(od[1].tolist()) == [1, 1, 2, 3, 4, 5, 6, 400]
+    assert 1300 in oi[1].tolist()
+    for r in range(2, 8):
+        assert sorted(od[r].tolist()) == sorted(warm[r])
+    # without row 0's second entry no row hides one: the narrow rounds
+    vals[198] = 3000.0
+    od, oi, _it, wide = _run(_line(qx), _line(vals), _carry(warm),
+                             n_real=512, id_base=1000, kc=8, mxu_gate=gate)
+    assert wide.tolist() == [[0]]
+    assert sorted(od[0].tolist()) == [1, 2, 3, 4, 5, 6, 9, 400]
+    assert sorted(od[1].tolist()) == [1, 1, 2, 3, 4, 5, 6, 400]
+
+
+def test_the_block_picks_the_fold():
+    """``fold`` is a function of the dispatch shape: whole lane vectors
+    a bucket, the largest divisor of the block's that leaves the folded
+    array ten of them (a shorter block folds in two), whatever the list
+    width; the wrapper resolves it from the tiles it runs, a caller's
+    own included."""
+    from dmlp_tpu.ops.pallas_extract import fold_slabs, resolve_variant
+    assert [fold_slabs(tn) for tn in (12800, 10240, 6400, 2560, 1280, 768,
+                                      512, 256, 128)] \
+        == [10, 8, 5, 2, 2, 2, 2, 2, 0]
+    for tn in range(128, 12801, 128):
+        f = fold_slabs(tn)
+        if f:
+            assert (tn // 128) % f == 0
+            assert tn // (128 * f) >= min(10, tn // 256)
+    assert resolve_variant(512, 51200, 1024, 128)["fold"] == 10
+    assert resolve_variant(32, 51200, 1024, 960)["fold"] == fold_slabs(6400)
+    q, d = jnp.zeros((8, 4), jnp.float32), jnp.zeros((512, 4), jnp.float32)
+    with pytest.raises(ValueError, match="untileable"):
+        extract_topk(q, d, n_real=512, kc=8, interpret=True, tile_n=256,
+                     fold=4)                   # half a block a bucket
 
 
 def test_unknown_form_is_refused():

@@ -347,21 +347,25 @@ FOLD_BUCKETS = {"q128": 5, "q1024": 1000}
 
 def _reference_fold(eng, q_dev, order, kc, prec):
     """Today's fold as PR 27 ran it: the resolved kernel once a chunk
-    from Python, first call with no carry, the gate counted eagerly."""
+    from Python, first call with no carry, the gate's pair (visits
+    that extracted nothing, visits that extracted at full width)
+    counted eagerly."""
     from dmlp_tpu.ops import pallas_fused
     cr = eng._ex_chunk_rows
     kern, _ = pallas_fused.resolve_topk_kernel(
         q_dev.shape[0], cr, eng.num_attrs, kc, rung=eng._degrade_rung)
     od = oi = None
-    gated = tiles = 0
+    gated = wide = tiles = 0
     for c in order:
-        od, oi, its = kern(q_dev, eng._chunks[c], od, oi,
-                           n_real=min(eng.n_real - c * cr, cr),
-                           id_base=c * cr, kc=kc,
-                           interpret=eng._interpret, precision=prec)
+        od, oi, its, wd = kern(q_dev, eng._chunks[c], od, oi,
+                               n_real=min(eng.n_real - c * cr, cr),
+                               id_base=c * cr, kc=kc,
+                               interpret=eng._interpret, precision=prec,
+                               with_wide=True)
         gated += int(np.count_nonzero(np.asarray(its) == 0))
+        wide += int(np.asarray(wd).sum())
         tiles += its.size
-    return np.array(od), np.array(oi), gated, tiles
+    return np.array(od), np.array(oi), [gated, wide], tiles
 
 
 @pytest.fixture(scope="module")
@@ -408,7 +412,8 @@ def fold_journey():
             seen[step, bucket] = {
                 "order": order, "n_real": eng.n_real,
                 # copies: a view would keep the device array alive
-                "got": (np.array(od), np.array(oi), int(gated), tiles),
+                "got": (np.array(od), np.array(oi),
+                        np.asarray(gated).tolist(), tiles),
                 "want": _reference_fold(eng, q_dev, order, kc, prec),
                 "counters": (eng.compile_count,
                              se._fold_stack._cache_size())}
@@ -428,8 +433,10 @@ def test_one_program_fold_equals_the_chunk_loop_bit_for_bit(
     # bit for bit: the running lists themselves, not just the answers
     assert od.tobytes() == rod.tobytes()
     assert oi.tobytes() == roi.tobytes()
-    # the gate gauges: as many tiles gated, of as many visited
+    # the gate gauges: as many tiles gated and as many at full width,
+    # of as many visited
     assert (gated, tiles) == (rgated, rtiles)
+    assert 0 <= gated[1] <= tiles - gated[0]
 
 
 @pytest.mark.parametrize("step", [s[0] for s in FOLD_STEPS])

@@ -565,13 +565,17 @@ def _mesh_report(eng):
 def test_two_mesh_batches_alive_do_not_write_each_others_report():
     """Begin A, begin B, finish A: the engine's ``last_*`` report, the
     gate flush and ``bucket_stats()`` are A's; B's only once B has
-    finished. A and B differ in bucket (k16 against k256: another
-    candidate width, so another variant and other merge traffic) and in
-    what the scorer pruned (nothing; one shard's piece of a chunk)."""
+    finished. A and B differ in bucket (q128k16 against q256k256:
+    another candidate width, so another variant and other merge
+    traffic, and another count of query tiles) and in what the scorer
+    pruned (nothing; one shard's piece of a chunk)."""
     eng, alone = engine_for("mesh"), engine_for("mesh")
     rng = np.random.default_rng(83)
     qa, ka = rng.uniform(-10, 10, (NQ, NA)), np.full(NQ, 5, np.int32)
-    qb, kb = rng.uniform(-10, 10, (NQ, NA)), np.full(NQ, 200, np.int32)
+    # B also fills a second query tile (every list width runs 128-row
+    # tiles since PR 47), so the two batches' folds visit other counts
+    nb = 130
+    qb, kb = rng.uniform(-10, 10, (nb, NA)), np.full(nb, 200, np.int32)
     keep = np.ones((MESH[0], eng._nchunks), bool)
     keep[1, 1] = False
     prune_b = {"blocks_total": keep.size, "blocks_pruned": 1}

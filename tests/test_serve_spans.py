@@ -185,7 +185,13 @@ def test_span_args_say_what_the_work_was(traced):
     counts = traced["stats"]["engine"]["repairs"]
     assert counts["flagged_queries"] == counts["device"] + counts["host"]
     assert counts["device"] >= 3
-    assert ev["serve.after_batch"]["tiles"] >= 1
+    after = ev["serve.after_batch"]
+    assert after["tiles"] >= 1
+    # what select_wide_pct.bulk reads: visits that extracted at full
+    # width, of the visits the fold made
+    assert 0 <= after["wide"] <= after["tiles"] - after["gated"]
+    assert after["wide_pct"] == pytest.approx(
+        100.0 * after["wide"] / after["tiles"], abs=1e-3)
     assert ev["serve.phase.parse"]["queries"] == 3
     assert ev["serve.phase.parse"]["bytes"] > 0
     assert ev["serve.phase.parse"]["rid"] == "r-1"

@@ -5,8 +5,10 @@ libtpu is installed in the CPU container, and
 topology from it, so Mosaic and XLA:TPU can be asked to compile every
 kernel variant the engine can dispatch at the bench config 4 shape
 (input3: 200 000 x 10 000 x 64, k in [1, 32]) — the dispatch the engine
-plans on a TPU: bf16 staging, kcap 144, (tile_q 64, ne 4), qpad 10112,
-chunks of 51 200 rows. This catches what interpret mode cannot: PR 18's
+plans on a TPU: bf16 staging, kcap 144, qpad 10112, chunks of 51 200
+rows, under the tiles it ran with until PR 47 (tile_q 64, ne 4, the
+full-width loop alone: what ``fold`` 0 still compiles) and, further down,
+under the two-level selection's. This catches what interpret mode cannot: PR 18's
 bf16 first pass had only ever run interpreted and did not compile
 ("Bad lhs type": bf16 operands under an fp32 contract precision).
 Compiling says nothing about running; chip_smoke.py does that.
@@ -23,7 +25,7 @@ from dmlp_tpu.config import EngineConfig
 from dmlp_tpu.ops.pallas_extract import _extract_topk_jit
 
 QPAD, CHUNK, NA, KCAP = 10112, 51200, 64, 144
-VARIANT = dict(tile_q=64, tile_n=12800, ne=4, unroll=1)   # kcap > 64
+VARIANT = dict(tile_q=64, tile_n=12800, ne=4, unroll=1)   # PR 46's, kcap > 64
 
 
 @pytest.fixture(scope="module")
@@ -67,6 +69,106 @@ def test_extract_kernel_compiles_for_v5e(v5e, mxu_gate, precision, carry):
                 if 'custom_call_target="tpu_custom_call"' in line)
     assert call.lstrip().removeprefix("ROOT ").startswith(f"%{name}."), \
         call[:120]
+
+
+#: (q, attrs, staged dtype, first-pass form, kc, score): the dispatch
+#: shapes this file holds, under the two-level selection (PR 47), by
+#: the cell that runs them
+TWO_LEVEL = {
+    "bigann.bulk": (1024, 128, jnp.float32, "bf16x3", 32, "l2"),
+    "bigann.steady": (128, 128, jnp.float32, "bf16x3", 32, "l2"),
+    "bigann.fast_f32": (1024, 128, jnp.float32, "f32", 32, "l2"),
+    "gist.bulk": (1024, 1024, jnp.float32, "bf16x3", 40, "l2"),
+    "a2048": (1024, 2048, jnp.float32, "bf16x3", 32, "l2"),
+    "a64": (1024, 64, jnp.bfloat16, "f32", 32, "l2"),
+    "bigann-10m.bulk": (1024, 128, jnp.bfloat16, "f32", 120, "l2"),
+    "text2image-10m.bulk": (1024, 256, jnp.bfloat16, "f32", 120, "ip"),
+    "narrow100": (1024, 100, jnp.bfloat16, "f32", 120, "l2"),
+    "batch.config4": (10112, 64, jnp.bfloat16, "f32", 144, "l2"),
+    "bigann-gt1000.bulk": (1024, 128, jnp.float32, "bf16x3", 512, "l2"),
+    "retry": (16, 128, jnp.bfloat16, "f32", 512, "l2"),
+}
+
+
+@pytest.mark.parametrize("carry", [False, True], ids=["fresh", "carry"])
+@pytest.mark.parametrize("shape", TWO_LEVEL.values(), ids=TWO_LEVEL.keys())
+def test_two_level_selection_compiles_for_v5e(v5e, shape, carry):
+    """The kernel WITH the fold pass (slab slices of the distance
+    scratch, two more VMEM scratches, a ``cond`` that hands two scalars
+    out of the gated branch, two while loops) at every shape above:
+    Mosaic takes the slicing at 12 800, 6 400 and 2 560 rows a block,
+    under float32 and bfloat16 blocks, both scores, fresh and carried,
+    at 32 to 512 slots.
+    The engines' fold programs in this file compile the same kernel
+    through ``_kernel_statics``; here the statics say so."""
+    from dmlp_tpu.ops.pallas_extract import fold_slabs
+    from dmlp_tpu.serve.engine import _kernel_statics
+    q, attrs, staged, precision, kc, score = shape
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    kern = _kernel_statics("fused", kc, 51200, q, attrs, precision, False,
+                           score)
+    assert kern["fold"] == fold_slabs(kern["tile_n"]) >= 2
+    assert kern["tile_n"] % (128 * kern["fold"]) == 0
+    lists = ((spec((q, kc), jnp.float32), spec((q, kc), jnp.int32))
+             if carry else (None, None))
+    compiled = _extract_topk_jit.lower(
+        spec((q, attrs), staged), spec((51200, attrs), staged), *lists,
+        n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
+        block_skip=True, floor=None, **kern).compile()
+    assert len(_kernel_calls(compiled.as_text())) == 1
+
+
+def test_the_one_level_kernel_still_compiles_for_v5e(v5e):
+    """``fold`` 0 is what a block of one lane vector resolves to and
+    what a caller may pass: PR 46's kernel, the full-width loop alone."""
+    from dmlp_tpu.serve.engine import _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    kern = _kernel_statics("fused", 32, 51200, 1024, 128, "bf16x3", False)
+    _extract_topk_jit.lower(
+        spec((1024, 128), jnp.float32), spec((51200, 128), jnp.float32),
+        spec((1024, 32), jnp.float32), spec((1024, 32), jnp.int32),
+        n_real=spec((), jnp.int32), id_base=spec((), jnp.int32),
+        block_skip=True, floor=None, **{**kern, "fold": 0}).compile()
+
+
+def test_the_compilers_schedule_has_a_narrow_round_a_fraction_of_a_wide_one(
+        v5e):
+    """``tools/kernel_bundles.py`` reads the VLIW schedule libtpu writes
+    for the described chip: at ``bigann.steady``'s shape (the kernel of
+    ``bigann.bulk``, one query tile) the visit is the slab loop, which
+    holds every MXU slot, then the two extraction loops' rounds, and a
+    round over the folded array is under a fifth of a full-width one: the
+    1 / F the two-level selection rests on, less what a round spends on
+    the lists whatever its width."""
+    import re
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    tool = Path(__file__).resolve().parents[1] / "tools/kernel_bundles.py"
+    out = subprocess.run(
+        [sys.executable, str(tool), "--shape", "bigann.steady"],
+        capture_output=True, text=True, timeout=600).stdout
+    if "wrote no schedule" in out:
+        pytest.skip("this libtpu writes no schedule dump")
+    assert "'fold': 10" in out.splitlines()[0]
+    parts = re.findall(r"(\d+) bundles, (straight line|a round of a loop)"
+                       r": MXU (\d+)", out)
+    loops = [(int(n), int(mxu)) for n, what, mxu in parts
+             if what == "a round of a loop"]
+    # the slab loop holds the MXU's work (five slabs a round); the two
+    # extraction loops none
+    (slabs,) = [n for n, mxu in loops if mxu > 0]
+    (wide, narrow) = [n for n, mxu in loops if mxu == 0]
+    assert narrow * 5 < wide < slabs * 2
 
 
 def test_split_check_kernel_compiles_for_v5e(v5e):
@@ -238,8 +340,8 @@ def test_retry_fold_program_compiles_for_v5e(v5e, staged, precision, chunks,
 
 def test_default_dtype_fold_program_compiles_for_v5e(v5e):
     """``bigann-10m.bulk``'s own fold: q1024 at the 120-slot window its
-    bucket plans under bfloat16 staging (tile_q 64: a data block is
-    read 16 times a chunk), over 328 resident chunks of 51 200 x 128
+    bucket plans under bfloat16 staging (tile_q 128 since PR 47: a data
+    block is read 8 times a chunk), over 328 resident chunks of 51 200 x 128
     bfloat16. The kernel streams the stack's rows as they are; a
     float32 query panel beside bfloat16 rows (no engine stages that)
     converts the chunk, and only the chunk, to float32 first."""
@@ -256,7 +358,7 @@ def test_default_dtype_fold_program_compiles_for_v5e(v5e):
                       precision="f32", na=128)
     kern = _kernel_statics("fused", kc, 51200, 1024, 128, "f32", False)
     assert (kc, kern["tile_q"], kern["tile_n"], kern["ne"]) \
-        == (120, 64, 12800, 4)
+        == (120, 128, 12800, 4)
 
     def fold(q_dtype):
         return _fold_stack.lower(
@@ -465,7 +567,8 @@ def test_wide_k_programs_compile_for_v5e(v5e, precision):
     """The multipass driver's two kernel programs at
     ``bigann-gt1000.bulk``'s shape (q1024, 82 resident chunks of
     51 200 x 128 float32, k = 1000: bucket 1024, 1152 slots, 3 passes
-    at ``kc`` 512, tiles tile_q 64 / ne 4): pass 1 is the one-program
+    at ``kc`` 512, tiles tile_q 128 / ne 4 / fold 10 since PR 47): pass 1
+    is the one-program
     fold with 512-wide lists, every further pass ONE kernel call over
     the stack as a (4 198 400, 128) array above a per-query floor. The
     reshape is free inside the program: the sweep allocates no
@@ -489,7 +592,8 @@ def test_wide_k_programs_compile_for_v5e(v5e, precision):
                            False)
     sweep = _kernel_statics(impl, 512, rows, 1024, 128, precision, False)
     assert impl == "fused" and fold == sweep
-    assert (fold["tile_q"], fold["tile_n"], fold["ne"]) == (64, 12800, 4)
+    assert (fold["tile_q"], fold["tile_n"], fold["ne"], fold["fold"]) \
+        == (128, 12800, 4, 10)
     stack = spec((82, 51200, 128), jnp.float32)
     norms = spec((82, 1, 51200), jnp.float32)
     folded = _fold_stack.lower(

@@ -26,8 +26,11 @@ from dmlp_tpu.serve.engine import ResidentEngine
 
 #: the variants the rule gave before the width entered it: what the
 #: accepted cells' Mosaic programs were built from
-NARROW = {"tile_q": 128, "ne": 2, "unroll": 1}
-WIDE_K = {"tile_q": 64, "ne": 4, "unroll": 1}
+#: (the fold pass's lane vectors ride with the tiles since PR 47: 10 a
+#: bucket at a 12 800-row block; the same PR re-measured the wide
+#: lists' query tile with the pass in: 128 rows, not 64)
+NARROW = {"tile_q": 128, "ne": 2, "unroll": 1, "fold": 10}
+WIDE_K = {"tile_q": 128, "ne": 4, "unroll": 1, "fold": 10}
 
 
 def config():
@@ -68,7 +71,9 @@ def test_narrow_rows_resolve_to_the_variants_they_always_did(
                                       (4096, 1280)])
 def test_the_data_block_follows_the_width(a, tile_n):
     v = pallas_extract.resolve_variant(32, 51200, 1024, a)
-    assert v == {**NARROW, "tile_n": tile_n}
+    assert v == {**NARROW, "tile_n": tile_n,
+                 "fold": pallas_extract.fold_slabs(tile_n)}
+    assert v["fold"] == {10240: 8, 6400: 5, 2560: 2, 1280: 2}[tile_n]
     assert pallas_extract.supports(1024, 51200, a, 32)
     kern, impl = pallas_fused.resolve_topk_kernel(1024, 51200, a, 32)
     assert impl == "fused" and kern is pallas_fused.fused_topk
