@@ -7,8 +7,13 @@ double-precision ordering, engine.cpp:12 / common.h:13, without paying f64 on
 the MXU), the per-query k cut, the majority vote (engine.cpp:320-332), the
 report sort (engine.cpp:334-338), and -1-sentinel padding (common.cpp:66).
 
-Everything is vectorized NumPy over (Q, K) arrays — K is small (tens), so
-this is a negligible epilogue next to the O(Q*N*A) device work.
+Everything is vectorized NumPy over (Q, K) arrays. It is no epilogue:
+since the kernel's PR 47 the float64 gather of a served micro-batch (a
+row a candidate slot, each at a random place of the host corpus) is
+longer than the device's fold in every one-chip bulk cell (PERF.md
+section 5). So where the caller read the device distances and the hazard
+test's bound (boundary_band), only the slots that bound cannot order are
+gathered and scored; a caller with neither rescores every slot.
 """
 
 from __future__ import annotations
@@ -66,13 +71,21 @@ def rescore_block(k: int, num_attrs: int) -> int:
 
 def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
                 data_attrs: np.ndarray, block: int | None = None,
-                score: str = "l2") -> np.ndarray:
+                score: str = "l2",
+                widths: np.ndarray | None = None) -> np.ndarray:
     """Exact float64 distances for candidate ids (difference form, like
     computeDistance at engine.cpp:12-18). ids < 0 map to +inf.
     Under ``score`` "ip" the ordered quantity is the NEGATED inner
     product -(q . x) of the gathered rows (so that it ascends like a
     distance; finalize_host hands the wire the product itself): one
     pass over the buffer where the difference form takes two.
+
+    ``widths`` (band_widths; (Q,) ints) are the leading slots of each
+    list to gather and score; a slot past its query's width carries
+    +inf and its row is never read. Queries of one width are scored
+    together, as a rectangle of that width through the same block loop,
+    so a distance is the bits the whole window's call gives it. Left
+    out, every slot is scored.
 
     ``block`` queries are rescored at a time, in ONE (block, K, A)
     buffer that holds the gathered rows and then their difference; left
@@ -90,25 +103,36 @@ def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
     over six processes), a batch every 555. The 512 came from a
     pre-round sweep at 10240 x 4608 x 64 where 64-512 read alike."""
     q, k = cand_ids.shape
-    if block is None:
-        block = rescore_block(k, data_attrs.shape[1])
-    out = np.empty((q, k), np.float64)
+    na = data_attrs.shape[1]
     safe = np.clip(cand_ids, 0, data_attrs.shape[0] - 1)
-    buf = np.empty((min(block, q), k, data_attrs.shape[1]), data_attrs.dtype)
+    out = np.full((q, k), np.inf)
+    # (queries, how many, width, queries a block) of each rectangle
+    if widths is None:
+        groups = [(slice(None), q, k)] if q and k else []
+    else:
+        widths = np.minimum(widths, k)
+        members = [np.nonzero(widths == w)[0]
+                   for w in np.unique(widths) if w]
+        groups = [(idx, idx.size, int(widths[idx[0]])) for idx in members]
+    groups = [(idx, m, w, block or rescore_block(w, na))
+              for idx, m, w in groups]
+    buf = np.empty(max((min(b, m) * w * na for _i, m, w, b in groups),
+                       default=0), data_attrs.dtype)
     inplace = buf.dtype == np.float64
-    for q0 in range(0, q, block):
-        q1 = min(q0 + block, q)
-        rows = buf[:q1 - q0]                                     # (b, K, A)
-        np.take(data_attrs, safe[q0:q1], axis=0, out=rows, mode="clip")
-        if score == "ip":
-            np.einsum("qka,qa->qk", rows, query_attrs[q0:q1],
-                      out=out[q0:q1])
-            continue
-        diff = np.subtract(rows, query_attrs[q0:q1, None, :],
-                           out=rows if inplace else None)
-        out[q0:q1] = np.einsum("qka,qka->qk", diff, diff)
-    if score == "ip":
-        np.negative(out, out=out)
+    for idx, m, w, b in groups:
+        ids, qa = safe[idx, :w], query_attrs[idx]
+        dst = np.empty((m, w), np.float64)
+        for q0 in range(0, m, b):
+            q1 = min(q0 + b, m)
+            rows = buf[:(q1 - q0) * w * na].reshape(q1 - q0, w, na)
+            np.take(data_attrs, ids[q0:q1], axis=0, out=rows, mode="clip")
+            if score == "ip":
+                np.einsum("qka,qa->qk", rows, qa[q0:q1], out=dst[q0:q1])
+                continue
+            diff = np.subtract(rows, qa[q0:q1, None, :],
+                               out=rows if inplace else None)
+            dst[q0:q1] = np.einsum("qka,qka->qk", diff, diff)
+        out[idx, :w] = np.negative(dst, out=dst) if score == "ip" else dst
     out[cand_ids < 0] = np.inf
     return out
 
@@ -327,6 +351,72 @@ def boundary_hazard(kth: np.ndarray, last: np.ndarray,
     return np.isfinite(last) & (last <= kth + eps)
 
 
+#: How many widths a batch's bands are rounded up to (band_widths):
+#: queries of one width are rescored as one rectangle, so a batch costs
+#: at most this many gathers' fixed parts, and a query reads at most a
+#: sixteenth of the window beyond its own band.
+BAND_STEPS = 16
+
+
+def boundary_band(device_dists: np.ndarray, cand_ids: np.ndarray,
+                  kth: np.ndarray,
+                  eps: np.ndarray | float = 0.0) -> np.ndarray:
+    """The slots of each candidate list that the float64 rescore has to
+    read: ``(ids >= 0) & (d~ <= kth + eps)``, boundary_hazard's own
+    statement turned on the rows INSIDE the list.
+
+    The k slots before the k-th hold device distances <= ``kth`` (the
+    lists are in device order), and ``eps`` bounds how far two device
+    distances can swap against their float64 values (staging_eps +
+    lowp_eps, or the "ip" bound: the doubling folded in). The hazard
+    test trusts that of a row the list MISSED, all of them >= ``last``:
+    one whose device distance is above ``kth + eps`` lies, in float64,
+    beyond each of those k and cannot be among the true k nearest. That
+    holds word for word of a candidate j of the list with d~_j > kth +
+    eps: it is out of the float64 top k without a look at its row,
+    whether or not the query is flagged. Every slot that can be
+    REPORTED is in the band (a position below k has d~ <= kth), so the
+    float64 order of the band's rows, cut at k, is the whole window's.
+
+    ``eps`` is the vector the hazard test just used, as it is:
+    staging_eps' first term is taken at ``last``, the largest distance
+    of the list, so it bounds an in-list candidate at least as well as a
+    missed row; the comparison is non-strict, the mirror of
+    boundary_hazard's ``last <= kth + eps``. A caller that has no bound
+    (a window that holds the whole corpus is never tested) or no device
+    distances has no band: it rescores every slot.
+
+    Args:
+      device_dists: (Q, K) raw device candidate distances, device order
+        (under "ip" the negated products, as kth and eps are).
+      cand_ids: (Q, K) candidate ids, -1 where a list is short.
+      kth: (Q,) device distance of each query's k-th candidate.
+      eps: scalar or (Q,) bound of the hazard test.
+
+    Returns:
+      (Q, K) bool mask, True where the rescore must read the row.
+    """
+    horizon = np.asarray(kth, np.float64) + eps
+    return (np.asarray(cand_ids) >= 0) \
+        & (np.asarray(device_dists, np.float64) <= horizon[:, None])
+
+
+def band_widths(in_band: np.ndarray) -> np.ndarray:
+    """(Q,) leading slots of each list that rescore_f64 reads for the
+    band ``in_band`` (boundary_band): up to the band's last slot (the
+    lists are in device order, so a band is a prefix of its list; a slot
+    before the last that is not in it is read too, which decides
+    nothing), rounded up to one of BAND_STEPS widths of the window. 0
+    where a list holds no band."""
+    q, kcap = in_band.shape
+    if q == 0 or kcap == 0:
+        return np.zeros(q, np.int64)
+    width = np.where(in_band.any(axis=1),
+                     kcap - np.argmax(in_band[:, ::-1], axis=1), 0)
+    step = -(-kcap // BAND_STEPS)
+    return np.minimum(-(-width // step) * step, kcap)
+
+
 def boundary_overflow(device_dists: np.ndarray, ks: np.ndarray,
                       eps: np.ndarray | float = 0.0) -> np.ndarray:
     """Queries whose fast-path candidate set may have truncated a tie group.
@@ -360,9 +450,17 @@ def boundary_overflow(device_dists: np.ndarray, ks: np.ndarray,
     q, kcap = device_dists.shape
     if q == 0 or kcap == 0:
         return np.zeros(q, bool)
-    last = device_dists[:, kcap - 1]
-    kth = device_dists[np.arange(q), np.clip(np.asarray(ks) - 1, 0, kcap - 1)]
-    return boundary_hazard(kth, last, eps)
+    return boundary_hazard(kth_column(device_dists, ks),
+                           device_dists[:, kcap - 1], eps)
+
+
+def kth_column(device_dists: np.ndarray, ks: np.ndarray) -> np.ndarray:
+    """(Q,) device distance of each query's k-th candidate, of the
+    (Q, K) lists read back whole (what engine.single._boundary_cols
+    takes on the device)."""
+    q, kcap = device_dists.shape
+    return device_dists[np.arange(q),
+                        np.clip(np.asarray(ks) - 1, 0, kcap - 1)]
 
 
 def repair_boundary_overflow(results: List[QueryResult],

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dmlp_tpu.config import EngineConfig
-from dmlp_tpu.engine.finalize import (boundary_hazard, finalize_host,
+from dmlp_tpu.engine.finalize import (band_widths, boundary_band,
+                                      boundary_hazard, finalize_host,
                                       lowp_eps, repair_boundary_overflow,
                                       rescore_f64, staging_eps)
 from dmlp_tpu.io.grammar import KNNInput, subset_queries
@@ -479,10 +480,12 @@ def _chunk_fold(carry: TopK, q_attrs, battrs, blabels, bids, *, k, select,
 
 @jax.jit
 def _boundary_cols(dists, ks):
-    """(kth, last) candidate-distance columns, stacked (2, Q) — computed on
-    device so the exact path never reads the (Q, K) distance matrix back
-    over the link. The host applies the staging-eps hazard test to these
-    two vectors (engine.finalize.boundary_overflow / staging_eps)."""
+    """(kth, last) candidate-distance columns, stacked (2, Q), taken on
+    the device. The host applies the staging-eps hazard test to these
+    two vectors (engine.finalize.boundary_hazard / staging_eps); the
+    (Q, K) distances themselves are read back beside them (fast mode
+    reports them; exact mode cuts the float64 rescore to the band the
+    bound cannot order: engine.finalize.boundary_band)."""
     kcap = dists.shape[1]
     last = dists[:, kcap - 1]
     kth = jnp.take_along_axis(
@@ -620,6 +623,9 @@ class PendingRun:
     phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
     repairs: int = 0              # queries the hazard test flagged
     retry_cleared: int = 0        # of them, repaired by the device retry
+    # the float64 rescore: candidate slots finalized, rows gathered
+    rescore_slots: int = 0
+    rescore_rows: int = 0
 
 
 class SingleChipEngine:
@@ -1371,11 +1377,13 @@ class SingleChipEngine:
         set may have truncated a distance-tie group (boundary_overflow) are
         recomputed exactly — parity holds on either path.
 
-        Readback is kept minimal: in exact mode only the candidate ids and
-        the device-computed hazard flags cross the link (labels are
-        re-derived from ids on host, distances are rescored in float64
-        anyway); the (Q, K) f32 distance matrix is fetched only in fast
-        mode, where it is the result.
+        Readback is kept small: the candidate ids, the two boundary
+        columns and the (Q, K) f32 device distances cross the link
+        (labels are re-derived from ids on host). Fast mode reports the
+        distances; exact mode rescores in float64 and reads them only to
+        leave out the rows its bound already orders
+        (engine.finalize.boundary_band), so a window no hazard test
+        bounds does not fetch them.
         """
         kmax = int(inp.ks.max()) if inp.params.num_queries else 0
         with staging_for_k(self, kmax):
@@ -1472,7 +1480,13 @@ class SingleChipEngine:
             # bytes — and past _CHUNK_WINDOW chunks the enqueue phase
             # absorbs throttled transfer wait too. Don't read this table
             # as "readback costs X ms".
-            fetch = ([] if self.config.exact else [top.dists]) + [top.ids] \
+            # The device distances come back wherever something reads
+            # them: fast mode reports them, exact mode cuts its rescore
+            # with them where the hazard test gives a bound (a window
+            # that holds the whole corpus has none).
+            exact = self.config.exact
+            with_dists = not exact or cols_dev is not None
+            fetch = ([top.dists] if with_dists else []) + [top.ids] \
                 + ([cols_dev] if cols_dev is not None else [])
             # The whole call, retries and injected faults included, is
             # one device wait of this thread (site: the span's own).
@@ -1489,10 +1503,10 @@ class SingleChipEngine:
             # cost a pass over the WHOLE host corpus inside this span.
             with obs_span("single.hazard", rows=n, score=score,
                           **targs) as hz:
-                dists = None if self.config.exact \
-                    else np.asarray(fetched.pop(0), np.float64)[:nq]
+                dists = np.asarray(fetched.pop(0), np.float64)[:nq] \
+                    if with_dists else None
                 ids = fetched.pop(0)[:nq]
-                flags = None
+                flags = widths = None
                 if cols_dev is not None:
                     kth, last = np.asarray(fetched.pop(0),
                                            np.float64)[:, :nq]
@@ -1505,6 +1519,9 @@ class SingleChipEngine:
                     eps = self._hazard_eps(last, qn, dn_max, select, prec,
                                            inp.params.num_attrs)
                     flags = boundary_hazard(kth, last, eps)
+                    if exact:
+                        widths = band_widths(
+                            boundary_band(dists, ids, kth, eps))
                     # How many times its bound the window clears, for
                     # the batch's tightest query: 1 or less is a flag.
                     full = np.isfinite(last) & (eps > 0)
@@ -1526,11 +1543,13 @@ class SingleChipEngine:
 
             t0 = _time.perf_counter()
             hazard_ms += (t0 - t1) * 1e3
-            # gather_bytes: the float64 rows the rescore gathers, one a
-            # candidate (Q x kcap x A x 8 B; none in fast mode).
-            gather = ids.size * inp.params.num_attrs * 8 \
-                if self.config.exact else 0
-            with obs_span("single.finalize", exact=self.config.exact,
+            # rows: the float64 rows the rescore gathers, one a slot of
+            # a query's band (every slot where there is no band; none in
+            # fast mode); gather_bytes: rows x A x 8 B.
+            rows = 0 if not exact else ids.size if widths is None \
+                else int(widths.sum())
+            gather = rows * inp.params.num_attrs * 8
+            with obs_span("single.finalize", exact=exact,
                           gather_bytes=gather, score=score, **targs) as sp:
                 suspects = np.nonzero(flags)[0] if flags is not None \
                     else np.zeros(0, np.intp)
@@ -1542,18 +1561,23 @@ class SingleChipEngine:
                 # host oracle's.
                 retry = self._retry_begin(pend, sub, suspects, select,
                                           kcap) if suspects.size else None
-                if self.config.exact:
+                if exact:
                     # The float64 gather-and-score, under a span of its
                     # own: the part of the finalize the score changes
                     # (difference form; under "ip" the product alone).
                     # finalize_host takes the rescored distances as it
                     # takes fast mode's device ones.
+                    pend.rescore_slots += ids.size
+                    pend.rescore_rows += rows
                     with obs_span("single.rescore", queries=nq,
-                                  slots=kcap, bytes=gather, score=score,
-                                  **targs):
+                                  slots=kcap, rows=rows, bytes=gather,
+                                  band_pct=round(100.0 * rows
+                                                 / max(ids.size, 1), 3),
+                                  score=score, **targs):
                         dists = rescore_f64(np.asarray(ids, np.int64),
                                             sub.query_attrs,
-                                            sub.data_attrs, score=score)
+                                            sub.data_attrs, score=score,
+                                            widths=widths)
                 results = finalize_host(dists, labels, ids, sub.ks,
                                         sub.query_attrs, sub.data_attrs,
                                         exact=False, query_ids=idx,

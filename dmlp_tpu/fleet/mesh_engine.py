@@ -74,9 +74,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dmlp_tpu.config import EngineConfig
-from dmlp_tpu.engine.finalize import (boundary_overflow, finalize_host,
-                                      lowp_eps, repair_boundary_overflow,
-                                      staging_eps)
+from dmlp_tpu.engine.finalize import (band_widths, boundary_band,
+                                      boundary_hazard, finalize_host,
+                                      kth_column, lowp_eps,
+                                      repair_boundary_overflow,
+                                      rescore_f64, staging_eps)
 from dmlp_tpu.engine.sharded import (ShardedEngine, _chunk_span,
                                      _np_staging_dtype)
 from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, MeasuredIters,
@@ -816,6 +818,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # corpus-wide scalar was at hand or cost a pass over the WHOLE
         # float64 host corpus inside this span (a daemon's first batch).
         suspects = np.zeros(0, np.int64)
+        exact, widths = self.config.exact, None
         with obs_span("fleet.hazard", rows=n, **self._rid_args()) as hz:
             if pend.select in ("sort", "topk", "seg", "extract") \
                     and dists.shape[1] < n:
@@ -837,15 +840,34 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                     # bound of the form that ran (finalize.lowp_eps;
                     # zero for the one HIGHEST dot).
                     eps = eps + lowp_eps(prec, qn, dn_max)
+                kth = kth_column(dists, inp.ks)
                 suspects = np.nonzero(
-                    boundary_overflow(dists, inp.ks, eps))[0]
+                    boundary_hazard(kth, dists[:, -1], eps))[0]
                 hz.set(flagged=int(suspects.size))
+                if exact:
+                    # the rescore's band, by the same bound
+                    widths = band_widths(
+                        boundary_band(dists, ids, kth, eps))
         t2 = clock()
-        with obs_span("fleet.finalize", exact=self.config.exact,
+        # rows: the float64 rows the rescore gathers, one a slot of a
+        # query's band (every slot where there is no band; none in fast
+        # mode), as single.finalize / single.rescore say it
+        rows = 0 if not exact else ids.size if widths is None \
+            else int(widths.sum())
+        with obs_span("fleet.finalize", exact=exact, queries=nq,
+                      slots=dists.shape[1], rows=rows,
+                      gather_bytes=rows * self.num_attrs * 8,
+                      band_pct=round(100.0 * rows / max(ids.size, 1), 3),
                       **self._rid_args()) as sp:
+            if exact:
+                pend.rescore_slots += ids.size
+                pend.rescore_rows += rows
+                dists = rescore_f64(np.asarray(ids, np.int64),
+                                    inp.query_attrs, inp.data_attrs,
+                                    widths=widths)
             results = finalize_host(dists, labels, ids, inp.ks,
                                     inp.query_attrs, inp.data_attrs,
-                                    exact=self.config.exact)
+                                    exact=False)
             if suspects.size:
                 with obs_span("fleet.repair", queries=int(suspects.size),
                               **self._rid_args()):
@@ -858,6 +880,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         t3 = clock()
         pend.phase_ms.update(fetch=(t1 - t0) * 1e3, hazard=(t2 - t1) * 1e3,
                              finalize=(t3 - t2) * 1e3)
+        self._note_rescore(pend)
         self._after_batch(pend, results)
         self._report_finished(pend)
         self.last_phase_ms = pend.phase_ms
@@ -1000,6 +1023,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             "last_gated_fraction": self.last_gated_fraction,
             "overlap": self._overlap_stats(),
             "repairs": self._repair_stats(),
+            "rescore": self._rescore_stats(),
             "extract_chunks": (self._nchunks if self._chunks is not None
                                else 0),
             "summary_blocks": (r * self._nchunks if self._summ else 0),

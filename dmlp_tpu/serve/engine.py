@@ -533,6 +533,22 @@ class ResidentServingCore:
                 "host": int(repairs.value("host"))}
 
     @staticmethod
+    def _note_rescore(pend: "PendingBatch") -> None:
+        """Always-on counts of the float64 rescore: candidate slots
+        finalized and rows gathered for them (fewer where the hazard
+        test's bound cut the rescore to its band:
+        engine.finalize.boundary_band)."""
+        reg = telemetry.registry()
+        reg.counter("serve.rescore_slots").inc(pend.rescore_slots)
+        reg.counter("serve.rescore_rows").inc(pend.rescore_rows)
+
+    @staticmethod
+    def _rescore_stats() -> Dict[str, int]:
+        reg = telemetry.registry()
+        return {"slots": int(reg.counter("serve.rescore_slots").total()),
+                "rows": int(reg.counter("serve.rescore_rows").total())}
+
+    @staticmethod
     def _overlap_stats() -> Dict[str, int]:
         """Always-on counts of the batcher's pipeline: micro-batches
         delivered, and those begun while another was in flight."""
@@ -1800,6 +1816,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     def _run_finish(self, pend: PendingBatch) -> List[QueryResult]:
         results = super()._run_finish(pend)
         self._note_flagged(pend.repairs, pend.retry_cleared)
+        self._note_rescore(pend)
         self._after_batch(pend, results)
         self._report_finished(pend)
         self.last_kernel_calls = pend.kernel_calls
@@ -1965,6 +1982,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             # queries the hazard test flagged, and where each was
             # repaired (the device retry / the host oracle)
             "repairs": self._repair_stats(),
+            # candidate slots finalized in float64 and rows gathered
+            # for them since start (the band: finalize.boundary_band)
+            "rescore": self._rescore_stats(),
             "summary_blocks": self._ex_nchunks if self._summ else 0,
             "summary_rebuilds": self.summary_rebuilds,
             # chunks whose staged row norms were written since start:

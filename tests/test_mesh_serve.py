@@ -354,8 +354,19 @@ def test_the_mesh_spans_carry_what_the_metrics_read(traced):
     hz = named(traced["served"], "fleet.hazard")[0]["args"]
     assert hz["rows"] == 100000 and hz["flagged"] == 0
     assert hz["dn_max_cached"] is True      # warm-up's batch made the pass
-    assert named(traced["served"], "fleet.finalize")[0]["args"][
-        "repairs"] == 0
+    fin = named(traced["served"], "fleet.finalize")[0]["args"]
+    assert fin["repairs"] == 0
+    # the float64 rescore gathers each query's band, not its window
+    # (PR 48): rows of slots, the bytes they weigh, the share
+    slots = fin["slots"] * fin["queries"]
+    assert fin["queries"] == 8 and 8 * K <= fin["rows"] < slots
+    assert fin["gather_bytes"] == fin["rows"] * NA * 8
+    assert fin["band_pct"] == pytest.approx(100.0 * fin["rows"] / slots,
+                                            abs=1e-3)
+    finals = [e["args"] for e in named(traced["all"], "fleet.finalize")]
+    assert traced["stats"]["engine"]["rescore"] == {
+        "slots": sum(a["slots"] * a["queries"] for a in finals),
+        "rows": sum(a["rows"] for a in finals)}
     merge = named(traced["served"], "fleet.merge")[0]["args"]
     assert merge["strategy"] == "allgather"
     assert merge["bytes"] == traced["merge_bytes"] \

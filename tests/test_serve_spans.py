@@ -172,8 +172,13 @@ def test_span_args_say_what_the_work_was(traced):
     # bucket's window, the bytes the finalize span reports, the score
     rescore = ev["single.rescore"]
     assert rescore["queries"] == 3 and rescore["slots"] >= 8
+    # ``rows``: what the rescore gathered. A flagged query's band is its
+    # whole window (PR 48; the 40 copies sit inside the bound), so here
+    # it is every slot; ``gather_bytes`` is rows x A x 8 B either way
+    assert rescore["rows"] == 3 * rescore["slots"]
+    assert rescore["band_pct"] == 100.0
     assert rescore["bytes"] == ev["single.finalize"]["gather_bytes"] \
-        == 3 * rescore["slots"] * NA * 8
+        == rescore["rows"] * NA * 8
     for n in ("serve.solve_extract", "single.hazard", "single.finalize",
               "single.retry_begin", "single.rescore"):
         assert ev[n]["score"] == "l2", n
@@ -196,6 +201,20 @@ def test_span_args_say_what_the_work_was(traced):
     assert ev["serve.phase.parse"]["bytes"] > 0
     assert ev["serve.phase.parse"]["rid"] == "r-1"
     assert ev["serve.phase.respond"]["rid"] == "r-1"
+
+
+def test_the_rescore_counter_adds_up_over_batches(traced):
+    """Always on: ``stats.engine.rescore`` is the candidate slots
+    finalized in float64 and the rows gathered for them since start:
+    warm-up's batch and the served one, as their spans say it."""
+    spans = [e["args"] for e in named(traced["all"], "single.rescore")]
+    assert len(spans) >= 2
+    assert traced["stats"]["engine"]["rescore"] == {
+        "slots": sum(a["queries"] * a["slots"] for a in spans),
+        "rows": sum(a["rows"] for a in spans)}
+    finals = [e["args"] for e in named(traced["all"], "single.finalize")]
+    assert sum(a["gather_bytes"] for a in finals) \
+        == sum(a["rows"] for a in spans) * NA * 8
 
 
 @pytest.mark.parametrize("name", ["serve.solve_extract",

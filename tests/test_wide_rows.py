@@ -240,7 +240,12 @@ def test_a_window_the_bound_does_not_clear_is_flagged_and_repaired():
     assert hz["flagged"] == 1 and 0 <= hz["clear_min"] < 1
     assert fin["repairs"] == 1 and eng.last_repairs == 1
     assert eng.bucket_plan(2, k) == (128, 16, 40)
-    assert fin["gather_bytes"] == 2 * 40 * na * 8
+    # the flagged query's band is its whole window (the pack sits inside
+    # the bound), the other query's a few slots past its k-th: the
+    # finalize gathers the bands' rows, not the 2 x 40 slots
+    (rs,) = _spans(tracer, "single.rescore")
+    assert rs["slots"] == 40 and 40 + k <= rs["rows"] < 2 * 40
+    assert fin["gather_bytes"] == rs["rows"] * na * 8
     inp = KNNInput(Params(n, 2, na), corpus.labels, rows, ks, q)
     assert format_results(got) == format_results(knn_golden(inp))
     assert set(got[0].neighbor_ids) <= set(pack.tolist())
