@@ -23,6 +23,11 @@ binary (``oracle_capture/oracle_4.out``):
                                                      product: the golden
                                                      model's answers, ties
                                                      included
+  cosine    python chip_smoke.py --cosine-child      the same ranked by
+                                                     cosine: unit rows on
+                                                     the device, float32,
+                                                     zero rows and exact
+                                                     copies included
   serve     python -m dmlp_tpu.serve --pallas ...    queries over TCP,
                                                      stats, SIGTERM drain
   mesh      --mode sharded|ring --mesh 4,1           only on >= 4 chips
@@ -81,6 +86,17 @@ unless every answer is the golden model's under ip (labels, ids in
 order, products within 1e-11 of |q| max|x|), the stamp says select
 ``extract`` and score ``ip``, and the tied queries were flagged and
 repaired (the device retry, then the host oracle).
+
+``cosine`` (PR 49) holds the chip to the third score: one child builds
+the serving engine under ``score="cosine"`` and ``dtype="float32"`` (as
+the benchmark's cell stages it) over seeded reals of 1536 attributes,
+twelve whole lane vectors, that are NOT unit vectors, with a zero row,
+a block of rows a hundred exact copies each and a zero query, solves one
+micro-batch and fails unless every answer is the golden model's under
+cosine (labels, ids in order, angular distances within 1e-11), the
+stamp says select ``extract`` and score ``cosine``, the first pass ran
+as ``bf16x3`` over rows the device holds at unit norm, and the tied
+queries were flagged and repaired.
 
 The configs run in the order given (default ``1,4``): config 1 is the
 same path at a size that takes seconds, so a machine with no chip fails
@@ -691,6 +707,130 @@ def ip_misses(got: Dict[str, Any]) -> List[str]:
     return bad
 
 
+#: the ``cosine`` phase's corpus: uniform rows of norms over two decades,
+#: one of them zero, then ``points`` rows ``copies`` exact copies each
+COSINE_SHAPE = dict(rows=12000, attrs=1536, points=4, copies=100,
+                    queries=128, k=10)
+
+
+def cosine_child(out_path: str) -> int:
+    """The ``cosine`` child: a resident engine under ``score="cosine"``
+    and float32 staging over a seeded corpus (a zero row; its last rows
+    exact copies by the hundred), one micro-batch of uniform queries, of
+    scaled copies of the copied rows (the best cosines tie past the
+    candidate window) and one zero query, against golden.fast under
+    cosine; what it found goes to ``out_path``."""
+    import numpy as np
+
+    from dmlp_tpu.config import EngineConfig
+    from dmlp_tpu.golden.fast import knn_golden_fast
+    from dmlp_tpu.io.grammar import KNNInput, Params
+    from dmlp_tpu.obs.run import device_stamp
+    from dmlp_tpu.serve.engine import ResidentEngine
+    n, na, points, copies, nq, k = (COSINE_SHAPE[x] for x in (
+        "rows", "attrs", "points", "copies", "queries", "k"))
+    rng = np.random.default_rng(49)
+    rows = (rng.uniform(-1, 1, (n, na)) * 10.0 ** rng.uniform(-1, 1, (n, 1))
+            ).astype(np.float32).astype(np.float64)
+    rows[17] = 0.0
+    pts = rows[:points].copy()
+    rows[n - points * copies:] = np.repeat(pts, copies, axis=0)
+    labels = rng.integers(0, 10, n).astype(np.int32)
+    queries = rng.uniform(-1, 1, (nq, na)).astype(np.float32).astype(
+        np.float64)
+    queries[:points] = pts * 4.0
+    queries[points] = 0.0
+    ks = np.full(nq, k, np.int32)
+    corpus = KNNInput(Params(n, 0, na), labels, rows, np.zeros(0, np.int32),
+                      np.zeros((0, na)))
+    eng = ResidentEngine(corpus, EngineConfig(
+        use_pallas=True, score="cosine", dtype="float32"))
+    got = eng.solve_batch(queries, ks)
+    want = knn_golden_fast(KNNInput(Params(n, nq, na), labels, rows, ks,
+                                    queries), score="cosine")
+    wrong = sum(int(g.predicted_label != w.predicted_label
+                    or not np.array_equal(g.neighbor_ids, w.neighbor_ids))
+                for g, w in zip(got, want))
+    err = max(float(np.max(np.abs(g.neighbor_dists - w.neighbor_dists)))
+              for g, w in zip(got, want))
+    tied = sum(int(len(set(g.neighbor_dists.tolist())) == 1)
+               for g in got[:points])
+    staged = np.asarray(eng._chunks[0, :64, :], np.float64)
+    stats = eng.bucket_stats()
+    with open(out_path, "w") as f:
+        json.dump({
+            "device": device_stamp(eng), "shape": COSINE_SHAPE,
+            "staging": eng._staging, "staged_attrs": stats["staged_attrs"],
+            "paths": stats["paths"], "wrong": wrong, "err": err,
+            "tied_answers": tied,
+            "zero_query_ids": got[points].neighbor_ids.tolist(),
+            "staged_norm_err": float(np.abs(np.sqrt(
+                (staged[:17] ** 2).sum(axis=1)) - 1.0).max()),
+            "staged_zero_row": float(np.abs(staged[17]).max()),
+            "first_pass": (eng.last_precision or {}).get("active"),
+            "repairs": stats["repairs"],
+        }, f)
+    return 0
+
+
+def phase_cosine(c: Config) -> List[str]:
+    """``cosine``: a resident corpus ranked by cosine answers as the
+    golden model does, zero rows and exact copies included, on the
+    extract path, from unit rows in float32."""
+    got, wall, bad = run_fold_child(c, "cosine", "--cosine-child")
+    if got is None:
+        return bad
+    stamp = got.get("device") or {}
+    say(f"  cosine: wall {wall:.1f} s (smoke timing); {got['shape']}; "
+        f"staged {got['staging']} on {got['staged_attrs']} lanes, |x^| - 1 "
+        f"at most {got['staged_norm_err']:.3g}; paths {got['paths']}; "
+        f"score {stamp.get('score')}; first pass {got['first_pass']}; "
+        f"answers off the golden model's: {got['wrong']}; distances "
+        f"against float64 {got['err']:.3g}; repairs {got['repairs']}")
+    return cosine_misses(got)
+
+
+def cosine_misses(got: Dict[str, Any]) -> List[str]:
+    """Every miss in the ``cosine`` child's record, named."""
+    stamp = got.get("device") or {}
+    bad = device_misses(stamp)
+    if stamp.get("score") != "cosine" or stamp.get("select") != "extract":
+        bad.append(f"the stamp says score {stamp.get('score')!r}, select "
+                   f"{stamp.get('select')!r}, not cosine on the extract "
+                   "path")
+    if set((got.get("paths") or {}).values()) != {"extract"}:
+        bad.append(f"bucket paths are {got.get('paths')}, not extract")
+    if got.get("staging") != "float32" or got.get("first_pass") != "bf16x3":
+        bad.append(f"staged {got.get('staging')}, first pass "
+                   f"{got.get('first_pass')!r}: not float32 rows under the "
+                   "three-pass split")
+    if got.get("staged_attrs") != COSINE_SHAPE["attrs"]:
+        bad.append(f"1536-wide rows are staged {got.get('staged_attrs')} "
+                   "wide, not on their own twelve lane vectors")
+    if not got.get("staged_norm_err", 1.0) <= 1e-6 \
+            or got.get("staged_zero_row") != 0.0:
+        bad.append(f"the device's rows are not unit rows (|x^| - 1 up to "
+                   f"{got.get('staged_norm_err')}; the zero row holds "
+                   f"{got.get('staged_zero_row')})")
+    if got.get("wrong"):
+        bad.append(f"{got['wrong']} answers differ from the golden "
+                   "model's under cosine")
+    if not got.get("err", 1.0) <= 1e-11:
+        bad.append(f"the angular distances are off float64's by "
+                   f"{got.get('err')}")
+    if got.get("tied_answers") != COSINE_SHAPE["points"]:
+        bad.append(f"{got.get('tied_answers')} of {COSINE_SHAPE['points']} "
+                   "tied queries came back as one tie group")
+    n, k = COSINE_SHAPE["rows"], COSINE_SHAPE["k"]
+    if got.get("zero_query_ids") != list(range(n - 1, n - 1 - k, -1)):
+        bad.append(f"the zero query's neighbours are "
+                   f"{got.get('zero_query_ids')}, not the largest ids")
+    repairs = got.get("repairs") or {}
+    if repairs.get("flagged_queries", 0) < COSINE_SHAPE["points"] + 1:
+        bad.append(f"repairs {repairs}: the tied queries were not flagged")
+    return bad
+
+
 def read_queries(c: Config, count: int) -> Tuple[List[int], List[list]]:
     """The first ``count`` queries of the input's query section, with
     their own k — straight from the text the batch child parsed."""
@@ -801,6 +941,7 @@ def run_config(config_id: int) -> Tuple[List[str], Optional[Dict]]:
     misses += [f"config {config_id} fold.narrow: {m}"
                for m in phase_fold_narrow(c)]
     misses += [f"config {config_id} ip: {m}" for m in phase_ip(c)]
+    misses += [f"config {config_id} cosine: {m}" for m in phase_cosine(c)]
     misses += [f"config {config_id} serve: {m}" for m in phase_serve(c)]
     from dmlp_tpu.config import EngineConfig
     chips = stamp.get("device_count", 0)
@@ -845,6 +986,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help=argparse.SUPPRESS)   # the fold.narrow phase's child
     ap.add_argument("--ip-child", metavar="OUT", default=None,
                     help=argparse.SUPPRESS)   # the ip phase's child
+    ap.add_argument("--cosine-child", metavar="OUT", default=None,
+                    help=argparse.SUPPRESS)   # the cosine phase's child
     args = ap.parse_args(argv)
     if args.fold_child:
         return fold_child(args.fold_child)
@@ -852,6 +995,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         return narrow_child(args.narrow_child)
     if args.ip_child:
         return ip_child(args.ip_child)
+    if args.cosine_child:
+        return cosine_child(args.cosine_child)
     if not os.path.isdir(os.path.join(REPO, "dmlp_tpu")):
         print(f"chip_smoke: no dmlp_tpu package beside {__file__}",
               file=sys.stderr)

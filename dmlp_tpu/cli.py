@@ -16,7 +16,7 @@ summary to stderr after the contract line.
 Usage::
 
     python -m dmlp_tpu [--mode single|sharded|ring|auto] [--debug] [--fast]
-                       [--engine jax|golden|auto] [--score l2|ip]
+                       [--engine jax|golden|auto] [--score l2|ip|cosine]
                        [--phase-times]
                        [--compile-cache DIR] [--hlo-report FILE]
                        [--trace FILE] [--metrics FILE] [--counters] < input.in
@@ -28,7 +28,7 @@ import argparse
 import sys
 from typing import IO, Optional, Sequence
 
-from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.config import SCORES, EngineConfig
 from dmlp_tpu.io.grammar import parse_input
 from dmlp_tpu.io.report import format_results
 from dmlp_tpu.obs.trace import span as obs_span
@@ -199,14 +199,16 @@ def main(argv: Optional[Sequence[str]] = None,
     parser.add_argument("--select", default="auto",
                         choices=["auto", "sort", "topk", "seg", "extract"],
                         help="device k-selection strategy")
-    parser.add_argument("--score", default="l2", choices=["l2", "ip"],
+    parser.add_argument("--score", default="l2", choices=list(SCORES),
                         help="what the corpus is ranked by: l2 = smallest "
                              "squared distance; ip = LARGEST inner product "
                              "(larger id first on ties; --debug prints the "
-                             "products). The golden model (--engine golden) "
-                             "and the serving daemon's one-chip extract "
-                             "path have the ip form; the batch engines "
-                             "refuse it by name")
+                             "products); cosine = LARGEST q.x / (|q||x|), 0 "
+                             "against a zero vector (--debug prints the "
+                             "angular distance 1 - s). The golden model "
+                             "(--engine golden) and the serving daemon's "
+                             "one-chip extract path have the ip and cosine "
+                             "forms; the batch engines refuse them by name")
     parser.add_argument("--phase-times", action="store_true",
                         help="per-phase ms breakdown on stderr (extension)")
     parser.add_argument("--pallas", action="store_true",

@@ -13,9 +13,21 @@ import dataclasses
 from typing import Optional, Tuple
 
 
-#: what a corpus may be ranked by (EngineConfig.score; the kernel's
-#: ``score`` static, ops.pallas_extract): THE single definition
-SCORES = ("l2", "ip")
+#: what a corpus may be ranked by (EngineConfig.score): THE single
+#: definition
+SCORES = ("l2", "ip", "cosine")
+
+#: the forms the extraction kernel has (its ``score`` static,
+#: ops.pallas_extract). "cosine" is none of them: it is the "ip" form
+#: over operands normalised at staging (kernel_score)
+KERNEL_SCORES = ("l2", "ip")
+
+
+def kernel_score(score: str) -> str:
+    """The kernel form that orders a corpus ranked by ``score``: its
+    own for "l2" and "ip"; "ip" for "cosine", whose staged rows and
+    queries are x / |x| and q / |q|, so that -q^.x^ is -s."""
+    return "ip" if score == "cosine" else score
 
 
 def score_of(engine) -> str:
@@ -126,14 +138,18 @@ class EngineConfig:
       score: what a corpus is ranked by, "l2" (the default: smallest
         squared Euclidean distance) or "ip" (LARGEST inner product
         s(q, x) = sum_a q_a x_a, float64; neighbours by (s descending,
-        id DESCENDING on ties); golden.reference has the contract).
+        id DESCENDING on ties) or "cosine" (LARGEST s(q, x) = q.x /
+        (|q| |x|), 0 against a zero vector, same order; reported as the
+        angular distance 1 - s); golden.reference has the contracts.
         A property of the corpus, never of a request: every program an
         engine compiles is keyed on it, and inside the program the
-        ordered quantity under "ip" is -s so that every list still
-        ascends. The one-chip serving engine's extract path
+        ordered quantity under "ip" and "cosine" is -s so that every
+        list still ascends. The one-chip serving engine's extract path
         (serve.engine.ResidentEngine) and the golden model have the
-        "ip" form; every other engine refuses it at construction by
-        name (require_score): none answers an ip corpus in L2.
+        "ip" and "cosine" forms (cosine is the ip kernel over rows and
+        queries normalised in float64 at staging: kernel_score); every
+        other engine refuses them at construction by name
+        (require_score): none answers an ip or cosine corpus in L2.
     """
 
     AUTO_SELECT_THRESHOLD = 8192
@@ -176,15 +192,15 @@ class EngineConfig:
                       scores: Tuple[str, ...] = ("l2",)) -> None:
         """Refuse, by name, an engine that lacks this configuration's
         score: ``engine`` ranks by ``scores`` alone (squared L2 unless
-        it says otherwise), and answering an inner-product corpus in
-        L2 would be a wrong answer, not a slow one."""
+        it says otherwise), and answering an inner-product or a cosine
+        corpus in L2 would be a wrong answer, not a slow one."""
         if self.score not in scores:
             raise ValueError(
                 f"{engine} has no score={self.score!r} form (it ranks by "
-                f"{' | '.join(scores)}): serve an inner-product corpus "
-                "through the one-chip daemon's extract path "
-                "(python -m dmlp_tpu.serve --pallas --score ip) or the "
-                "golden model (--engine golden)")
+                f"{' | '.join(scores)}): serve an inner-product or a "
+                "cosine corpus through the one-chip daemon's extract path "
+                f"(python -m dmlp_tpu.serve --pallas --score {self.score}) "
+                "or the golden model (--engine golden)")
 
     def resolve_dtype(self) -> str:
         """Concrete staging dtype ("float32" | "bfloat16") for this run.

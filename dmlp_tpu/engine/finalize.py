@@ -22,6 +22,7 @@ from typing import List
 
 import numpy as np
 
+from dmlp_tpu.golden.reference import cosine_of, row_norms
 from dmlp_tpu.io.report import QueryResult
 
 
@@ -72,13 +73,20 @@ def rescore_block(k: int, num_attrs: int) -> int:
 def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
                 data_attrs: np.ndarray, block: int | None = None,
                 score: str = "l2",
-                widths: np.ndarray | None = None) -> np.ndarray:
+                widths: np.ndarray | None = None,
+                data_norms: np.ndarray | None = None) -> np.ndarray:
     """Exact float64 distances for candidate ids (difference form, like
     computeDistance at engine.cpp:12-18). ids < 0 map to +inf.
     Under ``score`` "ip" the ordered quantity is the NEGATED inner
     product -(q . x) of the gathered rows (so that it ascends like a
     distance; finalize_host hands the wire the product itself): one
-    pass over the buffer where the difference form takes two.
+    pass over the buffer where the difference form takes two. Under
+    "cosine" it is -s, the contract's expression (golden.reference
+    .cosine_of) on that product, the queries' norms and ``data_norms``,
+    the rows' |x| as golden.reference.row_norms gives them (a holder of
+    the corpus keeps them beside it: KNNInput.data_norms): the same one
+    pass, and the ORIGINAL rows, never the normalised ones the device
+    holds.
 
     ``widths`` (band_widths; (Q,) ints) are the leading slots of each
     list to gather and score; a slot past its query's width carries
@@ -106,6 +114,9 @@ def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
     na = data_attrs.shape[1]
     safe = np.clip(cand_ids, 0, data_attrs.shape[0] - 1)
     out = np.full((q, k), np.inf)
+    product = score in ("ip", "cosine")
+    if score == "cosine" and data_norms is None:
+        data_norms = row_norms(data_attrs)
     # (queries, how many, width, queries a block) of each rectangle
     if widths is None:
         groups = [(slice(None), q, k)] if q and k else []
@@ -126,13 +137,15 @@ def rescore_f64(cand_ids: np.ndarray, query_attrs: np.ndarray,
             q1 = min(q0 + b, m)
             rows = buf[:(q1 - q0) * w * na].reshape(q1 - q0, w, na)
             np.take(data_attrs, ids[q0:q1], axis=0, out=rows, mode="clip")
-            if score == "ip":
+            if product:
                 np.einsum("qka,qa->qk", rows, qa[q0:q1], out=dst[q0:q1])
                 continue
             diff = np.subtract(rows, qa[q0:q1, None, :],
                                out=rows if inplace else None)
             dst[q0:q1] = np.einsum("qka,qka->qk", diff, diff)
-        out[idx, :w] = np.negative(dst, out=dst) if score == "ip" else dst
+        if score == "cosine":
+            dst = cosine_of(dst, row_norms(qa)[:, None], data_norms[ids])
+        out[idx, :w] = np.negative(dst, out=dst) if product else dst
     out[cand_ids < 0] = np.inf
     return out
 
@@ -245,10 +258,43 @@ LOWP_COEF = {"f32": 0.0, "bf16x3": 2.0 ** -14 * (1.0 + 2.0 ** -16),
 EPS_IP_REL = {"bfloat16": 2.0 ** -6 * (1.0 + 2.0 ** -8),
               "float32": 2.0 ** -22 * (1.0 + 2.0 ** -8)}
 
+#: The cosine score (config.EngineConfig.score "cosine"): the device
+#: holds x^ = fl64(x / |x|) and is handed q^ = fl64(q / |q|), each then
+#: cast to the staging dtype, and runs the "ip" form over them, so the
+#: bound is ip_coef at |q^| max|x^| = 1 (0 for a zero query or an
+#: all-zero corpus: every device score is then an exact 0) PLUS what
+#: separates q^ . x^ from the host's s, which the "ip" bound never had
+#: to count because there both sides start from the same operands.
+#:
+#: NORMALISATION. fl(sum_a x_a^2) = |x|^2 (1 + t), |t| <= A 2^-53; the
+#: square root halves that and rounds once, the division rounds once:
+#: x^_a = (x_a / |x|)(1 + h_a), |h_a| <= (A / 2 + 2) 2^-53, and q^
+#: alike, so |q^ . x^ - s| <= (A + 4) 2^-53 sum_a |q_a x_a| / (|q||x|)
+#: <= (A + 4) 2^-53 to first order. The host's own s (the dot's A
+#: products, two norms, one product and one quotient, all float64) is
+#: within (2A + 4) 2^-53 of the real number. One device score against
+#: one host score: (3A + 8) 2^-53; the test compares two: (3A + 8)
+#: 2^-52 <= COS_NORM_COEF * (A + 4), 1.5e-12 at 1536 attributes beside
+#: the float32 staging's 1.1e-3. (|x^| itself is 1 + (A / 2 + 2) 2^-53
+#: at most, 2e-13 of the CAST term: inside the (1 + 2^-8) that term is
+#: rounded up by.)
+COS_NORM_COEF = 2.0 ** -50
+
 
 def _ip_lowp_coef(precision: str) -> float:
     return EPS_IP_REL["bfloat16"] if precision == "bf16" \
         else LOWP_COEF[precision]
+
+
+def _product_scale(qn: np.ndarray, dn_max: float, score: str) -> np.ndarray:
+    """|q| max|x| of the operands the DEVICE multiplied, the scale of
+    the "ip" and "cosine" bounds. Under "ip" the operands are the
+    caller's. Under "cosine" they were normalised: ``qn`` (of the
+    queries as given) says only which queries are zero, and ``dn_max``
+    is the staged rows' own, 1 (0 of an all-zero corpus)."""
+    if score == "cosine":
+        qn = qn > 0
+    return np.sqrt(qn * dn_max)
 
 
 def ip_coef(staging: str, na: int, precision: str = "f32") -> float:
@@ -276,14 +322,15 @@ def lowp_eps(precision: str, qn: np.ndarray, dn_max: float,
     three-pass "bf16x3" form, 2^-6 for one bf16 pass. Raises KeyError on
     a precision with no static bound (int8 — see LOWP_COEF). Under
     ``score`` "ip" the scale is |q| sqrt(dn_max) and the coefficient
-    the form's inner-product one (EPS_IP_REL's comment)."""
+    the form's inner-product one (EPS_IP_REL's comment); under "cosine"
+    the same at unit operands (_product_scale)."""
     qn = np.asarray(qn, np.float64)
-    coef = _ip_lowp_coef(precision) if score == "ip" \
-        else LOWP_COEF[precision]
+    product = score in ("ip", "cosine")
+    coef = _ip_lowp_coef(precision) if product else LOWP_COEF[precision]
     if not coef:
         return np.zeros_like(qn)
-    if score == "ip":
-        return coef * np.sqrt(qn * dn_max)
+    if product:
+        return coef * _product_scale(qn, dn_max, score)
     return coef * (qn + dn_max)
 
 
@@ -329,11 +376,16 @@ def staging_eps(last: np.ndarray, qn: np.ndarray, dn_max: float,
     and no cancellation: (EPS_IP_REL[staging] + EPS_CANCEL_COEF *
     (na + 2)) * |q| * sqrt(dn_max), the cast of both operands and the
     float32 accumulation of the dot, whatever ``last`` is (EPS_IP_REL's
-    comment derives both).
+    comment derives both). Under "cosine" that bound at the unit
+    operands the device was given, and the normalisation's own rounding
+    beside it (COS_NORM_COEF's comment).
     """
-    if score == "ip":
-        return ip_coef(staging, na) * np.sqrt(
-            np.asarray(qn, np.float64) * dn_max)
+    if score in ("ip", "cosine"):
+        coef = ip_coef(staging, na)
+        if score == "cosine":
+            coef += COS_NORM_COEF * (na + 4)
+        return coef * _product_scale(np.asarray(qn, np.float64), dn_max,
+                                     score)
     rel = EPS_REL_BF16 if staging == "bfloat16" else EPS_REL_F32
     scale = qn + dn_max
     return (rel * np.sqrt(np.maximum(last, 0.0) * scale)
@@ -494,7 +546,9 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
                   query_attrs: np.ndarray, data_attrs: np.ndarray,
                   exact: bool = True,
                   query_ids: np.ndarray | None = None,
-                  score: str = "l2") -> List[QueryResult]:
+                  score: str = "l2",
+                  data_norms: np.ndarray | None = None
+                  ) -> List[QueryResult]:
     """Candidate lists -> final per-query results.
 
     Args:
@@ -510,7 +564,11 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
         NEGATED inner product (``cand_dists`` too), so the one
         (dist asc, id desc) order is (s desc, id desc); the results
         carry s itself, the contract's value (padding -inf), which is
-        what the wire and the golden model report.
+        what the wire and the golden model report. Under "cosine" they
+        are -s likewise and the results carry the angular distance
+        d = 1 - s, ascending, padding +inf.
+      data_norms: the rows' norms for an ``exact`` rescore under
+        "cosine" (rescore_f64).
     """
     q, kcap = cand_ids.shape
     ks = np.asarray(ks, np.int64)
@@ -518,7 +576,8 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
         raise ValueError(f"candidate width {kcap} < max k {ks.max()}")
     cand_ids = np.asarray(cand_ids, np.int64)
     cand_labels = np.asarray(cand_labels, np.int64)
-    d = rescore_f64(cand_ids, query_attrs, data_attrs, score=score) \
+    d = rescore_f64(cand_ids, query_attrs, data_attrs, score=score,
+                    data_norms=data_norms) \
         if exact else np.asarray(cand_dists, np.float64)
 
     # Re-derive the selection order (dist asc, id desc — the measured
@@ -544,6 +603,8 @@ def finalize_host(cand_dists: np.ndarray | None, cand_labels: np.ndarray,
     rids = np.where(valid, ids, -1)
     if score == "ip":
         rd = -rd          # the product itself; -(-0.0) is +0.0
+    elif score == "cosine":
+        rd = 1.0 + rd     # 1 - s; a padded slot stays +inf
 
     if query_ids is None:
         query_ids = np.arange(q, dtype=np.int64)

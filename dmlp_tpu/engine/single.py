@@ -1564,7 +1564,8 @@ class SingleChipEngine:
                 if exact:
                     # The float64 gather-and-score, under a span of its
                     # own: the part of the finalize the score changes
-                    # (difference form; under "ip" the product alone).
+                    # (difference form; under "ip" the product alone,
+                    # under "cosine" the product over the norms).
                     # finalize_host takes the rescored distances as it
                     # takes fast mode's device ones.
                     pend.rescore_slots += ids.size
@@ -1577,7 +1578,8 @@ class SingleChipEngine:
                         dists = rescore_f64(np.asarray(ids, np.int64),
                                             sub.query_attrs,
                                             sub.data_attrs, score=score,
-                                            widths=widths)
+                                            widths=widths,
+                                            data_norms=sub.data_norms)
                 results = finalize_host(dists, labels, ids, sub.ks,
                                         sub.query_attrs, sub.data_attrs,
                                         exact=False, query_ids=idx,

@@ -19,7 +19,10 @@ continuous data, possible for adversarial duplicates).
 Under ``score="ip"`` (golden.reference has the contract) the coarse value
 is the dgemm's -q.x alone and the rescore the same product by einsum over
 the gathered rows; the safety check stands with the dot's own bound
-(A products of magnitude at most |q||x|, no cancellation).
+(A products of magnitude at most |q||x|, no cancellation). Under
+``score="cosine"`` both are golden.reference.cosine_of on that product
+and the norms (``inp.data_norms`` where the corpus' holder keeps them),
+and the bound is the dot's at unit operands.
 """
 
 from __future__ import annotations
@@ -29,17 +32,22 @@ from typing import List, Optional
 import numpy as np
 
 from dmlp_tpu.engine.finalize import finalize_host
-from dmlp_tpu.golden.reference import finalize_query
+from dmlp_tpu.golden.reference import cosine_of, finalize_query, row_norms
 from dmlp_tpu.io.grammar import KNNInput
 from dmlp_tpu.io.report import QueryResult
 
 
 def _strict_row(inp: KNNInput, qi: int, data: np.ndarray,
                 labels: np.ndarray, ids: np.ndarray,
-                score: str = "l2") -> QueryResult:
+                score: str = "l2",
+                dnorm: Optional[np.ndarray] = None) -> QueryResult:
     """Exact full-row solve for one query (the knn_golden inner loop)."""
-    if score == "ip":
-        drow = -np.einsum("na,a->n", data, inp.query_attrs[qi])
+    if score in ("ip", "cosine"):
+        drow = np.einsum("na,a->n", data, inp.query_attrs[qi])
+        if score == "cosine":
+            drow = cosine_of(drow, row_norms(
+                inp.query_attrs[qi:qi + 1]), dnorm)
+        drow = -drow
     else:
         diff = data - inp.query_attrs[qi][None, :]
         drow = np.einsum("na,na->n", diff, diff)
@@ -57,10 +65,17 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
     to the strict full-row path>} so the safety valve's cost is observable.
     """
     nd, nq = inp.params.num_data, inp.params.num_queries
-    data = inp.data_attrs.astype(np.float64)
+    # (no copy of a float64 corpus: a served repair holds 10^7 rows)
+    data = inp.data_attrs.astype(np.float64, copy=False)
     labels = inp.labels.astype(np.int64)
     ids = np.arange(nd, dtype=np.int64)
-    dn = np.einsum("na,na->n", data, data)
+    product = score in ("ip", "cosine")
+    dnorm = dn = None
+    if score == "cosine":
+        dnorm = inp.data_norms if inp.data_norms is not None \
+            else row_norms(data)
+    else:
+        dn = np.einsum("na,na->n", data, data)
     kmax = int(inp.ks.max()) if nq else 1
     kcand = min(nd, kmax + margin)
     # Error bound of the norm+matmul form relative to the difference form:
@@ -74,11 +89,20 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
         q1 = min(q0 + query_block, nq)
         q = inp.query_attrs[q0:q1].astype(np.float64)
         qn = np.einsum("qa,qa->q", q, q)
+        if score == "cosine":
+            qnorm = row_norms(q)
         # In-place epilogue on the dgemm output: the broadcast expression
         # form allocates ~4 (Qb, N) f64 temporaries, which measured ~10x
         # the dgemm itself at benchmark scale (page faults on fresh GBs).
         coarse = q @ data.T
-        if score == "ip":
+        if product:
+            if score == "cosine":
+                # a screen (cosine_of decides, below): divided in place,
+                # no further (Qb, N) temporary; a zero norm's dots are 0
+                np.divide(coarse, dnorm[None, :], out=coarse,
+                          where=(dnorm > 0)[None, :])
+                np.divide(coarse, qnorm[:, None], out=coarse,
+                          where=(qnorm > 0)[:, None])
             np.negative(coarse, out=coarse)
         else:
             coarse *= -2.0
@@ -90,8 +114,11 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
         else:
             cand = np.broadcast_to(ids[None, :], (q1 - q0, nd))
         # Exact difference-form rescore of the candidates only.
-        if score == "ip":
-            exact = -np.einsum("qka,qa->qk", data[cand], q)
+        if product:
+            exact = np.einsum("qka,qa->qk", data[cand], q)
+            if score == "cosine":
+                exact = cosine_of(exact, qnorm[:, None], dnorm[cand])
+            exact = -exact
         else:
             diff = data[cand] - q[:, None, :]
             exact = np.einsum("qka,qka->qk", diff, diff)
@@ -106,7 +133,13 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
             # global max norm, not dn[cand] (ADVICE r1: the candidate-norm
             # bound did not strictly prove exactness for adversarial
             # large-norm excluded points).
-            err_q = 256.0 * eps * (qn + (dn.max() if nd else 0.0) + 1.0)
+            if score == "cosine":
+                # unit operands, A products a dot: the worst case grows
+                # with the width, the 256 does not
+                err_q = np.full(q1 - q0, (256.0 + 2.0 * data.shape[1])
+                                * eps * 3.0)
+            else:
+                err_q = 256.0 * eps * (qn + (dn.max() if nd else 0.0) + 1.0)
             # Safety (vectorized): the k-th exact distance must clear the
             # coarse selection boundary by the error bound, else that
             # query's candidates may be wrong -> strict full-row fallback.
@@ -144,7 +177,7 @@ def knn_golden_fast(inp: KNNInput, margin: int = 64,
         results[q0:q1] = blk
         for row in np.nonzero(~ok)[0]:
             results[q0 + row] = _strict_row(inp, q0 + row, data, labels,
-                                            ids, score)
+                                            ids, score, dnorm)
             fallbacks += 1
     if stats is not None:
         stats["fallbacks"] = fallbacks

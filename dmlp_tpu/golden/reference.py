@@ -37,6 +37,25 @@ one quantity changed:
   slots carry -inf (the worst possible score, as +inf is the worst
   distance).
 
+Under ``score="cosine"`` (a corpus ranked by cosine similarity, as
+ann-benchmarks' ``angular`` datasets are) the same contract again:
+
+- s(q, x) = (sum_a q_a x_a) / (sqrt(sum_a q_a^2) * sqrt(sum_a x_a^2)),
+  every sum, root, product and quotient in float64, on the rows and the
+  query AS GIVEN (:func:`cosine_of` on :func:`row_norms`: THE expression,
+  which the finalize's rescore evaluates too);
+- a zero row or a zero query scores s = 0 against everything (as FAISS's
+  ``normalize_L2`` leaves a zero vector zero); so does a pair whose
+  norms' product underflows to zero;
+- neighbours are the k rows of LARGEST s, ordered by (s descending, tie ->
+  **larger id** first), -s in the distance's place as under "ip";
+- vote, padding and checksum unchanged;
+- the reported ``neighbor_dists`` carry the angular distance d = 1 - s,
+  ASCENDING in that order; padded slots carry +inf;
+- exact copies of a row tie exactly (same products, same norm). Scaled
+  copies c * x are NOT promised to: their computed s may differ from
+  x's in the last place, and the order between them is then s's.
+
 On tie-free inputs — every graded benchmark input; continuous draws tie with
 probability ~0 — the label-free and label-aware orders coincide, which is
 why all 21,000 captured benchmark checksums match either way
@@ -70,6 +89,28 @@ def _select_order(dists: np.ndarray, labels: np.ndarray, ids: np.ndarray) -> np.
     return np.lexsort((-ids, dists))
 
 
+def row_norms(attrs: np.ndarray) -> np.ndarray:
+    """(n,) float64 |x| of the (n, a) rows: sqrt(sum_a x_a^2), the one
+    expression every holder of a cosine corpus computes its norms by
+    (the golden models, the serving engine's resident vector, the
+    rescore's query norms), so that a row's norm is the same bits
+    wherever it is read."""
+    attrs = np.asarray(attrs, np.float64)
+    return np.sqrt(np.einsum("na,na->n", attrs, attrs))
+
+
+def cosine_of(dot: np.ndarray, qnorm: np.ndarray,
+              xnorm: np.ndarray) -> np.ndarray:
+    """THE cosine contract's expression (module docstring): ``dot`` /
+    (``qnorm`` * ``xnorm``), broadcast; 0 wherever the norms' product is
+    0 (a zero row, a zero query)."""
+    den = np.asarray(qnorm * xnorm, np.float64)
+    dot = np.asarray(dot, np.float64)
+    out = np.zeros(np.broadcast(dot, den).shape)
+    np.divide(dot, den, out=out, where=den > 0)
+    return out
+
+
 def vote(labels: np.ndarray) -> int:
     """Majority vote with tie -> larger label (engine.cpp:320-332).
 
@@ -88,7 +129,8 @@ def finalize_query(drow: np.ndarray, labels: np.ndarray, ids: np.ndarray,
     """Candidate distances for one query -> its final QueryResult.
     Under ``score`` "ip" ``drow`` holds the NEGATED inner products (so
     the one order below is (s desc, id desc)) and the result reports s
-    itself, padded with -inf.
+    itself, padded with -inf; under "cosine" the negated cosines, and
+    the result reports d = 1 - s, padded with +inf.
 
     THE definition of the output contract, shared by the strict and fast
     oracles: select by (dist asc, id desc), vote (tie -> larger
@@ -109,15 +151,20 @@ def finalize_query(drow: np.ndarray, labels: np.ndarray, ids: np.ndarray,
         out_ids = np.concatenate([out_ids, np.full(pad, -1, np.int64)])
         out_dists = np.concatenate([out_dists, np.full(pad, np.inf)])
     out_dists = out_dists.astype(np.float64)
+    if score == "ip":
+        out_dists = -out_dists
+    elif score == "cosine":
+        out_dists = 1.0 + out_dists
     return QueryResult(qi, k, predicted, out_ids.astype(np.int64),
-                       -out_dists if score == "ip" else out_dists)
+                       out_dists)
 
 
 def knn_golden(inp: KNNInput, dtype=np.float64,
                query_block: int = 256,
                score: str = "l2") -> List[QueryResult]:
     """Solve a problem instance exactly; returns per-query results in id order.
-    ``score`` "l2" | "ip" (the module docstring has both contracts).
+    ``score`` "l2" | "ip" | "cosine" (the module docstring has the
+    contracts).
 
     ``dtype`` controls the distance arithmetic (float64 = reference parity;
     float32 mirrors the on-device engines for like-for-like differential
@@ -130,6 +177,8 @@ def knn_golden(inp: KNNInput, dtype=np.float64,
     queries = inp.query_attrs.astype(dtype)
     labels = inp.labels.astype(np.int64)
     ids = np.arange(nd, dtype=np.int64)
+    if score == "cosine":
+        dnorm, qnorm = row_norms(data), row_norms(queries)
 
     results: List[QueryResult] = []
     data_block = 8192  # bounds the (qb, nb, A) diff tensor
@@ -141,9 +190,12 @@ def knn_golden(inp: KNNInput, dtype=np.float64,
         dists = np.empty((q1 - q0, nd), dtype)
         for n0 in range(0, nd, data_block):
             n1 = min(n0 + data_block, nd)
-            if score == "ip":
-                dists[:, n0:n1] = -np.einsum("qa,na->qn", queries[q0:q1],
-                                             data[n0:n1])
+            if score in ("ip", "cosine"):
+                dot = np.einsum("qa,na->qn", queries[q0:q1], data[n0:n1])
+                if score == "cosine":
+                    dot = cosine_of(dot, qnorm[q0:q1, None],
+                                    dnorm[None, n0:n1])
+                dists[:, n0:n1] = -dot
                 continue
             diff = queries[q0:q1, None, :] - data[None, n0:n1, :]
             dists[:, n0:n1] = np.einsum("qna,qna->qn", diff, diff)

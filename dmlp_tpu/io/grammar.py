@@ -22,7 +22,7 @@ empty"; query line not starting with 'Q' -> "Line is wrongly formatted".
 from __future__ import annotations
 
 import dataclasses
-from typing import IO, List, Union
+from typing import IO, List, Optional, Union
 
 import numpy as np
 
@@ -88,6 +88,11 @@ class KNNInput:
     # back from the C++ tokenizer to the Python one silently when the
     # on-demand g++ build fails, so the run records say which ran.
     parser: str = "python"
+    # (num_data,) float64 |x| of data_attrs (golden.reference.row_norms)
+    # where the corpus' holder keeps them: a cosine corpus' serving
+    # engine, for the float64 rescore and the host oracle. None: whoever
+    # needs them computes them.
+    data_norms: Optional[np.ndarray] = None
 
     @property
     def data_ids(self) -> np.ndarray:
@@ -104,7 +109,8 @@ def subset_queries(inp: KNNInput, idx: np.ndarray) -> KNNInput:
     (engine.single) and the hazard repair (engine.finalize)."""
     return KNNInput(
         Params(inp.params.num_data, len(idx), inp.params.num_attrs),
-        inp.labels, inp.data_attrs, inp.ks[idx], inp.query_attrs[idx])
+        inp.labels, inp.data_attrs, inp.ks[idx], inp.query_attrs[idx],
+        inp.parser, inp.data_norms)
 
 
 def _strict_int(tok: str) -> int:

@@ -33,8 +33,10 @@ class QueryResult:
     golden.reference has the contract) ``neighbor_dists`` carries the
     inner products s themselves, sorted by (s DESCENDING, tie -> larger
     id), padded slots -inf; the debug report prints them in the
-    distance's place. The checksum is over the label and the ids either
-    way.
+    distance's place. Of a corpus ranked by cosine ("cosine") it carries
+    the angular distances 1 - s, ascending (s DESCENDING, tie -> larger
+    id), padded slots +inf. The checksum is over the label and the ids
+    whatever the score.
     """
 
     query_id: int

@@ -85,7 +85,7 @@ def device_stamp(engine=None) -> Dict[str, Any]:
         stamp.update(
             mesh=list(mesh.devices.shape) if mesh is not None else None,
             select=getattr(engine, "_last_select", None),
-            # what the corpus is ranked by ("l2" | "ip")
+            # what the corpus is ranked by (config.SCORES)
             score=score_of(engine),
             extract_impl=getattr(engine, "last_extract_impl", None),
             # None where the engine has no ladder (the mesh engines)

@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dmlp_tpu.config import SCORES
+from dmlp_tpu.config import KERNEL_SCORES
 from dmlp_tpu.utils.compat import tpu_compiler_params
 
 from dmlp_tpu.ops.pallas_distance import _tile
@@ -961,7 +961,7 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     "bf16", the candidate window: resolve_kcap) for the exact pipeline
     to stay byte-identical. Static: part of the jit cache key, resolved
     by callers OUTSIDE every jit (R2 discipline).
-    ``score`` ("l2" | "ip": SCORES) is what the lists are ordered by
+    ``score`` ("l2" | "ip": KERNEL_SCORES) is what the lists are ordered by
     (_score_block): the squared distance, or -q.d under "ip", where
     "dists" holds the NEGATED inner products, ascending like any other
     list (the norm planes are still read: the MXU gate's bound is made
@@ -1001,8 +1001,10 @@ def extract_topk(q_attrs: jax.Array, d_attrs: jax.Array,
     if precision not in PRECISIONS:
         raise ValueError(f"unsupported first-pass precision {precision!r} "
                          "(int8 is the gated follow-on — see ROADMAP)")
-    if score not in SCORES:
-        raise ValueError(f"unknown score {score!r} (one of {SCORES})")
+    if score not in KERNEL_SCORES:
+        raise ValueError(f"unknown kernel score {score!r} (one of "
+                         f"{KERNEL_SCORES}; config.kernel_score maps an "
+                         "engine's)")
     out = _extract_topk_jit(
         q_attrs, d_attrs, carry_d, carry_i, n_real=n_real,
         id_base=id_base, chunk=chunk, d_norms=d_norms, kc=kc,

@@ -44,7 +44,8 @@ crashing, and every rung preserves the contract checksums exactly:
                     (engine.single._solve_pipelined): no running-list
                     kernel state, the live tile shrinks to one
                     (query_block x chunk) slab. Squared L2 alone: an
-                    engine that ranks by inner product skips it.
+                    engine that ranks by inner product or by cosine
+                    skips it.
 6. ``host``       — the float64 golden solve on the host
                     (golden.fast.knn_golden_fast): zero device memory;
                     it IS the oracle the contract diffs against, so

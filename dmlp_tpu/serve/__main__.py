@@ -6,7 +6,7 @@ Usage::
         [--capacity ROWS] [--max-k K] [--max-batch-queries N]
         [--max-queue-queries N] [--tick-ms MS] [--gate-carry on|off]
         [--hbm-budget BYTES|auto] [--pallas] [--select auto|...]
-        [--dtype auto|float32|bfloat16] [--score l2|ip] [--data-block N]
+        [--dtype auto|float32|bfloat16] [--score l2|ip|cosine] [--data-block N]
         [--warm-buckets NQxK,NQxK,...] [--compile-cache DIR]
         [--telemetry FILE] [--telemetry-port PORT] [--record FILE]
         [--snapshot-every-s S] [--ready-file PATH] [--faults FILE]
@@ -43,6 +43,7 @@ def _parse_warm_buckets(spec: str) -> List[Tuple[int, int]]:
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
+    from dmlp_tpu.config import SCORES     # (imports no jax)
     p = argparse.ArgumentParser(prog="dmlp_tpu.serve",
                                 description=__doc__)
     p.add_argument("--corpus", required=True,
@@ -91,15 +92,17 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "the host only what that does not clear; off = "
                         "the host oracle alone; answers are identical "
                         "either way")
-    p.add_argument("--score", choices=["l2", "ip"], default="l2",
+    p.add_argument("--score", choices=list(SCORES), default="l2",
                    help="what the corpus is ranked by: l2 = smallest "
                         "squared Euclidean distance; ip = LARGEST inner "
                         "product (s descending, larger id first on "
-                        "ties; 'dists' then carries s itself). The "
-                        "one-chip extract path (--pallas, more than "
-                        "8192 rows or --select extract) has the ip "
-                        "form; --mesh and the streaming select refuse "
-                        "it by name")
+                        "ties; 'dists' then carries s itself); cosine = "
+                        "LARGEST q.x / (|q||x|), 0 against a zero vector "
+                        "(same order; 'dists' carries the angular "
+                        "distance 1 - s, ascending). The one-chip extract "
+                        "path (--pallas, more than 8192 rows or --select "
+                        "extract) has the ip and cosine forms; --mesh and "
+                        "the streaming select refuse them by name")
     p.add_argument("--data-block", type=int, default=None)
     p.add_argument("--warm-buckets", default=None, metavar="NQxK,...",
                    help="extra shape buckets to compile before ready")

@@ -86,7 +86,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.config import EngineConfig, kernel_score
 from dmlp_tpu.engine.finalize import boundary_hazard, finalize_host
 from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, PendingRun,
                                     SingleChipEngine, _boundary_cols,
@@ -95,6 +95,7 @@ from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, PendingRun,
                                     np_staging_dtype, plan_chunks,
                                     resilient_get, resolve_kcap, round_up,
                                     stage_put)
+from dmlp_tpu.golden.reference import row_norms as row_norms_f64
 from dmlp_tpu.io.grammar import KNNInput, Params, subset_queries
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import telemetry
@@ -174,14 +175,16 @@ def _kernel_statics(impl: str, kc: int, b: int, qb: int, a: int,
     (qb, b, a): exactly what ``fused_topk`` / ``extract_topk`` would
     resolve for themselves, made concrete here so that it keys the
     enclosing program's jit cache; ``score`` is the engine's (a corpus
-    has one)."""
+    has one), handed to the kernel as the form that orders it
+    (config.kernel_score: "cosine" folds normalised operands in the
+    "ip" form)."""
     from dmlp_tpu.ops.pallas_extract import _TN, resolve_variant
     v = resolve_variant(kc, b, qb, a)
     return dict(kc=kc, interpret=interpret, tile_q=v["tile_q"],
                 tile_n=v.get("tile_n", _TN), ne=v["ne"],
                 unroll=v["unroll"], fold=v.get("fold", 0),
                 mxu_gate=impl == "fused",
-                precision=precision, score=score)
+                precision=precision, score=kernel_score(score))
 
 
 def fold_chunks(q, stack, norms, order, nfold, span, **kern):
@@ -682,6 +685,11 @@ class ResidentServingCore:
     # -- corpus max squared norm (boundary-eps / multipass floors) ----------
 
     def _dn_max(self) -> float:
+        if self._dn_max_cache is None and self.config.score == "cosine":
+            # of the rows the DEVICE holds, x / |x|: 1, and 0 of a
+            # corpus of zero rows (finalize._product_scale); no pass
+            self._dn_max_cache = float(
+                self._host_norms[:self.n_real].any())
         if self._dn_max_cache is None:
             a = self._host_attrs[:self.n_real]
             # The one pass over the whole float64 host corpus: spanned
@@ -702,9 +710,12 @@ class ResidentServingCore:
 
     def _note_ingested_norms(self, attrs: np.ndarray) -> None:
         """Append-only ingest keeps the cache incremental: the max
-        squared norm only grows."""
+        squared norm only grows (under "cosine", of the normalised rows
+        the device holds: to 1 with the first row that is not zero)."""
         if self._dn_max_cache is not None and len(attrs):
             nn = np.einsum("ma,ma->m", attrs, attrs).max()
+            if self.config.score == "cosine":
+                nn = float(nn > 0)
             self._dn_max_cache = max(self._dn_max_cache, float(nn))
 
     # -- gate effectiveness (the fused kernel's gated-tile count) ------------
@@ -847,10 +858,25 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
     whose window passes the kernel's 512 slots at admission
     (:attr:`max_k`), the ladder's ``streaming`` rung is skipped
     (resilience.degrade) and the scorer does not run, so that no path
-    answers an inner-product corpus in L2.
+    answers an inner-product corpus in L2. "cosine" is the "ip" form
+    over unit vectors, and its work is at staging: every device copy
+    holds x / |x|, computed in float64 from the host's rows and then
+    cast (``_staged_rows``: first staging, a restaged chunk, an ingest),
+    a batch's queries are normalised the same way
+    (``_stage_batch_queries``), the kernel runs its "ip" form over them
+    (config.kernel_score), and the bounds are ip's at unit operands
+    (finalize.COS_NORM_COEF). The HOST keeps the rows as they were given
+    with their norms beside them (``_host_norms``): the float64 rescore
+    evaluates the contract on the originals, and ``corpus_slice``, the
+    corpus signature and a replica seeded from this one see the corpus
+    unchanged. Everything "ip" is refused, "cosine" is refused.
     """
 
-    _scores = ("l2", "ip")
+    _scores = ("l2", "ip", "cosine")
+
+    #: rows a block of ``_staged_rows``' float64 quotient (its one
+    #: temporary: 100 MB at 1536 attributes)
+    _NORMALIZE_ROWS = 8192
 
     def __init__(self, corpus: KNNInput, config: EngineConfig = None,
                  capacity: Optional[int] = None, gate_carry: bool = True):
@@ -924,6 +950,13 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             self._host_labels = np.full(host_rows, -1, np.int32)
             self._host_labels[:n] = corpus.labels
         self.n_real = n
+        # A cosine corpus' |x|, float64, beside the rows (0 past the
+        # last): what normalises a staged row and what the rescore and
+        # the host oracle divide by (KNNInput.data_norms).
+        self._host_norms: Optional[np.ndarray] = None
+        if cfg.score == "cosine":
+            self._host_norms = np.zeros(host_rows, np.float64)
+            self._note_norms(0, n)
         with obs_span("serve.init.row_hashes", rows=n):
             self._sig_init()
         # The corpus max-sq-norm the hazard test and the multipass floor
@@ -937,11 +970,21 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         with obs_span("serve.stage_resident", rows=self.capacity_rows,
                       na=na, a_pad=na, pad_bytes=0):
             sdt = np_staging_dtype(self._staging)
-            attrs = np.zeros((self.capacity_rows, na), sdt)
-            attrs[:n] = corpus.data_attrs
+            # Allocated on the device, then filled a block at a time by
+            # a donated update, as the extract stack is: the host never
+            # holds a staged copy of the corpus beside its float64 one
+            # (6.1 GB at 990 000 x 1536 float32, which with the harness's
+            # and the engine's float64 rows passed a 40 GiB machine).
+            step = self._data_block
+            self._d_attrs = jnp.zeros((self.capacity_rows, na), sdt)
+            for lo in range(0, n, step):
+                blk = np.zeros((step, na), sdt)
+                self._staged_rows(lo, min(lo + step, n), blk)
+                self._d_attrs = _update_rows_2d(
+                    self._d_attrs, stage_put(blk, self._staging),
+                    jax.device_put(np.int32(lo)))
             ids = np.full(self.capacity_rows, -1, np.int32)
             ids[:n] = np.arange(n, dtype=np.int32)
-            self._d_attrs = stage_put(attrs, self._staging)
             self._d_labels = jax.device_put(
                 self._host_labels[:self.capacity_rows])
             self._d_ids = jax.device_put(ids)
@@ -1174,6 +1217,40 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         telemetry.registry().counter("prune.summary_rebuilds").inc(
             len(blocks))
 
+    def _note_norms(self, lo: int, hi: int) -> None:
+        """``_host_norms`` of rows [lo, hi), from the host rows as
+        they now stand (a cosine engine's alone)."""
+        with obs_span("serve.normalize_rows", site="norms", rows=hi - lo,
+                      bytes=(hi - lo) * self.num_attrs * 8) as sp:
+            nrm = row_norms_f64(self._host_attrs[lo:hi])
+            self._host_norms[lo:hi] = nrm
+            zero = int(np.count_nonzero(nrm == 0))
+            sp.set(zero_rows=zero)
+        if zero:
+            telemetry.registry().counter("serve.zero_rows").inc(zero)
+
+    def _staged_rows(self, lo: int, hi: int, out: np.ndarray) -> None:
+        """Host rows [lo, hi) as the device holds them, cast into
+        ``out[:hi - lo, :num_attrs]`` (an array of the staging dtype):
+        the rows themselves, or under "cosine" x / |x|, the quotient
+        taken in float64 and then cast; a zero row stays zero."""
+        na = self.num_attrs
+        if self.config.score != "cosine":
+            out[:hi - lo, :na] = self._host_attrs[lo:hi]
+            return
+        step = self._NORMALIZE_ROWS
+        with obs_span("serve.normalize_rows", site="stage", rows=hi - lo,
+                      bytes=(hi - lo) * na * 8) as sp:
+            buf = np.empty((min(step, hi - lo), na), np.float64)
+            nrm = self._host_norms[lo:hi]
+            for a in range(0, hi - lo, step):
+                b = min(a + step, hi - lo)
+                out[a:b, :na] = np.divide(
+                    self._host_attrs[lo + a:lo + b],
+                    np.where(nrm[a:b] > 0, nrm[a:b], 1.0)[:, None],
+                    out=buf[:b - a])
+            sp.set(zero_rows=int(np.count_nonzero(nrm == 0)))
+
     def _restage_chunk(self, c: int) -> None:
         sdt = np_staging_dtype(self._staging)
         cr = self._ex_chunk_rows
@@ -1181,7 +1258,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         hi = min(lo + cr, self.n_real)
         a = np.zeros((cr, self._ex_attrs), sdt)
         if hi > lo:
-            a[:hi - lo, :self.num_attrs] = self._host_attrs[lo:hi]
+            self._staged_rows(lo, hi, a)
         self._chunks, self._norms = _update_chunk(
             self._chunks, self._norms, stage_put(a, self._staging),
             jax.device_put(np.int32(c)))
@@ -1226,14 +1303,16 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         with obs_span("serve.ingest", rows=m, corpus_rows=new_n):
             self._host_attrs[at:end] = attrs
             self._host_labels[at:end] = labels
+            if self._host_norms is not None:
+                self._note_norms(at, end)
             self.n_real = new_n
             # Bucketed fixed-shape device update, rebuilt from host
             # state so the pad region rewrites what is already there.
             mpad = min(shape_bucket(m), self.capacity_rows - at)
             mpad = max(mpad, m)
             sdt = np_staging_dtype(self._staging)
-            blk = np.ascontiguousarray(
-                self._host_attrs[at:at + mpad], sdt)
+            blk = np.empty((mpad, self.num_attrs), sdt)
+            self._staged_rows(at, at + mpad, blk)
             rng = np.arange(at, at + mpad, dtype=np.int32)
             blk_ids = np.where(rng < new_n, rng, -1).astype(np.int32)
             blk_labels = self._host_labels[at:at + mpad]
@@ -1277,7 +1356,9 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             self._host_labels[:self.n_real],
             self._host_attrs[:self.n_real],
             np.asarray(ks, np.int32),
-            np.asarray(query_attrs, np.float64))
+            np.asarray(query_attrs, np.float64),
+            data_norms=None if self._host_norms is None
+            else self._host_norms[:self.n_real])
 
     def _solve_resident_stream(self, pend: PendingBatch,
                                entry: _Bucket) -> Tuple[TopK, int]:
@@ -1341,9 +1422,20 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     def _stage_batch_queries(self, inp: KNNInput, qpad: int):
         """A micro-batch's query rows on the device, padded to the
-        bucket's rows and to the resident stack's width."""
+        bucket's rows and to the resident stack's width; under "cosine"
+        q / |q|, the quotient taken in float64 like a staged row's
+        (a zero query stays zero: it scores 0 against every row)."""
+        nq = inp.params.num_queries
         q = np.zeros((qpad, self._ex_attrs), np.float32)
-        q[:inp.params.num_queries, :self.num_attrs] = inp.query_attrs
+        if self.config.score != "cosine":
+            q[:nq, :self.num_attrs] = inp.query_attrs
+            return stage_put(q, self._staging)
+        with obs_span("serve.normalize_queries", queries=nq,
+                      **self._rid_args()) as sp:
+            nrm = row_norms_f64(inp.query_attrs)
+            q[:nq, :self.num_attrs] = inp.query_attrs / np.where(
+                nrm > 0, nrm, 1.0)[:, None]
+            sp.set(zero_queries=int(np.count_nonzero(nrm == 0)))
         return stage_put(q, self._staging)
 
     def _variant_stamp(self, kc: int, qpad: int,
@@ -1731,7 +1823,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
                     sub.data_attrs, exact=exact,
                     query_ids=np.asarray(
                         [results[int(qi)].query_id for qi in idx]),
-                    score=self.config.score)
+                    score=self.config.score, data_norms=sub.data_norms)
                 for j, qi in enumerate(idx):
                     if not still[j]:
                         results[int(qi)] = fixed[j]

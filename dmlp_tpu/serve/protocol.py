@@ -14,7 +14,9 @@ the micro-batcher coalesces across all of them). Shapes:
   in ``stats``): squared L2 distances, ascending, padded slots
   ``Infinity``; of a corpus ranked by inner product the products s
   themselves, DESCENDING (FAISS ``IndexFlatIP``'s convention), padded
-  slots ``-Infinity``. ``neighbors`` follows the same order, larger id
+  slots ``-Infinity``; of a corpus ranked by cosine the angular
+  distances ``1 - s``, ascending (ann-benchmarks' ``angular``), padded
+  slots ``Infinity``. ``neighbors`` follows the same order, larger id
   first on ties. A score is a property of the corpus, never of a
   request.
 - ``{"op": "ingest", "labels": [...], "rows": [[...]], "start"?: S}``

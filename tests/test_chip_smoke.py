@@ -51,10 +51,17 @@ def test_chip_smoke_dry_run_matches_oracle_and_refuses_cpu():
     # and the resident corpus ranked by inner product (PR 46): the golden
     # model's answers, the tied queries flagged and repaired
     assert "ip: wall" in out and "score ip" in out
-    assert "answers off the golden model's: 0" in out
     assert "FAIL config 1 ip: platform is cpu" in out
     assert "FAIL config 1 ip: pallas_interpret is True" in out
     assert out.count("FAIL config 1 ip") == 2
+    # and the same engine ranked by cosine (PR 49): unit rows in float32,
+    # the zero row, the zero query and the exact copies as the golden model
+    assert "cosine: wall" in out and "score cosine" in out
+    assert "first pass bf16x3" in out
+    assert out.count("answers off the golden model's: 0") == 2
+    assert "FAIL config 1 cosine: platform is cpu" in out
+    assert "FAIL config 1 cosine: pallas_interpret is True" in out
+    assert out.count("FAIL config 1 cosine") == 2
     # both children ran to the end and answered byte-identically
     assert "serve: 3 requests x 256 queries" in out
     assert "differ" not in out and "exited" not in out
@@ -265,6 +272,49 @@ def test_ip_phase_names_every_miss(change, named):
     answer the golden model's, the tied queries flagged."""
     cs = _load_chip_smoke()
     misses = cs.ip_misses(dict(_IP_OK, **change))
+    if named is None:
+        assert misses == []
+    else:
+        assert len(misses) == 1 and named in misses[0], misses
+
+
+_COSINE_OK = {
+    "device": {"platform": "tpu", "pallas_interpret": False,
+               "score": "cosine", "select": "extract"},
+    "staging": "float32", "staged_attrs": 1536, "first_pass": "bf16x3",
+    "paths": {"q128k16": "extract"}, "wrong": 0, "err": 0.0,
+    "tied_answers": 4, "staged_norm_err": 3e-9, "staged_zero_row": 0.0,
+    "zero_query_ids": list(range(11999, 11989, -1)),
+    "repairs": {"flagged_queries": 5, "device": 4, "host": 1}}
+
+
+@pytest.mark.parametrize("change, named", [
+    ({}, None),
+    ({"device": dict(_COSINE_OK["device"], score="ip")},
+     "not cosine on the"),
+    ({"device": dict(_COSINE_OK["device"], select="seg")},
+     "not cosine on the"),
+    ({"paths": {"q128k16": "stream"}}, "not extract"),
+    ({"staging": "bfloat16"}, "not float32 rows"),
+    ({"first_pass": "f32"}, "not float32 rows"),
+    ({"staged_attrs": 2048}, "not on their own twelve lane vectors"),
+    ({"staged_norm_err": 0.3}, "are not unit rows"),
+    ({"staged_zero_row": 1.0}, "are not unit rows"),
+    ({"wrong": 2}, "2 answers differ from the golden"),
+    ({"err": 1e-7}, "off float64's by"),
+    ({"tied_answers": 3}, "3 of 4 tied queries"),
+    ({"zero_query_ids": list(range(10))}, "not the largest ids"),
+    ({"repairs": {"flagged_queries": 0, "device": 0, "host": 0}},
+     "were not flagged"),
+    ({"device": dict(_COSINE_OK["device"], platform="cpu")},
+     "platform is cpu")])
+def test_cosine_phase_names_every_miss(change, named):
+    """The ``cosine`` phase's verdict on its child's record: score
+    cosine on the extract path, float32 unit rows on their own 1536
+    lanes under the three-pass split, every answer the golden model's,
+    the zero query's the largest ids, the tied queries flagged."""
+    cs = _load_chip_smoke()
+    misses = cs.cosine_misses(dict(_COSINE_OK, **change))
     if named is None:
         assert misses == []
     else:

@@ -757,3 +757,106 @@ def test_ip_kernel_never_loses_a_winner_unflagged(na, staging, precision,
     lost = np.array([not set(order[j]) <= set(oi[j]) for j in range(nq)])
     assert not np.any(lost & ~flagged), np.nonzero(lost & ~flagged)
     assert flagged.sum() >= nq // 2     # the corpus does what it is for
+
+
+# -- the cosine score's bounds (PR 49) ---------------------------------------
+
+def _unit_adversary(na: int, u: float) -> np.ndarray:
+    """A float64 vector of norm 1 (to an ulp) whose components, all but
+    the last, sit a hair under a rounding midpoint just above a power
+    of two of the dtype whose unit roundoff is ``u``, all of one sign:
+    staging rounds every one of them DOWN by u of its value, the worst a
+    cast can do, and a row and a query made of it are parallel, so that
+    sum |q^_a x^_a| = |q^||x^| = 1. Two binades are mixed so that the
+    squares add up to just under 1 at any width; the last component
+    takes what is left (under 6 hundredths of the mass, 2 thousandths
+    at the wide widths)."""
+    import math
+    e = math.floor(math.log2(na ** -0.5))        # 4^e <= 1/na < 4^(e+1)
+    c = 1.0 + u * (1.0 - 2.0 ** -20)
+    budget = 0.998 / (c * c) * 4.0 ** -e         # in units of 4^e
+    n_hi = max(0, min(na - 1, int((budget - (na - 1)) // 3)))
+    v = np.concatenate([np.full(n_hi, 2.0 ** (e + 1) * c),
+                        np.full(na - 1 - n_hi, 2.0 ** e * c)])
+    rest = 1.0 - float(np.sum(v * v))
+    assert 0.0 < rest < 0.06
+    return np.concatenate([v, [math.sqrt(rest)]])
+
+
+def test_cosine_coefficients_are_ips_at_unit_operands():
+    """Under "cosine" the scale is 1 whatever norms are handed in (0 for
+    a zero query, or a corpus of zero rows), the coefficient ip's, and
+    the normalisation's own rounding is added once."""
+    qn = np.array([4.0e6, 0.0, 1e-30])
+    for staging, na in (("bfloat16", 200), ("float32", 1536)):
+        want = finalize.ip_coef(staging, na) \
+            + finalize.COS_NORM_COEF * (na + 4)
+        assert finalize.staging_eps(None, qn, 1.0, staging, na,
+                                    "cosine").tolist() == [want, 0.0, want]
+        assert finalize.staging_eps(None, qn, 0.0, staging, na,
+                                    "cosine").tolist() == [0.0] * 3
+    assert finalize.lowp_eps("bf16x3", qn, 1.0, "cosine").tolist() == [
+        finalize.LOWP_COEF["bf16x3"], 0.0, finalize.LOWP_COEF["bf16x3"]]
+    assert finalize.lowp_eps("bf16", qn, 1.0, "cosine").tolist() == [
+        finalize.EPS_IP_REL["bfloat16"], 0.0,
+        finalize.EPS_IP_REL["bfloat16"]]
+    assert finalize.lowp_eps("f32", qn, 1.0, "cosine").tolist() == [0.0] * 3
+    # (3A + 8) 2^-52, the two-sided count of the derivation, fits
+    for na in (1, 8, 1536, 65536):
+        assert (3 * na + 8) * 2.0 ** -52 \
+            <= finalize.COS_NORM_COEF * (na + 4)
+    # the cell's arithmetic: 1.16e-3 under float32 staging with the
+    # split pass; under bfloat16 staging the engine runs the one-pass
+    # "f32" form over the bf16 rows, which casts nothing again: 0.0168
+    # (ISSUE 49 reckoned 0.033, the cast counted twice: the "bf16" form)
+    f32 = finalize.ip_coef("float32", 1536, "bf16x3")
+    b16 = finalize.ip_coef("bfloat16", 1536, "f32")
+    twice = finalize.ip_coef("bfloat16", 1536, "bf16")
+    assert 1.15e-3 < f32 < 1.17e-3 and 0.0167 < b16 < 0.0169
+    assert 0.032 < twice < 0.034
+
+
+@pytest.mark.parametrize("staging", ["bfloat16", "float32"])
+@pytest.mark.parametrize("na", [1536, 200, 1000, 17])
+def test_directed_unit_operands_reach_the_cosine_bound_and_never_pass_it(
+        na, staging):
+    """Rows and queries that normalise (in float64, as the engine
+    stages them) to ``_unit_adversary``: every component is cast down by
+    u, so the device's cosine of the staged operands is off the host's
+    float64 cosine of the ORIGINAL rows by 2u + u^2 to within a percent:
+    at least 0.9 of the cast term, and never more than half of
+    staging_eps' cosine form (two erring scores), at the cell's width
+    too."""
+    from dmlp_tpu.golden.reference import cosine_of, row_norms
+    u = 2.0 ** -8 if staging == "bfloat16" else 2.0 ** -24
+    v = _unit_adversary(na, u)
+    rng = np.random.default_rng(4900 + na)
+    rows = v[None, :] * 2.0 ** rng.integers(-20, 21, (64, 1))
+    queries = v[None, :] * 2.0 ** rng.integers(-20, 21, (8, 1))
+    rn, qn = row_norms(rows), row_norms(queries)
+    s = cosine_of(queries @ rows.T, qn[:, None], rn[None, :])
+    assert np.all(np.abs(s - 1.0) < 1e-12)
+    staged = _stage(queries / qn[:, None], staging) \
+        @ _stage(rows / rn[:, None], staging).T
+    err = np.abs(staged - s)
+    half = finalize.staging_eps(None, qn * qn, 1.0, staging, na,
+                                "cosine") / 2
+    assert np.all(err <= half[:, None]), (err / half[:, None]).max()
+    cast = finalize.EPS_IP_REL[staging] / 2
+    assert (err / cast).min() >= 0.9, (err / cast).min()
+
+
+@pytest.mark.parametrize("na", [3, 200, 1536])
+def test_the_normalisation_term_bounds_what_normalising_first_changes(na):
+    """q^ . x^ of operands normalised in float64 against the contract's
+    s on the originals, norms over twelve decades: within half of
+    COS_NORM_COEF * (A + 4) (the term covers two scores)."""
+    from dmlp_tpu.golden.reference import cosine_of, row_norms
+    rng = np.random.default_rng(4950 + na)
+    rows = rng.normal(0, 1, (256, na)) * 10.0 ** rng.uniform(-6, 6, (256, 1))
+    queries = rng.normal(0, 1, (32, na)) * 10.0 ** rng.uniform(-6, 6, (32, 1))
+    rn, qn = row_norms(rows), row_norms(queries)
+    s = cosine_of(np.einsum("qa,na->qn", queries, rows), qn[:, None],
+                  rn[None, :])
+    unit = np.einsum("qa,na->qn", queries / qn[:, None], rows / rn[:, None])
+    assert np.abs(unit - s).max() <= finalize.COS_NORM_COEF * (na + 4) / 2

@@ -1,10 +1,13 @@
 """Every engine without the inner-product form refuses ``score="ip"``
-by name (PR 46): at construction (the batch solve, the mesh engines,
+by name (PR 46), and ``score="cosine"`` (PR 49: the ip kernel form over
+operands normalised at staging, so whatever lacks the one lacks the
+other): at construction (the batch solve, the mesh engines,
 the mesh daemon, a resident engine whose corpus takes the streaming
 select), at admission (a k whose window passes the kernel's one pass:
 the multipass driver), at its entry (the multi-host feed). None answers
-an inner-product corpus in squared L2, the ladder's ``streaming`` rung
-included: it is skipped, and the host oracle answers under ip."""
+an inner-product or a cosine corpus in squared L2, the ladder's
+``streaming`` rung included: it is skipped, and the host oracle answers
+under the engine's score."""
 
 from __future__ import annotations
 
@@ -24,61 +27,61 @@ def corpus(n=300, na=8, seed=46) -> KNNInput:
                     np.zeros((0, na)))
 
 
-def _single():
+def _single(score):
     from dmlp_tpu.engine.single import SingleChipEngine
-    SingleChipEngine(EngineConfig(score="ip"))
+    SingleChipEngine(EngineConfig(score=score))
 
 
 def _cli(mode):
-    def build():
+    def build(score):
         from dmlp_tpu.cli import make_engine
-        make_engine(EngineConfig(mode=mode, score="ip", mesh_shape=(2, 1)
+        make_engine(EngineConfig(mode=mode, score=score, mesh_shape=(2, 1)
                                  if mode != "single" else None))
     return build
 
 
-def _mesh_daemon():
+def _mesh_daemon(score):
     from dmlp_tpu.serve.daemon import ServeDaemon
-    ServeDaemon(corpus(), EngineConfig(mode="sharded", score="ip"),
+    ServeDaemon(corpus(), EngineConfig(mode="sharded", score=score),
                 mesh_shape=(2, 1))
 
 
-def _mesh_engine():
+def _mesh_engine(score):
     from dmlp_tpu.fleet.mesh_engine import MeshResidentEngine
-    MeshResidentEngine(corpus(), EngineConfig(mode="sharded", score="ip"),
+    MeshResidentEngine(corpus(), EngineConfig(mode="sharded", score=score),
                        mesh_shape=(2, 1))
 
 
-def _streaming_select():
+def _streaming_select(score):
     from dmlp_tpu.serve.engine import ResidentEngine
-    ResidentEngine(corpus(), EngineConfig(score="ip"))     # no use_pallas
+    ResidentEngine(corpus(), EngineConfig(score=score))     # no use_pallas
 
 
-def _small_auto_corpus():
+def _small_auto_corpus(score):
     from dmlp_tpu.serve.engine import ResidentEngine
-    ResidentEngine(corpus(), EngineConfig(score="ip", use_pallas=True))
+    ResidentEngine(corpus(), EngineConfig(score=score, use_pallas=True))
 
 
-def _distributed():
+def _distributed(score):
     from dmlp_tpu.parallel.distributed import distributed_contract_run
     distributed_contract_run("/nonexistent", types.SimpleNamespace(
-        config=EngineConfig(mode="sharded", score="ip")))
+        config=EngineConfig(mode="sharded", score=score)))
 
 
-def _multipass():
+def _multipass(score):
     from dmlp_tpu.serve.engine import ResidentEngine
     eng = ResidentEngine(corpus(2000), EngineConfig(
-        score="ip", use_pallas=True, select="extract"))
+        score=score, use_pallas=True, select="extract"))
     assert eng.max_k == 256         # the last bucket of one kernel pass
-    eng.solve_batch(np.zeros((2, 8)), np.full(2, 300, np.int32))
+    eng.solve_batch(np.ones((2, 8)), np.full(2, 300, np.int32))
 
 
 REFUSALS = {
     "batch_solve": (_single, r"engine\.single\.SingleChipEngine \(the batch "
-                             r"solve\) has no score='ip' form"),
+                             r"solve\) has no score='SCORE' form"),
     "cli_single": (_cli("single"), r"engine\.single\.SingleChipEngine"),
     "sharded": (_cli("sharded"), r"engine\.sharded\.ShardedEngine has no "
-                                 r"score='ip' form"),
+                                 r"score='SCORE' form"),
     "ring": (_cli("ring"), r"engine\.sharded\.RingEngine has no"),
     "auto": (_cli("auto"), r"engine\.auto\.AutoShardedEngine has no"),
     "mesh_daemon": (_mesh_daemon,
@@ -87,27 +90,37 @@ REFUSALS = {
                     r"fleet\.mesh_engine\.MeshResidentEngine has no"),
     "streaming_select": (_streaming_select,
                          r"ResidentEngine's streaming select .* has no "
-                         r"score='ip' form"),
+                         r"score='SCORE' form"),
     "small_auto_corpus": (_small_auto_corpus,
                           r"ResidentEngine's streaming select .*8192 rows"),
     "multi_host_feed": (_distributed,
                         r"parallel\.distributed\.distributed_contract_run"),
     "multipass": (_multipass, r"k=300 beyond the serving cap 256 under "
-                              r"score='ip': serve\.engine\.ResidentEngine's "
-                              r"multipass driver"),
+                              r"score='SCORE': serve\.engine\.ResidentEngine"
+                              r"'s multipass driver"),
 }
 
 
 @pytest.mark.parametrize("case", REFUSALS)
 def test_an_engine_without_the_ip_form_refuses_it_by_name(case):
     build, message = REFUSALS[case]
-    with pytest.raises(ValueError, match=message):
-        build()
+    with pytest.raises(ValueError, match=message.replace("SCORE", "ip")):
+        build("ip")
+
+
+@pytest.mark.parametrize("case", REFUSALS)
+def test_an_engine_without_the_cosine_form_refuses_it_by_name(case):
+    build, message = REFUSALS[case]
+    with pytest.raises(ValueError,
+                       match=message.replace("SCORE", "cosine")) as e:
+        build("cosine")
+    # what it is told to do instead names its own score
+    assert "--score ip" not in str(e.value)
 
 
 def test_an_unknown_score_is_refused_and_l2_builds_everywhere():
-    with pytest.raises(ValueError, match="unknown score 'cosine'"):
-        EngineConfig(score="cosine")
+    with pytest.raises(ValueError, match="unknown score 'manhattan'"):
+        EngineConfig(score="manhattan")
     from dmlp_tpu.cli import make_engine
     for mode in ("single", "sharded", "ring", "auto"):
         make_engine(EngineConfig(mode=mode, mesh_shape=(2, 1)
@@ -120,17 +133,18 @@ def test_admission_refuses_a_k_past_one_pass_on_the_wire():
     from dmlp_tpu.serve.admission import AdmissionController
     from dmlp_tpu.serve.engine import ResidentEngine
     caps = {}
-    for score in ("l2", "ip"):
+    for score in ("l2", "ip", "cosine"):
         eng = ResidentEngine(corpus(2000), EngineConfig(
             score=score, use_pallas=True, select="extract"))
         caps[score] = AdmissionController(eng).max_k
-    assert caps["ip"] == 256 < caps["l2"]
+    assert caps["ip"] == caps["cosine"] == 256 < caps["l2"]
 
 
 def test_the_ladder_skips_the_streaming_rung_under_ip():
-    """An engine under ip that runs out of memory on every kernel rung
-    steps from ``heuristic`` to the host oracle, which answers under
-    ip; under squared L2 the ``streaming`` rung is still tried."""
+    """An engine under ip or cosine that runs out of memory on every
+    kernel rung steps from ``heuristic`` to the host oracle, which
+    answers under its score; under squared L2 the ``streaming`` rung is
+    still tried."""
     from dmlp_tpu.golden.reference import knn_golden
     from dmlp_tpu.resilience import degrade
     from dmlp_tpu.resilience.retry import SimulatedResourceExhausted
@@ -138,6 +152,7 @@ def test_the_ladder_skips_the_streaming_rung_under_ip():
     inp = KNNInput(Params(60, 3, 8), c.labels, c.data_attrs,
                    np.full(3, 4, np.int32), c.data_attrs[:3] * 2.0)
     for score, want in (("ip", list(degrade.RUNGS[:4])),
+                        ("cosine", list(degrade.RUNGS[:4])),
                         ("l2", list(degrade.RUNGS[:5]))):
         eng = types.SimpleNamespace(config=EngineConfig(score=score))
         tried = []

@@ -479,3 +479,99 @@ def test_distance_kernel_is_named():
         lambda q, d, i: fused_dist_segmin(q, d, i, interpret=True))(
             q, d, jnp.arange(1024, dtype=jnp.int32))
     assert _pallas_names(jaxpr) == ["dmlp_dist_segmin"]
+
+
+# -- a cosine daemon's own spans (PR 49) --------------------------------------
+
+NORMALIZE = ["serve.normalize_rows", "serve.normalize_queries"]
+
+
+def _scored_events(score: str):
+    """The events of one daemon under ``score``: set-up, one request of
+    three queries (the second a zero query), an ingest of four rows (the
+    last a zero row), one more request."""
+    corpus = tied_corpus()
+    tracer = obs_trace.install(obs_trace.Tracer())
+    daemon = None
+    try:
+        daemon = ServeDaemon(
+            corpus, EngineConfig(use_pallas=True, select="extract",
+                                 score=score), warm_buckets=[(3, 4)])
+        daemon.start()
+        mark = len(tracer.events())
+        req = query(corpus)
+        req["queries"][1] = [0.0] * NA
+        assert ask(daemon.port, req)["ok"]
+        rows = corpus.data_attrs[:4] * 3.0
+        rows[3] = 0.0
+        got = ask(daemon.port, {"op": "ingest", "labels": [0, 1, 2, 3],
+                                "rows": rows.tolist()})
+        assert got["ok"], got
+        assert ask(daemon.port, req)["ok"]
+        zero_rows = telemetry.registry().counter("serve.zero_rows").total()
+    finally:
+        if daemon is not None:
+            daemon.close()
+        obs_trace.uninstall()
+    events = [e for e in tracer.events() if e.get("ph") == "X"]
+    return events, [e for e in tracer.events()[mark:]
+                    if e.get("ph") == "X"], zero_rows
+
+
+@pytest.mark.parametrize("score", ["l2", "ip"])
+def test_a_daemon_under_another_score_emits_no_normalize_span(score):
+    events, _served, _zero = _scored_events(score)
+    for name in NORMALIZE:
+        assert named(events, name) == [], (score, name)
+    assert {e["args"]["score"] for e in named(events, "single.hazard")} \
+        == {score}
+
+
+def test_a_cosine_daemon_normalises_rows_at_set_up_and_at_ingest():
+    """``serve.normalize_rows`` (rows, zero_rows, bytes): the float64
+    norm pass over the corpus inside the construction, one span a staged
+    block inside ``serve.stage_resident`` and ``serve.stage_chunks``,
+    and again inside ``serve.ingest`` for the rows it brings (the zero
+    row among them counted, in the span and in the registry);
+    ``serve.normalize_queries`` (queries, zero_queries) once a
+    micro-batch, inside the cycle's ``serve.solve_stage``."""
+    zero0 = telemetry.registry().counter("serve.zero_rows").total()
+    events, served, zero1 = _scored_events("cosine")
+    rows = named(events, "serve.normalize_rows")
+    n = tied_corpus().params.num_data
+    for e in rows:
+        assert set(e["args"]) >= {"rows", "zero_rows", "bytes", "site"}
+        assert e["args"]["bytes"] == e["args"]["rows"] * NA * 8
+    setup = [e for e in rows if e not in served]
+    assert [e["args"]["site"] for e in setup[:2]] == ["norms", "stage"]
+    assert setup[0]["args"]["rows"] == setup[1]["args"]["rows"] == n
+    (resident,) = named(events, "serve.stage_resident")
+    assert inside(setup[1], resident)
+    (chunks,) = named(events, "serve.stage_chunks")
+    staged = [e for e in setup[2:] if inside(e, chunks)]
+    assert staged and sum(e["args"]["rows"] for e in staged) == n
+    assert chunks["args"]["score"] == "cosine"
+    (ingest,) = named(served, "serve.ingest")
+    during = [e for e in named(served, "serve.normalize_rows")
+              if inside(e, ingest)]
+    assert [e["args"]["site"] for e in during][:2] == ["norms", "stage"]
+    assert during[0]["args"]["rows"] == 4
+    assert during[0]["args"]["zero_rows"] == 1
+    assert zero1 - zero0 == 1
+    # (the corpus ties forty deep, so every query is flagged: the device
+    # retry stages its group's queries too, inside single.retry_begin)
+    queries = named(served, "serve.normalize_queries")
+    retries = named(served, "single.retry_begin")
+    own = [e for e in queries if not any(inside(e, r) for r in retries)]
+    assert len(queries) == 4 and len(own) == 2
+    assert [e["args"]["queries"] for e in own] == [3, 3]
+    assert [e["args"]["zero_queries"] for e in own] == [1, 1]
+    stages = named(served, "serve.solve_stage")
+    for e in own:
+        assert e["args"]["batch"] is not None
+        assert any(inside(e, s) and s["args"]["batch"] == e["args"]["batch"]
+                   for s in stages)
+    for name in ("serve.solve_extract", "single.hazard", "single.rescore",
+                 "single.finalize"):
+        assert {e["args"]["score"] for e in named(served, name)} \
+            == {"cosine"}, name

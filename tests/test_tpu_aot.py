@@ -492,6 +492,46 @@ def test_inner_product_fold_programs_compile_for_v5e(v5e, program):
         hlo, f"bf16[{chunks},51200,{a}]"))
 
 
+def test_cosine_fold_program_compiles_for_v5e(v5e):
+    """``dbpedia-openai-1m.bulk``'s fold (PR 49): q1024, the 21 resident
+    chunks of 51 200 rows the default 2^20 capacity stages, 1536
+    attributes (twelve whole lane vectors: no padding), float32, the
+    window its own plan gives at k = 10, under the engine's
+    ``score="cosine"``, which reaches the kernel as its "ip" form
+    (config.kernel_score: no kernel of its own and no further static)
+    in the three-pass split every exact float32 engine runs. The data
+    block follows the width, the kernel reads its blocks out of the
+    6.6 GB stack itself and the program allocates less than one chunk
+    (315 MB) beside it."""
+    from dmlp_tpu.engine.single import resolve_kcap
+    from dmlp_tpu.ops.pallas_extract import lane_padded
+    from dmlp_tpu.serve.engine import _fold_stack, _kernel_statics
+    sh = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    a = lane_padded(1536)
+    assert a == 1536
+    cfg = EngineConfig(dtype="float32", use_pallas=True, score="cosine")
+    kc = resolve_kcap(cfg, 16, "extract", 1 << 20, staging="float32",
+                      precision="bf16x3", na=1536)
+    kern = _kernel_statics("fused", kc, 51200, 1024, a, "bf16x3", False,
+                           cfg.score)
+    assert kern["score"] == "ip" and kern["precision"] == "bf16x3"
+    chunks = 21
+    compiled = _fold_stack.lower(
+        spec((1024, a), jnp.float32), spec((chunks, 51200, a), jnp.float32),
+        spec((chunks, 1, 51200), jnp.float32), spec((chunks,), jnp.int32),
+        spec((), jnp.int32), spec((), jnp.int32), **kern).compile()
+    hlo = compiled.as_text()
+    assert len(_kernel_calls(hlo)) == 2 and " while(" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= chunks * 51200 * a * 4
+    assert mem.temp_size_in_bytes < 51200 * a * 4
+    _assert_fold_reads_the_stack(compiled, "f32", chunks, 51200, a)
+
+
 @pytest.mark.parametrize("na", [100, 96])
 @pytest.mark.parametrize("staged,precision", [
     (jnp.bfloat16, "f32"), (jnp.float32, "bf16x3")],

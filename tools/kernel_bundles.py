@@ -43,6 +43,8 @@ SHAPES = {
     "bigann-gt1000.bulk": (1024, 128, "float32", "bf16x3", 512, "l2"),
     "bigann-10m.bulk": (1024, 128, "bfloat16", "f32", 120, "l2"),
     "text2image-10m.bulk": (1024, 256, "bfloat16", "f32", 120, "ip"),
+    # (cosine reaches the kernel as "ip": config.kernel_score)
+    "dbpedia-openai-1m.bulk": (1024, 1536, "float32", "bf16x3", 56, "ip"),
 }
 UNITS = ["MXU", "XLU", "VALU", "EUP", "VLOAD", "VLOAD:FILL", "VSTORE",
          "VSTORE:SPILL", "SALU"]
