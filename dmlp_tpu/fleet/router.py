@@ -64,7 +64,7 @@ from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.resilience.retry import classify
 
 #: request-line cap mirrored from the daemon protocol
-from dmlp_tpu.serve.protocol import MAX_LINE_BYTES, encode
+from dmlp_tpu.serve.protocol import MAX_LINE_BYTES, LineReader, encode
 
 
 class Replica:
@@ -229,8 +229,7 @@ class Replica:
             with socket.create_connection((self.host, self.port),
                                           timeout=timeout_s) as sock:
                 sock.sendall(line)
-                with sock.makefile("rb") as rf:
-                    resp = rf.readline(MAX_LINE_BYTES + 1)
+                resp = LineReader(sock).readline()
             if not resp:
                 raise ConnectionError(
                     f"replica {self.name} closed the connection "
@@ -246,8 +245,9 @@ class _RouterHandler(socketserver.StreamRequestHandler):
 
     def handle(self):  # noqa: D102 (socketserver API)
         router: FleetRouter = self.server.router
+        reader = LineReader(self.connection)    # not rfile: one owner
         while True:
-            raw = self.rfile.readline(MAX_LINE_BYTES + 1)
+            raw = reader.readline()
             if not raw:
                 break
             if len(raw) > MAX_LINE_BYTES:

@@ -86,21 +86,20 @@ class _Handler(socketserver.StreamRequestHandler):
 
     def handle(self):  # noqa: D102 (socketserver API)
         daemon: ServeDaemon = self.server.daemon
+        # The handler reads the socket itself, never through rfile
+        # (whose buffer would keep bytes from the reader). Bounded
+        # read: the reader never holds more than the cap + 1, so an
+        # oversized request line cannot balloon the daemon's memory
+        # before rejection. A cap-exceeding read has lost line framing
+        # — reject and drop the connection. The read is timed from the
+        # request's first byte: an idle connection's wait is not in it.
+        reader = protocol.LineReader(self.connection)
         while True:
-            # Bounded read: readline(cap + 1) never buffers more than
-            # the cap, so an oversized request line cannot balloon the
-            # daemon's memory before rejection. A cap-exceeding read
-            # has lost line framing — reject and drop the connection.
-            # The read is timed from the request's first byte (peek
-            # consumes nothing, and returns empty at the end of the
-            # stream, where readline still decides): an idle
-            # connection's wait is not in it.
-            self.rfile.peek(1)
-            t_first = time.perf_counter()
-            raw = self.rfile.readline(protocol.MAX_LINE_BYTES + 1)
+            raw = reader.readline()
             if not raw:
                 break
             t_read = time.perf_counter()
+            t_first, pieces = reader.t_first, reader.pieces
             if len(raw) > protocol.MAX_LINE_BYTES:
                 self.wfile.write(protocol.encode(
                     {"ok": False,
@@ -132,8 +131,11 @@ class _Handler(socketserver.StreamRequestHandler):
                     telemetry.registry().histogram(
                         "serve.phase_ms.read", unit="ms").observe(
                             (t_read - t_first) * 1e3)
+                    telemetry.registry().histogram(
+                        "serve.read_pieces", unit="calls").observe(pieces)
                 obs_trace.complete_at(
                     "serve.phase.read", t_first, t_read, bytes=len(raw),
+                    pieces=pieces,
                     **({"rid": rid} if rid else {}), **_batch_arg(req))
                 w0 = time.perf_counter()
                 data = protocol.encode(resp)

@@ -58,6 +58,8 @@ from __future__ import annotations
 
 import json
 import re
+import socket
+import time
 from typing import Any, Dict, Optional, Tuple, Union
 
 import numpy as np
@@ -69,9 +71,9 @@ from dmlp_tpu.serve.batching import Request
 PROTOCOL_VERSION = 1
 
 #: request-line size cap. The daemon's connection handler enforces it
-#: AT THE READ (``readline(cap + 1)``) so an oversized line never
-#: buffers past the cap; the re-check in parse_request covers
-#: non-socket callers.
+#: AT THE READ (:class:`LineReader` never holds more than cap + 1
+#: bytes) so an oversized line never buffers past the cap; the
+#: re-check in parse_request covers non-socket callers.
 MAX_LINE_BYTES = 64 << 20
 
 #: per-request row cap of the ``corpus`` read op (bounds one response
@@ -81,6 +83,70 @@ CORPUS_FETCH_MAX = 65536
 
 class ProtocolError(ValueError):
     """A malformed request line (the response names the defect)."""
+
+
+class LineReader:
+    """One connection's lines, received in pieces as large as the
+    kernel hands over.
+
+    A 31 MB query line read through ``socketserver``'s 8 KB
+    ``BufferedReader`` is ~3 800 raw reads, each of which drops the
+    interpreter lock and has to take it back from the batcher thread
+    and the other handlers; here it is tens of ``recv_into`` calls
+    into ONE buffer the connection keeps between lines. The buffer
+    starts at :attr:`FIRST_BYTES`, doubles when a line fills it, and is
+    never shrunk or reallocated: a connection that sent one 31 MB line
+    has room for the next, one that sends KB lines never grows. The
+    reader must own the socket alone (a ``makefile`` reader beside it
+    would keep bytes in a buffer of its own).
+
+    ``readline`` mirrors ``BufferedReader.readline(MAX_LINE_BYTES +
+    1)``: the line with its newline; at the end of the stream what
+    arrived without one, ``b""`` when that is nothing; and never more
+    than ``MAX_LINE_BYTES + 1`` bytes held, a line that long handed
+    on unterminated for the caller to refuse. Bytes that followed a
+    newline (a client may pipeline) start the next line."""
+
+    FIRST_BYTES = 64 << 10
+
+    def __init__(self, sock: socket.socket):
+        self._sock = sock
+        self._buf = bytearray(self.FIRST_BYTES)
+        self._have = 0          # bytes of the next line already here
+        #: receive calls the last line took (0: whole in the carry-over)
+        self.pieces = 0
+        #: perf_counter when the last line's first bytes were in hand
+        self.t_first = 0.0
+
+    def readline(self) -> bytes:
+        buf, have = self._buf, self._have
+        cap = MAX_LINE_BYTES + 1
+        self.pieces = 0
+        if have:
+            self.t_first = time.perf_counter()
+        seen = 0                # a newline is sought in new bytes only
+        while True:
+            nl = buf.find(b"\n", seen, have)
+            if nl >= 0 or have >= cap:
+                break
+            seen = have
+            if have == len(buf):
+                buf.extend(bytes(min(len(buf), cap - len(buf))))
+            with memoryview(buf) as whole, \
+                    whole[have:min(len(buf), cap)] as free:
+                got = self._sock.recv_into(free)
+            self.pieces += 1
+            if not got:         # end of stream: what came is the line
+                break
+            if not have:
+                self.t_first = time.perf_counter()
+            have += got
+        end = nl + 1 if nl >= 0 else have
+        with memoryview(buf) as whole:
+            line = bytes(whole[:end])       # the one copy of the line
+        buf[:have - end] = buf[end:have]
+        self._have = have - end
+        return line
 
 
 def _is_int(v) -> bool:
