@@ -1490,9 +1490,8 @@ class SingleChipEngine:
                 + ([cols_dev] if cols_dev is not None else [])
             # The whole call, retries and injected faults included, is
             # one device wait of this thread (site: the span's own).
-            with obs_span("single.fetch", select=select, kcap=kcap,
-                          site="fetch", **targs), \
-                    obs_trace.device_wait("fetch", span=False):
+            with obs_trace.device_wait("fetch", name="single.fetch",
+                                       select=select, kcap=kcap, **targs):
                 fetched = list(resilient_get(fetch))
             t1 = _time.perf_counter()
             fetch_ms += (t1 - t0) * 1e3

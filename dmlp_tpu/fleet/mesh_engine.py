@@ -786,9 +786,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             # phase timings behind `stats` are the same numbers the
             # spans carry.)
             t_drain = clock()
-            with obs_span("fleet.merge_drain", dispatches=1,
-                          site="merge_drain", **self._rid_args()), \
-                    obs_trace.device_wait("merge_drain", span=False):
+            with obs_trace.device_wait("merge_drain",
+                                       name="fleet.merge_drain",
+                                       dispatches=1, **self._rid_args()):
                 jax.block_until_ready(pend.lists)  # check: allow-host-sync
             merge_bytes = sum(t.bytes_total for t in pend.comms)
             telemetry.registry().counter("fleet.merge_bytes").inc(
@@ -804,14 +804,14 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             pend.phase_ms["dispatch"] += (t_merge - t_drain) * 1e3
             pend.phase_ms["merge"] = (clock() - t_merge) * 1e3
         t0 = clock()
-        with obs_span("fleet.fetch", site="fetch", **self._rid_args()):
-            telemetry.sample_memory_now()
-            with obs_trace.device_wait("fetch", span=False):
-                od, ol, oi = resilient_get(
-                    (top.dists, top.labels, top.ids), site="sharded.fetch")
-            dists = np.asarray(od, np.float64)[:nq]
-            labels = ol[:nq]
-            ids = oi[:nq]
+        telemetry.sample_memory_now()
+        with obs_trace.device_wait("fetch", name="fleet.fetch",
+                                   **self._rid_args()):
+            od, ol, oi = resilient_get(
+                (top.dists, top.labels, top.ids), site="sharded.fetch")
+        dists = np.asarray(od, np.float64)[:nq]
+        labels = ol[:nq]
+        ids = oi[:nq]
         t1 = clock()
         # What the host does between the readback and the finalize: the
         # eps-widened boundary test. dn_max_cached says whether the

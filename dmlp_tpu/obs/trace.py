@@ -4,8 +4,22 @@ One process-wide :class:`Tracer` (installed with :func:`install`) collects
 complete-duration events (``ph: "X"``) from ``with span("name"):`` blocks
 scattered through the engines, the CLI, and the train loop. When no tracer
 is installed every hook degenerates to a module-global read returning a
-shared no-op span — the hot paths pay nothing measurable (the <2%
-instrumentation-overhead budget is enforced by the obs-smoke bench).
+shared no-op span (``NULL_SPAN``): no object is built and no clock is
+read. What stays on without a sink is the host-sync bracket at the end of
+this module (``device_wait``: two ``perf_counter`` and two
+``thread_time`` reads a wait) and the micro-batcher's account of its
+cycle (serve/batching.py); the calls they make a cycle are counted by
+tests/test_batcher_cycle.py.
+
+While a sink is installed every span also says how long its thread was
+on a core: ``cpu_ms`` (``time.thread_time`` across the block) and
+``offcpu_ms`` (the duration less that: the thread wanted to run and did
+not — the interpreter lock, the scheduler, a blocking fault, a sleep).
+The thread's CPU clock is the host kernel's: where it advances in ticks
+(10 ms on the chip's sealed host) one span's ``cpu_ms`` is a multiple of
+the tick and only the mean over many spans of a name means anything.
+A span stitched from clock reads taken on TWO threads
+(:func:`complete_at` without ``cpu_s``) carries neither.
 
 Device work is asynchronous under JAX, so a span that brackets only the
 *enqueue* of a dispatch would lie about where time goes. Spans therefore
@@ -35,6 +49,8 @@ import time
 from typing import Any, Dict, List, Optional
 
 _clock = time.perf_counter
+#: the calling thread's CPU time, user and system (CLOCK_THREAD_CPUTIME_ID)
+_cpu = time.thread_time
 
 # -- telemetry bridge ---------------------------------------------------------
 # When a telemetry session (obs.telemetry) is active it registers
@@ -60,12 +76,12 @@ class _TelemetrySpan:
     is installed: measures wall duration (honoring device fences, like
     the real Span) and forwards one observation — no event storage."""
 
-    __slots__ = ("name", "args", "_t0", "_fences")
+    __slots__ = ("name", "args", "_t0", "_c0", "_fences")
 
     def __init__(self, name: str, args: Dict[str, Any]):
         self.name = name
         self.args = dict(args) if args else {}
-        self._t0 = 0.0
+        self._t0 = self._c0 = 0.0
         self._fences: list = []
 
     def set(self, **kwargs) -> None:
@@ -76,6 +92,7 @@ class _TelemetrySpan:
 
     def __enter__(self) -> "_TelemetrySpan":
         self._t0 = _clock()
+        self._c0 = _cpu()
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -88,7 +105,9 @@ class _TelemetrySpan:
             self._fences = []
         cb = _span_observer
         if cb is not None:
-            cb(self.name, (_clock() - self._t0) * 1e3, self.args)
+            cpu_s = _cpu() - self._c0
+            dur_ms = (_clock() - self._t0) * 1e3
+            cb(self.name, dur_ms, _with_cpu(self.args, dur_ms, cpu_s))
         return False
 
 
@@ -114,19 +133,52 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str):
+    """``name`` entered as a ``jax.profiler.TraceAnnotation`` (None
+    where the profiler cannot be had): how a span shows inside an XLA
+    profiler capture."""
+    try:
+        from jax.profiler import TraceAnnotation
+        annot = TraceAnnotation(name)
+        annot.__enter__()
+        return annot
+    except Exception:
+        return None
+
+
+def _close_annotation(annot, exc) -> None:
+    if annot is not None:
+        try:
+            annot.__exit__(*exc)
+        except Exception:
+            pass
+
+
+def _with_cpu(args: Dict[str, Any], dur_ms: float,
+              cpu_s: Optional[float]) -> Dict[str, Any]:
+    """``args`` with the span's CPU account, where its two ends were
+    read on one thread (``cpu_s``: that thread's CPU seconds between
+    them)."""
+    if cpu_s is not None:
+        args["cpu_ms"] = cpu_s * 1e3
+        args["offcpu_ms"] = dur_ms - cpu_s * 1e3
+    return args
+
+
 class Span:
     """One traced region. Use as a context manager; ``set()`` attaches
     args (rendered in the Perfetto detail pane), ``fence()`` registers
     device values to ``block_until_ready`` before the closing timestamp."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_fences", "_annot")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_c0", "_fences",
+                 "_annot")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]):
         self._tracer = tracer
         self.name = name
         self.args = dict(args) if args else {}
-        self._t0 = 0.0
+        self._t0 = self._c0 = 0.0
         self._fences: list = []
         self._annot = None
 
@@ -138,13 +190,9 @@ class Span:
 
     def __enter__(self) -> "Span":
         if self._tracer._annotate:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annot = TraceAnnotation(self.name)
-                self._annot.__enter__()
-            except Exception:
-                self._annot = None
+            self._annot = _annotation(self.name)
         self._t0 = _clock()
+        self._c0 = _cpu()
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -155,13 +203,10 @@ class Span:
             except Exception:
                 pass  # fencing is best-effort; the span still records
             self._fences = []
+        cpu_s = _cpu() - self._c0
         t1 = _clock()
-        if self._annot is not None:
-            try:
-                self._annot.__exit__(*exc)
-            except Exception:
-                pass
-        self._tracer._complete(self.name, self._t0, t1, self.args)
+        _close_annotation(self._annot, exc)
+        self._tracer._complete(self.name, self._t0, t1, self.args, cpu_s)
         return False
 
 
@@ -230,7 +275,10 @@ class Tracer:
                      unix_ms=unix_ms, **args)
 
     def _complete(self, name: str, t0: float, t1: float,
-                  args: Dict[str, Any]) -> None:
+                  args: Dict[str, Any],
+                  cpu_s: Optional[float] = None) -> None:
+        dur_ms = max((t1 - t0) * 1e3, 0.0)
+        _with_cpu(args, dur_ms, cpu_s)
         ev = {"name": name, "ph": "X",
               "ts": (t0 - self._epoch) * 1e6,
               "dur": max((t1 - t0) * 1e6, 0.0),
@@ -240,7 +288,7 @@ class Tracer:
         self._append(ev)
         cb = _span_observer
         if cb is not None:
-            cb(name, max((t1 - t0) * 1e3, 0.0), args)
+            cb(name, dur_ms, args)
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -328,7 +376,17 @@ def sinks_active() -> bool:
     return _active is not None or _span_observer is not None
 
 
-def complete_at(name: str, t0: float, t1: float, **args) -> None:
+def thread_cpu() -> Optional[float]:
+    """The calling thread's CPU time while a sink is installed, None
+    otherwise (no system call on the untraced path): stamped beside a
+    ``perf_counter`` read that is taken anyway, at each end of a clock
+    pair whose two ends lie on one thread (``complete_at``'s
+    ``cpu_s``)."""
+    return _cpu() if sinks_active() else None
+
+
+def complete_at(name: str, t0: float, t1: float,
+                cpu_s: Optional[float] = None, **args) -> None:
     """Record a span from caller-measured ``perf_counter`` endpoints.
 
     The ``with span():`` form can only bracket one thread's stack
@@ -336,41 +394,50 @@ def complete_at(name: str, t0: float, t1: float, **args) -> None:
     on one thread and end on another, so the producer stamps ``t0``,
     the consumer stamps ``t1``, and this records the interval as a
     regular complete event — same tracer + observer fan-out as Span
-    exit, no-op when no sink is installed."""
+    exit, no-op when no sink is installed. A caller whose two ends lie
+    on ONE thread stamps ``time.thread_time`` beside each and passes
+    the difference as ``cpu_s``: the span then carries ``cpu_ms`` and
+    ``offcpu_ms`` as a ``with`` span does; a pair that crosses threads
+    has no thread's CPU time to give and carries neither."""
     t = _active
     if t is not None:
-        t._complete(name, t0, t1, args)
+        t._complete(name, t0, t1, args, cpu_s)
         return
     cb = _span_observer
     if cb is not None:
-        cb(name, max((t1 - t0) * 1e3, 0.0), args)
+        dur_ms = max((t1 - t0) * 1e3, 0.0)
+        cb(name, dur_ms, _with_cpu(args, dur_ms, cpu_s))
 
 
 # -- host-sync bracket ---------------------------------------------------------
 # Where a thread blocks on the device (the ``# check: allow-host-sync``
 # seams of the served path) it says so through ``device_wait``: two clock
-# reads and a per-thread tally, always on. A seam that has a span of its
-# own around exactly the wait (``single.fetch``, ``serve.mp_fetch``,
-# ``fleet.fetch``, ``fleet.merge_drain``) names its ``site`` on that
-# span; the others (``prune_score``, ``gate``, ``merge``, ``retry``)
-# get a ``serve.wait.device`` span while a sink is installed. The
-# micro-batcher reads its own thread's tally once a cycle
-# (serve/batching.py).
+# reads, two reads of the thread's CPU time and a per-thread tally, always
+# on. While a sink is installed the bracket's own reads are also a span:
+# the seam's own where it names one (``single.fetch``, ``serve.mp_fetch``,
+# ``fleet.fetch``, ``fleet.merge_drain``), ``serve.wait.device``
+# elsewhere (``prune_score``, ``gate``, ``merge``, ``retry``); either
+# carries ``site``, and a cycle's wait spans sum to its ``device_wait_ms``
+# by construction. The micro-batcher reads its own thread's tally once a
+# cycle (serve/batching.py).
 
 class WaitTally:
     """One thread's device waits since its last :meth:`take`: seconds
-    in all, and by ``site``. Only its own thread touches it."""
+    in all and by ``site``, and the CPU seconds the thread spent inside
+    them (a host sync that spins shows there). Only its own thread
+    touches it."""
 
-    __slots__ = ("seconds", "by_site")
+    __slots__ = ("seconds", "cpu_seconds", "by_site")
 
     def __init__(self):
-        self.seconds = 0.0
+        self.seconds = self.cpu_seconds = 0.0
         self.by_site: Dict[str, float] = {}
 
     def take(self):
-        """(seconds, {site: seconds}) waited since the last take."""
-        out = self.seconds, self.by_site
-        self.seconds, self.by_site = 0.0, {}
+        """(seconds, CPU seconds, {site: seconds}) waited since the
+        last take."""
+        out = self.seconds, self.cpu_seconds, self.by_site
+        self.seconds, self.cpu_seconds, self.by_site = 0.0, 0.0, {}
         return out
 
 
@@ -391,33 +458,44 @@ class device_wait:  # noqa: N801 (used as ``with device_wait(site):``)
     ``fetch``, ``mp_fetch``, ``gate``, ``merge_drain``, ``merge``,
     ``retry``).
     Retries and injected faults of the call inside stay inside; an
-    exception still counts the wait. ``span=False`` where the caller's
-    own span already brackets the wait (and carries ``site``): the
-    tally alone; otherwise a ``serve.wait.device`` span with ``site``
-    and the micro-batch (``batch``) it belongs to."""
+    exception still counts the wait. With a sink the bracket is a span
+    from its own two clock reads: ``name`` (the seam's own span, with
+    its ``args``) or ``serve.wait.device``, with ``site``, the
+    micro-batch (``batch``) it belongs to, and the CPU account every
+    one-thread span carries."""
 
-    __slots__ = ("site", "batch", "span", "_t0")
+    __slots__ = ("site", "name", "args", "_t0", "_c0", "_annot")
 
     def __init__(self, site: str, batch: Optional[int] = None,
-                 span: bool = True):
+                 name: str = "serve.wait.device", **args):
         self.site = site
-        self.batch = batch
-        self.span = span
-        self._t0 = 0.0
+        self.name = name
+        self.args = args
+        if batch is not None:
+            args["batch"] = batch
+        self._t0 = self._c0 = 0.0
+        self._annot = None
 
     def __enter__(self) -> "device_wait":
+        t = _active
+        if t is not None and t._annotate:
+            self._annot = _annotation(self.name)
+        # the CPU reads lie OUTSIDE the clock reads: what the cycle takes
+        # off its own CPU time covers all of what it takes off its wall
+        self._c0 = _cpu()
         self._t0 = _clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = _clock()
+        cpu_s = _cpu() - self._c0
         tally = wait_tally()
         tally.seconds += t1 - self._t0
+        tally.cpu_seconds += cpu_s
         tally.by_site[self.site] = \
             tally.by_site.get(self.site, 0.0) + (t1 - self._t0)
-        if self.span and sinks_active():
-            args = {"site": self.site}
-            if self.batch is not None:
-                args["batch"] = self.batch
-            complete_at("serve.wait.device", self._t0, t1, **args)
+        _close_annotation(self._annot, exc)
+        if sinks_active():
+            complete_at(self.name, self._t0, t1, cpu_s, site=self.site,
+                        **self.args)
         return False

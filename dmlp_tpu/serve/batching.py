@@ -25,10 +25,13 @@ the end of every delivery into **cycles**, one a micro-batch, and each
 cycle is split exactly three ways: the thread's waits for requests
 (``queue_wait``), its waits on the device (``device_wait``: the
 engines' host syncs, through ``obs.trace.device_wait``) and the rest
-(``own``). Always on: histograms ``serve.cycle_ms{,.own,.device_wait,
-.queue_wait}`` and a ring of slow cycles (``stats.batcher``); with a
-sink, span ``serve.cycle`` and, around each wait, ``serve.wait.queue``
-/ ``serve.wait.device`` (or the seam's own span, with its ``site``).
+(``own``). ``own`` is split again by the kernel's account of the thread
+(:func:`_cpu_now`, one reading a cycle): on a core (``own_cpu``) and
+off it (``own_offcpu``). Always on: histograms ``serve.cycle_ms{,.own,
+.device_wait,.queue_wait,.own_cpu,.own_offcpu,.wait_cpu}``, the thread's
+CPU totals and a ring of slow cycles (``stats.batcher``); with a sink,
+span ``serve.cycle`` and, around each wait, ``serve.wait.queue`` /
+``serve.wait.device`` (or the seam's own span, with its ``site``).
 
 Single consumer thread: the engine (and its ingest path) is driven by
 exactly one thread, so resident-buffer updates never race a solve.
@@ -39,6 +42,7 @@ block on it and write the response.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 import time
 from collections import deque
@@ -53,6 +57,45 @@ from dmlp_tpu.obs.trace import span as obs_span
 from dmlp_tpu.resilience import inject as rs_inject
 from dmlp_tpu.serve.admission import ACCEPT, AdmissionController
 from dmlp_tpu.serve.engine import ResidentServingCore
+
+try:
+    import resource
+    _RUSAGE_THREAD = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):    # not Linux: thread_time alone
+    _RUSAGE_THREAD = None
+
+#: what the cycle account reads of ``getrusage(RUSAGE_THREAD)``. The
+#: user / system split is tick-grained everywhere: exact over a window,
+#: a MEAN's worth over cycles, never a median's.
+_RUSAGE_FIELDS = (("user_s", "ru_utime"), ("sys_s", "ru_stime"),
+                  ("minflt", "ru_minflt"), ("majflt", "ru_majflt"),
+                  ("nvcsw", "ru_nvcsw"), ("nivcsw", "ru_nivcsw"))
+
+
+def _usable_cores() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:               # not Linux
+        return os.cpu_count() or 1
+
+
+def _cpu_now() -> Dict[str, float]:
+    """The kernel's account of the calling thread and of the process,
+    running totals: ``thread_s`` (``time.thread_time``: CPU seconds,
+    user and system), ``process_s`` (every thread's) and, on Linux, the
+    thread's rusage: the user / system split, minor and major page
+    faults, voluntary and involuntary context switches. Three system
+    calls. All of it is as fine as the host's kernel keeps it: a
+    sandboxed kernel may advance the CPU clocks in 10 ms ticks and
+    count no fault or switch at all (the chip's host does both), so
+    read a cycle's CPU parts as MEANS over a window."""
+    out = {"thread_s": time.thread_time(),
+           "process_s": time.process_time()}
+    if _RUSAGE_THREAD is not None:
+        ru = resource.getrusage(_RUSAGE_THREAD)
+        for key, field in _RUSAGE_FIELDS:
+            out[key] = getattr(ru, field)
+    return out
 
 #: default batcher tick: how long a lone request waits for company
 TICK_S = 0.002
@@ -105,8 +148,10 @@ class Request:
     latency_ms: Optional[float] = None
     batch: Optional[int] = None                   # serial of the micro-
     #                                               batch it rode
-    respond_pc: Optional[Tuple[float, float]] = None  # perf_counter pair
-    #                                               around query_response
+    respond_pc: Optional[Tuple[float, float, Optional[float]]] = None
+    #                                               perf_counter pair around
+    #                                               query_response, and the
+    #                                               handler's CPU seconds in it
     corpus_rows: Optional[int] = None             # ingest outcome
     payload: Optional[Dict[str, Any]] = None      # corpus outcome
 
@@ -129,7 +174,9 @@ class _Flight:
     one cycle of the batcher and finished in the next, so the spans
     that run from its begin to its finish (``serve.micro_batch``,
     ``serve.solve_multipass``) cross the other batch's second half;
-    every other span of it lies inside one cycle."""
+    every other span of it lies inside one cycle. Those two are clock
+    pairs with another batch's work between their ends, and carry no
+    ``cpu_ms`` / ``offcpu_ms``: the spans inside them do."""
 
     requests: List[Request]
     total: int           # queries
@@ -161,7 +208,17 @@ class MicroBatcher:
     ``device_wait`` its host syncs (the thread's ``WaitTally``), ``own``
     the rest (Python and NumPy work, waits for the interpreter lock,
     collector pauses). ``gc_ms`` beside them is the collector's pause
-    time, any thread's, that ended inside the cycle."""
+    time, any thread's, that ended inside the cycle.
+
+    ``own = own_cpu + own_offcpu``, exactly: ``own_cpu`` is the thread's
+    CPU time over the cycle less what it burnt inside its device waits
+    (``wait_cpu``: a host sync that spins) and its queue waits;
+    ``own_offcpu`` the rest of ``own``: the thread wanted to run and did
+    not (the interpreter lock, the scheduler, a blocking fault). Beside
+    them, from the thread's rusage where the platform has it:
+    ``own_sys_ms`` (system CPU over the whole cycle), ``minflt``,
+    ``majflt``, ``nvcsw``, ``nivcsw``; and ``cores_busy``, the whole
+    process's CPU time over the cycle's wall time."""
 
     def __init__(self, engine: ResidentServingCore,
                  admission: AdmissionController,
@@ -187,8 +244,14 @@ class MicroBatcher:
         # start. `cycles` and the ring are read by stats handlers.
         self._cycle_t0 = 0.0
         self._queue_wait_s = 0.0
+        self._queue_cpu_s = 0.0   # thread CPU inside those waits
         self._begun = 0
         self._gc_s = 0.0
+        # _cpu_now() at the thread's start and at the last cycle's end
+        # (each a fresh dict, swapped in whole: stats handlers read
+        # their difference as stats.batcher.cpu)
+        self._cpu_first: Dict[str, float] = {}
+        self._cpu_last: Dict[str, float] = {}
         self.cycles = 0
         self._slow: deque = deque(maxlen=SLOW_RING)
 
@@ -301,32 +364,37 @@ class MicroBatcher:
         pass before their fold can start. An empty return sends the
         caller to finish that batch. The time blocked in ``_cond.wait``
         (for work; for company) is the cycle's ``queue_wait``."""
-        waits: List[Tuple[float, float]] = []
+        waits: List[Tuple[float, float, float]] = []
         taken = self._take(block, waits)
         # outside the queue lock: the cycle's tally, and the spans
-        for w0, w1 in waits:
+        for w0, w1, cpu_s in waits:
             self._queue_wait_s += w1 - w0
-            obs_trace.complete_at("serve.wait.queue", w0, w1)
+            self._queue_cpu_s += cpu_s
+            obs_trace.complete_at("serve.wait.queue", w0, w1, cpu_s)
         return taken
 
-    def _take(self, block: bool, waits: List[Tuple[float, float]]
+    def _take(self, block: bool, waits: List[Tuple[float, float, float]]
               ) -> Tuple[List[Request], float]:
-        clock = time.perf_counter
+        """``waits``: (start, end, thread CPU seconds) of each block in
+        ``_cond.wait``."""
+        clock, cpu = time.perf_counter, time.thread_time
         with self._cond:
             if block and not self._queue and not self._stop:
-                w0 = clock()
+                c0, w0 = cpu(), clock()    # CPU reads outside the clock's
                 while not self._queue and not self._stop:
                     self._cond.wait(timeout=0.1)
-                waits.append((w0, clock()))
+                waits.append((w0, clock(), cpu() - c0))
             if not self._queue or (
                     not block and self._queue[0].kind == "query"
                     and self._queued_queries < self.max_batch_queries):
                 return [], 0.0
+            tick = block and not self._stop and self.tick_s > 0 \
+                and self._queued_queries < self.max_batch_queries
+            c0 = cpu() if tick else 0.0
             wake_pc = clock()
-            if block and not self._stop and self.tick_s > 0 \
-                    and self._queued_queries < self.max_batch_queries:
+            if tick:
                 self._cond.wait(timeout=self.tick_s)
-                waits.append((wake_pc, clock()))
+                waits.append((wake_pc, clock(), cpu() - c0))
             batch: List[Request] = []
             total = 0
             while self._queue:
@@ -352,6 +420,7 @@ class MicroBatcher:
     def _run_loop(self) -> None:
         flight: Optional[_Flight] = None
         self._cycle_t0 = time.perf_counter()
+        self._cpu_first = self._cpu_last = _cpu_now()
         self._gc_s = telemetry.gc_pauses().total_s
         obs_trace.wait_tally().take()
         while True:
@@ -384,7 +453,10 @@ class MicroBatcher:
         """One request-phase span through the complete_at seam (tracer
         AND the PR 9 telemetry observer, so ``serve.phase.*.ms``
         histograms stay live); rid-tagged when the request carried
-        one. A no-op when no sink is installed."""
+        one. A no-op when no sink is installed. These pairs start on a
+        handler thread (``serve.phase.queue``) or tile the batch's
+        interval per request, so none carries ``cpu_ms`` /
+        ``offcpu_ms``: no one thread's CPU time is theirs."""
         if rid:
             args["rid"] = rid
         obs_trace.complete_at(name, t0, max(t0, t1), **args)
@@ -516,12 +588,16 @@ class MicroBatcher:
         """Close the cycle that delivered ``f`` and open the next: the
         always-on histograms, the slow-cycle ring, the collector's
         noted pauses into their counters, and (with a sink) the
-        ``serve.cycle`` span. Everything after the one clock read is
-        the next cycle's ``own``."""
+        ``serve.cycle`` span. Everything after the one clock read and
+        the one reading of the thread's CPU account beside it is the
+        next cycle's ``own``."""
         t1 = time.perf_counter()
+        cpu = _cpu_now()
         t0, self._cycle_t0 = self._cycle_t0, t1
-        wait_s, sites = obs_trace.wait_tally().take()
+        last, self._cpu_last = self._cpu_last, cpu
+        wait_s, wait_cpu_s, sites = obs_trace.wait_tally().take()
         queue_s, self._queue_wait_s = self._queue_wait_s, 0.0
+        queue_cpu_s, self._queue_cpu_s = self._queue_cpu_s, 0.0
         begun, self._begun = self._begun, 0
         gcp = telemetry.gc_pauses()
         gc_s, self._gc_s = gcp.total_s - self._gc_s, gcp.total_s
@@ -529,6 +605,16 @@ class MicroBatcher:
         cycle_ms = (t1 - t0) * 1e3
         device_ms, queue_ms, gc_ms = wait_s * 1e3, queue_s * 1e3, gc_s * 1e3
         own_ms = cycle_ms - device_ms - queue_ms
+        spent = {k: v - last[k] for k, v in cpu.items()}
+        own_cpu_ms = (spent["thread_s"] - wait_cpu_s - queue_cpu_s) * 1e3
+        account = {"own_cpu_ms": own_cpu_ms,
+                   "own_offcpu_ms": own_ms - own_cpu_ms,
+                   "wait_cpu_ms": wait_cpu_s * 1e3,
+                   "cores_busy": spent["process_s"] / max(t1 - t0, 1e-9)}
+        if "sys_s" in spent:
+            account["own_sys_ms"] = spent["sys_s"] * 1e3
+            account.update((k, int(spent[k])) for k in (
+                "minflt", "majflt", "nvcsw", "nivcsw"))
         reg = telemetry.registry()
         h_cycle = reg.histogram("serve.cycle_ms", unit="ms")
         h_cycle.observe(cycle_ms)
@@ -537,6 +623,12 @@ class MicroBatcher:
                       unit="ms").observe(device_ms)
         reg.histogram("serve.cycle_ms.queue_wait",
                       unit="ms").observe(queue_ms)
+        reg.histogram("serve.cycle_ms.own_cpu",
+                      unit="ms").observe(own_cpu_ms)
+        reg.histogram("serve.cycle_ms.own_offcpu",
+                      unit="ms").observe(account["own_offcpu_ms"])
+        reg.histogram("serve.cycle_ms.wait_cpu",
+                      unit="ms").observe(account["wait_cpu_ms"])
         pend = f.pending
         overlapped = int(pend.overlapped)
         # A slow cycle is at least SLOW_MIN_OVER_MS long: the median is
@@ -559,7 +651,8 @@ class MicroBatcher:
                     "gc_ms": round(gc_ms, 3),
                     "device_wait_sites_ms": {
                         k: round(v * 1e3, 3) for k, v in sites.items()},
-                    "median_ms": round(median, 3)}
+                    "median_ms": round(median, 3),
+                    **{k: round(v, 3) for k, v in account.items()}}
         if slow is not None:
             with self._cond:
                 slow["queue_depth"] = self._queued_queries
@@ -576,16 +669,23 @@ class MicroBatcher:
                 queries=f.total, requests=len(f.requests),
                 overlapped=overlapped, own_ms=own_ms,
                 device_wait_ms=device_ms, queue_wait_ms=queue_ms,
-                gc_ms=gc_ms)
+                gc_ms=gc_ms, **account)
 
     def cycle_stats(self) -> Dict[str, Any]:
-        """``stats.batcher``: cycles closed, and the ring of slow ones
+        """``stats.batcher``: cycles closed, the ring of slow ones
         (newest last), each whole: when, which batch on which path, its
-        three parts and ``gc_ms``, the device wait by site, the queue's
-        depth when it ended."""
+        three parts and ``gc_ms``, the device wait by site, the CPU
+        account, the queue's depth when it ended; and ``cpu``: the
+        batcher thread's totals since it started, as of its last cycle
+        (``_cpu_now``'s fields), beside the cores the process may run
+        on."""
         with self._cond:
             slow = [dict(c) for c in self._slow]
-        return {"cycles": self.cycles, "slow_cycles": slow}
+        first, last = self._cpu_first, self._cpu_last
+        return {"cycles": self.cycles, "slow_cycles": slow,
+                "cpu": {**{k: round(v - first[k], 6)
+                           for k, v in last.items()},
+                        "cores": _usable_cores()}}
 
     def _deliver(self, f: _Flight, results: List, t1: float) -> None:
         """A solved micro-batch back to its requests: the batch's

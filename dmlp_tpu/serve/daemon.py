@@ -47,7 +47,9 @@ from dmlp_tpu.serve.engine import ResidentEngine
 #: (the ``serve.phase.finalize`` span); ``batch.finalize`` is the
 #: engine's float64 finalize. "cycle": the batcher thread's timeline
 #: cut a delivered micro-batch, ``cycle = own + device_wait +
-#: queue_wait`` (serve/batching.py; fed once per micro-batch).
+#: queue_wait`` and ``own = own_cpu + own_offcpu`` by the kernel's
+#: account of the thread, ``wait_cpu`` the CPU it burnt inside its device
+#: waits (serve/batching.py; fed once per micro-batch).
 PHASE_HISTOGRAMS = {
     "request": (("read", "serve.phase_ms.read"),
                 ("parse", "serve.phase_ms.parse"),
@@ -65,8 +67,20 @@ PHASE_HISTOGRAMS = {
     "cycle": (("cycle", "serve.cycle_ms"),
               ("own", "serve.cycle_ms.own"),
               ("device_wait", "serve.cycle_ms.device_wait"),
-              ("queue_wait", "serve.cycle_ms.queue_wait")),
+              ("queue_wait", "serve.cycle_ms.queue_wait"),
+              ("own_cpu", "serve.cycle_ms.own_cpu"),
+              ("own_offcpu", "serve.cycle_ms.own_offcpu"),
+              ("wait_cpu", "serve.cycle_ms.wait_cpu")),
 }
+
+
+def _cpu_between(c0: Optional[float], c1: Optional[float]
+                 ) -> Optional[float]:
+    """The ``cpu_s`` of a one-thread phase span (``serve.phase.read`` /
+    ``parse`` / ``respond`` / ``write``) from ``obs.trace.thread_cpu``
+    at its two ends: None, and the span without ``cpu_ms`` /
+    ``offcpu_ms``, unless a sink was installed at both."""
+    return None if c0 is None or c1 is None else c1 - c0
 
 
 def default_warm_buckets(corpus: KNNInput) -> List[Tuple[int, int]]:
@@ -98,7 +112,7 @@ class _Handler(socketserver.StreamRequestHandler):
             raw = reader.readline()
             if not raw:
                 break
-            t_read = time.perf_counter()
+            t_read, c_read = time.perf_counter(), obs_trace.thread_cpu()
             t_first, pieces = reader.t_first, reader.pieces
             if len(raw) > protocol.MAX_LINE_BYTES:
                 self.wfile.write(protocol.encode(
@@ -115,7 +129,7 @@ class _Handler(socketserver.StreamRequestHandler):
             try:
                 req = None
                 try:
-                    resp, req = daemon.serve_line(raw, t_read)
+                    resp, req = daemon.serve_line(raw, t_read, c_read)
                 except protocol.ProtocolError as e:
                     resp = {"ok": False, "error": str(e)}
                 except Exception as e:  # check: no-retry — the
@@ -134,21 +148,23 @@ class _Handler(socketserver.StreamRequestHandler):
                     telemetry.registry().histogram(
                         "serve.read_pieces", unit="calls").observe(pieces)
                 obs_trace.complete_at(
-                    "serve.phase.read", t_first, t_read, bytes=len(raw),
+                    "serve.phase.read", t_first, t_read,
+                    _cpu_between(reader.c_first, c_read), bytes=len(raw),
                     pieces=pieces,
                     **({"rid": rid} if rid else {}), **_batch_arg(req))
-                w0 = time.perf_counter()
+                w0, c0 = time.perf_counter(), obs_trace.thread_cpu()
                 data = protocol.encode(resp)
                 self.wfile.write(data)
                 self.wfile.flush()
-                w1 = time.perf_counter()
+                w1, c1 = time.perf_counter(), obs_trace.thread_cpu()
                 if req is not None and req.kind == "query":
                     telemetry.registry().histogram(
                         "serve.phase_ms.write", unit="ms").observe(
                             (w1 - w0) * 1e3)
                     daemon.record_respond(req, len(data))
                 obs_trace.complete_at(
-                    "serve.phase.write", w0, w1, bytes=len(data),
+                    "serve.phase.write", w0, w1, _cpu_between(c0, c1),
+                    bytes=len(data),
                     **({"rid": rid} if rid else {}),
                     **_batch_arg(req))
             finally:
@@ -373,15 +389,16 @@ class ServeDaemon:
         response went onto a socket, the ``bytes`` it encoded to."""
         if not obs_trace.sinks_active():
             return
-        r0, r1 = req.respond_pc
+        r0, r1, cpu_s = req.respond_pc
         obs_trace.complete_at(
-            "serve.phase.respond", r0, r1, queries=req.nq,
+            "serve.phase.respond", r0, r1, cpu_s, queries=req.nq,
             k=int(req.ks.max()) if req.nq else 0,
             **({} if nbytes is None else {"bytes": nbytes}),
             **({"rid": req.rid} if req.rid else {}), **_batch_arg(req))
 
     def serve_line(self, line: Union[str, bytes],
-                   t_read: Optional[float] = None
+                   t_read: Optional[float] = None,
+                   c_read: Optional[float] = None
                    ) -> Tuple[Optional[Dict[str, Any]], Optional[Request]]:
         """One request line to its response, and the Request it made
         (None for the control ops; both None for a blank line). A line
@@ -389,13 +406,14 @@ class ServeDaemon:
         decoded natively where the library is loaded
         (``protocol.parse_request``). ``t_read`` is the perf_counter at
         which the line had been read: the start of the request's parse
-        phase (now, when the caller read no socket). A query request's
-        ``parse`` and ``respond`` phases are timed here, each one clock
-        pair feeding its always-on histogram and — with a sink
-        installed — its ``serve.phase.*`` span (``respond``'s is
+        phase (now, when the caller read no socket), ``c_read`` the
+        thread's CPU time then (``obs.trace.thread_cpu``). A query
+        request's ``parse`` and ``respond`` phases are timed here, each
+        one clock pair feeding its always-on histogram and — with a
+        sink installed — its ``serve.phase.*`` span (``respond``'s is
         recorded by the caller: :meth:`record_respond`)."""
         if t_read is None:
-            t_read = time.perf_counter()
+            t_read, c_read = time.perf_counter(), obs_trace.thread_cpu()
         obj = protocol.parse_request(line, self.corpus.params.num_attrs)
         if obj is None:
             return None, None
@@ -413,7 +431,7 @@ class ServeDaemon:
             return protocol.corpus_response(req), req
         reg = telemetry.registry()
         rid = {"rid": req.rid} if req.rid else {}
-        t_parsed = time.perf_counter()
+        t_parsed, c_parsed = time.perf_counter(), obs_trace.thread_cpu()
         reg.histogram("serve.phase_ms.parse", unit="ms").observe(
             (t_parsed - t_read) * 1e3)
         reg.counter("serve.parse_requests").inc(
@@ -423,15 +441,17 @@ class ServeDaemon:
         # recorded now, not when it ended: only now is the micro-batch
         # the request rode known
         obs_trace.complete_at(
-            "serve.phase.parse", t_read, t_parsed, queries=req.nq,
+            "serve.phase.parse", t_read, t_parsed,
+            _cpu_between(c_read, c_parsed), queries=req.nq,
             native_queries=req.nq if req.parsed_native else 0,
             bytes=len(line), **rid, **_batch_arg(req))
-        r0 = time.perf_counter()
+        r0, c0 = time.perf_counter(), obs_trace.thread_cpu()
         resp = protocol.query_response(req)
-        r1 = time.perf_counter()
+        r1, c1 = time.perf_counter(), obs_trace.thread_cpu()
         reg.histogram("serve.phase_ms.respond", unit="ms").observe(
             (r1 - r0) * 1e3)
-        req.respond_pc = (r0, r1)       # the span: record_respond
+        # the span: record_respond
+        req.respond_pc = (r0, r1, _cpu_between(c0, c1))
         return resp, req
 
     def stats(self) -> Dict[str, Any]:
@@ -482,9 +502,12 @@ class ServeDaemon:
                 "count": h.count,
             }
         phases = {
+            # (the quantiles are a log bucket's, 12% apart; the mean is
+            # exact, and the one reading of a tick-grained CPU part)
             group: {key: {"count": h.count,
                           "p50": round(h.quantile(0.5), 3),
-                          "p95": round(h.quantile(0.95), 3)}
+                          "p95": round(h.quantile(0.95), 3),
+                          "mean": round(h.sum / h.count, 3)}
                     for key, h in ((k, reg.get(n)) for k, n in names)
                     if h is not None and h.count}
             for group, names in PHASE_HISTOGRAMS.items()}

@@ -1670,13 +1670,15 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         and the final valid counts (shortfall check); the boundary
         repair makes both exact. Closes ``serve.solve_multipass``,
         which began with the batch's first enqueue in the FIRST half:
-        a span that crosses batches when another is in flight."""
+        a span that crosses batches when another is in flight (so it
+        carries no ``cpu_ms`` / ``offcpu_ms``; ``serve.mp_fetch`` and
+        the spans of each half do)."""
         (t_begin, fence, args), pend.mp_fence = pend.mp_fence, None
         inp = pend.inp
         nq = inp.params.num_queries
         targs = self._rid_args()
-        with obs_span("serve.mp_fetch", site="mp_fetch", **targs), \
-                obs_trace.device_wait("mp_fetch", span=False):
+        with obs_trace.device_wait("mp_fetch", name="serve.mp_fetch",
+                                   **targs):
             fetched = resilient_get(fence)
         valid_h, fd_h = fetched[0], fetched[1:]
         stalled = np.zeros(args["qpad"], bool)

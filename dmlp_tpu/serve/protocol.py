@@ -65,6 +65,7 @@ from typing import Any, Dict, Optional, Tuple, Union
 import numpy as np
 
 from dmlp_tpu.io import native
+from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.serve.batching import Request
 
 #: protocol schema version, echoed in hello/stats
@@ -115,15 +116,17 @@ class LineReader:
         self._have = 0          # bytes of the next line already here
         #: receive calls the last line took (0: whole in the carry-over)
         self.pieces = 0
-        #: perf_counter when the last line's first bytes were in hand
+        #: perf_counter when the last line's first bytes were in hand,
+        #: and the thread's CPU time then while a sink is installed
         self.t_first = 0.0
+        self.c_first: Optional[float] = None
 
     def readline(self) -> bytes:
         buf, have = self._buf, self._have
         cap = MAX_LINE_BYTES + 1
         self.pieces = 0
         if have:
-            self.t_first = time.perf_counter()
+            self._stamp_first()
         seen = 0                # a newline is sought in new bytes only
         while True:
             nl = buf.find(b"\n", seen, have)
@@ -139,7 +142,7 @@ class LineReader:
             if not got:         # end of stream: what came is the line
                 break
             if not have:
-                self.t_first = time.perf_counter()
+                self._stamp_first()
             have += got
         end = nl + 1 if nl >= 0 else have
         with memoryview(buf) as whole:
@@ -147,6 +150,10 @@ class LineReader:
         buf[:have - end] = buf[end:have]
         self._have = have - end
         return line
+
+    def _stamp_first(self) -> None:
+        self.t_first = time.perf_counter()
+        self.c_first = obs_trace.thread_cpu()
 
 
 def _is_int(v) -> bool:

@@ -7,6 +7,12 @@ the slow ones; one ``gc.callbacks`` hook notes the collector's pauses
 without taking a lock; the handler times a request's line from its
 first byte. Held here by structure (what tiles what, which part a
 delay lands in), with injected delays large against the CPU's noise.
+
+PR 51: ``own`` is split again by the kernel's account of the thread
+(``own = own_cpu + own_offcpu``; ``wait_cpu``; the thread's rusage), one
+reading a cycle, always on: a sleep lands off the core, a spin on it,
+the interpreter lock held by another thread off it, a spin inside a
+host sync in ``wait_cpu``; and the calls it makes a cycle are counted.
 """
 
 from __future__ import annotations
@@ -14,6 +20,7 @@ from __future__ import annotations
 import gc
 import json
 import socket
+import sys
 import threading
 import time
 
@@ -21,6 +28,7 @@ import numpy as np
 import pytest
 
 from dmlp_tpu.config import EngineConfig
+from dmlp_tpu.engine import single
 from dmlp_tpu.io.grammar import KNNInput, Params
 from dmlp_tpu.obs import telemetry
 from dmlp_tpu.obs import trace as obs_trace
@@ -36,6 +44,13 @@ NA = 4
 NQ = 6          # queries a request, and the batch cap: a request a batch
 WAIT = 300
 PARTS = ("own_ms", "device_wait_ms", "queue_wait_ms")
+#: the cycle's CPU account (PR 51): on every ``serve.cycle`` span and in
+#: every slow-cycle record, the rusage half where the platform has it
+CPU_PARTS = ("own_cpu_ms", "own_offcpu_ms", "wait_cpu_ms", "cores_busy")
+RUSAGE_PARTS = ("own_sys_ms", "minflt", "majflt", "nvcsw", "nivcsw")
+CPU_TOTALS = {"thread_s", "process_s", "cores"} | (
+    {"user_s", "sys_s", "minflt", "majflt", "nvcsw", "nivcsw"}
+    if batching._RUSAGE_THREAD is not None else set())
 #: the second half of a micro-batch on the one-chip engine
 SECOND_HALF = ["single.fetch", "single.hazard", "single.finalize",
                "serve.after_batch", "serve.batch_deliver"]
@@ -82,6 +97,13 @@ def closed(b: MicroBatcher, n: int):
     while b.cycles < n and time.monotonic() < deadline:
         time.sleep(0.001)
     return b.cycle_stats()
+
+
+def spin(seconds: float) -> None:
+    """Burn ``seconds`` of the calling thread's CPU time."""
+    c0 = time.thread_time()
+    while time.thread_time() - c0 < seconds:
+        pass
 
 
 def delays(site: str, ms: int, times: int = 1) -> FaultSchedule:
@@ -150,6 +172,32 @@ def test_a_cycle_is_split_exactly_three_ways(flows, order):
 
 
 @pytest.mark.parametrize("order", ["pipelined", "serial"])
+def test_own_is_split_exactly_on_and_off_the_core(flows, order):
+    """``own_ms = own_cpu_ms + own_offcpu_ms`` to the float's rounding,
+    on every cycle; the thread's CPU inside its waits is not in it."""
+    for c in named(flows[order]["spans"], "serve.cycle"):
+        a = c["args"]
+        assert set(CPU_PARTS) <= set(a)
+        assert abs(a["own_ms"] - a["own_cpu_ms"] - a["own_offcpu_ms"]) \
+            < 1e-9
+        assert a["own_cpu_ms"] > 0 and a["wait_cpu_ms"] >= 0
+        # the waits' CPU lies inside the waits' wall time
+        assert a["wait_cpu_ms"] <= a["device_wait_ms"] + 0.01
+        assert 0 < a["cores_busy"] <= batching._usable_cores() + 0.5
+        if batching._RUSAGE_THREAD is not None:
+            assert all(a[k] >= 0 for k in RUSAGE_PARTS)
+            assert all(isinstance(a[k], int) for k in RUSAGE_PARTS[1:])
+        # a cycle's one-thread spans cannot have been on a core longer
+        # than the cycle's thread was, in its own stretch and its waits
+        inner = [w for w in flows[order]["spans"]
+                 if w["name"] in ("single.finalize", "serve.batch_deliver")
+                 and w["tid"] == c["tid"] and c["ts"] <= w["ts"]
+                 and end(w) <= end(c) + 1e-3]
+        assert inner and sum(w["args"]["cpu_ms"] for w in inner) \
+            <= a["own_cpu_ms"] + a["wait_cpu_ms"] + 1e-6
+
+
+@pytest.mark.parametrize("order", ["pipelined", "serial"])
 def test_cycles_tile_the_batcher_thread(flows, order):
     """One cycle a batch, in the order of delivery, on one thread; the
     next starts where the last ended (to the microsecond's rounding)."""
@@ -205,9 +253,10 @@ def test_device_waits_are_spans_with_their_site_and_sum_to_the_part(
         assert all(w["args"]["batch"] in (c["args"]["batch"],
                                           c["args"]["begun"])
                    for w in waits)
-        # (a seam's own span opens a call before the bracket inside it)
-        assert 0 <= sum(w["dur"] for w in waits) / 1e3 \
-            - c["args"]["device_wait_ms"] < 0.2
+        # each span IS the bracket's two clock reads (PR 51): the sum
+        # equals the part to the float's rounding
+        assert abs(sum(w["dur"] for w in waits) / 1e3
+                   - c["args"]["device_wait_ms"]) < 1e-6
 
 
 def test_the_epilogue_ends_with_the_first_half(flows):
@@ -247,15 +296,22 @@ def solve_traced(eng, k: int = 300):
     return spans_of(tracer)
 
 
-def test_the_multipass_first_half_is_tiled_in_the_order_it_enqueues():
-    """Staged, pass 1 dispatched, and only then the floor chain's
-    scalars put (the device has its work first), the later passes, the
-    merge, the epilogue: one span each, none inside another, all inside
-    the span that crosses batches."""
+@pytest.fixture(scope="module")
+def multipass_spans():
     eng = multipass_engine()
     eng.warmup([(NQ, 300)])
     spans = solve_traced(eng)
     assert eng.last_mp_passes == 2
+    return spans
+
+
+def test_the_multipass_first_half_is_tiled_in_the_order_it_enqueues(
+        multipass_spans):
+    """Staged, pass 1 dispatched, and only then the floor chain's
+    scalars put (the device has its work first), the later passes, the
+    merge, the epilogue: one span each, none inside another, all inside
+    the span that crosses batches."""
+    spans = multipass_spans
     chain = [e for e in spans if e["name"] in (
         "serve.solve_stage", "serve.mp_pass", "serve.mp_norms",
         "serve.mp_merge", "serve.solve_epilogue")]
@@ -268,6 +324,24 @@ def test_the_multipass_first_half_is_tiled_in_the_order_it_enqueues():
     (whole,) = named(spans, "serve.solve_multipass")
     assert end(chain[0]) <= whole["ts"] + 1e-3      # staged before it
     assert whole["ts"] <= chain[1]["ts"] + 1e-3
+
+
+def test_the_span_that_crosses_batches_has_no_cpu_account(multipass_spans):
+    """``serve.solve_multipass`` is a clock pair with another batch's
+    work between its ends when one is in flight: no thread's CPU time
+    is its own. Its fence is the host-sync bracket's own span, and the
+    ``with`` spans of either half carry the account."""
+    (whole,) = named(multipass_spans, "serve.solve_multipass")
+    assert not {"cpu_ms", "offcpu_ms"} & set(whole["args"])
+    for name in ("serve.mp_fetch", "serve.mp_pass", "serve.mp_merge",
+                 "serve.solve_stage"):
+        for e in named(multipass_spans, name):
+            a = e["args"]
+            assert abs(e["dur"] / 1e3 - a["cpu_ms"] - a["offcpu_ms"]) \
+                < 1e-6, name
+            assert a["cpu_ms"] > 0
+    (fence,) = named(multipass_spans, "serve.mp_fetch")
+    assert fence["args"]["site"] == "mp_fetch"
 
 
 def test_a_refused_multipass_leaves_no_stage_span_behind(monkeypatch):
@@ -288,9 +362,11 @@ def test_a_refused_multipass_leaves_no_stage_span_behind(monkeypatch):
 @pytest.fixture(scope="module")
 def slowed():
     """A batcher past its first 32 cycles, then one straggler at
-    ``serve.solve`` (the batcher's own 150 ms), one delayed readback
-    (``single.fetch``: 150 ms inside the host-sync bracket), then 20
-    more stragglers; a straggler among the first 32 before all that."""
+    ``serve.solve`` (the batcher's own 150 ms, asleep), one delayed
+    readback (``single.fetch``: 150 ms inside the host-sync bracket),
+    one first half that SPINS 150 ms of the thread's CPU time, one
+    readback that spins them inside the bracket, then 20 more
+    stragglers; a straggler among the first 32 before all that."""
     eng = stream_engine()
     eng.warmup([(NQ, 4)])
     # as a daemon does: the running median is this lifetime's
@@ -311,13 +387,26 @@ def slowed():
         inject.install(delays("single.fetch", 150))
         out["device"] = serve_one(b, request(101, rng)).batch
         out["two"] = closed(b, batching.SLOW_WARM_CYCLES + 2)
+        begin, get = eng.begin_batch, single.resilient_get
+        eng.begin_batch = lambda *a, **kw: (spin(0.15), begin(*a, **kw))[1]
+        try:
+            out["spin"] = serve_one(b, request(102, rng)).batch
+        finally:
+            eng.begin_batch = begin
+        single.resilient_get = lambda *a, **kw: (spin(0.15),
+                                                 get(*a, **kw))[1]
+        try:
+            out["spun_wait"] = serve_one(b, request(103, rng)).batch
+        finally:
+            single.resilient_get = get
+        out["four"] = closed(b, batching.SLOW_WARM_CYCLES + 4)
         # 100 ms, not the rule's bare 50 over the median: a loaded
         # machine's median cycle is tens of ms, and 3 x that passed a
         # 60 ms straggler once in the driver's whole run (PR 38)
         inject.install(delays("serve.solve", 100, times=20))
         for i in range(20):
             serve_one(b, request(200 + i, rng))
-        out["ring"] = closed(b, batching.SLOW_WARM_CYCLES + 22)
+        out["ring"] = closed(b, batching.SLOW_WARM_CYCLES + 24)
     finally:
         inject.uninstall()
         b.stop(drain=True)
@@ -328,8 +417,9 @@ def slowed():
 
 
 def test_the_ring_is_empty_for_the_first_32_cycles(slowed):
-    assert slowed["warm"] == {"cycles": batching.SLOW_WARM_CYCLES,
-                              "slow_cycles": []}
+    warm = dict(slowed["warm"])
+    assert set(warm.pop("cpu")) == CPU_TOTALS
+    assert warm == {"cycles": batching.SLOW_WARM_CYCLES, "slow_cycles": []}
     (first,) = named(slowed["spans"], "serve.cycle", batch=1)
     assert first["args"]["own_ms"] >= 150       # slow, and not kept
 
@@ -364,10 +454,110 @@ def test_a_delayed_readback_lands_in_device_wait_with_its_site(slowed):
     assert rec["device_wait_sites_ms"]["fetch"] >= 150
 
 
+def test_a_sleeping_straggler_is_off_the_core_and_a_spinning_one_on_it(
+        slowed):
+    """The same 150 ms of ``own``: asleep at ``serve.solve`` they are
+    ``own_offcpu_ms``, spun in the first half ``own_cpu_ms``; the ring's
+    record says which, with the rusage fields beside it."""
+    (slept,) = named(slowed["spans"], "serve.cycle", batch=slowed["own"])
+    (spun,) = named(slowed["spans"], "serve.cycle", batch=slowed["spin"])
+    assert slept["args"]["own_offcpu_ms"] >= 150
+    assert slept["args"]["own_cpu_ms"] < 100
+    assert spun["args"]["own_cpu_ms"] >= 150 and spun["args"]["own_ms"] >= 150
+    assert spun["args"]["wait_cpu_ms"] < 100
+    recs = {r["batch"]: r for r in slowed["four"]["slow_cycles"]}
+    assert recs[slowed["own"]]["own_offcpu_ms"] >= 150
+    assert recs[slowed["spin"]]["own_cpu_ms"] >= 150
+    for rec in recs.values():
+        assert set(CPU_PARTS) <= set(rec)
+        assert abs(rec["own_ms"] - rec["own_cpu_ms"]
+                   - rec["own_offcpu_ms"]) < 0.01
+        if batching._RUSAGE_THREAD is not None:
+            assert set(RUSAGE_PARTS) <= set(rec)
+    # ... and so does the instant a Tracer gets
+    (inst,) = [e for e in slowed["instants"]
+               if e["name"] == "serve.slow_cycle"
+               and e["args"]["batch"] == slowed["spin"]]
+    assert inst["args"]["own_cpu_ms"] >= 150
+
+
+def test_a_readback_that_spins_lands_in_wait_cpu_and_not_in_own_cpu(slowed):
+    """CPU burnt inside the host-sync bracket is the wait's
+    (``wait_cpu_ms``, the span's ``cpu_ms``): ``own_cpu_ms`` is the
+    thread's CPU outside its waits; a readback that SLEEPS burns none."""
+    (c,) = named(slowed["spans"], "serve.cycle", batch=slowed["spun_wait"])
+    assert c["args"]["wait_cpu_ms"] >= 150
+    assert c["args"]["device_wait_ms"] >= 150
+    assert c["args"]["own_cpu_ms"] < 100
+    (w,) = named(slowed["spans"], "single.fetch", site="fetch",
+                 batch=slowed["spun_wait"])
+    assert w["args"]["cpu_ms"] >= 150
+    assert abs(w["dur"] / 1e3 - w["args"]["cpu_ms"]
+               - w["args"]["offcpu_ms"]) < 1e-6
+    (asleep,) = named(slowed["spans"], "serve.cycle", batch=slowed["device"])
+    assert asleep["args"]["wait_cpu_ms"] < 100
+    (w,) = named(slowed["spans"], "single.fetch", site="fetch",
+                 batch=slowed["device"])
+    assert w["args"]["offcpu_ms"] >= 150
+
+
+def test_the_interpreter_lock_held_elsewhere_is_off_the_core():
+    """The same pure-Python second half twice: alone, and beside a
+    thread that loops in Python under a short switch interval. The
+    batcher waits for the lock every other slice: ``own_offcpu_ms``
+    grows by about the work's own length, ``own_cpu_ms`` does not."""
+    eng = stream_engine()
+    eng.warmup([(NQ, 4)])
+    finish = eng.finish_batch
+
+    def working(pending):
+        n = 0
+        for i in range(1_500_000):      # holds the lock throughout
+            n += i
+        return finish(pending)
+    eng.finish_batch = working
+    b = batcher_for(eng)
+    rng = np.random.default_rng(13)
+    stop = threading.Event()
+
+    def hog():
+        n = 0
+        while not stop.is_set():
+            for i in range(10_000):
+                n += i
+    tracer = obs_trace.install(obs_trace.Tracer())
+    interval = sys.getswitchinterval()
+    b.start()
+    try:
+        quiet = serve_one(b, request(0, rng)).batch
+        closed(b, 1)
+        sys.setswitchinterval(1e-4)
+        t = threading.Thread(target=hog, daemon=True)
+        t.start()
+        try:
+            beside = serve_one(b, request(1, rng)).batch
+        finally:
+            stop.set()
+            t.join(timeout=30)
+        assert not t.is_alive()
+        closed(b, 2)
+    finally:
+        sys.setswitchinterval(interval)
+        b.stop(drain=True)
+        obs_trace.uninstall()
+    (c0,) = named(spans_of(tracer), "serve.cycle", batch=quiet)
+    (c1,) = named(spans_of(tracer), "serve.cycle", batch=beside)
+    work = c0["args"]["own_cpu_ms"]
+    waited = c1["args"]["own_offcpu_ms"] - c0["args"]["own_offcpu_ms"]
+    assert work > 10 and waited > 0.4 * work, (c0["args"], c1["args"])
+    assert c1["args"]["own_cpu_ms"] - work < 0.5 * waited, \
+        (c0["args"], c1["args"])
+
+
 def test_the_ring_is_bounded(slowed):
     ring = slowed["ring"]["slow_cycles"]
     assert len(ring) == batching.SLOW_RING == 16
-    assert slowed["ring"]["cycles"] == batching.SLOW_WARM_CYCLES + 22
+    assert slowed["ring"]["cycles"] == batching.SLOW_WARM_CYCLES + 24
     serials = [r["batch"] for r in ring]
     assert serials == sorted(serials) and serials[-1] == \
         slowed["ring"]["cycles"]        # the newest 16, newest last
@@ -552,26 +742,101 @@ def untraced():
 def test_no_sink_builds_no_span_and_the_histograms_still_count(untraced):
     assert untraced["built"] == []
     assert untraced["null"] is obs_trace.NULL_SPAN
-    assert untraced["stats"] == {"cycles": 3, "slow_cycles": []}
+    stats = dict(untraced["stats"])
+    cpu = stats.pop("cpu")
+    assert stats == {"cycles": 3, "slow_cycles": []}
     assert untraced["counts"] == {
         "serve.cycle_ms": 3, "serve.cycle_ms.own": 3,
-        "serve.cycle_ms.device_wait": 3, "serve.cycle_ms.queue_wait": 3}
+        "serve.cycle_ms.device_wait": 3, "serve.cycle_ms.queue_wait": 3,
+        "serve.cycle_ms.own_cpu": 3, "serve.cycle_ms.own_offcpu": 3,
+        "serve.cycle_ms.wait_cpu": 3}
+    # the thread's totals since it started, as of its last cycle, and
+    # the cores the process may run on (cores_busy's denominator)
+    assert set(cpu) == CPU_TOTALS
+    assert 0 < cpu["thread_s"] <= cpu["process_s"]
+    assert cpu["cores"] == batching._usable_cores() >= 1
+    if batching._RUSAGE_THREAD is not None:
+        assert abs(cpu["user_s"] + cpu["sys_s"] - cpu["thread_s"]) < 0.05
+        assert cpu["nvcsw"] >= 1 and cpu["minflt"] >= 0
+
+
+def test_the_account_makes_a_fixed_number_of_calls_a_cycle(monkeypatch):
+    """With no sink, a cycle of the pipelined order (no queue wait)
+    reads the kernel's account once (``time.thread_time``,
+    ``time.process_time``, ``resource.getrusage``: one call each) and
+    the thread's CPU time twice more for each host sync; nothing else
+    in the program calls them, and no span object is built."""
+    assert not obs_trace.sinks_active()
+    calls = {"thread_time": 0, "process_time": 0, "getrusage": 0,
+             "device_wait": 0, "span": 0}
+
+    def counted(key, fn):
+        def call(*a, **kw):
+            calls[key] += 1
+            return fn(*a, **kw)
+        return call
+    thread_time = counted("thread_time", time.thread_time)
+    monkeypatch.setattr(time, "thread_time", thread_time)
+    monkeypatch.setattr(obs_trace, "_cpu", thread_time)
+    monkeypatch.setattr(time, "process_time",
+                        counted("process_time", time.process_time))
+    if batching._RUSAGE_THREAD is not None:
+        monkeypatch.setattr(batching.resource, "getrusage", counted(
+            "getrusage", batching.resource.getrusage))
+    monkeypatch.setattr(obs_trace.device_wait, "__enter__", counted(
+        "device_wait", obs_trace.device_wait.__enter__))
+    for cls in (obs_trace.Span, obs_trace._TelemetrySpan):
+        monkeypatch.setattr(cls, "__init__", counted("span", cls.__init__))
+    eng = stream_engine()
+    eng.warmup([(NQ, 4)])
+    b = batcher_for(eng)
+    at_end = []
+    end_cycle = b._end_cycle
+
+    def noting(f):
+        end_cycle(f)
+        at_end.append(dict(calls))
+    b._end_cycle = noting
+    rng = np.random.default_rng(17)
+    reqs = [request(i, rng) for i in range(4)]
+    for r in reqs:
+        assert b.submit(r)["verdict"] == "accept"
+    b.start()
+    for r in reqs:
+        assert r.done.wait(timeout=WAIT) and r.error is None
+    b.stop(drain=True)
+    assert len(at_end) == 4 and calls["span"] == 0
+    per_cycle = [{k: b_[k] - a[k] for k in a}
+                 for a, b_ in zip(at_end, at_end[1:])]
+    # cycles 2..4 began behind another batch: no queue wait inside
+    for got in per_cycle:
+        assert got["device_wait"] == per_cycle[0]["device_wait"] >= 1
+        assert got["thread_time"] == 1 + 2 * got["device_wait"]
+        assert got["process_time"] == 1
+        assert got["getrusage"] == int(batching._RUSAGE_THREAD is not None)
 
 
 @pytest.mark.parametrize("key", ["cycle", "own", "device_wait",
-                                 "queue_wait"])
+                                 "queue_wait", "own_cpu", "own_offcpu",
+                                 "wait_cpu"])
 def test_stats_reports_the_cycle(traced_daemon, key):
     stats = traced_daemon["stats"]
-    assert stats["batcher"] == {"cycles": 2, "slow_cycles": []}
+    batcher = dict(stats["batcher"])
+    assert set(batcher.pop("cpu")) == CPU_TOTALS
+    assert batcher == {"cycles": 2, "slow_cycles": []}
     got = stats["phases_ms"]["cycle"][key]
-    assert got["count"] == 2 and 0 <= got["p50"] <= got["p95"]
+    # (own_offcpu is a difference of two clocks: a thread that never
+    # left its core may read a microsecond under zero)
+    assert got["count"] == 2 and -0.01 <= got["p50"] <= got["p95"]
     assert stats["phases_ms"]["cycle"]["cycle"]["p95"] >= got["p95"]
 
 
 @pytest.mark.parametrize("series", [
     "serve_cycle_ms_count 2", "serve_cycle_ms_own_count 2",
     "serve_cycle_ms_device_wait_count 2",
-    "serve_cycle_ms_queue_wait_count 2", "serve_phase_ms_read_count 2",
+    "serve_cycle_ms_queue_wait_count 2", "serve_cycle_ms_own_cpu_count 2",
+    "serve_cycle_ms_own_offcpu_count 2", "serve_cycle_ms_wait_cpu_count 2",
+    "serve_phase_ms_read_count 2",
     'runtime_gc_pause_ms_total{key="gen2"}',
     'runtime_gc_collections_total{key="gen2"}'])
 def test_openmetrics_carries_the_new_series(traced_daemon, series):

@@ -296,7 +296,7 @@ def test_the_retrys_wait_is_a_device_wait_of_its_own_site(warmed, corpus,
                                                           tracer):
     obs_trace.wait_tally().take()
     solve(warmed, queries_at(corpus, CLUSTER, 6))
-    _seconds, by_site = obs_trace.wait_tally().take()
+    _seconds, _cpu_seconds, by_site = obs_trace.wait_tally().take()
     assert by_site.get("retry", 0) > 0
     waits = [e for e in spans(tracer, "serve.wait.device")
              if e["args"]["site"] == "retry"]

@@ -111,6 +111,12 @@ def named(events, name, batch=None):
             and (batch is None or e["args"].get("batch") == batch)]
 
 
+def work_args(e) -> dict:
+    """A span's args without its CPU account (PR 51: every span's)."""
+    return {k: v for k, v in e["args"].items()
+            if k not in ("cpu_ms", "offcpu_ms")}
+
+
 def inside(child, parent):
     return (parent["ts"] <= child["ts"]
             and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
@@ -125,6 +131,41 @@ def test_one_micro_batch_yields_the_span_once(traced, name):
         spans = [e for e in spans if e.get("args", {}).get("rid")]
     assert len(spans) == 1, [e["name"] for e in traced["served"]]
     assert spans[0]["args"]["batch"] == 1
+
+
+#: clock pairs whose two ends lie on one thread (PR 51): the handler's
+ONE_THREAD = ["read", "parse", "respond", "write"]
+#: ... and those that cross threads, or cross another batch's work
+CROSSING = ["serve.phase.queue", "serve.phase.coalesce",
+            "serve.phase.solve", "serve.phase.finalize",
+            "serve.micro_batch"]
+
+
+@pytest.mark.parametrize(
+    "name", [n for n in CYCLE + IN_BATCH + sorted(NESTED)
+             # (the two the batcher stitches from clock reads of its own)
+             if n not in ("serve.micro_batch", "serve.solve_epilogue")]
+    + [f"serve.phase.{p}" for p in ONE_THREAD] + ["serve.wait.device"])
+def test_a_one_thread_span_says_how_long_it_was_on_a_core(traced, name):
+    """Every ``with`` span, the host-sync bracket's span and the four
+    handler phases timed on one thread carry ``cpu_ms`` (the thread's
+    CPU time between the span's ends) and ``offcpu_ms``, which tile the
+    duration."""
+    spans = [e for e in named(traced["served"], name)
+             if name != "serve.phase.write" or e["args"].get("rid")]
+    assert spans
+    for e in spans:
+        a = e["args"]
+        assert abs(e["dur"] / 1e3 - a["cpu_ms"] - a["offcpu_ms"]) < 1e-6
+        assert 0 <= a["cpu_ms"] <= e["dur"] / 1e3 + 0.05, (name, a)
+
+
+@pytest.mark.parametrize("name", CROSSING)
+def test_a_clock_pair_that_crosses_threads_has_no_cpu_account(traced, name):
+    spans = named(traced["served"], name)
+    assert spans
+    for e in spans:
+        assert not {"cpu_ms", "offcpu_ms"} & set(e["args"]), name
 
 
 def test_batcher_spans_tile_the_cycle_in_order(traced):
@@ -256,7 +297,7 @@ def test_the_norm_pass_is_set_up_and_no_hazard_span_holds_it(traced):
     the engine is built: before warm-up's solves, outside every
     ``single.hazard`` (warm-up's too), tagged with no batch."""
     (pas,) = named(traced["all"], DN_MAX)
-    assert pas["args"] == {"rows": 2400}
+    assert work_args(pas) == {"rows": 2400}
     hazards = named(traced["all"], "single.hazard")
     assert len(hazards) >= 2                    # warm-up's and the served
     assert not any(inside(pas, h) for h in hazards)
@@ -438,7 +479,7 @@ def test_batch_engine_makes_the_norm_pass_once_a_run_inside_hazard():
     for events in (tracer.events()[:first], tracer.events()[first:]):
         spans = [e for e in events if e.get("ph") == "X"]
         (pas,) = named(spans, DN_MAX)
-        assert pas["args"] == {"rows": 2400}
+        assert work_args(pas) == {"rows": 2400}
         first_seg, second_seg = named(spans, "single.hazard")
         assert eng.last_hetk == (4, 2)
         assert first_seg["args"]["dn_max_cached"] is False
