@@ -40,20 +40,58 @@ def fnv1a_checksum(label: int, neighbor_ids: Iterable[int]) -> int:
 
 def fnv1a_checksum_batch(labels: Sequence[int], neighbor_ids: np.ndarray,
                          valid_counts: Sequence[int]) -> np.ndarray:
-    """Vectorized checksums for a batch of query results.
+    """Checksums of a batch of query results, folded a neighbour
+    POSITION at a time over all queries at once: Kmax array steps for
+    the Q * Kmax scalar ones of :func:`fnv1a_checksum`, which stays the
+    contract's reference (equal values: tests/test_checksum.py).
+
+    The fold runs in uint64, whose sum and product wrap mod 2**64: the
+    scalar's ``& _MASK``, and C++'s ``static_cast<unsigned long long>``
+    of a negative label or the -1 sentinel id. A query whose list has
+    ended (a request may mix k) is stepped with ``c ^= 0; c *= 1``,
+    which leaves it as it is.
+
+    Made of elementwise operations alone: on a small batch NumPy runs
+    those without letting go of the interpreter lock, where a sort or
+    a fancy index hands it to whichever thread waits, and a handler
+    that answers a 2-query request then waits for the lock's return
+    (serve/protocol.py:query_response calls this on handler threads).
 
     Args:
       labels: (Q,) predicted labels.
-      neighbor_ids: (Q, Kmax) neighbor ids in report order; entries at or
-        beyond each query's valid count are ignored.
-      valid_counts: (Q,) number of reported neighbors per query (its k).
+      neighbor_ids: (Q, Kmax) integer neighbor ids in report order;
+        entries at or beyond each query's valid count are ignored.
+      valid_counts: (Q,) number of reported neighbors per query (its
+        k), each in [0, Kmax].
 
     Returns:
-      (Q,) uint64-valued Python-int array (dtype object to avoid overflow
-      surprises in downstream formatting).
+      (Q,) uint64 checksums.
     """
-    out = np.empty(len(labels), dtype=object)
     ids = np.asarray(neighbor_ids)
-    for qi in range(len(labels)):
-        out[qi] = fnv1a_checksum(int(labels[qi]), ids[qi, : int(valid_counts[qi])])
-    return out
+    counts = np.asarray(valid_counts, np.int64).reshape(-1)
+    q, kmax = ids.shape
+    if not np.issubdtype(ids.dtype, np.integer):
+        raise TypeError(f"neighbor ids must be integers, got {ids.dtype}")
+    if len(labels) != q or len(counts) != q:
+        raise ValueError("labels, neighbor_ids and valid_counts must "
+                         "describe the same queries")
+    if q and not (0 <= counts.min() and counts.max() <= kmax):
+        raise ValueError(f"valid_counts must lie in [0, {kmax}]")
+    # live[j]: the queries whose list reaches position j (the positions
+    # from a range: np.arange lets go of the lock, as a sort does)
+    live = np.array(range(kmax), np.int64).reshape(-1, 1) < counts
+    # steps[j]: position j's (id + 1) of every query, contiguous; 0
+    # where the list has ended, and there the multiplier is 1
+    steps = np.empty((kmax, q), np.uint64)
+    np.add(ids.astype(np.int64, copy=False).view(np.uint64).T,
+           np.uint64(1), out=steps)
+    steps *= live
+    prime = np.uint64(FNV_PRIME)
+    primes = np.where(live, prime, np.uint64(1))
+    c = np.asarray(labels, np.int64).reshape(-1).view(np.uint64) \
+        ^ np.uint64(FNV_BASIS)
+    c *= prime
+    for step, mult in zip(steps, primes):
+        c ^= step
+        c *= mult
+    return c

@@ -153,18 +153,23 @@ class _Handler(socketserver.StreamRequestHandler):
                     pieces=pieces,
                     **({"rid": rid} if rid else {}), **_batch_arg(req))
                 w0, c0 = time.perf_counter(), obs_trace.thread_cpu()
-                data = protocol.encode(resp)
-                self.wfile.write(data)
+                # encoded and written a piece at a time: each write
+                # hands the interpreter lock over (protocol.encode_pieces)
+                nbytes = writes = 0
+                for piece in protocol.encode_pieces(resp):
+                    self.wfile.write(piece)
+                    nbytes += len(piece)
+                    writes += 1
                 self.wfile.flush()
                 w1, c1 = time.perf_counter(), obs_trace.thread_cpu()
                 if req is not None and req.kind == "query":
                     telemetry.registry().histogram(
                         "serve.phase_ms.write", unit="ms").observe(
                             (w1 - w0) * 1e3)
-                    daemon.record_respond(req, len(data))
+                    daemon.record_respond(req, nbytes)
                 obs_trace.complete_at(
                     "serve.phase.write", w0, w1, _cpu_between(c0, c1),
-                    bytes=len(data),
+                    bytes=nbytes, pieces=writes,
                     **({"rid": rid} if rid else {}),
                     **_batch_arg(req))
             finally:
@@ -384,8 +389,8 @@ class ServeDaemon:
         clock pair serve_line kept: recorded by whoever holds the
         response next, once it knows what the span should say of the
         work: ``k`` (the request's largest: ``query_response`` folds k
-        ids into every checksum and, on a ``debug`` request, makes k
-        ids and k distances a query Python numbers) and, where the
+        positions into the checksums and, on a ``debug`` request, makes
+        k ids and k distances a query Python numbers) and, where the
         response went onto a socket, the ``bytes`` it encoded to."""
         if not obs_trace.sinks_active():
             return

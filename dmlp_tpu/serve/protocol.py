@@ -60,11 +60,12 @@ import json
 import re
 import socket
 import time
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
 
 import numpy as np
 
 from dmlp_tpu.io import native
+from dmlp_tpu.io.checksum import fnv1a_checksum_batch
 from dmlp_tpu.obs import trace as obs_trace
 from dmlp_tpu.serve.batching import Request
 
@@ -76,6 +77,19 @@ PROTOCOL_VERSION = 1
 #: bytes) so an oversized line never buffers past the cap; the
 #: re-check in parse_request covers non-socket callers.
 MAX_LINE_BYTES = 64 << 20
+
+#: the size a response line is encoded and written in pieces of
+#: (:func:`encode_pieces`), about what one of :class:`LineReader`'s
+#: receives brings of a large line: each piece is a socket write, which
+#: hands the interpreter lock over
+PIECE_BYTES = 1 << 20
+
+#: numbers after which :func:`encode_pieces` closes a group of rows, one
+#: ``json.dumps`` call: the C encoder cannot hand the interpreter lock
+#: over inside a call, and a float takes it about a microsecond, so a
+#: call is kept to a fraction of a millisecond (a row of 1000 ids alone,
+#: 52 rows of 10)
+_GROUP_NUMBERS = 512
 
 #: per-request row cap of the ``corpus`` read op (bounds one response
 #: line; replay loops page through larger ranges)
@@ -313,22 +327,35 @@ def _rid_echo(req: Request, out: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def _checksums(labels: np.ndarray, results: List) -> np.ndarray:
+    """The contract checksum of each QueryResult
+    (``QueryResult.checksum()``: its label, then every id of its
+    list), from the lists stacked into one padded array: 1024 x 1000
+    ids are ~1000 array steps, not a million Python ones."""
+    counts = [len(r.neighbor_ids) for r in results]
+    ids = np.full((len(results), max(counts, default=0)), -1, np.int64)
+    for row, r, n in zip(ids, results, counts):
+        row[:n] = r.neighbor_ids
+    return fnv1a_checksum_batch(labels, ids, counts)
+
+
 def query_response(req: Request, debug: bool = False) -> Dict[str, Any]:
-    """The completed query Request -> its wire response."""
+    """The completed query Request -> its wire response. Numbers leave
+    their arrays by ``tolist()`` (the Python ints and floats
+    ``json.dumps`` writes), the lists a query's at a time."""
     if req.error is not None:
         return _rid_echo(req, {"id": req.req_id, "ok": False,
                                "error": req.error})
+    labels = np.array([r.predicted_label for r in req.results], np.int64)
     out: Dict[str, Any] = {
         "id": req.req_id, "ok": True,
-        "labels": [int(r.predicted_label) for r in req.results],
-        "checksums": [int(r.checksum()) for r in req.results],
+        "labels": labels.tolist(),
+        "checksums": _checksums(labels, req.results).tolist(),
         "latency_ms": round(req.latency_ms, 3),
     }
     if debug or req.debug:
-        out["neighbors"] = [[int(i) for i in r.neighbor_ids]
-                            for r in req.results]
-        out["dists"] = [[float(d) for d in r.neighbor_dists]
-                        for r in req.results]
+        out["neighbors"] = [r.neighbor_ids.tolist() for r in req.results]
+        out["dists"] = [r.neighbor_dists.tolist() for r in req.results]
     return _rid_echo(req, out)
 
 
@@ -351,6 +378,72 @@ def corpus_response(req: Request) -> Dict[str, Any]:
                            **(req.payload or {})})
 
 
+#: ``json.dumps(obj, separators=(",", ":"), sort_keys=True)``, built once
+_dumps = json.JSONEncoder(separators=(",", ":"), sort_keys=True).encode
+
+
+def _groups(rows: List) -> Iterator[List]:
+    """``rows`` in order, in slices that each hold ``_GROUP_NUMBERS``
+    numbers or little more (a row that is a list counts its length)."""
+    start = numbers = 0
+    for i, row in enumerate(rows):
+        numbers += len(row) if isinstance(row, list) else 1
+        if numbers >= _GROUP_NUMBERS:
+            yield rows[start:i + 1]
+            start, numbers = i + 1, 0
+    if start < len(rows):
+        yield rows[start:]
+
+
+def _texts(obj: Dict[str, Any]) -> Iterator[str]:
+    """The wire text of ``obj`` in order, in fragments none of which
+    took one ``json.dumps`` call long: a member that is a list of lists
+    (``neighbors``, ``dists``, a ``corpus`` read's ``rows``) a group of
+    its rows a call, the members between two such in one call. Keys are
+    strings, as every wire object's are, in sorted order."""
+    sep, plain = "{", {}
+    for key in sorted(obj):
+        value = obj[key]
+        if not (isinstance(value, list) and value
+                and isinstance(value[0], list)):
+            plain[key] = value
+            continue
+        if plain:
+            yield sep + _dumps(plain)[1:-1]
+            sep, plain = ",", {}
+        sep += _dumps(key) + ":["
+        for group in _groups(value):
+            yield sep + _dumps(group)[1:-1]
+            sep = ","
+        sep = "],"
+    if plain or sep == "{":
+        yield sep + _dumps(plain)[1:-1] + "}\n"
+    else:
+        yield "]}\n"
+
+
+def encode_pieces(obj: Dict[str, Any]) -> Iterator[bytes]:
+    """A wire line in pieces of about ``PIECE_BYTES``: joined, they
+    are ``json.dumps(obj, separators=(",", ":"), sort_keys=True)`` and
+    a newline, to the byte. A ``debug`` response at k = 1000 is 26 MB;
+    encoded by one ``json.dumps`` its handler holds the interpreter
+    lock for over a second, the batcher thread beside it waits that
+    long to take it back after a NumPy call, and the chip waits for the
+    batcher. Here the text is made a fragment at a time
+    (:func:`_texts`) and leaves as soon as a piece is full, for the
+    caller to write before the next is encoded. A small line is one
+    ``json.dumps`` and one piece."""
+    held: List[str] = []
+    size = 0
+    for text in _texts(obj):
+        held.append(text)
+        size += len(text)       # ASCII: json escapes the rest
+        if size >= PIECE_BYTES:
+            yield "".join(held).encode()
+            held, size = [], 0
+    if held:
+        yield "".join(held).encode()
+
+
 def encode(obj: Dict[str, Any]) -> bytes:
-    return (json.dumps(obj, separators=(",", ":"),
-                       sort_keys=True) + "\n").encode()
+    return b"".join(encode_pieces(obj))

@@ -191,6 +191,7 @@ def test_the_multipass_path_says_what_it_did(served, kind, name):
     assert respond["args"]["queries"] == len(ks)
     assert respond["args"]["bytes"] == write["args"]["bytes"] \
         > sum(ks) * 4
+    assert write["args"]["pieces"] == 1     # under a megabyte: one write
     assert respond["args"]["rid"] == f"r-{name}"
 
     # what the driver flagged: real-valued rows clear every floor; on
