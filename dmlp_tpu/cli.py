@@ -207,8 +207,9 @@ def main(argv: Optional[Sequence[str]] = None,
                              "against a zero vector (--debug prints the "
                              "angular distance 1 - s). The golden model "
                              "(--engine golden) and the serving daemon's "
-                             "one-chip extract path have the ip and cosine "
-                             "forms; the batch engines refuse them by name")
+                             "extract path (one chip or --mesh) have the ip "
+                             "and cosine forms; the batch engines refuse "
+                             "them by name")
     parser.add_argument("--phase-times", action="store_true",
                         help="per-phase ms breakdown on stderr (extension)")
     parser.add_argument("--pallas", action="store_true",

@@ -144,12 +144,19 @@ class EngineConfig:
         A property of the corpus, never of a request: every program an
         engine compiles is keyed on it, and inside the program the
         ordered quantity under "ip" and "cosine" is -s so that every
-        list still ascends. The one-chip serving engine's extract path
-        (serve.engine.ResidentEngine) and the golden model have the
-        "ip" and "cosine" forms (cosine is the ip kernel over rows and
-        queries normalised in float64 at staging: kernel_score); every
-        other engine refuses them at construction by name
-        (require_score): none answers an ip or cosine corpus in L2.
+        list still ascends. The two serving engines' extract paths
+        (serve.engine.ResidentEngine on one chip,
+        fleet.mesh_engine.MeshResidentEngine over a mesh) and the
+        golden model have the "ip" and "cosine" forms (cosine is the ip
+        kernel over rows and queries normalised in float64 at staging:
+        kernel_score). The batch engines (engine.single's solve,
+        engine.sharded's ShardedEngine and RingEngine, engine.auto's
+        AutoShardedEngine) and the multi-host feed
+        (parallel.distributed) refuse them at construction by name
+        (require_score), as does a serving engine's bucket off the
+        extract path (the streaming select, the multipass driver, the
+        mesh engine's monolithic stream path): none answers an ip or
+        cosine corpus in L2.
     """
 
     AUTO_SELECT_THRESHOLD = 8192
@@ -198,9 +205,10 @@ class EngineConfig:
             raise ValueError(
                 f"{engine} has no score={self.score!r} form (it ranks by "
                 f"{' | '.join(scores)}): serve an inner-product or a "
-                "cosine corpus through the one-chip daemon's extract path "
-                f"(python -m dmlp_tpu.serve --pallas --score {self.score}) "
-                "or the golden model (--engine golden)")
+                "cosine corpus through a daemon's extract path (python -m "
+                f"dmlp_tpu.serve --pallas --score {self.score}, on one "
+                "chip or with --mesh RxC) or the golden model (--engine "
+                "golden)")
 
     def resolve_dtype(self) -> str:
         """Concrete staging dtype ("float32" | "bfloat16") for this run.

@@ -403,6 +403,19 @@ def boundary_hazard(kth: np.ndarray, last: np.ndarray,
     return np.isfinite(last) & (last <= kth + eps)
 
 
+def boundary_clearance(kth: np.ndarray, last: np.ndarray,
+                       eps: np.ndarray) -> float | None:
+    """How many times its bound a batch's TIGHTEST query clears the
+    window: the smallest (last - kth) / eps over the queries whose list
+    is full and whose bound is positive; 1 or less is a flag
+    (boundary_hazard). None where no query has both. What the hazard
+    spans report as ``clear_min``."""
+    full = np.isfinite(last) & (eps > 0)
+    if not full.any():
+        return None
+    return round(float(((last - kth)[full] / eps[full]).min()), 4)
+
+
 #: How many widths a batch's bands are rounded up to (band_widths):
 #: queries of one width are rescored as one rectangle, so a batch costs
 #: at most this many gathers' fixed parts, and a query reads at most a

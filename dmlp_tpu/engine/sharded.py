@@ -91,13 +91,18 @@ class ShardedEngine:
 
     _merge_strategy = "allgather"
 
+    #: the scores this engine ranks by (config.EngineConfig.score): the
+    #: batch mesh engines (this one, the ring and the compiler-sharded
+    #: one) and the multi-host feed know squared L2 alone; the mesh
+    #: daemon's engine (fleet.mesh_engine.MeshResidentEngine) has the
+    #: product scores on its extract path and says so
+    _scores: Tuple[str, ...] = ("l2",)
+
     def __init__(self, config: EngineConfig = EngineConfig(mode="sharded"),
                  mesh: Optional[Mesh] = None):
-        # The mesh engines (this one, the ring and the compiler-sharded
-        # one, the mesh daemon's, the multi-host feed) rank by squared
-        # L2 alone: none answers an inner-product corpus.
+        # none answers an inner-product or a cosine corpus in L2
         config.require_score(
-            f"{type(self).__module__}.{type(self).__name__}")
+            f"{type(self).__module__}.{type(self).__name__}", self._scores)
         self.config = config
         self.mesh = mesh if mesh is not None else make_mesh(config.mesh_shape)
         self._staging = config.resolve_dtype()

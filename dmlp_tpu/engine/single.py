@@ -31,6 +31,7 @@ import numpy as np
 
 from dmlp_tpu.config import EngineConfig
 from dmlp_tpu.engine.finalize import (band_widths, boundary_band,
+                                      boundary_clearance,
                                       boundary_hazard, finalize_host,
                                       lowp_eps, repair_boundary_overflow,
                                       rescore_f64, staging_eps)
@@ -1521,12 +1522,9 @@ class SingleChipEngine:
                     if exact:
                         widths = band_widths(
                             boundary_band(dists, ids, kth, eps))
-                    # How many times its bound the window clears, for
-                    # the batch's tightest query: 1 or less is a flag.
-                    full = np.isfinite(last) & (eps > 0)
-                    if full.any():
-                        hz.set(clear_min=round(float(
-                            ((last - kth)[full] / eps[full]).min()), 4))
+                    clear = boundary_clearance(kth, last, eps)
+                    if clear is not None:
+                        hz.set(clear_min=clear)
                 # Multi-pass extraction's own loss detectors (stall/
                 # shortfall, _solve_extract_multipass) join the standard
                 # boundary test.

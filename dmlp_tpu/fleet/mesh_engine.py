@@ -55,11 +55,21 @@ ways a persistent multi-chip server needs:
   asserted by the compile counter like the single-chip resident
   engine).
 
+- **Three scores on the extract path.** ``config.score`` "l2", "ip" or
+  "cosine" reaches the shards as the kernel's ``score`` static, the
+  staged rows and queries (x / |x| and q / |q| under "cosine", taken in
+  float64 on the host) and the ``score`` argument of every bound and of
+  the float64 rescore: the one-chip resident engine's forms, shared
+  through ``ResidentServingCore``; nothing here is a second
+  implementation of them. The merge orders what the kernel emits.
+
 Configs whose plan does not select the extraction kernel fall back to
 a resident MONOLITHIC layout: the full capacity-padded
 ``(R * shard_rows, A)`` dataset + label/id arrays staged once, solved
 by the engines' merged ``_fn`` program (the allgather/ring merge runs
-inside it). Both layouts share the one global-row-id contract.
+inside it). Both layouts share the one global-row-id contract. That
+program ranks by squared L2 alone: under "ip" or "cosine" a corpus or a
+bucket that would take it is refused by name (``_ensure_monolithic``).
 """
 
 from __future__ import annotations
@@ -75,16 +85,17 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dmlp_tpu.config import EngineConfig
 from dmlp_tpu.engine.finalize import (band_widths, boundary_band,
+                                      boundary_clearance,
                                       boundary_hazard, finalize_host,
                                       kth_column, lowp_eps,
                                       repair_boundary_overflow,
                                       rescore_f64, staging_eps)
 from dmlp_tpu.engine.sharded import (ShardedEngine, _chunk_span,
                                      _np_staging_dtype)
-from dmlp_tpu.engine.single import (_BF16_AUTO_K_CAP, MeasuredIters,
+from dmlp_tpu.engine.single import (MeasuredIters, SingleChipEngine,
                                     flush_measured_iters, plan_chunks,
                                     resilient_get, resolve_kcap, round_up)
-from dmlp_tpu.io.grammar import KNNInput, Params
+from dmlp_tpu.io.grammar import KNNInput
 from dmlp_tpu.io.report import QueryResult
 from dmlp_tpu.obs import counters as obs_counters
 from dmlp_tpu.obs import memwatch, telemetry
@@ -147,7 +158,32 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
     collective ("allgather" | "ring" | "auto" — "auto" hands the
     cross-shard merge to the GSPMD partitioner via the engines' "gspmd"
     chunk-merge program; no analytic comms model, see obs.comms).
+
+    **Score** (``config.score``): the extract path's buckets rank by
+    squared L2, by inner product or by cosine, through the forms the
+    one-chip engine has and no other: the fold takes the kernel's
+    ``score`` static as it takes ``precision`` (``_kernel_statics``),
+    staging and ingest put x / |x| on the shards under "cosine" with
+    the float64 norms kept beside the host rows (``_staged_rows``,
+    ``_host_norms``: ``ResidentServingCore``'s), a batch's queries are
+    normalised the same way (``_staged_queries``), and the hazard test,
+    the band, the float64 rescore and the host oracle are called with
+    the score. The merge needs no form of its own: it re-selects by
+    (value ascending, id descending) whatever the kernel emits, and
+    under a product score that is -q.x, so every list still ascends.
+    The block-prune scorer's bounds are squared L2's and it does not
+    run under another score (every fold dense, as on one chip); the
+    monolithic ``stream`` layout ranks by squared L2 alone, so a corpus
+    or a bucket that would fall to it is refused by name, and a k past
+    one kernel pass at admission (``max_k``).
     """
+
+    _scores = ("l2", "ip", "cosine")
+    _span_ns = "fleet"
+    _l2_only = ("fleet.mesh_engine.MeshResidentEngine's monolithic "
+                "stream path")
+    #: slots of the kernel's widest single pass (the one-chip engines')
+    _MP_KC = SingleChipEngine._MP_KC
 
     def __init__(self, corpus: KNNInput, config: EngineConfig = None,
                  mesh=None, mesh_shape: Optional[Tuple[int, int]] = None,
@@ -222,6 +258,7 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             self._host_labels = np.full(self.capacity_rows, -1, np.int32)
             self._host_labels[:n] = corpus.labels
         self.n_real = n
+        self._init_host_norms(self.capacity_rows)
         with obs_span("fleet.init.row_hashes", rows=n):
             self._sig_init()
         # Corpus max squared norm for the boundary-repair eps — cached
@@ -249,7 +286,10 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         if self._extract_ok:
             self._stage_chunks()
         else:
-            self._ensure_monolithic()
+            self._ensure_monolithic(
+                "a corpus that does not take the extract path: no "
+                f"use_pallas, or no more than {cfg.AUTO_SELECT_THRESHOLD} "
+                "rows a shard under select='auto'")
         self._check_placement()
 
         # -- resident per-(shard, chunk) summaries ---------------------------
@@ -310,7 +350,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
 
     def _chunk_host(self, t: int) -> np.ndarray:
         """Chunk ``t``'s (R * chunk_rows, A) staging buffer from the
-        current host rows (each shard's span in its slot, pad zeroed)."""
+        current host rows as the device holds them (``_staged_rows``:
+        x / |x| under "cosine"), each shard's span in its slot, pad
+        zeroed."""
         r, _ = self.mesh.devices.shape
         cr = self._chunk_rows
         sdt = _np_staging_dtype(self._staging)
@@ -318,14 +360,15 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         for rr in range(r):
             lo, hi = self._block_span(rr, t)
             if hi > lo:
-                a[rr * cr: rr * cr + (hi - lo)] = self._host_attrs[lo:hi]
+                self._staged_rows(lo, hi, a[rr * cr:(rr + 1) * cr])
         return a
 
     def _stage_chunks(self) -> None:
         r, _ = self.mesh.devices.shape
         with obs_span("fleet.stage_resident", chunks=self._nchunks,
                       mesh=list(self.mesh.devices.shape),
-                      norm_bytes=self._nchunks * r * self._chunk_rows * 4):
+                      norm_bytes=self._nchunks * r * self._chunk_rows * 4,
+                      score=self.config.score):
             # Allocated on the devices, then filled a chunk at a time by
             # a donated update (ResidentEngine._ensure_chunks' way): a
             # stack of separately staged chunks would hold the corpus
@@ -351,9 +394,15 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             jax.device_put(np.int32(t), self._rsh))
         self.norm_restages += 1
 
-    def _ensure_monolithic(self) -> None:
+    def _ensure_monolithic(self, why: str = "a bucket off the extract "
+                           "path") -> None:
         """The streaming paths' resident layout: full capacity-padded
-        (attrs, labels, ids) staged once, sharded over "data"."""
+        (attrs, labels, ids) staged once, sharded over "data". The
+        engines' merged program that reads it ranks by squared L2
+        alone: under another score whatever asks for it (``why``) is
+        refused by name."""
+        self.config.require_score(
+            f"{self._l2_only} ({why})")
         if self._mono is not None:
             return
         sdt = _np_staging_dtype(self._staging)
@@ -377,7 +426,12 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
     def _build_summaries(self) -> None:
         from dmlp_tpu.ops import summaries as osum
         r, _ = self.mesh.devices.shape
-        if r * self._nchunks <= 1 or not osum.prune_enabled():
+        # The norm band and the box gap are lower bounds of a squared
+        # L2: under another score the scorer does not run (no
+        # fleet.prune_score in the cycle) and every fold is dense, as on
+        # one chip.
+        if r * self._nchunks <= 1 or not osum.prune_enabled() \
+                or self.config.score != "l2":
             return
         with obs_span("fleet.summary_build", blocks=r * self._nchunks):
             self._summ = osum.build_summaries(self._host_attrs,
@@ -443,13 +497,6 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             return QUERY_TILE
         return 8
 
-    @property
-    def max_k(self) -> int:
-        cap = self.capacity_rows
-        if self._staging == "bfloat16":
-            cap = min(cap, _BF16_AUTO_K_CAP)
-        return cap
-
     def bucket_shape(self, nq: int, kmax: int) -> Tuple[int, int]:
         _r, c = self.mesh.devices.shape
         qloc = query_bucket(max(-(-max(nq, 1) // c), 1),
@@ -481,34 +528,27 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         qloc = qpad // c
         kcap = self._kcap_for(kb)
         path = "stream"
-        if self._extract_ok and kcap <= 512:
+        if self._extract_ok and kcap <= self._MP_KC:
             from dmlp_tpu.ops import pallas_fused
             kern, _ = pallas_fused.resolve_topk_kernel(
                 qloc, self._chunk_rows, self.num_attrs, kcap)
             if kern is not None:
                 path = "extract"
+        entry = _MeshBucket(qpad, kb, kcap, path, qloc)
         if path == "stream":
-            self._ensure_monolithic()
-        return _MeshBucket(qpad, kb, kcap, path, qloc)
+            self._ensure_monolithic(
+                f"bucket {entry.key}: {kcap} slots the kernel does not "
+                "tile")
+        return entry
 
     # -- resident solves ------------------------------------------------------
 
-    def _batch_input(self, query_attrs: np.ndarray,
-                     ks: np.ndarray) -> KNNInput:
-        nq = len(ks)
-        return KNNInput(
-            Params(self.n_real, nq, self.num_attrs),
-            self._host_labels[:self.n_real],
-            self._host_attrs[:self.n_real],
-            np.asarray(ks, np.int32),
-            np.asarray(query_attrs, np.float64))
-
     def _stage_queries(self, inp: KNNInput, qpad: int):
-        nq = inp.params.num_queries
-        q = np.zeros((qpad, self.num_attrs), np.float32)
-        q[:nq] = inp.query_attrs
-        np_dtype = self._np_dtype()
-        return jax.device_put(q.astype(np_dtype, copy=False), self._qsh)
+        """A micro-batch's query rows over the query axis
+        (``_staged_queries``: q / |q| under "cosine")."""
+        q = self._staged_queries(inp, qpad, self.num_attrs)
+        return jax.device_put(q.astype(self._np_dtype(), copy=False),
+                              self._qsh)
 
     def _block_rows(self) -> np.ndarray:
         """(R, T) resident rows of every (shard, chunk) block."""
@@ -592,9 +632,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             pend.extract_impl = impl
             pend.variant = {**pallas_fused.variant_stamp(
                 k, cr, entry.qloc, na, prec, self._staging),
-                "norms": "staged"}
+                "norms": "staged", "score": self.config.score}
             kern = _kernel_statics(impl, k, cr, entry.qloc, na, prec,
-                                   self._interpret)
+                                   self._interpret, self.config.score)
             q_dev = self._stage_queries(inp, entry.qpad)
             fold = self._resident_fold_fn(kern)
         keep_m, prune_stats = self._prune_live(inp, entry, q_dev)
@@ -819,7 +859,9 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # float64 host corpus inside this span (a daemon's first batch).
         suspects = np.zeros(0, np.int64)
         exact, widths = self.config.exact, None
-        with obs_span("fleet.hazard", rows=n, **self._rid_args()) as hz:
+        score = self.config.score
+        with obs_span("fleet.hazard", rows=n, score=score,
+                      **self._rid_args()) as hz:
             if pend.select in ("sort", "topk", "seg", "extract") \
                     and dists.shape[1] < n:
                 # Same per-shard-truncation hazard test as the batch
@@ -830,20 +872,23 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
                 dn_max = self._dn_max()
                 qn = np.einsum("qa,qa->q", inp.query_attrs,
                                inp.query_attrs)
-                eps = staging_eps(
-                    np.asarray(dists[:, -1], np.float64), qn, dn_max,
-                    self._staging, self.num_attrs)
+                last = dists[:, -1]
+                eps = staging_eps(last, qn, dn_max, self._staging,
+                                  self.num_attrs, score)
                 if pend.select == "extract":
                     # A first pass that drops products ("bf16x3",
                     # "bf16") perturbs device distances beyond the
                     # staging model — widen the hazard test by the
                     # bound of the form that ran (finalize.lowp_eps;
                     # zero for the one HIGHEST dot).
-                    eps = eps + lowp_eps(prec, qn, dn_max)
+                    eps = eps + lowp_eps(prec, qn, dn_max, score)
                 kth = kth_column(dists, inp.ks)
                 suspects = np.nonzero(
-                    boundary_hazard(kth, dists[:, -1], eps))[0]
+                    boundary_hazard(kth, last, eps))[0]
                 hz.set(flagged=int(suspects.size))
+                clear = boundary_clearance(kth, last, eps)
+                if clear is not None:
+                    hz.set(clear_min=clear)
                 if exact:
                     # the rescore's band, by the same bound
                     widths = band_widths(
@@ -854,24 +899,35 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         # mode), as single.finalize / single.rescore say it
         rows = 0 if not exact else ids.size if widths is None \
             else int(widths.sum())
+        gather = rows * self.num_attrs * 8
+        band_pct = round(100.0 * rows / max(ids.size, 1), 3)
         with obs_span("fleet.finalize", exact=exact, queries=nq,
                       slots=dists.shape[1], rows=rows,
-                      gather_bytes=rows * self.num_attrs * 8,
-                      band_pct=round(100.0 * rows / max(ids.size, 1), 3),
+                      gather_bytes=gather, band_pct=band_pct, score=score,
                       **self._rid_args()) as sp:
             if exact:
+                # The float64 gather-and-score, under a span of its own
+                # (single.rescore's twin): the part of the finalize the
+                # score changes (difference form; under "ip" the product
+                # alone, under "cosine" the product over the norms).
                 pend.rescore_slots += ids.size
                 pend.rescore_rows += rows
-                dists = rescore_f64(np.asarray(ids, np.int64),
-                                    inp.query_attrs, inp.data_attrs,
-                                    widths=widths)
+                with obs_span("fleet.rescore", queries=nq,
+                              slots=dists.shape[1], rows=rows,
+                              bytes=gather, band_pct=band_pct,
+                              score=score, **self._rid_args()):
+                    dists = rescore_f64(np.asarray(ids, np.int64),
+                                        inp.query_attrs, inp.data_attrs,
+                                        score=score, widths=widths,
+                                        data_norms=inp.data_norms)
             results = finalize_host(dists, labels, ids, inp.ks,
                                     inp.query_attrs, inp.data_attrs,
-                                    exact=False)
+                                    exact=False, score=score)
             if suspects.size:
                 with obs_span("fleet.repair", queries=int(suspects.size),
                               **self._rid_args()):
-                    repair_boundary_overflow(results, suspects, inp)
+                    repair_boundary_overflow(results, suspects, inp,
+                                             score=score)
                 pend.repairs += int(suspects.size)
                 # every flagged query is the host oracle's here: the
                 # device retry is the one-chip engine's
@@ -927,6 +983,8 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
         with obs_span("fleet.ingest", rows=m, corpus_rows=new_n):
             self._host_attrs[at:end] = attrs
             self._host_labels[at:end] = labels
+            if self._host_norms is not None:
+                self._note_norms(at, end)
             self.n_real = new_n
             self._note_ingested_norms(attrs)
             self._sig_update(at, end)
@@ -1037,4 +1095,5 @@ class MeshResidentEngine(ResidentServingCore, ShardedEngine):
             "mesh": [r, c],
             "merge": self._merge_strategy,
             "shard_rows": self._shard_rows,
+            "score": self.config.score,
         }

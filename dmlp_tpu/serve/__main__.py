@@ -99,10 +99,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "ties; 'dists' then carries s itself); cosine = "
                         "LARGEST q.x / (|q||x|), 0 against a zero vector "
                         "(same order; 'dists' carries the angular "
-                        "distance 1 - s, ascending). The one-chip extract "
-                        "path (--pallas, more than 8192 rows or --select "
-                        "extract) has the ip and cosine forms; --mesh and "
-                        "the streaming select refuse them by name")
+                        "distance 1 - s, ascending). The extract path "
+                        "(--pallas, more than 8192 rows a chip or --select "
+                        "extract), on one chip or with --mesh, has the ip "
+                        "and cosine forms; a k past one kernel pass, the "
+                        "streaming select and the mesh's monolithic stream "
+                        "path refuse them by name")
     p.add_argument("--data-block", type=int, default=None)
     p.add_argument("--warm-buckets", default=None, metavar="NQxK,...",
                    help="extra shape buckets to compile before ready")

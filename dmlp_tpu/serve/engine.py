@@ -370,9 +370,18 @@ class ResidentServingCore:
     fence and the host's share) and keeps what a solve says of itself
     in the batch's :class:`PendingBatch`.
 
-    Subclass contract: ``bucket_shape``/``_build_bucket``/``max_k``/
-    ``_batch_input``/``_first_half``/``_second_half`` plus the resident
-    state the hooks read; the
+    What a score asks of an engine outside its programs is here too,
+    once: the float64 norms kept beside the host rows and the rows and
+    queries as the device holds them (x / |x| and q / |q| under
+    "cosine": :meth:`_staged_rows`, :meth:`_staged_queries`), the
+    micro-batch's input with those norms (:meth:`_batch_input`) and the
+    serving cap under a score only the extract path has
+    (:attr:`max_k`).
+
+    Subclass contract: ``bucket_shape``/``_build_bucket``/``_kcap_for``/
+    ``_MP_KC``/``_first_half``/``_second_half`` plus the resident
+    state the hooks read (``_host_attrs``, ``_host_labels``, ``n_real``,
+    ``capacity_rows``, ``num_attrs``, ``_staging``, ``config``); the
     subclass implements :meth:`mem_model` (its analytic per-device
     model, batch terms included iff ``nq > 0``) and
     :meth:`batch_model_bytes` (the marginal per-batch terms — the
@@ -398,6 +407,19 @@ class ResidentServingCore:
 
     #: the begun micro-batch finish_batch is handing to solve_batch
     _handed = None
+
+    #: whose spans the shared staging steps are ("serve.normalize_rows"
+    #: on one chip, "fleet.normalize_rows" on a mesh)
+    _span_ns = "serve"
+
+    #: what of this engine ranks by squared L2 alone, as a request past
+    #: the one-pass cap under another score is told (_k_refusal)
+    _l2_only = ("serve.engine.ResidentEngine's multipass driver and "
+                "streaming select")
+
+    #: rows a block of ``_staged_rows``' float64 quotient (its one
+    #: temporary: 100 MB at 1536 attributes)
+    _NORMALIZE_ROWS = 8192
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
@@ -493,6 +515,43 @@ class ResidentServingCore:
         if pend.last_prune is not None:
             self.last_prune_fraction = pend.last_prune["pruned_fraction"]
 
+    def _batch_input(self, query_attrs: np.ndarray,
+                     ks: np.ndarray) -> KNNInput:
+        """A micro-batch as a KNNInput over the resident corpus (host
+        views feed the float64 finalize/repair exactly as a solo solve
+        over the same corpus would)."""
+        nq = len(ks)
+        return KNNInput(
+            Params(self.n_real, nq, self.num_attrs),
+            self._host_labels[:self.n_real],
+            self._host_attrs[:self.n_real],
+            np.asarray(ks, np.int32),
+            np.asarray(query_attrs, np.float64),
+            data_norms=None if self._host_norms is None
+            else self._host_norms[:self.n_real])
+
+    @property
+    def max_k(self) -> int:
+        """Largest per-query k this resident engine serves: the staging
+        dtype's safe cap (bf16 margins blow past the resident layout
+        beyond it), the corpus capacity and, under a score only the
+        extract path has, the last bucket of one kernel pass."""
+        cap = self.capacity_rows
+        if self._staging == "bfloat16":
+            cap = min(cap, _BF16_AUTO_K_CAP)
+        if self.config.score != "l2":
+            cap = min(cap, self._one_pass_max_k)
+        return cap
+
+    @functools.cached_property
+    def _one_pass_max_k(self) -> int:
+        """The largest k whose bucket still plans one kernel pass (a
+        window of at most _MP_KC slots): under a score the paths past
+        it lack (``_l2_only``), the serving cap."""
+        return max((k for k in (1 << p for p in range(
+            self._MP_KC.bit_length())) if self._kcap_for(k)
+            <= self._MP_KC), default=0)
+
     def _check_k(self, inp: KNNInput) -> None:
         kmax = int(inp.ks.max()) if inp.params.num_queries else 0
         if kmax > self.max_k:
@@ -500,7 +559,12 @@ class ResidentServingCore:
 
     def _k_refusal(self, kmax: int) -> str:
         """What a request past the serving cap is told."""
-        return f"k={kmax} beyond the serving cap {self.max_k}"
+        msg = f"k={kmax} beyond the serving cap {self.max_k}"
+        if self.config.score != "l2" and kmax <= self.capacity_rows:
+            msg += (f" under score={self.config.score!r}: {self._l2_only} "
+                    f"(a window past {self._MP_KC} slots) rank by squared "
+                    "L2 alone")
+        return msg
 
     @contextlib.contextmanager
     def _tagged(self, pending):
@@ -681,6 +745,73 @@ class ResidentServingCore:
         end = max(start, min(start + int(count), n))
         return (self._host_labels[start:end].copy(),
                 self._host_attrs[start:end].copy())
+
+    # -- what the device holds of a row and of a query (cosine: x / |x|) -----
+
+    def _init_host_norms(self, host_rows: int) -> None:
+        """A cosine corpus' |x|, float64, beside the host rows (0 past
+        the last): what normalises a staged row and what the rescore
+        and the host oracle divide by (KNNInput.data_norms). None under
+        the other scores."""
+        self._host_norms: Optional[np.ndarray] = None
+        if self.config.score == "cosine":
+            self._host_norms = np.zeros(host_rows, np.float64)
+            self._note_norms(0, self.n_real)
+
+    def _note_norms(self, lo: int, hi: int) -> None:
+        """``_host_norms`` of rows [lo, hi), from the host rows as
+        they now stand (a cosine engine's alone)."""
+        with obs_span(f"{self._span_ns}.normalize_rows", site="norms",
+                      rows=hi - lo,
+                      bytes=(hi - lo) * self.num_attrs * 8) as sp:
+            nrm = row_norms_f64(self._host_attrs[lo:hi])
+            self._host_norms[lo:hi] = nrm
+            zero = int(np.count_nonzero(nrm == 0))
+            sp.set(zero_rows=zero)
+        if zero:
+            telemetry.registry().counter("serve.zero_rows").inc(zero)
+
+    def _staged_rows(self, lo: int, hi: int, out: np.ndarray) -> None:
+        """Host rows [lo, hi) as the device holds them, cast into
+        ``out[:hi - lo, :num_attrs]`` (an array of the staging dtype):
+        the rows themselves, or under "cosine" x / |x|, the quotient
+        taken in float64 and then cast; a zero row stays zero."""
+        na = self.num_attrs
+        if self.config.score != "cosine":
+            out[:hi - lo, :na] = self._host_attrs[lo:hi]
+            return
+        step = self._NORMALIZE_ROWS
+        with obs_span(f"{self._span_ns}.normalize_rows", site="stage",
+                      rows=hi - lo, bytes=(hi - lo) * na * 8) as sp:
+            buf = np.empty((min(step, hi - lo), na), np.float64)
+            nrm = self._host_norms[lo:hi]
+            for a in range(0, hi - lo, step):
+                b = min(a + step, hi - lo)
+                out[a:b, :na] = np.divide(
+                    self._host_attrs[lo + a:lo + b],
+                    np.where(nrm[a:b] > 0, nrm[a:b], 1.0)[:, None],
+                    out=buf[:b - a])
+            sp.set(zero_rows=int(np.count_nonzero(nrm == 0)))
+
+    def _staged_queries(self, inp: KNNInput, qpad: int,
+                        width: int) -> np.ndarray:
+        """A micro-batch's query rows as the device holds them, float32,
+        padded to ``qpad`` rows of ``width`` columns: the rows
+        themselves, or under "cosine" q / |q|, the quotient taken in
+        float64 like a staged row's (a zero query stays zero: it scores
+        0 against every row)."""
+        nq, na = inp.params.num_queries, self.num_attrs
+        q = np.zeros((qpad, width), np.float32)
+        if self.config.score != "cosine":
+            q[:nq, :na] = inp.query_attrs
+            return q
+        with obs_span(f"{self._span_ns}.normalize_queries", queries=nq,
+                      **self._rid_args()) as sp:
+            nrm = row_norms_f64(inp.query_attrs)
+            q[:nq, :na] = inp.query_attrs / np.where(
+                nrm > 0, nrm, 1.0)[:, None]
+            sp.set(zero_queries=int(np.count_nonzero(nrm == 0)))
+        return q
 
     # -- corpus max squared norm (boundary-eps / multipass floors) ----------
 
@@ -874,10 +1005,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     _scores = ("l2", "ip", "cosine")
 
-    #: rows a block of ``_staged_rows``' float64 quotient (its one
-    #: temporary: 100 MB at 1536 attributes)
-    _NORMALIZE_ROWS = 8192
-
     def __init__(self, corpus: KNNInput, config: EngineConfig = None,
                  capacity: Optional[int] = None, gate_carry: bool = True):
         super().__init__(config or EngineConfig())
@@ -950,13 +1077,7 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             self._host_labels = np.full(host_rows, -1, np.int32)
             self._host_labels[:n] = corpus.labels
         self.n_real = n
-        # A cosine corpus' |x|, float64, beside the rows (0 past the
-        # last): what normalises a staged row and what the rescore and
-        # the host oracle divide by (KNNInput.data_norms).
-        self._host_norms: Optional[np.ndarray] = None
-        if cfg.score == "cosine":
-            self._host_norms = np.zeros(host_rows, np.float64)
-            self._note_norms(0, n)
+        self._init_host_norms(host_rows)
         with obs_span("serve.init.row_hashes", rows=n):
             self._sig_init()
         # The corpus max-sq-norm the hazard test and the multipass floor
@@ -1025,38 +1146,8 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
             return QUERY_TILE
         return 8
 
-    @property
-    def max_k(self) -> int:
-        """Largest per-query k this resident engine serves: the staging
-        dtype's safe cap (bf16 margins blow past the resident layout
-        beyond it) and the corpus capacity."""
-        cap = self.capacity_rows
-        if self._staging == "bfloat16":
-            cap = min(cap, _BF16_AUTO_K_CAP)
-        if self.config.score != "l2":
-            cap = min(cap, self._one_pass_max_k)
-        return cap
-
-    @functools.cached_property
-    def _one_pass_max_k(self) -> int:
-        """The largest k whose bucket still plans one kernel pass (a
-        window of at most _MP_KC slots): under a score the multipass
-        driver and the streaming select lack, the serving cap."""
-        return max((k for k in (1 << p for p in range(
-            self._MP_KC.bit_length())) if self._kcap_for(k)
-            <= self._MP_KC), default=0)
-
     def bucket_shape(self, nq: int, kmax: int) -> Tuple[int, int]:
         return (query_bucket(nq, self.query_granule), k_bucket(kmax))
-
-    def _k_refusal(self, kmax: int) -> str:
-        msg = super()._k_refusal(kmax)
-        if self.config.score != "l2" and kmax <= self.capacity_rows:
-            msg += (f" under score={self.config.score!r}: "
-                    "serve.engine.ResidentEngine's multipass driver and "
-                    "streaming select (a window past "
-                    f"{self._MP_KC} slots) rank by squared L2 alone")
-        return msg
 
     def _kcap_for(self, kb: int) -> int:
         return resolve_kcap(self.config, kb, self._stream_select,
@@ -1217,40 +1308,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
         telemetry.registry().counter("prune.summary_rebuilds").inc(
             len(blocks))
 
-    def _note_norms(self, lo: int, hi: int) -> None:
-        """``_host_norms`` of rows [lo, hi), from the host rows as
-        they now stand (a cosine engine's alone)."""
-        with obs_span("serve.normalize_rows", site="norms", rows=hi - lo,
-                      bytes=(hi - lo) * self.num_attrs * 8) as sp:
-            nrm = row_norms_f64(self._host_attrs[lo:hi])
-            self._host_norms[lo:hi] = nrm
-            zero = int(np.count_nonzero(nrm == 0))
-            sp.set(zero_rows=zero)
-        if zero:
-            telemetry.registry().counter("serve.zero_rows").inc(zero)
-
-    def _staged_rows(self, lo: int, hi: int, out: np.ndarray) -> None:
-        """Host rows [lo, hi) as the device holds them, cast into
-        ``out[:hi - lo, :num_attrs]`` (an array of the staging dtype):
-        the rows themselves, or under "cosine" x / |x|, the quotient
-        taken in float64 and then cast; a zero row stays zero."""
-        na = self.num_attrs
-        if self.config.score != "cosine":
-            out[:hi - lo, :na] = self._host_attrs[lo:hi]
-            return
-        step = self._NORMALIZE_ROWS
-        with obs_span("serve.normalize_rows", site="stage", rows=hi - lo,
-                      bytes=(hi - lo) * na * 8) as sp:
-            buf = np.empty((min(step, hi - lo), na), np.float64)
-            nrm = self._host_norms[lo:hi]
-            for a in range(0, hi - lo, step):
-                b = min(a + step, hi - lo)
-                out[a:b, :na] = np.divide(
-                    self._host_attrs[lo + a:lo + b],
-                    np.where(nrm[a:b] > 0, nrm[a:b], 1.0)[:, None],
-                    out=buf[:b - a])
-            sp.set(zero_rows=int(np.count_nonzero(nrm == 0)))
-
     def _restage_chunk(self, c: int) -> None:
         sdt = np_staging_dtype(self._staging)
         cr = self._ex_chunk_rows
@@ -1345,21 +1402,6 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     # -- resident solves ------------------------------------------------------
 
-    def _batch_input(self, query_attrs: np.ndarray,
-                     ks: np.ndarray) -> KNNInput:
-        """A micro-batch as a KNNInput over the resident corpus (host
-        views feed the float64 finalize/repair exactly as a solo solve
-        over the same corpus would)."""
-        nq = len(ks)
-        return KNNInput(
-            Params(self.n_real, nq, self.num_attrs),
-            self._host_labels[:self.n_real],
-            self._host_attrs[:self.n_real],
-            np.asarray(ks, np.int32),
-            np.asarray(query_attrs, np.float64),
-            data_norms=None if self._host_norms is None
-            else self._host_norms[:self.n_real])
-
     def _solve_resident_stream(self, pend: PendingBatch,
                                entry: _Bucket) -> Tuple[TopK, int]:
         # no path answers an inner-product corpus in L2
@@ -1422,21 +1464,10 @@ class ResidentEngine(ResidentServingCore, SingleChipEngine):
 
     def _stage_batch_queries(self, inp: KNNInput, qpad: int):
         """A micro-batch's query rows on the device, padded to the
-        bucket's rows and to the resident stack's width; under "cosine"
-        q / |q|, the quotient taken in float64 like a staged row's
-        (a zero query stays zero: it scores 0 against every row)."""
-        nq = inp.params.num_queries
-        q = np.zeros((qpad, self._ex_attrs), np.float32)
-        if self.config.score != "cosine":
-            q[:nq, :self.num_attrs] = inp.query_attrs
-            return stage_put(q, self._staging)
-        with obs_span("serve.normalize_queries", queries=nq,
-                      **self._rid_args()) as sp:
-            nrm = row_norms_f64(inp.query_attrs)
-            q[:nq, :self.num_attrs] = inp.query_attrs / np.where(
-                nrm > 0, nrm, 1.0)[:, None]
-            sp.set(zero_queries=int(np.count_nonzero(nrm == 0)))
-        return stage_put(q, self._staging)
+        bucket's rows and to the resident stack's width
+        (``_staged_queries``: q / |q| under "cosine")."""
+        return stage_put(self._staged_queries(inp, qpad, self._ex_attrs),
+                         self._staging)
 
     def _variant_stamp(self, kc: int, qpad: int,
                        prec: str) -> Dict[str, Any]:

@@ -1,13 +1,16 @@
 """Every engine without the inner-product form refuses ``score="ip"``
 by name (PR 46), and ``score="cosine"`` (PR 49: the ip kernel form over
 operands normalised at staging, so whatever lacks the one lacks the
-other): at construction (the batch solve, the mesh engines,
-the mesh daemon, a resident engine whose corpus takes the streaming
-select), at admission (a k whose window passes the kernel's one pass:
-the multipass driver), at its entry (the multi-host feed). None answers
-an inner-product or a cosine corpus in squared L2, the ladder's
-``streaming`` rung included: it is skipped, and the host oracle answers
-under the engine's score."""
+other): at construction (the batch solve, the batch mesh engines, a
+resident engine whose corpus takes the streaming select, a mesh daemon
+whose corpus takes the monolithic stream layout), at admission (a k
+whose window passes the kernel's one pass: the multipass driver), at its
+entry (the multi-host feed). None answers an inner-product or a cosine
+corpus in squared L2, the ladder's ``streaming`` rung included: it is
+skipped, and the host oracle answers under the engine's score. Since
+PR 53 the mesh daemon's extract path HAS both forms
+(``tests/test_mesh_score.py``); what is left of its refusal is the
+layout that ranks by squared L2 alone."""
 
 from __future__ import annotations
 
@@ -40,16 +43,10 @@ def _cli(mode):
     return build
 
 
-def _mesh_daemon(score):
+def _mesh_daemon_off_the_extract_path(score):
     from dmlp_tpu.serve.daemon import ServeDaemon
     ServeDaemon(corpus(), EngineConfig(mode="sharded", score=score),
-                mesh_shape=(2, 1))
-
-
-def _mesh_engine(score):
-    from dmlp_tpu.fleet.mesh_engine import MeshResidentEngine
-    MeshResidentEngine(corpus(), EngineConfig(mode="sharded", score=score),
-                       mesh_shape=(2, 1))
+                mesh_shape=(2, 1))                          # no use_pallas
 
 
 def _streaming_select(score):
@@ -84,10 +81,11 @@ REFUSALS = {
                                  r"score='SCORE' form"),
     "ring": (_cli("ring"), r"engine\.sharded\.RingEngine has no"),
     "auto": (_cli("auto"), r"engine\.auto\.AutoShardedEngine has no"),
-    "mesh_daemon": (_mesh_daemon,
-                    r"fleet\.mesh_engine\.MeshResidentEngine has no"),
-    "mesh_engine": (_mesh_engine,
-                    r"fleet\.mesh_engine\.MeshResidentEngine has no"),
+    "mesh_daemon_off_the_extract_path": (
+        _mesh_daemon_off_the_extract_path,
+        r"fleet\.mesh_engine\.MeshResidentEngine's monolithic stream path "
+        r"\(a corpus that does not take the extract path.*\) has no "
+        r"score='SCORE' form"),
     "streaming_select": (_streaming_select,
                          r"ResidentEngine's streaming select .* has no "
                          r"score='SCORE' form"),

@@ -743,6 +743,67 @@ def test_mesh_resident_fold_program_compiles_for_v5e_4x1(v5e, precision):
     assert "all-" not in update.as_text()
 
 
+def test_mesh_cosine_fold_and_merge_compile_for_v5e_4x1(v5e):
+    """``openai-c4-mesh4.bulk``'s two programs (PR 53): the mesh fold at
+    q1024 over a 4x1 mesh's 14 resident chunks of 4 x 51 200 x 1536
+    float32 (a stated capacity of 2 800 000 rows: 704 000 a shard),
+    the 168-slot window its own plan gives at k = 100, under the
+    engine's ``score="cosine"``, which reaches each shard's kernel as
+    the "ip" form in the three-pass split, exactly as the one-chip fold
+    takes it (``_kernel_statics``: no static the l2 program lacks); and
+    the all-gather merge of the four shards' lists behind it, which has
+    no score: it re-selects what the kernel emits. A shard's 4.4 GB of
+    the stack is an argument and less than a chunk is allocated beside
+    it; the fold holds no collective."""
+    from dmlp_tpu.engine.single import plan_chunks, resolve_kcap
+    from dmlp_tpu.fleet.mesh_engine import MeshResidentEngine
+    from dmlp_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS
+    from dmlp_tpu.serve.engine import _kernel_statics
+    mesh = Mesh(np.asarray(v5e).reshape(4, 1), (DATA_AXIS, QUERY_AXIS))
+    cfg = EngineConfig(mode="sharded", dtype="float32", use_pallas=True,
+                       score="cosine")
+    na = 1536
+    sr, t, cr = plan_chunks(2800000 // 4, cfg.resolve_granule("extract"),
+                            cfg.data_block)
+    assert (sr, t, cr) == (704000, 14, 51200)
+    kc = resolve_kcap(cfg, 128, "extract", 4 * sr, staging="float32",
+                      precision="bf16x3", na=na)
+    assert kc == 168
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    eng = object.__new__(MeshResidentEngine)
+    eng.mesh, eng._fns = mesh, {}
+    eng._chunk_rows, eng._shard_rows = cr, sr
+    eng._merge_strategy = "allgather"
+    kern = _kernel_statics("fused", kc, cr, 1024, na, "bf16x3", False,
+                           cfg.score)
+    assert kern["score"] == "ip"
+    compiled = eng._resident_fold_fn(kern).lower(
+        spec((1024, na), jnp.float32, QUERY_AXIS, None),
+        spec((t, 4 * cr, na), jnp.float32, None, DATA_AXIS, None),
+        spec((t, 1, 4 * cr), jnp.float32, None, None, DATA_AXIS),
+        spec((t,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        spec((4, t), jnp.int32, DATA_AXIS, None)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_dmlp_mesh_fold")
+    assert len(_kernel_calls(hlo)) == 2 and " while(" in hlo
+    assert "all-reduce" not in hlo and "all-gather" not in hlo
+    mem = compiled.memory_analysis()          # of one device
+    assert mem.argument_size_in_bytes >= t * cr * na * 4
+    assert mem.temp_size_in_bytes < cr * na * 4
+    _assert_fold_reads_the_stack(compiled, "f32", t, cr, na)
+    lists = spec((4, 1024, kc), jnp.float32, DATA_AXIS, QUERY_AXIS, None)
+    merged = eng._chunk_merge_fn(kc).lower(
+        lists, spec((4, 1024, kc), jnp.int32, DATA_AXIS, QUERY_AXIS, None),
+        spec((4 * sr,), jnp.int32)).compile()
+    text = merged.as_text()
+    assert text.startswith("HloModule jit_dmlp_mesh_merge")
+    assert "all-gather" in text
+
+
 @pytest.mark.slow   # ~25 s, nearly all of it XLA:TPU compiling the merge sort
 def test_sharded_engine_program_compiles_for_v5e_2x2(v5e):
     """The all-gather-merge mesh program, kernel inside shard_map, on
